@@ -25,8 +25,8 @@ class AcceleratorManager:
         return None
 
     @staticmethod
-    def set_visible_accelerator_ids(env: Dict[str, str],
-                                    ids: List[str]) -> None:
+    def set_visible_accelerator_ids(env: Dict[str, str], ids: List[str],
+                                    host_chips: int) -> None:
         pass
 
     @staticmethod

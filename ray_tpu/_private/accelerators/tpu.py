@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import glob
 import os
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, MutableMapping, Optional
 
 from .accelerator import AcceleratorManager
 
@@ -68,19 +68,30 @@ class TPUAcceleratorManager(AcceleratorManager):
         return TPU_VISIBLE_CHIPS_ENV
 
     @staticmethod
-    def set_visible_accelerator_ids(env: Dict[str, str],
-                                    ids: List[str]) -> None:
-        """Sub-host slicing: constrain a worker to a subset of the
-        host's chips (reference :155+ — requires matching
-        TPU_CHIPS_PER_HOST_BOUNDS so libtpu carves the host)."""
-        env[TPU_VISIBLE_CHIPS_ENV] = ",".join(ids)
+    def set_visible_accelerator_ids(env: Dict[str, str], ids: List[str],
+                                    host_chips: int) -> None:
+        """Make a worker see exactly the chips it was granted
+        (reference :155+). A grant of the whole host changes nothing:
+        the machine's own TPU environment (its bounds, topology and
+        accelerator type) already describes it. A sub-host grant names
+        the chips and the bounds by which libtpu carves the host; with
+        libtpu 0.0.34 on a four-chip v5e host these two variables give
+        1- and 2-chip workers that run side by side (chip probe, PR 21).
+        """
+        if len(ids) >= host_chips:
+            return
         bounds = {
             1: TPU_CHIPS_PER_HOST_BOUNDS_1_CHIP,
             2: TPU_CHIPS_PER_HOST_BOUNDS_2_CHIP,
             4: TPU_CHIPS_PER_HOST_BOUNDS_4_CHIP,
         }.get(len(ids))
-        if bounds:
-            env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = bounds
+        if bounds is None:
+            raise ValueError(
+                f"no TPU_CHIPS_PER_HOST_BOUNDS for {len(ids)} of "
+                f"{host_chips} chips; grants are 1, 2, 4 or the whole host"
+            )
+        env[TPU_VISIBLE_CHIPS_ENV] = ",".join(ids)
+        env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = bounds
 
     @staticmethod
     def get_current_node_additional_resources() -> Dict[str, float]:
@@ -97,3 +108,59 @@ class TPUAcceleratorManager(AcceleratorManager):
         if acc_type and worker_id == "0":
             out[f"TPU-{acc_type}-head"] = 1.0
         return out
+
+
+class ChipTable:
+    """Which worker process holds which chip of one host.
+
+    libtpu gives a chip to one process at a time, so a chip passes on
+    only when its holder's process has exited — not when the scheduler
+    stops counting the worker: a killed worker keeps its chips until
+    the kernel has torn the process down. Not thread-safe: the owning
+    control plane (GCS, node daemon) calls it under its own lock."""
+
+    def __init__(self, num_chips: int):
+        self.num_chips = num_chips
+        # chip -> Popen-shaped holder; None while its process starts.
+        self._holder: Dict[int, Any] = {}
+
+    def reserve(self, n: int) -> Optional[List[int]]:
+        """Take n free chips for a process about to start, or None
+        while fewer than n are free."""
+        free = [
+            c for c in range(self.num_chips)
+            if c not in self._holder
+            or (self._holder[c] is not None
+                and self._holder[c].poll() is not None)
+        ]
+        if len(free) < n:
+            return None
+        for c in free[:n]:
+            self._holder[c] = None
+        return free[:n]
+
+    def bind(self, chips: List[int], proc) -> None:
+        for c in chips:
+            self._holder[c] = proc
+
+    def release(self, chips: List[int]) -> None:
+        """Give back a reservation whose process never started."""
+        for c in chips:
+            self._holder.pop(c, None)
+
+
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+)
+
+
+def place_compile_cache(env: MutableMapping[str, str]) -> str:
+    """Decide where a process about to compile for the chip keeps JAX's
+    persistent compile cache, and return the directory. A directory
+    placed from outside (JAX_COMPILATION_CACHE_DIR) is left alone — JAX
+    reads the variable itself; otherwise it is a fixed path inside the
+    checkout, so that every process and every run finds the same one."""
+    return env.setdefault(
+        COMPILE_CACHE_ENV, os.path.join(_CHECKOUT, ".jax_cache")
+    )

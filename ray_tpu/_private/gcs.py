@@ -48,6 +48,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 
 from . import chaos as _chaos
 from . import events as _events
+from .accelerators.tpu import ChipTable, TPUAcceleratorManager
 from .config import RayConfig
 from .object_plane import directory as _objdir
 from .ids import ActorID, NodeID, ObjectID, PlacementGroupID, WorkerID
@@ -133,11 +134,13 @@ class WorkerHandle:
     # Startup reaping: remote spawns have proc=None, so a raylet that
     # never delivers the worker is caught by register-timeout instead.
     spawned_at: float = field(default_factory=time.time)
-    # TPU-visible worker: spawned with accelerator access (reference:
-    # accelerator visibility env vars set per worker —
-    # _private/accelerators/tpu.py TPU_VISIBLE_CHIPS). Non-TPU workers
-    # are pinned to CPU so they never contend for the chip.
-    tpu: bool = False
+    # Chips granted to a TPU-visible worker; it sees exactly these
+    # (reference: accelerator visibility env vars set per worker —
+    # _private/accelerators/tpu.py TPU_VISIBLE_CHIPS). 0 is a CPU
+    # worker, pinned to CPU so it never contends for a chip. libtpu
+    # keeps a chip for the life of the process that opened it, so a TPU
+    # worker serves one task or actor and is retired, never pooled.
+    num_chips: int = 0
     # Direct actor-call socket served by the worker process (reference:
     # actor calls bypass raylets — direct_actor_task_submitter.h).
     direct_addr: str = ""
@@ -149,6 +152,10 @@ class WorkerHandle:
     packed: Dict[bytes, TaskSpec] = field(default_factory=dict)
     # Resources held while leased to a client (direct task transport).
     lease_resources: Optional[Dict[str, float]] = None
+
+    @property
+    def tpu(self) -> bool:
+        return self.num_chips > 0
 
 
 @dataclass
@@ -188,6 +195,9 @@ class NodeState:
     # (reference: raylet NodeManager + embedded ObjectManager).
     conn: Optional[PeerConn] = None
     transfer_addr: str = ""
+    # Chip identity for the TPU workers the GCS spawns on this node
+    # itself (conn is None); a daemon owns it on its own node.
+    chips: Optional[ChipTable] = None
     # Liveness bookkeeping rides time.monotonic() (NOT wall clock): a
     # wall step — NTP slew, VM resume — must never mass-declare live
     # nodes dead (the health sweep compares against monotonic now).
@@ -319,6 +329,11 @@ class _PendingQueue:
 
 class _Unschedulable(Exception):
     """Task can never be placed (bad/removed PG); fail instead of requeue."""
+
+
+def _chips_for(spec: TaskSpec) -> int:
+    """Chips the worker that runs ``spec`` must see (0: a CPU worker)."""
+    return math.ceil(spec.resources.get("TPU", 0))
 
 
 def _fits(avail: Dict[str, float], demand: Dict[str, float]) -> bool:
@@ -1435,6 +1450,8 @@ class GcsServer:
                     if not w.current_task.actor_creation or error_blob is not None:
                         self._release_task_resources(w.current_task, w.node_id)
                 w.current_task = None
+                if w.state == W_IDLE and w.tpu:
+                    self._retire_worker(w)
         total = msg.get("streaming_total")
         if total is not None:
             self._end_stream(task_id, total, error_blob)
@@ -4896,8 +4913,8 @@ class GcsServer:
         # Each task that found resources but no worker claims starting
         # workers of its kind; we only spawn when claims exceed workers
         # already starting (reference: worker_pool.cc PopWorker ->
-        # StartWorkerProcess). Keyed by (node, needs_tpu).
-        claims: Dict[Tuple[bytes, bool], int] = {}
+        # StartWorkerProcess). Keyed by (node, chips per worker).
+        claims: Dict[Tuple[bytes, int], int] = {}
         # Special queue (PG-pinned / strategy tasks): placement is
         # per-task state, scan them all.
         special_requeue: List[TaskSpec] = []
@@ -4976,7 +4993,7 @@ class GcsServer:
                 self._pending.classes.move_to_end(key)
         return progressed
 
-    def _try_place(self, spec: TaskSpec, claims: Dict[Tuple[bytes, bool], int],
+    def _try_place(self, spec: TaskSpec, claims: Dict[Tuple[bytes, int], int],
                    backlog: int = 0) -> str:
         """Attempt to place one pending task. Returns "dispatched",
         "unschedulable" (terminal failure recorded), "deferred" (deps
@@ -5009,8 +5026,8 @@ class GcsServer:
             # resources were acquired in _pick_node; give them back and
             # retry once a worker registers.
             self._release_task_resources(spec, node.node_id)
-            needs_tpu = spec.resources.get("TPU", 0) > 0
-            nid = (node.node_id.binary(), needs_tpu)
+            num_chips = _chips_for(spec)
+            nid = (node.node_id.binary(), num_chips)
             # This probe stands for the whole blocked class behind it:
             # claim enough boots to cover the backlog (the admission cap
             # still bounds concurrent boots).
@@ -5022,17 +5039,17 @@ class GcsServer:
                 for w in self.workers.values()
                 if w.node_id == node.node_id
                 and w.state == W_STARTING
-                and w.tpu == needs_tpu
+                and w.num_chips == num_chips
             )
             pool_same_kind = sum(
                 1
                 for wid in node.pool
                 if (w := self.workers.get(wid)) is not None
-                and w.tpu == needs_tpu
+                and w.num_chips == num_chips
             )
             can_grow = (
                 spec.actor_creation
-                or needs_tpu
+                or num_chips
                 or pool_same_kind + starting
                 < max(int(node.total.get("CPU", 1)), 1)
             )
@@ -5046,9 +5063,10 @@ class GcsServer:
                 4, int(node.total.get("CPU", 1))
             )
             while starting < claims[nid] and can_grow and starting < cap:
-                self._spawn_worker(node, tpu=needs_tpu)
+                if self._spawn_worker(node, num_chips) is None:
+                    break  # chips still held by an exiting process
                 starting += 1
-                if not (spec.actor_creation or needs_tpu):
+                if not (spec.actor_creation or num_chips):
                     can_grow = pool_same_kind + starting < max(
                         int(node.total.get("CPU", 1)), 1
                     )
@@ -5119,8 +5137,8 @@ class GcsServer:
         )
 
     def _pick_worker(self, node: NodeState, spec: TaskSpec) -> Optional[WorkerHandle]:
-        needs_tpu = spec.resources.get("TPU", 0) > 0
-        if not needs_tpu and self._packable(spec):
+        num_chips = _chips_for(spec)
+        if not num_chips and self._packable(spec):
             # Pick the least-loaded live host; but while every host is
             # at/over the spread threshold and the node can still open
             # hosts, prefer converting another idle worker — packing
@@ -5168,28 +5186,46 @@ class GcsServer:
                 w is not None
                 and w.state == W_IDLE
                 and w.conn is not None
-                and w.tpu == needs_tpu
+                and w.num_chips == num_chips
             ):
                 if spec.actor_creation:
                     node.pool.discard(wid)
                 return w
         return None
 
-    def _spawn_worker(self, node: NodeState, tpu: bool = False) -> WorkerHandle:
+    def _spawn_worker(self, node: NodeState,
+                      num_chips: int = 0) -> Optional[WorkerHandle]:
+        """Start a worker that sees ``num_chips`` chips (0: a CPU
+        worker). On a node the GCS spawns for itself, returns None and
+        starts nothing while fewer chips are free: a retired or killed
+        TPU worker holds its chips until its process has exited."""
+        chips = None
+        if num_chips and node.conn is None:
+            if node.chips is None:
+                node.chips = ChipTable(int(node.total.get("TPU", 0)))
+            chips = node.chips.reserve(num_chips)
+            if chips is None:
+                return None
         self._worker_counter += 1
         wid = WorkerID.from_random()
-        w = WorkerHandle(worker_id=wid, node_id=node.node_id, tpu=tpu)
+        w = WorkerHandle(
+            worker_id=wid, node_id=node.node_id, num_chips=num_chips
+        )
         self.workers[wid.binary()] = w
         _events.record(
             _events.WORKER, wid.hex(), "SPAWN_REQUESTED",
-            {"node": node.node_id.hex()[:12], "tpu": tpu},
+            {"node": node.node_id.hex()[:12], "chips": num_chips},
         )
         if node.conn is not None:
-            # Remote node: its daemon spawns the worker; the worker
-            # connects back to us over TCP on its own.
+            # Remote node: its daemon spawns the worker (and owns chip
+            # identity there); the worker connects back to us over TCP
+            # on its own.
             try:
                 node.conn.send(
-                    {"type": "spawn_worker", "worker_id": wid.binary(), "tpu": tpu}
+                    {
+                        "type": "spawn_worker", "worker_id": wid.binary(),
+                        "num_chips": num_chips,
+                    }
                 )
             except ConnectionLost:
                 self._handle_node_death(
@@ -5214,19 +5250,43 @@ class GcsServer:
                 "1" if _events.get_recorder().enabled else "0"
             ),
         }
+        if chips is not None:
+            TPUAcceleratorManager.set_visible_accelerator_ids(
+                env, [str(c) for c in chips], node.chips.num_chips
+            )
         logdir = os.path.join(self.session_dir, "logs")
         os.makedirs(logdir, exist_ok=True)
         log_path = os.path.join(logdir, f"worker-{wid.hex()[:8]}.out")
         # Pipelined spawn returns before the fork completes; a failed
         # fork must tear down the W_STARTING entry or pool accounting
         # would count a ghost forever.
-        w.proc = self._spawner.spawn(
-            env, log_path, tpu=tpu,
-            on_fail=lambda b=wid.binary(): self._handle_worker_death(
-                b, "worker spawn failed"
-            ),
-        )
+        try:
+            w.proc = self._spawner.spawn(
+                env, log_path, tpu=num_chips > 0,
+                on_fail=lambda b=wid.binary(): self._handle_worker_death(
+                    b, "worker spawn failed"
+                ),
+            )
+        except BaseException:
+            if chips is not None:
+                node.chips.release(chips)
+            raise
+        if chips is not None:
+            node.chips.bind(chips, w.proc)
         return w
+
+    def _retire_worker(self, w: WorkerHandle) -> None:
+        """A TPU worker has served its task: ask it to exit and drop it
+        through the death path (which reaps the process). Its chips
+        pass on once the process is gone. Caller holds the lock."""
+        if w.conn is not None:
+            try:
+                w.conn.send({"type": "exit"})
+            except ConnectionLost:
+                pass
+        self._handle_worker_death(
+            w.worker_id.binary(), "tpu worker retired"
+        )
 
     def _maybe_repool_host(self, w: WorkerHandle) -> None:
         """An emptied shared host (no packed actors, no in-flight

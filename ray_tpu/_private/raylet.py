@@ -32,6 +32,7 @@ from typing import Dict, Optional
 
 from . import chaos as _chaos
 from . import events as _events
+from .accelerators.tpu import ChipTable, TPUAcceleratorManager
 from .config import RayConfig
 from .ids import WorkerID
 from .object_store import ObjectStore
@@ -171,11 +172,11 @@ class NodeDaemon:
         # Leased-out counts by worker kind; feeds the heartbeat's
         # local_*_in_use resource-view sync.
         self._leased_count = {"cpu": 0, "tpu": 0}
-        # TPU chip slots (one chip per TPU worker, local or head-
-        # routed; TPU_VISIBLE_CHIPS pins each worker to its chip).
-        # Grown on demand — chips are too valuable to prestart on.
-        self._tpu_slots = int(self.resources.get("TPU", 0))
-        self._chip_owner: Dict[int, bytes] = {}  # chip -> worker id
+        # Chip identity on this node (guarded by self._lock): every TPU
+        # worker, locally leased or head-routed, sees exactly the chips
+        # reserved for it here. Grown on demand — chips are too valuable
+        # to prestart on.
+        self._chips = ChipTable(int(self.resources.get("TPU", 0)))
         self._lease_addr = f"/tmp/rtpu-rl-{self.node_ns.rstrip('_')}.sock"
         try:
             os.unlink(self._lease_addr)
@@ -270,36 +271,43 @@ class NodeDaemon:
         }
         if msg.get("local_only"):
             env["RAY_TPU_LOCAL_ONLY"] = "1"
-        chips = msg.get("visible_chips")
-        if chips is None and msg.get("tpu") and self._tpu_slots:
+        chips = msg.get("visible_chips")  # reserved by the local lease
+        if chips is None and msg.get("num_chips"):
             # Head-routed TPU spawn: this daemon owns chip identity on
-            # its node — assign a free chip so head-scheduled and
-            # locally-leased workers never initialize the same device.
-            chip = self._assign_chip(wid.binary())
-            chips = None if chip is None else [chip]
+            # its node, so head-scheduled and locally-leased workers
+            # never initialize the same device.
+            chips = self._reserve_chips(msg["num_chips"])
+            if chips is None:
+                # Still held by an exiting process or by this daemon's
+                # own lease pool (idle ones were just retired): the head
+                # drops its W_STARTING entry and asks again.
+                self._report_spawn_failure(wid)
+                return
         if chips is not None:
-            from .accelerators.tpu import TPUAcceleratorManager
-
             TPUAcceleratorManager.set_visible_accelerator_ids(
-                env, [str(c) for c in chips]
+                env, [str(c) for c in chips], self._chips.num_chips
             )
-            with self._lock:
-                self._chip_owner.update(
-                    {int(c): wid.binary() for c in chips}
-                )
         os.makedirs(self.logs_dir, exist_ok=True)
         log_path = os.path.join(self.logs_dir, f"worker-{wid.hex()[:8]}.out")
-        proc = self._spawner.spawn(
-            env,
-            log_path,
-            tpu=bool(msg.get("tpu")),
-            # Even the cold-path Popen failed: tell the head, or its
-            # W_STARTING entry (proc=None for remote spawns) would hold
-            # the startup-cap slot and the claimed task forever.
-            on_fail=lambda w=wid: self._report_spawn_failure(w),
-        )
+        try:
+            proc = self._spawner.spawn(
+                env,
+                log_path,
+                tpu=chips is not None,
+                # Even the cold-path Popen failed: tell the head, or its
+                # W_STARTING entry (proc=None for remote spawns) would
+                # hold the startup-cap slot and the claimed task forever.
+                on_fail=lambda w=wid: self._report_spawn_failure(w),
+            )
+        except BaseException:
+            if chips is not None:
+                with self._lock:
+                    self._chips.release(chips)
+            raise
         with self._lock:
             self._workers[wid.binary()] = proc
+            if chips is not None:
+                self._chips.bind(chips, proc)
 
     def _report_spawn_failure(self, wid) -> None:
         try:
@@ -309,33 +317,30 @@ class NodeDaemon:
         except ConnectionLost:
             pass
 
-    def _assign_chip_locked(self, wid: bytes):
-        """Caller holds self._lock."""
-        for c in range(self._tpu_slots):
-            owner = self._chip_owner.get(c)
-            if owner is None or self._worker_dead(owner):
-                self._chip_owner[c] = wid
-                return c
-        return None  # overcommitted: spawn unrestricted (legacy shape)
-
-    def _assign_chip(self, wid: bytes):
+    def _reserve_chips(self, n: int):
+        """n chips for a head-routed worker, or None while fewer are
+        free. This daemon's idle leased TPU workers are a cache that
+        holds chips the head's resource view counts as free: on a
+        shortfall they are retired, so the head's next ask finds their
+        chips."""
         with self._lock:
-            return self._assign_chip_locked(wid)
-
-    def _worker_dead(self, wid: bytes) -> bool:
-        proc = self._workers.get(wid)
-        return proc is None or proc.poll() is not None
-
-    def _free_chips(self, wid: bytes):
-        with self._lock:
-            for c, owner in list(self._chip_owner.items()):
-                if owner == wid:
-                    del self._chip_owner[c]
+            chips = self._chips.reserve(n)
+            if chips is not None:
+                return chips
+            idle = [
+                rec for rec in self._local_workers.values()
+                if rec.get("tpu") and rec["state"] == "idle"
+                and rec["proc"] is not None
+            ]
+            for rec in idle:
+                rec["state"] = "dead"
+        for rec in idle:
+            rec["proc"].terminate()
+        return None
 
     def _kill_worker(self, wid: bytes):
         with self._lock:
             proc = self._workers.pop(wid, None)
-        self._free_chips(wid)
         if proc is not None:
             proc.terminate()
 
@@ -356,13 +361,10 @@ class NodeDaemon:
                     "tpu": False, "chip": None,
                 }
         with self._lock:
-            rec0 = self._local_workers.get(wid.binary(), {})
-            tpu = bool(rec0.get("tpu"))
-            chip = rec0.get("chip")
+            chip = self._local_workers.get(wid.binary(), {}).get("chip")
         self._spawn_worker(
             {
                 "worker_id": wid.binary(),
-                "tpu": tpu,
                 "local_only": True,
                 "visible_chips": None if chip is None else [chip],
             }
@@ -442,7 +444,7 @@ class NodeDaemon:
                         and bool(r.get("tpu")) == wants_tpu
                     )
                     cap = int(
-                        self._tpu_slots
+                        self._chips.num_chips
                         if wants_tpu
                         else self.resources.get("CPU", 0)
                     )
@@ -454,15 +456,16 @@ class NodeDaemon:
                         w = WorkerID(os.urandom(16))
                         chip = None
                         if wants_tpu:
-                            chip = self._assign_chip_locked(w.binary())
-                            if chip is None:
-                                # All chips owned (e.g. by head-routed
+                            reserved = self._chips.reserve(1)
+                            if reserved is None:
+                                # All chips held (e.g. by head-routed
                                 # workers): deny; the GCS route queues.
                                 try:
                                     peer.reply(msg, ok=False)
                                 except ConnectionLost:
                                     pass
                                 return
+                            chip = reserved[0]
                         self._local_workers[w.binary()] = {
                             "state": "starting", "addr": None, "proc": None,
                             "tpu": wants_tpu, "chip": chip,
@@ -695,7 +698,6 @@ class NodeDaemon:
                 self._workers.clear()
                 self._local_workers.clear()
                 self._leased_count = {"cpu": 0, "tpu": 0}
-                self._chip_owner.clear()
             for proc in workers:
                 proc.terminate()
             deadline = time.time() + 2.0

@@ -35,7 +35,7 @@ class MixtralConfig(LlamaConfig):
     capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.02
     # "auto" (default): measured selection between the backends below,
-    # cached per (backend, device kind, shape) — resolve_moe_dispatch().
+    # cached per process and shape — resolve_moe_dispatch().
     # "capacity": capacity-bounded static buffers with an [E, B, C, D]
     # expert axis — mesh-shards for expert parallelism (dispatch rides
     # an all-to-all over ICI) and lowers to plain batched matmuls, at
@@ -89,13 +89,13 @@ def resolve_moe_dispatch(
     (fwd+bwd) at this config's shapes on the live backend — not a
     config flag: ragged_dot vs capacity vs the pallas gmm rank
     differently across TPU generations and compiler versions.
-    Resolutions persist to ~/.cache/ray_tpu/moe_dispatch.json keyed by
-    (backend, device kind, shape), so the probe runs once per machine.
+    Resolutions are kept per process, keyed by shape. A backend that
+    fails to compile or run raises: a verdict reached by dropping the
+    failed candidate would hide a broken kernel behind the other path.
     Under an expert-sharded mesh the capacity path is returned without
     probing (its [E, B, C, D] layout is what rides the EP all-to-all;
     the gmm layout is per-shard).
     """
-    import json
     import os
     import time
 
@@ -111,21 +111,6 @@ def resolve_moe_dispatch(
     skey = _shape_key(cfg)
     if skey in _RESOLVED:
         return _RESOLVED[skey]
-    dev = jax.devices()[0]
-    cache_key = (
-        f"{jax.default_backend()}-{dev.device_kind}-{skey}-N{tokens}"
-    )
-    cache_path = os.path.join(
-        os.path.expanduser("~"), ".cache", "ray_tpu", "moe_dispatch.json"
-    )
-    try:
-        with open(cache_path) as f:
-            disk = json.load(f)
-    except (OSError, ValueError):
-        disk = {}
-    if cache_key in disk:
-        _RESOLVED[skey] = disk[cache_key]
-        return disk[cache_key]
 
     import numpy as np
     from dataclasses import replace as _replace
@@ -162,27 +147,9 @@ def resolve_moe_dispatch(
         jax.tree_util.tree_map(lambda a: a.block_until_ready(), g)
         return time.perf_counter() - t0
 
-    candidates = ["capacity", "gmm"]
-    times = {}
-    for name in candidates:
-        try:
-            times[name] = _time_backend(name)
-        except Exception:  # noqa: BLE001 - backend unsupported here
-            continue
-    if not times:
-        # Transient probe failure (e.g. chip busy): fall back WITHOUT
-        # persisting, so the next process probes again.
-        _RESOLVED[skey] = "capacity"
-        return "capacity"
+    times = {name: _time_backend(name) for name in ("capacity", "gmm")}
     winner = min(times, key=times.get)
     _RESOLVED[skey] = winner
-    disk[cache_key] = winner
-    try:
-        os.makedirs(os.path.dirname(cache_path), exist_ok=True)
-        with open(cache_path, "w") as f:
-            json.dump(disk, f)
-    except OSError:
-        pass
     return winner
 
 

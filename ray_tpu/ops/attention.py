@@ -7,14 +7,16 @@ standard blockwise-softmax scheme: iterate kv blocks innermost,
 carrying a running (max, sum, acc) triple in VMEM so the full [Tq, Tk]
 score matrix never materializes in HBM.
 
-Forward is a pallas kernel on TPU (MXU matmuls in f32 accumulation);
-backward recomputes probabilities from the saved log-sum-exp in plain
-XLA ops (O(T^2) flops, O(T*block) live memory after XLA fusion). On
-non-TPU backends everything falls back to `attention_reference`.
+Forward and backward are pallas kernels on a TPU backend (MXU matmuls
+in f32 accumulation; the backward recomputes probabilities from the
+saved log-sum-exp). On any other backend `flash_attention` is
+`attention_reference`; which one ran is visible in the lowered program
+(`tpu_custom_call`), and chip_smoke.py asserts it.
 """
 from __future__ import annotations
 
 import functools
+import os
 from typing import Optional
 
 import jax
@@ -26,18 +28,21 @@ NEG_INF = -1e30
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
     """Pallas interpreter mode: lets the TPU kernels (incl. the causal
-    block-skip control flow) run bit-accurately on CPU for tests."""
-    import os
-
-    return os.environ.get("RAY_TPU_PALLAS_INTERPRET") == "1"
+    block-skip control flow) run bit-accurately on CPU for tests.
+    Refused on a TPU backend, where it would quietly stand in for the
+    compiled kernels."""
+    on = os.environ.get("RAY_TPU_PALLAS_INTERPRET") == "1"
+    if on and _on_tpu():
+        raise RuntimeError(
+            "RAY_TPU_PALLAS_INTERPRET=1 on a TPU backend: interpret mode "
+            "is for the CPU tests; unset it to run the compiled kernels"
+        )
+    return on
 
 
 def attention_reference(
@@ -417,17 +422,15 @@ def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, do):
     q, k, v, o, lse = res
     tq, tk = q.shape[1], k.shape[1]
-    if (
-        (_on_tpu() or _interpret())
-        and tq >= 128 and tk >= 128 and q.shape[2] % 8 == 0
-    ):
+    if tq >= 128 and tk >= 128 and q.shape[2] % 8 == 0:
         return _flash_bwd_pallas(
             q, k, v, o, lse, do, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k,
         )
-    # XLA fallback: recompute probabilities from lse, p = exp(s - lse).
-    # Memory high-water is the [Tq, Tk] block per batch*head slice —
-    # fine at short seq, the pallas kernels carry long context.
+    # Shapes below the kernels' tiling (reached only through
+    # force_pallas): recompute probabilities from lse in XLA,
+    # p = exp(s - lse). Memory high-water is the [Tq, Tk] block per
+    # batch*head slice.
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
     ) * sm_scale

@@ -21,16 +21,13 @@ expert (tokens are group-sorted, so revisits are consecutive).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return os.environ.get("RAY_TPU_PALLAS_INTERPRET") == "1"
+from .attention import _interpret
 
 
 def _gmm_kernel(tg_ref, lhs_ref, rhs_ref, out_ref):
@@ -63,8 +60,13 @@ def _gmm_pallas(lhs, rhs, tile_group, block_m, block_n):
 
 def _tgmm_kernel(tg_ref, lhs_ref, dout_ref, drhs_ref, acc_scr):
     im = pl.program_id(2)
+    nm = pl.num_programs(2)
+    # Both sides of logical_or are evaluated: the neighbour index is
+    # clamped so the first and last tile never read outside tg_ref.
+    prev_im = jnp.maximum(im - 1, 0)
+    next_im = jnp.minimum(im + 1, nm - 1)
 
-    @pl.when(jnp.logical_or(im == 0, tg_ref[im] != tg_ref[im - 1]))
+    @pl.when(jnp.logical_or(im == 0, tg_ref[im] != tg_ref[prev_im]))
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
@@ -75,9 +77,7 @@ def _tgmm_kernel(tg_ref, lhs_ref, dout_ref, drhs_ref, acc_scr):
         preferred_element_type=jnp.float32,
     )
 
-    nm = pl.num_programs(2)
-
-    @pl.when(jnp.logical_or(im == nm - 1, tg_ref[im + 1] != tg_ref[im]))
+    @pl.when(jnp.logical_or(im == nm - 1, tg_ref[next_im] != tg_ref[im]))
     def _flush():
         drhs_ref[0] = acc_scr[...].astype(drhs_ref.dtype)
 

@@ -26,8 +26,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-from ray_tpu._compat import axis_size, shard_map
 
 from .attention import _flash_bwd_pallas, _flash_fwd_pallas, _on_tpu
 

@@ -119,33 +119,12 @@ def logical_sharding(mesh: Mesh, logical_axes: Sequence[Optional[str]]) -> Named
     return NamedSharding(mesh, logical_to_spec(logical_axes))
 
 
-def _ambient_mesh_axis_names():
-    """Axis names of the ambient mesh: jax.set_mesh context first, then
-    the legacy `with mesh:` resource env. None if neither is active."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and mesh.axis_names:
-            return mesh.axis_names
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-
-        phys = mesh_lib.thread_resources.env.physical_mesh
-        if phys is not None and not phys.empty:
-            return phys.axis_names
-    except Exception:
-        pass
-    return None
-
-
 def with_logical_constraint(x, logical_axes: Sequence[Optional[str]]):
     """In-jit sharding constraint by logical axis names. No-op when there
-    is no ambient mesh (single-device runs, unit tests) so model code can
-    annotate unconditionally. Honors both `jax.set_mesh` and the legacy
-    `with mesh:` context."""
-    axis_names = _ambient_mesh_axis_names()
-    if axis_names is None:
+    is no ambient mesh (`jax.set_mesh`; single-device runs, unit tests)
+    so model code can annotate unconditionally."""
+    axis_names = jax.sharding.get_abstract_mesh().axis_names
+    if not axis_names:
         return x
     spec = logical_to_spec(logical_axes)
     # Drop axes the ambient mesh doesn't have.

@@ -35,10 +35,8 @@ from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ray_tpu._compat import shard_map
 
 
 def stack_stage_params(per_stage: Sequence[Any]):

@@ -12,6 +12,7 @@ from .config import (  # noqa: F401
     ScalingConfig,
 )
 from .session import get_context, report  # noqa: F401
+from .step import make_train_step  # noqa: F401
 from .trainer import JaxTrainer, get_checkpoint  # noqa: F401
 
 from ray_tpu._private import usage_stats as _usage

@@ -202,7 +202,13 @@ class JaxTrainer:
             import secrets
 
             rdv_token = secrets.token_hex(4)
-            worker_cls = ray_tpu.remote(TrainWorker)
+            # Each worker takes what its bundle reserved: a worker that
+            # asks for no TPU is spawned pinned to the CPU, whatever
+            # bundle it is placed in.
+            res = sc.worker_resources()
+            worker_cls = ray_tpu.remote(TrainWorker).options(
+                num_cpus=res.pop("CPU", 0), resources=res, max_concurrency=2
+            )
             for rank in range(n):
                 workers.append(
                     worker_cls.options(
@@ -210,7 +216,6 @@ class JaxTrainer:
                             placement_group=pg,
                             placement_group_bundle_index=rank,
                         ),
-                        max_concurrency=2,
                     ).remote(
                         rank, n, name, storage, sc.use_jax_distributed,
                         None, rdv_token,

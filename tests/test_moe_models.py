@@ -240,8 +240,10 @@ def test_gmm_dispatch_agrees_with_ragged(tiny_moe, monkeypatch):
 
 
 def test_moe_dispatch_auto_resolution(tiny_moe, monkeypatch, tmp_path):
-    """"auto" resolves via a measured probe, caches to disk, and forces
-    capacity under an expert-sharded mesh."""
+    """"auto" resolves via a measured probe, keeps the verdict in the
+    process (nothing is written under the home directory), forces
+    capacity under an expert-sharded mesh, and lets a backend's failure
+    through."""
     import dataclasses
 
     from ray_tpu.models import mixtral as mx
@@ -262,15 +264,21 @@ def test_moe_dispatch_auto_resolution(tiny_moe, monkeypatch, tmp_path):
     mx._RESOLVED.clear()
     assert mx.resolve_moe_dispatch(auto_cfg, mesh=mesh) == "capacity"
 
-    # Measured probe on this backend: must return a working backend and
-    # persist it (gmm needs interpret mode to be probe-able on CPU).
+    # A backend whose kernel cannot compile here (the gmm kernel on a CPU
+    # backend without interpret mode) fails the resolution; it is not
+    # dropped in favour of the other one.
     monkeypatch.setenv("HOME", str(tmp_path))
-    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     mx._RESOLVED.clear()
+    with pytest.raises(Exception):
+        mx.resolve_moe_dispatch(auto_cfg, tokens=64, steps=1)
+    assert not mx._RESOLVED
+
+    # Measured probe on this backend: must return a working backend
+    # (gmm needs interpret mode to be probe-able on CPU).
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     winner = mx.resolve_moe_dispatch(auto_cfg, tokens=64, steps=1)
     assert winner in ("capacity", "gmm")
-    cache = tmp_path / ".cache" / "ray_tpu" / "moe_dispatch.json"
-    assert cache.exists()
-    # Cached: a fresh in-process resolution short-circuits to the same.
-    mx._RESOLVED.clear()
+    assert list(tmp_path.iterdir()) == []
+    # Kept for the process: resolving again does not probe.
+    monkeypatch.setattr(mx, "MoELayer", None)
     assert mx.resolve_moe_dispatch(auto_cfg) == winner

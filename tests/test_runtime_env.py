@@ -109,7 +109,7 @@ def test_tpu_visible_chips_bounds():
     from ray_tpu._private.accelerators import TPUAcceleratorManager as M
 
     env = {}
-    M.set_visible_accelerator_ids(env, ["0", "1"])
+    M.set_visible_accelerator_ids(env, ["0", "1"], host_chips=4)
     assert env["TPU_VISIBLE_CHIPS"] == "0,1"
     assert env["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,2,1"
 
