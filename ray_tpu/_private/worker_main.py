@@ -50,6 +50,7 @@ from .protocol import OP_CALL, OP_REPLY
 from .task_spec import TaskSpec
 from ..exceptions import RayActorError, RayTaskError
 from ..object_ref import ObjectRef
+from ..util import tracing as _tracing
 
 
 def _spec_from_frame(frame) -> TaskSpec:
@@ -424,13 +425,6 @@ class WorkerRuntime:
                 # lazy reply: the reader thread flushes once input drains.
                 self._execute(spec, (peer, req_id, True))
             return
-        from ..util import tracing
-
-        if tracing.enabled():
-            spec = _spec_from_frame(frame)
-            with self._lock_for(frame[7]):
-                self._execute(spec, (peer, req_id, True))
-            return
         self._execute_inline(frame, peer)
 
     _SEALED_LEDGER_CAP = 8192
@@ -447,7 +441,7 @@ class WorkerRuntime:
         """Lean serial executor for OP_CALL frames: no shim TaskSpec, one
         results pass building both the reply tuples and the (batched)
         task_done record. The generic path handles everything this
-        declines (async/pool actors, terminate, apply, tracing)."""
+        declines (async/pool actors, terminate, apply)."""
         from .submit import _EMPTY_ARGS_BLOB
         from ..object_ref import _CaptureRefs
 
@@ -460,7 +454,7 @@ class WorkerRuntime:
         self._executing[tid] = tuple(
             tid[:12] + i.to_bytes(4, "little") for i in range(nret)
         )
-        with self._lock_for(aid):
+        with self._lock_for(aid), _tracing.span(_tracing.WORKER_EXEC):
             _events.set_task_context(tid_hex)
             try:
                 if aid is not None:
@@ -493,66 +487,69 @@ class WorkerRuntime:
             finally:
                 _events.set_task_context(None)
         t_end = time.time() if _rec.enabled else 0.0
-        error_blob = None
-        tuple_results = None
-        dict_results = []
-        if exc is not None:
-            if not isinstance(exc, (RayTaskError, RayActorError)):
-                exc = RayTaskError.from_exception(name, exc)
-            try:
-                error_blob = serialization.pack(exc)
-            except Exception:
-                error_blob = serialization.pack(
-                    RayTaskError(name, exc.traceback_str)
-                )
-            dict_results = [
-                {"object_id": tid[:12] + i.to_bytes(4, "little")}
-                for i in range(nret)
-            ]
-        else:
-            values = list(value) if nret > 1 else [value]
-            if nret > 1 and len(values) != nret:
-                error_blob = serialization.pack(
-                    RayTaskError(
-                        name,
-                        f"task declared num_returns={nret} but "
-                        f"returned {len(values)} values",
+        from .protocol import ConnectionLost
+
+        with _tracing.span(_tracing.WORKER_REPLY):
+            error_blob = None
+            tuple_results = None
+            dict_results = []
+            if exc is not None:
+                if not isinstance(exc, (RayTaskError, RayActorError)):
+                    exc = RayTaskError.from_exception(name, exc)
+                try:
+                    error_blob = serialization.pack(exc)
+                except Exception:
+                    error_blob = serialization.pack(
+                        RayTaskError(name, exc.traceback_str)
                     )
-                )
                 dict_results = [
                     {"object_id": tid[:12] + i.to_bytes(4, "little")}
                     for i in range(nret)
                 ]
             else:
-                tuple_results = []
-                for i, v in enumerate(values):
-                    d = self._seal_value(tid[:12] + i.to_bytes(4, "little"), v)
-                    tuple_results.append(
-                        (
-                            d.get("inline"),
-                            d.get("segment"),
-                            d.get("size", 0),
-                            # () not None: None used to push the whole
-                            # reply onto the pickle fallback (fastpath
-                            # enc_reply rejected it).
-                            d.get("children") or (),
+                values = list(value) if nret > 1 else [value]
+                if nret > 1 and len(values) != nret:
+                    error_blob = serialization.pack(
+                        RayTaskError(
+                            name,
+                            f"task declared num_returns={nret} but "
+                            f"returned {len(values)} values",
                         )
                     )
-                    dict_results.append(d)
-        from .protocol import ConnectionLost
-
-        try:
-            peer.send_lazy((OP_REPLY, req_id, error_blob, tuple_results))
-        except ConnectionLost:
-            pass
-        self._done_batcher.add(
-            {
-                "task_id": tid,
-                "name": name,
-                "results": dict_results,
-                "error": error_blob,
-            }
-        )
+                    dict_results = [
+                        {"object_id": tid[:12] + i.to_bytes(4, "little")}
+                        for i in range(nret)
+                    ]
+                else:
+                    tuple_results = []
+                    for i, v in enumerate(values):
+                        d = self._seal_value(
+                            tid[:12] + i.to_bytes(4, "little"), v
+                        )
+                        tuple_results.append(
+                            (
+                                d.get("inline"),
+                                d.get("segment"),
+                                d.get("size", 0),
+                                # () not None: None used to push the whole
+                                # reply onto the pickle fallback (fastpath
+                                # enc_reply rejected it).
+                                d.get("children") or (),
+                            )
+                        )
+                        dict_results.append(d)
+            try:
+                peer.send_lazy((OP_REPLY, req_id, error_blob, tuple_results))
+            except ConnectionLost:
+                pass
+            self._done_batcher.add(
+                {
+                    "task_id": tid,
+                    "name": name,
+                    "results": dict_results,
+                    "error": error_blob,
+                }
+            )
         self._executing.pop(tid, None)
         # t_fork truthy too: recording may have been toggled on
         # mid-execution, and a half-captured span (0.0 boundaries)
@@ -691,30 +688,11 @@ class WorkerRuntime:
             method = getattr(
                 self._actor_for(spec.actor_id.binary()), spec.method_name
             )
-            from ..util import tracing
-
-            if tracing.enabled():
-                # Actor-method span, parented to the actor's creation
-                # context (per-call caller context isn't carried).
-                ctx = tracing.new_context(spec.name)
-                t0 = time.time()
-                result = method(*args, **kwargs)
-                tracing.record_span(spec.name, t0, time.time(), ctx)
-                return result
             return method(*args, **kwargs)
         if spec.runtime_env:
             with _re.activate(spec.runtime_env, self.client):
                 args, kwargs = self._resolve_args(spec)
                 fn = self._resolve_function(spec)
-                from ..util import tracing
-
-                if tracing.enabled():
-                    t0 = time.time()
-                    result = fn(*args, **kwargs)
-                    tracing.record_span(
-                        spec.name, t0, time.time(), tracing.current_context()
-                    )
-                    return result
                 return fn(*args, **kwargs)
         args, kwargs = self._resolve_args(spec)
         fn = self._resolve_function(spec)
@@ -1144,7 +1122,8 @@ class WorkerRuntime:
         _events.set_task_context(spec.task_id.hex())
         t_exec0 = time.monotonic()
         try:
-            value = self._run_user_code(spec)
+            with _tracing.span(_tracing.WORKER_EXEC):
+                value = self._run_user_code(spec)
             exc = None
         except BaseException as e:  # noqa: BLE001
             value, exc = None, e
@@ -1167,7 +1146,8 @@ class WorkerRuntime:
             self._stream_results(spec, value, origin, exc=exc)
             self._executing.pop(tid_b, None)
             return
-        self._report_done(spec, value, exc, origin)
+        with _tracing.span(_tracing.WORKER_REPLY):
+            self._report_done(spec, value, exc, origin)
         self._executing.pop(tid_b, None)
         self._cancelled.discard(tid_b)
         # t_fork truthy too: a mid-execution toggle-on must not ship a
@@ -1258,6 +1238,11 @@ def main():
     # instant our hello registers, on the reader thread.
     task_queue: "queue.Queue" = queue.Queue()
     rt_holder: Dict[str, Any] = {}
+
+    # raylint: dispatch-only
+    def on_push(msg):
+        with _tracing.span(_tracing.WORKER_RECV):
+            push(msg)
 
     # raylint: dispatch-only
     def push(msg):
@@ -1458,6 +1443,10 @@ def main():
             holder = {}
 
             def on_direct(msg, h=holder):
+                with _tracing.span(_tracing.WORKER_RECV):
+                    _on_direct(msg, h)
+
+            def _on_direct(msg, h):
                 if type(msg) is tuple:
                     if msg[0] == OP_CALL:
                         r = rt_holder.get("rt")
@@ -1499,7 +1488,7 @@ def main():
         _prof.enable()
     client = CoreClient(
         address, authkey, role="worker", worker_id=worker_id,
-        push_handler=push, direct_addr=direct_addr,
+        push_handler=on_push, direct_addr=direct_addr,
     )
     if _prof is not None:
         import io

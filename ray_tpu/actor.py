@@ -16,12 +16,6 @@ from ._private.task_spec import TaskSpec
 from ._private.worker import global_client
 from .object_ref import ObjectRef
 
-def _maybe_trace(runtime_env, name):
-    from .util import tracing
-
-    return tracing.inject(runtime_env, name)
-
-
 _VALID_ACTOR_OPTIONS = {
     "num_cpus",
     "num_gpus",
@@ -249,9 +243,7 @@ class ActorClass:
             ),
             scheduling_strategy=_submit.normalize_strategy(strategy),
             runtime_env=_submit.prepare_runtime_env(
-                _maybe_trace(
-                    opts.get("runtime_env"), f"{self._cls.__name__}.__init__"
-                ),
+                opts.get("runtime_env"),
                 client,
             ),
         )

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel import with_logical_constraint
+from ..util import tracing
 from .llama import CONFIGS as LLAMA_CONFIGS
 from .llama import Attention, LlamaConfig, RMSNorm, causal_lm_loss  # noqa: F401
 
@@ -205,29 +206,30 @@ class MoELayer(nn.Module):
         E, K = cfg.num_experts, cfg.num_experts_per_tok
         C = max(1, int(cfg.capacity_factor * T * K / E))
 
-        router = nn.Dense(
-            E, use_bias=False, dtype=jnp.float32,
-            param_dtype=cfg.param_dtype, name="router",
-        )
-        logits = router(x.astype(jnp.float32))  # [B, T, E] — fp32 routing
-        probs = jax.nn.softmax(logits, axis=-1)
+        # The four scopes below are the layer's names in a profile
+        # (util/tracing.py); every dispatch branch uses the same four.
+        with tracing.scope(tracing.MOE_ROUTER):
+            router = nn.Dense(
+                E, use_bias=False, dtype=jnp.float32,
+                param_dtype=cfg.param_dtype, name="router",
+            )
+            logits = router(x.astype(jnp.float32))  # [B, T, E] — fp32 routing
+            probs = jax.nn.softmax(logits, axis=-1)
 
-        # Top-k gates, renormalized over the chosen experts.
-        gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B, T, K]
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9
-        )
+            # Top-k gates, renormalized over the chosen experts.
+            gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B, T, K]
+            gate_vals = gate_vals / jnp.maximum(
+                gate_vals.sum(-1, keepdims=True), 1e-9
+            )
 
-        # Aux load-balance loss (Switch Transformer eq. 4): mean gate
-        # fraction x mean dispatch fraction per expert.
-        onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [B,T,K,E]
-        expert_mask = onehot.sum(2)  # [B, T, E] (0/1 per expert)
-        frac_tokens = expert_mask.mean(axis=(0, 1))
-        frac_probs = probs.mean(axis=(0, 1))
-        aux = E * jnp.sum(frac_tokens * frac_probs)
-        self.sow("intermediates", "router_aux_loss", aux)
-
-        xd = x.astype(cfg.dtype)
+            # Aux load-balance loss (Switch Transformer eq. 4): mean gate
+            # fraction x mean dispatch fraction per expert.
+            onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [B,T,K,E]
+            expert_mask = onehot.sum(2)  # [B, T, E] (0/1 per expert)
+            frac_tokens = expert_mask.mean(axis=(0, 1))
+            frac_probs = probs.mean(axis=(0, 1))
+            aux = E * jnp.sum(frac_tokens * frac_probs)
+            self.sow("intermediates", "router_aux_loss", aux)
 
         def pvar(name, shape):
             return self.param(
@@ -246,78 +248,77 @@ class MoELayer(nn.Module):
             from ..ops.gmm import aligned_group_layout, gmm
 
             N = B * T * K
-            x2 = xd.reshape(B * T, D)
-            e_flat = gate_idx.reshape(N)
-            order, dst, tile_group, m_pad = aligned_group_layout(
-                e_flat, E, block_m=128
-            )
-            tok_of_pair = jnp.arange(N, dtype=jnp.int32) // K
-            tok_sorted = tok_of_pair[order]
-            # Row GATHER into the aligned layout (row scatters serialize
-            # on TPU; gathers vectorize — same trick as the capacity
-            # path). inv maps aligned slot -> sorted-pair index, with
-            # padding slots reading a zero row.
-            inv = (
-                jnp.full((m_pad,), N, jnp.int32)
-                .at[dst]
-                .set(jnp.arange(N, dtype=jnp.int32), unique_indices=True)
-            )
-            src_tok = jnp.concatenate(
-                [tok_sorted, jnp.full((1,), B * T, jnp.int32)]
-            )[inv]
-            x_pad = jnp.concatenate(
-                [x2, jnp.zeros((1, D), x2.dtype)], axis=0
-            )
-            lhs = x_pad[src_tok]  # [m_pad, D]
-            h = gmm(lhs, w_gate.astype(cfg.dtype), tile_group)
-            u = gmm(lhs, w_up.astype(cfg.dtype), tile_group)
-            act = nn.silu(h) * u
-            eo = gmm(act, w_down.astype(cfg.dtype), tile_group)
-            gates_sorted = gate_vals.astype(cfg.dtype).reshape(N)[order]
-            pair_out = eo[dst] * gates_sorted[:, None]
-            out2 = (
-                jnp.zeros((B * T, D), cfg.dtype)
-                .at[tok_sorted]
-                .add(pair_out)
-            )
-            out = out2.reshape(B, T, D)
-            return with_logical_constraint(out, ("batch", "seq", "embed"))
+            with tracing.scope(tracing.MOE_DISPATCH):
+                x2 = x.astype(cfg.dtype).reshape(B * T, D)
+                e_flat = gate_idx.reshape(N)
+                order, dst, tile_group, m_pad = aligned_group_layout(
+                    e_flat, E, block_m=128
+                )
+                tok_of_pair = jnp.arange(N, dtype=jnp.int32) // K
+                tok_sorted = tok_of_pair[order]
+                # Row GATHER into the aligned layout (row scatters
+                # serialize on TPU; gathers vectorize — same trick as the
+                # capacity path). inv maps aligned slot -> sorted-pair
+                # index, with padding slots reading a zero row.
+                inv = (
+                    jnp.full((m_pad,), N, jnp.int32)
+                    .at[dst]
+                    .set(jnp.arange(N, dtype=jnp.int32), unique_indices=True)
+                )
+                src_tok = jnp.concatenate(
+                    [tok_sorted, jnp.full((1,), B * T, jnp.int32)]
+                )[inv]
+                x_pad = jnp.concatenate(
+                    [x2, jnp.zeros((1, D), x2.dtype)], axis=0
+                )
+                lhs = x_pad[src_tok]  # [m_pad, D]
+            with tracing.scope(tracing.MOE_EXPERTS):
+                h = gmm(lhs, w_gate.astype(cfg.dtype), tile_group)
+                u = gmm(lhs, w_up.astype(cfg.dtype), tile_group)
+                act = nn.silu(h) * u
+                eo = gmm(act, w_down.astype(cfg.dtype), tile_group)
+            with tracing.scope(tracing.MOE_COMBINE):
+                gates_sorted = gate_vals.astype(cfg.dtype).reshape(N)[order]
+                pair_out = eo[dst] * gates_sorted[:, None]
+                out2 = (
+                    jnp.zeros((B * T, D), cfg.dtype)
+                    .at[tok_sorted]
+                    .add(pair_out)
+                )
+                out = out2.reshape(B, T, D)
+                return with_logical_constraint(out, ("batch", "seq", "embed"))
 
         if dispatch == "ragged":
             # Exact-group dispatch: argsort the (token, k) pairs by
             # expert and run each group through its expert with
             # lax.ragged_dot — FLOPs are exactly the active tokens'.
             N = B * T * K
-            x2 = xd.reshape(B * T, D)
-            e_flat = gate_idx.reshape(N)
-            order = jnp.argsort(e_flat)
-            tok_of_pair = jnp.arange(N, dtype=jnp.int32) // K
-            tok_sorted = tok_of_pair[order]
-            xs = x2[tok_sorted]  # [N, D] grouped by expert
-            group_sizes = jnp.bincount(e_flat, length=E).astype(jnp.int32)
-            h = jax.lax.ragged_dot(xs, w_gate.astype(cfg.dtype), group_sizes)
-            u = jax.lax.ragged_dot(xs, w_up.astype(cfg.dtype), group_sizes)
-            act = nn.silu(h) * u
-            eo = jax.lax.ragged_dot(
-                act, w_down.astype(cfg.dtype), group_sizes
-            )
-            gates_sorted = gate_vals.astype(cfg.dtype).reshape(N)[order]
-            out2 = (
-                jnp.zeros((B * T, D), cfg.dtype)
-                .at[tok_sorted]
-                .add(eo * gates_sorted[:, None])
-            )
-            out = out2.reshape(B, T, D)
-            return with_logical_constraint(out, ("batch", "seq", "embed"))
+            with tracing.scope(tracing.MOE_DISPATCH):
+                x2 = x.astype(cfg.dtype).reshape(B * T, D)
+                e_flat = gate_idx.reshape(N)
+                order = jnp.argsort(e_flat)
+                tok_of_pair = jnp.arange(N, dtype=jnp.int32) // K
+                tok_sorted = tok_of_pair[order]
+                xs = x2[tok_sorted]  # [N, D] grouped by expert
+                group_sizes = jnp.bincount(e_flat, length=E).astype(jnp.int32)
+            with tracing.scope(tracing.MOE_EXPERTS):
+                h = jax.lax.ragged_dot(xs, w_gate.astype(cfg.dtype), group_sizes)
+                u = jax.lax.ragged_dot(xs, w_up.astype(cfg.dtype), group_sizes)
+                act = nn.silu(h) * u
+                eo = jax.lax.ragged_dot(
+                    act, w_down.astype(cfg.dtype), group_sizes
+                )
+            with tracing.scope(tracing.MOE_COMBINE):
+                gates_sorted = gate_vals.astype(cfg.dtype).reshape(N)[order]
+                out2 = (
+                    jnp.zeros((B * T, D), cfg.dtype)
+                    .at[tok_sorted]
+                    .add(eo * gates_sorted[:, None])
+                )
+                out = out2.reshape(B, T, D)
+                return with_logical_constraint(out, ("batch", "seq", "embed"))
 
         NK = T * K
-
-        # Arrival-order position of each token within its expert's
-        # buffer: cumsum over T of the small [B,T,E] mask (E is tiny) —
-        # no sort, no [B,T,E,C] one-hot.
-        position = (
-            jnp.cumsum(expert_mask, axis=1) - expert_mask
-        )  # [B, T, E] tokens before me per expert
 
         def route_one(xrow, idx_row, pos_row):
             """One batch row: the first C arrivals per expert own its
@@ -349,29 +350,35 @@ class MoELayer(nn.Module):
             buf = x_pad[inv[: E * C]]  # [E*C, D] row gather
             return buf, jnp.minimum(slot, E * C)
 
-        buf, slot = jax.vmap(route_one)(
-            xd, gate_idx, position.astype(jnp.float32)
-        )
-        # [B, E*C, D] -> [E, B, C, D]; under GSPMD the expert axis is
-        # mesh-sharded (all-to-all over ICI).
-        expert_in = buf.reshape(B, E, C, D).transpose(1, 0, 2, 3)
-        expert_in = with_logical_constraint(
-            expert_in, ("expert", "batch", None, "embed")
-        )
+        with tracing.scope(tracing.MOE_DISPATCH):
+            # Arrival-order position of each token within its expert's
+            # buffer: cumsum over T of the small [B,T,E] mask (E is tiny) —
+            # no sort, no [B,T,E,C] one-hot.
+            position = (
+                jnp.cumsum(expert_mask, axis=1) - expert_mask
+            )  # [B, T, E] tokens before me per expert
+            buf, slot = jax.vmap(route_one)(
+                x.astype(cfg.dtype), gate_idx, position.astype(jnp.float32)
+            )
+            # [B, E*C, D] -> [E, B, C, D]; under GSPMD the expert axis is
+            # mesh-sharded (all-to-all over ICI).
+            expert_in = buf.reshape(B, E, C, D).transpose(1, 0, 2, 3)
+            expert_in = with_logical_constraint(
+                expert_in, ("expert", "batch", None, "embed")
+            )
 
         # Stacked expert FFN (SwiGLU like the dense path). E-major
         # weights (created above); parallel.mesh.spec_for_param shards
         # them P("expert", "fsdp"/"tensor", ...) by name.
-        h = jnp.einsum("ebcd,edf->ebcf", expert_in, w_gate.astype(cfg.dtype))
-        u = jnp.einsum("ebcd,edf->ebcf", expert_in, w_up.astype(cfg.dtype))
-        act = nn.silu(h) * u
-        expert_out = jnp.einsum("ebcf,efd->ebcd", act, w_down.astype(cfg.dtype))
-
-        # Combine back to token order, weighted by gates: gather each
-        # pair's expert output (dropped pairs read the zero dump row),
-        # scale, and reduce the K pairs of every token — pair order is
-        # token-major, so the reduction is a reshape-sum, no scatter.
-        expert_out = expert_out.transpose(1, 0, 2, 3).reshape(B, E * C, D)
+        with tracing.scope(tracing.MOE_EXPERTS):
+            h = jnp.einsum(
+                "ebcd,edf->ebcf", expert_in, w_gate.astype(cfg.dtype)
+            )
+            u = jnp.einsum("ebcd,edf->ebcf", expert_in, w_up.astype(cfg.dtype))
+            act = nn.silu(h) * u
+            expert_out = jnp.einsum(
+                "ebcf,efd->ebcd", act, w_down.astype(cfg.dtype)
+            )
 
         def combine_one(eo_row, slot_row, gate_row):
             eo_row = jnp.concatenate(
@@ -380,10 +387,16 @@ class MoELayer(nn.Module):
             pair_out = eo_row[slot_row] * gate_row[:, None]
             return pair_out.reshape(T, K, D).sum(1)
 
-        out = jax.vmap(combine_one)(
-            expert_out, slot, gate_vals.astype(cfg.dtype).reshape(B, NK)
-        )
-        return with_logical_constraint(out, ("batch", "seq", "embed"))
+        # Combine back to token order, weighted by gates: gather each
+        # pair's expert output (dropped pairs read the zero dump row),
+        # scale, and reduce the K pairs of every token — pair order is
+        # token-major, so the reduction is a reshape-sum, no scatter.
+        with tracing.scope(tracing.MOE_COMBINE):
+            expert_out = expert_out.transpose(1, 0, 2, 3).reshape(B, E * C, D)
+            out = jax.vmap(combine_one)(
+                expert_out, slot, gate_vals.astype(cfg.dtype).reshape(B, NK)
+            )
+            return with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
 class MoEDecoderLayer(nn.Module):

@@ -27,14 +27,6 @@ _VALID_OPTIONS = {
 }
 
 
-def _maybe_trace(runtime_env, task_name):
-    """Inject span context when RAY_TPU_TRACE=1 (reference:
-    tracing_helper.py _tracing_task_invocation)."""
-    from .util import tracing
-
-    return tracing.inject(runtime_env, task_name)
-
-
 class RemoteFunction:
     def __init__(self, fn, **default_options):
         bad = set(default_options) - _VALID_OPTIONS
@@ -112,43 +104,40 @@ class RemoteFunction:
                 borrowed=borrowed,
             )
         if self._simple:
-            from .util import tracing
-
-            if not tracing.enabled():
-                spec = TaskSpec.__new__(TaskSpec)
-                # Syscall-free id on the steady-state path; return
-                # object ids derive from bytes [:12] which stay unique
-                # (see ids.fast_unique_bytes).
-                spec.task_id = TaskID(fast_unique_bytes())
-                spec.name = self._fn.__name__
-                spec.function_id = self._function_id
-                spec.function_blob = client.register_function_once(
-                    self._function_id, self._blob
-                )
-                spec.args_blob = args_blob
-                spec.dependencies = deps
-                spec.borrowed_refs = borrowed
-                spec.num_returns = num_returns
-                spec.resources = self._resources
-                spec.actor_creation = False
-                spec.actor_id = None
-                spec.method_name = ""
-                spec.max_restarts = 0
-                spec.max_retries = self._max_retries
-                spec.retry_exceptions = self._retry_exceptions
-                spec.max_concurrency = 1
-                spec.placement_group_id = None
-                spec.placement_group_bundle_index = -1
-                spec.scheduling_strategy = None
-                spec.actor_name = None
-                spec.lifetime = None
-                spec.runtime_env = None
-                spec.concurrency_groups = None
-                spec.concurrency_group = None
-                refs = client.submit_task_leased(spec)
-                if refs is None:
-                    refs = client.submit(spec)
-                return refs[0] if num_returns == 1 else refs
+            spec = TaskSpec.__new__(TaskSpec)
+            # Syscall-free id on the steady-state path; return
+            # object ids derive from bytes [:12] which stay unique
+            # (see ids.fast_unique_bytes).
+            spec.task_id = TaskID(fast_unique_bytes())
+            spec.name = self._fn.__name__
+            spec.function_id = self._function_id
+            spec.function_blob = client.register_function_once(
+                self._function_id, self._blob
+            )
+            spec.args_blob = args_blob
+            spec.dependencies = deps
+            spec.borrowed_refs = borrowed
+            spec.num_returns = num_returns
+            spec.resources = self._resources
+            spec.actor_creation = False
+            spec.actor_id = None
+            spec.method_name = ""
+            spec.max_restarts = 0
+            spec.max_retries = self._max_retries
+            spec.retry_exceptions = self._retry_exceptions
+            spec.max_concurrency = 1
+            spec.placement_group_id = None
+            spec.placement_group_bundle_index = -1
+            spec.scheduling_strategy = None
+            spec.actor_name = None
+            spec.lifetime = None
+            spec.runtime_env = None
+            spec.concurrency_groups = None
+            spec.concurrency_group = None
+            refs = client.submit_task_leased(spec)
+            if refs is None:
+                refs = client.submit(spec)
+            return refs[0] if num_returns == 1 else refs
         pg = opts.get("placement_group")
         pg_id: Optional[PlacementGroupID] = None
         bundle_index = opts.get("placement_group_bundle_index", -1)
@@ -180,8 +169,7 @@ class RemoteFunction:
             ),
             scheduling_strategy=_submit.normalize_strategy(strategy),
             runtime_env=_submit.prepare_runtime_env(
-                _maybe_trace(opts.get("runtime_env"),
-                             opts.get("name") or self._fn.__name__),
+                opts.get("runtime_env"),
                 client,
             ),
         )

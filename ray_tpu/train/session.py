@@ -12,6 +12,8 @@ import threading
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
+from ..util import tracing
+
 _session_lock = threading.Lock()
 _session: Optional["TrainSession"] = None
 
@@ -34,7 +36,8 @@ class TrainSession:
         self.error: Optional[BaseException] = None
 
     def report(self, metrics: Dict[str, Any], checkpoint=None):
-        self.result_queue.put(("report", metrics, checkpoint))
+        with tracing.span(tracing.TRAIN_REPORT):
+            self.result_queue.put(("report", metrics, checkpoint))
 
     def finish(self, error: Optional[BaseException] = None):
         self.error = error
@@ -42,7 +45,8 @@ class TrainSession:
         self.result_queue.put(("done", None, None))
 
     def next_result(self, timeout: Optional[float] = None):
-        return self.result_queue.get(timeout=timeout)
+        with tracing.span(tracing.TRAIN_RESULT_WAIT):
+            return self.result_queue.get(timeout=timeout)
 
 
 def init_session(context: TrainContext) -> TrainSession:

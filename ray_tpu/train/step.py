@@ -17,10 +17,14 @@ def make_train_step(loss_fn: Callable[..., Any], tx) -> Callable[..., Any]:
     import jax
     import optax
 
+    from ..util import tracing
+
     @partial(jax.jit, donate_argnums=(0, 1))
     def train_step(params, opt_state, *batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
+        with tracing.scope(tracing.OPTIMIZER):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, loss
 
     return train_step

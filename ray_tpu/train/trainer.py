@@ -27,6 +27,7 @@ from typing import Any, Callable, Dict, Optional
 import ray_tpu
 from ..exceptions import RayActorError
 from ..util.placement_group import placement_group, remove_placement_group
+from ..util import tracing
 from ..util.scheduling_strategies import PlacementGroupSchedulingStrategy
 from .checkpoint import Checkpoint
 from .config import Result, RunConfig, ScalingConfig
@@ -126,15 +127,16 @@ class TrainWorker:
         return True
 
     def next_result(self):
-        kind, metrics, checkpoint = self.session.next_result()
-        if kind == "done":
-            err = self.session.error
-            if err is not None:
-                raise err if isinstance(err, Exception) else RuntimeError(str(err))
-            return ("done", None, None)
-        # Checkpoints are directories on shared storage; ship the path.
-        ckpt_path = checkpoint.path if isinstance(checkpoint, Checkpoint) else checkpoint
-        return (kind, metrics, ckpt_path)
+        with tracing.span(tracing.TRAIN_NEXT_RESULT):
+            kind, metrics, checkpoint = self.session.next_result()
+            if kind == "done":
+                err = self.session.error
+                if err is not None:
+                    raise err if isinstance(err, Exception) else RuntimeError(str(err))
+                return ("done", None, None)
+            # Checkpoints are directories on shared storage; ship the path.
+            ckpt_path = checkpoint.path if isinstance(checkpoint, Checkpoint) else checkpoint
+            return (kind, metrics, ckpt_path)
 
     def ping(self):
         return self.rank

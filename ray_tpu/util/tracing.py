@@ -1,118 +1,59 @@
-"""Tracing: span context propagated through task submission.
+"""Program spans on the profiler's clock: the one place that names them.
 
-Reference: util/tracing/tracing_helper.py:36-82 — OpenTelemetry spans
-injected around _remote calls with context carried in the TaskSpec.
-Zero-dependency equivalent: when RAY_TPU_TRACE=1, submissions stamp a
-(trace_id, parent span) into the runtime_env env_vars and executions
-record spans; spans export through the GCS KV and assemble into one
-chrome-trace / parent-child tree with ``get_trace`` or
-``ray_tpu timeline`` (task events already cover execution timing —
-this adds cross-task causality).
+Two things and a list. ``span(name)`` is a host span: a
+``jax.profiler.TraceAnnotation`` when ``jax`` is already in ``sys.modules``
+and nothing otherwise. It never imports jax, so the head, a raylet and a
+driver that opens no backend pay a dictionary lookup and nothing else; in
+the process that holds the chip a span costs under a microsecond while no
+profiler session is open and is recorded, on the device's clock and under
+the thread it ran on, while one is (``jax.profiler.start_trace``).
+``scope(name)`` is ``jax.named_scope``: trace-time metadata that reaches
+every device operation's ``op_name``, at no run-time cost.
+
+There is no switch: a profiler session is "on". A span goes around work,
+never around a blocking wait, except the one that is named as a wait.
+The control plane's own always-on record is the flight recorder
+(``_private/events.py``, ``ray_tpu timeline``), on the host's wall clock.
 """
 from __future__ import annotations
 
-import json
-import os
-import time
-import uuid
-from typing import Any, Dict, List, Optional
+import contextlib
+import sys
 
-_NS = "__traces__"
-_TRACE_ENV = "RAY_TPU_TRACE_CTX"
+# Host spans, in the process that holds the chip. Every name starts with
+# ``ray_tpu.`` (the benchmark's own spans start with ``bench.``).
+TRAIN_REPORT = "ray_tpu.train.report"  # TrainSession.report, the loop's thread
+TRAIN_NEXT_RESULT = "ray_tpu.train.next_result"  # TrainWorker.next_result, whole call
+TRAIN_RESULT_WAIT = "ray_tpu.train.result_wait"  # the blocking queue.get in it: a wait
+WORKER_EXEC = "ray_tpu.worker.exec"  # one task or actor method, arguments to value
+WORKER_REPLY = "ray_tpu.worker.reply"  # results packed, reply and task_done handed over
+WORKER_RECV = "ray_tpu.worker.recv"  # one received frame dispatched, not the socket wait
+HOST_SPANS = (TRAIN_REPORT, TRAIN_NEXT_RESULT, TRAIN_RESULT_WAIT,
+              WORKER_EXEC, WORKER_REPLY, WORKER_RECV)
 
+# In-graph scopes. Forward and backward are already told apart by JAX's
+# ``jvp(`` / ``transpose(`` and a remat replay by ``rematted_computation``.
+OPTIMIZER = "optimizer"  # tx.update and apply_updates in make_train_step
+MOE_ROUTER = "router"  # logits, softmax, top-k, renormalisation, aux loss
+MOE_DISPATCH = "dispatch"  # positions, slot map, gather into the expert buffer
+MOE_EXPERTS = "experts"  # the three expert matmuls and the activation
+MOE_COMBINE = "combine"  # gather back, gate scaling, the reduction over k
+SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
 
-def enabled() -> bool:
-    return os.environ.get("RAY_TPU_TRACE", "0") == "1"
-
-
-def current_context() -> Optional[Dict[str, str]]:
-    blob = os.environ.get(_TRACE_ENV)
-    return json.loads(blob) if blob else None
-
-
-def new_context(name: str) -> Dict[str, str]:
-    parent = current_context()
-    return {
-        "trace_id": parent["trace_id"] if parent else uuid.uuid4().hex[:16],
-        "span_id": uuid.uuid4().hex[:8],
-        "parent_span_id": parent["span_id"] if parent else "",
-        "name": name,
-    }
+_OFF = contextlib.nullcontext()
 
 
-def inject(runtime_env: Optional[Dict[str, Any]], task_name: str):
-    """Called at submission: thread the span context into the task's
-    env so the worker's execution becomes a child span."""
-    if not enabled():
-        return runtime_env
-    ctx = new_context(task_name)
-    runtime_env = dict(runtime_env or {})
-    env_vars = dict(runtime_env.get("env_vars") or {})
-    env_vars[_TRACE_ENV] = json.dumps(ctx)
-    env_vars["RAY_TPU_TRACE"] = "1"
-    runtime_env["env_vars"] = env_vars
-    return runtime_env
+def span(name: str):
+    """A host span around the ``with`` body; nothing where jax is not loaded."""
+    # getattr: another thread may be half way through importing jax.
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return _OFF
+    return profiler.TraceAnnotation(name)
 
 
-def record_span(name: str, start: float, end: float,
-                ctx: Optional[Dict[str, str]] = None) -> None:
-    if not enabled():
-        return
-    from .._private.worker import global_client, is_initialized
+def scope(name: str):
+    """``jax.named_scope(name)``: called from code that is being traced."""
+    import jax
 
-    if not is_initialized():
-        return
-    ctx = ctx or current_context() or new_context(name)
-    span = {
-        "name": name,
-        "trace_id": ctx["trace_id"],
-        "span_id": ctx["span_id"],
-        "parent_span_id": ctx.get("parent_span_id", ""),
-        "start": start,
-        "end": end,
-        "pid": os.getpid(),
-    }
-    global_client().kv_put(
-        f"{ctx['trace_id']}:{ctx['span_id']}".encode(),
-        json.dumps(span).encode(),
-        ns=_NS,
-    )
-
-
-class span:
-    """Context manager for user code: ``with tracing.span("step"): ...``"""
-
-    def __init__(self, name: str):
-        self.name = name
-        self._ctx = None
-        self._start = 0.0
-        self._saved = None
-
-    def __enter__(self):
-        self._ctx = new_context(self.name)
-        self._start = time.time()
-        self._saved = os.environ.get(_TRACE_ENV)
-        os.environ[_TRACE_ENV] = json.dumps(self._ctx)
-        return self
-
-    def __exit__(self, *exc):
-        record_span(self.name, self._start, time.time(), self._ctx)
-        if self._saved is None:
-            os.environ.pop(_TRACE_ENV, None)
-        else:
-            os.environ[_TRACE_ENV] = self._saved
-        return False
-
-
-def get_trace(trace_id: Optional[str] = None) -> List[Dict[str, Any]]:
-    """All spans (optionally one trace), sorted by start time."""
-    from .._private.worker import global_client
-
-    client = global_client()
-    spans = []
-    prefix = f"{trace_id}:".encode() if trace_id else b""
-    for key in client.kv_keys(prefix, ns=_NS):
-        blob = client.kv_get(key, ns=_NS)
-        if blob:
-            spans.append(json.loads(blob))
-    return sorted(spans, key=lambda s: s["start"])
+    return jax.named_scope(name)

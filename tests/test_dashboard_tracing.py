@@ -1,6 +1,6 @@
-"""Dashboard HTTP API + tracing spans."""
+"""Dashboard HTTP API (the profiler spans of util/tracing.py are in
+test_program_spans.py)."""
 import json
-import time
 import urllib.error
 import urllib.request
 
@@ -54,27 +54,3 @@ def test_dashboard_serves_state(cluster):
         assert any(t["name"] == "f" for t in tasks)
     with urllib.request.urlopen(f"{url}/api/nodes") as r:
         assert len(json.loads(r.read())) >= 1
-
-
-def test_tracing_spans_parent_child(cluster, monkeypatch):
-    monkeypatch.setenv("RAY_TPU_TRACE", "1")
-    from ray_tpu.util import tracing
-
-    @ray_tpu.remote
-    def child_task(x):
-        time.sleep(0.02)
-        return x * 2
-
-    with tracing.span("driver_block"):
-        ref = child_task.remote(21)
-        assert ray_tpu.get(ref) == 42
-
-    spans = tracing.get_trace()
-    names = {s["name"] for s in spans}
-    assert "driver_block" in names and "child_task" in names
-    driver = next(s for s in spans if s["name"] == "driver_block")
-    child = next(s for s in spans if s["name"] == "child_task")
-    # Same trace; the task span is a child of the driver span.
-    assert child["trace_id"] == driver["trace_id"]
-    assert child["parent_span_id"] == driver["span_id"]
-    assert child["end"] - child["start"] >= 0.015
