@@ -1,0 +1,50 @@
+"""The least time the chip could take for the grouped-matmul calls it
+executed, over the time they took on device 0.
+
+Each call is counted at what the mathematics needs (benchmarks/lib/
+flops_gmm.py gmm_call): the rows that hold a (token, expert) pair, batch x
+seq x experts per token of them, not the rows that pad an expert's segment
+to whole tiles; the two row arrays and every expert's matrix moved once.
+Every call of a layer has the hidden size and one expert's width as its two
+matrix dimensions, in either order, so all count alike; its floor is the
+larger of FLOPs over the bf16 peak and bytes over the HBM peak. A remat
+replay the compiler keeps is an executed call and counts. One device holds
+every expert (the gmm dispatch is per device)."""
+from benchmarks.lib import trace as tracing
+from benchmarks.lib.flops_gmm import GMM_KERNELS, gmm_call
+from benchmarks.lib.peaks import peaks_for
+
+
+def read(run):
+    found = tracing.traced_device(run)
+    if found is None:
+        return None
+    trace, device, (lo, hi) = found
+    calls = [(e, tracing.kernel_of(e)) for e in trace.devices[device]
+             if e.start >= lo and e.end <= hi]
+    calls = [(e, k) for e, k in calls if k in GMM_KERNELS]
+    if not calls:
+        return None
+    config, traffic = run["cell"]["config"], run["cell"]["traffic"]
+    peaks = peaks_for(run["setup"]["device_kind"])
+    pairs = traffic["batch"] * traffic["seq"] * config["num_experts_per_tok"]
+    floors = {}  # kernel -> (seconds by FLOPs, seconds by bytes) of one call
+    for kernel in GMM_KERNELS:
+        flops, nbytes = gmm_call(
+            kernel, pairs, config["hidden_size"], config["intermediate_size"],
+            config["num_experts"],
+        )
+        floors[kernel] = (flops / peaks["bf16_flops_per_s"],
+                          nbytes / peaks["hbm_bytes_per_s"])
+    floor = sum(max(floors[k]) for _, k in calls)
+    compute_bound = sum(floors[k][0] >= floors[k][1] for _, k in calls)
+    seconds = {k: sum(e.dur for e, kernel in calls if kernel == k)
+               for k in GMM_KERNELS}
+    total = sum(seconds.values())
+    run["notes"].append(
+        f"kernel.gmm_roofline: {len(calls)} calls, {compute_bound} of them "
+        f"bound by compute, the rest by bytes; floor {floor:.4f} s of "
+        f"{total:.4f} s ("
+        + ", ".join(f"{k} {s:.4f} s" for k, s in seconds.items()) + ")"
+    )
+    return 100.0 * floor / total

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from ..ops.attention import flash_attention
 from ..ops.ring_attention import ring_self_attention
 from ..parallel.mesh import with_logical_constraint
+from ..util import tracing
 
 
 @dataclass(frozen=True)
@@ -44,16 +45,25 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
     tie_embeddings: bool = False
+    # OLMoE's attention: RMSNorm over the whole q and the whole k
+    # projection (all heads at once), before the head split and rope. A
+    # fact of the architecture, read from its modelling code.
+    qk_norm: bool = False
+    # The architecture's own draw (its config's initializer_range, 0.02
+    # for OLMoE): every weight matrix and the embedding normal(0, this),
+    # one matrix at a time. None keeps flax's draws (weight_init).
+    initializer_range: Optional[float] = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     # Sequence parallelism: run attention as a ring over the mesh `seq`
     # axis (requires an ambient mesh passed to __call__ via module attr).
     remat: bool = True
-    # "nothing": full per-layer recompute in backward (minimum memory,
-    # pays an extra forward — the right trade at 1B+ params on 16 GiB).
-    # "dots": save matmul outputs, recompute only elementwise — the
-    # right trade for smaller models (e.g. sparse-MoE) where the extra
-    # forward caps MFU at 0.75 of peak but activations fit.
+    # "nothing": save only a layer's input (minimum memory). With
+    # prevent_cse=False XLA merges the replay with its forward twin:
+    # step.remat_share reads 0.00-2.44% of busy time in the dense cells
+    # and the OLMoE cell, 10.53% in the Mixtral cell (PERF.md §5).
+    # "dots": save matmul outputs, recompute only elementwise — moves
+    # memory, not time, where nothing is replayed.
     remat_policy: str = "nothing"
 
     @property
@@ -66,6 +76,8 @@ class LlamaConfig:
         attn = h * (self.num_heads * hd) * 2 + h * (self.num_kv_heads * hd) * 2
         mlp = 3 * h * i
         per_layer = attn + mlp + 2 * h
+        if self.qk_norm:
+            per_layer += (self.num_heads + self.num_kv_heads) * hd
         emb = v * h * (1 if self.tie_embeddings else 2)
         return l * per_layer + emb + h
 
@@ -96,6 +108,14 @@ def remat_policy(cfg: LlamaConfig):
     if cfg.remat_policy == "dots":
         return jax.checkpoint_policies.checkpoint_dots
     return jax.checkpoint_policies.nothing_saveable
+
+
+def weight_init(cfg: LlamaConfig, default=nn.initializers.lecun_normal()):
+    """The initializer of a weight: normal(0, cfg.initializer_range) where
+    the architecture names one, else `default` (flax's own for the layer)."""
+    if cfg.initializer_range is None:
+        return default
+    return nn.initializers.normal(cfg.initializer_range)
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -131,11 +151,18 @@ class Attention(nn.Module):
         hd = cfg.head_dim_
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name=name,
+            param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+            name=name,
         )
         q = dense((cfg.num_heads, hd), "q_proj")(x)
         k = dense((cfg.num_kv_heads, hd), "k_proj")(x)
         v = dense((cfg.num_kv_heads, hd), "v_proj")(x)
+        if cfg.qk_norm:
+            with tracing.scope(tracing.QK_NORM):
+                norm = lambda t, name: RMSNorm(  # noqa: E731
+                    cfg.rms_eps, cfg.param_dtype, name=name
+                )(t.reshape(*t.shape[:2], -1)).reshape(t.shape)
+                q, k = norm(q, "q_norm"), norm(k, "k_norm")
         # [B, T, H, D] -> [B, H, T, D]
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         q = _rope(q, positions, cfg.rope_theta)
@@ -150,7 +177,8 @@ class Attention(nn.Module):
         o = o.transpose(0, 2, 1, 3)  # [B, T, H, D]
         out = nn.DenseGeneral(
             cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name="o_proj",
+            param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+            name="o_proj",
         )(o)
         return out
 
@@ -161,14 +189,16 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        gate = nn.Dense(cfg.intermediate_size, use_bias=False, dtype=cfg.dtype,
-                        param_dtype=cfg.param_dtype, name="gate_proj")(x)
-        up = nn.Dense(cfg.intermediate_size, use_bias=False, dtype=cfg.dtype,
-                      param_dtype=cfg.param_dtype, name="up_proj")(x)
+        dense = lambda feats, name: nn.Dense(  # noqa: E731
+            feats, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+            name=name,
+        )
+        gate = dense(cfg.intermediate_size, "gate_proj")(x)
+        up = dense(cfg.intermediate_size, "up_proj")(x)
         h = nn.silu(gate) * up
         h = with_logical_constraint(h, ("batch", "seq", "mlp"))
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
-                        param_dtype=cfg.param_dtype, name="down_proj")(h)
+        return dense(cfg.hidden_size, "down_proj")(h)
 
 
 class DecoderLayer(nn.Module):
@@ -206,6 +236,7 @@ class LlamaForCausalLM(nn.Module):
         emb = nn.Embed(
             cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="embed_tokens",
+            embedding_init=weight_init(cfg, nn.linear.default_embed_init),
         )
         if self.mesh is not None and self.mesh.size > 1:
             # One-hot matmul lookup: with the table sharded
@@ -237,7 +268,8 @@ class LlamaForCausalLM(nn.Module):
         else:
             logits = nn.Dense(
                 cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-                param_dtype=cfg.param_dtype, name="lm_head",
+                param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+                name="lm_head",
             )(x)
         return logits
 

@@ -25,15 +25,24 @@ from ..parallel import (
 )
 from ..util import tracing
 from .llama import CONFIGS as LLAMA_CONFIGS
-from .llama import Attention, LlamaConfig, RMSNorm, causal_lm_loss  # noqa: F401
+from .llama import (  # noqa: F401
+    Attention, LlamaConfig, RMSNorm, causal_lm_loss, weight_init,
+)
 
 
 @dataclass(frozen=True)
 class MixtralConfig(LlamaConfig):
     num_experts: int = 8
     num_experts_per_tok: int = 2  # top-k routing
-    # Sparse models are small enough to save matmul outputs in remat:
-    # full recompute would cap MFU at 0.75 of peak for no memory win.
+    # True (Mixtral): the top-k probabilities are renormalised to sum to
+    # one. False (OLMoE's norm_topk_prob): they are the gates as they are.
+    norm_topk_prob: bool = True
+    # Saves matmul outputs where they fit. Both MoE cells of the
+    # benchmark set "nothing" instead (their expert buffers do not fit).
+    # That costs less than a second forward: XLA merges the replay with
+    # its forward twin, and step.remat_share reads 0.00% of busy time
+    # through "gmm" at 64 experts and 10.53% through the tiled
+    # "capacity" FFN (PERF.md §5).
     remat_policy: str = "dots"
     # Per-expert token capacity = capacity_factor * T * k / E
     # (capacity dispatch only). E / k (4.0 for 8 experts, top-2) is the
@@ -50,10 +59,11 @@ class MixtralConfig(LlamaConfig):
     # default factor the buffers are 80% full and it is one einsum over
     # all of them: 25% padding FLOPs.
     # "gmm": tile-aligned group-sorted dispatch through the pallas
-    # grouped matmul (ops/gmm.py) — <=E*block_m rows of padding (~6%)
-    # and zero drops; single-device per expert shard (the EP path
-    # stays capacity). "ragged": exact-group lax.ragged_dot — the
-    # semantic oracle; measured slower than both on current backends.
+    # grouped matmul (ops/gmm.py) — E*block_m rows of padding (8,192 on
+    # 65,536 pairs at 64 experts top-8, b2 x s4096) and zero drops;
+    # single-device per expert shard (the EP path stays capacity).
+    # "ragged": exact-group lax.ragged_dot — the semantic oracle;
+    # measured slower than both on current backends.
     moe_dispatch: str = "auto"
 
     def num_params(self) -> int:
@@ -401,8 +411,8 @@ class MoELayer(nn.Module):
     and what it resolves to on an expert-sharded mesh.
 
     "gmm": (token, k) pairs sorted by expert into 128-row tiles for the
-    pallas grouped matmul (ops/gmm.py): ~6% padding, zero drops, one
-    device per expert shard.
+    pallas grouped matmul (ops/gmm.py): at most E tiles of padding, zero
+    drops, one device per expert shard.
 
     "ragged" (opt-in): (token, k) pairs argsorted by expert feed
     `lax.ragged_dot` with exact group sizes — zero capacity padding and
@@ -437,16 +447,19 @@ class MoELayer(nn.Module):
         with tracing.scope(tracing.MOE_ROUTER):
             router = nn.Dense(
                 E, use_bias=False, dtype=jnp.float32,
-                param_dtype=cfg.param_dtype, name="router",
+                param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+                name="router",
             )
             logits = router(x.astype(jnp.float32))  # [B, T, E] — fp32 routing
             probs = jax.nn.softmax(logits, axis=-1)
 
-            # Top-k gates, renormalized over the chosen experts.
+            # Top-k gates, renormalized over the chosen experts where
+            # the architecture does (cfg.norm_topk_prob).
             gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B, T, K]
-            gate_vals = gate_vals / jnp.maximum(
-                gate_vals.sum(-1, keepdims=True), 1e-9
-            )
+            if cfg.norm_topk_prob:
+                gate_vals = gate_vals / jnp.maximum(
+                    gate_vals.sum(-1, keepdims=True), 1e-9
+                )
 
             # Aux load-balance loss (Switch Transformer eq. 4): mean gate
             # fraction x mean dispatch fraction per expert.
@@ -458,9 +471,12 @@ class MoELayer(nn.Module):
             self.sow("intermediates", "router_aux_loss", aux)
 
         def pvar(name, shape):
-            return self.param(
-                name, nn.initializers.lecun_normal(), shape, cfg.param_dtype
-            )
+            # flax's lecun_normal reads the stacked [E, in, out] as one
+            # matrix with a fan-in of E x in: without an
+            # initializer_range each expert starts sqrt(E) small (the
+            # Mixtral cell's draw, which its reference's TOLERANCE was
+            # measured on; PERF.md §7).
+            return self.param(name, weight_init(cfg), shape, cfg.param_dtype)
 
         w_gate = pvar("w_gate", (E, D, cfg.intermediate_size))
         w_up = pvar("w_up", (E, D, cfg.intermediate_size))
@@ -469,31 +485,35 @@ class MoELayer(nn.Module):
         if dispatch == "gmm":
             # Tile-aligned group-sorted dispatch through the pallas
             # grouped matmul: every block_m row-tile belongs to one
-            # expert, so the FFN runs as dense MXU tiles with ~6%
-            # padding — and zero drops.
+            # expert, so the FFN runs as dense MXU tiles with at most E
+            # tiles of padding — and zero drops.
             from ..ops.gmm import aligned_group_layout, gmm
 
             N = B * T * K
             with tracing.scope(tracing.MOE_DISPATCH):
                 x2 = x.astype(cfg.dtype).reshape(B * T, D)
-                e_flat = gate_idx.reshape(N)
-                order, dst, tile_group, m_pad = aligned_group_layout(
-                    e_flat, E, block_m=128
-                )
-                tok_of_pair = jnp.arange(N, dtype=jnp.int32) // K
-                tok_sorted = tok_of_pair[order]
+                # The index work, under a scope of its own so that a
+                # profile tells it from the row gather below.
+                with tracing.scope(tracing.MOE_LAYOUT):
+                    e_flat = gate_idx.reshape(N)
+                    order, dst, tile_group, m_pad = aligned_group_layout(
+                        e_flat, E, block_m=128
+                    )
+                    tok_of_pair = jnp.arange(N, dtype=jnp.int32) // K
+                    tok_sorted = tok_of_pair[order]
+                    # inv maps aligned slot -> sorted-pair index, with
+                    # padding slots reading a zero row.
+                    inv = (
+                        jnp.full((m_pad,), N, jnp.int32)
+                        .at[dst]
+                        .set(jnp.arange(N, dtype=jnp.int32), unique_indices=True)
+                    )
+                    src_tok = jnp.concatenate(
+                        [tok_sorted, jnp.full((1,), B * T, jnp.int32)]
+                    )[inv]
                 # Row GATHER into the aligned layout (row scatters
                 # serialize on TPU; gathers vectorize — same trick as the
-                # capacity path). inv maps aligned slot -> sorted-pair
-                # index, with padding slots reading a zero row.
-                inv = (
-                    jnp.full((m_pad,), N, jnp.int32)
-                    .at[dst]
-                    .set(jnp.arange(N, dtype=jnp.int32), unique_indices=True)
-                )
-                src_tok = jnp.concatenate(
-                    [tok_sorted, jnp.full((1,), B * T, jnp.int32)]
-                )[inv]
+                # capacity path).
                 x_pad = jnp.concatenate(
                     [x2, jnp.zeros((1, D), x2.dtype)], axis=0
                 )
@@ -649,6 +669,7 @@ class MixtralForCausalLM(nn.Module):
         emb = nn.Embed(
             cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="embed_tokens",
+            embedding_init=weight_init(cfg, nn.linear.default_embed_init),
         )
         x = emb(input_ids)
         x = with_logical_constraint(x, ("batch", "seq", "embed"))
@@ -663,8 +684,13 @@ class MixtralForCausalLM(nn.Module):
         for i in range(cfg.num_layers):
             x = layer_cls(cfg, mesh=self.mesh, name=f"layers_{i}")(x, positions)
         x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="final_norm")(x)
-        logits = emb.attend(x.astype(cfg.param_dtype))
-        return logits
+        if cfg.tie_embeddings:
+            return emb.attend(x.astype(cfg.param_dtype))
+        return nn.Dense(
+            cfg.vocab_size, use_bias=False, dtype=jnp.float32,
+            param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+            name="lm_head",
+        )(x)
 
 
 def moe_lm_loss(model: MixtralForCausalLM, params, input_ids, targets,

@@ -10,8 +10,11 @@ expert's segment padded up to a `block_m` boundary ("tile-aligned
 groups"), so each m-tile belongs to exactly ONE expert. That turns the
 ragged problem into a dense batched matmul with a scalar-prefetched
 expert index per tile — no masking, no ragged loops, full MXU tiles.
-Worst-case padding is E*block_m rows (~6% at mixtral-small shapes) vs
-the capacity path's 25% (capacity_factor 1.25), and zero token drops.
+The padded layout is static: N + E*block_m rows whatever the routing
+(73,728 for 65,536 pairs at 64 experts top-8, b2 x s4096: 12.5% more
+tiles than pairs fill), and zero token drops; the capacity path needs
+factor E/k to drop none (8.0 there: 193.7 against 68.0 ms a layer
+forward and backward on a v5e, PERF.md §6, PR 26).
 
 Backward: dlhs reuses the same kernel with per-expert-transposed rhs;
 drhs is a group-accumulating transposed gmm (`_tgmm`) that keeps the
@@ -157,16 +160,22 @@ def aligned_group_layout(e_flat, num_groups: int, block_m: int = 128):
     """Tile-aligned destinations for group-sorted dispatch.
 
     e_flat [N] int32: group id of each row. Returns
-    (dst [N], tile_group [Gm], m_pad) where dst is each sorted row's
-    slot in the padded layout (expert segments start on block_m
-    boundaries), tile_group maps every m-tile to its group, and m_pad
-    is the static padded row count. Rows must be scattered in sorted
-    order (argsort by e_flat) for dst to be contiguous per group.
+    (order [N], dst [N], tile_group [Gm], m_pad) where dst is each
+    sorted row's slot in the padded layout (expert segments start on
+    block_m boundaries), tile_group maps every m-tile to its group, and
+    m_pad is the static padded row count. Rows must be scattered in
+    sorted order (argsort by e_flat) for dst to be contiguous per group.
+
+    A group with no row keeps one tile, of padding: `_tgmm` writes a
+    group's block of the weight gradient when it leaves the group's
+    tiles, and a group it never visits would keep uninitialised memory
+    (with 64 experts a router at initialisation leaves some empty).
+    m_pad has room: a group pads by at most block_m either way.
     """
     n = e_flat.shape[0]
     m_pad = -(-(n + num_groups * block_m) // block_m) * block_m
     sizes = jnp.bincount(e_flat, length=num_groups)  # [E]
-    aligned = -(-sizes // block_m) * block_m
+    aligned = jnp.maximum(-(-sizes // block_m), 1) * block_m
     starts = jnp.concatenate(
         [jnp.zeros((1,), aligned.dtype), jnp.cumsum(aligned)[:-1]]
     )

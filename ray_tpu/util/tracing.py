@@ -38,7 +38,10 @@ MOE_ROUTER = "router"  # logits, softmax, top-k, renormalisation, aux loss
 MOE_DISPATCH = "dispatch"  # positions, slot map, gather into the expert buffer
 MOE_EXPERTS = "experts"  # the three expert matmuls and the activation
 MOE_COMBINE = "combine"  # gather back, gate scaling, the reduction over k
-SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
+MOE_LAYOUT = "layout"  # inside dispatch, "gmm" only: sort, tile layout, inverse map
+QK_NORM = "qk_norm"  # RMSNorm of the whole q and k projections (cfg.qk_norm)
+SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
+          MOE_LAYOUT, QK_NORM)
 
 _OFF = contextlib.nullcontext()
 

@@ -64,9 +64,15 @@ def test_flash_bwd_compiles_for_v5e(v5e, bh, t, d, block):
 
 # mixtral-small: b2 x s2048 tokens x top-2 pairs padded to 128-row tiles
 # per expert, hidden 1024 <-> expert width 3584 (w_gate/w_up and w_down).
-@pytest.mark.parametrize("k,n", [(1024, 3584), (3584, 1024)])
-def test_gmm_and_its_gradient_compile_for_v5e(v5e, k, n):
-    m, experts = 2 * 2048 * 2 + 8 * 128, 8
+# OLMoE-1B-7B (the benchmark's dropless-4k cell): b2 x s4096 x top-8 pairs
+# over 64 experts, hidden 2048 <-> expert width 1024.
+@pytest.mark.parametrize("m,experts,k,n", [
+    (2 * 2048 * 2 + 8 * 128, 8, 1024, 3584),
+    (2 * 2048 * 2 + 8 * 128, 8, 3584, 1024),
+    (2 * 4096 * 8 + 64 * 128, 64, 2048, 1024),
+    (2 * 4096 * 8 + 64 * 128, 64, 1024, 2048),
+])
+def test_gmm_and_its_gradient_compile_for_v5e(v5e, m, experts, k, n):
     operands = (
         ((m, k), jnp.bfloat16), ((experts, k, n), jnp.bfloat16),
         ((m // 128,), jnp.int32),

@@ -73,6 +73,20 @@ def llama_paths():
 
 
 @pytest.fixture(scope="module")
+def qk_norm_paths():
+    """Paths of a tiny Llama with OLMoE's QK-norm."""
+    from ray_tpu.models import CONFIGS, LlamaForCausalLM
+    from ray_tpu.models.llama import causal_lm_loss
+
+    cfg = dataclasses.replace(CONFIGS["llama-tiny"], remat=True, qk_norm=True)
+    model = LlamaForCausalLM(cfg)
+    ids = jnp.zeros((2, 32), jnp.int32)
+    return paths_of(compiled_step(
+        model, lambda p, i, t: causal_lm_loss(model.apply(p, i), t), ids
+    ))
+
+
+@pytest.fixture(scope="module")
 def moe_paths():
     """dispatch branch -> paths of a tiny Mixtral's compiled train step."""
     from ray_tpu.models.mixtral import CONFIGS, MixtralForCausalLM, moe_lm_loss
@@ -123,6 +137,32 @@ def test_moe_layer_carries_the_four_scopes(moe_paths, branch):
                if not re.search(r"/moe/(%s)/" % "|".join(MOE_SCOPES), p)]
     assert not unnamed, unnamed[:5]
     assert all(pass_of(p) in ("forward", "backward", "replay") for p in in_moe)
+
+
+def test_gmm_dispatch_tells_its_index_work_from_its_row_gather(moe_paths):
+    nested = f"/moe/{tracing.MOE_DISPATCH}/{tracing.MOE_LAYOUT}/"
+    layout = [p for p in moe_paths["gmm"] if nested in p]
+    assert any(p.endswith("/sort") for p in layout), layout[:5]  # the argsort
+    assert not [p for p in layout if p.endswith("/dot_general")]
+    # the row gather into the tile-aligned buffer is dispatch's own
+    rows = [p for p in moe_paths["gmm"]
+            if f"/moe/{tracing.MOE_DISPATCH}/" in p and nested not in p]
+    assert any(p.endswith("/gather") for p in rows), rows[:5]
+    for branch in ("capacity", "ragged"):
+        assert not [p for p in moe_paths[branch] if f"/{tracing.MOE_LAYOUT}/" in p]
+
+
+def test_qk_norm_scope_holds_both_norms_and_only_where_the_model_has_them(
+    llama_paths, qk_norm_paths
+):
+    scoped = [p for p in qk_norm_paths if f"/attn/{tracing.QK_NORM}/" in p]
+    for norm in ("q_norm", "k_norm"):
+        for kind in ("forward", "backward"):
+            assert [p for p in scoped
+                    if f"/{tracing.QK_NORM}/{norm}/" in p and pass_of(p) == kind], (
+                norm, kind)
+    assert not [p for p in scoped if p.endswith("/dot_general")]
+    assert not [p for p in llama_paths if f"/{tracing.QK_NORM}/" in p]
 
 
 @pytest.mark.parametrize("branch", BRANCHES)
@@ -273,13 +313,14 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 
 
 def test_names_emitted_are_exactly_the_list(
-    llama_paths, moe_paths, session_lines, actor_lines
+    llama_paths, qk_norm_paths, moe_paths, session_lines, actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + [p for ps in moe_paths.values() for p in ps]
+    paths = llama_paths + qk_norm_paths + [
+        p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:
         assert any(f"/{name}/" in p for p in paths), name
 
