@@ -384,6 +384,80 @@ def expert_ffn(expert_in, w_gate, w_up, w_down, counts, pairs):
     return _tiled_ffn(expert_in, w_gate, w_up, w_down, tiles, trips, rows)
 
 
+def _pair_slots(order, dst, m_pad, K):
+    """``aligned_group_layout``'s permutation both ways, as two scalar
+    scatters: ``slot_of_pair`` [S, K], the aligned slot of each (token, k)
+    pair, pairs counted token-major, and ``pair_of_slot`` [m_pad], a
+    padding slot holding S * K (the pair of token S, which is a zero
+    row)."""
+    N = order.shape[0]
+    slot_of_pair = (
+        jnp.zeros((N,), jnp.int32).at[order].set(dst, unique_indices=True)
+    )
+    pair_of_slot = (
+        jnp.full((m_pad,), N, jnp.int32).at[dst].set(order, unique_indices=True)
+    )
+    return slot_of_pair.reshape(N // K, K), pair_of_slot
+
+
+@jax.custom_vjp
+def _rows_to_slots(x2, slot_of_pair, pair_of_slot):
+    """The ``gmm`` dispatch's row move: tokens [S, D] to the tile-aligned
+    layout [m_pad, D], a padding slot reading zeros. ``slot_of_pair``
+    [S, K] and ``pair_of_slot`` [m_pad] are one permutation both ways
+    (pairs count token-major, a padding slot holds S * K). Each slot reads
+    one token and each token is read from its K slots, so the gradient is
+    a gather and a sum over K where XLA, which cannot know that the map is
+    injective, would scatter-add 73,728 rows into 8,192 (5.3 ms a layer at
+    OLMoE's shapes against 2.6: PERF.md §6, PR 27)."""
+    x_pad = jnp.concatenate([x2, jnp.zeros((1, x2.shape[1]), x2.dtype)])
+    return x_pad[pair_of_slot // slot_of_pair.shape[1]]
+
+
+def _rows_to_slots_fwd(x2, slot_of_pair, pair_of_slot):
+    return _rows_to_slots(x2, slot_of_pair, pair_of_slot), slot_of_pair
+
+
+def _rows_to_slots_bwd(slot_of_pair, g):
+    dx = g[slot_of_pair].sum(1, dtype=jnp.float32).astype(g.dtype)
+    return dx, None, None
+
+
+_rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
+
+
+@jax.custom_vjp
+def _slots_to_rows(eo, gates, slot_of_pair, pair_of_slot):
+    """The ``gmm`` combine: expert outputs [m_pad, D] back to tokens
+    [S, D], each the sum of its K pairs' rows weighted by ``gates``
+    [S, K], added up in float32 and rounded once. The index maps are
+    ``_rows_to_slots``'s; forward and both gradients are gathers for the
+    same reason."""
+    pairs = eo[slot_of_pair].astype(jnp.float32) * gates[..., None]
+    return pairs.sum(1).astype(eo.dtype)  # [S, K, D] summed over K
+
+
+def _slots_to_rows_fwd(eo, gates, slot_of_pair, pair_of_slot):
+    out = _slots_to_rows(eo, gates, slot_of_pair, pair_of_slot)
+    return out, (eo, gates, slot_of_pair, pair_of_slot)
+
+
+def _slots_to_rows_bwd(residuals, g):
+    eo, gates, slot_of_pair, pair_of_slot = residuals
+    # A slot's row is its token's cotangent times its pair's gate; a
+    # padding slot reads the zero row and a zero gate.
+    g_pad = jnp.concatenate([g, jnp.zeros((1, g.shape[1]), g.dtype)])
+    gate_of_slot = jnp.concatenate(
+        [gates.reshape(-1), jnp.zeros((1,), gates.dtype)]
+    )[pair_of_slot]
+    d_eo = g_pad[pair_of_slot // gates.shape[1]] * gate_of_slot[:, None]
+    d_gates = (eo[slot_of_pair].astype(jnp.float32) * g[:, None, :]).sum(-1)
+    return d_eo.astype(eo.dtype), d_gates.astype(gates.dtype), None, None
+
+
+_slots_to_rows.defvjp(_slots_to_rows_fwd, _slots_to_rows_bwd)
+
+
 CONFIGS = {
     "mixtral-tiny": MixtralConfig(
         vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -412,7 +486,11 @@ class MoELayer(nn.Module):
 
     "gmm": (token, k) pairs sorted by expert into 128-row tiles for the
     pallas grouped matmul (ops/gmm.py): at most E tiles of padding, zero
-    drops, one device per expert shard.
+    drops, one device per expert shard. Rows move by gathers in both
+    directions, forward and backward: the sort is a permutation whose
+    inverse the layer holds, and a token's K pairs are consecutive in
+    pair order, so what would be a scatter-add is a gather and a sum
+    over K (_rows_to_slots, _slots_to_rows).
 
     "ragged" (opt-in): (token, k) pairs argsorted by expert feed
     `lax.ragged_dot` with exact group sizes — zero capacity padding and
@@ -495,41 +573,25 @@ class MoELayer(nn.Module):
                 # The index work, under a scope of its own so that a
                 # profile tells it from the row gather below.
                 with tracing.scope(tracing.MOE_LAYOUT):
-                    e_flat = gate_idx.reshape(N)
                     order, dst, tile_group, m_pad = aligned_group_layout(
-                        e_flat, E, block_m=128
+                        gate_idx.reshape(N), E, block_m=128
                     )
-                    tok_of_pair = jnp.arange(N, dtype=jnp.int32) // K
-                    tok_sorted = tok_of_pair[order]
-                    # inv maps aligned slot -> sorted-pair index, with
-                    # padding slots reading a zero row.
-                    inv = (
-                        jnp.full((m_pad,), N, jnp.int32)
-                        .at[dst]
-                        .set(jnp.arange(N, dtype=jnp.int32), unique_indices=True)
+                    slot_of_pair, pair_of_slot = _pair_slots(
+                        order, dst, m_pad, K
                     )
-                    src_tok = jnp.concatenate(
-                        [tok_sorted, jnp.full((1,), B * T, jnp.int32)]
-                    )[inv]
-                # Row GATHER into the aligned layout (row scatters
-                # serialize on TPU; gathers vectorize — same trick as the
-                # capacity path).
-                x_pad = jnp.concatenate(
-                    [x2, jnp.zeros((1, D), x2.dtype)], axis=0
-                )
-                lhs = x_pad[src_tok]  # [m_pad, D]
+                # Row GATHER into the aligned layout, and a gather back
+                # in its gradient (a row scatter-add costs 7-11 times a
+                # copy of the same rows on a v5e: PERF.md §6, PR 26).
+                lhs = _rows_to_slots(x2, slot_of_pair, pair_of_slot)
             with tracing.scope(tracing.MOE_EXPERTS):
                 h = gmm(lhs, w_gate.astype(cfg.dtype), tile_group)
                 u = gmm(lhs, w_up.astype(cfg.dtype), tile_group)
                 act = nn.silu(h) * u
                 eo = gmm(act, w_down.astype(cfg.dtype), tile_group)
             with tracing.scope(tracing.MOE_COMBINE):
-                gates_sorted = gate_vals.astype(cfg.dtype).reshape(N)[order]
-                pair_out = eo[dst] * gates_sorted[:, None]
-                out2 = (
-                    jnp.zeros((B * T, D), cfg.dtype)
-                    .at[tok_sorted]
-                    .add(pair_out)
+                out2 = _slots_to_rows(
+                    eo, gate_vals.astype(cfg.dtype).reshape(B * T, K),
+                    slot_of_pair, pair_of_slot,
                 )
                 out = out2.reshape(B, T, D)
                 return with_logical_constraint(out, ("batch", "seq", "embed"))

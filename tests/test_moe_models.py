@@ -239,6 +239,86 @@ def test_gmm_dispatch_agrees_with_ragged(tiny_moe, monkeypatch):
         )
 
 
+def _routing(case, rng):
+    """(experts, expert of each (token, k) pair [S, K], dtype) of a case."""
+    if case == "top1":
+        return 4, rng.randint(0, 4, (24, 1)), jnp.float32
+    if case == "one_expert_takes_every_token":
+        return 4, np.full((24, 2), 2), jnp.float32
+    # 8 of 64, the experts from 40 on chosen by no token; the same in
+    # bfloat16, held to a float32 oracle.
+    picks = np.stack([rng.permutation(40)[:8] for _ in range(32)])
+    return 64, picks, jnp.bfloat16 if case == "bfloat16" else jnp.float32
+
+
+@pytest.mark.parametrize("case", [
+    "top1", "top8_of_64_with_experts_empty", "one_expert_takes_every_token",
+    "bfloat16",
+])
+def test_gmm_row_moves_are_the_scatters_they_replace(case):
+    """`_rows_to_slots` and `_slots_to_rows`, forward and every gradient,
+    against the plain gather and `.at[].add` over the sorted pairs that
+    the "gmm" branch moved its rows by (the "ragged" branch still does):
+    the index maps there are built as that branch built them."""
+    from ray_tpu.models.mixtral import (
+        _pair_slots, _rows_to_slots, _slots_to_rows,
+    )
+    from ray_tpu.ops.gmm import aligned_group_layout
+
+    rng = np.random.RandomState(7)
+    E, picks, dtype = _routing(case, rng)
+    (S, K), N, D = picks.shape, picks.size, 16
+    order, dst, _, m_pad = aligned_group_layout(
+        jnp.asarray(picks.reshape(N), jnp.int32), E, block_m=8
+    )
+    slot_of_pair, pair_of_slot = _pair_slots(order, dst, m_pad, K)
+
+    tok_sorted = (jnp.arange(N, dtype=jnp.int32) // K)[order]
+    inv = jnp.full((m_pad,), N, jnp.int32).at[dst].set(jnp.arange(N))
+    src_tok = jnp.concatenate([tok_sorted, jnp.full((1,), S, jnp.int32)])[inv]
+    padding = np.asarray(inv) == N
+    assert padding.sum() == m_pad - N > 0
+
+    def dispatch_oracle(x2):
+        return jnp.concatenate([x2, jnp.zeros((1, D), x2.dtype)])[src_tok]
+
+    def combine_oracle(eo, gates):
+        pair_out = eo[dst] * gates.reshape(N)[order][:, None]
+        return jnp.zeros((S, D), eo.dtype).at[tok_sorted].add(pair_out)
+
+    def draw(*shape):
+        return jnp.asarray(rng.randn(*shape), dtype)
+
+    # Padding slots hold noise, in the expert outputs and the cotangents:
+    # neither pass may read them.
+    x2, eo, d_lhs, d_out = draw(S, D), draw(m_pad, D), draw(m_pad, D), draw(S, D)
+    gates = jnp.asarray(rng.rand(S, K), dtype)
+
+    def f32(*arrays):
+        return [a.astype(jnp.float32) for a in arrays]
+
+    lhs, pull_x = jax.vjp(lambda x: _rows_to_slots(x, slot_of_pair, pair_of_slot), x2)
+    out, pull_eo = jax.vjp(
+        lambda e, g: _slots_to_rows(e, g, slot_of_pair, pair_of_slot), eo, gates
+    )
+    got = [lhs, *pull_x(d_lhs), out, *pull_eo(d_out)]
+    assert [a.dtype for a in got] == [dtype] * 5
+    want_lhs, pull_x = jax.vjp(dispatch_oracle, *f32(x2))
+    want_out, pull_eo = jax.vjp(combine_oracle, *f32(eo, gates))
+    want = [want_lhs, *pull_x(*f32(d_lhs)), want_out, *pull_eo(*f32(d_out))]
+
+    # A sum of K or D products rounds once to the dtype: half a unit in
+    # bfloat16's eighth bit, and float32's own noise.
+    rtol = 2.0 ** -8 if dtype == jnp.bfloat16 else 1e-6
+    for name, a, b in zip(
+        ("lhs", "d_x", "out", "d_eo", "d_gates"), f32(*got), want
+    ):
+        np.testing.assert_allclose(a, b, rtol=rtol, atol=1e-6, err_msg=name)
+    d_eo = np.asarray(got[3].astype(jnp.float32))
+    assert (d_eo[padding] == 0).all() and (d_eo[~padding] != 0).any()
+    assert (np.asarray(lhs.astype(jnp.float32))[padding] == 0).all()
+
+
 def test_moe_dispatch_auto_resolution(tiny_moe, monkeypatch, tmp_path):
     """"auto" resolves via a measured probe, keeps the verdict in the
     process (nothing is written under the home directory), forces

@@ -152,6 +152,28 @@ def test_gmm_dispatch_tells_its_index_work_from_its_row_gather(moe_paths):
         assert not [p for p in moe_paths[branch] if f"/{tracing.MOE_LAYOUT}/" in p]
 
 
+def test_gmm_moves_its_rows_by_gathers_forward_and_backward(moe_paths):
+    def scatter_adds(branch):
+        return [p for p in moe_paths[branch]
+                if "/moe/" in p and p.endswith("/scatter-add")]
+
+    # What is left adds scalars: the layout's bincount and the gradient of
+    # the router's top_k.
+    scalars = (f"/moe/{tracing.MOE_ROUTER}/",
+               f"/moe/{tracing.MOE_DISPATCH}/{tracing.MOE_LAYOUT}/")
+    assert not [p for p in scatter_adds("gmm")
+                if not any(scope in p for scope in scalars)]
+    # The oracle's combine is the scatter-add this check has to be able to see.
+    assert [p for p in scatter_adds("ragged")
+            if f"/moe/{tracing.MOE_COMBINE}/" in p]
+    # The hand-written gradients' gathers keep their layer's scope.
+    for scope in (tracing.MOE_DISPATCH, tracing.MOE_COMBINE):
+        back = [p for p in moe_paths["gmm"] if p.endswith("/gather")
+                and f"/moe/{scope}/" in p and f"/{tracing.MOE_LAYOUT}/" not in p
+                and pass_of(p) == "backward"]
+        assert back, scope
+
+
 def test_qk_norm_scope_holds_both_norms_and_only_where_the_model_has_them(
     llama_paths, qk_norm_paths
 ):
