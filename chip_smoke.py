@@ -1,7 +1,7 @@
 """The quickest proof that the system still starts on the chip.
 
 Drives the normal path once — ``ray_tpu.init`` -> ``JaxTrainer`` -> mesh ->
-step — at the full width and depth of llama-1b, sized as bench.py sizes it
+step — at the full width and depth of llama-1b
 (bf16 params, b2 x s2048, adamw with bf16 mu, donated buffers, full remat),
 with random weights from a seed:
 

@@ -1,8 +1,8 @@
 # Repo-root conftest: makes `ray_tpu` importable and pins JAX to a virtual
 # 8-device CPU mesh for tests (multi-chip sharding is validated on CPU; the
-# real chip is exercised by chip_smoke.py and bench.py). The pin goes
-# through the environment, which worker processes inherit, and through
-# jax.config before any backend initializes.
+# real chip is exercised by chip_smoke.py and benchmarks/run.py). The pin
+# goes through the environment, which worker processes inherit, and
+# through jax.config before any backend initializes.
 import os
 import sys
 

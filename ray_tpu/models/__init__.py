@@ -1,5 +1,3 @@
 from .llama import LlamaConfig, LlamaForCausalLM, CONFIGS  # noqa: F401
-from .gpt import GPTConfig, GPTForCausalLM  # noqa: F401
-from .gpt import CONFIGS as GPT_CONFIGS  # noqa: F401
 from .mixtral import MixtralConfig, MixtralForCausalLM, moe_lm_loss  # noqa: F401
 from .mixtral import CONFIGS as MIXTRAL_CONFIGS  # noqa: F401

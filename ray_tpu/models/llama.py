@@ -20,7 +20,7 @@ HBM-light).
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import flash_attention
 from ..ops.ring_attention import ring_self_attention
-from ..parallel.mesh import with_logical_constraint
+from ..parallel.mesh import logical_axis_shards, with_logical_constraint
 from ..util import tracing
 
 
@@ -203,23 +203,31 @@ class MLP(nn.Module):
 
 class DecoderLayer(nn.Module):
     cfg: LlamaConfig
+    # The FFN's flax name and its module, called as module(cfg, name=name).
+    ffn: Tuple[str, Any]
     mesh: Optional[Any] = None
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
+        ffn_name, ffn = self.ffn
         h = x + Attention(cfg, mesh=self.mesh, name="attn")(
             RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(x), positions
         )
-        out = h + MLP(cfg, name="mlp")(
+        out = h + ffn(cfg, name=ffn_name)(
             RMSNorm(cfg.rms_eps, cfg.param_dtype, name="post_attn_norm")(h)
         )
         return with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
 class LlamaForCausalLM(nn.Module):
+    """The decoder body of every family: embedding, layers, final norm,
+    head. A family differs in its layers' FFN alone and says so by
+    binding ``ffn`` (MixtralForCausalLM: the sparse layer, as ``moe``)."""
+
     cfg: LlamaConfig
     mesh: Optional[Any] = None
+    ffn = ("mlp", MLP)  # a class attribute, not a field: no caller sets it
 
     @nn.compact
     def __call__(self, input_ids, positions=None, return_hidden=False):
@@ -238,13 +246,15 @@ class LlamaForCausalLM(nn.Module):
             param_dtype=cfg.param_dtype, name="embed_tokens",
             embedding_init=weight_init(cfg, nn.linear.default_embed_init),
         )
-        if self.mesh is not None and self.mesh.size > 1:
-            # One-hot matmul lookup: with the table sharded
-            # (vocab=tensor, embed=fsdp) a gather forces SPMD into full
-            # rematerialization (replicate-then-repartition every step);
-            # a contraction over the vocab axis instead becomes partial
-            # products + psum over `tensor`, rides the MXU, and XLA fuses
-            # the one-hot so the [B,S,V] operand is never materialized.
+        if logical_axis_shards("vocab") * logical_axis_shards("embed") > 1:
+            # One-hot matmul lookup where the ambient mesh splits the
+            # table (vocab=tensor, embed=fsdp): a gather forces SPMD into
+            # full rematerialization (replicate-then-repartition every
+            # step); a contraction over the vocab axis instead becomes
+            # partial products + psum over `tensor`, rides the MXU, and
+            # XLA fuses the one-hot so the [B,S,V] operand is never
+            # materialized. A whole table (one chip, or a mesh of data,
+            # seq and expert axes alone) is read by a gather.
             one_hot = jax.nn.one_hot(input_ids, cfg.vocab_size, dtype=cfg.dtype)
             x = jnp.einsum(
                 "bsv,ve->bse", one_hot, emb.embedding.astype(cfg.dtype)
@@ -259,7 +269,9 @@ class LlamaForCausalLM(nn.Module):
                 policy=remat_policy(cfg),
             )
         for i in range(cfg.num_layers):
-            x = layer_cls(cfg, mesh=self.mesh, name=f"layers_{i}")(x, positions)
+            x = layer_cls(
+                cfg, self.ffn, mesh=self.mesh, name=f"layers_{i}"
+            )(x, positions)
         x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="final_norm")(x)
         if return_hidden:
             return x

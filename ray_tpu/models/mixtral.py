@@ -1,19 +1,22 @@
 """Mixtral-style sparse-MoE causal LM with expert parallelism.
 
 Net-new vs the reference (SURVEY.md §2.3: EP/MoE "absent — integration
-delegated"; here it's first-class). TPU-first design: experts live in
-one stacked tensor with logical axis "expert" → the `expert` mesh axis,
-and token dispatch/combine are dense einsums against a capacity-bounded
-one-hot dispatch mask (GShard-style). Under GSPMD, batch-sharded
-activations meeting expert-sharded weights compile into the all-to-all
-over ICI automatically — no hand-written routing collectives, static
-shapes throughout (XLA-friendly: no ragged tensors).
+delegated"; here it's first-class). The model is llama.py's decoder body
+with `MoELayer` as every layer's FFN. Experts live in one stacked tensor
+per projection, [E, in, out], whose logical axis "expert" maps to the
+`expert` mesh axis. A layer moves its tokens to its experts and back in
+one of three ways, which the config names (`MixtralConfig.moe_dispatch`):
+capacity-bounded static buffers [E, B, C, D] filled and read back by row
+gathers (the layout that shards over `expert`: under GSPMD the
+batch-sharded tokens meet the expert-sharded weights in collectives over
+ICI, none written by hand), a tile-aligned sort for the pallas grouped
+matmul (drop-free, one device per expert shard), or exact groups through
+`lax.ragged_dot` (the tests' oracle). Static shapes throughout.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -24,10 +27,7 @@ from ..parallel import (
     ambient_axes, ambient_spec, logical_axis_shards, with_logical_constraint,
 )
 from ..util import tracing
-from .llama import CONFIGS as LLAMA_CONFIGS
-from .llama import (  # noqa: F401
-    Attention, LlamaConfig, RMSNorm, causal_lm_loss, weight_init,
-)
+from .llama import LlamaConfig, LlamaForCausalLM, causal_lm_loss, weight_init
 
 
 @dataclass(frozen=True)
@@ -49,22 +49,27 @@ class MixtralConfig(LlamaConfig):
     # least factor at which no pair can be dropped.
     capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.02
-    # "auto" (default): measured selection between "capacity" and "gmm",
-    # cached per process and shape — resolve_moe_dispatch().
+    # How a layer moves tokens to experts and back; the architecture's
+    # semantics (bounded buffers that may drop pairs, or drop-free), so
+    # a model's file names it and nothing measures or overrides it.
     # "capacity": capacity-bounded static buffers with an [E, B, C, D]
     # expert axis, the one layout that mesh-shards for expert
-    # parallelism. Its FFN computes a slot on one chip and, where a
-    # row's pairs cannot reach a quarter or more of a chip's 512-slot
-    # tiles, only as many tiles as they can reach (expert_ffn); at the
-    # default factor the buffers are 80% full and it is one einsum over
-    # all of them: 25% padding FLOPs.
+    # parallelism; pairs past an expert's capacity are dropped. Its FFN
+    # computes a slot on one chip and, where a row's pairs cannot reach
+    # a quarter or more of a chip's 512-slot tiles, only as many tiles
+    # as they can reach (expert_ffn); at the default factor the buffers
+    # are 80% full and it is one einsum over all of them: 25% padding
+    # FLOPs.
     # "gmm": tile-aligned group-sorted dispatch through the pallas
     # grouped matmul (ops/gmm.py) — E*block_m rows of padding (8,192 on
     # 65,536 pairs at 64 experts top-8, b2 x s4096) and zero drops;
     # single-device per expert shard (the EP path stays capacity).
-    # "ragged": exact-group lax.ragged_dot — the semantic oracle;
-    # measured slower than both on current backends.
-    moe_dispatch: str = "auto"
+    # "ragged": exact-group lax.ragged_dot, zero padding and zero
+    # drops — the semantic oracle; measured slower than both on
+    # current backends.
+    # "auto": the same as "capacity"; kept because the benchmark's
+    # accepted Mixtral file sets it (ROADMAP C3).
+    moe_dispatch: str = "capacity"
 
     def num_params(self) -> int:
         """Llama count minus its dense MLP, plus E stacked experts and
@@ -75,100 +80,12 @@ class MixtralConfig(LlamaConfig):
         moe_mlp = self.num_experts * 3 * h * i + h * self.num_experts
         return super().num_params() + l * (moe_mlp - dense_mlp)
 
-    def active_params_per_token(self) -> int:
-        """FLOPs-relevant parameter count: only top-k experts run per
-        token (what an MFU estimate should use)."""
-        h, i, l = self.hidden_size, self.intermediate_size, self.num_layers
-        dense_mlp = 3 * h * i
-        active_mlp = self.num_experts_per_tok * 3 * h * i + h * self.num_experts
-        return super().num_params() + l * (active_mlp - dense_mlp)
 
-
-# moe_dispatch="auto" resolutions, keyed by _shape_key: warmed by
-# resolve_moe_dispatch() (outside jit), read at trace time.
-_RESOLVED: dict = {}
-
-
-def _shape_key(cfg: "MixtralConfig") -> str:
-    return (
-        f"E{cfg.num_experts}-K{cfg.num_experts_per_tok}-"
-        f"D{cfg.hidden_size}-F{cfg.intermediate_size}"
-    )
-
-
-def resolve_moe_dispatch(
-    cfg: "MixtralConfig",
-    tokens: int = 4096,
-    mesh=None,
-    steps: int = 10,
-) -> str:
-    """Measure-and-pick the MoE dispatch backend for this device.
-
-    The judge of record is a timed probe of the dispatch+FFN core
-    (fwd+bwd) at this config's shapes on the live backend — not a
-    config flag: ragged_dot vs capacity vs the pallas gmm rank
-    differently across TPU generations and compiler versions.
-    Resolutions are kept per process, keyed by shape. A backend that
-    fails to compile or run raises: a verdict reached by dropping the
-    failed candidate would hide a broken kernel behind the other path.
-    Under an expert-sharded mesh the capacity path is returned without
-    probing (its [E, B, C, D] layout is what rides the EP all-to-all;
-    the gmm layout is per-shard).
-    """
-    import os
-    import time
-
-    if cfg.moe_dispatch != "auto":
-        return cfg.moe_dispatch
-    env = os.environ.get("RAY_TPU_MOE_DISPATCH")
-    if env:
-        _RESOLVED[_shape_key(cfg)] = env
-        return env
-    if mesh is not None and mesh.shape.get("expert", 1) > 1:
-        _RESOLVED[_shape_key(cfg)] = "capacity"
-        return "capacity"
-    skey = _shape_key(cfg)
-    if skey in _RESOLVED:
-        return _RESOLVED[skey]
-
-    from dataclasses import replace as _replace
-
-    probe_cfg = _replace(
-        cfg,
-        vocab_size=256,
-        num_layers=1,
-        num_heads=4,
-        num_kv_heads=4,
-        remat=False,
-    )
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(
-        rng.randn(1, tokens, cfg.hidden_size), probe_cfg.dtype
-    )
-
-    def _time_backend(name: str) -> float:
-        layer = MoELayer(_replace(probe_cfg, moe_dispatch=name))
-        params = jax.jit(layer.init)(jax.random.PRNGKey(0), x[:, :256])
-
-        @jax.jit
-        def step(p, x):
-            def loss(p):
-                return (layer.apply(p, x) ** 2).sum()
-
-            return jax.grad(loss)(p)
-
-        g = step(params, x)
-        jax.tree_util.tree_map(lambda a: a.block_until_ready(), g)
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            g = step(params, x)
-        jax.tree_util.tree_map(lambda a: a.block_until_ready(), g)
-        return time.perf_counter() - t0
-
-    times = {name: _time_backend(name) for name in ("capacity", "gmm")}
-    winner = min(times, key=times.get)
-    _RESOLVED[skey] = winner
-    return winner
+def resolve_moe_dispatch(cfg: "MixtralConfig", tokens=None, mesh=None) -> str:
+    """The dispatch branch ``cfg`` names, "capacity" for "auto". Nothing
+    is measured: ``tokens`` and ``mesh`` are taken because the
+    benchmark's loop passes them."""
+    return "capacity" if cfg.moe_dispatch == "auto" else cfg.moe_dispatch
 
 
 # Slots in a tile of a capacity buffer, the unit the expert FFN computes
@@ -473,16 +390,16 @@ CONFIGS = {
 
 
 class MoELayer(nn.Module):
-    """Top-k router with three dispatch backends (cfg.moe_dispatch).
+    """Top-k router and the stacked experts' SwiGLU, with the three
+    dispatch branches cfg.moe_dispatch names ("auto" is "capacity").
 
-    "capacity": gather/scatter into capacity-bounded static buffers
-    with an explicit [E, B, C, D] expert axis — under GSPMD the expert
-    dim mesh-shards and dispatch rides the collectives over ICI. The
-    expert FFN (expert_ffn) lowers to XLA's batched matmuls over the
-    slots that can hold a pair, each slot on one chip. Still far
-    cheaper than the GShard dense one-hot einsum, whose [B,T,E,C] mask
-    costs O(B*T^2*D) MXU FLOPs at long T. What "auto" falls back to,
-    and what it resolves to on an expert-sharded mesh.
+    "capacity": row gathers into capacity-bounded static buffers with an
+    explicit [E, B, C, D] expert axis; under GSPMD the expert dim
+    mesh-shards and dispatch rides the collectives over ICI. A pair
+    that arrives past its expert's C slots is dropped (never at factor
+    E / K). The expert FFN (expert_ffn) is XLA's batched matmuls over
+    the slots that can hold a pair, each slot on one chip. Combine is a
+    gather and a sum over a token's K pairs.
 
     "gmm": (token, k) pairs sorted by expert into 128-row tiles for the
     pallas grouped matmul (ops/gmm.py): at most E tiles of padding, zero
@@ -492,11 +409,11 @@ class MoELayer(nn.Module):
     pair order, so what would be a scatter-add is a gather and a sum
     over K (_rows_to_slots, _slots_to_rows).
 
-    "ragged" (opt-in): (token, k) pairs argsorted by expert feed
+    "ragged": (token, k) pairs argsorted by expert feed
     `lax.ragged_dot` with exact group sizes — zero capacity padding and
     zero drops. Measured slower than capacity on current TPU backends
-    (ragged_dot lowers to a masked loop), so it serves as the semantic
-    oracle and the path for backends where it wins.
+    (ragged_dot lowers to a masked loop): the semantic oracle that the
+    tests and chip_smoke.py hold the other two to.
 
     Gradients flow through the gathers/ragged dots and gate weights."""
 
@@ -505,12 +422,7 @@ class MoELayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        dispatch = cfg.moe_dispatch
-        if dispatch == "auto":
-            # Trace-time: use the process cache warmed by
-            # resolve_moe_dispatch() (bench/trainer call it before jit);
-            # capacity is the safe fallback everywhere.
-            dispatch = _RESOLVED.get(_shape_key(cfg), "capacity")
+        dispatch = resolve_moe_dispatch(cfg)
         if dispatch not in ("ragged", "capacity", "gmm"):
             raise ValueError(
                 f"moe_dispatch must be 'auto', 'ragged', 'capacity' or "
@@ -700,59 +612,10 @@ class MoELayer(nn.Module):
             return with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
-class MoEDecoderLayer(nn.Module):
-    cfg: MixtralConfig
-    mesh: Optional[Any] = None
+class MixtralForCausalLM(LlamaForCausalLM):
+    """The decoder body of llama.py with the sparse FFN in every layer."""
 
-    @nn.compact
-    def __call__(self, x, positions):
-        cfg = self.cfg
-        h = x + Attention(cfg, mesh=self.mesh, name="attn")(
-            RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(x),
-            positions,
-        )
-        out = h + MoELayer(cfg, name="moe")(
-            RMSNorm(cfg.rms_eps, cfg.param_dtype, name="post_attn_norm")(h)
-        )
-        return with_logical_constraint(out, ("batch", "seq", "embed"))
-
-
-class MixtralForCausalLM(nn.Module):
-    cfg: MixtralConfig
-    mesh: Optional[Any] = None
-
-    @nn.compact
-    def __call__(self, input_ids, positions=None):
-        cfg = self.cfg
-        if positions is None:
-            positions = jnp.broadcast_to(
-                jnp.arange(input_ids.shape[1])[None], input_ids.shape
-            )
-        emb = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name="embed_tokens",
-            embedding_init=weight_init(cfg, nn.linear.default_embed_init),
-        )
-        x = emb(input_ids)
-        x = with_logical_constraint(x, ("batch", "seq", "embed"))
-        from .llama import remat_policy
-
-        layer_cls = MoEDecoderLayer
-        if cfg.remat:
-            layer_cls = nn.remat(
-                MoEDecoderLayer, prevent_cse=False,
-                policy=remat_policy(cfg),
-            )
-        for i in range(cfg.num_layers):
-            x = layer_cls(cfg, mesh=self.mesh, name=f"layers_{i}")(x, positions)
-        x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="final_norm")(x)
-        if cfg.tie_embeddings:
-            return emb.attend(x.astype(cfg.param_dtype))
-        return nn.Dense(
-            cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-            param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
-            name="lm_head",
-        )(x)
+    ffn = ("moe", MoELayer)
 
 
 def moe_lm_loss(model: MixtralForCausalLM, params, input_ids, targets,

