@@ -60,10 +60,10 @@ class MixtralConfig(LlamaConfig):
     # as they can reach (expert_ffn); at the default factor the buffers
     # are 80% full and it is one einsum over all of them: 25% padding
     # FLOPs.
-    # "gmm": tile-aligned group-sorted dispatch through the pallas
-    # grouped matmul (ops/gmm.py) — E*block_m rows of padding (8,192 on
-    # 65,536 pairs at 64 experts top-8, b2 x s4096) and zero drops;
-    # single-device per expert shard (the EP path stays capacity).
+    # "gmm": tile-aligned group-sorted dispatch through the pallas grouped
+    # matmul (ops/gmm.py), an expert's weights resident in VMEM over its
+    # tiles (1.97 ms a call in OLMoE's step on a v5e, PERF.md §6, PR 29);
+    # E tiles of padding at most, zero drops; one device (EP: capacity).
     # "ragged": exact-group lax.ragged_dot, zero padding and zero
     # drops — the semantic oracle; measured slower than both on
     # current backends.

@@ -16,10 +16,28 @@ tiles than pairs fill), and zero token drops; the capacity path needs
 factor E/k to drop none (8.0 there: 193.7 against 68.0 ms a layer
 forward and backward on a v5e, PERF.md §6, PR 26).
 
-Backward: dlhs reuses the same kernel with per-expert-transposed rhs;
-drhs is a group-accumulating transposed gmm (`_tgmm`) that keeps the
-output block resident in VMEM across the consecutive m-tiles of each
-expert (tokens are group-sorted, so revisits are consecutive).
+What crosses HBM: each operand block once per use the mathematics has
+for it. The row tiles are every grid's inner dimension and an expert's
+tiles are consecutive, so Pallas, which fetches a block only when its
+index differs from the step before, reads an expert's weight block
+once for all of the expert's tiles; the block is the expert's whole
+[K, N] matrix wherever that fits VMEM twice over (`_gmm_block_n`: 4 MiB
+at OLMoE's 2048 x 1024), so the rows are read once too. At the OLMoE
+cell's shapes (73,728 rows, 64 experts) a call moves 0.72-0.88 GB and
+takes 2.02-2.05 ms where the column blocks innermost fetched 2.4 GB of
+weights and took 3.93-4.19 ms (PERF.md §6, PR 29: one call alone on a
+v5e); what is left is the MXU's 1.57 ms on the padded rows, a fetch
+that a 2.7 us step cannot hide at each change of expert, and 576 grid
+steps.
+
+Backward: dlhs is the same kernel contracting the weights' last
+dimension as they are stored (no transposed copy of them; Mosaic
+lowers it at the forward's speed: 2.02-2.05 ms); drhs is a
+group-accumulating transposed gmm (`_tgmm`) that keeps the output block
+and its float32 accumulator resident in VMEM across the consecutive
+m-tiles of each expert and takes the whole [K, N] as that block where
+it fits (`_tgmm_blocks`), so each input is read once: 2.11 ms a call
+where (512, 512) blocks took 3.77 (the same runs).
 """
 from __future__ import annotations
 
@@ -33,32 +51,117 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import _interpret
 
 
-def _gmm_kernel(tg_ref, lhs_ref, rhs_ref, out_ref):
-    out_ref[...] = jnp.dot(
-        lhs_ref[...], rhs_ref[0], preferred_element_type=jnp.float32
+# What a call's blocks may take of VMEM, double buffers and the float32
+# accumulator included, and the limit handed to Mosaic (a v5e core has
+# 128 MiB; Mosaic's default lets a kernel use 16).
+_BLOCK_BUDGET = 40 * 2**20
+_VMEM_LIMIT = 64 * 2**20
+
+
+def _blocks(dim: int) -> list:
+    """The blocks a dimension can be cut into, largest first: the whole
+    of it, then every divisor that is a multiple of the 128 lanes."""
+    return [dim] + [
+        dim // parts for parts in range(2, dim // 128 + 1)
+        if dim % parts == 0 and (dim // parts) % 128 == 0
+    ]
+
+
+# Index maps, by grid position (j, i): block j of the output's columns,
+# row tile i, the row tiles innermost. Module level so that
+# tests/test_gmm_kernel.py walks the grid with what the kernel uses.
+def _gmm_lhs_index(j, i, tg):
+    return i, 0
+
+
+def _gmm_rhs_index(j, i, tg):
+    return tg[i], 0, j
+
+
+def _gmm_rhs_t_index(j, i, tg):
+    return tg[i], j, 0
+
+
+def _gmm_out_index(j, i, tg):
+    return i, j
+
+
+def _gmm_kernel(tg_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs):
+    # lhs @ rhs, or lhs @ rhs^T on the weights as stored.
+    contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+    out_ref[...] = jax.lax.dot_general(
+        lhs_ref[...], rhs_ref[0], contract,
+        preferred_element_type=jnp.float32,
     ).astype(out_ref.dtype)
 
 
-def _gmm_pallas(lhs, rhs, tile_group, block_m, block_n):
+def _gmm_block_n(k: int, n: int, itemsize: int, block_m: int = 128) -> int:
+    """Columns of the output a grid step computes: all of them where the
+    expert's whole [k, n] matrix fits the budget twice over, else the
+    largest block that does."""
+    def fits(bn):
+        blocks = (block_m * k + k * bn + block_m * bn) * itemsize
+        return 2 * blocks + 4 * block_m * bn <= _BLOCK_BUDGET
+
+    blocks = _blocks(n)
+    return next((bn for bn in blocks if fits(bn)), blocks[-1])
+
+
+def _gmm_grid(m, k, n, block_m, block_n, transpose_rhs=False):
+    """(grid, in_specs, out_spec) of a `_gmm_kernel` call. The row tiles
+    are the grid's inner dimension and the weight block's index follows
+    the tile only through its expert, so over an expert's consecutive
+    tiles the pipeline keeps the block it holds and fetches none."""
+    if transpose_rhs:
+        rhs_spec = pl.BlockSpec((1, block_n, k), _gmm_rhs_t_index)
+    else:
+        rhs_spec = pl.BlockSpec((1, k, block_n), _gmm_rhs_index)
+    return (
+        (n // block_n, m // block_m),
+        [pl.BlockSpec((block_m, k), _gmm_lhs_index), rhs_spec],
+        pl.BlockSpec((block_m, block_n), _gmm_out_index),
+    )
+
+
+def _gmm_pallas(lhs, rhs, tile_group, block_m, transpose_rhs=False):
+    """out[tile t] = lhs[tile t] @ rhs[tile_group[t]]; with
+    `transpose_rhs`, @ rhs[tile_group[t]]^T, contracted on the weights'
+    last dimension as they are stored."""
     m, k = lhs.shape
-    e, _, n = rhs.shape
-    grid = (m // block_m, n // block_n)
+    n = rhs.shape[1 if transpose_rhs else 2]
+    block_n = _gmm_block_n(k, n, lhs.dtype.itemsize, block_m)
+    grid, in_specs, out_spec = _gmm_grid(
+        m, k, n, block_m, block_n, transpose_rhs
+    )
     return pl.pallas_call(
-        _gmm_kernel,
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_m, k), lambda i, j, tg: (i, 0)),
-                pl.BlockSpec((1, k, block_n), lambda i, j, tg: (tg[i], 0, j)),
-            ],
-            out_specs=pl.BlockSpec(
-                (block_m, block_n), lambda i, j, tg: (i, j)
-            ),
+            in_specs=in_specs,
+            out_specs=out_spec,
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
         interpret=_interpret(),
     )(tile_group, lhs, rhs)
+
+
+# Grid position (i, j, t): block i of the weight's rows, block j of its
+# columns, row tile t innermost.
+def _tgmm_lhs_index(i, j, t, tg):
+    return t, i
+
+
+def _tgmm_dout_index(i, j, t, tg):
+    return t, j
+
+
+def _tgmm_out_index(i, j, t, tg):
+    return tg[t], i, j
 
 
 def _tgmm_kernel(tg_ref, lhs_ref, dout_ref, drhs_ref, acc_scr):
@@ -73,6 +176,9 @@ def _tgmm_kernel(tg_ref, lhs_ref, dout_ref, drhs_ref, acc_scr):
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    # `+=` on the scratch is what Mosaic folds into the matmul's own
+    # accumulation; a product stored on an expert's first tile and added
+    # on the others costs a third more (PERF.md §6, PR 29).
     acc_scr[...] += jax.lax.dot_general(
         lhs_ref[...],
         dout_ref[...],
@@ -85,42 +191,63 @@ def _tgmm_kernel(tg_ref, lhs_ref, dout_ref, drhs_ref, acc_scr):
         drhs_ref[0] = acc_scr[...].astype(drhs_ref.dtype)
 
 
-def _tgmm_pallas(lhs, dout, tile_group, num_groups, block_k, block_n):
+def _tgmm_blocks(k: int, n: int, itemsize: int, block_m: int = 128) -> tuple:
+    """(block_k, block_n) of the weight gradient's block a grid step
+    accumulates: of those that fit the budget the pair that reads the
+    fewest input bytes (lhs once per column block, dout once per row
+    block); the whole [k, n] reads both once."""
+    def fits(bk, bn):
+        acc_and_out = (4 + 2 * itemsize) * bk * bn
+        return acc_and_out + 2 * block_m * (bk + bn) * itemsize <= _BLOCK_BUDGET
+
+    pairs = [(bk, bn) for bk in _blocks(k) for bn in _blocks(n)]
+    return min(
+        [p for p in pairs if fits(*p)] or pairs[-1:],
+        key=lambda p: k * (n // p[1]) + n * (k // p[0]),
+    )
+
+
+def _tgmm_grid(m, k, n, block_m, block_k, block_n):
+    """(grid, in_specs, out_spec) of a `_tgmm_kernel` call: the row
+    tiles innermost, so all tiles of one expert meet the same output
+    block consecutively and it is written once."""
+    return (
+        (k // block_k, n // block_n, m // block_m),
+        [
+            pl.BlockSpec((block_m, block_k), _tgmm_lhs_index),
+            pl.BlockSpec((block_m, block_n), _tgmm_dout_index),
+        ],
+        pl.BlockSpec((1, block_k, block_n), _tgmm_out_index),
+    )
+
+
+def _tgmm_pallas(lhs, dout, tile_group, num_groups, block_m):
     """drhs[e] = sum over m-tiles t with tile_group[t]==e of
-    lhs[t]^T @ dout[t].  Grid puts m innermost so all tiles of one
-    expert hit the same output block consecutively."""
+    lhs[t]^T @ dout[t]."""
     m, k = lhs.shape
     _, n = dout.shape
-    block_m = 128
-    grid = (k // block_k, n // block_n, m // block_m)
+    block_k, block_n = _tgmm_blocks(k, n, lhs.dtype.itemsize, block_m)
+    grid, in_specs, out_spec = _tgmm_grid(m, k, n, block_m, block_k, block_n)
     return pl.pallas_call(
         _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_m, block_k), lambda i, j, t, tg: (t, i)),
-                pl.BlockSpec((block_m, block_n), lambda i, j, t, tg: (t, j)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, block_k, block_n), lambda i, j, t, tg: (tg[t], i, j)
-            ),
+            in_specs=in_specs,
+            out_specs=out_spec,
             scratch_shapes=[pltpu.VMEM((block_k, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((num_groups, k, n), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
         interpret=_interpret(),
     )(tile_group, lhs, dout)
 
 
-def _pick_block(dim: int, preferred: int) -> int:
-    b = min(preferred, dim)
-    while dim % b:
-        b //= 2
-    return max(b, 1)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def gmm(lhs, rhs, tile_group, block_m: int = 128, block_n: int = 512):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def gmm(lhs, rhs, tile_group, block_m: int = 128):
     """Grouped matmul: out[t*bm:(t+1)*bm] = lhs[t*bm:(t+1)*bm] @
     rhs[tile_group[t]].
 
@@ -128,27 +255,24 @@ def gmm(lhs, rhs, tile_group, block_m: int = 128, block_n: int = 512):
     belongs to one group; rhs [E, K, N]; tile_group [M // block_m]
     int32. Differentiable in lhs and rhs.
     """
-    return _gmm_fwd(lhs, rhs, tile_group, block_m, block_n)[0]
+    return _gmm_fwd(lhs, rhs, tile_group, block_m)[0]
 
 
-def _gmm_fwd(lhs, rhs, tile_group, block_m, block_n):
-    bn = _pick_block(rhs.shape[2], block_n)
-    out = _gmm_pallas(lhs, rhs, tile_group, block_m, bn)
+def _gmm_fwd(lhs, rhs, tile_group, block_m):
+    out = _gmm_pallas(lhs, rhs, tile_group, block_m)
     return out, (lhs, rhs, tile_group)
 
 
-def _gmm_bwd(block_m, block_n, res, dout):
+def _gmm_bwd(block_m, res, dout):
     lhs, rhs, tile_group = res
-    e, k, n = rhs.shape
-    # dlhs: same kernel, per-expert-transposed weights.
-    bk = _pick_block(k, block_n)
+    # dlhs: the same kernel, contracting the weights' last dimension as
+    # they are stored.
     dlhs = _gmm_pallas(
-        dout, rhs.transpose(0, 2, 1), tile_group, block_m, bk
+        dout, rhs, tile_group, block_m, transpose_rhs=True
     ).astype(lhs.dtype)
     # drhs: group-accumulating transposed gmm.
     drhs = _tgmm_pallas(
-        lhs, dout, tile_group, e,
-        _pick_block(k, 512), _pick_block(n, 512),
+        lhs, dout, tile_group, rhs.shape[0], block_m
     ).astype(rhs.dtype)
     return dlhs, drhs, jnp.zeros(tile_group.shape, jax.dtypes.float0)
 
