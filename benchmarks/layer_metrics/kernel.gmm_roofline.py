@@ -1,17 +1,16 @@
 """The least time the chip could take for the grouped-matmul calls it
 executed, over the time they took on device 0.
 
-Each call is counted at what the mathematics needs (benchmarks/lib/
-flops_gmm.py gmm_call): the rows that hold a (token, expert) pair, batch x
-seq x experts per token of them, not the rows that pad an expert's segment
-to whole tiles; the two row arrays and every expert's matrix moved once.
-Every call of a layer has the hidden size and one expert's width as its two
-matrix dimensions, in either order, so all count alike; its floor is the
-larger of FLOPs over the bf16 peak and bytes over the HBM peak. A remat
-replay the compiler keeps is an executed call and counts. One device holds
-every expert (the gmm dispatch is per device)."""
+Each call is counted at what the configuration's ``kernels`` function states
+for its kernel (``benchmarks/lib/kernels_*.py``, through ``lib/flops_gmm.py
+gmm_call``): the rows that hold a (token, expert) pair computed on this
+device, not the rows that pad an expert's segment to whole tiles; the two row
+arrays and every held expert's matrix moved once. Its floor is the larger of
+FLOPs over the bf16 peak and bytes over the HBM peak. A remat replay the
+compiler keeps is an executed call and counts."""
 from benchmarks.lib import trace as tracing
-from benchmarks.lib.flops_gmm import GMM_KERNELS, gmm_call
+from benchmarks.lib.cells import stated_kernels
+from benchmarks.lib.flops_gmm import GMM_KERNELS
 from benchmarks.lib.peaks import peaks_for
 
 
@@ -25,15 +24,11 @@ def read(run):
     calls = [(e, k) for e, k in calls if k in GMM_KERNELS]
     if not calls:
         return None
-    config, traffic = run["cell"]["config"], run["cell"]["traffic"]
     peaks = peaks_for(run["setup"]["device_kind"])
-    pairs = traffic["batch"] * traffic["seq"] * config["num_experts_per_tok"]
+    stated = stated_kernels(run["cell"])
     floors = {}  # kernel -> (seconds by FLOPs, seconds by bytes) of one call
-    for kernel in GMM_KERNELS:
-        flops, nbytes = gmm_call(
-            kernel, pairs, config["hidden_size"], config["intermediate_size"],
-            config["num_experts"],
-        )
+    for kernel in {k for _, k in calls}:
+        flops, nbytes = stated[kernel]["call"]
         floors[kernel] = (flops / peaks["bf16_flops_per_s"],
                           nbytes / peaks["hbm_bytes_per_s"])
     floor = sum(max(floors[k]) for _, k in calls)

@@ -40,6 +40,12 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
     cell = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(os.path.join(root, configs[cell["config"]]["file"]))
+    if "kernels" not in config:
+        raise KeyError(
+            f"{configs[cell['config']]['file']} names no \"kernels\" function: "
+            "which Pallas kernels its step holds is the configuration's to "
+            "state, and there is no default"
+        )
     traffic = load_json(
         os.path.join(root, "benchmarks", "traffic", cell["traffic"] + ".json")
     )
@@ -67,6 +73,13 @@ def rehearsed(cell: dict) -> dict:
     for key in ("config", "traffic"):
         out[key] = {**cell[key], **cell[key].get("rehearsal", {})}
     return out
+
+
+def stated_kernels(cell: dict) -> dict:
+    """``{kernel name: {"least": n, "call": (FLOPs, HBM bytes)}}`` as the
+    configuration's ``kernels`` function states it for this cell's traffic
+    and mesh (``benchmarks/lib/kernels_flash.py`` says what each means)."""
+    return resolve(cell["config"]["kernels"])(cell["config"], cell["traffic"])
 
 
 def program_config(config: dict):

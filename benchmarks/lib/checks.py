@@ -3,7 +3,8 @@ from __future__ import annotations
 
 import math
 
-FLASH_KERNELS = ("_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel")
+from .cells import stated_kernels
+
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
@@ -38,8 +39,14 @@ class CompileCounter:
                 "backend_compiles": self.backend_compiles}
 
 
-def count_pallas_kernels(lowered_text: str) -> dict:
-    return {k: lowered_text.count(f'kernel_name = "{k}"') for k in FLASH_KERNELS}
+def count_pallas_kernels(lowered_text: str, names) -> dict:
+    return {k: lowered_text.count(f'kernel_name = "{k}"') for k in names}
+
+
+def holds_stated_kernels(counts: dict, stated: dict) -> bool:
+    """Every kernel the configuration states (``cells.stated_kernels``), at
+    least its stated count among the lowered step's ``counts``."""
+    return all(counts.get(kernel, 0) >= s["least"] for kernel, s in stated.items())
 
 
 def count_collectives(compiled_text: str) -> dict:
@@ -73,7 +80,7 @@ def logits_agreement(system_logits, reference_logits, tolerance: dict) -> dict:
 def decide(setup: dict, steps: list, final: dict, cell: dict) -> dict:
     """Every condition of ``correct`` by name; the run is correct when all
     hold. ``steps`` are the window's steps in order."""
-    traffic, config = cell["traffic"], cell["config"]
+    traffic = cell["traffic"]
     losses = [s["loss"] for s in steps]
     # A pass over the corpus, or half the window where it holds fewer than two.
     n = min(traffic["batches"], len(losses) // 2)
@@ -94,10 +101,8 @@ def decide(setup: dict, steps: list, final: dict, cell: dict) -> dict:
     if "moe_dispatch" in expected:
         checks["moe_dispatch"] = setup["moe_dispatch"] == expected["moe_dispatch"]
     if not setup["rehearsal"]:
-        # The flash forward and both backward kernels in every layer.
-        layers = config["num_hidden_layers"]
-        checks["pallas_kernels"] = all(
-            setup["pallas_kernels"][k] >= layers for k in FLASH_KERNELS
+        checks["pallas_kernels"] = holds_stated_kernels(
+            setup["pallas_kernels"], stated_kernels(cell)
         )
         checks["on_tpu"] = setup["platform"] == "tpu"
         if cell["chips"] > 1:

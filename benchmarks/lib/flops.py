@@ -7,7 +7,9 @@ nothing. The functions take the configuration file's own keys (the source's
 ``config.json`` names), so they never read the program's classes.
 
 A configuration names its function as ``benchmarks.lib.flops:<name>``; a new
-architecture brings a file of its own.
+architecture brings a file of its own. What one call of a kernel needs is
+counted here and in ``flops_gmm.py``; which calls a configuration's step
+holds, at which shapes, its ``kernels`` function says (``kernels_*.py``).
 """
 from __future__ import annotations
 
@@ -56,26 +58,33 @@ def moe_decoder(cfg: dict, seq: int) -> float:
     return 6.0 * (body + head) + causal_attention_flops_per_token(cfg, seq)
 
 
-# Matmuls of [T, T] extent in one flash-attention kernel call: the forward
-# computes scores and weighted values; the dk/dv kernel recomputes scores
-# and computes dP, dV, dK; the dq kernel recomputes scores and computes dP
-# and dQ.
-FLASH_MATMULS = {"_fwd_kernel": 2, "_bwd_dkv_kernel": 4, "_bwd_dq_kernel": 3}
+# Matmuls of [T, T] extent in one flash-attention kernel call, as (those that
+# contract or produce q/k's head dim, those of v's): the forward computes
+# scores and weighted values; the dk/dv kernel recomputes scores and computes
+# dK, and dP and dV; the dq kernel recomputes scores and computes dQ, and dP.
+FLASH_MATMULS = {"_fwd_kernel": (1, 1), "_bwd_dkv_kernel": (2, 2),
+                 "_bwd_dq_kernel": (2, 1)}
 
 
 def flash_call(kernel: str, bh: int, tq: int, tk: int, d: int,
-               causal: bool, itemsize: int = 2) -> tuple[float, float]:
+               causal: bool, itemsize: int = 2,
+               d_v: int | None = None) -> tuple[float, float]:
     """(FLOPs, HBM bytes) one call of a flash kernel needs: its [tq, tk]
     matmuls, the masked half of a causal block not counted; every operand
-    and result moved once."""
+    and result moved once. ``d`` is q's and k's head dim and ``d_v`` that of
+    v, o and do, ``d`` where it is not given."""
+    d_v = d if d_v is None else d_v
     pairs = tq * tk / 2 if causal else tq * tk
-    flops = FLASH_MATMULS[kernel] * 2.0 * bh * pairs * d
-    rows = {
+    n_qk, n_v = FLASH_MATMULS[kernel]
+    flops = 2.0 * bh * pairs * (n_qk * d + n_v * d_v)
+    qk_rows, v_rows = {
         # q, k, v in; o out (+ f32 lse)
-        "_fwd_kernel": 2 * tq + 2 * tk,
+        "_fwd_kernel": (tq + tk, tk + tq),
         # q, k, v, do in; dk, dv out
-        "_bwd_dkv_kernel": 2 * tq + 4 * tk,
+        "_bwd_dkv_kernel": (tq + 2 * tk, 2 * tk + tq),
         # q, k, v, do in; dq out
-        "_bwd_dq_kernel": 3 * tq + 2 * tk,
+        "_bwd_dq_kernel": (2 * tq + tk, tk + tq),
     }[kernel]
-    return flops, float(bh * rows * d * itemsize + bh * tq * 4)
+    return flops, float(
+        bh * (qk_rows * d + v_rows * d_v) * itemsize + bh * tq * 4
+    )

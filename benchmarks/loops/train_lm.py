@@ -72,7 +72,7 @@ def train_loop(run: dict) -> None:
     t_loop = time.time()  # the worker is up: ends control.worker_ready_s
 
     import jax
-    from benchmarks.lib.cells import program_config, resolve
+    from benchmarks.lib.cells import program_config, resolve, stated_kernels
     from benchmarks.lib.checks import (
         CompileCounter, count_collectives, count_pallas_kernels,
     )
@@ -144,7 +144,9 @@ def train_loop(run: dict) -> None:
         sharding = logical_sharding(mesh, ("batch", "seq"))
         ids, targets = jax.device_put((ids_all[0], targets_all[0]), sharding)
         lowered = step.lower(params, opt_state, ids, targets)
-        pallas_kernels = count_pallas_kernels(lowered.as_text())
+        pallas_kernels = count_pallas_kernels(
+            lowered.as_text(), stated_kernels(cell)
+        )
         t0 = phase("lower_s", t0)
         compiled = lowered.compile()
         t0 = phase("compile_s", t0)

@@ -5,8 +5,10 @@ described v5e topology, with no chip attached.
 
 What the chip's compiler would refuse (memory, a kernel it cannot tile or
 partition) it refuses here, at no chip time. Prints the compiler's memory
-analysis per device, the Pallas kernels in the lowered step and the
-collectives in the compiled one. Nothing runs, so nothing here is a time.
+analysis per device, the Pallas kernels the configuration states as the
+lowered step holds them, beside the least it states of each, and the
+collectives in the compiled one; exits 1 where a stated kernel is short.
+Nothing runs, so nothing here is a time.
 
 The program takes its kernels only where ``jax.default_backend()`` is the
 TPU, and here it is the CPU: this script steers them on by standing in for
@@ -73,7 +75,8 @@ def compile_cell(name: str, topo) -> dict:
     with jax.set_mesh(mesh):
         step = train.make_train_step(make_loss_fn(traffic, model), tx)
         lowered = step.lower(params, opt_state, batch, batch)
-        kernels = checks.count_pallas_kernels(lowered.as_text())
+        stated = cells.stated_kernels(cell)
+        kernels = checks.count_pallas_kernels(lowered.as_text(), stated)
         t0 = time.perf_counter()
         compiled = lowered.compile()
         seconds = time.perf_counter() - t0
@@ -86,6 +89,8 @@ def compile_cell(name: str, topo) -> dict:
         "aliased_gib": round(memory.alias_size_in_bytes / gib, 2),
         "temporaries_gib": round(memory.temp_size_in_bytes / gib, 2),
         "pallas_kernels": kernels,
+        "stated_least": {k: s["least"] for k, s in stated.items()},
+        "holds_stated_kernels": checks.holds_stated_kernels(kernels, stated),
         "collectives": checks.count_collectives(compiled.as_text()),
     }
 
@@ -104,9 +109,12 @@ def main(names) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     attention._on_tpu = ring_attention._on_tpu = lambda: True
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    held = True
     for name in names:
-        print(compile_cell(name, topo), flush=True)
-    return 0
+        found = compile_cell(name, topo)
+        print(found, flush=True)
+        held &= found["holds_stated_kernels"]
+    return int(not held)
 
 
 if __name__ == "__main__":

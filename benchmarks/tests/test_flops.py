@@ -48,6 +48,37 @@ def test_flash_call_counts_the_causal_half_and_each_operand_once():
     assert (dkv, dq) == (2 * f, 1.5 * f)
 
 
+@pytest.mark.parametrize("kernel, qk_matmuls, v_matmuls, qk_rows, v_rows", [
+    # scores q k^T contract q/k's dim and p v produces v's; q, k in at one
+    # width and v in, o out at the other
+    ("_fwd_kernel", 1, 1, 4096 + 4096, 4096 + 4096),
+    # scores again and dK = dS^T q; dP = do v^T and dV = p^T do; q, k in and
+    # dk out; v, do in and dv out
+    ("_bwd_dkv_kernel", 2, 2, 4096 + 2 * 4096, 2 * 4096 + 4096),
+    # scores again and dQ = dS k; dP; q, k in and dq out; v, do in
+    ("_bwd_dq_kernel", 2, 1, 2 * 4096 + 4096, 4096 + 4096),
+])
+def test_flash_call_takes_the_two_head_dims_apart(kernel, qk_matmuls, v_matmuls,
+                                                  qk_rows, v_rows):
+    # 64 (batch x head) blocks of 4096 x 4096, q and k of 192, v of 128
+    f, b = flops.flash_call(kernel, 64, 4096, 4096, 192, True, d_v=128)
+    pairs = 4096 * 4096 // 2
+    assert pairs == 8_388_608
+    assert f == 2 * 64 * pairs * (qk_matmuls * 192 + v_matmuls * 128)
+    assert b == 64 * 2 * (qk_rows * 192 + v_rows * 128) + 64 * 4096 * 4
+    # where the two are equal it is the count with one width, as before
+    for d in (64, 128):
+        one = flops.flash_call(kernel, 64, 4096, 4096, d, True)
+        assert one == flops.flash_call(kernel, 64, 4096, 4096, d, True, d_v=d)
+        assert one[0] == (qk_matmuls + v_matmuls) * 2 * 64 * pairs * d
+        assert one[1] == 64 * 2 * (qk_rows + v_rows) * d + 64 * 4096 * 4
+
+
+def test_the_forward_at_192_and_128_by_hand():
+    f, b = flops.flash_call("_fwd_kernel", 64, 4096, 4096, 192, True, d_v=128)
+    assert (f, b) == (343_597_383_680.0, 336_592_896.0)
+
+
 def test_an_unknown_device_kind_is_an_error():
     from benchmarks.lib.peaks import peaks_for
 

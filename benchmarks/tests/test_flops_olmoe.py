@@ -42,6 +42,30 @@ def test_gmm_call_counts_the_rows_that_hold_a_pair_and_each_operand_once(kernel)
     assert nbytes / 819e9 == pytest.approx(0.819e-3, rel=1e-2)
 
 
+def test_olmoe_states_its_kernels_counts_and_one_calls_need():
+    from benchmarks.lib.flops import flash_call
+    from benchmarks.lib.kernels_olmoe import olmoe_decoder as kernels
+
+    cell = cells.load_cell("olmoe-1b-7b-1chip.dropless-4k")
+    assert cells.resolve(cell["config"]["kernels"]) is kernels
+    stated = cells.stated_kernels(cell)
+    # three layers: the flash kernels once each, the three expert matmuls
+    # and their inputs' gradients through _gmm_kernel, the weights' through
+    # _tgmm_kernel; a kept replay of the forward is not asked for
+    assert {k: s["least"] for k, s in stated.items()} == {
+        "_fwd_kernel": 3, "_bwd_dkv_kernel": 3, "_bwd_dq_kernel": 3,
+        "_gmm_kernel": 18, "_tgmm_kernel": 9}
+    for kernel in ("_gmm_kernel", "_tgmm_kernel"):
+        assert stated[kernel]["call"] == gmm_call(kernel, 65536, 2048, 1024, 64)
+    # 2 sequences x 16 heads of 128 over the whole 4,096-token context
+    for kernel in ("_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel"):
+        assert stated[kernel]["call"] == flash_call(kernel, 32, 4096, 4096, 128, True)
+    # a mesh divides what one device's call takes
+    split = kernels(cell["config"], {**cell["traffic"], "mesh": {"seq": 2, "data": 2}})
+    assert split["_fwd_kernel"]["call"] == flash_call(
+        "_fwd_kernel", 16, 2048, 2048, 128, True)
+
+
 def test_gmm_call_knows_only_the_grouped_matmul():
     with pytest.raises(KeyError):
         gmm_call("_fwd_kernel", 1, 1, 1, 1)

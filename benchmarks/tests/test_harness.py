@@ -25,14 +25,14 @@ def made_up_run(cell, trace=False):
     steps = [{"t_start": i * 0.1, "t_dispatch": i * 0.1 + 0.01,
               "t_done": i * 0.1 + 0.1, "loss": 9.0 - 0.01 * i}
              for i in range(n)]
-    layers = cell["config"]["num_hidden_layers"]
     setup = {
         "kind": "setup", "rehearsal": False, "platform": "tpu",
         "device_kind": "TPU v5 lite", "device_count": cell["chips"],
         "mesh": {a: s for a, s in cell["traffic"]["mesh"].items() if s > 1},
         "moe_dispatch": cell["traffic"].get("expect", {}).get("moe_dispatch"),
-        "pallas_kernels": {"_fwd_kernel": 2 * layers, "_bwd_dkv_kernel": layers,
-                           "_bwd_dq_kernel": layers},
+        # what the configuration's function states, and a kept remat replay
+        "pallas_kernels": {k: (1 + (k == "_fwd_kernel")) * stated["least"]
+                           for k, stated in cells.stated_kernels(cell).items()},
         "collectives": None, "step_bytes": 12 * 2**30,
         "reference": {"ok": True}, "phases": {"compile_s": 7.0},
         "t_loop": 115.0, "t_ready": 140.0, "cache_dir": "x",
@@ -114,12 +114,29 @@ def test_a_wrong_run_is_not_correct():
     for i, step in enumerate(run["steps"]):
         step["loss"] = 9.0 + 0.01 * i
     assert result.result_line(run)[0]["correct"] is False
+    # an OLMoE step whose expert layer says "gmm" and holds no grouped matmul
+    cell = cells.load_cell("olmoe-1b-7b-1chip.dropless-4k")
+    run = made_up_run(cell)
+    assert run["setup"]["pallas_kernels"]["_gmm_kernel"] == 18
+    assert result.result_line(run)[0]["correct"] is True
+    del run["setup"]["pallas_kernels"]["_gmm_kernel"]
+    assert result.result_line(run)[0]["correct"] is False
+    run = made_up_run(cell)
+    run["setup"]["pallas_kernels"]["_tgmm_kernel"] = 8  # one of nine missing
+    assert result.result_line(run)[0]["correct"] is False
+
+
+def checkout(tmp_path):
+    """A copy of the benchmark's files for a test to add to."""
+    root = tmp_path / "checkout"
+    root.mkdir()
+    shutil.copytree(cells.BENCH_DIR, root / "benchmarks",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    return root
 
 
 def test_new_files_are_found_without_an_edit(tmp_path):
-    root = tmp_path / "checkout"
-    root.mkdir()
-    shutil.copytree(cells.BENCH_DIR, root / "benchmarks")
+    root = checkout(tmp_path)
     bench = benchmark()
     # a configuration, a traffic mix and a per-layer metric, each a new file
     config = cells.load_json(f"{cells.BENCH_DIR}/configs/mistral-7b-l4.json")
@@ -163,6 +180,161 @@ def test_new_files_are_found_without_an_edit(tmp_path):
     # required FLOPs follow the new files too
     flops = cells.resolve(cell["config"]["required_flops"])
     assert flops(cell["config"], 1024) < flops(old["config"], 1024)
+
+
+HYBRID_KERNELS = '''
+from benchmarks.lib.flops import FLASH_MATMULS, flash_call
+from benchmarks.lib.flops_gmm import gmm_call
+
+
+def required(config, seq):
+    return 1.5e9  # made up
+
+
+def hybrid(config, traffic):
+    full = config["full_attention_layers"]
+    bh = traffic["batch"] * config["num_attention_heads"]
+    stated = {
+        kernel: {"least": full, "call": flash_call(
+            kernel, bh, traffic["seq"], traffic["seq"], config["qk_head_dim"],
+            causal=True, d_v=config["v_head_dim"])}
+        for kernel in FLASH_MATMULS
+    }
+    # forward and backward of the other mixer, at a made-up cost
+    stated["_delta_chunk_kernel"] = {
+        "least": 2 * (config["num_hidden_layers"] - full), "call": (4e12, 6e8)}
+    held = config["num_experts_held"]
+    pairs = (traffic["batch"] * traffic["seq"] * config["num_experts_per_token"]
+             * held // config["num_routed_experts"])
+    for kernel, calls in (("_gmm_kernel", 6), ("_tgmm_kernel", 3)):
+        stated[kernel] = {
+            "least": calls * config["num_hidden_layers"],
+            "call": gmm_call(kernel, pairs, config["hidden_size"],
+                             config["moe_intermediate_size"], held)}
+    return stated
+'''
+
+
+def test_a_hybrid_is_taken_judged_and_measured_by_new_files_alone(tmp_path, monkeypatch):
+    """4 layers of which 1 calls the flash kernels at q/k 192, v 128 and 3
+    call a kernel of another name; 256 routed experts of which 16 are held.
+    Its configuration, traffic and ``kernels`` function are new files."""
+    import sys
+
+    import benchmarks.lib
+    from benchmarks.lib.trace import Event, Trace
+
+    root = checkout(tmp_path)
+    (root / "benchmarks/configs/hybrid-l4.json").write_text(json.dumps({
+        "name": "hybrid-l4", "source": "https://example.org/hybrid",
+        "hidden_size": 2304, "num_hidden_layers": 4, "full_attention_layers": 1,
+        "num_attention_heads": 32, "qk_head_dim": 192, "v_head_dim": 128,
+        "num_routed_experts": 256, "num_experts_held": 16,
+        "num_experts_per_token": 8, "moe_intermediate_size": 1024,
+        "reduced": {"num_hidden_layers": {}},
+        "required_flops": "benchmarks.lib.kernels_hybrid:required",
+        "kernels": "benchmarks.lib.kernels_hybrid:hybrid"}))
+    (root / "benchmarks/traffic/b2s8k.json").write_text(json.dumps({
+        "name": "b2s8k", "mesh": {}, "batch": 2, "seq": 8192, "batches": 16}))
+    (root / "benchmarks/lib/kernels_hybrid.py").write_text(HYBRID_KERNELS)
+    bench = benchmark()
+    bench["configs"].append({
+        "name": "hybrid-l4", "source": "https://example.org/hybrid",
+        "file": "benchmarks/configs/hybrid-l4.json",
+        "reduced": ["num_hidden_layers"], "why": "a test"})
+    bench["workloads"].append({
+        "name": "hybrid-l4.b2s8k", "config": "hybrid-l4", "traffic": "b2s8k",
+        "chips": 1, "why": "a test"})
+    for metric in bench["per_layer"]:
+        if metric["name"].startswith("kernel.gmm_"):
+            metric["workloads"] = metric["workloads"] + ["hybrid-l4.b2s8k"]
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    # no file that is there is edited
+    for directory, _, files in os.walk(cells.BENCH_DIR):
+        for name in files:
+            if "__pycache__" in directory:
+                continue
+            there = os.path.join(directory, name)
+            copy = root / "benchmarks" / os.path.relpath(there, cells.BENCH_DIR)
+            assert copy.read_bytes() == open(there, "rb").read(), there
+    # the copy's lib directory stands in for the repository's
+    monkeypatch.setattr(benchmarks.lib, "__path__",
+                        [*benchmarks.lib.__path__, str(root / "benchmarks/lib")])
+    monkeypatch.delitem(sys.modules, "benchmarks.lib.kernels_hybrid", raising=False)
+
+    cell = cells.load_cell("hybrid-l4.b2s8k", root=str(root))
+    stated = cells.stated_kernels(cell)
+    assert {k: s["least"] for k, s in stated.items()} == {
+        "_fwd_kernel": 1, "_bwd_dkv_kernel": 1, "_bwd_dq_kernel": 1,
+        "_delta_chunk_kernel": 6, "_gmm_kernel": 24, "_tgmm_kernel": 12}
+    # judged: correct with what its function states in the step (flash in one
+    # layer of four), and not with one stated kernel short
+    run = made_up_run(cell)
+    assert run["setup"]["pallas_kernels"]["_bwd_dq_kernel"] == 1
+    assert result.result_line(run)[0]["correct"] is True
+    for kernel in stated:
+        run = made_up_run(cell)
+        run["setup"]["pallas_kernels"][kernel] = stated[kernel]["least"] - 1
+        assert result.result_line(run)[0]["correct"] is False, kernel
+    # measured: two steps of a made-up trace in which every call takes twice
+    # its floor as counted by hand. 64 (batch x head) blocks of 8192 x 8192,
+    # causal half: the forward's two matmuls contract 192 and produce 128;
+    # q and k move at 192, v and o at 128, the lse in float32.
+    pairs = 64 * 8192 * 8192 // 2
+    assert stated["_fwd_kernel"]["call"] == (
+        2 * pairs * (192 + 128),
+        64 * 2 * (2 * 8192 * 192 + 2 * 8192 * 128) + 64 * 8192 * 4)
+    assert stated["_bwd_dkv_kernel"]["call"][0] == 2 * pairs * (2 * 192 + 2 * 128)
+    assert stated["_bwd_dq_kernel"]["call"][0] == 2 * pairs * (2 * 192 + 128)
+    # every call bound by compute on a v5e: FLOPs over 197e12
+    flash_floor = 2 * pairs * (320 + 640 + 512) / 197e12
+    assert flash_floor == pytest.approx(32.094e-3, rel=1e-4)
+    # 2 x 8192 tokens x top-8 = 131,072 pairs, a sixteenth of them here
+    assert stated["_gmm_kernel"]["call"] == (
+        2 * 8192 * 2304 * 1024, 2 * (8192 * (2304 + 1024) + 16 * 2304 * 1024))
+    gmm_floor = 2 * 8192 * 2304 * 1024 / 197e12
+    device, host, at = [], [], 0.0
+    for step in range(2):
+        start = at
+        for kernel, calls in (("_fwd_kernel", 1), ("_delta_chunk_kernel", 6),
+                              ("_gmm_kernel", 24), ("_tgmm_kernel", 12),
+                              ("_bwd_dkv_kernel", 1), ("_bwd_dq_kernel", 1)):
+            flops, nbytes = stated[kernel]["call"]
+            dur = 2 * max(flops / 197e12, nbytes / 819e9)
+            for i in range(calls):
+                device.append(Event(f"{kernel}.{step}.{i}", at, dur,
+                                    f"jit(train_step)/x kernel_name={kernel}"))
+                at += dur
+        host.append(Event("bench.step", start, at - start))
+    run = made_up_run(cell, trace=True)
+    run["trace_data"], run["notes"] = Trace({0: device}, {0: []}, host), []
+    kernel_metrics = [m for m in cell["per_layer"] if m["name"].startswith("kernel.")]
+    assert len(kernel_metrics) == 4
+    metrics = cells.read_metrics(
+        kernel_metrics, str(root / "benchmarks/layer_metrics"), run)
+    assert metrics["kernel.flash_roofline"]["value"] == pytest.approx(50.0)
+    assert f"6 calls, 6 of them bound by compute, the rest by bytes; floor " \
+           f"{2 * flash_floor:.4f} s" in run["notes"][0]
+    assert metrics["kernel.gmm_roofline"]["value"] == pytest.approx(50.0)
+    assert f"72 calls, 72 of them bound by compute, the rest by bytes; floor " \
+           f"{72 * gmm_floor:.4f} s" in run["notes"][1]
+    # counted as if every pair of the 256 experts were computed here, the
+    # same trace would read 16 times the work: an impossible share
+    assert 16 * metrics["kernel.gmm_roofline"]["value"] > 105
+    share = 100 * (72 * 2 * gmm_floor + 2 * 2 * flash_floor) / at
+    assert metrics["kernel.gmm_share"]["value"] + metrics[
+        "kernel.flash_share"]["value"] == pytest.approx(share)
+
+
+def test_a_configuration_without_a_kernels_function_is_an_error(tmp_path):
+    root = checkout(tmp_path)
+    path = root / "benchmarks/configs/mistral-7b-l4.json"
+    config = json.loads(path.read_text())
+    del config["kernels"]
+    path.write_text(json.dumps(config))
+    shutil.copy(os.path.join(cells.ROOT, "BENCHMARK.json"), root)
+    with pytest.raises(KeyError, match="kernels"):
+        cells.load_cell("mistral-7b-l4.sft512", root=str(root))
 
 
 def test_benchmark_json_meets_the_contract():

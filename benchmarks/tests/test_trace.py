@@ -128,3 +128,34 @@ def test_the_reduction_on_a_trace_recorded_on_the_chip():
         got = cells.load_reader(directory, metric).read(run)
         assert got == (None if value is None else pytest.approx(value)), metric
     assert "24 calls, 0 of them bound by compute" in run["notes"][-1]
+
+
+def test_the_grouped_matmul_readers_on_a_trace_recorded_on_the_chip():
+    """Two steps of olmoe-1b-7b-1chip.dropless-4k on a v5e chip (PR 30's
+    traced run of its parent commit, PR 29's program, cut by the parent's
+    ``trace record`` to its first two bench.step spans). The numbers are what
+    the parent's readers gave on it, when ``kernel.gmm_roofline`` still read
+    OLMoE's keys itself: a reader or a ``kernels`` function that moves them
+    has changed the yardstick."""
+    import gzip
+
+    from benchmarks.lib import cells
+
+    with gzip.open(os.path.join(DATA, "dropless4k_2steps.trace.json.gz"), "rt") as f:
+        trace = Trace.from_json(json.load(f))
+    calls = [tracing.kernel_of(e) for e in trace.devices[0]]
+    assert (calls.count("_gmm_kernel"), calls.count("_tgmm_kernel")) == (36, 18)
+    cell = cells.load_cell("olmoe-1b-7b-1chip.dropless-4k")
+    run = {"cell": cell, "trace_data": trace,
+           "notes": [], "setup": {"device_kind": "TPU v5 lite"}}
+    directory = os.path.join(cells.BENCH_DIR, "layer_metrics")
+    want = {
+        "kernel.gmm_roofline": 69.60775424919562,
+        "kernel.gmm_share": 26.282712968882855,
+        "kernel.flash_roofline": 54.767811258491115,
+        "kernel.flash_share": 8.351076039470653,
+    }
+    for metric, value in want.items():
+        got = cells.load_reader(directory, metric).read(run)
+        assert got == pytest.approx(value, rel=1e-12), metric
+    assert "54 calls, 54 of them bound by compute" in run["notes"][0]
