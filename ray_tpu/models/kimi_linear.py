@@ -1,0 +1,214 @@
+"""Kimi-Linear: a hybrid of gated delta-rule linear attention (KDA) and
+latent attention (MLA) layers over a sigmoid-routed mixture of experts.
+
+The model is llama.py's decoder body; what is here is its two mixers and
+the configuration that says which layer has which (moonshotai's
+``modeling_kimi.py`` and ``fla.layers.kda``, as far as they are known:
+the benchmark's configuration file lists what was assumed). No layer has
+a rotary embedding: KDA carries position in its state and MLA runs
+without one (``mla_use_nope``).
+
+KDA (``KDAMixer``): q, k, v projections, each through a causal depthwise
+convolution of 4 taps and SiLU; q and k L2-normalised per head; a
+per-channel log-decay g = -exp(A_log) softplus(W_f2 W_f1 x + dt_bias)
+through a low-rank map of the head dim; a write strength beta = sigmoid(W_b
+x) per head; the recurrence of ``ops/kda.py``; a per-head RMSNorm gated by
+sigmoid(W_g2 W_g1 x); the output projection.
+
+MLA (``MLAMixer``): q heads of 128 + 64; keys and values from a shared
+latent of 512 (down-projection, RMSNorm, up-projection to 128 + 128 a head)
+and one 64-wide key part shared by all heads; softmax attention with q/k
+heads of 192 and v heads of 128 through the flash kernels, K and V
+materialised (training).
+
+The expert layer is mixtral.py's ``MoELayer`` told to score by sigmoid, to
+scale its renormalised gates, to add a shared expert and to hold a range of
+the router's experts; the leading dense layer is llama.py's ``MLP``.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import flash_attention
+from ..ops.kda import chunk_kda, kda_gate, l2norm, short_conv
+from ..util import tracing
+from .llama import RMSNorm, weight_init
+from .mixtral import MixtralConfig, MixtralForCausalLM
+
+
+@dataclass(frozen=True)
+class KimiLinearConfig(MixtralConfig):
+    # Each layer's (mixer, ffn): "kda" or "mla", "mlp" or "moe".
+    layer_kinds: Tuple[Tuple[str, str], ...] = ()
+    kda_num_heads: int = 32
+    kda_head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    router_score: str = "sigmoid"
+    moe_dispatch: str = "gmm"
+    remat_policy: str = "nothing"
+    # Each KDA layer's replay keeps 512 MiB of chunk states at 16k tokens.
+    remat_prevent_cse: bool = True
+    router_aux_loss_coef: float = 0.0
+
+    @property
+    def layers(self):
+        return self.layer_kinds
+
+
+def kimi_linear_config(
+    *, linear_attn_config: dict, first_k_dense_replace: int,
+    moe_layer_freq: int, num_layers: int, num_experts_held: int,
+    expert_rank: int = 0, **fields,
+) -> KimiLinearConfig:
+    """The program's config from the source's keys: its nested
+    ``linear_attn_config`` (which layers, counted from 1, are KDA and which
+    full attention; KDA's heads, head dim and convolution), the leading dense
+    layers and the expert layers' frequency; and the deployment's: how many
+    of the router's experts a rank holds, and which rank this is."""
+    kda = set(linear_attn_config["kda_layers"])
+    full = set(linear_attn_config["full_attn_layers"])
+    kinds = []
+    for i in range(num_layers):
+        if i + 1 not in kda | full:
+            raise ValueError(f"layer {i + 1} is neither a KDA nor a full-attention layer")
+        sparse = i >= first_k_dense_replace and i % moe_layer_freq == 0
+        kinds.append(("kda" if i + 1 in kda else "mla", "moe" if sparse else "mlp"))
+    first = expert_rank * num_experts_held
+    return KimiLinearConfig(
+        num_layers=num_layers, layer_kinds=tuple(kinds),
+        kda_num_heads=linear_attn_config["num_heads"],
+        kda_head_dim=linear_attn_config["head_dim"],
+        short_conv_kernel_size=linear_attn_config["short_conv_kernel_size"],
+        experts_held=(first, first + num_experts_held), **fields,
+    )
+
+
+def _a_log_init(key, shape, dtype):
+    """fla's KDA: A uniform in [1, 16), kept as its logarithm."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype):
+    """fla's KDA (Mamba's): dt log-uniform in [0.001, 0.1], kept as its
+    inverse softplus."""
+    lo, hi = math.log(0.001), math.log(0.1)
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype) * (hi - lo) + lo)
+    dt = jnp.maximum(dt, 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _conv_init(key, shape, dtype):
+    """torch's Conv1d default for a depthwise filter of 4 taps:
+    uniform(-1/sqrt(taps), 1/sqrt(taps))."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def _dense(cfg, features, name, use_bias=False, dtype=None):
+    return nn.Dense(
+        features, use_bias=use_bias, dtype=dtype or cfg.dtype,
+        param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg), name=name,
+    )
+
+
+class KDAMixer(nn.Module):
+    cfg: KimiLinearConfig
+    mesh: Optional[Any] = None  # one device: the recurrence is not sharded
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.cfg
+        H, d = cfg.kda_num_heads, cfg.kda_head_dim
+        B, T, _ = x.shape
+        f32 = jnp.float32
+        proj = {n: _dense(cfg, H * d, f"{n}_proj", dtype=f32)(x) for n in "qkv"}
+        with tracing.scope(tracing.KDA_CONV):
+            q, k, v = (
+                nn.silu(short_conv(proj[n], self.param(
+                    f"{n}_conv", _conv_init,
+                    (cfg.short_conv_kernel_size, H * d), cfg.param_dtype,
+                ))).reshape(B, T, H, d)
+                for n in "qkv"
+            )
+        # The low-rank maps of the gates have the head dim as their width.
+        # The decay's comes out in float32: exp(A_log) is up to 16, the
+        # log-decay adds up over a chunk and is exponentiated, so a bfloat16
+        # rounding of it is a relative error of the state's whole horizon.
+        f = _dense(cfg, H * d, "f_b_proj", dtype=f32)(
+            _dense(cfg, d, "f_a_proj", dtype=f32)(x)
+        )
+        b = _dense(cfg, H, "b_proj", dtype=f32)(x)
+        with tracing.scope(tracing.KDA_GATE):
+            g = kda_gate(
+                f.reshape(B, T, H, d),
+                self.param("A_log", _a_log_init, (H,), f32),
+                self.param("dt_bias", _dt_bias_init, (H * d,), f32).reshape(H, d),
+            )
+            beta = jax.nn.sigmoid(b)
+        # The convolution, SiLU and normalisation stay in float32 (XLA fuses
+        # them into one pass over each projection); q, k and v are rounded
+        # once, where the kernel takes them.
+        with tracing.scope(tracing.KDA_SCAN):
+            q = (l2norm(q) * d ** -0.5).astype(cfg.dtype)
+            o = chunk_kda(q, l2norm(k).astype(cfg.dtype), v.astype(cfg.dtype), g, beta)
+        gate = _dense(cfg, H * d, "g_b_proj", use_bias=True)(
+            _dense(cfg, d, "g_a_proj")(x)
+        )
+        with tracing.scope(tracing.KDA_OUT_NORM):
+            o = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="o_norm")(o.astype(f32))
+            o = (o * jax.nn.sigmoid(gate.reshape(B, T, H, d).astype(f32))).astype(cfg.dtype)
+        return _dense(cfg, cfg.hidden_size, "o_proj")(o.reshape(B, T, H * d))
+
+
+class MLAMixer(nn.Module):
+    cfg: KimiLinearConfig
+    mesh: Optional[Any] = None
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.cfg
+        H, rank = cfg.num_heads, cfg.kv_lora_rank
+        nope, pe, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        heads = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+            (H, feats), axis=-1, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+            name=name,
+        )
+        q = heads(nope + pe, "q_proj")(x)  # [B, T, H, 192]: nope | pe
+        with tracing.scope(tracing.MLA_LATENT):
+            latent = _dense(cfg, rank + pe, "kv_a_proj")(x)
+            c = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="kv_a_norm")(
+                latent[..., :rank]
+            )
+            kv = heads(nope + dv, "kv_b_proj")(c)  # [B, T, H, 256]: k nope | v
+            # The 64-wide key part is one for all heads.
+            k_pe = jnp.broadcast_to(
+                latent[..., None, rank:], (*kv.shape[:3], pe)
+            )
+            k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+            v = kv[..., nope:]
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        o = flash_attention(q, k, v, causal=True, sm_scale=(nope + pe) ** -0.5)
+        return nn.DenseGeneral(
+            cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+            name="o_proj",
+        )(o.transpose(0, 2, 1, 3))
+
+
+class KimiLinearForCausalLM(MixtralForCausalLM):
+    """The decoder body of llama.py with a mixer and an FFN chosen per
+    layer (``KimiLinearConfig.layer_kinds``)."""
+
+    blocks = {**MixtralForCausalLM.blocks, tracing.KDA: KDAMixer,
+              tracing.MLA: MLAMixer}

@@ -65,10 +65,23 @@ class LlamaConfig:
     # "dots": save matmul outputs, recompute only elementwise — moves
     # memory, not time, where nothing is replayed.
     remat_policy: str = "nothing"
+    # True puts a barrier around each layer's replay, so that XLA neither
+    # merges it with its forward twin nor moves it ahead of the backward
+    # pass that needs it: a replay that keeps large residuals (a scan's
+    # per-chunk states) then lives for one layer's backward pass only.
+    remat_prevent_cse: bool = False
 
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def layers(self) -> Tuple[Tuple[str, str], ...]:
+        """Each layer's (mixer, ffn), by the names under which the model
+        class binds their modules (``LlamaForCausalLM.blocks``), which are
+        also their flax names. A family with one kind of layer says it
+        once; one with several kinds gives the tuple from its file."""
+        return (("attn", "mlp"),) * self.num_layers
 
     def num_params(self) -> int:
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
@@ -185,17 +198,21 @@ class Attention(nn.Module):
 
 class MLP(nn.Module):
     cfg: LlamaConfig
+    # The hidden width, cfg.intermediate_size where none is given (a
+    # shared expert has the routed experts' width, not the dense layer's).
+    width: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
+        width = self.width or cfg.intermediate_size
         dense = lambda feats, name: nn.Dense(  # noqa: E731
             feats, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
             name=name,
         )
-        gate = dense(cfg.intermediate_size, "gate_proj")(x)
-        up = dense(cfg.intermediate_size, "up_proj")(x)
+        gate = dense(width, "gate_proj")(x)
+        up = dense(width, "up_proj")(x)
         h = nn.silu(gate) * up
         h = with_logical_constraint(h, ("batch", "seq", "mlp"))
         return dense(cfg.hidden_size, "down_proj")(h)
@@ -203,15 +220,19 @@ class MLP(nn.Module):
 
 class DecoderLayer(nn.Module):
     cfg: LlamaConfig
-    # The FFN's flax name and its module, called as module(cfg, name=name).
+    # The mixer's and the FFN's flax names and modules, called as
+    # module(cfg, mesh=mesh, name=name)(x, positions) and
+    # module(cfg, name=name)(x).
+    mixer: Tuple[str, Any]
     ffn: Tuple[str, Any]
     mesh: Optional[Any] = None
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
+        mixer_name, mixer = self.mixer
         ffn_name, ffn = self.ffn
-        h = x + Attention(cfg, mesh=self.mesh, name="attn")(
+        h = x + mixer(cfg, mesh=self.mesh, name=mixer_name)(
             RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(x), positions
         )
         out = h + ffn(cfg, name=ffn_name)(
@@ -222,12 +243,14 @@ class DecoderLayer(nn.Module):
 
 class LlamaForCausalLM(nn.Module):
     """The decoder body of every family: embedding, layers, final norm,
-    head. A family differs in its layers' FFN alone and says so by
-    binding ``ffn`` (MixtralForCausalLM: the sparse layer, as ``moe``)."""
+    head. Each layer's mixer and FFN are the configuration's to name
+    (``cfg.layers``); a family binds the modules its names stand for in
+    ``blocks`` (MixtralForCausalLM: the sparse layer, as ``moe``)."""
 
     cfg: LlamaConfig
     mesh: Optional[Any] = None
-    ffn = ("mlp", MLP)  # a class attribute, not a field: no caller sets it
+    # A class attribute, not a field: no caller sets it.
+    blocks = {"attn": Attention, "mlp": MLP}
 
     @nn.compact
     def __call__(self, input_ids, positions=None, return_hidden=False):
@@ -265,12 +288,13 @@ class LlamaForCausalLM(nn.Module):
         layer_cls = DecoderLayer
         if cfg.remat:
             layer_cls = nn.remat(
-                DecoderLayer, prevent_cse=False,
+                DecoderLayer, prevent_cse=cfg.remat_prevent_cse,
                 policy=remat_policy(cfg),
             )
-        for i in range(cfg.num_layers):
+        for i, (mixer, ffn) in enumerate(cfg.layers):
             x = layer_cls(
-                cfg, self.ffn, mesh=self.mesh, name=f"layers_{i}"
+                cfg, (mixer, self.blocks[mixer]), (ffn, self.blocks[ffn]),
+                mesh=self.mesh, name=f"layers_{i}",
             )(x, positions)
         x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="final_norm")(x)
         if return_hidden:

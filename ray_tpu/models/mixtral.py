@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -27,7 +28,9 @@ from ..parallel import (
     ambient_axes, ambient_spec, logical_axis_shards, with_logical_constraint,
 )
 from ..util import tracing
-from .llama import LlamaConfig, LlamaForCausalLM, causal_lm_loss, weight_init
+from .llama import (
+    MLP, LlamaConfig, LlamaForCausalLM, causal_lm_loss, weight_init,
+)
 
 
 @dataclass(frozen=True)
@@ -70,6 +73,34 @@ class MixtralConfig(LlamaConfig):
     # "auto": the same as "capacity"; kept because the benchmark's
     # accepted Mixtral file sets it (ROADMAP C3).
     moe_dispatch: str = "capacity"
+    # One routed expert's width; intermediate_size where none is given (a
+    # model with dense layers beside its expert layers has both).
+    moe_intermediate_size: Optional[int] = None
+    # "softmax": scores are a softmax over the experts and the gates are
+    # the top-k scores (renormalised or not: norm_topk_prob). "sigmoid":
+    # scores are sigmoids; the top k of score + a selection bias (a
+    # parameter no gradient reaches) are chosen, and the gates are the
+    # chosen experts' scores, renormalised where norm_topk_prob says so,
+    # times routed_scaling_factor. No auxiliary loss is sown for it.
+    router_score: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # Experts every token passes, beside the routed ones: one SwiGLU of
+    # num_shared_experts x the routed width, under the flax name "shared".
+    num_shared_experts: int = 0
+    # The routed experts this device holds, [first, past the last) of the
+    # router's num_experts: one expert-parallel rank's share. The router
+    # keeps its width and its top-k; the layer computes the held experts'
+    # part of the result for the pairs routed to them and nothing stands
+    # in for the rest ("gmm" only). None: every expert.
+    experts_held: Optional[Tuple[int, int]] = None
+
+    @property
+    def layers(self):
+        return (("attn", "moe"),) * self.num_layers
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
 
     def num_params(self) -> int:
         """Llama count minus its dense MLP, plus E stacked experts and
@@ -301,18 +332,20 @@ def expert_ffn(expert_in, w_gate, w_up, w_down, counts, pairs):
     return _tiled_ffn(expert_in, w_gate, w_up, w_down, tiles, trips, rows)
 
 
-def _pair_slots(order, dst, m_pad, K):
+def _pair_slots(order, dst, m_pad, K, here=None):
     """``aligned_group_layout``'s permutation both ways, as two scalar
     scatters: ``slot_of_pair`` [S, K], the aligned slot of each (token, k)
     pair, pairs counted token-major, and ``pair_of_slot`` [m_pad], a
     padding slot holding S * K (the pair of token S, which is a zero
-    row)."""
+    row). ``here`` [N] bool, in sorted order: the pairs whose expert this
+    device holds; the slot of any other pair reads the zero row too."""
     N = order.shape[0]
     slot_of_pair = (
         jnp.zeros((N,), jnp.int32).at[order].set(dst, unique_indices=True)
     )
+    pair = order if here is None else jnp.where(here, order, N)
     pair_of_slot = (
-        jnp.full((m_pad,), N, jnp.int32).at[dst].set(order, unique_indices=True)
+        jnp.full((m_pad,), N, jnp.int32).at[dst].set(pair, unique_indices=True)
     )
     return slot_of_pair.reshape(N // K, K), pair_of_slot
 
@@ -431,6 +464,18 @@ class MoELayer(nn.Module):
         B, T, D = x.shape
         E, K = cfg.num_experts, cfg.num_experts_per_tok
         C = max(1, int(cfg.capacity_factor * T * K / E))
+        held = cfg.experts_held
+        if held is not None and dispatch != "gmm":
+            raise ValueError(
+                f"experts_held={held} needs moe_dispatch 'gmm', got "
+                f"{cfg.moe_dispatch!r}: the other branches lay out every "
+                "expert of the router"
+            )
+        if cfg.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"router_score must be 'softmax' or 'sigmoid', got "
+                f"{cfg.router_score!r}"
+            )
 
         # The four scopes below are the layer's names in a profile
         # (util/tracing.py); every dispatch branch uses the same four.
@@ -441,24 +486,40 @@ class MoELayer(nn.Module):
                 name="router",
             )
             logits = router(x.astype(jnp.float32))  # [B, T, E] — fp32 routing
-            probs = jax.nn.softmax(logits, axis=-1)
+            if cfg.router_score == "sigmoid":
+                # The selection bias moves which experts are chosen and
+                # never the gates; whatever balances the load updates it
+                # outside the gradient.
+                probs = jax.nn.sigmoid(logits)
+                bias = self.param(
+                    "router_bias", nn.initializers.zeros, (E,), jnp.float32
+                )
+                _, gate_idx = jax.lax.top_k(
+                    probs + jax.lax.stop_gradient(bias), K
+                )
+                gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)
+                gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B, T, K]
 
             # Top-k gates, renormalized over the chosen experts where
             # the architecture does (cfg.norm_topk_prob).
-            gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B, T, K]
             if cfg.norm_topk_prob:
                 gate_vals = gate_vals / jnp.maximum(
                     gate_vals.sum(-1, keepdims=True), 1e-9
                 )
+            if cfg.routed_scaling_factor != 1.0:
+                gate_vals = gate_vals * cfg.routed_scaling_factor
 
-            # Aux load-balance loss (Switch Transformer eq. 4): mean gate
-            # fraction x mean dispatch fraction per expert.
             onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [B,T,K,E]
             expert_mask = onehot.sum(2)  # [B, T, E] (0/1 per expert)
-            frac_tokens = expert_mask.mean(axis=(0, 1))
-            frac_probs = probs.mean(axis=(0, 1))
-            aux = E * jnp.sum(frac_tokens * frac_probs)
-            self.sow("intermediates", "router_aux_loss", aux)
+            if cfg.router_score == "softmax":
+                # Aux load-balance loss (Switch Transformer eq. 4): mean
+                # gate fraction x mean dispatch fraction per expert.
+                frac_tokens = expert_mask.mean(axis=(0, 1))
+                frac_probs = probs.mean(axis=(0, 1))
+                aux = E * jnp.sum(frac_tokens * frac_probs)
+                self.sow("intermediates", "router_aux_loss", aux)
 
         def pvar(name, shape):
             # flax's lecun_normal reads the stacked [E, in, out] as one
@@ -468,9 +529,24 @@ class MoELayer(nn.Module):
             # measured on; PERF.md §7).
             return self.param(name, weight_init(cfg), shape, cfg.param_dtype)
 
-        w_gate = pvar("w_gate", (E, D, cfg.intermediate_size))
-        w_up = pvar("w_up", (E, D, cfg.intermediate_size))
-        w_down = pvar("w_down", (E, cfg.intermediate_size, D))
+        # The experts held here: all of the router's, or its share.
+        E_w = E if held is None else held[1] - held[0]
+        w_gate = pvar("w_gate", (E_w, D, cfg.expert_width))
+        w_up = pvar("w_up", (E_w, D, cfg.expert_width))
+        w_down = pvar("w_down", (E_w, cfg.expert_width, D))
+
+        shared = None
+        if cfg.num_shared_experts:
+            with tracing.scope(tracing.MOE_SHARED):
+                shared = MLP(
+                    cfg, cfg.num_shared_experts * cfg.expert_width,
+                    name="shared",
+                )(x.astype(cfg.dtype))
+
+        def finish(out):
+            if shared is not None:
+                out = out + shared
+            return with_logical_constraint(out, ("batch", "seq", "embed"))
 
         if dispatch == "gmm":
             # Tile-aligned group-sorted dispatch through the pallas
@@ -485,28 +561,45 @@ class MoELayer(nn.Module):
                 # The index work, under a scope of its own so that a
                 # profile tells it from the row gather below.
                 with tracing.scope(tracing.MOE_LAYOUT):
+                    groups, here, tiles_used = gate_idx.reshape(N), None, None
+                    if held is not None:
+                        # The pairs of experts held elsewhere form one
+                        # more group, sorted last: the layout keeps its
+                        # static bound of N rows (a routing may send every
+                        # pair here), and the tiles from that group's first
+                        # on hold no row of this device's.
+                        here = (groups >= held[0]) & (groups < held[1])
+                        groups = jnp.where(here, groups - held[0], E_w)
                     order, dst, tile_group, m_pad = aligned_group_layout(
-                        gate_idx.reshape(N), E, block_m=128
+                        groups, E_w + (held is not None), block_m=128
                     )
+                    if held is not None:
+                        here = here[order]
+                        tiles_used = jnp.sum(
+                            tile_group < E_w, dtype=jnp.int32
+                        ).reshape(1)
+                        tile_group = jnp.minimum(tile_group, E_w - 1)
                     slot_of_pair, pair_of_slot = _pair_slots(
-                        order, dst, m_pad, K
+                        order, dst, m_pad, K, here
                     )
                 # Row GATHER into the aligned layout, and a gather back
                 # in its gradient (a row scatter-add costs 7-11 times a
                 # copy of the same rows on a v5e: PERF.md §6, PR 26).
                 lhs = _rows_to_slots(x2, slot_of_pair, pair_of_slot)
             with tracing.scope(tracing.MOE_EXPERTS):
-                h = gmm(lhs, w_gate.astype(cfg.dtype), tile_group)
-                u = gmm(lhs, w_up.astype(cfg.dtype), tile_group)
+                def grouped(rows, w):
+                    return gmm(rows, w, tile_group, 128, tiles_used)
+
+                h = grouped(lhs, w_gate.astype(cfg.dtype))
+                u = grouped(lhs, w_up.astype(cfg.dtype))
                 act = nn.silu(h) * u
-                eo = gmm(act, w_down.astype(cfg.dtype), tile_group)
+                eo = grouped(act, w_down.astype(cfg.dtype))
             with tracing.scope(tracing.MOE_COMBINE):
                 out2 = _slots_to_rows(
                     eo, gate_vals.astype(cfg.dtype).reshape(B * T, K),
                     slot_of_pair, pair_of_slot,
                 )
-                out = out2.reshape(B, T, D)
-                return with_logical_constraint(out, ("batch", "seq", "embed"))
+                return finish(out2.reshape(B, T, D))
 
         if dispatch == "ragged":
             # Exact-group dispatch: argsort the (token, k) pairs by
@@ -535,8 +628,7 @@ class MoELayer(nn.Module):
                     .at[tok_sorted]
                     .add(eo * gates_sorted[:, None])
                 )
-                out = out2.reshape(B, T, D)
-                return with_logical_constraint(out, ("batch", "seq", "embed"))
+                return finish(out2.reshape(B, T, D))
 
         NK = T * K
 
@@ -609,13 +701,13 @@ class MoELayer(nn.Module):
             out = jax.vmap(combine_one)(
                 expert_out, slot, gate_vals.astype(cfg.dtype).reshape(B, NK)
             )
-            return with_logical_constraint(out, ("batch", "seq", "embed"))
+            return finish(out)
 
 
 class MixtralForCausalLM(LlamaForCausalLM):
     """The decoder body of llama.py with the sparse FFN in every layer."""
 
-    ffn = ("moe", MoELayer)
+    blocks = {**LlamaForCausalLM.blocks, "moe": MoELayer}
 
 
 def moe_lm_loss(model: MixtralForCausalLM, params, input_ids, targets,

@@ -55,7 +55,9 @@ def attention_reference(
 ) -> jax.Array:
     """Plain XLA attention; also the numerics oracle for kernel tests.
 
-    Shapes: q [B, H, Tq, D]; k, v [B, Hkv, Tk, D] with H % Hkv == 0 (GQA).
+    Shapes: q [B, H, Tq, D]; k [B, Hkv, Tk, D]; v [B, Hkv, Tk, Dv] with
+    H % Hkv == 0 (GQA). Dv may differ from D (latent attention: q/k heads
+    of 192, v heads of 128); the default scale is D ** -0.5.
     """
     b, h, tq, d = q.shape
     hkv = k.shape[1]
@@ -140,7 +142,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q, block_k):
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, d_v = k.shape[1], v.shape[2]
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
     tq_p = (tq + block_q - 1) // block_q * block_q
@@ -167,29 +169,29 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
             # lse kept 3-D (bh, tq, 1) so the trailing dims satisfy TPU
             # tiling (block_q % 8, last dim == full dim).
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq_p, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, tq_p, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * tq_p * tk_p * d,
-            bytes_accessed=(q.size + k.size + v.size + bh * tq_p * d) * 2,
+            flops=2 * bh * tq_p * tk_p * (d + d_v),
+            bytes_accessed=(q.size + k.size + v.size + bh * tq_p * d_v) * 2,
             transcendentals=bh * tq_p * tk_p,
         ),
     )(q, k, v)
@@ -320,7 +322,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
                       block_q, block_k):
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, d_v = k.shape[1], v.shape[2]
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
     tq_p = (tq + block_q - 1) // block_q * block_q
@@ -341,8 +343,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
     lse3 = lse[..., None]
     delta3 = delta[..., None]
 
+    # q and k (and their gradients) have d lanes; v, do and dv have d_v.
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))
+    do_spec = pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, j, 0))
     kv_spec_i = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
+    v_spec_i = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, i, 0))
     row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -351,28 +356,30 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
         ),
         interpret=_interpret(),
         grid=(bh, tk_p // block_k, tq_p // block_q),
-        in_specs=[q_spec, kv_spec_i, kv_spec_i, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec_i, kv_spec_i],
+        in_specs=[q_spec, kv_spec_i, v_spec_i, do_spec, row_spec, row_spec],
+        out_specs=[kv_spec_i, v_spec_i],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk_p, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, tk_p, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=5 * bh * tq_p * tk_p * d,
+            flops=5 * bh * tq_p * tk_p * (d + d_v) // 2,
             bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
             transcendentals=bh * tq_p * tk_p,
         ),
     )(q, k, v, do, lse3, delta3)
 
     q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    do_spec2 = pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0))
     kv_spec_j = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    v_spec_j = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, j, 0))
     row_spec2 = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(
@@ -381,7 +388,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
         ),
         interpret=_interpret(),
         grid=(bh, tq_p // block_q, tk_p // block_k),
-        in_specs=[q_spec2, kv_spec_j, kv_spec_j, q_spec2, row_spec2, row_spec2],
+        in_specs=[q_spec2, kv_spec_j, v_spec_j, do_spec2, row_spec2, row_spec2],
         out_specs=q_spec2,
         out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -389,7 +396,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=5 * bh * tq_p * tk_p * d,
+            flops=5 * bh * tq_p * tk_p * (d + d_v) // 2,
             bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
             transcendentals=bh * tq_p * tk_p,
         ),
@@ -481,8 +488,10 @@ def flash_attention(
 ) -> jax.Array:
     """Blockwise (flash) attention.
 
-    q [B, H, Tq, D]; k, v [B, Hkv, Tk, D], GQA via H % Hkv == 0.
-    Uses the pallas kernel on TPU, XLA reference elsewhere.
+    q [B, H, Tq, D]; k [B, Hkv, Tk, D]; v [B, Hkv, Tk, Dv], GQA via
+    H % Hkv == 0. Dv may differ from D; ``sm_scale`` is the caller's,
+    D ** -0.5 where none is given. Uses the pallas kernel on TPU, XLA
+    reference elsewhere.
     """
     b, h, tq, d = q.shape
     tk = k.shape[2]
@@ -496,7 +505,8 @@ def flash_attention(
         )
     # The kernel needs >=8x128-tileable blocks; tiny shapes (unit tests,
     # short prompts) take the XLA path.
-    shapes_ok = tq >= 128 and tk >= 128 and d % 8 == 0
+    d_v = v.shape[-1]
+    shapes_ok = tq >= 128 and tk >= 128 and d % 8 == 0 and d_v % 8 == 0
     if not ((_on_tpu() and shapes_ok) or force_pallas):
         return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale)
     hkv = k.shape[1]
@@ -506,6 +516,6 @@ def flash_attention(
     scale = sm_scale if sm_scale is not None else 1.0 / d**0.5
     qf = q.reshape(b * h, tq, d)
     kf = k.reshape(b * h, -1, d)
-    vf = v.reshape(b * h, -1, d)
+    vf = v.reshape(b * h, -1, d_v)
     o = _flash(qf, kf, vf, causal, scale, block_q, block_k)
-    return o.reshape(b, h, tq, d)
+    return o.reshape(b, h, tq, d_v)

@@ -86,13 +86,40 @@ def _gmm_out_index(j, i, tg):
     return i, j
 
 
-def _gmm_kernel(tg_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs):
+def _gmm_kernel(tg_ref, *refs, transpose_rhs, bounded=False):
     # lhs @ rhs, or lhs @ rhs^T on the weights as stored.
+    used_ref, lhs_ref, rhs_ref, out_ref = refs if bounded else (None, *refs)
     contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
-    out_ref[...] = jax.lax.dot_general(
-        lhs_ref[...], rhs_ref[0], contract,
-        preferred_element_type=jnp.float32,
-    ).astype(out_ref.dtype)
+
+    def compute():
+        out_ref[...] = jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[0], contract,
+            preferred_element_type=jnp.float32,
+        ).astype(out_ref.dtype)
+
+    if not bounded:
+        compute()
+        return
+    # A tile past the used ones holds no row: its output is zeros, and no
+    # input block is fetched for it (`_used`).
+    pl.when(pl.program_id(1) < used_ref[0])(compute)
+
+    @pl.when(pl.program_id(1) >= used_ref[0])
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+
+def _used(index):
+    """``index`` for a call that is told how many row tiles hold rows
+    (``tiles_used``, the second prefetched scalar): a tile past them takes
+    the input block of the last used one, which the pipeline holds already
+    and does not fetch again."""
+    def bounded(*args):
+        *grid, tile = args[:-2]
+        tg, used = args[-2:]
+        return index(*grid, jnp.minimum(tile, used[0] - 1), tg)
+
+    return bounded
 
 
 def _gmm_block_n(k: int, n: int, itemsize: int, block_m: int = 128) -> int:
@@ -107,36 +134,49 @@ def _gmm_block_n(k: int, n: int, itemsize: int, block_m: int = 128) -> int:
     return next((bn for bn in blocks if fits(bn)), blocks[-1])
 
 
-def _gmm_grid(m, k, n, block_m, block_n, transpose_rhs=False):
+def _gmm_grid(m, k, n, block_m, block_n, transpose_rhs=False, bounded=False):
     """(grid, in_specs, out_spec) of a `_gmm_kernel` call. The row tiles
     are the grid's inner dimension and the weight block's index follows
     the tile only through its expert, so over an expert's consecutive
-    tiles the pipeline keeps the block it holds and fetches none."""
+    tiles the pipeline keeps the block it holds and fetches none.
+    ``bounded``: the call takes ``tiles_used`` (`_used`); every tile's
+    output block is still its own, and written."""
+    at = _used if bounded else (lambda index: index)
     if transpose_rhs:
-        rhs_spec = pl.BlockSpec((1, block_n, k), _gmm_rhs_t_index)
+        rhs_spec = pl.BlockSpec((1, block_n, k), at(_gmm_rhs_t_index))
     else:
-        rhs_spec = pl.BlockSpec((1, k, block_n), _gmm_rhs_index)
+        rhs_spec = pl.BlockSpec((1, k, block_n), at(_gmm_rhs_index))
+    out_index = _gmm_out_index
+    if bounded:
+        out_index = lambda j, i, tg, used: _gmm_out_index(j, i, tg)  # noqa: E731
     return (
         (n // block_n, m // block_m),
-        [pl.BlockSpec((block_m, k), _gmm_lhs_index), rhs_spec],
-        pl.BlockSpec((block_m, block_n), _gmm_out_index),
+        [pl.BlockSpec((block_m, k), at(_gmm_lhs_index)), rhs_spec],
+        pl.BlockSpec((block_m, block_n), out_index),
     )
 
 
-def _gmm_pallas(lhs, rhs, tile_group, block_m, transpose_rhs=False):
+def _gmm_pallas(lhs, rhs, tile_group, block_m, transpose_rhs=False,
+                tiles_used=None):
     """out[tile t] = lhs[tile t] @ rhs[tile_group[t]]; with
     `transpose_rhs`, @ rhs[tile_group[t]]^T, contracted on the weights'
-    last dimension as they are stored."""
+    last dimension as they are stored. With ``tiles_used`` [1] int32, tiles
+    from that one on are zeros and cost a block's write."""
     m, k = lhs.shape
     n = rhs.shape[1 if transpose_rhs else 2]
     block_n = _gmm_block_n(k, n, lhs.dtype.itemsize, block_m)
+    bounded = tiles_used is not None
     grid, in_specs, out_spec = _gmm_grid(
-        m, k, n, block_m, block_n, transpose_rhs
+        m, k, n, block_m, block_n, transpose_rhs, bounded
     )
+    scalars = (tile_group, tiles_used) if bounded else (tile_group,)
+    kernel = functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs)
+    if bounded:
+        kernel = functools.partial(kernel, bounded=True)
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(scalars),
             grid=grid,
             in_specs=in_specs,
             out_specs=out_spec,
@@ -147,7 +187,7 @@ def _gmm_pallas(lhs, rhs, tile_group, block_m, transpose_rhs=False):
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=_interpret(),
-    )(tile_group, lhs, rhs)
+    )(*scalars, lhs, rhs)
 
 
 # Grid position (i, j, t): block i of the weight's rows, block j of its
@@ -164,7 +204,10 @@ def _tgmm_out_index(i, j, t, tg):
     return tg[t], i, j
 
 
-def _tgmm_kernel(tg_ref, lhs_ref, dout_ref, drhs_ref, acc_scr):
+def _tgmm_kernel(tg_ref, *refs, bounded=False):
+    used_ref, lhs_ref, dout_ref, drhs_ref, acc_scr = (
+        refs if bounded else (None, *refs)
+    )
     im = pl.program_id(2)
     nm = pl.num_programs(2)
     # Both sides of logical_or are evaluated: the neighbour index is
@@ -179,12 +222,21 @@ def _tgmm_kernel(tg_ref, lhs_ref, dout_ref, drhs_ref, acc_scr):
     # `+=` on the scratch is what Mosaic folds into the matmul's own
     # accumulation; a product stored on an expert's first tile and added
     # on the others costs a third more (PERF.md §6, PR 29).
-    acc_scr[...] += jax.lax.dot_general(
-        lhs_ref[...],
-        dout_ref[...],
-        (((0,), (0,)), ((), ())),  # lhs^T @ dout
-        preferred_element_type=jnp.float32,
-    )
+    def accumulate():
+        acc_scr[...] += jax.lax.dot_general(
+            lhs_ref[...],
+            dout_ref[...],
+            (((0,), (0,)), ((), ())),  # lhs^T @ dout
+            preferred_element_type=jnp.float32,
+        )
+
+    if bounded:
+        # Tiles past the used ones belong to the last group (their
+        # tile_group says so), add nothing and fetch nothing (`_used`);
+        # the group's block is written when the grid ends.
+        pl.when(im < used_ref[0])(accumulate)
+    else:
+        accumulate()
 
     @pl.when(jnp.logical_or(im == nm - 1, tg_ref[next_im] != tg_ref[im]))
     def _flush():
@@ -207,31 +259,40 @@ def _tgmm_blocks(k: int, n: int, itemsize: int, block_m: int = 128) -> tuple:
     )
 
 
-def _tgmm_grid(m, k, n, block_m, block_k, block_n):
+def _tgmm_grid(m, k, n, block_m, block_k, block_n, bounded=False):
     """(grid, in_specs, out_spec) of a `_tgmm_kernel` call: the row
     tiles innermost, so all tiles of one expert meet the same output
     block consecutively and it is written once."""
+    at = _used if bounded else (lambda index: index)
+    out_index = _tgmm_out_index
+    if bounded:
+        out_index = lambda i, j, t, tg, used: _tgmm_out_index(i, j, t, tg)  # noqa: E731
     return (
         (k // block_k, n // block_n, m // block_m),
         [
-            pl.BlockSpec((block_m, block_k), _tgmm_lhs_index),
-            pl.BlockSpec((block_m, block_n), _tgmm_dout_index),
+            pl.BlockSpec((block_m, block_k), at(_tgmm_lhs_index)),
+            pl.BlockSpec((block_m, block_n), at(_tgmm_dout_index)),
         ],
-        pl.BlockSpec((1, block_k, block_n), _tgmm_out_index),
+        pl.BlockSpec((1, block_k, block_n), out_index),
     )
 
 
-def _tgmm_pallas(lhs, dout, tile_group, num_groups, block_m):
+def _tgmm_pallas(lhs, dout, tile_group, num_groups, block_m, tiles_used=None):
     """drhs[e] = sum over m-tiles t with tile_group[t]==e of
-    lhs[t]^T @ dout[t]."""
+    lhs[t]^T @ dout[t], over the first ``tiles_used`` tiles where that is
+    given."""
     m, k = lhs.shape
     _, n = dout.shape
     block_k, block_n = _tgmm_blocks(k, n, lhs.dtype.itemsize, block_m)
-    grid, in_specs, out_spec = _tgmm_grid(m, k, n, block_m, block_k, block_n)
+    bounded = tiles_used is not None
+    grid, in_specs, out_spec = _tgmm_grid(
+        m, k, n, block_m, block_k, block_n, bounded
+    )
+    scalars = (tile_group, tiles_used) if bounded else (tile_group,)
     return pl.pallas_call(
-        _tgmm_kernel,
+        functools.partial(_tgmm_kernel, bounded=True) if bounded else _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(scalars),
             grid=grid,
             in_specs=in_specs,
             out_specs=out_spec,
@@ -243,38 +304,46 @@ def _tgmm_pallas(lhs, dout, tile_group, num_groups, block_m):
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=_interpret(),
-    )(tile_group, lhs, dout)
+    )(*scalars, lhs, dout)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def gmm(lhs, rhs, tile_group, block_m: int = 128):
+def gmm(lhs, rhs, tile_group, block_m: int = 128, tiles_used=None):
     """Grouped matmul: out[t*bm:(t+1)*bm] = lhs[t*bm:(t+1)*bm] @
     rhs[tile_group[t]].
 
     lhs [M, K] with M % block_m == 0, rows sorted so each block_m tile
     belongs to one group; rhs [E, K, N]; tile_group [M // block_m]
     int32. Differentiable in lhs and rhs.
+
+    ``tiles_used`` [1] int32 (at least 1), where the layout is a static
+    bound that the rows do not fill: tiles from that one on hold no row.
+    Their output rows are zeros, their inputs are not read and their matmuls
+    not computed, forward and backward; ``tile_group`` names the last group
+    for them.
     """
-    return _gmm_fwd(lhs, rhs, tile_group, block_m)[0]
+    return _gmm_fwd(lhs, rhs, tile_group, block_m, tiles_used)[0]
 
 
-def _gmm_fwd(lhs, rhs, tile_group, block_m):
-    out = _gmm_pallas(lhs, rhs, tile_group, block_m)
-    return out, (lhs, rhs, tile_group)
+def _gmm_fwd(lhs, rhs, tile_group, block_m, tiles_used=None):
+    out = _gmm_pallas(lhs, rhs, tile_group, block_m, tiles_used=tiles_used)
+    return out, (lhs, rhs, tile_group, tiles_used)
 
 
 def _gmm_bwd(block_m, res, dout):
-    lhs, rhs, tile_group = res
+    lhs, rhs, tile_group, tiles_used = res
     # dlhs: the same kernel, contracting the weights' last dimension as
     # they are stored.
     dlhs = _gmm_pallas(
-        dout, rhs, tile_group, block_m, transpose_rhs=True
+        dout, rhs, tile_group, block_m, transpose_rhs=True,
+        tiles_used=tiles_used,
     ).astype(lhs.dtype)
     # drhs: group-accumulating transposed gmm.
     drhs = _tgmm_pallas(
-        lhs, dout, tile_group, rhs.shape[0], block_m
+        lhs, dout, tile_group, rhs.shape[0], block_m, tiles_used
     ).astype(rhs.dtype)
-    return dlhs, drhs, jnp.zeros(tile_group.shape, jax.dtypes.float0)
+    no_grad = lambda x: None if x is None else jnp.zeros(x.shape, jax.dtypes.float0)  # noqa: E731
+    return dlhs, drhs, no_grad(tile_group), no_grad(tiles_used)
 
 
 gmm.defvjp(_gmm_fwd, _gmm_bwd)

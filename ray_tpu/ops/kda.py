@@ -1,0 +1,448 @@
+"""Kimi Delta Attention (KDA): a gated delta rule with per-channel decay.
+
+Per head, with a float32 state S in R^{dk x dv}:
+
+    S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T
+    o_t = S_t^T q_t
+
+``a_t = exp(g_t)`` in (0, 1]^dk is the decay and ``b_t`` in (0, 1) the
+write strength. ``chunk_kda`` computes it in chunks of 64 tokens: inside a
+chunk the rule is a unit lower-triangular system (the WY form of the
+products of Householder-like factors), between chunks the state is carried.
+
+Inside a chunk, with G the running sum of g inside it:
+
+    A[t, s]   = b_t sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c])      s < t
+    Aqk[t, s] =     sum_c q_t[c] k_s[c] exp(G_t[c] - G_s[c])      s <= t
+    T = (I + A)^-1,  W = T (b k exp(G)),  U0 = T (b v)
+
+No exponent is ever positive, however strong the decay: entry (t, s) is
+computed at the level b in {1, 2, .., 32} at which t and s lie in the two
+halves of one block of 2b rows, as exp(G_t - r) * exp(r - G_s) with r the
+running sum at the first row of t's half, which lies between them. Each
+level is one masked [64, dk] x [dk, 64] matmul. G is one lower-triangular
+0/1 matrix times g, for every head at once (``_sum_matrix``); the running
+sum at the first row of every row's block of 2, 4, .., 64 rows follows from
+G in six steps of a sublane roll and a select (row t takes row t - s where
+bit s of t is set), so no level costs a matmul of its own. The inverse
+doubles the same way: the inverse X of the block diagonal at block size b
+gives that of 2b as X - X E X, E the blocks the doubling takes in (E X E =
+0), exact at every level, so there is no row-by-row substitution.
+
+Between chunks, with S the state at the chunk's start:
+
+    U = U0 - W S,   O = (q exp(G)) S + Aqk U,
+    S' = Diag(exp(G_last)) S + (k exp(G_last - G))^T U
+
+One function of 2-D values, ``_head_chunk``, is a chunk of one head from
+the state at its start to the state at its end. On a TPU the forward kernel
+walks the grid (batch, chunk, head) with every head's state in VMEM, in
+float32; at a chunk's first head it takes G for all heads in one matmul. Called under a gradient it also writes the state at every
+chunk's start (256 chunks x 32 heads x 64 KiB = 512 MiB a layer at 16k
+tokens, alive inside one layer's backward pass under the decoder's
+per-layer remat). The backward kernel walks the chunks in reverse with the
+states' cotangents in VMEM and differentiates ``_head_chunk`` where it
+stands (``jax.vjp`` inside the kernel, from the saved state: a replay of
+one chunk, nothing of a chunk's interior ever in HBM), then takes G's
+cotangent back to g's in one matmul. Elsewhere the same
+function runs under ``lax.scan`` and JAX differentiates it. Matmul operands
+are the inputs' dtype (bfloat16 in the models), accumulation, the running
+sums and the state float32.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import attention as _attention
+
+CHUNK = 64
+_LEVELS = (1, 2, 4, 8, 16, 32)
+F32 = jnp.float32
+# What a kernel may take of a v5e core's 128 MiB of VMEM (Mosaic's default
+# lets it use 16): the running sums of every head are 4 MiB at 32 heads of
+# 128, their cotangents as much, the states 2 MiB.
+_VMEM_LIMIT = 64 * 2**20
+
+
+def short_conv(x, w):
+    """Causal depthwise convolution over time, in float32. x [B, T, D]; w
+    [K, D], tap K - 1 on the current token: y_t = sum_i w[i] x_{t - (K - 1)
+    + i}."""
+    taps, t = w.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(F32), ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(padded[:, i:i + t] * w[i].astype(F32) for i in range(taps))
+
+
+def l2norm(x, eps: float = 1e-6):
+    """x / sqrt(sum x^2 + eps) over the last axis, in float32."""
+    xf = x.astype(F32)
+    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, -1, keepdims=True) + eps)
+
+
+def kda_gate(f, a_log, dt_bias):
+    """The per-channel log-decay g = -exp(A_log_h) softplus(f + dt_bias),
+    float32. f [B, T, H, dk]; a_log [H]; dt_bias [H, dk]."""
+    soft = jax.nn.softplus(f.astype(F32) + dt_bias.astype(F32))
+    return -jnp.exp(a_log.astype(F32))[:, None] * soft
+
+
+def _dot(a, b, contract):
+    precision = jax.lax.Precision.HIGHEST if a.dtype == F32 else None
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), preferred_element_type=F32,
+        precision=precision,
+    )
+
+
+def _nn(a, b):  # a @ b
+    return _dot(a, b, ((1,), (0,)))
+
+
+def _nt(a, b):  # a @ b^T
+    return _dot(a, b, ((1,), (1,)))
+
+
+def _tn(a, b):  # a^T @ b
+    return _dot(a, b, ((0,), (0,)))
+
+
+# ------------------------------------------------------- the running sums
+def _sum_matrix(dv: int) -> np.ndarray:
+    """[(2 * CHUNK + dv), CHUNK] of 0 and 1: the lower triangle with its
+    diagonal, then ones. Times g [CHUNK, .] its row blocks are G (the
+    running sum of g inside the chunk), G_last on CHUNK rows (beside G) and
+    G_last on ``dv`` rows (what scales the state, which lies [dv, dk])."""
+    t = np.arange(CHUNK)[:, None]
+    j = np.arange(CHUNK)[None, :]
+    return np.concatenate(
+        [j <= t, np.ones((CHUNK + dv, CHUNK), bool)]
+    ).astype(np.float32)
+
+
+def _tpu_roll(x, shift):
+    """``jnp.roll(x, shift, 0)`` inside a compiled kernel: a sublane
+    rotation, with the opposite rotation as its transpose."""
+    return _rotate(x, shift % x.shape[0])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rotate(x, shift):
+    return pltpu.roll(x, shift, 0)
+
+
+def _rotate_fwd(x, shift):
+    return _rotate(x, shift), None
+
+
+def _rotate_bwd(shift, _, g):
+    return (pltpu.roll(g, (g.shape[0] - shift) % g.shape[0], 0),)
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
+
+
+def _xla_roll(x, shift):
+    return jnp.roll(x, shift, 0)
+
+
+def _split3(x):
+    """x as three bfloat16 terms whose sum is x to float32's precision: a
+    0/1 matrix times each is exact on the MXU in one pass."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(F32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(F32)).astype(jnp.bfloat16)
+
+
+def _exact_matmul(m, x, contract):
+    """m (0/1, bfloat16) contracted with float32 x, exact to float32."""
+    return sum(
+        jax.lax.dot_general(m, part, (contract, ((), ())),
+                            preferred_element_type=F32)
+        for part in _split3(x)
+    )
+
+
+# ----------------------------------------------------- one chunk of one head
+
+
+def _masks():
+    """{b: [C, C] bool}: row t and column s lie in one block of 2b rows, t
+    in its second half and s in its first (over the six levels, the strict
+    lower triangle once), and the diagonal."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+    masks = {}
+    for level, b in enumerate(_LEVELS):
+        same = (row >> (level + 1)) == (col >> (level + 1))
+        masks[b] = same & (((row >> level) & 1) == 1) & (((col >> level) & 1) == 0)
+    return masks, row == col
+
+
+def _head_chunk(St, q, k, v, beta, G, last, last_dv, roll=_xla_roll):
+    """A chunk of one head. St [dv, dk] float32, the state at its start,
+    transposed so that the decay scales lanes; q, k [C, dk]; v [C, dv]; beta
+    [C, 1] float32; G [C, dk] the running sum of g, ``last`` its last row
+    on C rows and ``last_dv`` on dv rows. -> (the state at its end, o [C,
+    dv])."""
+    dt = q.dtype
+    masks, eye = _masks()
+    rows = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, 1), 0)
+    kf, qf = k.astype(F32), q.astype(F32)
+    A = Aqk = jnp.zeros((CHUNK, CHUNK), F32)
+    start = G  # G at the first row of every row's block of b rows
+    for level, b in enumerate(_LEVELS):
+        second = ((rows >> level) & 1) == 1
+        to_row = jnp.exp(G - start) if b > 1 else 1.0
+        # For a row of a first half: from it to the first row of the second
+        # half. (The rows of a second half are masked out as columns.)
+        ahead = jnp.where(second, 0.0, jnp.minimum(roll(start, -b) - G, 0.0))
+        ks = (kf * jnp.exp(ahead)).astype(dt)
+        A = A + jnp.where(masks[b], _nt((kf * to_row).astype(dt), ks), 0.0)
+        Aqk = Aqk + jnp.where(masks[b], _nt((qf * to_row).astype(dt), ks), 0.0)
+        start = jnp.where(second, roll(start, b), start)
+    Aqk = Aqk + jnp.where(eye, _nt(q, k), 0.0)
+    A = A * beta
+    # (I + A)^-1 by doubling; at block size 1 the block diagonal is I.
+    X = jnp.where(eye, 1.0, 0.0) - jnp.where(masks[1], A, 0.0)
+    for b in _LEVELS[1:]:
+        E = jnp.where(masks[b], A, 0.0).astype(dt)
+        X = X - _nn(X.astype(dt), _nn(E, X.astype(dt)).astype(dt))
+    T = X.astype(dt)
+    w = _nn(T, (kf * jnp.exp(G) * beta).astype(dt)).astype(dt)
+    u0 = _nn(T, (v.astype(F32) * beta).astype(dt))
+    sd = St.astype(dt)
+    u = (u0 - _nt(w, sd)).astype(dt)
+    o = _nt((qf * jnp.exp(G)).astype(dt), sd) + _nn(Aqk.astype(dt), u)
+    kd = (kf * jnp.exp(last - G)).astype(dt)
+    return jnp.exp(last_dv) * St + _tn(u, kd), o
+
+
+# ------------------------------------------------------------ Pallas kernels
+# Grid (batch, chunk, head), the heads innermost: every head's state stays
+# in VMEM over a sequence's chunks, and the running sums of all heads are
+# taken once a chunk, at its first head.
+
+
+def _head_sums(d_scr, dk, dv):
+    """Head ``program_id(2)``'s G, G_last on CHUNK rows and G_last on dv
+    rows: row blocks of its ``dk`` lanes in the [2 * CHUNK + dv, H * dk]
+    scratch of running sums, as index tuples."""
+    lanes = pl.ds(pl.multiple_of(pl.program_id(2) * dk, dk), dk)
+    return [(pl.ds(0, CHUNK), lanes), (pl.ds(CHUNK, CHUNK), lanes),
+            (pl.ds(2 * CHUNK, dv), lanes)]
+
+
+def _roll_here():
+    """The interpreter runs a kernel's body as XLA does."""
+    return _xla_roll if _attention._interpret() else _tpu_roll
+
+
+def _take_sums(m_ref, g_ref, d_scr):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        d_scr[...] = _exact_matmul(m_ref[...], g_ref[0], ((1,), (0,)))
+
+
+def _kda_fwd_kernel(m_ref, g_ref, q_ref, k_ref, v_ref, beta_ref, o_ref, *rest):
+    # rest: (the states' output, the two scratches) or the scratches alone.
+    s_ref, d_scr, st_scr = rest if len(rest) == 3 else (None, *rest)
+    head = pl.program_id(2)
+    dv, dk = st_scr.shape[1:]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        st_scr[head] = jnp.zeros((dv, dk), F32)
+
+    _take_sums(m_ref, g_ref, d_scr)
+    St = st_scr[head]
+    if s_ref is not None:
+        s_ref[0, 0] = St
+    st_scr[head], o = _head_chunk(
+        St, q_ref[0], k_ref[0], v_ref[0], beta_ref[0, 0],
+        *(d_scr[at] for at in _head_sums(d_scr, dk, dv)), roll=_roll_here(),
+    )
+    o_ref[0] = o.astype(o_ref.dtype)
+
+
+def _kda_bwd_kernel(m_ref, mt_ref, g_ref, q_ref, k_ref, v_ref, beta_ref,
+                    s_ref, do_ref, dq_ref, dk_ref, dv_ref, dbeta_ref, dg_ref,
+                    d_scr, dd_scr, dst_scr):
+    head = pl.program_id(2)
+    dv, dk = dst_scr.shape[1:]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        dst_scr[head] = jnp.zeros((dv, dk), F32)
+
+    _take_sums(m_ref, g_ref, d_scr)
+    sums = _head_sums(d_scr, dk, dv)
+    _, vjp = jax.vjp(
+        functools.partial(_head_chunk, roll=_roll_here()), s_ref[0, 0],
+        q_ref[0], k_ref[0], v_ref[0], beta_ref[0, 0],
+        *(d_scr[at] for at in sums),
+    )
+    dst, dq, dk_, dv_, dbeta, *dsums = vjp(
+        (dst_scr[head], do_ref[0].astype(F32))
+    )
+    dst_scr[head] = dst
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dk_ref[0] = dk_.astype(dk_ref.dtype)
+    dv_ref[0] = dv_.astype(dv_ref.dtype)
+    dbeta_ref[0, 0] = dbeta
+    for at, ds in zip(sums, dsums):
+        dd_scr[at] = ds
+
+    @pl.when(head == pl.num_programs(2) - 1)
+    def _dg():
+        dg_ref[0] = _exact_matmul(mt_ref[...], dd_scr[...], ((1,), (0,)))
+
+
+def _specs(heads, dk, dv, chunk_of):
+    """BlockSpecs over grid (batch, step, head), ``chunk_of(step)`` the
+    chunk a step works on. Rows lie [B, T, H * d]: a block is one chunk's
+    rows of one head's lanes, or of all heads' (g, whose running sums are
+    taken for all heads at once). beta lies [B, H, T, 1] and the states
+    [B, N, dv, H * dk]."""
+    def rows(d):
+        return pl.BlockSpec((1, CHUNK, d), lambda b, n, h: (b, chunk_of(n), h))
+
+    whole = lambda *shape: pl.BlockSpec(shape, lambda b, n, h: (0,) * len(shape))  # noqa: E731
+    return {
+        "k": rows(dk), "v": rows(dv),
+        "g": pl.BlockSpec((1, CHUNK, heads * dk),
+                          lambda b, n, h: (b, chunk_of(n), 0)),
+        "beta": pl.BlockSpec((1, 1, CHUNK, 1),
+                             lambda b, n, h: (b, h, chunk_of(n), 0)),
+        "state": pl.BlockSpec((1, 1, dv, dk),
+                              lambda b, n, h: (b, chunk_of(n), 0, h)),
+        "m": whole(2 * CHUNK + dv, CHUNK), "mt": whole(CHUNK, 2 * CHUNK + dv),
+    }
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT,
+    )
+
+
+def _forward_pallas(q, k, v, g, beta, heads, states):
+    batch, t, _ = q.shape
+    dk, dv, n = q.shape[2] // heads, v.shape[2] // heads, t // CHUNK
+    s = _specs(heads, dk, dv, lambda i: i)
+    m = jnp.asarray(_sum_matrix(dv), jnp.bfloat16)
+    return pl.pallas_call(
+        _kda_fwd_kernel,
+        grid=(batch, n, heads),
+        in_specs=[s["m"], s["g"], s["k"], s["k"], s["v"], s["beta"]],
+        out_specs=[s["v"], s["state"]][:1 + states],
+        out_shape=[
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((batch, n, dv, heads * dk), F32),
+        ][:1 + states],
+        scratch_shapes=[pltpu.VMEM((m.shape[0], heads * dk), F32),
+                        pltpu.VMEM((heads, dv, dk), F32)],
+        compiler_params=_params(),
+        interpret=_attention._interpret(),
+    )(m, g, q, k, v, beta)
+
+
+def _backward_pallas(q, k, v, g, beta, states, do, heads):
+    batch, t, _ = q.shape
+    dk, dv, n = q.shape[2] // heads, v.shape[2] // heads, t // CHUNK
+    s = _specs(heads, dk, dv, lambda i: n - 1 - i)
+    m = _sum_matrix(dv)
+    sums = pltpu.VMEM((m.shape[0], heads * dk), F32)
+    return pl.pallas_call(
+        _kda_bwd_kernel,
+        grid=(batch, n, heads),
+        in_specs=[s["m"], s["mt"], s["g"], s["k"], s["k"], s["v"], s["beta"],
+                  s["state"], s["v"]],
+        out_specs=[s["k"], s["k"], s["v"], s["beta"], s["g"]],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (q, k, v, beta, g)],
+        scratch_shapes=[sums, sums, pltpu.VMEM((heads, dv, dk), F32)],
+        compiler_params=_params(),
+        interpret=_attention._interpret(),
+    )(jnp.asarray(m, jnp.bfloat16), jnp.asarray(m.T, jnp.bfloat16),
+      g, q, k, v, beta, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kda_pallas(q, k, v, g, beta, heads):
+    """o [B, T, H * dv] from q, k, g [B, T, H * dk], v [B, T, H * dv] and
+    beta [B, H, T, 1], T a whole number of chunks.
+
+    Called outside a gradient it writes no states: a call that did would be
+    the twin of the one a remat replay makes, XLA would merge the two, and
+    every layer's 512 MiB of states would live from the forward pass to the
+    backward."""
+    return _forward_pallas(q, k, v, g, beta, heads, states=False)[0]
+
+
+def _kda_pallas_fwd(q, k, v, g, beta, heads):
+    o, states = _forward_pallas(q, k, v, g, beta, heads, states=True)
+    return o, (q, k, v, g, beta, states)
+
+
+def _kda_pallas_bwd(heads, residuals, do):
+    dq, dk, dv, dbeta, dg = _backward_pallas(
+        *residuals, do.astype(residuals[2].dtype), heads
+    )
+    return dq, dk, dv, dg, dbeta
+
+
+_kda_pallas.defvjp(_kda_pallas_fwd, _kda_pallas_bwd)
+
+
+def _kda_xla(q, k, v, g, beta, heads):
+    """The same function of the same layouts under ``lax.scan``, for JAX to
+    differentiate: where there is no TPU."""
+    batch, t, _ = q.shape
+    n = t // CHUNK
+    dv = v.shape[2] // heads
+    m = jnp.asarray(_sum_matrix(dv))
+
+    def chunks(x):  # [B, T, H * d] -> [N, B, H, C, d]
+        return x.reshape(batch, n, CHUNK, heads, -1).transpose(1, 0, 3, 2, 4)
+
+    sums = jnp.einsum("rc,nbhcd->nbhrd", m, chunks(g),
+                      precision=jax.lax.Precision.HIGHEST)
+    beta = beta.reshape(batch, heads, n, CHUNK, 1).transpose(2, 0, 1, 3, 4)
+
+    def one(St, q, k, v, beta, d):
+        return _head_chunk(St, q, k, v, beta, d[:CHUNK], d[CHUNK:2 * CHUNK],
+                           d[2 * CHUNK:])
+
+    def step(St, chunk):
+        return jax.vmap(jax.vmap(one))(St, *chunk)
+
+    start = jnp.zeros((batch, heads, dv, q.shape[2] // heads), F32)
+    _, o = jax.lax.scan(step, start, (chunks(q), chunks(k), chunks(v), beta, sums))
+    return o.transpose(1, 0, 3, 2, 4).reshape(batch, t, heads * dv).astype(v.dtype)
+
+
+def chunk_kda(q, k, v, g, beta):
+    """The recurrence at the top of this file, chunked. q, k [B, T, H, dk]
+    (q already scaled, both already normalised); v [B, T, H, dv]; g [B, T,
+    H, dk] float32 log-decay (<= 0); beta [B, T, H] in (0, 1). Returns o
+    [B, T, H, dv] in v's dtype. Differentiable in all five."""
+    batch, t, heads, _ = q.shape
+    pad = -t % CHUNK
+    if pad:
+        # Padding tokens write nothing (beta 0) and decay nothing (g 0).
+        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+        q, k, v, g = (jnp.pad(x, widths) for x in (q, k, v, g))
+        beta = jnp.pad(beta, widths[:3])
+    flat = lambda x: x.reshape(batch, t + pad, -1)  # noqa: E731
+    beta = beta.astype(F32).transpose(0, 2, 1)[..., None]  # [B, H, T', 1]
+    run = _kda_pallas if _attention._on_tpu() or _attention._interpret() else _kda_xla
+    o = run(flat(q), flat(k), flat(v), flat(g.astype(F32)), beta, heads)
+    return o.reshape(batch, t + pad, heads, -1)[:, :t]

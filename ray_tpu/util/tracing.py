@@ -40,8 +40,19 @@ MOE_EXPERTS = "experts"  # the three expert matmuls and the activation
 MOE_COMBINE = "combine"  # gather back, gate scaling, the reduction over k
 MOE_LAYOUT = "layout"  # inside dispatch, "gmm" only: sort, tile layout, inverse map
 QK_NORM = "qk_norm"  # RMSNorm of the whole q and k projections (cfg.qk_norm)
+MOE_SHARED = "shared"  # inside moe: the shared expert every token passes
+# The mixers' flax names, which reach op_name as the attention's "attn" does.
+KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer)
+MLA = "mla"  # the latent-attention mixer (models/kimi_linear.py MLAMixer)
+KDA_CONV = "conv"  # inside kda: the short convolutions of q, k, v and their SiLU
+KDA_GATE = "gate"  # inside kda: the log-decay g and the write strength beta
+KDA_SCAN = "scan"  # inside kda: q/k normalisation and ops/kda.py chunk_kda
+KDA_OUT_NORM = "out_norm"  # inside kda: the per-head RMSNorm and the output gate
+MLA_LATENT = "latent"  # inside mla: down-projection, norm, up-projection of K/V
 SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
-          MOE_LAYOUT, QK_NORM)
+          MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
+          KDA_OUT_NORM, MLA_LATENT)
+MIXERS = (KDA, MLA)  # flax module names, bound in KimiLinearForCausalLM.blocks
 
 _OFF = contextlib.nullcontext()
 

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from ray_tpu.ops import kda
 from ray_tpu.ops.attention import _flash_bwd_pallas, _flash_fwd_pallas
 from ray_tpu.ops.gmm import gmm
 
@@ -87,3 +88,103 @@ def test_gmm_and_its_gradient_compile_for_v5e(v5e, m, experts, k, n):
         )(lhs, rhs),
         *operands,
     )
+
+
+# Kimi-Linear's MLA layer (the benchmark's longctx-16k cell): 32 heads over
+# 16,384 tokens, q/k heads of 128 + 64 and v heads of 128.
+def test_flash_at_192_and_128_compiles_for_v5e(v5e):
+    bh, t, d, d_v, block = 32, 16384, 192, 128, 1024
+    qk, v = ((bh, t, d), jnp.bfloat16), ((bh, t, d_v), jnp.bfloat16)
+    args = dict(causal=True, sm_scale=d**-0.5, block_q=block, block_k=block)
+    _compile_for(v5e, lambda q, k, v: _flash_fwd_pallas(q, k, v, **args), qk, qk, v)
+    _compile_for(
+        v5e,
+        lambda q, k, v, o, lse, do: _flash_bwd_pallas(q, k, v, o, lse, do, **args),
+        qk, qk, v, v, ((bh, t), jnp.float32), v,
+    )
+
+
+# The same cell's expert layer: 16 held experts of 2304 x 1024 over a
+# layout bounded at every pair of 16,384 tokens x top-8 (+ 17 tiles), told
+# how many tiles hold rows.
+@pytest.mark.parametrize("k,n", [(2304, 1024), (1024, 2304)])
+def test_bounded_gmm_and_its_gradient_compile_for_v5e(v5e, k, n):
+    m, experts = 16384 * 8 + 17 * 128, 16
+    text = _compile_for(
+        v5e,
+        lambda lhs, rhs, tg, used: jax.grad(
+            lambda a, b: gmm(a, b, tg, 128, used).astype(jnp.float32).sum(), (0, 1)
+        )(lhs, rhs),
+        ((m, k), jnp.bfloat16), ((experts, k, n), jnp.bfloat16),
+        ((m // 128,), jnp.int32), ((1,), jnp.int32),
+    )
+    assert text.count("tpu_custom_call") >= 2  # dlhs and drhs
+
+
+# The same cell's KDA layers: 32 heads of 128 over 16,384 tokens, the
+# forward kernel (with and without the states) and the backward kernel,
+# which differentiates a chunk inside the kernel.
+def test_kda_kernels_compile_for_v5e(v5e):
+    b, t, h, d = 1, 16384, 32, 128
+    rows = ((b, t, h * d), jnp.bfloat16)
+    operands = (rows, rows, rows, ((b, t, h * d), jnp.float32),
+                ((b, h, t, 1), jnp.float32))
+    _compile_for(v5e, lambda *a: kda._forward_pallas(*a, h, states=False), *operands)
+    _compile_for(v5e, lambda *a: kda._forward_pallas(*a, h, states=True), *operands)
+    _compile_for(
+        v5e, lambda *a: kda._backward_pallas(*a, h), *operands,
+        ((b, t // kda.CHUNK, d, h * d), jnp.float32), rows,
+    )
+
+
+# The models the benchmark already had lower to the Pallas kernels they had
+# before a layer could choose its mixer and FFN: read by this same code at
+# commit 57913f4, each configuration file at its rehearsal size, b1 x s256.
+KERNELS_BEFORE = {
+    "mistral-7b-l4": {"_fwd_kernel": 4, "_bwd_dkv_kernel": 2, "_bwd_dq_kernel": 2},
+    "mixtral-8x7b-l2": {"_fwd_kernel": 4, "_bwd_dkv_kernel": 2, "_bwd_dq_kernel": 2},
+    "olmoe-1b-7b-1chip": {"_fwd_kernel": 4, "_bwd_dkv_kernel": 2, "_bwd_dq_kernel": 2,
+                          "_gmm_kernel": 18, "_tgmm_kernel": 6},
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS_BEFORE))
+def test_the_models_that_were_there_lower_to_the_kernels_they_had(v5e, monkeypatch, name):
+    import importlib
+
+    import numpy as np
+    import optax
+
+    from benchmarks.lib import cells, checks
+    from ray_tpu import train
+    from ray_tpu.models.llama import causal_lm_loss
+    from ray_tpu.models.mixtral import moe_lm_loss
+
+    # The program takes its kernels where the backend is the TPU; here it is
+    # the CPU, and the test stands in for that one probe.
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    config = cells.load_json(f"{cells.BENCH_DIR}/configs/{name}.json")
+    config = {**config, **config["rehearsal"]}
+    cfg = cells.program_config(config)
+    model = cells.resolve(config["program"]["model"])(cfg)
+    shapes = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e), tree)
+
+    if hasattr(cfg, "num_experts"):
+        loss = lambda p, ids, t: moe_lm_loss(model, p, ids, t)  # noqa: E731
+    else:
+        loss = lambda p, ids, t: causal_lm_loss(model.apply(p, ids), t)  # noqa: E731
+    tx = optax.adamw(3e-4)
+    batch = jax.ShapeDtypeStruct((1, 256), np.int32, sharding=v5e)
+    text = train.make_train_step(loss, tx).lower(
+        placed(shapes), placed(jax.eval_shape(tx.init, shapes)), batch, batch
+    ).as_text()
+    names = ("_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel", "_gmm_kernel",
+             "_tgmm_kernel", "_kda_fwd_kernel", "_kda_bwd_kernel")
+    counts = {k: n for k, n in checks.count_pallas_kernels(text, names).items() if n}
+    assert counts == KERNELS_BEFORE[name]

@@ -109,7 +109,62 @@ def moe_paths():
         del os.environ["RAY_TPU_PALLAS_INTERPRET"]
 
 
+@pytest.fixture(scope="module")
+def kimi_paths():
+    """Paths of a tiny Kimi-Linear's compiled train step: a leading dense
+    layer under KDA, KDA and MLA over the expert layer with its shared
+    expert, through the chunked loss."""
+    from ray_tpu.models.kimi_linear import (
+        KimiLinearForCausalLM, kimi_linear_config,
+    )
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"  # read when gmm is traced
+    try:
+        cfg = kimi_linear_config(
+            linear_attn_config={"kda_layers": [1, 2], "full_attn_layers": [3],
+                                "num_heads": 2, "head_dim": 16,
+                                "short_conv_kernel_size": 4},
+            first_k_dense_replace=1, moe_layer_freq=1, num_layers=3,
+            num_experts_held=2, vocab_size=128, hidden_size=32,
+            intermediate_size=64, moe_intermediate_size=16, num_heads=2,
+            num_kv_heads=2, num_experts=8, num_experts_per_tok=2,
+            num_shared_experts=1, routed_scaling_factor=2.446, kv_lora_rank=16,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        )
+        model = KimiLinearForCausalLM(cfg)
+        ids = jnp.zeros((1, 64), jnp.int32)
+        return paths_of(compiled_step(
+            model,
+            lambda p, i, t: chunked_causal_lm_loss(model, p, i, t, chunk_size=32),
+            ids,
+        ))
+    finally:
+        del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+
+
 # ----------------------------------------------------------- in-graph scopes
+
+
+def test_the_hybrid_carries_its_mixers_names_and_scopes(kimi_paths):
+    """What the benchmark's model.kda_share and model.mla_share select by,
+    and the scopes inside the two mixers and the shared expert."""
+    for name in tracing.MIXERS:
+        assert any(f"/{name}/" in p for p in kimi_paths), name
+    kda = [p for p in kimi_paths if "/kda/" in p]
+    for name in (tracing.KDA_CONV, tracing.KDA_GATE, tracing.KDA_SCAN,
+                 tracing.KDA_OUT_NORM):
+        assert any(f"/kda/{name}/" in p for p in kda), name
+    assert any(f"/mla/{tracing.MLA_LATENT}/kv_b_proj/" in p for p in kimi_paths)
+    assert any(f"/moe/{tracing.MOE_SHARED}/shared/" in p for p in kimi_paths)
+    # layer 0 is dense under KDA, layer 2 is MLA over experts; forward,
+    # backward and replay all carry the names
+    assert any("/layers_0/kda/" in p for p in kimi_paths)
+    assert any("/layers_0/mlp/" in p for p in kimi_paths)
+    assert any("/layers_2/mla/" in p and "/layers_2/moe/" not in p for p in kimi_paths)
+    assert {pass_of(p) for p in kda} >= {"forward", "backward", "replay"}
+    assert not [p for p in kimi_paths if "/attn/" in p]
+
 
 
 def test_optimizer_scope_is_on_the_update_and_nowhere_in_the_model(llama_paths):
@@ -335,13 +390,13 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 
 
 def test_names_emitted_are_exactly_the_list(
-    llama_paths, qk_norm_paths, moe_paths, session_lines, actor_lines
+    llama_paths, qk_norm_paths, moe_paths, kimi_paths, session_lines, actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + qk_norm_paths + [
+    paths = llama_paths + qk_norm_paths + kimi_paths + [
         p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:
         assert any(f"/{name}/" in p for p in paths), name
@@ -353,7 +408,7 @@ def test_source_names_no_span_or_scope_outside_the_list():
     constants = {k for k, v in vars(tracing).items()
                  if k.isupper() and isinstance(v, str)}
     assert {getattr(tracing, k) for k in constants} == (
-        set(tracing.HOST_SPANS) | set(tracing.SCOPES)
+        set(tracing.HOST_SPANS) | set(tracing.SCOPES) | set(tracing.MIXERS)
     )
     calls = 0
     for path in glob.glob(os.path.join(REPO, "ray_tpu", "**", "*.py"), recursive=True):
