@@ -34,20 +34,38 @@ Between chunks, with S the state at the chunk's start:
     U = U0 - W S,   O = (q exp(G)) S + Aqk U,
     S' = Diag(exp(G_last)) S + (k exp(G_last - G))^T U
 
-One function of 2-D values, ``_head_chunk``, is a chunk of one head from
-the state at its start to the state at its end. On a TPU the forward kernel
-walks the grid (batch, chunk, head) with every head's state in VMEM, in
-float32; at a chunk's first head it takes G for all heads in one matmul. Called under a gradient it also writes the state at every
-chunk's start (256 chunks x 32 heads x 64 KiB = 512 MiB a layer at 16k
-tokens, alive inside one layer's backward pass under the decoder's
-per-layer remat). The backward kernel walks the chunks in reverse with the
-states' cotangents in VMEM and differentiates ``_head_chunk`` where it
-stands (``jax.vjp`` inside the kernel, from the saved state: a replay of
-one chunk, nothing of a chunk's interior ever in HBM), then takes G's
-cotangent back to g's in one matmul. Elsewhere the same
-function runs under ``lax.scan`` and JAX differentiates it. Matmul operands
-are the inputs' dtype (bfloat16 in the models), accumulation, the running
-sums and the state float32.
+One function of 2-D values, ``_head_chunk``, is a chunk from the state at
+its start to the state at its end, of one head or of P heads stacked on
+rows: [P * 64, dk], [P * 64, dv], [P * 64, 1]. Over P * 64 rows the level
+masks are block-diagonal over heads (a block of 2b <= 64 rows never spans
+two), so A, Aqk, every E and X and T are, their cross-head blocks exact
+zeros, and the same twelve level matmuls and ten of the inverse serve P
+heads, each head's numbers the sums they are alone plus zeros. Only the
+state's three products are a head's own: its 64 rows against its [dv, dk]
+state.
+
+On a TPU the forward kernel walks the grid (batch, chunk, heads / P) with
+every head's state in VMEM, in float32, and P = 128 / 64 = 2 heads a step
+where the heads pair off (else one): a step takes the pair's lanes of q, k,
+v and of the running sums and stacks them on rows, whole vregs where dk and
+dv are multiples of 128. The reason is the MXU's tile of 128 x 128: a 64-row
+product fills a quarter of it and costs its whole latency, and the ten
+matmuls of the inverse each wait for the one before; at one head a step
+they were 11 of a 19 ms call at 16k tokens and 32 heads of 128, at two 7 of
+14. (Four heads side by side in a step, as four chains for the scheduler to
+interleave, did not overlap: a chain's latency is not hidden, it is shared.)
+At a chunk's first step the kernel takes G for all heads in one matmul.
+Called under a gradient it also writes the state at every chunk's start (256
+chunks x 32 heads x 64 KiB = 512 MiB a layer at 16k tokens, alive inside
+one layer's backward pass under the decoder's per-layer remat). The backward
+kernel walks the chunks in reverse with the states' cotangents in VMEM and
+differentiates ``_head_chunk`` of the stacked pair where it stands
+(``jax.vjp`` inside the kernel, from the saved states: a replay of one chunk,
+nothing of a chunk's interior ever in HBM), then takes G's cotangent back to
+g's in one matmul. Elsewhere the same function runs under ``lax.scan``, one
+head a call, and JAX differentiates it. Matmul operands are the inputs'
+dtype (bfloat16 in the models), accumulation, the running sums and the state
+float32.
 """
 from __future__ import annotations
 
@@ -63,6 +81,9 @@ from . import attention as _attention
 
 CHUNK = 64
 _LEVELS = (1, 2, 4, 8, 16, 32)
+# Heads a grid step of the Pallas kernels stacks on rows: the MXU's 128
+# rows over a chunk's.
+_PAIR = 128 // CHUNK
 F32 = jnp.float32
 # What a kernel may take of a v5e core's 128 MiB of VMEM (Mosaic's default
 # lets it use 16): the running sums of every head are 4 MiB at 32 heads of
@@ -169,15 +190,17 @@ def _exact_matmul(m, x, contract):
     )
 
 
-# ----------------------------------------------------- one chunk of one head
+# ------------------------------------------- one chunk of the stacked heads
 
 
-def _masks():
-    """{b: [C, C] bool}: row t and column s lie in one block of 2b rows, t
+def _masks(n):
+    """{b: [n, n] bool}: row t and column s lie in one block of 2b rows, t
     in its second half and s in its first (over the six levels, the strict
-    lower triangle once), and the diagonal."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+    lower triangle of every CHUNK x CHUNK diagonal block once), and the
+    diagonal. A block of 2b <= CHUNK rows never spans two of the heads
+    stacked on the n rows, so every mask is block-diagonal over heads."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
     masks = {}
     for level, b in enumerate(_LEVELS):
         same = (row >> (level + 1)) == (col >> (level + 1))
@@ -185,17 +208,40 @@ def _masks():
     return masks, row == col
 
 
+def _heads_of(x, p):
+    """The p row blocks of x, one a stacked head."""
+    r = x.shape[0] // p
+    return [x[i * r:(i + 1) * r] for i in range(p)]
+
+
+def _per_head(dot, a, b, p):
+    """``dot`` of each stacked head's rows of a with its rows of b, the
+    results stacked again (p = 1: ``dot(a, b)``, nothing sliced or joined)."""
+    return jnp.concatenate(
+        [dot(x, y) for x, y in zip(_heads_of(a, p), _heads_of(b, p))])
+
+
 def _head_chunk(St, q, k, v, beta, G, last, last_dv, roll=_xla_roll):
-    """A chunk of one head. St [dv, dk] float32, the state at its start,
-    transposed so that the decay scales lanes; q, k [C, dk]; v [C, dv]; beta
-    [C, 1] float32; G [C, dk] the running sum of g, ``last`` its last row
-    on C rows and ``last_dv`` on dv rows. -> (the state at its end, o [C,
-    dv])."""
+    """A chunk of P heads stacked on rows (P = 1: of one head). St [P * dv,
+    dk] float32, the states at its start, each transposed so that the decay
+    scales lanes; q, k [P * C, dk]; v [P * C, dv]; beta [P * C, 1] float32;
+    G [P * C, dk] the running sum of g, ``last`` its last row on C rows a
+    head and ``last_dv`` on dv rows a head. -> (the states at its end, o
+    [P * C, dv]).
+
+    Every [P * C, P * C] matrix below (A, Aqk, each E and X, T) is
+    block-diagonal over heads, its cross-head blocks exact zeros: the masks
+    are, a roll by b < C brings a row of another head only to rows whose
+    select takes the other value, and a product of block-diagonal matrices
+    is one. So a head's numbers are the sums they are alone, plus zeros.
+    Only the state's three products are a head's own."""
     dt = q.dtype
-    masks, eye = _masks()
-    rows = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, 1), 0)
+    n = q.shape[0]
+    p = n // CHUNK
+    masks, eye = _masks(n)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
     kf, qf = k.astype(F32), q.astype(F32)
-    A = Aqk = jnp.zeros((CHUNK, CHUNK), F32)
+    A = Aqk = jnp.zeros((n, n), F32)
     start = G  # G at the first row of every row's block of b rows
     for level, b in enumerate(_LEVELS):
         second = ((rows >> level) & 1) == 1
@@ -218,23 +264,35 @@ def _head_chunk(St, q, k, v, beta, G, last, last_dv, roll=_xla_roll):
     w = _nn(T, (kf * jnp.exp(G) * beta).astype(dt)).astype(dt)
     u0 = _nn(T, (v.astype(F32) * beta).astype(dt))
     sd = St.astype(dt)
-    u = (u0 - _nt(w, sd)).astype(dt)
-    o = _nt((qf * jnp.exp(G)).astype(dt), sd) + _nn(Aqk.astype(dt), u)
+    u = (u0 - _per_head(_nt, w, sd, p)).astype(dt)
+    o = _per_head(_nt, (qf * jnp.exp(G)).astype(dt), sd, p) + _nn(Aqk.astype(dt), u)
     kd = (kf * jnp.exp(last - G)).astype(dt)
-    return jnp.exp(last_dv) * St + _tn(u, kd), o
+    return jnp.exp(last_dv) * St + _per_head(_tn, u, kd, p), o
 
 
 # ------------------------------------------------------------ Pallas kernels
-# Grid (batch, chunk, head), the heads innermost: every head's state stays
-# in VMEM over a sequence's chunks, and the running sums of all heads are
-# taken once a chunk, at its first head.
+# Grid (batch, chunk, heads // P), the heads innermost and P of them a
+# step: every head's state stays in VMEM over a sequence's chunks, and the
+# running sums of all heads are taken once a chunk, at its first step.
 
 
-def _head_sums(d_scr, dk, dv):
-    """Head ``program_id(2)``'s G, G_last on CHUNK rows and G_last on dv
-    rows: row blocks of its ``dk`` lanes in the [2 * CHUNK + dv, H * dk]
-    scratch of running sums, as index tuples."""
-    lanes = pl.ds(pl.multiple_of(pl.program_id(2) * dk, dk), dk)
+def _stack(x, p):
+    """[r, p * d] -> [p * r, d]: p heads from lanes to rows, whole vregs
+    where d is a multiple of 128."""
+    d = x.shape[1] // p
+    return jnp.concatenate([x[:, i * d:(i + 1) * d] for i in range(p)])
+
+
+def _unstack(x, p):
+    """[p * r, d] -> [r, p * d]: ``_stack`` undone."""
+    return jnp.concatenate(_heads_of(x, p), axis=1)
+
+
+def _step_sums(d_scr, p, dk, dv):
+    """Step ``program_id(2)``'s G, G_last on CHUNK rows and G_last on dv
+    rows: row blocks of its heads' ``p * dk`` lanes in the [2 * CHUNK + dv,
+    H * dk] scratch of running sums, as index tuples."""
+    lanes = pl.ds(pl.multiple_of(pl.program_id(2) * p * dk, p * dk), p * dk)
     return [(pl.ds(0, CHUNK), lanes), (pl.ds(CHUNK, CHUNK), lanes),
             (pl.ds(2 * CHUNK, dv), lanes)]
 
@@ -250,79 +308,100 @@ def _take_sums(m_ref, g_ref, d_scr):
         d_scr[...] = _exact_matmul(m_ref[...], g_ref[0], ((1,), (0,)))
 
 
+def _stacked(refs, beta_ref, d_scr, sums, p):
+    """A step's operands of ``_head_chunk`` after the states: q, k, v from
+    ``refs``, beta and the three running sums, its p heads on rows."""
+    return (*(_stack(ref[0], p) for ref in refs),
+            jnp.concatenate([beta_ref[0, i] for i in range(p)]),
+            *(_stack(d_scr[at], p) for at in sums))
+
+
 def _kda_fwd_kernel(m_ref, g_ref, q_ref, k_ref, v_ref, beta_ref, o_ref, *rest):
     # rest: (the states' output, the two scratches) or the scratches alone.
     s_ref, d_scr, st_scr = rest if len(rest) == 3 else (None, *rest)
-    head = pl.program_id(2)
-    dv, dk = st_scr.shape[1:]
+    step, p = pl.program_id(2), beta_ref.shape[1]
+    dv, dk = st_scr.shape[1] // p, st_scr.shape[2]
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        st_scr[head] = jnp.zeros((dv, dk), F32)
+        st_scr[step] = jnp.zeros((p * dv, dk), F32)
 
     _take_sums(m_ref, g_ref, d_scr)
-    St = st_scr[head]
+    St = st_scr[step]
     if s_ref is not None:
-        s_ref[0, 0] = St
-    st_scr[head], o = _head_chunk(
-        St, q_ref[0], k_ref[0], v_ref[0], beta_ref[0, 0],
-        *(d_scr[at] for at in _head_sums(d_scr, dk, dv)), roll=_roll_here(),
+        s_ref[0, 0] = _unstack(St, p)
+    st_scr[step], o = _head_chunk(
+        St, *_stacked((q_ref, k_ref, v_ref), beta_ref, d_scr,
+                      _step_sums(d_scr, p, dk, dv), p),
+        roll=_roll_here(),
     )
-    o_ref[0] = o.astype(o_ref.dtype)
+    o_ref[0] = _unstack(o, p).astype(o_ref.dtype)
 
 
 def _kda_bwd_kernel(m_ref, mt_ref, g_ref, q_ref, k_ref, v_ref, beta_ref,
                     s_ref, do_ref, dq_ref, dk_ref, dv_ref, dbeta_ref, dg_ref,
                     d_scr, dd_scr, dst_scr):
-    head = pl.program_id(2)
-    dv, dk = dst_scr.shape[1:]
+    step, p = pl.program_id(2), beta_ref.shape[1]
+    dv, dk = dst_scr.shape[1] // p, dst_scr.shape[2]
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        dst_scr[head] = jnp.zeros((dv, dk), F32)
+        dst_scr[step] = jnp.zeros((p * dv, dk), F32)
 
     _take_sums(m_ref, g_ref, d_scr)
-    sums = _head_sums(d_scr, dk, dv)
+    sums = _step_sums(d_scr, p, dk, dv)
     _, vjp = jax.vjp(
-        functools.partial(_head_chunk, roll=_roll_here()), s_ref[0, 0],
-        q_ref[0], k_ref[0], v_ref[0], beta_ref[0, 0],
-        *(d_scr[at] for at in sums),
+        functools.partial(_head_chunk, roll=_roll_here()),
+        _stack(s_ref[0, 0], p),
+        *_stacked((q_ref, k_ref, v_ref), beta_ref, d_scr, sums, p),
     )
     dst, dq, dk_, dv_, dbeta, *dsums = vjp(
-        (dst_scr[head], do_ref[0].astype(F32))
+        (dst_scr[step], _stack(do_ref[0], p).astype(F32))
     )
-    dst_scr[head] = dst
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-    dk_ref[0] = dk_.astype(dk_ref.dtype)
-    dv_ref[0] = dv_.astype(dv_ref.dtype)
-    dbeta_ref[0, 0] = dbeta
+    dst_scr[step] = dst
+    dq_ref[0] = _unstack(dq, p).astype(dq_ref.dtype)
+    dk_ref[0] = _unstack(dk_, p).astype(dk_ref.dtype)
+    dv_ref[0] = _unstack(dv_, p).astype(dv_ref.dtype)
+    for i, part in enumerate(_heads_of(dbeta, p)):
+        dbeta_ref[0, i] = part
     for at, ds in zip(sums, dsums):
-        dd_scr[at] = ds
+        dd_scr[at] = _unstack(ds, p)
 
-    @pl.when(head == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _dg():
         dg_ref[0] = _exact_matmul(mt_ref[...], dd_scr[...], ((1,), (0,)))
 
 
+def _heads_a_step(heads):
+    """Two where the heads pair off (a [128, .] operand fills the MXU's
+    rows), else one."""
+    return 1 if heads % _PAIR else _PAIR
+
+
 def _specs(heads, dk, dv, chunk_of):
-    """BlockSpecs over grid (batch, step, head), ``chunk_of(step)`` the
+    """BlockSpecs over grid (batch, step, heads // p), ``chunk_of(step)`` the
     chunk a step works on. Rows lie [B, T, H * d]: a block is one chunk's
-    rows of one head's lanes, or of all heads' (g, whose running sums are
+    rows of p heads' lanes, or of all heads' (g, whose running sums are
     taken for all heads at once). beta lies [B, H, T, 1] and the states
-    [B, N, dv, H * dk]."""
+    [B, N, dv, H * dk]. With them the grid's last extent (``steps``) and the
+    scratch that holds every head's state, p heads stacked a step."""
+    p = _heads_a_step(heads)
+
     def rows(d):
-        return pl.BlockSpec((1, CHUNK, d), lambda b, n, h: (b, chunk_of(n), h))
+        return pl.BlockSpec((1, CHUNK, p * d), lambda b, n, h: (b, chunk_of(n), h))
 
     whole = lambda *shape: pl.BlockSpec(shape, lambda b, n, h: (0,) * len(shape))  # noqa: E731
     return {
         "k": rows(dk), "v": rows(dv),
         "g": pl.BlockSpec((1, CHUNK, heads * dk),
                           lambda b, n, h: (b, chunk_of(n), 0)),
-        "beta": pl.BlockSpec((1, 1, CHUNK, 1),
+        "beta": pl.BlockSpec((1, p, CHUNK, 1),
                              lambda b, n, h: (b, h, chunk_of(n), 0)),
-        "state": pl.BlockSpec((1, 1, dv, dk),
+        "state": pl.BlockSpec((1, 1, dv, p * dk),
                               lambda b, n, h: (b, chunk_of(n), 0, h)),
         "m": whole(2 * CHUNK + dv, CHUNK), "mt": whole(CHUNK, 2 * CHUNK + dv),
+        "steps": heads // p,
+        "states": pltpu.VMEM((heads // p, p * dv, dk), F32),
     }
 
 
@@ -340,15 +419,14 @@ def _forward_pallas(q, k, v, g, beta, heads, states):
     m = jnp.asarray(_sum_matrix(dv), jnp.bfloat16)
     return pl.pallas_call(
         _kda_fwd_kernel,
-        grid=(batch, n, heads),
+        grid=(batch, n, s["steps"]),
         in_specs=[s["m"], s["g"], s["k"], s["k"], s["v"], s["beta"]],
         out_specs=[s["v"], s["state"]][:1 + states],
         out_shape=[
             jax.ShapeDtypeStruct(v.shape, v.dtype),
             jax.ShapeDtypeStruct((batch, n, dv, heads * dk), F32),
         ][:1 + states],
-        scratch_shapes=[pltpu.VMEM((m.shape[0], heads * dk), F32),
-                        pltpu.VMEM((heads, dv, dk), F32)],
+        scratch_shapes=[pltpu.VMEM((m.shape[0], heads * dk), F32), s["states"]],
         compiler_params=_params(),
         interpret=_attention._interpret(),
     )(m, g, q, k, v, beta)
@@ -362,13 +440,13 @@ def _backward_pallas(q, k, v, g, beta, states, do, heads):
     sums = pltpu.VMEM((m.shape[0], heads * dk), F32)
     return pl.pallas_call(
         _kda_bwd_kernel,
-        grid=(batch, n, heads),
+        grid=(batch, n, s["steps"]),
         in_specs=[s["m"], s["mt"], s["g"], s["k"], s["k"], s["v"], s["beta"],
                   s["state"], s["v"]],
         out_specs=[s["k"], s["k"], s["v"], s["beta"], s["g"]],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (q, k, v, beta, g)],
-        scratch_shapes=[sums, sums, pltpu.VMEM((heads, dv, dk), F32)],
+        scratch_shapes=[sums, sums, s["states"]],
         compiler_params=_params(),
         interpret=_attention._interpret(),
     )(jnp.asarray(m, jnp.bfloat16), jnp.asarray(m.T, jnp.bfloat16),

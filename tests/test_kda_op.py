@@ -1,8 +1,10 @@
 """The gated delta rule of ``ray_tpu/ops/kda.py`` on the CPU: the chunked
 function and its ``custom_vjp`` against the token-by-token recurrence,
 forward and every gradient, at several sequence lengths and at a decay near
-0 and near 1; the Pallas scan kernels in interpret mode against the same;
-the short convolution and the gate beside it.
+0 and near 1; the Pallas scan kernels in interpret mode against the same,
+two heads a grid step and, where the heads do not pair off, one; a head
+through the pair path against the same head alone, bit for bit; the short
+convolution and the gate beside it.
 """
 import jax
 import jax.numpy as jnp
@@ -31,21 +33,21 @@ def recurrence(q, k, v, g, beta):
         return jax.vmap(heads)(q, k, v, g, beta)
 
 
-def inputs(t, decay, seed=0):
+def inputs(t, decay, seed=0, heads=H):
     """q, k normalised as the mixer does; g = -decay x uniform(0.5, 1.5):
     exp(g) is near 1 at decay 1e-3 and under 1e-6 at decay 30."""
     r = np.random.default_rng(seed)
     draw = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)  # noqa: E731
-    q = kda.l2norm(draw(B, t, H, DK)) * DK ** -0.5
-    k = kda.l2norm(draw(B, t, H, DK))
-    v = draw(B, t, H, DV)
-    g = -jnp.asarray(r.uniform(0.5, 1.5, size=(B, t, H, DK)), jnp.float32) * decay
-    beta = jax.nn.sigmoid(draw(B, t, H))
+    q = kda.l2norm(draw(B, t, heads, DK)) * DK ** -0.5
+    k = kda.l2norm(draw(B, t, heads, DK))
+    v = draw(B, t, heads, DV)
+    g = -jnp.asarray(r.uniform(0.5, 1.5, size=(B, t, heads, DK)), jnp.float32) * decay
+    beta = jax.nn.sigmoid(draw(B, t, heads))
     return q, k, v, g, beta
 
 
-def compare(t, decay):
-    args = inputs(t, decay)
+def compare(t, decay, heads=H):
+    args = inputs(t, decay, heads=heads)
     w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
     want = recurrence(*args)
     got = jax.jit(kda.chunk_kda)(*args)
@@ -68,22 +70,87 @@ def test_chunked_form_and_its_vjp_are_the_recurrence(t, decay):
     compare(t, decay)
 
 
+@pytest.mark.parametrize("heads", [2, 3], ids=["pair", "odd"])
 @pytest.mark.parametrize("t,decay", [(100, 0.3), (256, 1e-3), (192, 30.0)])
-def test_pallas_kernels_in_interpret_mode_are_the_recurrence(monkeypatch, t, decay):
+def test_pallas_kernels_in_interpret_mode_are_the_recurrence(monkeypatch, t, decay, heads):
     """The forward kernel and, under its ``custom_vjp``, the backward kernel
-    that differentiates ``_head_chunk`` where it stands."""
+    that differentiates ``_head_chunk`` where it stands: two heads a grid
+    step, and three heads one a step."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    compare(t, decay)
+    compare(t, decay, heads)
 
 
-def pallas_outputs(jaxpr, found):
-    """Number of outputs of every pallas_call in a jaxpr, nested ones too."""
+def test_a_head_through_the_pair_path_is_the_head_alone_bit_for_bit(monkeypatch):
+    """Stacked on another head's rows a head's sums gain exact zeros and
+    nothing else: output and all five gradients of four heads, two a grid
+    step, equal those of the same call one head a step, and those of each
+    head in a call of its own. (g's gradient leaves the kernel through one
+    matmul over every head's lanes, which the interpreter's backend sums in
+    another order at another width: against a one-head call it agrees to
+    rounding, from either path.)"""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    args = inputs(192, 0.3, heads=4)
+    w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
+
+    def run(w, *a):
+        loss = lambda *a: jnp.sum(kda.chunk_kda(*a) * w)  # noqa: E731
+        return kda.chunk_kda(*a), *jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*a)
+
+    names = "o q k v g beta".split()
+    paired = jax.jit(run)(w, *args)
+    for h in range(4):
+        alone = lambda x: x[:, :, h:h + 1]  # noqa: E731
+        for name, a, b in zip(names, paired, jax.jit(run)(alone(w), *map(alone, args))):
+            assert float(jnp.abs(b).max()) > 0, name
+            if name == "g":
+                np.testing.assert_allclose(
+                    alone(a), b, rtol=0, atol=1e-6 * float(jnp.abs(b).max()))
+            else:
+                np.testing.assert_array_equal(alone(a), b, err_msg=f"head {h}: {name}")
+    monkeypatch.setattr(kda, "_PAIR", 1)  # the same call, one head a step
+    for name, a, b in zip(names, paired, jax.jit(run)(w, *args)):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_the_masks_of_stacked_heads_are_block_diagonal():
+    """A level's block of 2b <= 64 rows never spans two heads: over 128 rows
+    every mask is the one-head mask on both diagonal blocks and false
+    between heads."""
+    c = kda.CHUNK
+    (one, eye1), (two, eye2) = kda._masks(c), kda._masks(2 * c)
+    lower = np.tril(np.ones((c, c), bool), -1)
+    assert (sum(np.asarray(m, int) for m in one.values()) == lower).all()
+    for b, mask in two.items():
+        mask = np.asarray(mask)
+        assert not mask[:c, c:].any() and not mask[c:, :c].any(), b
+        assert (mask[:c, :c] == one[b]).all() and (mask[c:, c:] == one[b]).all(), b
+    assert (np.asarray(eye2) == np.eye(2 * c, dtype=bool)).all()
+    assert (np.asarray(eye1) == np.eye(c, dtype=bool)).all()
+
+
+def pallas_calls(jaxpr, found):
+    """Every pallas_call equation in a jaxpr, nested ones too."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            found.append(len(eqn.outvars))
+            found.append(eqn)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            pallas_outputs(sub, found)
+            pallas_calls(sub, found)
     return found
+
+
+def pallas_outputs(jaxpr):
+    """Number of outputs of every pallas_call in a jaxpr, nested ones too."""
+    return [len(eqn.outvars) for eqn in pallas_calls(jaxpr, [])]
+
+
+@pytest.mark.parametrize("heads,steps", [(32, 16), (3, 3)])
+def test_the_grid_takes_two_heads_a_step_where_they_pair_off(monkeypatch, heads, steps):
+    """Forward (with its states) and backward, as a gradient lowers them."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    args = inputs(128, 0.3, heads=heads)
+    both = jax.make_jaxpr(jax.grad(lambda *a: kda.chunk_kda(*a).sum()))(*args)
+    grids = [eqn.params["grid_mapping"].grid for eqn in pallas_calls(both.jaxpr, [])]
+    assert grids == [(B, 128 // kda.CHUNK, steps)] * 2
 
 
 def test_the_forward_outside_a_gradient_writes_no_states(monkeypatch):
@@ -92,10 +159,10 @@ def test_the_forward_outside_a_gradient_writes_no_states(monkeypatch):
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     args = inputs(128, 0.3)
     forward = jax.make_jaxpr(kda.chunk_kda)(*args)
-    assert pallas_outputs(forward.jaxpr, []) == [1]  # o alone
+    assert pallas_outputs(forward.jaxpr) == [1]  # o alone
     both = jax.make_jaxpr(jax.grad(lambda *a: kda.chunk_kda(*a).sum()))(*args)
     # o and the states; then the five cotangents
-    assert pallas_outputs(both.jaxpr, []) == [2, 5]
+    assert pallas_outputs(both.jaxpr) == [2, 5]
 
 
 def test_a_strong_decay_neither_overflows_nor_loses_the_state():
