@@ -332,20 +332,18 @@ def expert_ffn(expert_in, w_gate, w_up, w_down, counts, pairs):
     return _tiled_ffn(expert_in, w_gate, w_up, w_down, tiles, trips, rows)
 
 
-def _pair_slots(order, dst, m_pad, K, here=None):
+def _pair_slots(order, dst, m_pad, K):
     """``aligned_group_layout``'s permutation both ways, as two scalar
     scatters: ``slot_of_pair`` [S, K], the aligned slot of each (token, k)
     pair, pairs counted token-major, and ``pair_of_slot`` [m_pad], a
     padding slot holding S * K (the pair of token S, which is a zero
-    row). ``here`` [N] bool, in sorted order: the pairs whose expert this
-    device holds; the slot of any other pair reads the zero row too."""
+    row)."""
     N = order.shape[0]
     slot_of_pair = (
         jnp.zeros((N,), jnp.int32).at[order].set(dst, unique_indices=True)
     )
-    pair = order if here is None else jnp.where(here, order, N)
     pair_of_slot = (
-        jnp.full((m_pad,), N, jnp.int32).at[dst].set(pair, unique_indices=True)
+        jnp.full((m_pad,), N, jnp.int32).at[dst].set(order, unique_indices=True)
     )
     return slot_of_pair.reshape(N // K, K), pair_of_slot
 
@@ -408,6 +406,228 @@ def _slots_to_rows_bwd(residuals, g):
 _slots_to_rows.defvjp(_slots_to_rows_fwd, _slots_to_rows_bwd)
 
 
+# Row tiles a trip of the held share's loops covers (2,048 rows). The
+# loops run over the tiles that hold rows, so their last trip may cover up
+# to a window less one tile that hold none.
+_WINDOW = 16
+
+
+_window = jax.lax.dynamic_slice_in_dim  # (rows, at, size): rows [at, at + size)
+_put = partial(jax.lax.dynamic_update_slice_in_dim, axis=0)  # (rows, new, at)
+
+
+def _windows(used, size, total, trip, carry):
+    """``carry = trip(at, size, carry)`` for the windows [at, at + size)
+    that cover the first ``used`` (traced) of ``total`` positions, under a
+    loop of ceil(used / size) trips. Where ``size`` does not divide
+    ``total`` the last window is moved back to fit and overlaps the one
+    before; ``size`` is no more than ``total``."""
+    size = min(size, total)
+
+    def body(w, carry):
+        return trip(jnp.minimum(w * size, total - size), size, carry)
+
+    return jax.lax.fori_loop(0, -(-used // size), body, carry)
+
+
+def _held_pair_of_slot(order, dst, n_here, m_pad):
+    """``pair_of_slot`` [m_pad] of a layout whose last group is the pairs
+    held elsewhere (``aligned_group_layout``'s ``order`` and ``dst``; the
+    first ``n_here`` sorted pairs are this device's): the pair, counted
+    token-major, in each slot of a held expert's tiles, and the pair count
+    in a padding slot and in every slot past those tiles. Written a window
+    of sorted pairs at a time, this device's alone."""
+    N = order.shape[0]
+
+    def trip(at, size, pair_of_slot):
+        here = at + jnp.arange(size, dtype=jnp.int32) < n_here
+        slot = _window(dst, at, size)
+        return pair_of_slot.at[jnp.where(here, slot, m_pad)].set(
+            _window(order, at, size), mode="drop"
+        )
+
+    return _windows(
+        n_here, _WINDOW * 128, N, trip, jnp.full((m_pad,), N, jnp.int32)
+    )
+
+
+def _held_rows(x2, pair_of_slot, used, K, after):
+    """Tokens ``x2`` [S, D] in their slots of the layout's first ``used``
+    rows, zeros in a padding slot; the rows past them are not written
+    (``ops.gmm.unwritten``, which takes ``after``)."""
+    from ..ops import gmm as G
+
+    def gather(at, size, lhs):
+        # A padding slot names token S, past the rows: it reads zeros.
+        token = _window(pair_of_slot, at, size) // K
+        return _put(lhs, x2.at[token].get(mode="fill", fill_value=0), at)
+
+    m_pad = pair_of_slot.shape[0]
+    return _windows(
+        used, _WINDOW * 128, m_pad, gather,
+        G.unwritten((m_pad, x2.shape[1]), x2.dtype, after),
+    )
+
+
+def _swiglu_rows(h, u):
+    return nn.silu(h) * u
+
+
+def _held_ffn_fwd(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
+                  tiles_used):
+    from ..ops import gmm as G
+
+    (S, D), K, F = x2.shape, gates.shape[1], w_gate.shape[2]
+    m_pad = pair_of_slot.shape[0]
+    used, span = tiles_used[0] * 128, _WINDOW * 128
+
+    def grouped(lhs, w):
+        return G._gmm_pallas(lhs, w, tile_group, 128, tiles_used=tiles_used)
+
+    with tracing.scope(tracing.MOE_DISPATCH):
+        lhs = _held_rows(x2, pair_of_slot, used, K, x2)
+    with tracing.scope(tracing.MOE_EXPERTS):
+        h, u = grouped(lhs, w_gate), grouped(lhs, w_up)
+
+        def activate(at, size, act):
+            return _put(
+                act, _swiglu_rows(_window(h, at, size), _window(u, at, size)), at
+            )
+
+        act = _windows(
+            used, span, m_pad, activate, G.unwritten((m_pad, F), h.dtype, h)
+        )
+        eo = grouped(act, w_down)
+    with tracing.scope(tracing.MOE_COMBINE):
+        gate_of_pair = gates.reshape(S * K)
+
+        def add(at, size, out):
+            # A padding slot's pair and token lie past the arrays: its gate
+            # reads zero and its row, whatever it holds, is dropped.
+            pair = _window(pair_of_slot, at, size)
+            gate = gate_of_pair.at[pair].get(mode="fill", fill_value=0)
+            scaled = _window(eo, at, size).astype(jnp.float32) * gate[:, None]
+            return out.at[pair // K].add(scaled, mode="drop")
+
+        out = _windows(
+            used, span, m_pad, add, jnp.zeros((S, D), jnp.float32)
+        ).astype(eo.dtype)
+    return out, (x2, h, u, eo, gates, w_gate, w_up, w_down, pair_of_slot,
+                 tile_group, tiles_used)
+
+
+@jax.custom_vjp
+def _held_ffn(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
+              tiles_used):
+    """The held experts' SwiGLU of tokens ``x2`` [S, D] over a layout that
+    is bounded at every pair and filled by the pairs that are here, and the
+    sum of each token's pairs weighted by ``gates`` [S, K]: what
+    ``_rows_to_slots``, three ``gmm`` calls and ``_slots_to_rows`` compute,
+    at the cost of the ``tiles_used`` row tiles that hold rows.
+
+    The [m_pad, .] buffers exist whole (the bound costs memory) and only
+    the used tiles of each are ever written or read (it costs no time): a
+    row of a later tile is uninitialised memory. Rows move to slots by
+    gathers over the used tiles, a window of them a trip (``_windows``;
+    ``m_pad`` is whole windows), and back to tokens by walking the same
+    slots and adding each into its token's float32 row, where a gather over
+    tokens would read 131,072 rows to find the 8,192 that hold a pair. The
+    backward is written out for the same reason: JAX's would add the two
+    up-projections' row gradients over the whole bound.
+
+    ``pair_of_slot`` [m_pad] int32: a slot's (token, k) pair counted
+    token-major, S * K in a padding slot (``_held_pair_of_slot``);
+    ``tile_group`` and ``tiles_used`` as ``ops.gmm.gmm`` takes them."""
+    return _held_ffn_fwd(
+        x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group, tiles_used
+    )[0]
+
+
+def _held_ffn_bwd(residuals, g):
+    from ..ops import gmm as G
+
+    (x2, h, u, eo, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
+     tiles_used) = residuals
+    (S, K), (m_pad, D) = gates.shape, eo.shape
+    used, span = tiles_used[0] * 128, _WINDOW * 128
+
+    def to_rows(dout, w):
+        return G._gmm_pallas(
+            dout, w, tile_group, 128, transpose_rhs=True, tiles_used=tiles_used
+        )
+
+    def to_weight(rows_in, dout, w):
+        return G._tgmm_pallas(
+            rows_in, dout, tile_group, w.shape[0], 128, tiles_used
+        ).astype(w.dtype)
+
+    # The loops below work in place: a window of a buffer is read and what
+    # it turns into written over it, so the backward allocates no row
+    # buffer but those its matmuls return and the tokens' rows, which are
+    # gathered again when the up-projections' gradients need them (0.4 ms
+    # a layer against 0.58 GiB held from the replay on). The barriers put
+    # each weight's gradient before the row buffers that come next, so
+    # that its operands are free by then: left to the scheduler the three
+    # come last and Kimi-Linear's step holds 0.56 GiB more (7.56 against
+    # 7.00 GiB of temporaries, AOT compile: PERF.md §6, PR 33).
+    with tracing.scope(tracing.MOE_COMBINE):
+        gate_of_pair = gates.reshape(S * K)
+
+        def pull(at, size, carry):
+            # A slot's row is its token's cotangent times its pair's gate,
+            # its gate's the row's product with that cotangent; a padding
+            # slot reads zeros and its product is dropped.
+            rows, d_gate = carry
+            pair = _window(pair_of_slot, at, size)
+            g_rows = g.at[pair // K].get(mode="fill", fill_value=0)
+            gate = gate_of_pair.at[pair].get(mode="fill", fill_value=0)
+            dots = (_window(rows, at, size).astype(jnp.float32) * g_rows).sum(-1)
+            return (
+                _put(rows, (g_rows * gate[:, None]).astype(rows.dtype), at),
+                d_gate.at[pair].set(dots, mode="drop"),
+            )
+
+        d_eo, d_gate = _windows(
+            used, span, m_pad, pull, (eo, jnp.zeros((S * K,), jnp.float32))
+        )
+    with tracing.scope(tracing.MOE_EXPERTS):
+        def through(at, size, carry):
+            d_act, h, u = (_window(rows, at, size) for rows in carry)
+            act, pullback = jax.vjp(_swiglu_rows, h, u)
+            return tuple(
+                _put(rows, new, at)
+                for rows, new in zip(carry, (act, *pullback(d_act)))
+            )
+
+        act, d_h, d_u = _windows(
+            used, span, m_pad, through, (to_rows(d_eo, w_down), h, u)
+        )
+        d_w_down, d_h, d_u = jax.lax.optimization_barrier(
+            (to_weight(act, d_eo, w_down), d_h, d_u)
+        )
+    with tracing.scope(tracing.MOE_DISPATCH):
+        lhs = _held_rows(x2, pair_of_slot, used, K, d_w_down)
+    with tracing.scope(tracing.MOE_EXPERTS):
+        d_w_gate, d_w_up, d_h, d_u = jax.lax.optimization_barrier(
+            (to_weight(lhs, d_h, w_gate), to_weight(lhs, d_u, w_up), d_h, d_u)
+        )
+        d_lhs = to_rows(d_h, w_gate), to_rows(d_u, w_up)
+    with tracing.scope(tracing.MOE_DISPATCH):
+        def add(at, size, dx):
+            token = _window(pair_of_slot, at, size) // K
+            both = sum(_window(d, at, size).astype(jnp.float32) for d in d_lhs)
+            return dx.at[token].add(both, mode="drop")
+
+        dx = _windows(
+            used, span, m_pad, add, jnp.zeros((S, D), jnp.float32)
+        ).astype(x2.dtype)
+    d_gates = d_gate.reshape(S, K).astype(gates.dtype)
+    return dx, d_gates, d_w_gate, d_w_up, d_w_down, None, None, None
+
+
+_held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
+
+
 CONFIGS = {
     "mixtral-tiny": MixtralConfig(
         vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -440,7 +660,11 @@ class MoELayer(nn.Module):
     directions, forward and backward: the sort is a permutation whose
     inverse the layer holds, and a token's K pairs are consecutive in
     pair order, so what would be a scatter-add is a gather and a sum
-    over K (_rows_to_slots, _slots_to_rows).
+    over K (_rows_to_slots, _slots_to_rows). Where the device holds a share
+    of the experts (cfg.experts_held) the layout is bounded at every pair
+    and a sixteenth of it is filled: there every pass stops at the tiles
+    that hold rows, and rows go back to tokens by walking those tiles'
+    slots and adding each into its token's row (_held_ffn).
 
     "ragged": (token, k) pairs argsorted by expert feed
     `lax.ragged_dot` with exact group sizes — zero capacity padding and
@@ -548,6 +772,47 @@ class MoELayer(nn.Module):
                 out = out + shared
             return with_logical_constraint(out, ("batch", "seq", "embed"))
 
+        if dispatch == "gmm" and held is not None:
+            # One expert-parallel rank's share of the layer. The pairs of
+            # experts held elsewhere form one more group, sorted last, so
+            # the layout keeps its static bound of N rows: a routing may
+            # send every pair here, and none is dropped. The bound costs
+            # memory and no time: the tiles from that group's first on
+            # hold no row of this device's, `tiles_used` counts the ones
+            # before it, and whatever reads or writes the layout stops
+            # there (`_held_ffn`).
+            from ..ops.gmm import aligned_group_layout
+
+            N = B * T * K
+            with tracing.scope(tracing.MOE_DISPATCH):
+                x2 = x.astype(cfg.dtype).reshape(B * T, D)
+                with tracing.scope(tracing.MOE_LAYOUT):
+                    groups = gate_idx.reshape(N)
+                    here = (groups >= held[0]) & (groups < held[1])
+                    order, dst, tile_group, m_pad = aligned_group_layout(
+                        jnp.where(here, groups - held[0], E_w), E_w + 1,
+                        block_m=128,
+                    )
+                    tiles_used = jnp.sum(
+                        tile_group < E_w, dtype=jnp.int32
+                    ).reshape(1)
+                    # Whole windows of tiles, each past the used ones
+                    # named for the last expert that is here.
+                    tiles = -(-m_pad // (_WINDOW * 128)) * _WINDOW
+                    tile_group = jnp.pad(
+                        jnp.minimum(tile_group, E_w - 1),
+                        (0, tiles - m_pad // 128), constant_values=E_w - 1,
+                    )
+                    pair_of_slot = _held_pair_of_slot(
+                        order, dst, jnp.sum(here, dtype=jnp.int32), tiles * 128
+                    )
+            out2 = _held_ffn(
+                x2, gate_vals.astype(cfg.dtype).reshape(B * T, K),
+                w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
+                w_down.astype(cfg.dtype), pair_of_slot, tile_group, tiles_used,
+            )
+            return finish(out2.reshape(B, T, D))
+
         if dispatch == "gmm":
             # Tile-aligned group-sorted dispatch through the pallas
             # grouped matmul: every block_m row-tile belongs to one
@@ -561,39 +826,21 @@ class MoELayer(nn.Module):
                 # The index work, under a scope of its own so that a
                 # profile tells it from the row gather below.
                 with tracing.scope(tracing.MOE_LAYOUT):
-                    groups, here, tiles_used = gate_idx.reshape(N), None, None
-                    if held is not None:
-                        # The pairs of experts held elsewhere form one
-                        # more group, sorted last: the layout keeps its
-                        # static bound of N rows (a routing may send every
-                        # pair here), and the tiles from that group's first
-                        # on hold no row of this device's.
-                        here = (groups >= held[0]) & (groups < held[1])
-                        groups = jnp.where(here, groups - held[0], E_w)
                     order, dst, tile_group, m_pad = aligned_group_layout(
-                        groups, E_w + (held is not None), block_m=128
+                        gate_idx.reshape(N), E, block_m=128
                     )
-                    if held is not None:
-                        here = here[order]
-                        tiles_used = jnp.sum(
-                            tile_group < E_w, dtype=jnp.int32
-                        ).reshape(1)
-                        tile_group = jnp.minimum(tile_group, E_w - 1)
                     slot_of_pair, pair_of_slot = _pair_slots(
-                        order, dst, m_pad, K, here
+                        order, dst, m_pad, K
                     )
                 # Row GATHER into the aligned layout, and a gather back
                 # in its gradient (a row scatter-add costs 7-11 times a
                 # copy of the same rows on a v5e: PERF.md §6, PR 26).
                 lhs = _rows_to_slots(x2, slot_of_pair, pair_of_slot)
             with tracing.scope(tracing.MOE_EXPERTS):
-                def grouped(rows, w):
-                    return gmm(rows, w, tile_group, 128, tiles_used)
-
-                h = grouped(lhs, w_gate.astype(cfg.dtype))
-                u = grouped(lhs, w_up.astype(cfg.dtype))
+                h = gmm(lhs, w_gate.astype(cfg.dtype), tile_group)
+                u = gmm(lhs, w_up.astype(cfg.dtype), tile_group)
                 act = nn.silu(h) * u
-                eo = grouped(act, w_down.astype(cfg.dtype))
+                eo = gmm(act, w_down.astype(cfg.dtype), tile_group)
             with tracing.scope(tracing.MOE_COMBINE):
                 out2 = _slots_to_rows(
                     eo, gate_vals.astype(cfg.dtype).reshape(B * T, K),

@@ -30,6 +30,19 @@ v5e); what is left is the MXU's 1.57 ms on the padded rows, a fetch
 that a 2.7 us step cannot hide at each change of expert, and 576 grid
 steps.
 
+A layout may be a bound that its rows do not fill: one expert-parallel
+rank's share keeps room for every pair, N + (E_held + 1) * block_m rows,
+and an expected E_held / E of them arrive. Such a caller passes
+`tiles_used`, and the bound then costs memory and no time: a tile past
+the used ones is not fetched, not multiplied and not written, forward or
+backward, so those rows of every output are whatever the buffer held
+(`unwritten` makes such a buffer for the caller's own passes), and
+whoever reads the layout stops at `tiles_used` as the kernels do. A
+skipped grid step costs about 0.2 us where writing its zeros cost 0.3
+to 0.7: a call at Kimi-Linear's shapes, 1,056 tiles of which ~60 hold
+rows, takes 0.22 ms at either width where it took 0.54 (1024 columns)
+and 1.05 (2304) (PERF.md §6, PR 33).
+
 Backward: dlhs is the same kernel contracting the weights' last
 dimension as they are stored (no transposed copy of them; Mosaic
 lowers it at the forward's speed: 2.02-2.05 ms); drhs is a
@@ -100,20 +113,18 @@ def _gmm_kernel(tg_ref, *refs, transpose_rhs, bounded=False):
     if not bounded:
         compute()
         return
-    # A tile past the used ones holds no row: its output is zeros, and no
-    # input block is fetched for it (`_used`).
+    # A tile past the used ones holds no row: nothing is fetched for it and
+    # nothing written (`_used` keeps every block where the last used tile
+    # left it).
     pl.when(pl.program_id(1) < used_ref[0])(compute)
-
-    @pl.when(pl.program_id(1) >= used_ref[0])
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
 
 
 def _used(index):
     """``index`` for a call that is told how many row tiles hold rows
     (``tiles_used``, the second prefetched scalar): a tile past them takes
-    the input block of the last used one, which the pipeline holds already
-    and does not fetch again."""
+    the block of the last used one, which the pipeline holds already: an
+    input is not fetched again, and an output goes back once, as that
+    tile's step left it, when the grid moves on or ends."""
     def bounded(*args):
         *grid, tile = args[:-2]
         tg, used = args[-2:]
@@ -139,20 +150,17 @@ def _gmm_grid(m, k, n, block_m, block_n, transpose_rhs=False, bounded=False):
     are the grid's inner dimension and the weight block's index follows
     the tile only through its expert, so over an expert's consecutive
     tiles the pipeline keeps the block it holds and fetches none.
-    ``bounded``: the call takes ``tiles_used`` (`_used`); every tile's
-    output block is still its own, and written."""
+    ``bounded``: the call takes ``tiles_used`` (`_used`), and the output
+    blocks of the tiles past them are never written."""
     at = _used if bounded else (lambda index: index)
     if transpose_rhs:
         rhs_spec = pl.BlockSpec((1, block_n, k), at(_gmm_rhs_t_index))
     else:
         rhs_spec = pl.BlockSpec((1, k, block_n), at(_gmm_rhs_index))
-    out_index = _gmm_out_index
-    if bounded:
-        out_index = lambda j, i, tg, used: _gmm_out_index(j, i, tg)  # noqa: E731
     return (
         (n // block_n, m // block_m),
         [pl.BlockSpec((block_m, k), at(_gmm_lhs_index)), rhs_spec],
-        pl.BlockSpec((block_m, block_n), out_index),
+        pl.BlockSpec((block_m, block_n), at(_gmm_out_index)),
     )
 
 
@@ -160,8 +168,9 @@ def _gmm_pallas(lhs, rhs, tile_group, block_m, transpose_rhs=False,
                 tiles_used=None):
     """out[tile t] = lhs[tile t] @ rhs[tile_group[t]]; with
     `transpose_rhs`, @ rhs[tile_group[t]]^T, contracted on the weights'
-    last dimension as they are stored. With ``tiles_used`` [1] int32, tiles
-    from that one on are zeros and cost a block's write."""
+    last dimension as they are stored. With ``tiles_used`` [1] int32, the
+    rows of the tiles from that one on are not written: they hold whatever
+    the buffer held."""
     m, k = lhs.shape
     n = rhs.shape[1 if transpose_rhs else 2]
     block_n = _gmm_block_n(k, n, lhs.dtype.itemsize, block_m)
@@ -318,9 +327,11 @@ def gmm(lhs, rhs, tile_group, block_m: int = 128, tiles_used=None):
 
     ``tiles_used`` [1] int32 (at least 1), where the layout is a static
     bound that the rows do not fill: tiles from that one on hold no row.
-    Their output rows are zeros, their inputs are not read and their matmuls
-    not computed, forward and backward; ``tile_group`` names the last group
-    for them.
+    Their inputs are not read, their matmuls not computed and their output
+    rows not written, forward and backward: those rows of ``out`` and of
+    ``lhs``'s gradient are uninitialised memory, and whoever reads the
+    layout stops at ``tiles_used`` too. ``tile_group`` names the last
+    group for them.
     """
     return _gmm_fwd(lhs, rhs, tile_group, block_m, tiles_used)[0]
 
@@ -349,6 +360,27 @@ def _gmm_bwd(block_m, res, dout):
 gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
+def _unwritten_kernel(after_ref, out_ref):
+    pass
+
+
+def unwritten(shape, dtype, after):
+    """An uninitialised buffer that exists once ``after`` does, for a loop
+    that fills the part of a bounded layout that holds rows (a bounded
+    ``gmm`` call leaves the rest of its output as this leaves all of it). A
+    kernel that writes nothing, and not ``lax.empty`` or zeros: a loop
+    carried from an allocation that has no operand sends XLA's TPU scheduler
+    to program order, every optimizer update after the last backward op,
+    and Kimi-Linear's step holds 1.4 GiB more (PERF.md §6, PR 33)."""
+    return pl.pallas_call(
+        _unwritten_kernel,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        interpret=_interpret(),
+    )(after)
+
+
 def aligned_group_layout(e_flat, num_groups: int, block_m: int = 128):
     """Tile-aligned destinations for group-sorted dispatch.
 
@@ -367,20 +399,22 @@ def aligned_group_layout(e_flat, num_groups: int, block_m: int = 128):
     """
     n = e_flat.shape[0]
     m_pad = -(-(n + num_groups * block_m) // block_m) * block_m
-    sizes = jnp.bincount(e_flat, length=num_groups)  # [E]
+    # Counts and per-row lookups of a [num_groups] table are compares
+    # against the group ids, groups on the major axis, which XLA fuses
+    # into one pass over the rows: an element-wise gather or scatter of n
+    # scalars takes 0.6-1.1 ms at 131,072 rows on a v5e, the sort 0.1
+    # (PERF.md §6, PR 33).
+    ids = jnp.arange(num_groups, dtype=jnp.int32)[:, None]
+    sizes = jnp.sum(ids == e_flat[None, :], axis=1, dtype=jnp.int32)  # [E]
     aligned = jnp.maximum(-(-sizes // block_m), 1) * block_m
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), aligned.dtype), jnp.cumsum(aligned)[:-1]]
-    )
-    raw_starts = jnp.concatenate(
-        [jnp.zeros((1,), sizes.dtype), jnp.cumsum(sizes)[:-1]]
-    )
-    order = jnp.argsort(e_flat)  # stable
-    e_sorted = e_flat[order]
-    rank = jnp.arange(n, dtype=jnp.int32) - raw_starts[e_sorted].astype(
-        jnp.int32
-    )
-    dst = starts[e_sorted].astype(jnp.int32) + rank
+    starts = jnp.cumsum(aligned) - aligned
+    raw_starts = jnp.cumsum(sizes) - sizes
+    e_sorted, order = jax.lax.sort(
+        (e_flat.astype(jnp.int32), jnp.arange(n, dtype=jnp.int32)), num_keys=1
+    )  # stable
+    # A sorted row moves by its group's padding so far.
+    shift = jnp.where(ids == e_sorted[None, :], (starts - raw_starts)[:, None], 0)
+    dst = jnp.arange(n, dtype=jnp.int32) + shift.sum(0)
     tile_start = jnp.arange(m_pad // block_m, dtype=jnp.int32) * block_m
     tile_group = (
         jnp.searchsorted(starts, tile_start, side="right").astype(jnp.int32)

@@ -95,27 +95,34 @@ def bounded_case(sizes, tail, k=64, n=128, seed=0):
     ((128, 128), 0),  # nothing elsewhere: the tail is the layout's padding
     ((5,), 2000),
 ], ids=["mixed", "all-here", "nearly-none"])
-def test_tiles_past_the_used_ones_are_zeros_and_cost_no_gradient(sizes, tail):
+def test_tiles_past_the_used_ones_are_not_touched_and_cost_no_gradient(sizes, tail):
+    """A bounded call promises nothing about the rows past ``tiles_used``,
+    in its output or in ``lhs``'s gradient, and reads none of them: NaN
+    there, in the rows and in the cotangent, reaches no row it does
+    promise and no weight gradient."""
     lhs, rhs, tile_group, used, raw = bounded_case(sizes, tail)
-    groups = len(sizes)
-    # the oracle: every tile computed, the unused ones masked after
-    live = jnp.repeat(raw < groups, 128)[:, None]
-    want = jnp.where(live, gmm(lhs, rhs, tile_group), 0.0)
-    got = gmm(lhs, rhs, tile_group, 128, used)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    assert not np.asarray(got)[int(used[0]) * 128:].any()
-    w = jnp.asarray(np.random.default_rng(2).normal(size=got.shape), jnp.float32)
-    g_got = jax.grad(lambda a, b: jnp.sum(gmm(a, b, tile_group, 128, used) * w), (0, 1))(lhs, rhs)
-    g_want = jax.grad(
-        lambda a, b: jnp.sum(jnp.where(live, gmm(a, b, tile_group), 0.0) * w), (0, 1))(lhs, rhs)
-    for a, b in zip(g_got, g_want):
-        assert np.isfinite(np.asarray(a)).all()
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
-    # garbage past the used tiles is neither read nor written through
-    dirty = lhs.at[int(used[0]) * 128:].set(jnp.nan)
-    clean = gmm(dirty, rhs, tile_group, 128, used)
-    assert np.isfinite(np.asarray(clean)).all()
-    np.testing.assert_allclose(clean, got, rtol=1e-6, atol=1e-6)
+    rows = int(used[0]) * 128
+    assert (np.asarray(raw)[:rows // 128] < len(sizes)).all()
+    assert (np.asarray(raw)[rows // 128:] == len(sizes)).all()
+    w = jnp.asarray(np.random.default_rng(2).normal(size=(lhs.shape[0], rhs.shape[2])), jnp.float32)
+
+    def loss(call):
+        return lambda a, b: jnp.sum((call(a, b) * w)[:rows])
+
+    # the oracle: every tile computed, on rows that hold zeros past the used ones
+    want = gmm(lhs, rhs, tile_group)
+    g_want = jax.grad(loss(lambda a, b: gmm(a, b, tile_group)), (0, 1))(lhs, rhs)
+    dirty = lhs.at[rows:].set(jnp.nan)
+    bounded = lambda a, b: gmm(a, b, tile_group, 128, used)  # noqa: E731
+    got = bounded(dirty, rhs)
+    np.testing.assert_allclose(got[:rows], want[:rows], rtol=1e-5, atol=1e-5)
+    # the cotangent of an unused row is whatever its consumer left there
+    g_got = jax.grad(
+        lambda a, b: jnp.sum(bounded(a, b) * w.at[rows:].set(jnp.nan)), (0, 1)
+    )(dirty, rhs)
+    np.testing.assert_allclose(g_got[0][:rows], g_want[0][:rows], rtol=1e-4, atol=1e-4)
+    assert np.isfinite(np.asarray(g_got[1])).all()
+    np.testing.assert_allclose(g_got[1], g_want[1], rtol=1e-4, atol=1e-4)
 
 
 # ------------------------------------------------ the models that were there

@@ -185,6 +185,52 @@ def test_the_models_that_were_there_lower_to_the_kernels_they_had(v5e, monkeypat
         placed(shapes), placed(jax.eval_shape(tx.init, shapes)), batch, batch
     ).as_text()
     names = ("_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel", "_gmm_kernel",
-             "_tgmm_kernel", "_kda_fwd_kernel", "_kda_bwd_kernel")
+             "_tgmm_kernel", "_kda_fwd_kernel", "_kda_bwd_kernel", "_unwritten_kernel")
     counts = {k: n for k, n in checks.count_pallas_kernels(text, names).items() if n}
     assert counts == KERNELS_BEFORE[name]
+
+
+# Kimi-Linear's step at the benchmark's real size (b1 x s16384, five layers at
+# the published widths): every kernel its configuration states, and the held
+# share's rows moved a window of tiles at a time, never over the static bound
+# of every (token, expert) pair.
+def test_kimi_linears_step_holds_its_kernels_and_no_gather_over_the_bound(v5e, monkeypatch):
+    import importlib
+    import re
+
+    import numpy as np
+
+    from benchmarks.lib import cells, checks
+    from benchmarks.loops.train_lm import make_loss_fn, make_optimizer
+    from ray_tpu import train
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cell = cells.load_cell("kimi-linear-48b-a3b-l5.longctx-16k")
+    config, traffic = cell["config"], cell["traffic"]
+    model = cells.resolve(config["program"]["model"])(cells.program_config(config))
+    shapes = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e), tree)
+
+    tx = make_optimizer(traffic)
+    batch = jax.ShapeDtypeStruct(
+        (traffic["batch"], traffic["seq"]), np.int32, sharding=v5e)
+    text = train.make_train_step(make_loss_fn(traffic, model), tx).lower(
+        placed(shapes), placed(jax.eval_shape(tx.init, shapes)), batch, batch
+    ).as_text()
+    stated = cells.stated_kernels(cell)
+    counts = checks.count_pallas_kernels(text, stated)
+    assert checks.holds_stated_kernels(counts, stated), (counts, stated)
+    pairs = traffic["batch"] * traffic["seq"] * config["num_experts_per_token"]
+    gathered = [
+        int(rows) for rows in re.findall(
+            r'"stablehlo\.gather".*\) -> tensor<(?:1x)?(\d+)x', text)
+    ]
+    width = config["hidden_size"]
+    assert f"-> tensor<2048x{width}xbf16>" in text  # a window of sixteen tiles
+    assert gathered and max(gathered) < pairs, sorted(set(gathered))
+
