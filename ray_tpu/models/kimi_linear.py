@@ -12,8 +12,10 @@ KDA (``KDAMixer``): q, k, v projections, each through a causal depthwise
 convolution of 4 taps and SiLU; q and k L2-normalised per head; a
 per-channel log-decay g = -exp(A_log) softplus(W_f2 W_f1 x + dt_bias)
 through a low-rank map of the head dim; a write strength beta = sigmoid(W_b
-x) per head; the recurrence of ``ops/kda.py``; a per-head RMSNorm gated by
-sigmoid(W_g2 W_g1 x); the output projection.
+x) per head; the recurrence; a per-head RMSNorm gated by sigmoid(W_g2 W_g1
+x); the output projection. The three normalisations over a head's channels
+and the output gate are ``ops/kda.py``'s, on the blocks its kernels hold:
+the mixer hands it q and k raw and takes o normalised and gated.
 
 MLA (``MLAMixer``): q heads of 128 + 64; keys and values from a shared
 latent of 512 (down-projection, RMSNorm, up-projection to 128 + 128 a head)
@@ -36,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import flash_attention
-from ..ops.kda import chunk_kda, kda_gate, l2norm, short_conv
+from ..ops.kda import chunk_kda, kda_gate, short_conv
 from ..util import tracing
 from .llama import RMSNorm, weight_init
 from .mixtral import MixtralConfig, MixtralForCausalLM
@@ -121,6 +123,16 @@ def _dense(cfg, features, name, use_bias=False, dtype=None):
     )
 
 
+class NormWeight(nn.Module):
+    """The weight of a norm that a kernel applies: ``RMSNorm``'s parameter
+    under the name and shape ``RMSNorm`` gives it."""
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, dim):
+        return self.param("scale", nn.initializers.ones, (dim,), self.param_dtype)
+
+
 class KDAMixer(nn.Module):
     cfg: KimiLinearConfig
     mesh: Optional[Any] = None  # one device: the recurrence is not sharded
@@ -155,18 +167,20 @@ class KDAMixer(nn.Module):
                 self.param("dt_bias", _dt_bias_init, (H * d,), f32).reshape(H, d),
             )
             beta = jax.nn.sigmoid(b)
-        # The convolution, SiLU and normalisation stay in float32 (XLA fuses
-        # them into one pass over each projection); q, k and v are rounded
-        # once, where the kernel takes them.
-        with tracing.scope(tracing.KDA_SCAN):
-            q = (l2norm(q) * d ** -0.5).astype(cfg.dtype)
-            o = chunk_kda(q, l2norm(k).astype(cfg.dtype), v.astype(cfg.dtype), g, beta)
         gate = _dense(cfg, H * d, "g_b_proj", use_bias=True)(
             _dense(cfg, d, "g_a_proj")(x)
         )
-        with tracing.scope(tracing.KDA_OUT_NORM):
-            o = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="o_norm")(o.astype(f32))
-            o = (o * jax.nn.sigmoid(gate.reshape(B, T, H, d).astype(f32))).astype(cfg.dtype)
+        # The convolution and SiLU stay in float32. q and k go to the scan
+        # as they are, and so does the output gate: its kernels normalise a
+        # head's channels on the blocks they hold (q's and k's L2 norm with
+        # one rounding to v's dtype; o's RMSNorm and gate with one rounding
+        # as it is stored). v is rounded here, in the pass that convolves it.
+        with tracing.scope(tracing.KDA_SCAN):
+            o = chunk_kda(
+                q, k, v.astype(cfg.dtype), g, beta, gate.reshape(B, T, H, d),
+                NormWeight(cfg.param_dtype, name="o_norm")(d),
+                scale=d ** -0.5, rms_eps=cfg.rms_eps,
+            )
         return _dense(cfg, cfg.hidden_size, "o_proj")(o.reshape(B, T, H * d))
 
 

@@ -10,6 +10,16 @@ write strength. ``chunk_kda`` computes it in chunks of 64 tokens: inside a
 chunk the rule is a unit lower-triangular system (the WY form of the
 products of Householder-like factors), between chunks the state is carried.
 
+Who normalises: this file. The mixer's three normalisations over a head's
+channels are sums over the lanes of a row the scan already holds, so they
+are taken there and not as passes over [T, H * d] arrays in HBM
+(``_normed_chunk``): q and k arrive raw and float32, as the mixer's
+convolution and SiLU leave them, and become ``l2norm(q) * scale`` and
+``l2norm(k)`` in float32 with one rounding to the matmuls' dtype; o leaves
+through its RMSNorm and the output gate's sigmoid while it is float32 and
+is rounded once, as it is stored. The gradients are those of the raw q and
+k, of the gate and of the norm's weight.
+
 Inside a chunk, with G the running sum of g inside it:
 
     A[t, s]   = b_t sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c])      s < t
@@ -59,13 +69,14 @@ Called under a gradient it also writes the state at every chunk's start (256
 chunks x 32 heads x 64 KiB = 512 MiB a layer at 16k tokens, alive inside
 one layer's backward pass under the decoder's per-layer remat). The backward
 kernel walks the chunks in reverse with the states' cotangents in VMEM and
-differentiates ``_head_chunk`` of the stacked pair where it stands
+differentiates ``_normed_chunk`` of the stacked pair where it stands
 (``jax.vjp`` inside the kernel, from the saved states: a replay of one chunk,
 nothing of a chunk's interior ever in HBM), then takes G's cotangent back to
-g's in one matmul. Elsewhere the same function runs under ``lax.scan``, one
-head a call, and JAX differentiates it. Matmul operands are the inputs'
-dtype (bfloat16 in the models), accumulation, the running sums and the state
-float32.
+g's in one matmul; the cotangent of the norm's weight adds up over a batch
+row's steps in the row's output block. Elsewhere the same function runs
+under ``lax.scan``, one head a call, and JAX differentiates it. Matmul
+operands are v's dtype (bfloat16 in the models), accumulation, the
+normalisations, the running sums and the state float32.
 """
 from __future__ import annotations
 
@@ -270,6 +281,25 @@ def _head_chunk(St, q, k, v, beta, G, last, last_dv, roll=_xla_roll):
     return jnp.exp(last_dv) * St + _per_head(_tn, u, kd, p), o
 
 
+def _normed_chunk(St, q, k, v, beta, G, last, last_dv, gate, weight, *, norm,
+                  roll=_xla_roll):
+    """``_head_chunk`` between the mixer's normalisations, each over a row
+    (a head's channels of one token) in float32. q and k come as the
+    convolution and SiLU leave them: L2-normalised, q scaled, then the one
+    rounding to the matmuls' dtype, which is v's. o leaves through the
+    per-head RMSNorm (``weight`` [1, dv] float32) and the output gate (``gate`` [P *
+    C, dv], before its sigmoid), still float32. ``norm`` is (q's scale, the
+    L2 norm's epsilon, the RMSNorm's). Differentiated as one function, so
+    the cotangents are those of the raw q and k, of the gate and of the
+    weight."""
+    scale, l2_eps, rms_eps = norm
+    q = (l2norm(q, l2_eps) * scale).astype(v.dtype)
+    k = l2norm(k, l2_eps).astype(v.dtype)
+    St, o = _head_chunk(St, q, k, v, beta, G, last, last_dv, roll)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + rms_eps)
+    return St, o * weight * jax.nn.sigmoid(gate.astype(F32))
+
+
 # ------------------------------------------------------------ Pallas kernels
 # Grid (batch, chunk, heads // P), the heads innermost and P of them a
 # step: every head's state stays in VMEM over a sequence's chunks, and the
@@ -316,7 +346,8 @@ def _stacked(refs, beta_ref, d_scr, sums, p):
             *(_stack(d_scr[at], p) for at in sums))
 
 
-def _kda_fwd_kernel(m_ref, g_ref, q_ref, k_ref, v_ref, beta_ref, o_ref, *rest):
+def _kda_fwd_kernel(m_ref, g_ref, q_ref, k_ref, v_ref, beta_ref, gate_ref,
+                    w_ref, o_ref, *rest, norm):
     # rest: (the states' output, the two scratches) or the scratches alone.
     s_ref, d_scr, st_scr = rest if len(rest) == 3 else (None, *rest)
     step, p = pl.program_id(2), beta_ref.shape[1]
@@ -330,17 +361,18 @@ def _kda_fwd_kernel(m_ref, g_ref, q_ref, k_ref, v_ref, beta_ref, o_ref, *rest):
     St = st_scr[step]
     if s_ref is not None:
         s_ref[0, 0] = _unstack(St, p)
-    st_scr[step], o = _head_chunk(
+    st_scr[step], o = _normed_chunk(
         St, *_stacked((q_ref, k_ref, v_ref), beta_ref, d_scr,
                       _step_sums(d_scr, p, dk, dv), p),
-        roll=_roll_here(),
+        _stack(gate_ref[0], p), w_ref[...], norm=norm, roll=_roll_here(),
     )
     o_ref[0] = _unstack(o, p).astype(o_ref.dtype)
 
 
 def _kda_bwd_kernel(m_ref, mt_ref, g_ref, q_ref, k_ref, v_ref, beta_ref,
-                    s_ref, do_ref, dq_ref, dk_ref, dv_ref, dbeta_ref, dg_ref,
-                    d_scr, dd_scr, dst_scr):
+                    gate_ref, w_ref, s_ref, do_ref, dq_ref, dk_ref, dv_ref,
+                    dbeta_ref, dg_ref, dgate_ref, dw_ref, d_scr, dd_scr,
+                    dst_scr, *, norm):
     step, p = pl.program_id(2), beta_ref.shape[1]
     dv, dk = dst_scr.shape[1] // p, dst_scr.shape[2]
 
@@ -348,20 +380,29 @@ def _kda_bwd_kernel(m_ref, mt_ref, g_ref, q_ref, k_ref, v_ref, beta_ref,
     def _init():
         dst_scr[step] = jnp.zeros((p * dv, dk), F32)
 
+    # The weight's cotangent adds up over a batch row's steps in its output
+    # block, which stays in VMEM while the block's index (the row) stands.
+    @pl.when((pl.program_id(1) == 0) & (step == 0))
+    def _init_dw():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, F32)
+
     _take_sums(m_ref, g_ref, d_scr)
     sums = _step_sums(d_scr, p, dk, dv)
     _, vjp = jax.vjp(
-        functools.partial(_head_chunk, roll=_roll_here()),
+        functools.partial(_normed_chunk, norm=norm, roll=_roll_here()),
         _stack(s_ref[0, 0], p),
         *_stacked((q_ref, k_ref, v_ref), beta_ref, d_scr, sums, p),
+        _stack(gate_ref[0], p), w_ref[...],
     )
-    dst, dq, dk_, dv_, dbeta, *dsums = vjp(
+    dst, dq, dk_, dv_, dbeta, *dsums, dgate, dw = vjp(
         (dst_scr[step], _stack(do_ref[0], p).astype(F32))
     )
     dst_scr[step] = dst
     dq_ref[0] = _unstack(dq, p).astype(dq_ref.dtype)
     dk_ref[0] = _unstack(dk_, p).astype(dk_ref.dtype)
     dv_ref[0] = _unstack(dv_, p).astype(dv_ref.dtype)
+    dgate_ref[0] = _unstack(dgate, p).astype(dgate_ref.dtype)
+    dw_ref[0] += dw
     for i, part in enumerate(_heads_of(dbeta, p)):
         dbeta_ref[0, i] = part
     for at, ds in zip(sums, dsums):
@@ -400,6 +441,8 @@ def _specs(heads, dk, dv, chunk_of):
         "state": pl.BlockSpec((1, 1, dv, p * dk),
                               lambda b, n, h: (b, chunk_of(n), 0, h)),
         "m": whole(2 * CHUNK + dv, CHUNK), "mt": whole(CHUNK, 2 * CHUNK + dv),
+        "weight": whole(1, dv),
+        "dweight": pl.BlockSpec((1, 1, dv), lambda b, n, h: (b, 0, 0)),
         "steps": heads // p,
         "states": pltpu.VMEM((heads // p, p * dv, dk), F32),
     }
@@ -412,15 +455,16 @@ def _params():
     )
 
 
-def _forward_pallas(q, k, v, g, beta, heads, states):
+def _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm, states):
     batch, t, _ = q.shape
     dk, dv, n = q.shape[2] // heads, v.shape[2] // heads, t // CHUNK
     s = _specs(heads, dk, dv, lambda i: i)
     m = jnp.asarray(_sum_matrix(dv), jnp.bfloat16)
     return pl.pallas_call(
-        _kda_fwd_kernel,
+        functools.partial(_kda_fwd_kernel, norm=norm),
         grid=(batch, n, s["steps"]),
-        in_specs=[s["m"], s["g"], s["k"], s["k"], s["v"], s["beta"]],
+        in_specs=[s["m"], s["g"], s["k"], s["k"], s["v"], s["beta"], s["v"],
+                  s["weight"]],
         out_specs=[s["v"], s["state"]][:1 + states],
         out_shape=[
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -429,58 +473,66 @@ def _forward_pallas(q, k, v, g, beta, heads, states):
         scratch_shapes=[pltpu.VMEM((m.shape[0], heads * dk), F32), s["states"]],
         compiler_params=_params(),
         interpret=_attention._interpret(),
-    )(m, g, q, k, v, beta)
+    )(m, g, q, k, v, beta, gate, weight)
 
 
-def _backward_pallas(q, k, v, g, beta, states, do, heads):
+def _backward_pallas(q, k, v, g, beta, gate, weight, states, do, heads, norm):
     batch, t, _ = q.shape
     dk, dv, n = q.shape[2] // heads, v.shape[2] // heads, t // CHUNK
     s = _specs(heads, dk, dv, lambda i: n - 1 - i)
     m = _sum_matrix(dv)
     sums = pltpu.VMEM((m.shape[0], heads * dk), F32)
     return pl.pallas_call(
-        _kda_bwd_kernel,
+        functools.partial(_kda_bwd_kernel, norm=norm),
         grid=(batch, n, s["steps"]),
         in_specs=[s["m"], s["mt"], s["g"], s["k"], s["k"], s["v"], s["beta"],
-                  s["state"], s["v"]],
-        out_specs=[s["k"], s["k"], s["v"], s["beta"], s["g"]],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
-                   for x in (q, k, v, beta, g)],
+                  s["v"], s["weight"], s["state"], s["v"]],
+        out_specs=[s["k"], s["k"], s["v"], s["beta"], s["g"], s["v"],
+                   s["dweight"]],
+        out_shape=[
+            *(jax.ShapeDtypeStruct(x.shape, x.dtype)
+              for x in (q, k, v, beta, g, gate)),
+            jax.ShapeDtypeStruct((batch, *weight.shape), F32),
+        ],
         scratch_shapes=[sums, sums, s["states"]],
         compiler_params=_params(),
         interpret=_attention._interpret(),
     )(jnp.asarray(m, jnp.bfloat16), jnp.asarray(m.T, jnp.bfloat16),
-      g, q, k, v, beta, states, do)
+      g, q, k, v, beta, gate, weight, states, do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kda_pallas(q, k, v, g, beta, heads):
-    """o [B, T, H * dv] from q, k, g [B, T, H * dk], v [B, T, H * dv] and
-    beta [B, H, T, 1], T a whole number of chunks.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _kda_pallas(q, k, v, g, beta, gate, weight, heads, norm):
+    """The gated, normalised o [B, T, H * dv] from q, k, g [B, T, H * dk], v
+    and the gate [B, T, H * dv], beta [B, H, T, 1] and the norm's weight [1,
+    dv], T a whole number of chunks; q and k raw, ``norm`` as
+    ``_normed_chunk`` takes it.
 
     Called outside a gradient it writes no states: a call that did would be
     the twin of the one a remat replay makes, XLA would merge the two, and
     every layer's 512 MiB of states would live from the forward pass to the
     backward."""
-    return _forward_pallas(q, k, v, g, beta, heads, states=False)[0]
+    return _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm,
+                           states=False)[0]
 
 
-def _kda_pallas_fwd(q, k, v, g, beta, heads):
-    o, states = _forward_pallas(q, k, v, g, beta, heads, states=True)
-    return o, (q, k, v, g, beta, states)
+def _kda_pallas_fwd(q, k, v, g, beta, gate, weight, heads, norm):
+    o, states = _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm,
+                                states=True)
+    return o, (q, k, v, g, beta, gate, weight, states)
 
 
-def _kda_pallas_bwd(heads, residuals, do):
-    dq, dk, dv, dbeta, dg = _backward_pallas(
-        *residuals, do.astype(residuals[2].dtype), heads
+def _kda_pallas_bwd(heads, norm, residuals, do):
+    dq, dk, dv, dbeta, dg, dgate, dw = _backward_pallas(
+        *residuals, do.astype(residuals[2].dtype), heads, norm
     )
-    return dq, dk, dv, dg, dbeta
+    return dq, dk, dv, dg, dbeta, dgate, dw.sum(0)
 
 
 _kda_pallas.defvjp(_kda_pallas_fwd, _kda_pallas_bwd)
 
 
-def _kda_xla(q, k, v, g, beta, heads):
+def _kda_xla(q, k, v, g, beta, gate, weight, heads, norm):
     """The same function of the same layouts under ``lax.scan``, for JAX to
     differentiate: where there is no TPU."""
     batch, t, _ = q.shape
@@ -495,32 +547,43 @@ def _kda_xla(q, k, v, g, beta, heads):
                       precision=jax.lax.Precision.HIGHEST)
     beta = beta.reshape(batch, heads, n, CHUNK, 1).transpose(2, 0, 1, 3, 4)
 
-    def one(St, q, k, v, beta, d):
-        return _head_chunk(St, q, k, v, beta, d[:CHUNK], d[CHUNK:2 * CHUNK],
-                           d[2 * CHUNK:])
+    def one(St, q, k, v, beta, d, gate):
+        return _normed_chunk(St, q, k, v, beta, d[:CHUNK], d[CHUNK:2 * CHUNK],
+                             d[2 * CHUNK:], gate, weight, norm=norm)
 
     def step(St, chunk):
         return jax.vmap(jax.vmap(one))(St, *chunk)
 
     start = jnp.zeros((batch, heads, dv, q.shape[2] // heads), F32)
-    _, o = jax.lax.scan(step, start, (chunks(q), chunks(k), chunks(v), beta, sums))
+    _, o = jax.lax.scan(
+        step, start, (chunks(q), chunks(k), chunks(v), beta, sums, chunks(gate)))
     return o.transpose(1, 0, 3, 2, 4).reshape(batch, t, heads * dv).astype(v.dtype)
 
 
-def chunk_kda(q, k, v, g, beta):
-    """The recurrence at the top of this file, chunked. q, k [B, T, H, dk]
-    (q already scaled, both already normalised); v [B, T, H, dv]; g [B, T,
-    H, dk] float32 log-decay (<= 0); beta [B, T, H] in (0, 1). Returns o
-    [B, T, H, dv] in v's dtype. Differentiable in all five."""
+def chunk_kda(q, k, v, g, beta, gate, weight, *, scale, rms_eps, l2_eps=1e-6):
+    """A KDA mixer from its convolutions' outputs to its output projection's
+    input: the recurrence at the top of this file, chunked, of ``l2norm(q,
+    l2_eps) * scale`` and ``l2norm(k, l2_eps)``, then o's RMSNorm over a
+    head's channels (``weight`` [dv], ``rms_eps`` inside the root) times
+    ``sigmoid(gate)``. Nobody normalises outside: q, k [B, T, H, dk] come
+    raw, as the convolution and SiLU leave them (float32), and the kernels
+    normalise the blocks they load, in float32, with one rounding to v's
+    dtype, the matmuls'; o is normalised and gated where the forward kernel
+    holds it in float32 and rounded once, to v's dtype, as it is stored. v,
+    gate [B, T, H, dv], the gate before its sigmoid; g [B, T, H, dk] float32
+    log-decay (<= 0); beta [B, T, H] in (0, 1). Returns [B, T, H, dv].
+    Differentiable in all seven; the cotangents of q and k are those of the
+    raw ones."""
     batch, t, heads, _ = q.shape
     pad = -t % CHUNK
     if pad:
         # Padding tokens write nothing (beta 0) and decay nothing (g 0).
         widths = ((0, 0), (0, pad), (0, 0), (0, 0))
-        q, k, v, g = (jnp.pad(x, widths) for x in (q, k, v, g))
+        q, k, v, g, gate = (jnp.pad(x, widths) for x in (q, k, v, g, gate))
         beta = jnp.pad(beta, widths[:3])
     flat = lambda x: x.reshape(batch, t + pad, -1)  # noqa: E731
     beta = beta.astype(F32).transpose(0, 2, 1)[..., None]  # [B, H, T', 1]
     run = _kda_pallas if _attention._on_tpu() or _attention._interpret() else _kda_xla
-    o = run(flat(q), flat(k), flat(v), flat(g.astype(F32)), beta, heads)
+    o = run(flat(q), flat(k), flat(v), flat(g.astype(F32)), beta, flat(gate),
+            weight.astype(F32)[None], heads, (scale, l2_eps, rms_eps))
     return o.reshape(batch, t + pad, heads, -1)[:, :t]

@@ -46,12 +46,13 @@ KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer)
 MLA = "mla"  # the latent-attention mixer (models/kimi_linear.py MLAMixer)
 KDA_CONV = "conv"  # inside kda: the short convolutions of q, k, v and their SiLU
 KDA_GATE = "gate"  # inside kda: the log-decay g and the write strength beta
-KDA_SCAN = "scan"  # inside kda: q/k normalisation and ops/kda.py chunk_kda
-KDA_OUT_NORM = "out_norm"  # inside kda: the per-head RMSNorm and the output gate
+KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's and o's norms and the output gate), v's rounding, beta's transpose
+# (No "out_norm": o's per-head RMSNorm and output gate left XLA for the scan's
+# kernels, and a scope that no operation carries is not in this list.)
 MLA_LATENT = "latent"  # inside mla: down-projection, norm, up-projection of K/V
 SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
-          KDA_OUT_NORM, MLA_LATENT)
+          MLA_LATENT)
 MIXERS = (KDA, MLA)  # flax module names, bound in KimiLinearForCausalLM.blocks
 
 _OFF = contextlib.nullcontext()

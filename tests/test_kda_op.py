@@ -1,19 +1,48 @@
 """The gated delta rule of ``ray_tpu/ops/kda.py`` on the CPU: the chunked
-function and its ``custom_vjp`` against the token-by-token recurrence,
-forward and every gradient, at several sequence lengths and at a decay near
-0 and near 1; the Pallas scan kernels in interpret mode against the same,
-two heads a grid step and, where the heads do not pair off, one; a head
-through the pair path against the same head alone, bit for bit; the short
-convolution and the gate beside it.
+function and its ``custom_vjp``, which take q and k raw and give o
+normalised and gated, against the plain way (L2 norms, the token-by-token
+recurrence, ``RMSNorm``, the gate's sigmoid), forward and every gradient, at
+several sequence lengths and at a decay near 0 and near 1; the Pallas scan
+kernels in interpret mode against the same, two heads a grid step and, where
+the heads do not pair off, one; a head through the pair path against the
+same head alone, bit for bit; rows of zeros; the weight's gradient over
+batch rows; the short convolution and the gate beside it.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.models.llama import RMSNorm
 from ray_tpu.ops import kda
 
 B, H, DK, DV = 2, 2, 32, 16
+SCALE, RMS_EPS = DK ** -0.5, 1e-5
+NAMES = "q k v g beta gate weight".split()
+chunk_kda = functools.partial(kda.chunk_kda, scale=SCALE, rms_eps=RMS_EPS)
+
+
+def fresh():
+    """``chunk_kda`` as a new function object: ``jit`` and ``make_jaxpr`` keep
+    their traces by the function, and which path a trace took (the kernels
+    or ``lax.scan``) follows RAY_TPU_PALLAS_INTERPRET, which they do not
+    see."""
+    return lambda *a: chunk_kda(*a)
+
+
+def gated_norm(o, gate, weight):
+    """The mixer's way out of the recurrence before the kernels took it:
+    ``RMSNorm`` over a head's channels, then the output gate."""
+    normed = RMSNorm(RMS_EPS).apply({"params": {"scale": weight}}, o)
+    return normed * jax.nn.sigmoid(gate)
+
+
+def oracle(q, k, v, g, beta, gate, weight):
+    """What ``chunk_kda`` computes, the plain way."""
+    o = recurrence(kda.l2norm(q) * SCALE, kda.l2norm(k), v, g, beta)
+    return gated_norm(o, gate, weight)
 
 
 def recurrence(q, k, v, g, beta):
@@ -34,30 +63,29 @@ def recurrence(q, k, v, g, beta):
 
 
 def inputs(t, decay, seed=0, heads=H):
-    """q, k normalised as the mixer does; g = -decay x uniform(0.5, 1.5):
-    exp(g) is near 1 at decay 1e-3 and under 1e-6 at decay 30."""
+    """q, k raw, as the mixer's SiLU leaves them; g = -decay x uniform(0.5,
+    1.5): exp(g) is near 1 at decay 1e-3 and under 1e-6 at decay 30; the
+    output gate before its sigmoid and the norm's weight."""
     r = np.random.default_rng(seed)
     draw = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)  # noqa: E731
-    q = kda.l2norm(draw(B, t, heads, DK)) * DK ** -0.5
-    k = kda.l2norm(draw(B, t, heads, DK))
+    q, k = draw(B, t, heads, DK), draw(B, t, heads, DK)
     v = draw(B, t, heads, DV)
     g = -jnp.asarray(r.uniform(0.5, 1.5, size=(B, t, heads, DK)), jnp.float32) * decay
     beta = jax.nn.sigmoid(draw(B, t, heads))
-    return q, k, v, g, beta
+    return q, k, v, g, beta, draw(B, t, heads, DV), 1.0 + 0.3 * draw(DV)
 
 
-def compare(t, decay, heads=H):
-    args = inputs(t, decay, heads=heads)
+def compare(t, decay, heads=H, args=None):
+    args = args or inputs(t, decay, heads=heads)
     w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
-    want = recurrence(*args)
-    got = jax.jit(kda.chunk_kda)(*args)
+    want = oracle(*args)
+    got = jax.jit(fresh())(*args)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5 * float(jnp.abs(want).max()))
     grads = jax.jit(jax.grad(
-        lambda *a: jnp.sum(kda.chunk_kda(*a) * w), argnums=(0, 1, 2, 3, 4)))(*args)
-    wanted = jax.grad(
-        lambda *a: jnp.sum(recurrence(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
-    for name, a, b in zip("q k v g beta".split(), grads, wanted):
-        assert float(jnp.abs(b).max()) > 0, name
+        lambda *a: jnp.sum(chunk_kda(*a) * w), argnums=range(7)))(*args)
+    wanted = jax.grad(lambda *a: jnp.sum(oracle(*a) * w), argnums=range(7))(*args)
+    for name, a, b in zip(NAMES, grads, wanted):
+        assert float(jnp.abs(b).max()) > 0 and bool(jnp.isfinite(a).all()), name
         np.testing.assert_allclose(
             a, b, rtol=2e-3, atol=2e-4 * float(jnp.abs(b).max()), err_msg=name)
 
@@ -82,34 +110,116 @@ def test_pallas_kernels_in_interpret_mode_are_the_recurrence(monkeypatch, t, dec
 
 def test_a_head_through_the_pair_path_is_the_head_alone_bit_for_bit(monkeypatch):
     """Stacked on another head's rows a head's sums gain exact zeros and
-    nothing else: output and all five gradients of four heads, two a grid
-    step, equal those of the same call one head a step, and those of each
-    head in a call of its own. (g's gradient leaves the kernel through one
-    matmul over every head's lanes, which the interpreter's backend sums in
-    another order at another width: against a one-head call it agrees to
-    rounding, from either path.)"""
+    nothing else: output and the gradients of four heads, two a grid step,
+    equal those of the same call one head a step, and those of each head in
+    a call of its own. (g's gradient leaves the kernel through one matmul
+    over every head's lanes, and q's and k's through the normalisation's
+    own, a row sum that the interpreter's backend fuses with its neighbours
+    as the block's width lets it: against a one-head call these three agree
+    to rounding, from either path. The weight is every head's: its gradient
+    is the sum of theirs, in the order of the grid's steps.)"""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    args = inputs(192, 0.3, heads=4)
+    *args, weight = inputs(192, 0.3, heads=4)
     w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
 
     def run(w, *a):
-        loss = lambda *a: jnp.sum(kda.chunk_kda(*a) * w)  # noqa: E731
-        return kda.chunk_kda(*a), *jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*a)
+        loss = lambda *a: jnp.sum(chunk_kda(*a) * w)  # noqa: E731
+        return chunk_kda(*a), *jax.grad(loss, argnums=range(7))(*a)
 
-    names = "o q k v g beta".split()
-    paired = jax.jit(run)(w, *args)
+    names = ["o", *NAMES]
+    paired = jax.jit(run)(w, *args, weight)
+    d_weight = 0.0
     for h in range(4):
         alone = lambda x: x[:, :, h:h + 1]  # noqa: E731
-        for name, a, b in zip(names, paired, jax.jit(run)(alone(w), *map(alone, args))):
+        *got, d_weight_h = jax.jit(run)(alone(w), *map(alone, args), weight)
+        d_weight = d_weight + d_weight_h
+        for name, a, b in zip(names, paired, got):
             assert float(jnp.abs(b).max()) > 0, name
-            if name == "g":
+            if name in "qkg":
                 np.testing.assert_allclose(
                     alone(a), b, rtol=0, atol=1e-6 * float(jnp.abs(b).max()))
             else:
                 np.testing.assert_array_equal(alone(a), b, err_msg=f"head {h}: {name}")
+    np.testing.assert_allclose(paired[-1], d_weight, rtol=1e-5)
     monkeypatch.setattr(kda, "_PAIR", 1)  # the same call, one head a step
-    for name, a, b in zip(names, paired, jax.jit(run)(w, *args)):
-        np.testing.assert_array_equal(a, b, err_msg=name)
+    alone_a_step = jax.jit(lambda *a: run(*a))(w, *args, weight)  # traced anew
+    for name, a, b in zip(names, paired, alone_a_step):
+        if name == "weight":
+            np.testing.assert_allclose(a, b, rtol=1e-5)
+        elif name in "qk":  # the normalisation's own, as above
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-6 * float(jnp.abs(b).max()))
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_a_row_of_zeros_in_q_or_k_gives_zeros_and_finite_gradients(monkeypatch, path):
+    """The epsilons stand inside the roots: a token whose q is zero reads
+    zero (and its RMSNorm gives zero), one whose k is zero writes nothing,
+    and every gradient is finite and the plain way's."""
+    if path == "pallas":
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    q, k, *rest = inputs(128, 0.3)
+    q, k = q.at[:, 5].set(0.0).at[:, 64].set(0.0), k.at[:, 9].set(0.0).at[:, 64].set(0.0)
+    got = chunk_kda(q, k, *rest)
+    assert not np.asarray(got[:, 5]).any() and not np.asarray(got[:, 64]).any()
+    assert np.asarray(got[:, 9]).any()
+    compare(128, 0.3, args=(q, k, *rest))
+
+
+@pytest.mark.parametrize("stacked", [1, 2], ids=["one-head", "pair"])
+def test_a_chunks_normalisations_are_l2norm_before_it_and_rmsnorm_and_the_gate_after(stacked):
+    """``_normed_chunk``, which both kernels and the XLA form run, is
+    ``_head_chunk`` of ``l2norm(q) * scale`` and ``l2norm(k)`` and then
+    ``RMSNorm`` and the gate on its float32 o: value, the state, and the
+    vector-Jacobian products of every operand."""
+    r = np.random.default_rng(3)
+    n = stacked * kda.CHUNK
+    draw = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)  # noqa: E731
+    St, q, k, v = draw(stacked * DV, DK), draw(n, DK), draw(n, DK), draw(n, DV)
+    beta = jax.nn.sigmoid(draw(n, 1))
+    G = jnp.concatenate([jnp.cumsum(-jnp.abs(draw(kda.CHUNK, DK)) * 0.1, 0)
+                         for _ in range(stacked)])
+    last = jnp.concatenate([jnp.broadcast_to(x[-1:], (kda.CHUNK, DK))
+                            for x in jnp.split(G, stacked)])
+    last_dv = jnp.concatenate([jnp.broadcast_to(x[-1:], (DV, DK))
+                               for x in jnp.split(G, stacked)])
+    args = (St, q, k, v, beta, G, last, last_dv, draw(n, DV), 1.0 + 0.3 * draw(1, DV))
+
+    def plain(St, q, k, v, beta, G, last, last_dv, gate, weight):
+        St, o = kda._head_chunk(St, kda.l2norm(q) * SCALE, kda.l2norm(k), v, beta,
+                                G, last, last_dv)
+        return St, gated_norm(o, gate, weight[0])
+
+    fused = functools.partial(kda._normed_chunk, norm=(SCALE, 1e-6, RMS_EPS))
+    got, pull = jax.vjp(fused, *args)
+    want, pull_plain = jax.vjp(plain, *args)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    cot = (draw(*want[0].shape), draw(*want[1].shape))
+    for i, (a, b) in enumerate(zip(pull(cot), pull_plain(cot))):
+        assert float(jnp.abs(b).max()) > 0, i
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()), err_msg=str(i))
+
+
+def test_the_weights_gradient_adds_up_over_batch_rows_and_grid_steps(monkeypatch):
+    """The backward kernel adds the weight's cotangent up in a batch row's
+    output block over that row's steps, and the rows are summed outside: the
+    gradient of a batch of two is the sum of each row's in a call of its
+    own, and the gate's of a row is that row's alone."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    *args, weight = inputs(192, 0.3)
+    w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
+    grad = jax.jit(jax.grad(
+        lambda w, gate, weight, *a: jnp.sum(chunk_kda(*a, gate, weight) * w), (1, 2)))
+    d_gate, d_weight = grad(w, args[5], weight, *args[:5])
+    rows = [grad(w[b:b + 1], args[5][b:b + 1], weight, *(x[b:b + 1] for x in args[:5]))
+            for b in range(B)]
+    assert float(jnp.abs(rows[0][1]).max()) > 0 and float(jnp.abs(rows[1][1]).max()) > 0
+    np.testing.assert_allclose(d_weight, rows[0][1] + rows[1][1], rtol=1e-5)
+    for b in range(B):
+        np.testing.assert_array_equal(d_gate[b:b + 1], rows[b][0])
 
 
 def test_the_masks_of_stacked_heads_are_block_diagonal():
@@ -148,7 +258,7 @@ def test_the_grid_takes_two_heads_a_step_where_they_pair_off(monkeypatch, heads,
     """Forward (with its states) and backward, as a gradient lowers them."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     args = inputs(128, 0.3, heads=heads)
-    both = jax.make_jaxpr(jax.grad(lambda *a: kda.chunk_kda(*a).sum()))(*args)
+    both = jax.make_jaxpr(jax.grad(lambda *a: chunk_kda(*a).sum()))(*args)
     grids = [eqn.params["grid_mapping"].grid for eqn in pallas_calls(both.jaxpr, [])]
     assert grids == [(B, 128 // kda.CHUNK, steps)] * 2
 
@@ -158,20 +268,20 @@ def test_the_forward_outside_a_gradient_writes_no_states(monkeypatch):
     every layer's states alive from the forward pass to the backward."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     args = inputs(128, 0.3)
-    forward = jax.make_jaxpr(kda.chunk_kda)(*args)
+    forward = jax.make_jaxpr(fresh())(*args)
     assert pallas_outputs(forward.jaxpr) == [1]  # o alone
-    both = jax.make_jaxpr(jax.grad(lambda *a: kda.chunk_kda(*a).sum()))(*args)
-    # o and the states; then the five cotangents
-    assert pallas_outputs(both.jaxpr) == [2, 5]
+    both = jax.make_jaxpr(jax.grad(lambda *a: chunk_kda(*a).sum()))(*args)
+    # o and the states; then the seven cotangents
+    assert pallas_outputs(both.jaxpr) == [2, 7]
 
 
 def test_a_strong_decay_neither_overflows_nor_loses_the_state():
     """exp(-50) a step: every exponent the chunked form takes is <= 0."""
-    q, k, v, g, beta = inputs(128, 1.0)
+    q, k, v, g, beta, gate, weight = inputs(128, 1.0)
     g = jnp.full_like(g, -50.0).at[:, ::7].set(-1e-4)
-    got = kda.chunk_kda(q, k, v, g, beta)
+    got = chunk_kda(q, k, v, g, beta, gate, weight)
     assert bool(jnp.isfinite(got).all())
-    want = recurrence(q, k, v, g, beta)
+    want = oracle(q, k, v, g, beta, gate, weight)
     # the running sum of g reaches -3000 in a chunk: its float32 rounding
     # (2e-4) is the exponent's
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-4 * float(jnp.abs(want).max()))

@@ -121,18 +121,20 @@ def test_bounded_gmm_and_its_gradient_compile_for_v5e(v5e, k, n):
     assert text.count("tpu_custom_call") >= 2  # dlhs and drhs
 
 
-# The same cell's KDA layers: 32 heads of 128 over 16,384 tokens, the
+# The same cell's KDA layers: 32 heads of 128 over 16,384 tokens, q and k
+# raw in float32, the output gate and the norm's weight with them: the
 # forward kernel (with and without the states) and the backward kernel,
-# which differentiates a chunk inside the kernel.
+# which differentiates a chunk and its normalisations inside the kernel.
 def test_kda_kernels_compile_for_v5e(v5e):
     b, t, h, d = 1, 16384, 32, 128
-    rows = ((b, t, h * d), jnp.bfloat16)
-    operands = (rows, rows, rows, ((b, t, h * d), jnp.float32),
-                ((b, h, t, 1), jnp.float32))
-    _compile_for(v5e, lambda *a: kda._forward_pallas(*a, h, states=False), *operands)
-    _compile_for(v5e, lambda *a: kda._forward_pallas(*a, h, states=True), *operands)
+    raw, rows = ((b, t, h * d), jnp.float32), ((b, t, h * d), jnp.bfloat16)
+    operands = (raw, raw, rows, raw, ((b, h, t, 1), jnp.float32), rows,
+                ((1, d), jnp.float32))
+    norm = (d ** -0.5, 1e-6, 1e-5)
+    _compile_for(v5e, lambda *a: kda._forward_pallas(*a, h, norm, states=False), *operands)
+    _compile_for(v5e, lambda *a: kda._forward_pallas(*a, h, norm, states=True), *operands)
     _compile_for(
-        v5e, lambda *a: kda._backward_pallas(*a, h), *operands,
+        v5e, lambda *a: kda._backward_pallas(*a, h, norm), *operands,
         ((b, t // kda.CHUNK, d, h * d), jnp.float32), rows,
     )
 
@@ -191,21 +193,19 @@ def test_the_models_that_were_there_lower_to_the_kernels_they_had(v5e, monkeypat
 
 
 # Kimi-Linear's step at the benchmark's real size (b1 x s16384, five layers at
-# the published widths): every kernel its configuration states, and the held
-# share's rows moved a window of tiles at a time, never over the static bound
-# of every (token, expert) pair.
-def test_kimi_linears_step_holds_its_kernels_and_no_gather_over_the_bound(v5e, monkeypatch):
+# the published widths), lowered once for the tests below.
+@pytest.fixture(scope="module")
+def kimi_linears_step(v5e):
+    """(the cell, the lowered step's StableHLO)."""
     import importlib
-    import re
 
     import numpy as np
 
-    from benchmarks.lib import cells, checks
+    from benchmarks.lib import cells
     from benchmarks.loops.train_lm import make_loss_fn, make_optimizer
     from ray_tpu import train
 
     attention = importlib.import_module("ray_tpu.ops.attention")
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cell = cells.load_cell("kimi-linear-48b-a3b-l5.longctx-16k")
     config, traffic = cell["config"], cell["traffic"]
     model = cells.resolve(config["program"]["model"])(cells.program_config(config))
@@ -219,9 +219,24 @@ def test_kimi_linears_step_holds_its_kernels_and_no_gather_over_the_bound(v5e, m
     tx = make_optimizer(traffic)
     batch = jax.ShapeDtypeStruct(
         (traffic["batch"], traffic["seq"]), np.int32, sharding=v5e)
-    text = train.make_train_step(make_loss_fn(traffic, model), tx).lower(
-        placed(shapes), placed(jax.eval_shape(tx.init, shapes)), batch, batch
-    ).as_text()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, "_on_tpu", lambda: True)
+        text = train.make_train_step(make_loss_fn(traffic, model), tx).lower(
+            placed(shapes), placed(jax.eval_shape(tx.init, shapes)), batch, batch
+        ).as_text()
+    return cell, text
+
+
+def test_kimi_linears_step_holds_its_kernels_and_no_gather_over_the_bound(kimi_linears_step):
+    """Every kernel its configuration states, and the held share's rows moved
+    a window of tiles at a time, never over the static bound of every
+    (token, expert) pair."""
+    import re
+
+    from benchmarks.lib import cells, checks
+
+    cell, text = kimi_linears_step
+    config, traffic = cell["config"], cell["traffic"]
     stated = cells.stated_kernels(cell)
     counts = checks.count_pallas_kernels(text, stated)
     assert checks.holds_stated_kernels(counts, stated), (counts, stated)
@@ -234,3 +249,26 @@ def test_kimi_linears_step_holds_its_kernels_and_no_gather_over_the_bound(v5e, m
     assert f"-> tensor<2048x{width}xbf16>" in text  # a window of sixteen tiles
     assert gathered and max(gathered) < pairs, sorted(set(gathered))
 
+
+def test_kimi_linears_step_leaves_no_norm_over_a_heads_channels_to_xla(kimi_linears_step):
+    """q's and k's L2 norm and o's gated RMSNorm happen on the scan kernels'
+    own blocks in every KDA layer or in none: the lowered step reduces no
+    [1, 16384, 32, 128] float32 array over a head's channels token by token
+    (before the kernels took them: 36, forward, replay and backward of four
+    layers; the sums over tokens that are left are the gradients of the
+    decay's per-head parameters), and the scan's call sites are still twelve,
+    a forward, its replay and a backward a layer."""
+    import re
+
+    from benchmarks.lib import checks
+
+    cell, text = kimi_linears_step
+    traffic, kda_cfg = cell["traffic"], cell["config"]["linear_attn_config"]
+    rows = "x".join(str(n) for n in (
+        traffic["batch"], traffic["seq"], kda_cfg["num_heads"], kda_cfg["head_dim"]))
+    reduced = re.findall(
+        rf"stablehlo\.reduce.* across dimensions = \[([\d, ]+)\] : \(tensor<{rows}xf32>",
+        text)
+    assert reduced and all("1" in dims.split(", ") for dims in reduced), reduced
+    counts = checks.count_pallas_kernels(text, ("_kda_fwd_kernel", "_kda_bwd_kernel"))
+    assert counts == {"_kda_fwd_kernel": 8, "_kda_bwd_kernel": 4}

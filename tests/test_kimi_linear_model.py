@@ -167,6 +167,8 @@ def leaf(tree, path):
     ("layers_0", "kda", "o_norm", "scale"),
     ("layers_0", "mlp", "down_proj", "kernel"),
     ("layers_1", "kda", "v_proj", "kernel"),
+    ("layers_1", "kda", "k_proj", "kernel"),
+    ("layers_2", "kda", "g_a_proj", "kernel"),
     ("layers_1", "moe", "router", "kernel"),
     ("layers_1", "moe", "w_gate"),
     ("layers_1", "moe", "w_down"),

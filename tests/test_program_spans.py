@@ -152,9 +152,11 @@ def test_the_hybrid_carries_its_mixers_names_and_scopes(kimi_paths):
     for name in tracing.MIXERS:
         assert any(f"/{name}/" in p for p in kimi_paths), name
     kda = [p for p in kimi_paths if "/kda/" in p]
-    for name in (tracing.KDA_CONV, tracing.KDA_GATE, tracing.KDA_SCAN,
-                 tracing.KDA_OUT_NORM):
+    for name in (tracing.KDA_CONV, tracing.KDA_GATE, tracing.KDA_SCAN):
         assert any(f"/kda/{name}/" in p for p in kda), name
+    # o's RMSNorm and gate are the scan kernels': no operation is left for a
+    # scope of their own to name, and the parameter keeps RMSNorm's path
+    assert not [p for p in kda if "o_norm" in p or "out_norm" in p]
     assert any(f"/mla/{tracing.MLA_LATENT}/kv_b_proj/" in p for p in kimi_paths)
     assert any(f"/moe/{tracing.MOE_SHARED}/shared/" in p for p in kimi_paths)
     # layer 0 is dense under KDA, layer 2 is MLA over experts; forward,
