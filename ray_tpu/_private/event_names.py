@@ -24,13 +24,13 @@ from __future__ import annotations
 CATEGORIES = frozenset(
     {
         "task", "worker", "lease", "object", "transfer", "sched",
-        "refs", "chaos", "head",
+        "refs", "chaos", "head", "train",
     }
 )
 CATEGORY_CONSTS = frozenset(
     {
         "TASK", "WORKER", "LEASE", "OBJECT", "TRANSFER", "SCHED",
-        "REFS", "CHAOS", "HEAD",
+        "REFS", "CHAOS", "HEAD", "TRAIN",
     }
 )
 
@@ -109,6 +109,26 @@ EVENTS_BY_CATEGORY = {
             "NODE_READMIT", "HEDGE_LAUNCH", "HEDGE_WIN", "HEDGE_CANCEL",
         }
     ),
+    "train": frozenset(
+        {
+            # The trainer's own record of every turn of a user's loop
+            # (train/session.py): one REPORT a train.report call; one
+            # USAGE a report taken off the queue, with the loop
+            # thread's CPU time and the process's CPU and fault
+            # counters (cumulative); a collector pause; and the loop
+            # thread's stack when a report is overdue. The entity is
+            # the thread the event is about.
+            "REPORT", "USAGE", "GC_PAUSE", "OVERDUE",
+            # Host spans (util/tracing.py HOST_SPANS) that also record
+            # here while the process holds a train session: the event
+            # is the span's name, attrs its monotonic start.
+            # ray_tpu.worker.exec is not among them: the task
+            # category's EXEC_SPAN already holds that interval.
+            "ray_tpu.train.report", "ray_tpu.train.next_result",
+            "ray_tpu.train.result_wait", "ray_tpu.worker.reply",
+            "ray_tpu.worker.recv",
+        }
+    ),
 }
 
 #: Flat set: every registered recorder event name.
@@ -125,10 +145,3 @@ TASK_TABLE_EVENTS = frozenset(
 def is_registered(name: str) -> bool:
     return name in EVENT_NAMES
 
-
-def category_of(name: str):
-    """Categories a name is registered under (a name may legitimately
-    appear in several, e.g. SEALED in task + object)."""
-    return tuple(
-        c for c, names in EVENTS_BY_CATEGORY.items() if name in names
-    )

@@ -30,6 +30,9 @@ fork, execution, object seal/transfer. Three pieces:
 
 Event wire format (compact tuple):
     (t_wall, t_mono, category, entity, event, attrs-or-None)
+``t_mono`` is 0.0 except in the ``train`` category (the trainer's own
+record of every turn, train/session.py), whose readers take durations:
+:meth:`FlightRecorder.record_at` fills it.
 
 Canonical task lifecycle transitions (expanded by the aggregator):
     SUBMITTED → QUEUED → LEASED → FORKED → EXEC_START → EXEC_END
@@ -48,10 +51,29 @@ from collections import OrderedDict, deque
 from typing import Any, Dict, List, Optional, Tuple
 
 # Categories.
-TASK, WORKER, LEASE, OBJECT, TRANSFER, SCHED, REFS, CHAOS, HEAD = (
+TASK, WORKER, LEASE, OBJECT, TRANSFER, SCHED, REFS, CHAOS, HEAD, TRAIN = (
     "task", "worker", "lease", "object", "transfer", "sched", "refs",
-    "chaos", "head",
+    "chaos", "head", "train",
 )
+
+#: ``train`` events carry their values as a bare tuple in the attrs slot
+#: (no dict on the loop's thread); these are the names, in order, that
+#: _expand gives them on the head. USAGE holds cumulative counters, read
+#: by the thread that takes a report off the session's queue (a system
+#: call costs microseconds on some hosts, so none is made on the loop's
+#: thread): readers take differences between two of them. Every other
+#: ``train`` event is a host span (util/tracing.py): its name is the
+#: event, attrs its monotonic start, the stamps its end. The entity is
+#: the thread the event is about.
+TRAIN_FIELDS = {
+    "REPORT": ("ordinal",),
+    "USAGE": (
+        "ordinal", "thread_cpu_ns", "process_cpu_s",
+        "nivcsw", "majflt", "minflt",
+    ),
+    "GC_PAUSE": ("generation", "seconds"),
+    "OVERDUE": ("ordinal", "waited_s", "overslept_s", "frames"),
+}
 
 #: Order of the canonical per-task transitions; also the stitch order.
 TASK_TRANSITIONS = (
@@ -72,6 +94,12 @@ PHASE_BOUNDARIES = (
 # its timeline row. Thread-local, not a contextvar: prints happen on
 # the thread running the task (inline reader threads, pool threads).
 _ctx = threading.local()
+
+
+def worker_source(worker_id_hex: str) -> str:
+    """The name a worker's shipped events carry on the head (the
+    aggregator's "job")."""
+    return f"worker-{worker_id_hex[:12]}"
 
 
 def set_task_context(task_id_hex: Optional[str]) -> None:
@@ -121,6 +149,19 @@ class FlightRecorder:
         # feeds the stitcher (which clamps skew), and skipping the extra
         # clock read halves the timing cost of a record.
         buf.append((time.time(), 0.0, category, entity, event, attrs))
+
+    def record_at(self, t_wall: float, t_mono: float, category: str,
+                  entity: Any, event: str, attrs: Any = None) -> None:
+        """:meth:`record` for a caller that took the stamps itself and
+        fills the monotonic slot: an event whose readers take durations,
+        which must not follow a stepped wall clock (the ``train``
+        category's)."""
+        if not self.enabled:
+            return
+        buf = self._buf
+        if len(buf) == self.capacity:
+            self.dropped += 1
+        buf.append((t_wall, t_mono, category, entity, event, attrs))
 
     def drain(self) -> Tuple[List[tuple], int]:
         """Take everything recorded so far (+ the drop count since the
@@ -225,6 +266,12 @@ def _expand(item: tuple, source: str) -> List[Dict[str, Any]]:
     }
     span = _SPAN_KEYS.get(event) if category == TASK else None
     if span is None:
+        if category == TRAIN and not isinstance(attrs, dict):
+            fields = TRAIN_FIELDS.get(event)
+            if fields is None:
+                attrs = {"m_start": attrs}
+            else:
+                attrs = dict(zip(fields, attrs or ()))
         return [dict(base, event=event, attrs=attrs)]
     a = attrs or {}
     worker = a.get("worker", "")
@@ -243,6 +290,14 @@ def _expand(item: tuple, source: str) -> List[Dict[str, Any]]:
             ev_attrs["worker"] = worker
             if name == "SEALED" and a.get("error"):
                 ev_attrs["error"] = True
+            if name == "EXEC_END" and "m_end" in a:
+                # The executing thread and the interval on the
+                # monotonic clock: what a ray_tpu.worker.exec host
+                # span would say (util/tracing.py).
+                ev_attrs.update(
+                    thread=a.get("thread"), m_start=a.get("m_start"),
+                    m_end=a["m_end"],
+                )
         out.append(dict(base, event=name, timestamp=ts, attrs=ev_attrs))
     return out
 

@@ -1303,7 +1303,7 @@ class GcsServer:
         if source is None:
             wid = msg.get("worker_id")
             source = (
-                f"worker-{wid.hex()[:12]}"
+                _events.worker_source(wid.hex())
                 if isinstance(wid, bytes)
                 else str(msg.get("source", "?"))
             )

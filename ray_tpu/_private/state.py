@@ -350,8 +350,74 @@ def timeline(filename: Optional[str] = None) -> Optional[List[Dict]]:
             )
     except Exception:  # noqa: BLE001 - recorder disabled or old head
         pass
+    trace.extend(
+        train_rows(list_cluster_events(category="train", limit=100_000))
+    )
     if filename:
         with open(filename, "w") as f:
             json.dump(trace, f)
         return None
     return trace
+
+
+def train_rows(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """Train rows (pid "train <source>", one a worker that holds a train
+    session): on the row "turns" every turn of the loop as a slice from
+    one REPORT to the next (its args, from the two USAGE readings: the
+    loop thread's CPU ms, the process's involuntary switches and major
+    faults in it), collector pauses as slices nested in it and
+    overdue-report samples as instants with the loop thread's frames;
+    the host spans of util/tracing.py as slices on a row for the thread
+    that ran each. Durations are monotonic; a slice is placed by its
+    wall-clock end."""
+    rows: List[Dict[str, Any]] = []
+    usage = {
+        (ev.get("source", ""), ev["attrs"]["ordinal"]): ev["attrs"]
+        for ev in events if ev["event"] == "USAGE"
+    }
+    last_report: Dict[str, Dict[str, Any]] = {}
+    for ev in events:
+        name, attrs = ev["event"], ev.get("attrs") or {}
+        source = ev.get("source", "")
+        base = {"cat": "train", "pid": f"train {source}", "tid": "turns"}
+        if name == "REPORT":
+            prev = last_report.get(source)
+            last_report[source] = ev
+            if prev is None:
+                continue
+            dur = ev["monotonic"] - prev["monotonic"]
+            args = {"thread": ev["entity"]}
+            was = usage.get((source, prev["attrs"]["ordinal"]))
+            now = usage.get((source, attrs["ordinal"]))
+            if was and now:
+                args["nivcsw"] = now["nivcsw"] - was["nivcsw"]
+                args["majflt"] = now["majflt"] - was["majflt"]
+                if None not in (now["thread_cpu_ns"], was["thread_cpu_ns"]):
+                    args["loop_cpu_ms"] = (
+                        now["thread_cpu_ns"] - was["thread_cpu_ns"]
+                    ) / 1e6
+            rows.append({
+                **base, "name": f"turn {attrs['ordinal']}", "ph": "X",
+                "ts": (ev["timestamp"] - dur) * 1e6, "dur": dur * 1e6,
+                "args": args,
+            })
+        elif name == "GC_PAUSE":
+            dur = attrs["seconds"]
+            rows.append({
+                **base, "name": f"gc gen{attrs['generation']}", "ph": "X",
+                "ts": (ev["timestamp"] - dur) * 1e6, "dur": dur * 1e6,
+                "args": {"thread": ev["entity"]},
+            })
+        elif name == "OVERDUE":
+            rows.append({
+                **base, "name": "OVERDUE", "ph": "i", "s": "t",
+                "ts": ev["timestamp"] * 1e6, "args": attrs,
+            })
+        elif name != "USAGE":  # a host span: the event is its name
+            dur = ev["monotonic"] - attrs["m_start"]
+            rows.append({
+                **base, "name": name, "tid": f"thread {ev['entity']}",
+                "ph": "X", "ts": (ev["timestamp"] - dur) * 1e6,
+                "dur": dur * 1e6, "args": {},
+            })
+    return rows

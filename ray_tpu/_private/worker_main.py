@@ -43,6 +43,7 @@ import cloudpickle
 from . import chaos as _chaos
 from . import events as _events
 from . import serialization
+from . import stacks as _stacks
 from .client import CoreClient
 from .config import RayConfig
 from .ids import ActorID, TaskID, WorkerID
@@ -449,6 +450,7 @@ class WorkerRuntime:
         name = method or "task"
         _rec = _events.get_recorder()
         t_fork = time.time() if _rec.enabled else 0.0
+        m_start = time.monotonic()
         t_start = 0.0
         tid_hex = tid.hex()
         self._executing[tid] = tuple(
@@ -487,6 +489,7 @@ class WorkerRuntime:
             finally:
                 _events.set_task_context(None)
         t_end = time.time() if _rec.enabled else 0.0
+        m_end = time.monotonic()
         from .protocol import ConnectionLost
 
         with _tracing.span(_tracing.WORKER_REPLY):
@@ -563,6 +566,12 @@ class WorkerRuntime:
                 "t_end": t_end,
                 "t_seal": time.time(),
                 "worker": self._wid_hex,
+                # The ray_tpu.worker.exec host span's interval, on the
+                # monotonic clock and with its thread: the one record
+                # of it outside a profiler session (util/tracing.py).
+                "thread": threading.get_ident(),
+                "m_start": m_start,
+                "m_end": m_end,
             }
             if error_blob is not None:
                 attrs["error"] = True
@@ -1140,6 +1149,7 @@ class WorkerRuntime:
                 ),
             )
         t_end = time.time() if _rec.enabled else 0.0
+        m_end = time.monotonic()
         if spec.num_returns == -1:
             # Failures before iteration (bad args, fetch error) must
             # still end the stream or consumers park forever.
@@ -1159,6 +1169,9 @@ class WorkerRuntime:
                 "t_end": t_end,
                 "t_seal": time.time(),
                 "worker": self._wid_hex,
+                "thread": threading.get_ident(),
+                "m_start": t_exec0,
+                "m_end": m_end,
             }
             if exc is not None:
                 attrs["error"] = True
@@ -1354,17 +1367,7 @@ def main():
             # format every thread's stack right here on the reader
             # thread — works even when the main thread is stuck in user
             # code, which is exactly when you want a dump.
-            import traceback as _tb
-
-            frames = sys._current_frames()
-            names = {th.ident: th.name for th in threading.enumerate()}
-            parts = []
-            for tid, frame in frames.items():
-                parts.append(
-                    f"--- thread {names.get(tid, '?')} ({tid}) ---\n"
-                    + "".join(_tb.format_stack(frame))
-                )
-            _send_stack_reply(msg.get("token"), "".join(parts))
+            _send_stack_reply(msg.get("token"), _stacks.format_all())
         elif t == "profile_stacks":
             # Statistical sampling profile (reference: the dashboard's
             # py-spy -f flamegraph capture — here in-process, no
@@ -1382,20 +1385,10 @@ def main():
                 t_end = time.monotonic() + duration
                 n_samples = 0
                 while time.monotonic() < t_end:
-                    for tid, frame in sys._current_frames().items():
-                        if tid == me:
-                            continue
-                        stack = []
-                        f = frame
-                        while f is not None:
-                            c = f.f_code
-                            stack.append(
-                                f"{c.co_name} "
-                                f"({os.path.basename(c.co_filename)}"
-                                f":{f.f_lineno})"
-                            )
-                            f = f.f_back
-                        key = ";".join(reversed(stack))
+                    for frame in _stacks.thread_frames(skip=me).values():
+                        key = ";".join(
+                            reversed(_stacks.frame_lines(frame))
+                        )
                         counts[key] = counts.get(key, 0) + 1
                     n_samples += 1
                     time.sleep(interval)

@@ -456,7 +456,7 @@ def main(argv=None):
     sp.add_argument("--task", default=None, help="task id (hex) filter")
     sp.add_argument(
         "--category", default=None,
-        help="category filter (task/worker/lease/object/transfer/sched)",
+        help="category filter (task/worker/lease/object/transfer/sched/train)",
     )
     sp.add_argument("--limit", type=int, default=200)
     sp.add_argument("--json", action="store_true")

@@ -18,13 +18,16 @@ process — the bench path on one host.
 """
 from __future__ import annotations
 
+import json
+import logging
 import os
 import threading
 import time
 import traceback
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from .._private import events as _events
 from ..exceptions import RayActorError
 from ..util.placement_group import placement_group, remove_placement_group
 from ..util import tracing
@@ -32,6 +35,87 @@ from ..util.scheduling_strategies import PlacementGroupSchedulingStrategy
 from .checkpoint import Checkpoint
 from .config import Result, RunConfig, ScalingConfig
 from .session import TrainContext, get_session, init_session
+
+logger = logging.getLogger(__name__)
+
+#: Under ``Result.path``, one a rank: what the flight recorder holds of the
+#: run when ``fit`` ends (``write_train_events``).
+EVENTS_FILE = "train_events_rank{rank}.jsonl"
+
+
+def write_train_events(storage: str, worker_ids: List[str], t_fit: float,
+                       experiment: str) -> None:
+    """One JSON-lines file a rank under ``storage``, read back from the head's
+    flight recorder before the workers are killed. First a ``header`` line
+    (``dropped``: events of this worker that a ring or the head's retention
+    lost), then, by wall time, events as ``ray_tpu events --json`` prints
+    them: the rank's ``train`` category (REPORT, USAGE, GC_PAUSE, OVERDUE,
+    host spans); every task it executed as one more such span,
+    ``ray_tpu.worker.exec``, from the task's ``EXEC_SPAN``; the worker's own
+    lifecycle (SPAWN_REQUESTED ... REGISTERED); and the transitions, with the
+    task's name, of the tasks that ran before the loop's first report (the
+    actor's creation, ``run``)."""
+    from .._private import state
+    from .._private.config import RayConfig
+    from ..util.state import summarize_events
+
+    if not worker_ids:
+        return  # the group never came up
+    cap = int(RayConfig.event_retention_per_job)
+    names = {e["task_id"]: e["name"] for e in state.task_events()}
+    ranks = []
+    for rank, wid in enumerate(worker_ids):
+        source = _events.worker_source(wid)
+        # An EXEC_SPAN reads back as four transitions.
+        mine = state.list_cluster_events(job=source, limit=4 * cap)
+        first_report = next(
+            (e["timestamp"] for e in mine if e["event"] == "REPORT"), None
+        )
+        if first_report is None:
+            continue  # the loop never reported: there is no turn to read
+        lines, early = [], set()
+        for e in mine:
+            if e["category"] == _events.TRAIN:
+                lines.append(e)
+            elif e["category"] == _events.TASK:
+                if e["timestamp"] < first_report:
+                    early.add(e["entity"])
+                attrs = e.get("attrs") or {}
+                if e["event"] == "EXEC_END" and attrs.get("m_end") is not None:
+                    lines.append({
+                        "category": _events.TRAIN,
+                        "event": tracing.WORKER_EXEC,
+                        "entity": str(attrs["thread"]),
+                        "timestamp": e["timestamp"],
+                        "monotonic": attrs["m_end"],
+                        "attrs": {"m_start": attrs["m_start"]},
+                        "task": e["entity"], "source": source,
+                    })
+        lines += state.list_cluster_events(
+            category=_events.WORKER, entity=wid, limit=cap
+        )
+        for tid in early:
+            for e in state.list_cluster_events(
+                category=_events.TASK, entity=tid, limit=cap
+            ):
+                lines.append({**e, "name": names.get(tid, "")})
+        lines.sort(key=lambda e: e["timestamp"])
+        ranks.append((rank, wid, source, lines))
+    # After the reads: their barrier shipped the rings' last events and,
+    # with them, the count of what the rings evicted.
+    drops = summarize_events()["drops"]
+    for rank, wid, source, lines in ranks:
+        header = {
+            "rank": rank, "experiment": experiment, "worker_id": wid,
+            "source": source, "t_fit": t_fit, "t_written": time.time(),
+            "dropped": drops.get(source, 0),
+        }
+        path = os.path.join(storage, EVENTS_FILE.format(rank=rank))
+        with open(path, "w") as f:
+            f.write(json.dumps({"header": header}) + "\n")
+            for e in lines:
+                e.pop("job", None)
+                f.write(json.dumps(e, default=str) + "\n")
 
 
 class TrainWorker:
@@ -138,8 +222,13 @@ class TrainWorker:
             ckpt_path = checkpoint.path if isinstance(checkpoint, Checkpoint) else checkpoint
             return (kind, metrics, ckpt_path)
 
-    def ping(self):
-        return self.rank
+    def worker_id(self) -> str:
+        """Which worker process holds this rank (``fit`` waits for it as the
+        sign that the actor is up): its flight-recorder events reach the
+        head under that name."""
+        from .._private.worker import global_client
+
+        return global_client().worker_id.hex()
 
 
 class JaxTrainer:
@@ -193,8 +282,10 @@ class JaxTrainer:
         return os.path.join(storage, cands[-1]) if cands else None
 
     def _fit_once(self, name: str, storage: str, latest_ckpt: Optional[str]) -> Result:
+        t_fit = time.time()
         sc = self.scaling_config
         n = sc.num_workers
+        worker_ids: List[str] = []
         pg = placement_group(
             [sc.worker_resources() for _ in range(n)],
             strategy=sc.placement_strategy,
@@ -223,7 +314,9 @@ class JaxTrainer:
                         None, rdv_token,
                     )
                 )
-            ray_tpu.get([w.ping.remote() for w in workers], timeout=120)
+            worker_ids = ray_tpu.get(
+                [w.worker_id.remote() for w in workers], timeout=120
+            )
             cfg = self._config
             if self.datasets:
                 cfg = dict(cfg or {})
@@ -263,6 +356,10 @@ class JaxTrainer:
                 metrics_history=history,
             )
         finally:
+            try:
+                write_train_events(storage, worker_ids, t_fit, name)
+            except Exception:  # noqa: BLE001 - the record must not cost a run its result
+                logger.warning("train events were not written", exc_info=True)
             for w in workers:
                 try:
                     ray_tpu.kill(w)

@@ -14,11 +14,20 @@ There is no switch: a profiler session is "on". A span goes around work,
 never around a blocking wait, except the one that is named as a wait.
 The control plane's own always-on record is the flight recorder
 (``_private/events.py``, ``ray_tpu timeline``), on the host's wall clock.
+In the process that holds a train session (``train/session.py``) a span
+also leaves one event in that recorder's ``train`` category when it ends,
+profiler session or none: the thread, the name and both ends on the
+monotonic clock. ``ray_tpu.worker.exec`` leaves none: the ``task``
+category's ``EXEC_SPAN`` already holds its interval and thread.
 """
 from __future__ import annotations
 
 import contextlib
 import sys
+import threading
+import time
+
+from .._private import events as _events
 
 # Host spans, in the process that holds the chip. Every name starts with
 # ``ray_tpu.`` (the benchmark's own spans start with ``bench.``).
@@ -56,15 +65,50 @@ SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
 MIXERS = (KDA, MLA)  # flax module names, bound in KimiLinearForCausalLM.blocks
 
 _OFF = contextlib.nullcontext()
+# The flight recorder, while this process holds a train session.
+_recorder = None
+
+
+def record_spans_into(recorder) -> None:
+    """``train/session.py`` hands over the process's flight recorder when a
+    session starts and None when it ends."""
+    global _recorder
+    _recorder = recorder
+
+
+class _RecordedSpan:
+    """A span that also lands in the flight recorder: one tuple and one
+    append when it ends, nothing built before that."""
+
+    __slots__ = ("name", "annotation", "recorder", "m_start")
+
+    def __init__(self, name, annotation, recorder):
+        self.name, self.annotation, self.recorder = name, annotation, recorder
+
+    def __enter__(self):
+        if self.annotation is not None:
+            self.annotation.__enter__()
+        self.m_start = time.monotonic()
+
+    def __exit__(self, *exc):
+        self.recorder.record_at(
+            time.time(), time.monotonic(), _events.TRAIN,
+            str(threading.get_ident()), self.name, self.m_start,
+        )
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
 
 
 def span(name: str):
-    """A host span around the ``with`` body; nothing where jax is not loaded."""
+    """A host span around the ``with`` body; nothing where jax is not loaded
+    and no train session is held."""
     # getattr: another thread may be half way through importing jax.
     profiler = getattr(sys.modules.get("jax"), "profiler", None)
-    if profiler is None:
-        return _OFF
-    return profiler.TraceAnnotation(name)
+    annotation = None if profiler is None else profiler.TraceAnnotation(name)
+    recorder = _recorder
+    if recorder is None or not recorder.enabled or name == WORKER_EXEC:
+        return _OFF if annotation is None else annotation
+    return _RecordedSpan(name, annotation, recorder)
 
 
 def scope(name: str):

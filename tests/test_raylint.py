@@ -376,7 +376,7 @@ def test_registry_covers_runtime_constants():
     assert set(event_names.CATEGORIES) == {
         events.TASK, events.WORKER, events.LEASE, events.OBJECT,
         events.TRANSFER, events.SCHED, events.REFS, events.CHAOS,
-        events.HEAD,
+        events.HEAD, events.TRAIN,
     }
     # The witness's finding event is registered under chaos.
     assert "LOCK_ORDER" in event_names.EVENTS_BY_CATEGORY["chaos"]

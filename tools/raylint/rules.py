@@ -513,11 +513,15 @@ def event_taxonomy(ctx: FileContext) -> Iterator[Tuple[int, str]]:
             continue
         f = node.func
         if not (
-            isinstance(f, ast.Attribute) and f.attr == "record"
-            and len(node.args) >= 3
+            isinstance(f, ast.Attribute)
+            and f.attr in ("record", "record_at")
         ):
             continue
-        cat, _entity, name = node.args[0], node.args[1], node.args[2]
+        # record_at(t_wall, t_mono, category, entity, name, attrs)
+        args = node.args[2:] if f.attr == "record_at" else node.args
+        if len(args) < 3:
+            continue
+        cat, _entity, name = args[0], args[1], args[2]
         if isinstance(cat, ast.Constant) and isinstance(cat.value, str):
             if cat.value not in reg["categories"]:
                 yield (
