@@ -44,8 +44,8 @@ class MixtralConfig(LlamaConfig):
     # benchmark set "nothing" instead (their expert buffers do not fit).
     # That costs less than a second forward: XLA merges the replay with
     # its forward twin, and step.remat_share reads 0.00% of busy time
-    # through "gmm" at 64 experts and 10.53% through the tiled
-    # "capacity" FFN (PERF.md §5).
+    # through "gmm" at 64 experts and 0.98% through the tiled
+    # "capacity" FFN, whose loop the replay does not hold (PERF.md §5).
     remat_policy: str = "dots"
     # Per-expert token capacity = capacity_factor * T * k / E
     # (capacity dispatch only). E / k (4.0 for 8 experts, top-2) is the
@@ -160,8 +160,15 @@ def _worklist(tiles, per, trips):
 
 
 def _tile(buf, at):
+    """The tile at (expert, row, first slot) of [E, B, C] or [E, B, C, D]."""
     return jax.lax.dynamic_slice(
-        buf, (*at, 0), (1, 1, _FFN_TILE, buf.shape[-1])
+        buf, (*at, *(0,) * (buf.ndim - 3)), (1, 1, _FFN_TILE, *buf.shape[3:])
+    )
+
+
+def _put_tile(buf, tile, at):
+    return jax.lax.dynamic_update_slice(
+        buf, tile.astype(buf.dtype), (*at, *(0,) * (buf.ndim - 3))
     )
 
 
@@ -169,9 +176,10 @@ def _expert(w, e):
     return jax.lax.dynamic_slice_in_dim(w, e, 1, axis=0)
 
 
-def _tiles_forward(trips, axes, x, w_gate, w_up, w_down, tiles):
+def _tiles_forward(trips, axes, x, gates, w_gate, w_up, w_down, tiles):
     """One device's part of ``_tiled_ffn``: ``_swiglu`` over the tiles of
-    its worklist, one a trip of a single loop body, zeros elsewhere."""
+    its worklist, each slot's row times its gate, one tile a trip of a
+    single loop body, zeros elsewhere."""
     mlp, _ = axes
     where = _worklist(tiles, x.shape[2] // _FFN_TILE, trips)
 
@@ -181,7 +189,11 @@ def _tiles_forward(trips, axes, x, w_gate, w_up, w_down, tiles):
             _tile(x, at), _expert(w_gate, e), _expert(w_up, e),
             _expert(w_down, e),
         )
-        return jax.lax.dynamic_update_slice(out, y.astype(out.dtype), (*at, 0))
+        # Rounded to the buffers' dtype and then weighted, as combine
+        # weighted a pair's row when the gate was its to apply.
+        return _put_tile(
+            out, y.astype(out.dtype) * _tile(gates, at)[..., None], at
+        )
 
     out = jax.lax.fori_loop(0, trips, trip, jnp.zeros_like(x))
     # Where a mesh axis splits the experts' width, each device holds a
@@ -189,12 +201,15 @@ def _tiles_forward(trips, axes, x, w_gate, w_up, w_down, tiles):
     return (jax.lax.psum(out, mlp) if mlp else out,)
 
 
-def _tiles_backward(trips, axes, x, w_gate, w_up, w_down, tiles, g):
+def _tiles_backward(trips, axes, x, gates, w_gate, w_up, w_down, tiles, g):
     """One device's part of ``_tiled_ffn``'s gradients, tile by tile as
     the forward went. A tile's h and u are computed again here, so no
     [E, B, C, F] array ever exists; the weights' gradients add up in
     float32 and meet those of the other devices' rows once, after the
-    loop."""
+    loop. A slot's gate takes the product of its cotangent with the
+    unweighted row, <g, a w_down>, as <g w_down^T, a>: a row sum over two
+    arrays the tile holds anyway, so the unweighted rows are never
+    needed and nothing has to compute them a second time."""
     mlp, rows = axes
     where = _worklist(tiles, x.shape[2] // _FFN_TILE, trips)
 
@@ -205,90 +220,113 @@ def _tiles_backward(trips, axes, x, w_gate, w_up, w_down, tiles, g):
         return jax.lax.dynamic_update_slice_in_dim(dw, acc, e, axis=0)
 
     def trip(t, carry):
-        dx, dw_gate, dw_up, dw_down = carry
+        dx, d_gates, dw_gate, dw_up, dw_down = carry
         at = e, _, _ = tuple(i[t] for i in where)
-        xt, gt = _tile(x, at), _tile(g, at)
+        xt, gt, gate = _tile(x, at), _tile(g, at), _tile(gates, at)[..., None]
         wg, wu, wd = _expert(w_gate, e), _expert(w_up, e), _expert(w_down, e)
         h = jnp.einsum("ebcd,edf->ebcf", xt, wg)
         u = jnp.einsum("ebcd,edf->ebcf", xt, wu)
         sig = jax.nn.sigmoid(h)
         silu = h * sig
+        act = silu * u
         dact = jnp.einsum("ebcd,efd->ebcf", gt, wd)
+        d_gate = (dact.astype(jnp.float32) * act).sum(-1)
+        dact = dact * gate
         dh = dact * u * (sig + silu * (1 - sig))
         du = dact * silu
         dxt = jnp.einsum("ebcf,edf->ebcd", dh, wg) + jnp.einsum(
             "ebcf,edf->ebcd", du, wu
         )
         return (
-            jax.lax.dynamic_update_slice(dx, dxt.astype(dx.dtype), (*at, 0)),
+            _put_tile(dx, dxt, at),
+            _put_tile(d_gates, d_gate, at),
             add(dw_gate, e, xt, dh),
             add(dw_up, e, xt, du),
-            add(dw_down, e, silu * u, gt),
+            add(dw_down, e, act, gt * gate),
         )
 
-    dx, dw_gate, dw_up, dw_down = jax.lax.fori_loop(0, trips, trip, (
+    dx, d_gates, dw_gate, dw_up, dw_down = jax.lax.fori_loop(0, trips, trip, (
         jnp.zeros_like(x),
+        jnp.zeros(gates.shape, jnp.float32),
         jnp.zeros(w_gate.shape, jnp.float32),
         jnp.zeros(w_up.shape, jnp.float32),
         jnp.zeros(w_down.shape, jnp.float32),
     ))
     if mlp:
-        dx = jax.lax.psum(dx, mlp)
+        dx, d_gates = jax.lax.psum((dx, d_gates), mlp)
 
     def to_weight(dw, w):
         dw = dw.astype(w.dtype)
         return jax.lax.psum(dw, rows) if rows else dw
 
-    return (dx, to_weight(dw_gate, w_gate), to_weight(dw_up, w_up),
-            to_weight(dw_down, w_down))
+    return (dx, d_gates.astype(gates.dtype), to_weight(dw_gate, w_gate),
+            to_weight(dw_up, w_up), to_weight(dw_down, w_down))
 
 
 def _on_devices(local, rows, n_out, *args):
-    """``local`` on every device's shard of (buffers, w_gate, w_up,
+    """``local`` on every device's shard of (buffers, gates, w_gate, w_up,
     w_down, tiles[, cotangent]); called as it is where there is no
-    ambient mesh. The buffers come whole but for their experts and rows,
-    the weights whole but for the experts and their width. ``local``
-    takes the mesh axes first: those that split the width, and the rows."""
+    ambient mesh. The buffers and their slots' gates come whole but for
+    their experts and rows, the weights whole but for the experts and
+    their width. ``local`` takes the mesh axes first: those that split the
+    width, and the rows."""
     buffers = ambient_spec(("expert", rows, None, None))
     local = partial(local, (ambient_axes("mlp"), ambient_axes(rows)))
     if buffers is None:
         return local(*args)
+    slots = ambient_spec(("expert", rows, None))
     w_in = ambient_spec(("expert", None, "mlp"))
     w_out = ambient_spec(("expert", "mlp", None))
     # Forward and backward take a prefix of these and return one: the
     # result lies as the buffers, the gradients as what they are of.
-    specs = (buffers, w_in, w_in, w_out, ambient_spec(("expert", rows)), buffers)
+    specs = (buffers, slots, w_in, w_in, w_out,
+             ambient_spec(("expert", rows)), buffers)
     return jax.shard_map(
         local, in_specs=specs[:len(args)], out_specs=specs[:n_out],
         check_vma=False,
     )(*args)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _tiled_ffn(x, w_gate, w_up, w_down, tiles, trips, rows):
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _tiled_ffn(x, gates, w_gate, w_up, w_down, tiles, trips, rows):
     """``_swiglu`` over the first ``tiles[e, b]`` tiles of every (expert,
-    row) of the buffers, zeros in the rest, in ``trips`` trips a device
+    row) of the buffers, each slot's row times its gate (``gates``
+    [E, B, C]), zeros in the rest, in ``trips`` trips a device
     (``_ffn_trips``). ``rows`` names the logical axis of the batch rows."""
     return _on_devices(
-        partial(_tiles_forward, trips), rows, 1, x, w_gate, w_up, w_down, tiles
+        partial(_tiles_forward, trips), rows, 1,
+        x, gates, w_gate, w_up, w_down, tiles,
     )[0]
 
 
-def _tiled_ffn_fwd(x, w_gate, w_up, w_down, tiles, trips, rows):
-    out = _tiled_ffn(x, w_gate, w_up, w_down, tiles, trips, rows)
-    return out, (x, w_gate, w_up, w_down, tiles)
+def _tiled_ffn_fwd(x, gates, w_gate, w_up, w_down, tiles, trips, rows):
+    out = _tiled_ffn(x, gates, w_gate, w_up, w_down, tiles, trips, rows)
+    return out, (x, gates, w_gate, w_up, w_down, tiles)
 
 
 def _tiled_ffn_bwd(trips, rows, residuals, g):
-    grads = _on_devices(partial(_tiles_backward, trips), rows, 4, *residuals, g)
+    grads = _on_devices(partial(_tiles_backward, trips), rows, 5, *residuals, g)
     return (*grads, np.zeros(residuals[-1].shape, jax.dtypes.float0))
 
 
 _tiled_ffn.defvjp(_tiled_ffn_fwd, _tiled_ffn_bwd)
 
 
-def expert_ffn(expert_in, w_gate, w_up, w_down, counts, pairs):
-    """The stacked experts' SwiGLU over capacity buffers [E, B, C, D].
+def expert_ffn(expert_in, gates, w_gate, w_up, w_down, counts, pairs):
+    """The stacked experts' SwiGLU over capacity buffers [E, B, C, D],
+    each slot's row times its pair's gate (``gates`` [E, B, C], zero in
+    an empty slot).
+
+    The gate is applied here and not where the rows go back to their
+    tokens because of what its gradient is: the product of a pair's
+    cotangent with its unweighted row. Applied in combine, that row has
+    to exist in the backward, and a layer that keeps nothing (remat)
+    runs the whole forward loop a second time to have it. Applied here,
+    the hand-written backward takes the same product on the other side
+    of w_down, from what a tile's trip holds anyway (``_tiles_backward``),
+    combine is linear in what this returns, and no backward reads it: the
+    replayed loop is dead code (37.7 ms of a 474.1 ms step in the Mixtral
+    cell, PERF.md §6, PR 36).
 
     Each slot is computed by one chip: the batch rows are split over
     the mesh's sequence axis too, where they divide (the FFN is
@@ -311,6 +349,7 @@ def expert_ffn(expert_in, w_gate, w_up, w_down, counts, pairs):
     if B % logical_axis_shards(rows):
         rows = "batch"
     expert_in = with_logical_constraint(expert_in, ("expert", rows, None, None))
+    gates = with_logical_constraint(gates, ("expert", rows, None))
     # The weights, and with them their gradients, lie as the parameters
     # do (parallel.mesh.spec_for_param) whatever the buffers' layout
     # suggests to the partitioner.
@@ -322,14 +361,39 @@ def expert_ffn(expert_in, w_gate, w_up, w_down, counts, pairs):
         C, pairs,
     )
     if not trips:
-        return _swiglu(expert_in, w_gate, w_up, w_down)
+        return _swiglu(expert_in, w_gate, w_up, w_down) * gates[..., None]
     # Every device keeps all the counts: split by experts as the loop
     # wants them, they would pull the router behind them onto the
     # expert axis, and its kernel would leave the step laid differently
     # from how it came.
     counts = with_logical_constraint(counts, (None, None))
     tiles = -(-jnp.minimum(counts.T, C).astype(jnp.int32) // _FFN_TILE)
-    return _tiled_ffn(expert_in, w_gate, w_up, w_down, tiles, trips, rows)
+    return _tiled_ffn(
+        expert_in, gates, w_gate, w_up, w_down, tiles, trips, rows
+    )
+
+
+@jax.custom_vjp
+def _gates_to_slots(gates, slot_of_pair, pair_of_slot):
+    """A row's gates [B, N], pairs counted token-major, in their pairs'
+    slots of its capacity buffers [B, E * C]: zero in an empty slot,
+    which holds pair N. ``slot_of_pair`` [B, N] is the same map the
+    other way, E * C for a dropped pair, so the gradient is a gather
+    too (a dropped pair's is zero) where XLA would scatter-add."""
+    gates = jnp.pad(gates, ((0, 0), (0, 1)))
+    return jnp.take_along_axis(gates, pair_of_slot, axis=1)
+
+
+def _gates_to_slots_fwd(gates, slot_of_pair, pair_of_slot):
+    return _gates_to_slots(gates, slot_of_pair, pair_of_slot), slot_of_pair
+
+
+def _gates_to_slots_bwd(slot_of_pair, g):
+    g = jnp.pad(g, ((0, 0), (0, 1)))
+    return jnp.take_along_axis(g, slot_of_pair, axis=1), None, None
+
+
+_gates_to_slots.defvjp(_gates_to_slots_fwd, _gates_to_slots_bwd)
 
 
 def _pair_slots(order, dst, m_pad, K):
@@ -651,8 +715,12 @@ class MoELayer(nn.Module):
     mesh-shards and dispatch rides the collectives over ICI. A pair
     that arrives past its expert's C slots is dropped (never at factor
     E / K). The expert FFN (expert_ffn) is XLA's batched matmuls over
-    the slots that can hold a pair, each slot on one chip. Combine is a
-    gather and a sum over a token's K pairs.
+    the slots that can hold a pair, each slot on one chip; it takes each
+    slot's gate and returns weighted rows, so that combine is a gather
+    and a plain sum over a token's K pairs, linear in the rows: no
+    backward reads them, and a layer under remat does not run the FFN a
+    second time for the gates' gradient, which the FFN's own backward
+    returns.
 
     "gmm": (token, k) pairs sorted by expert into 128-row tiles for the
     pallas grouped matmul (ops/gmm.py): at most E tiles of padding, zero
@@ -884,7 +952,7 @@ class MoELayer(nn.Module):
             buffer slots; drops past capacity land in per-pair dump
             slots (kept unique so XLA needs no collision handling).
 
-            TPU shape of the dispatch: scatter only the int32 slot->token
+            TPU shape of the dispatch: scatter only the int32 slot->pair
             inverse map (cheap scalar scatter), then fill the buffer with
             a row GATHER — row scatters serialize on TPU, row gathers
             vectorize. Pair order stays token-major, so combine is a
@@ -897,17 +965,22 @@ class MoELayer(nn.Module):
             slot = jnp.where(
                 keep, e_flat * C + pos, E * C + jnp.arange(NK, dtype=jnp.int32)
             )
-            tok_ids = jnp.arange(NK, dtype=jnp.int32) // K
-            inv = (
-                jnp.full((E * C + NK,), T, jnp.int32)
+            # An empty slot holds pair NK: token T, the zero row.
+            pair_of_slot = (
+                jnp.full((E * C + NK,), NK, jnp.int32)
                 .at[slot]
-                .set(tok_ids, unique_indices=True)
-            )
+                .set(jnp.arange(NK, dtype=jnp.int32), unique_indices=True)
+            )[: E * C]
             x_pad = jnp.concatenate(
                 [xrow, jnp.zeros((1, D), xrow.dtype)], axis=0
             )
-            buf = x_pad[inv[: E * C]]  # [E*C, D] row gather
-            return buf, jnp.minimum(slot, E * C)
+            buf = x_pad[pair_of_slot // K]  # [E*C, D] row gather
+            return buf, jnp.minimum(slot, E * C), pair_of_slot
+
+        def slot_major(buf):
+            """[B, E*C, ...] -> [E, B, C, ...]; expert_ffn places it on
+            the mesh (the expert axis rides an all-to-all over ICI)."""
+            return jnp.swapaxes(buf.reshape(B, E, C, *buf.shape[2:]), 0, 1)
 
         with tracing.scope(tracing.MOE_DISPATCH):
             # Arrival-order position of each token within its expert's
@@ -916,39 +989,44 @@ class MoELayer(nn.Module):
             position = (
                 jnp.cumsum(expert_mask, axis=1) - expert_mask
             )  # [B, T, E] tokens before me per expert
-            buf, slot = jax.vmap(route_one)(
+            buf, slot, pair_of_slot = jax.vmap(route_one)(
                 x.astype(cfg.dtype), gate_idx, position.astype(jnp.float32)
             )
-            # [B, E*C, D] -> [E, B, C, D]; expert_ffn places it on the
-            # mesh (the expert axis rides an all-to-all over ICI).
-            expert_in = buf.reshape(B, E, C, D).transpose(1, 0, 2, 3)
+            # Each slot's gate rides with its row, by the same map. Every
+            # device keeps a row's gates whole, as it does the counts:
+            # read as the slots lie, they would pull the router onto the
+            # expert axis.
+            gates = with_logical_constraint(
+                gate_vals.astype(cfg.dtype).reshape(B, NK), ("batch", None)
+            )
+            gates = slot_major(_gates_to_slots(gates, slot, pair_of_slot))
+            expert_in = slot_major(buf)
 
         # Stacked expert FFN (SwiGLU like the dense path). E-major
         # weights (created above); parallel.mesh.spec_for_param shards
         # them P("expert", "fsdp"/"tensor", ...) by name.
         with tracing.scope(tracing.MOE_EXPERTS):
             expert_out = expert_ffn(
-                expert_in, w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
-                w_down.astype(cfg.dtype), expert_mask.sum(1), NK,
+                expert_in, gates, w_gate.astype(cfg.dtype),
+                w_up.astype(cfg.dtype), w_down.astype(cfg.dtype),
+                expert_mask.sum(1), NK,
             )
 
-        def combine_one(eo_row, slot_row, gate_row):
+        def combine_one(eo_row, slot_row):
             eo_row = jnp.concatenate(
                 [eo_row, jnp.zeros((1, D), eo_row.dtype)], axis=0
             )
-            pair_out = eo_row[slot_row] * gate_row[:, None]
-            return pair_out.reshape(T, K, D).sum(1)
+            return eo_row[slot_row].reshape(T, K, D).sum(1)
 
-        # Combine back to token order, weighted by gates: gather each
-        # pair's expert output (dropped pairs read the zero dump row),
-        # scale, and reduce the K pairs of every token — pair order is
-        # token-major, so the reduction is a reshape-sum, no scatter.
+        # Combine back to token order: gather each pair's expert output
+        # (dropped pairs read the zero dump row) and reduce the K pairs of
+        # every token — pair order is token-major, so the reduction is a
+        # reshape-sum, no scatter. The rows come weighted by their gates
+        # (expert_ffn says why), so this is linear in them and its
+        # backward reads none: under remat nothing computes them twice.
         with tracing.scope(tracing.MOE_COMBINE):
             expert_out = expert_out.transpose(1, 0, 2, 3).reshape(B, E * C, D)
-            out = jax.vmap(combine_one)(
-                expert_out, slot, gate_vals.astype(cfg.dtype).reshape(B, NK)
-            )
-            return finish(out)
+            return finish(jax.vmap(combine_one)(expert_out, slot))
 
 
 class MixtralForCausalLM(LlamaForCausalLM):

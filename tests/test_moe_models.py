@@ -48,33 +48,80 @@ def test_moe_dispatch_matches_naive_gather(tiny_moe):
     out = layer.apply(params, x)
 
     # Naive reference: per-token top-k gather through each expert's FFN.
+    want = _per_token_oracle(cfg, params, x, C=x.shape[1])[0]
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-3, rtol=2e-3)
+
+
+def _per_token_oracle(cfg, params, x, C):
+    """The layer one pair at a time in float64: each token's top-k experts
+    in order of arrival, a pair past its expert's C slots dropped. Returns
+    the output [B, T, D], and what a gradient through the gates needs with
+    the routing held fixed: the chosen experts [B, T, K], which pairs were
+    kept [B, T, K], and each pair's unweighted row [B, T, K, D]."""
+    E, K = cfg.num_experts, cfg.num_experts_per_tok
     p = params["params"]
-    router_w = np.asarray(p["router"]["kernel"], np.float64)
-    wg = np.asarray(p["w_gate"], np.float64)
-    wu = np.asarray(p["w_up"], np.float64)
-    wd = np.asarray(p["w_down"], np.float64)
+    router_w, wg, wu, wd = (
+        np.asarray(a, np.float64)
+        for a in (p["router"]["kernel"], p["w_gate"], p["w_up"], p["w_down"])
+    )
     xs = np.asarray(x, np.float64)
     B, T, D = xs.shape
     logits = xs @ router_w
     probs = np.exp(logits - logits.max(-1, keepdims=True))
     probs /= probs.sum(-1, keepdims=True)
     want = np.zeros_like(xs)
+    chosen = np.zeros((B, T, K), int)
+    kept = np.zeros((B, T, K), bool)
+    rows = np.zeros((B, T, K, D))
     for b in range(B):
+        arrived = np.zeros(E, int)
         for t in range(T):
-            topk = np.argsort(-probs[b, t])[: cfg.num_experts_per_tok]
-            gates = probs[b, t, topk]
-            gates = gates / gates.sum()
-            acc = np.zeros(D)
-            for gate, e in zip(gates, topk):
-                h = xs[b, t] @ wg[e]
-                u = xs[b, t] @ wu[e]
-                silu = h / (1 + np.exp(-h))
-                acc += gate * ((silu * u) @ wd[e])
-            want[b, t] = acc
-    np.testing.assert_allclose(np.asarray(out), want, atol=2e-3, rtol=2e-3)
+            chosen[b, t] = np.argsort(-probs[b, t], kind="stable")[:K]
+            gates = probs[b, t, chosen[b, t]] / probs[b, t, chosen[b, t]].sum()
+            for k, (gate, e) in enumerate(zip(gates, chosen[b, t])):
+                h, u = xs[b, t] @ wg[e], xs[b, t] @ wu[e]
+                rows[b, t, k] = (h / (1 + np.exp(-h)) * u) @ wd[e]
+                arrived[e] += 1
+                kept[b, t, k] = arrived[e] <= C
+                want[b, t] += kept[b, t, k] * gate * rows[b, t, k]
+    return want, chosen, kept, rows
 
 
-def test_moe_capacity_drops_tokens(tiny_moe):
+# What a capacity test checks: the layer's output, or the router kernel's
+# gradient, which reaches it through the gates alone: from the expert FFN's
+# backward, slot to pair, a dropped pair's zero.
+CAPACITY_CHECKS = ("values", "router_gradient")
+
+
+def _assert_router_gradients_agree(layer, params, x, oracle):
+    """d sum(out * g) / d router kernel, through the layer and through the
+    oracle's pairs: there the gates are the one thing the router moves
+    (renormalised over a token's K chosen experts, a dropped one's
+    probability included), and a dropped pair's gate moves nothing."""
+    _, chosen, kept, rows = oracle
+    g = np.random.RandomState(9).randn(*x.shape).astype(np.float32)
+
+    def per_token(router_w):
+        probs = jax.nn.softmax(x @ router_w, axis=-1)
+        gates = jnp.take_along_axis(probs, jnp.asarray(chosen), axis=-1)
+        gates = gates / gates.sum(-1, keepdims=True) * kept
+        return ((gates[..., None] * rows.astype(np.float32)).sum(2) * g).sum()
+
+    def through_layer(router_w):
+        p = {**params["params"], "router": {"kernel": router_w}}
+        return (layer.apply({"params": p}, x) * g).sum()
+
+    router_w = params["params"]["router"]["kernel"]
+    got, want = jax.grad(through_layer)(router_w), jax.grad(per_token)(router_w)
+    scale = float(np.abs(want).max())
+    assert scale > 0
+    np.testing.assert_allclose(
+        np.asarray(got) / scale, np.asarray(want) / scale, atol=1e-4
+    )
+
+
+@pytest.mark.parametrize("check", CAPACITY_CHECKS)
+def test_moe_capacity_drops_tokens(tiny_moe, check):
     """With capacity 0-ish, combine weights vanish: output ≈ 0."""
     import dataclasses
 
@@ -88,6 +135,11 @@ def test_moe_capacity_drops_tokens(tiny_moe):
     x = jnp.asarray(np.random.RandomState(2).randn(2, 16, cfg.hidden_size),
                     jnp.float32)
     params = layer.init(jax.random.PRNGKey(2), x)
+    if check == "router_gradient":
+        oracle = _per_token_oracle(cfg, params, x, C=1)
+        kept = oracle[2]
+        assert kept.sum() == 2 * cfg.num_experts and not kept[:, -1].any()
+        return _assert_router_gradients_agree(layer, params, x, oracle)
     out = layer.apply(params, x)
     # Capacity C=max(1, ...)=1: only the first token per expert survives.
     per_token = np.abs(np.asarray(out)).sum(-1)
@@ -494,8 +546,9 @@ def _mesh_context(axes):
 
 def _ffn_case(counts, seed=0):
     """Buffers [E, B, C, D] whose occupied slots are the counts' prefixes
-    (empty slots are zero rows, as the dispatch leaves them), a cotangent
-    that is zero where no pair is, as combine's is, and the weights."""
+    (empty slots are zero rows, as the dispatch leaves them), their slots'
+    gates [E, B, C] (zero in an empty slot), a cotangent that is zero where
+    no pair is, as combine's is, and the weights."""
     from ray_tpu.models.mixtral import CONFIGS, _ffn_trips
 
     cfg = CONFIGS["mixtral-tiny"]
@@ -509,39 +562,48 @@ def _ffn_case(counts, seed=0):
     occupied = np.arange(C)[None, None] < counts.T[:, :, None]  # [E, B, C]
     x = rng.randn(E, FFN_ROWS, C, D).astype(np.float32) * occupied[..., None]
     g = rng.randn(E, FFN_ROWS, C, D).astype(np.float32) * occupied[..., None]
+    gates = rng.rand(E, FFN_ROWS, C).astype(np.float32) * occupied
     weights = [
         (rng.randn(*shape) * 0.1).astype(np.float32)
         for shape in ((E, D, F), (E, D, F), (E, F, D))
     ]
-    return x, weights, g, jnp.asarray(counts), FFN_TOKENS * K
+    return x, gates, weights, g, jnp.asarray(counts), FFN_TOKENS * K
 
 
 @pytest.mark.parametrize("mesh", FFN_MESHES)
 @pytest.mark.parametrize("routing", ROUTINGS)
 def test_expert_ffn_matches_the_plain_einsum(routing, mesh):
-    """Values and all four gradients (x, w_gate, w_up, w_down): skipping the
-    tiles past each prefix changes nothing, on one device, on an expert-only
-    mesh, with the rows shared out over seq, and with the experts' width
-    split over a tensor axis."""
+    """Values and all five gradients (x, the slots' gates, w_gate, w_up,
+    w_down) against the plain einsum's rows times their gates: skipping the
+    tiles past each prefix changes nothing, and the gates' gradient taken
+    on the other side of w_down is the one JAX takes through the rows, on
+    one device, on an expert-only mesh, with the rows shared out over seq,
+    and with the experts' width split over a tensor axis."""
     from ray_tpu.models.mixtral import _swiglu, expert_ffn
 
-    x, weights, g, counts, pairs = _ffn_case(ROUTINGS[routing])
+    x, gates, weights, g, counts, pairs = _ffn_case(ROUTINGS[routing])
 
     def value_and_grads(ffn):
-        def loss(x, *w):
-            return (ffn(x, *w) * g).sum()
+        def loss(x, gates, *w):
+            return (ffn(x, gates, *w) * g).sum()
 
-        return jax.jit(lambda x, *w: (
-            ffn(x, *w), jax.grad(loss, argnums=(0, 1, 2, 3))(x, *w)
-        ))(x, *weights)
+        return jax.jit(lambda x, gates, *w: (
+            ffn(x, gates, *w),
+            jax.grad(loss, argnums=(0, 1, 2, 3, 4))(x, gates, *w),
+        ))(x, gates, *weights)
 
     with _mesh_context(FFN_MESHES[mesh]):
-        want, want_grads = value_and_grads(_swiglu)
+        want, want_grads = value_and_grads(
+            lambda x, gates, *w: _swiglu(x, *w) * gates[..., None]
+        )
         got, got_grads = value_and_grads(
-            lambda x, *w: expert_ffn(x, *w, counts, pairs)
+            lambda x, gates, *w: expert_ffn(x, gates, *w, counts, pairs)
         )
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    for name, a, b in zip(("x", "w_gate", "w_up", "w_down"), got_grads, want_grads):
+    assert np.abs(np.asarray(want_grads[1])).max() > 0
+    for name, a, b in zip(
+        ("x", "gates", "w_gate", "w_up", "w_down"), got_grads, want_grads
+    ):
         scale = float(np.abs(b).max()) or 1.0
         np.testing.assert_allclose(
             np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-5, err_msg=name
@@ -556,9 +618,9 @@ def test_expert_ffn_computes_the_tiles_its_prefixes_reach(routing):
     trips: the same number of tiles whatever the routing."""
     from ray_tpu.models.mixtral import _ffn_trips, expert_ffn
 
-    x, weights, _, counts, pairs = _ffn_case(ROUTINGS[routing])
-    out = jax.jit(expert_ffn, static_argnums=5)(
-        np.ones_like(x), *weights, counts, pairs
+    x, gates, weights, _, counts, pairs = _ffn_case(ROUTINGS[routing])
+    out = jax.jit(expert_ffn, static_argnums=6)(
+        np.ones_like(x), np.ones_like(gates), *weights, counts, pairs
     )
     E, B, C, _ = x.shape
     computed = np.abs(np.asarray(out)).reshape(E, B, C // 512, 512, -1).any(
@@ -604,7 +666,8 @@ def test_ffn_trips_cover_the_worst_routing():
     assert most == bound == 19
 
 
-def test_default_capacity_factor_computes_what_it_did(tiny_moe):
+@pytest.mark.parametrize("check", CAPACITY_CHECKS)
+def test_default_capacity_factor_computes_what_it_did(tiny_moe, check):
     """At the default factor 1.25 the buffers are four fifths full, the FFN
     is one einsum over all of them, and the layer computes what it always
     did: the per-token function, less the pairs that arrive after their
@@ -625,33 +688,12 @@ def test_default_capacity_factor_computes_what_it_did(tiny_moe):
     # that some expert is offered more pairs than its buffer holds.
     x = jnp.asarray(np.random.RandomState(6).randn(B, T, D) + 1.5, jnp.float32)
     params = layer.init(jax.random.PRNGKey(6), x)
+    oracle = _per_token_oracle(cfg, params, x, C)
+    assert not oracle[2].all()  # the case has pairs past capacity
+    if check == "router_gradient":
+        return _assert_router_gradients_agree(layer, params, x, oracle)
     out = np.asarray(layer.apply(params, x))
-
-    p = params["params"]
-    router_w, wg, wu, wd = (
-        np.asarray(a, np.float64)
-        for a in (p["router"]["kernel"], p["w_gate"], p["w_up"], p["w_down"])
-    )
-    xs = np.asarray(x, np.float64)
-    logits = xs @ router_w
-    probs = np.exp(logits - logits.max(-1, keepdims=True))
-    probs /= probs.sum(-1, keepdims=True)
-    want = np.zeros_like(xs)
-    dropped = 0
-    for b in range(B):
-        arrived = np.zeros(E, int)
-        for t in range(T):
-            topk = np.argsort(-probs[b, t], kind="stable")[:K]
-            gates = probs[b, t, topk] / probs[b, t, topk].sum()
-            for gate, e in zip(gates, topk):
-                arrived[e] += 1
-                if arrived[e] > C:
-                    dropped += 1
-                    continue
-                h, u = xs[b, t] @ wg[e], xs[b, t] @ wu[e]
-                want[b, t] += gate * ((h / (1 + np.exp(-h)) * u) @ wd[e])
-    assert dropped > 0  # the case has pairs past capacity
-    np.testing.assert_allclose(out, want, atol=2e-3, rtol=2e-3)
+    np.testing.assert_allclose(out, oracle[0], atol=2e-3, rtol=2e-3)
 
 
 LAYER_MESHES = {"expert2": dict(expert=2), "seq2_expert2": dict(seq=2, expert=2)}
@@ -660,7 +702,8 @@ LAYER_MESHES = {"expert2": dict(expert=2), "seq2_expert2": dict(seq=2, expert=2)
 @pytest.fixture(scope="module")
 def compiled_layers(tiny_moe):
     """mesh name -> (the compiled text of the layer's forward and backward
-    at factor 4.0, its parameters on that mesh, their gradients)."""
+    at factor 4.0, its parameters on that mesh, their gradients, the
+    compiled text of the same under a remat that saves nothing)."""
     import dataclasses
 
     from ray_tpu.models.mixtral import MoELayer
@@ -688,8 +731,21 @@ def compiled_layers(tiny_moe):
                 jax.grad(lambda p, x: (layer.apply(p, x) ** 2).sum())
             )
             text = step.lower(params, xs).compile().as_text()
-            out[name] = (text, params, step(params, xs))
+            replaying = jax.jit(jax.grad(lambda p, x: (jax.checkpoint(
+                layer.apply, policy=jax.checkpoint_policies.nothing_saveable
+            )(p, x) ** 2).sum()))
+            out[name] = (
+                text, params, step(params, xs),
+                replaying.lower(params, xs).compile().as_text(),
+            )
     return out
+
+
+def _ffn_loops(text):
+    return [
+        line for line in text.split("\n")
+        if " while(" in line and "/experts/" in line
+    ]
 
 
 @pytest.mark.parametrize("mesh", LAYER_MESHES)
@@ -707,10 +763,7 @@ def test_expert_ffn_is_not_replicated_over_seq(compiled_layers, mesh):
     C = int(4.0 * FFN_TOKENS * cfg.num_experts_per_tok / cfg.num_experts)
     slots = cfg.num_experts * FFN_ROWS * C
     devices = int(np.prod(list(LAYER_MESHES[mesh].values())))
-    loops = [
-        line for line in compiled_layers[mesh][0].split("\n")
-        if " while(" in line and "/experts/" in line
-    ]
+    loops = _ffn_loops(compiled_layers[mesh][0])
     assert len(loops) == 2, loops  # forward and backward
     for line in loops:
         buffers = set(re.findall(
@@ -722,10 +775,24 @@ def test_expert_ffn_is_not_replicated_over_seq(compiled_layers, mesh):
 
 
 @pytest.mark.parametrize("mesh", LAYER_MESHES)
+def test_replay_does_not_run_the_ffn_loop_again(compiled_layers, mesh):
+    """A layer that saves nothing replays its forward in the backward, and
+    the replay holds no FFN loop: the expert FFN's backward computes a
+    tile's activations itself and takes the gates' gradient from them, and
+    combine is linear in the weighted rows, so nothing reads what the
+    forward loop wrote and the compiled step has the two loops it has
+    without remat. A gate applied in combine makes them three: its gradient
+    is the cotangent times the unweighted rows, which only the whole
+    forward loop can give."""
+    loops = _ffn_loops(compiled_layers[mesh][3])
+    assert len(loops) == 2, loops  # forward and backward; no replay
+
+
+@pytest.mark.parametrize("mesh", LAYER_MESHES)
 def test_layer_gradients_lie_as_its_parameters(compiled_layers, mesh):
     """The buffers' split of the expert axis stays inside the FFN: a step
     that donates its parameters gets back arrays laid as it passed them."""
-    _, params, grads = compiled_layers[mesh]
+    _, params, grads, _ = compiled_layers[mesh]
     for g, p in zip(jax.tree_util.tree_leaves(grads),
                     jax.tree_util.tree_leaves(params)):
         assert g.sharding.is_equivalent_to(p.sharding, g.ndim), (
