@@ -17,11 +17,9 @@ x); the output projection. The three normalisations over a head's channels
 and the output gate are ``ops/kda.py``'s, on the blocks its kernels hold:
 the mixer hands it q and k raw and takes o normalised and gated.
 
-MLA (``MLAMixer``): q heads of 128 + 64; keys and values from a shared
-latent of 512 (down-projection, RMSNorm, up-projection to 128 + 128 a head)
-and one 64-wide key part shared by all heads; softmax attention with q/k
-heads of 192 and v heads of 128 through the flash kernels, K and V
-materialised (training).
+MLA (``mla.py``'s ``MLAMixer``, shared with ``sarvam_mla.py``): q heads of
+128 + 64; keys and values from a shared latent of 512 and one 64-wide key
+part shared by all heads; here without rotation, scaling or QK norm.
 
 The expert layer is mixtral.py's ``MoELayer`` told to score by sigmoid, to
 scale its renormalised gates, to add a shared expert and to hold a range of
@@ -37,24 +35,20 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import flash_attention
 from ..ops.kda import chunk_kda, kda_gate, short_conv
 from ..util import tracing
-from .llama import RMSNorm, weight_init
-from .mixtral import MixtralConfig, MixtralForCausalLM
+from .llama import weight_init
+from .mixtral import MixtralForCausalLM
+from .mla import MLAConfig, MLAMixer
 
 
 @dataclass(frozen=True)
-class KimiLinearConfig(MixtralConfig):
+class KimiLinearConfig(MLAConfig):
     # Each layer's (mixer, ffn): "kda" or "mla", "mlp" or "moe".
     layer_kinds: Tuple[Tuple[str, str], ...] = ()
     kda_num_heads: int = 32
     kda_head_dim: int = 128
     short_conv_kernel_size: int = 4
-    kv_lora_rank: int = 512
-    qk_nope_head_dim: int = 128
-    qk_rope_head_dim: int = 64
-    v_head_dim: int = 128
     router_score: str = "sigmoid"
     moe_dispatch: str = "gmm"
     remat_policy: str = "nothing"
@@ -182,42 +176,6 @@ class KDAMixer(nn.Module):
                 scale=d ** -0.5, rms_eps=cfg.rms_eps,
             )
         return _dense(cfg, cfg.hidden_size, "o_proj")(o.reshape(B, T, H * d))
-
-
-class MLAMixer(nn.Module):
-    cfg: KimiLinearConfig
-    mesh: Optional[Any] = None
-
-    @nn.compact
-    def __call__(self, x, positions):
-        cfg = self.cfg
-        H, rank = cfg.num_heads, cfg.kv_lora_rank
-        nope, pe, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        heads = lambda feats, name: nn.DenseGeneral(  # noqa: E731
-            (H, feats), axis=-1, use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
-            name=name,
-        )
-        q = heads(nope + pe, "q_proj")(x)  # [B, T, H, 192]: nope | pe
-        with tracing.scope(tracing.MLA_LATENT):
-            latent = _dense(cfg, rank + pe, "kv_a_proj")(x)
-            c = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="kv_a_norm")(
-                latent[..., :rank]
-            )
-            kv = heads(nope + dv, "kv_b_proj")(c)  # [B, T, H, 256]: k nope | v
-            # The 64-wide key part is one for all heads.
-            k_pe = jnp.broadcast_to(
-                latent[..., None, rank:], (*kv.shape[:3], pe)
-            )
-            k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
-            v = kv[..., nope:]
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        o = flash_attention(q, k, v, causal=True, sm_scale=(nope + pe) ** -0.5)
-        return nn.DenseGeneral(
-            cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
-            name="o_proj",
-        )(o.transpose(0, 2, 1, 3))
 
 
 class KimiLinearForCausalLM(MixtralForCausalLM):

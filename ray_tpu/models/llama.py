@@ -131,15 +131,26 @@ def weight_init(cfg: LlamaConfig, default=nn.initializers.lecun_normal()):
     return nn.initializers.normal(cfg.initializer_range)
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embeddings. x [B, H, T, D], positions [B, T]."""
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[:, None, :, None].astype(jnp.float32) * freqs  # [B,1,T,D/2]
+def rope_frequencies(dim: int, theta: float) -> jax.Array:
+    """The plain rotary table: ``dim // 2`` frequencies theta^(-2i/dim)."""
+    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+
+
+def _rope(x: jax.Array, positions: jax.Array, freqs: jax.Array) -> jax.Array:
+    """Rotary embeddings. x [B, H, T, D], positions [B, T]; ``freqs`` is
+    the caller's table (``rope_frequencies``, or a scaled one), one
+    frequency a pair of channels. The trailing ``2 * len(freqs)`` channels
+    of a head turn, channel i of them with channel i + len(freqs), and the
+    ones before them pass as they are."""
+    d = 2 * freqs.shape[0]
+    whole = d == x.shape[-1]
+    angles = positions[:, None, :, None].astype(jnp.float32) * freqs  # [B,1,T,d/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    turned = x if whole else x[..., -d:]
+    x1, x2 = jnp.split(turned.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    out = out.astype(x.dtype)
+    return out if whole else jnp.concatenate([x[..., :-d], out], axis=-1)
 
 
 class RMSNorm(nn.Module):
@@ -178,8 +189,8 @@ class Attention(nn.Module):
                 q, k = norm(q, "q_norm"), norm(k, "k_norm")
         # [B, T, H, D] -> [B, H, T, D]
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q = _rope(q, positions, rope_frequencies(hd, cfg.rope_theta))
+        k = _rope(k, positions, rope_frequencies(hd, cfg.rope_theta))
         use_ring = (
             self.mesh is not None and self.mesh.shape.get("seq", 1) > 1
         )

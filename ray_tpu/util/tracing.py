@@ -48,21 +48,23 @@ MOE_DISPATCH = "dispatch"  # positions, slot map, gather into the expert buffer
 MOE_EXPERTS = "experts"  # the three expert matmuls and the activation
 MOE_COMBINE = "combine"  # gather back, gate scaling, the reduction over k
 MOE_LAYOUT = "layout"  # inside dispatch, "gmm" only: sort, tile layout, inverse map
-QK_NORM = "qk_norm"  # RMSNorm of the whole q and k projections (cfg.qk_norm)
+QK_NORM = "qk_norm"  # RMSNorm of q and k: the whole projections in attn (cfg.qk_norm), a head's channels inside mla (cfg.qk_head_norm)
 MOE_SHARED = "shared"  # inside moe: the shared expert every token passes
 # The mixers' flax names, which reach op_name as the attention's "attn" does.
 KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer)
-MLA = "mla"  # the latent-attention mixer (models/kimi_linear.py MLAMixer)
+MLA = "mla"  # the latent-attention mixer (models/mla.py MLAMixer)
 KDA_CONV = "conv"  # inside kda: the short convolutions of q, k, v and their SiLU
 KDA_GATE = "gate"  # inside kda: the log-decay g and the write strength beta
 KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's and o's norms and the output gate), v's rounding, beta's transpose
 # (No "out_norm": o's per-head RMSNorm and output gate left XLA for the scan's
 # kernels, and a scope that no operation carries is not in this list.)
 MLA_LATENT = "latent"  # inside mla: down-projection, norm, up-projection of K/V
+MLA_ROPE = "rope"  # inside mla (cfg.mla_rope): the frequency table, the rotation of q's and k's pe parts, the slices and concatenations around it
 SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
-          MLA_LATENT)
-MIXERS = (KDA, MLA)  # flax module names, bound in KimiLinearForCausalLM.blocks
+          MLA_LATENT, MLA_ROPE)
+# Flax module names, bound in the model classes' ``blocks``.
+MIXERS = (KDA, MLA)
 
 _OFF = contextlib.nullcontext()
 # The flight recorder, while this process holds a train session.

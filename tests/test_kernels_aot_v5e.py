@@ -91,9 +91,11 @@ def test_gmm_and_its_gradient_compile_for_v5e(v5e, m, experts, k, n):
 
 
 # Kimi-Linear's MLA layer (the benchmark's longctx-16k cell): 32 heads over
-# 16,384 tokens, q/k heads of 128 + 64 and v heads of 128.
-def test_flash_at_192_and_128_compiles_for_v5e(v5e):
-    bh, t, d, d_v, block = 32, 16384, 192, 128, 1024
+# 16,384 tokens, q/k heads of 128 + 64 and v heads of 128; sarvam-105b's five
+# (pretrain-4k): 64 heads over 4,096 tokens at the same head dims.
+@pytest.mark.parametrize("bh,t", [(32, 16384), (64, 4096)])
+def test_flash_at_192_and_128_compiles_for_v5e(v5e, bh, t):
+    d, d_v, block = 192, 128, 1024
     qk, v = ((bh, t, d), jnp.bfloat16), ((bh, t, d_v), jnp.bfloat16)
     args = dict(causal=True, sm_scale=d**-0.5, block_q=block, block_k=block)
     _compile_for(v5e, lambda q, k, v: _flash_fwd_pallas(q, k, v, **args), qk, qk, v)
@@ -106,10 +108,14 @@ def test_flash_at_192_and_128_compiles_for_v5e(v5e):
 
 # The same cell's expert layer: 16 held experts of 2304 x 1024 over a
 # layout bounded at every pair of 16,384 tokens x top-8 (+ 17 tiles), told
-# how many tiles hold rows.
-@pytest.mark.parametrize("k,n", [(2304, 1024), (1024, 2304)])
-def test_bounded_gmm_and_its_gradient_compile_for_v5e(v5e, k, n):
-    m, experts = 16384 * 8 + 17 * 128, 16
+# how many tiles hold rows. And pretrain-4k's: 8 held experts of 4096 x 2048
+# over every pair of 4,096 tokens x top-8 (+ 9 tiles).
+@pytest.mark.parametrize("tokens,experts,k,n", [
+    (16384, 16, 2304, 1024), (16384, 16, 1024, 2304),
+    (4096, 8, 4096, 2048), (4096, 8, 2048, 4096),
+])
+def test_bounded_gmm_and_its_gradient_compile_for_v5e(v5e, tokens, experts, k, n):
+    m = tokens * 8 + (experts + 1) * 128
     text = _compile_for(
         v5e,
         lambda lhs, rhs, tg, used: jax.grad(

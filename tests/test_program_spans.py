@@ -143,7 +143,62 @@ def kimi_paths():
         del os.environ["RAY_TPU_PALLAS_INTERPRET"]
 
 
+@pytest.fixture(scope="module")
+def sarvam_paths():
+    """Paths of a tiny sarvam_mla model's compiled train step: a dense and
+    an expert layer, each under latent attention with its 8-wide parts
+    rotated under YaRN and a per-head QK norm."""
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+    from ray_tpu.models.sarvam_mla import SarvamMLAForCausalLM, sarvam_mla_config
+
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"  # read when gmm is traced
+    try:
+        cfg = sarvam_mla_config(
+            num_layers=2, num_experts_held=2, vocab_size=128, hidden_size=32,
+            intermediate_size=64, moe_intermediate_size=16, num_heads=2,
+            num_experts=8, num_experts_per_tok=2, num_shared_experts=1,
+            routed_scaling_factor=2.5, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16,
+            rope_scaling={"type": "deepseek_yarn", "factor": 40,
+                          "original_max_position_embeddings": 4096,
+                          "mscale": 1, "mscale_all_dim": 1},
+        )
+        model = SarvamMLAForCausalLM(cfg)
+        ids = jnp.zeros((1, 64), jnp.int32)
+        return paths_of(compiled_step(
+            model,
+            lambda p, i, t: chunked_causal_lm_loss(model, p, i, t, chunk_size=32),
+            ids,
+        ))
+    finally:
+        del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+
+
 # ----------------------------------------------------------- in-graph scopes
+
+
+def test_latent_attention_carries_rope_and_qk_norm_where_a_model_has_them(
+        sarvam_paths, kimi_paths):
+    """What the benchmark's model.mla_rotary_share selects by: the rotation
+    and the per-head norm inside /mla/, forward, backward and replay, in every
+    layer of the model that has them and in none of Kimi-Linear's."""
+    for layer in ("layers_0", "layers_1"):
+        mla = [p for p in sarvam_paths if f"/{layer}/mla/" in p]
+        for name in (tracing.MLA_ROPE, tracing.QK_NORM, tracing.MLA_LATENT):
+            scoped = [p for p in mla if f"/mla/{name}/" in p]
+            assert scoped, (layer, name)
+        for name in (tracing.MLA_ROPE, tracing.QK_NORM):
+            assert {pass_of(p) for p in mla if f"/mla/{name}/" in p} >= {
+                "forward", "backward"}, (layer, name)
+        assert any(f"/mla/{tracing.QK_NORM}/q_norm/" in p for p in mla)
+        assert any(f"/mla/{tracing.QK_NORM}/k_norm/" in p for p in mla)
+    # the projections and the latent are outside both scopes
+    assert not [p for p in sarvam_paths if "/rope/" in p and "proj" in p]
+    assert any("/layers_0/mlp/" in p for p in sarvam_paths)
+    assert any(f"/layers_1/moe/{tracing.MOE_SHARED}/shared/" in p for p in sarvam_paths)
+    assert not [p for p in sarvam_paths if "/attn/" in p or "/kda/" in p]
+    for name in (tracing.MLA_ROPE, tracing.QK_NORM):
+        assert not [p for p in kimi_paths if f"/mla/{name}/" in p], name
 
 
 def test_the_hybrid_carries_its_mixers_names_and_scopes(kimi_paths):
@@ -392,13 +447,14 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 
 
 def test_names_emitted_are_exactly_the_list(
-    llama_paths, qk_norm_paths, moe_paths, kimi_paths, session_lines, actor_lines
+    llama_paths, qk_norm_paths, moe_paths, kimi_paths, sarvam_paths,
+    session_lines, actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + qk_norm_paths + kimi_paths + [
+    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + [
         p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:
         assert any(f"/{name}/" in p for p in paths), name
