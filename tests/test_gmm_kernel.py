@@ -120,17 +120,61 @@ def test_tgmm_reads_its_inputs_at_most_twice_and_writes_each_block_once(call):
         assert lhs_b + dout_b + out_b <= 1.0e9
 
 
-@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 3584), (4096, 14336),
-                                 (96, 40)])
-def test_block_rules_keep_to_the_budget_and_divide_the_shape(k, n):
-    block_n = G._gmm_block_n(k, n, BF16)
+# The capacity FFN's weight gradients (models/mixtral.py _tiles_backward), one
+# chip of the Mixtral cell: four experts of 4096 x 14336, 19 trips of 512 rows.
+MIXTRAL = {"mixtral-gate_up": (4096, 14336), "mixtral-down": (14336, 4096)}
+
+
+@pytest.mark.parametrize("call", MIXTRAL)
+def test_tgmm_over_the_capacity_ffns_trips_writes_each_experts_block_once(call):
+    """The trips of a seeded routing as ``_worklist`` orders them: an
+    expert's are consecutive and none is without one, so each of the 14
+    (2048, 2048) blocks of each expert's gradient is written once, and the
+    stacked operands are read once a block of the other side."""
+    from ray_tpu.models.mixtral import _ffn_trips, _worklist
+
+    experts, rows, C, pairs = 4, 1, 4096, 8192
+    k, n = MIXTRAL[call]
+    trips = _ffn_trips(experts, rows, C, pairs)
+    counts = np.asarray([[2100], [0], [3000], [513]])  # an expert's pairs
+    tile_group = np.asarray(
+        _worklist(jnp.asarray(-(-counts // 512)), C // 512, trips)[0]
+    )
+    assert trips == 19 and _runs(tile_group) == experts
+    m = trips * 512
+    block_k, block_n = G._tgmm_blocks(k, n, BF16, 512)
+    assert (block_k, block_n) == (2048, 2048)
+    grid, in_specs, out_spec = G._tgmm_grid(m, k, n, 512, block_k, block_n)
+    assert grid == (k // 2048, n // 2048, trips)
+    assert _blocks_moved(grid, out_spec, tile_group) == experts * 14
+    lhs_b, dout_b, out_b = _bytes_moved(grid, (*in_specs, out_spec), tile_group)
+    assert lhs_b == grid[1] * m * k * BF16 and dout_b == grid[0] * m * n * BF16
+    assert out_b == experts * k * n * BF16
+    # A grid step's matmul against the bytes it fetches: on the MXU's side of
+    # a v5e's 240 FLOPs a byte.
+    assert 2 * block_k * block_n / ((block_k + block_n) * BF16) > 240
+
+
+@pytest.mark.parametrize("k,n,block_m", [
+    (2048, 1024, 128), (1024, 3584, 128), (4096, 14336, 128), (96, 40, 128),
+    (4096, 14336, 512), (14336, 4096, 512),
+])
+def test_block_rules_keep_to_the_budget_and_divide_the_shape(k, n, block_m):
+    block_n = G._gmm_block_n(k, n, BF16, block_m)
     assert n % block_n == 0 and (block_n == n or block_n % 128 == 0)
-    assert 2 * (128 * k + k * block_n + 128 * block_n) * BF16 <= G._BLOCK_BUDGET
-    block_k, block_n = G._tgmm_blocks(k, n, BF16)
+    assert (
+        2 * (block_m * k + k * block_n + block_m * block_n) * BF16
+        + 4 * block_m * block_n <= G._BLOCK_BUDGET
+    )
+    block_k, block_n = G._tgmm_blocks(k, n, BF16, block_m)
     assert k % block_k == 0 and n % block_n == 0
     assert block_k == k or block_k % 128 == 0
     assert block_n == n or block_n % 128 == 0
-    assert (4 + 2 * BF16) * block_k * block_n <= G._BLOCK_BUDGET < G._VMEM_LIMIT
+    assert (
+        (4 + 2 * BF16) * block_k * block_n
+        + 2 * block_m * (block_k + block_n) * BF16
+        <= G._BLOCK_BUDGET < G._VMEM_LIMIT
+    )
 
 
 # ------------------------------------------------------------------ numerics

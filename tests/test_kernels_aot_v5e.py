@@ -9,19 +9,23 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops import kda
 from ray_tpu.ops.attention import _flash_bwd_pallas, _flash_fwd_pallas
-from ray_tpu.ops.gmm import gmm
+from ray_tpu.ops.gmm import _tgmm_pallas, gmm
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def topo():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # noqa: BLE001 - libtpu absent or too old
         pytest.skip(f"libtpu gives no v5e topology here: {e}")
+
+
+@pytest.fixture(scope="module")
+def v5e(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -127,6 +131,19 @@ def test_bounded_gmm_and_its_gradient_compile_for_v5e(v5e, tokens, experts, k, n
     assert text.count("tpu_custom_call") >= 2  # dlhs and drhs
 
 
+# The Mixtral cell's capacity FFN (ep2seq2-4k): a chip's four experts of
+# 4096 x 14336, the weights' gradients over 19 stacked trips of 512 rows, a
+# trip a tile of one group: (2048, 2048) blocks with their float32
+# accumulator, 40 MiB of VMEM by the rule's own count.
+@pytest.mark.parametrize("k,n", [(4096, 14336), (14336, 4096)])
+def test_tgmm_over_the_capacity_ffns_trips_compiles_for_v5e(v5e, k, n):
+    m = 19 * 512
+    _compile_for(
+        v5e, lambda lhs, dout, tg: _tgmm_pallas(lhs, dout, tg, 4, 512),
+        ((m, k), jnp.bfloat16), ((m, n), jnp.bfloat16), ((19,), jnp.int32),
+    )
+
+
 # The same cell's KDA layers: 32 heads of 128 over 16,384 tokens, q and k
 # raw in float32, the output gate and the norm's weight with them: the
 # forward kernel (with and without the states) and the backward kernel,
@@ -196,6 +213,62 @@ def test_the_models_that_were_there_lower_to_the_kernels_they_had(v5e, monkeypat
              "_tgmm_kernel", "_kda_fwd_kernel", "_kda_bwd_kernel", "_unwritten_kernel")
     counts = {k: n for k, n in checks.count_pallas_kernels(text, names).items() if n}
     assert counts == KERNELS_BEFORE[name]
+
+
+def test_mixtrals_step_takes_its_weight_gradients_from_the_grouped_matmul(topo):
+    """The Mixtral cell's step at its real size on seq=2 x expert=2 (the
+    rehearsal size above runs the plain einsum: `_ffn_trips` is 0 there),
+    lowered and not compiled: each layer's backward ends in three
+    `_tgmm_kernel` calls over the five stacks its loop filled, and no
+    float32 array of a chip's four expert matrices is left for a loop to
+    carry."""
+    import importlib
+
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from benchmarks.lib import cells, checks
+    from benchmarks.loops.train_lm import make_loss_fn, make_optimizer
+    from ray_tpu import train
+    from ray_tpu.parallel import MeshSpec, logical_sharding
+    from ray_tpu.parallel.mesh import spec_for_param
+
+    cell = cells.load_cell("mixtral-8x7b-l2.ep2seq2-4k")
+    config, traffic = cell["config"], cell["traffic"]
+    mesh = MeshSpec(**traffic["mesh"]).build(topo.devices[: cell["chips"]])
+    cfg = cells.program_config(config)
+    model_cls = cells.resolve(config["program"]["model"])
+    shapes = jax.eval_shape(
+        model_cls(cfg).init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
+
+    def placed(path, leaf):
+        keys = tuple(getattr(p, "key", getattr(p, "name", getattr(p, "idx", "")))
+                     for p in path)
+        keys = keys[keys.index("params"):] if "params" in keys else keys
+        spec = spec_for_param(keys, leaf.shape) if leaf.ndim else PartitionSpec()
+        return jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec))
+
+    tx = make_optimizer(traffic)
+    batch = jax.ShapeDtypeStruct(
+        (traffic["batch"], traffic["seq"]), np.int32,
+        sharding=logical_sharding(mesh, ("batch", "seq")))
+    with pytest.MonkeyPatch.context() as patch, jax.set_mesh(mesh):
+        for module in ("ray_tpu.ops.attention", "ray_tpu.ops.ring_attention"):
+            patch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+        model = model_cls(cfg, mesh=mesh)
+        text = train.make_train_step(make_loss_fn(traffic, model), tx).lower(
+            jax.tree_util.tree_map_with_path(placed, shapes),
+            jax.tree_util.tree_map_with_path(placed, jax.eval_shape(tx.init, shapes)),
+            batch, batch,
+        ).as_text()
+    layers, width = config["num_hidden_layers"], config["intermediate_size"]
+    counts = checks.count_pallas_kernels(text, ("_tgmm_kernel", "_unwritten_kernel"))
+    assert counts == {"_tgmm_kernel": 3 * layers, "_unwritten_kernel": 5 * layers}
+    hidden = config["hidden_size"]
+    assert f"tensor<{19 * 512}x{width}xbf16>" in text  # a stack of 19 trips
+    for shape in (f"4x{hidden}x{width}", f"4x{width}x{hidden}"):
+        assert f"tensor<{shape}xbf16>" in text and f"tensor<{shape}xf32>" not in text
 
 
 # Kimi-Linear's step at the benchmark's real size (b1 x s16384, five layers at

@@ -502,7 +502,9 @@ def test_chunked_loss_on_moe_model_matches_full(tiny_moe):
 # expert_ffn against the plain einsum (_swiglu) on the same buffers. The
 # tiny configuration's widths with Mixtral's eight experts, top-2, at
 # capacity factor 4.0 and 2,048 tokens a row: C = 2,048 slots, four tiles of
-# 512, and a buffer that one expert's pairs can fill.
+# 512, and a buffer that one expert's pairs can fill. The tiled FFN's weight
+# gradients are ops/gmm.py's kernel, interpreted here: a block of them that
+# no trip visits reads NaN, and one written twice holds its last visit alone.
 
 FFN_ROWS, FFN_TOKENS, FFN_EXPERTS, FFN_TOP_K = 2, 2048, 8, 2
 # counts[row][expert]: pairs in the buffer of an (expert, row); the slots
@@ -522,6 +524,15 @@ ROUTINGS = {
     # of seq=2 x expert=2.
     "most_tiles_a_chip_can_reach": [[1152, 1152, 1152, 640, 0, 0, 0, 0],
                                     [0, 0, 0, 0, 640, 1152, 1152, 1152]],
+    # One expert of a chip's four holds all of a row's pairs that come to
+    # the chip: the three others' only trips are empty tiles.
+    "every_pair_to_one_expert_a_chip": [[2048, 0, 0, 0, 0, 2048, 0, 0],
+                                        [0, 0, 0, 2048, 0, 0, 2048, 0]],
+    # Experts that reach tiles between experts that reach none: the empty
+    # tiles that fill the trips lie before, between and after the reached
+    # ones, and each expert's trips still have to be consecutive.
+    "reached_and_empty_interleave": [[1, 0, 2047, 0, 2048, 0, 0, 0],
+                                     [0, 600, 0, 1448, 0, 2048, 0, 0]],
 }
 FFN_MESHES = {
     "single_device": None,
@@ -532,6 +543,11 @@ FFN_MESHES = {
     "seq2_expert2": dict(seq=2, expert=2),
     "data2_expert2_tensor2": dict(data=2, expert=2, tensor=2),
 }
+
+
+@pytest.fixture
+def interpret(monkeypatch):
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
 
 
 def _mesh_context(axes):
@@ -572,13 +588,16 @@ def _ffn_case(counts, seed=0):
 
 @pytest.mark.parametrize("mesh", FFN_MESHES)
 @pytest.mark.parametrize("routing", ROUTINGS)
-def test_expert_ffn_matches_the_plain_einsum(routing, mesh):
+def test_expert_ffn_matches_the_plain_einsum(routing, mesh, interpret):
     """Values and all five gradients (x, the slots' gates, w_gate, w_up,
     w_down) against the plain einsum's rows times their gates: skipping the
-    tiles past each prefix changes nothing, and the gates' gradient taken
-    on the other side of w_down is the one JAX takes through the rows, on
+    tiles past each prefix changes nothing, the gates' gradient taken
+    on the other side of w_down is the one JAX takes through the rows, and
+    the weights' gradients added up an expert at a time by the grouped
+    matmul after the loop are the ones added up over all slots, on
     one device, on an expert-only mesh, with the rows shared out over seq,
-    and with the experts' width split over a tensor axis."""
+    and with the experts' width split over a tensor axis. An expert that
+    no pair reached has gradients of exact zeros."""
     from ray_tpu.models.mixtral import _swiglu, expert_ffn
 
     x, gates, weights, g, counts, pairs = _ffn_case(ROUTINGS[routing])
@@ -608,10 +627,13 @@ def test_expert_ffn_matches_the_plain_einsum(routing, mesh):
         np.testing.assert_allclose(
             np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-5, err_msg=name
         )
+    unreached = np.asarray(counts).sum(0) == 0
+    for name, a in zip(("w_gate", "w_up", "w_down"), got_grads[2:]):
+        assert not np.asarray(a)[unreached].any(), name
 
 
 @pytest.mark.parametrize("routing", ROUTINGS)
-def test_expert_ffn_computes_the_tiles_its_prefixes_reach(routing):
+def test_expert_ffn_computes_the_tiles_its_prefixes_reach(routing, interpret):
     """Which slots the FFN computes: rows of ones in every slot, against the
     invariant, come back non-zero from every 512-slot tile that the prefix
     of its own (expert, row) reaches and from as many others as make up the
@@ -641,11 +663,62 @@ def test_expert_ffn_computes_the_tiles_its_prefixes_reach(routing):
     ((8, 1, 1280, 8192), 0),  # factor 1.25: C is not whole tiles
     ((8, 1, 1536, 8192), 0),  # factor 1.5: 23 of 24 tiles can be reached
     ((4, 1, 512, 8192), 0),  # one tile a buffer
+    # Pairs that are not whole tiles: 513 + 1 + 1 of them reach all four
+    # trips of the bound and leave the fourth expert without one.
+    ((4, 1, 1024, 515), 0),
 ])
 def test_ffn_trips_are_the_tiles_that_can_hold_a_pair(shape, trips):
     from ray_tpu.models.mixtral import _ffn_trips
 
     assert _ffn_trips(*shape) == trips
+
+
+def _routings(experts, rows, C, pairs, rng, n):
+    """``n`` counts [experts, rows] of a device's share of each row's pairs:
+    some of the experts, chosen anew each time, hold prefixes that end just
+    inside a tile; the worst for the tiles reached."""
+    for _ in range(n):
+        counts = np.zeros((experts, rows), int)
+        for row in range(rows):
+            some = rng.permutation(experts)[: rng.randint(0, experts + 1)]
+            left = rng.randint(0, pairs + 1)
+            for e in some:
+                counts[e, row] = took = min(
+                    left, C, rng.randint(0, C // 512 + 1) * 512 + 1
+                )
+                left -= took
+        yield counts
+
+
+@pytest.mark.parametrize("shape", [
+    (4, 1, 4096, 8192), (8, 2, 4096, 8192), (2, 2, 2048, 4096),
+    (8, 1, 1280, 8192), (8, 1, 1536, 8192), (4, 1, 512, 8192),
+])
+def test_worklist_keeps_an_experts_trips_together_and_leaves_no_expert_out(shape):
+    """What the weights' gradients need of the trips, on the shapes the
+    bound is tested on: every reached tile among them and none twice, in
+    (expert, row, slot) order so that an expert's trips are consecutive,
+    and at least one trip in every expert, the ones no pair reaches too.
+    Where the FFN is the plain einsum the trips asked for are all tiles."""
+    from ray_tpu.models.mixtral import _ffn_trips, _worklist
+
+    experts, rows, C, pairs = shape
+    per = -(-C // 512)
+    trips = _ffn_trips(*shape) or experts * rows * per
+    worklist = jax.jit(_worklist, static_argnums=(1, 2))
+    rng = np.random.RandomState(experts * rows + C)
+    for counts in _routings(experts, rows, C, pairs, rng, 40):
+        tiles = -(-counts // 512)
+        e, b, slot = (np.asarray(i) for i in worklist(jnp.asarray(tiles), per, trips))
+        flat = (e * rows + b) * per + slot // 512
+        assert len(flat) == trips and (np.diff(flat) > 0).all(), (counts, flat)
+        assert set(e) == set(range(experts)), (counts, e)
+        reached = {
+            (x * rows + y) * per + z
+            for x in range(experts) for y in range(rows)
+            for z in range(tiles[x, y])
+        }
+        assert reached <= set(flat), (counts, flat)
 
 
 def test_ffn_trips_cover_the_worst_routing():
@@ -722,7 +795,8 @@ def compiled_layers(tiny_moe):
     out = {}
     for name, axes in LAYER_MESHES.items():
         mesh = MeshSpec(**axes).build()
-        with jax.set_mesh(mesh):
+        with pytest.MonkeyPatch.context() as patch, jax.set_mesh(mesh):
+            patch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
             params = shard_params(host_params, mesh)
             xs = jax.device_put(
                 x, logical_sharding(mesh, ("batch", "seq", "embed"))
@@ -742,9 +816,15 @@ def compiled_layers(tiny_moe):
 
 
 def _ffn_loops(text):
+    """The expert FFN's loops over its tiles: those that carry a capacity
+    buffer [e, b, C, D]. (The grouped matmuls of the weights' gradients,
+    interpreted, are loops over their grids and carry none.)"""
+    import re
+
     return [
         line for line in text.split("\n")
         if " while(" in line and "/experts/" in line
+        and re.search(r"\[\d+,\d+,\d+,\d+\]", line)
     ]
 
 
@@ -800,7 +880,7 @@ def test_layer_gradients_lie_as_its_parameters(compiled_layers, mesh):
         )
 
 
-def test_moe_train_step_on_seq_and_expert_mesh_keeps_its_layout():
+def test_moe_train_step_on_seq_and_expert_mesh_keeps_its_layout(interpret):
     """Two donating train steps of the whole model at factor 4.0 on
     seq=2 x expert=2, where the tiled FFN runs: the compiled step returns
     parameters and optimizer state laid as it takes them (else the second
