@@ -30,6 +30,10 @@ from ..ops.attention import flash_attention
 from ..ops.ring_attention import ring_self_attention
 from ..parallel.mesh import logical_axis_shards, with_logical_constraint
 from ..util import tracing
+from .hyper_connections import (
+    HyperConnection, HyperConnections, collapse_streams, expand_streams,
+    write_streams,
+)
 
 
 @dataclass(frozen=True)
@@ -70,6 +74,11 @@ class LlamaConfig:
     # pass that needs it: a replay that keeps large residuals (a scan's
     # per-chunk states) then lives for one layer's backward pass only.
     remat_prevent_cse: bool = False
+    # The residual path as ``hyper_connections.py`` has it: that many streams
+    # a token, [n, B, T, C] between the layers, each sublayer reading a
+    # weighted sum of them and writing back through two more maps. None: the
+    # one stream of x + F(x).
+    hyper_connections: Optional[HyperConnections] = None
 
     @property
     def head_dim_(self) -> int:
@@ -243,6 +252,8 @@ class DecoderLayer(nn.Module):
         cfg = self.cfg
         mixer_name, mixer = self.mixer
         ffn_name, ffn = self.ffn
+        if cfg.hyper_connections is not None:
+            return _hyper_connected(self, x, positions)
         h = x + mixer(cfg, mesh=self.mesh, name=mixer_name)(
             RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(x), positions
         )
@@ -252,11 +263,101 @@ class DecoderLayer(nn.Module):
         return with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
+def _hyper_connected(layer: DecoderLayer, x, positions):
+    """The layer's two sublayers on the streams x [n, B, T, C], inside its
+    ``__call__``: each reads through its hyper-connection (``mixer_hc``,
+    ``ffn_hc``) and writes back through it."""
+    cfg = layer.cfg
+    mixer_name, mixer = layer.mixer
+    ffn_name, ffn = layer.ffn
+    connection = lambda name: HyperConnection(  # noqa: E731
+        cfg.hyper_connections, cfg.rms_eps, weight_init(cfg),
+        cfg.param_dtype, name=name,
+    )
+    u, maps = connection("mixer_hc")(x)
+    h = write_streams(x, mixer(cfg, mesh=layer.mesh, name=mixer_name)(
+        RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(u), positions
+    ), *maps)
+    u, maps = connection("ffn_hc")(h)
+    out = write_streams(h, ffn(cfg, name=ffn_name)(
+        RMSNorm(cfg.rms_eps, cfg.param_dtype, name="post_attn_norm")(u)
+    ), *maps)
+    return with_logical_constraint(out, (None, "batch", "seq", "embed"))
+
+
+def _embedding(cfg: LlamaConfig) -> nn.Embed:
+    return nn.Embed(
+        cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype, name="embed_tokens",
+        embedding_init=weight_init(cfg, nn.linear.default_embed_init),
+    )
+
+
+def _lookup(cfg: LlamaConfig, emb: nn.Embed, input_ids):
+    if logical_axis_shards("vocab") * logical_axis_shards("embed") > 1:
+        # One-hot matmul lookup where the ambient mesh splits the
+        # table (vocab=tensor, embed=fsdp): a gather forces SPMD into
+        # full rematerialization (replicate-then-repartition every
+        # step); a contraction over the vocab axis instead becomes
+        # partial products + psum over `tensor`, rides the MXU, and
+        # XLA fuses the one-hot so the [B,S,V] operand is never
+        # materialized. A whole table (one chip, or a mesh of data,
+        # seq and expert axes alone) is read by a gather.
+        one_hot = jax.nn.one_hot(input_ids, cfg.vocab_size, dtype=cfg.dtype)
+        x = jnp.einsum(
+            "bsv,ve->bse", one_hot, emb.embedding.astype(cfg.dtype)
+        )
+    else:
+        x = emb(input_ids)
+    return with_logical_constraint(x, ("batch", "seq", "embed"))
+
+
+def _through(model: "LlamaForCausalLM", layers, x, positions):
+    """x [B, T, C] through ``layers`` (flax name, mixer, ffn) of ``model``,
+    inside its ``__call__``: on hyper-connections, copied to the streams
+    before them and summed after."""
+    cfg = model.cfg
+    layer_cls = DecoderLayer
+    if cfg.remat:
+        layer_cls = nn.remat(
+            DecoderLayer, prevent_cse=cfg.remat_prevent_cse,
+            policy=remat_policy(cfg),
+        )
+    hc = cfg.hyper_connections
+    if hc is not None:
+        x = expand_streams(x, hc.mult)
+    for name, mixer, ffn in layers:
+        x = layer_cls(
+            cfg, (mixer, model.blocks[mixer]), (ffn, model.blocks[ffn]),
+            mesh=model.mesh, name=name,
+        )(x, positions)
+    return x if hc is None else collapse_streams(x)
+
+
+def _logits(cfg: LlamaConfig, emb: nn.Embed, x):
+    if cfg.tie_embeddings:
+        return emb.attend(x.astype(cfg.param_dtype))
+    return nn.Dense(
+        cfg.vocab_size, use_bias=False, dtype=jnp.float32,
+        param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+        name="lm_head",
+    )(x)
+
+
+def _positions(input_ids):
+    return jnp.broadcast_to(
+        jnp.arange(input_ids.shape[1])[None], input_ids.shape
+    )
+
+
 class LlamaForCausalLM(nn.Module):
     """The decoder body of every family: embedding, layers, final norm,
     head. Each layer's mixer and FFN are the configuration's to name
     (``cfg.layers``); a family binds the modules its names stand for in
-    ``blocks`` (MixtralForCausalLM: the sparse layer, as ``moe``)."""
+    ``blocks`` (MixtralForCausalLM: the sparse layer, as ``moe``). The
+    pieces of ``__call__`` are functions of this file, not methods (flax
+    would put a method's name into every operation's path), so that a family
+    whose ``__call__`` has more to it (xing4.py) is made of the same."""
 
     cfg: LlamaConfig
     mesh: Optional[Any] = None
@@ -272,53 +373,14 @@ class LlamaForCausalLM(nn.Module):
         activation and never needs to exist."""
         cfg = self.cfg
         if positions is None:
-            positions = jnp.broadcast_to(
-                jnp.arange(input_ids.shape[1])[None], input_ids.shape
-            )
-        emb = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name="embed_tokens",
-            embedding_init=weight_init(cfg, nn.linear.default_embed_init),
+            positions = _positions(input_ids)
+        emb = _embedding(cfg)
+        x = _through(
+            self, [(f"layers_{i}", *kinds) for i, kinds in enumerate(cfg.layers)],
+            _lookup(cfg, emb, input_ids), positions,
         )
-        if logical_axis_shards("vocab") * logical_axis_shards("embed") > 1:
-            # One-hot matmul lookup where the ambient mesh splits the
-            # table (vocab=tensor, embed=fsdp): a gather forces SPMD into
-            # full rematerialization (replicate-then-repartition every
-            # step); a contraction over the vocab axis instead becomes
-            # partial products + psum over `tensor`, rides the MXU, and
-            # XLA fuses the one-hot so the [B,S,V] operand is never
-            # materialized. A whole table (one chip, or a mesh of data,
-            # seq and expert axes alone) is read by a gather.
-            one_hot = jax.nn.one_hot(input_ids, cfg.vocab_size, dtype=cfg.dtype)
-            x = jnp.einsum(
-                "bsv,ve->bse", one_hot, emb.embedding.astype(cfg.dtype)
-            )
-        else:
-            x = emb(input_ids)
-        x = with_logical_constraint(x, ("batch", "seq", "embed"))
-        layer_cls = DecoderLayer
-        if cfg.remat:
-            layer_cls = nn.remat(
-                DecoderLayer, prevent_cse=cfg.remat_prevent_cse,
-                policy=remat_policy(cfg),
-            )
-        for i, (mixer, ffn) in enumerate(cfg.layers):
-            x = layer_cls(
-                cfg, (mixer, self.blocks[mixer]), (ffn, self.blocks[ffn]),
-                mesh=self.mesh, name=f"layers_{i}",
-            )(x, positions)
         x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="final_norm")(x)
-        if return_hidden:
-            return x
-        if cfg.tie_embeddings:
-            logits = emb.attend(x.astype(cfg.param_dtype))
-        else:
-            logits = nn.Dense(
-                cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-                param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
-                name="lm_head",
-            )(x)
-        return logits
+        return x if return_hidden else _logits(cfg, emb, x)
 
 
 def lm_head_weight(params) -> jax.Array:
@@ -348,9 +410,23 @@ def chunked_causal_lm_loss(
     end-to-end. Net-new vs the reference (its torch trainers
     materialize logits); the standard long-context recipe on TPU.
     """
-    b, t = targets.shape
     hidden = model.apply(params, input_ids, return_hidden=True)
-    head = lm_head_weight(params)  # [V, H]
+    return chunked_head_loss(
+        hidden, lm_head_weight(params), targets, mask, chunk_size
+    )
+
+
+def chunked_head_loss(
+    hidden: jax.Array,
+    head: jax.Array,
+    targets: jax.Array,
+    mask: Optional[jax.Array] = None,
+    chunk_size: int = 2048,
+) -> jax.Array:
+    """``chunked_causal_lm_loss`` from the final-norm hidden states [B, T, H]
+    on: the head [V, H] and the cross-entropy a chunk of the sequence at a
+    time, the mean over the positions ``mask`` keeps."""
+    b, t = targets.shape
     if mask is None:
         m_full = jnp.ones((b, t), jnp.float32)
     else:

@@ -93,6 +93,16 @@ class MixtralConfig(LlamaConfig):
     # part of the result for the pairs routed to them and nothing stands
     # in for the rest ("gmm" only). None: every expert.
     experts_held: Optional[Tuple[int, int]] = None
+    # How a held share's rows reach their slots and come back. "walk":
+    # over the slots of the tiles that hold rows, a window a trip
+    # (_held_ffn): it reads and writes the pairs that came, at 3.7 us a
+    # marginal row (scatter-adds; PERF.md §6, PR 37), so the step follows
+    # the routing. "gather": every pair is laid out and rows move by
+    # gathers over all the pairs, as in the whole layer: the same cost
+    # whatever the routing, each gather a copy's, and only the grouped
+    # matmuls follow the pairs that came. A held quarter gathers four times
+    # the rows it needs, a sixteenth sixteen times: for a large share.
+    held_rows: str = "walk"
 
     @property
     def layers(self):
@@ -509,6 +519,34 @@ def _slots_to_rows_bwd(residuals, g):
 _slots_to_rows.defvjp(_slots_to_rows_fwd, _slots_to_rows_bwd)
 
 
+def _used_rows(rows, tiles_used):
+    """``rows`` [m_pad, .] of a layout that ``ops.gmm.gmm`` filled up to
+    ``tiles_used`` row tiles, zeros in the rows past them, which are
+    uninitialised memory: a select, so that whatever they hold goes no
+    further."""
+    used = jnp.arange(rows.shape[0], dtype=jnp.int32) < tiles_used[0] * 128
+    return jnp.where(used[:, None], rows, jnp.zeros((), rows.dtype))
+
+
+@jax.custom_vjp
+def _used_rows_back(rows, tiles_used):
+    """``rows`` as they are; their gradient through ``_used_rows``: what
+    ``gmm`` hands back for its left operand is written up to ``tiles_used``
+    and no further."""
+    return rows
+
+
+def _used_rows_back_fwd(rows, tiles_used):
+    return rows, tiles_used
+
+
+def _used_rows_back_bwd(tiles_used, g):
+    return _used_rows(g, tiles_used), None
+
+
+_used_rows_back.defvjp(_used_rows_back_fwd, _used_rows_back_bwd)
+
+
 # Row tiles a trip of the held share's loops covers (2,048 rows). The
 # loops run over the tiles that hold rows, so their last trip may cover up
 # to a window less one tile that hold none.
@@ -768,7 +806,8 @@ class MoELayer(nn.Module):
     of the experts (cfg.experts_held) the layout is bounded at every pair
     and a sixteenth of it is filled: there every pass stops at the tiles
     that hold rows, and rows go back to tokens by walking those tiles'
-    slots and adding each into its token's row (_held_ffn).
+    slots and adding each into its token's row (_held_ffn), or, where
+    cfg.held_rows says "gather", by the gathers above over every pair.
 
     "ragged": (token, k) pairs argsorted by expert feed
     `lax.ragged_dot` with exact group sizes — zero capacity padding and
@@ -798,6 +837,10 @@ class MoELayer(nn.Module):
                 f"experts_held={held} needs moe_dispatch 'gmm', got "
                 f"{cfg.moe_dispatch!r}: the other branches lay out every "
                 "expert of the router"
+            )
+        if cfg.held_rows not in ("walk", "gather"):
+            raise ValueError(
+                f"held_rows must be 'walk' or 'gather', got {cfg.held_rows!r}"
             )
         if cfg.router_score not in ("softmax", "sigmoid"):
             raise ValueError(
@@ -900,12 +943,48 @@ class MoELayer(nn.Module):
                     tiles_used = jnp.sum(
                         tile_group < E_w, dtype=jnp.int32
                     ).reshape(1)
+                    tile_group = jnp.minimum(tile_group, E_w - 1)
+                    walks = cfg.held_rows == "walk"
+                    if not walks:
+                        slot_of_pair, pair_of_slot = _pair_slots(
+                            order, dst, m_pad, K
+                        )
+            if not walks:
+                # Every pair in the layout, the grouped matmuls over the
+                # tiles of the pairs that are here: the rows past them
+                # are never written, and `_used_rows` keeps them out of
+                # both gathers back to tokens.
+                from ..ops.gmm import gmm
+
+                tiles_used = jnp.maximum(tiles_used, 1)
+                with tracing.scope(tracing.MOE_DISPATCH):
+                    lhs = _used_rows_back(
+                        _rows_to_slots(x2, slot_of_pair, pair_of_slot),
+                        tiles_used,
+                    )
+                with tracing.scope(tracing.MOE_EXPERTS):
+                    def grouped(rows, w):
+                        return gmm(
+                            rows, w.astype(cfg.dtype), tile_group,
+                            tiles_used=tiles_used,
+                        )
+
+                    act = nn.silu(grouped(lhs, w_gate)) * grouped(lhs, w_up)
+                    eo = _used_rows(grouped(act, w_down), tiles_used)
+                with tracing.scope(tracing.MOE_COMBINE):
+                    gates = jnp.where(
+                        here, gate_vals.astype(cfg.dtype).reshape(N), 0
+                    ).reshape(B * T, K)
+                    out2 = _slots_to_rows(eo, gates, slot_of_pair, pair_of_slot)
+                return finish(out2.reshape(B, T, D))
+            with tracing.scope(tracing.MOE_DISPATCH):
+                with tracing.scope(tracing.MOE_LAYOUT):
                     # Whole windows of tiles, each past the used ones
                     # named for the last expert that is here.
                     tiles = -(-m_pad // (_WINDOW * 128)) * _WINDOW
                     tile_group = jnp.pad(
-                        jnp.minimum(tile_group, E_w - 1),
-                        (0, tiles - m_pad // 128), constant_values=E_w - 1,
+                        tile_group, (0, tiles - m_pad // 128),
+                        constant_values=E_w - 1,
                     )
                     pair_of_slot = _held_pair_of_slot(
                         order, dst, jnp.sum(here, dtype=jnp.int32), tiles * 128

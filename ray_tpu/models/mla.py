@@ -1,8 +1,9 @@
-"""Latent attention (DeepSeek-V2's MLA, without a q latent), the mixer of
-the models that have it: Kimi-Linear's every fourth layer
-(``kimi_linear.py``) and every layer of ``sarvam_mla.py``.
+"""Latent attention (DeepSeek-V2's MLA), the mixer of the models that have
+it: Kimi-Linear's every fourth layer (``kimi_linear.py``) and every layer of
+``sarvam_mla.py`` and of ``xing4.py``.
 
-q heads of nope + pe from one projection; keys and values from a shared
+q heads of nope + pe from one projection, or through a latent of their own
+(``q_lora_rank``); keys and values from a shared
 latent (down-projection, RMSNorm, up-projection to nope + v a head) and one
 pe-wide key part shared by all heads; softmax attention with q/k heads of
 nope + pe and v heads of ``v_head_dim`` through the flash kernels, K and V
@@ -18,6 +19,9 @@ runs its MLA layers without any of them, ``mla_use_nope``):
 - ``qk_head_norm``: an RMSNorm with a learned weight over each head's
   nope + pe channels of q and of k (the weight shared by the heads), before
   the rotation.
+- ``q_lora_rank``: q through a latent as K and V are: a down-projection
+  ``q_a_proj`` to that width, an RMSNorm, the up-projection ``q_b_proj`` to
+  the heads, in place of the one ``q_proj`` (DeepSeek-V2's q latent).
 """
 from __future__ import annotations
 
@@ -37,7 +41,8 @@ from .mixtral import MixtralConfig
 
 @dataclass(frozen=True)
 class YarnScaling:
-    """``rope_scaling`` of type ``deepseek_yarn``, by the source's keys."""
+    """``rope_scaling`` of type ``deepseek_yarn``, or of type ``yarn`` with
+    the same keys, by the source's keys."""
     factor: float
     original_max_position_embeddings: int
     beta_fast: float = 32.0
@@ -65,6 +70,21 @@ class MLAConfig(MixtralConfig):
     mla_rope: bool = False
     rope_scaling: Optional[YarnScaling] = None
     qk_head_norm: bool = False
+    q_lora_rank: Optional[int] = None
+
+
+# ``rope_scaling`` types whose keys and arithmetic ``YarnScaling`` has.
+YARN_TYPES = ("deepseek_yarn", "yarn")
+
+
+def yarn_scaling(rope_scaling: Optional[dict]) -> Optional[YarnScaling]:
+    """The source's nested ``rope_scaling`` as ``YarnScaling``; None for none."""
+    if rope_scaling is None:
+        return None
+    kind = rope_scaling["type"]
+    if kind not in YARN_TYPES:
+        raise ValueError(f"rope_scaling of type {kind!r} is not supported")
+    return YarnScaling(**{k: v for k, v in rope_scaling.items() if k != "type"})
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -105,7 +125,17 @@ class MLAMixer(nn.Module):
             param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
             name=name,
         )
-        q = heads(nope + pe, "q_proj")(x)  # [B, T, H, 192]: nope | pe
+        if cfg.q_lora_rank is None:
+            q = heads(nope + pe, "q_proj")(x)  # [B, T, H, 192]: nope | pe
+        else:
+            with tracing.scope(tracing.MLA_Q_LATENT):
+                c_q = nn.Dense(
+                    cfg.q_lora_rank, use_bias=False, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
+                    name="q_a_proj",
+                )(x)
+                c_q = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="q_a_norm")(c_q)
+                q = heads(nope + pe, "q_b_proj")(c_q)
         with tracing.scope(tracing.MLA_LATENT):
             latent = nn.Dense(
                 rank + pe, use_bias=False, dtype=cfg.dtype,

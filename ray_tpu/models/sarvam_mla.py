@@ -19,7 +19,7 @@ from typing import Optional
 
 from ..util import tracing
 from .mixtral import MixtralForCausalLM
-from .mla import MLAConfig, MLAMixer, YarnScaling
+from .mla import MLAConfig, MLAMixer, yarn_scaling
 
 
 @dataclass(frozen=True)
@@ -49,19 +49,12 @@ def sarvam_mla_config(
     rope_scaling: Optional[dict] = None, use_qk_norm: bool = True, **fields,
 ) -> SarvamMLAConfig:
     """The program's config from the source's keys (its nested
-    ``rope_scaling``, ``use_qk_norm``) and the deployment's: how many of the
-    router's experts a rank holds, and which rank this is."""
-    scaling = None
-    if rope_scaling is not None:
-        kind = rope_scaling["type"]
-        if kind != "deepseek_yarn":
-            raise ValueError(f"rope_scaling of type {kind!r} is not supported")
-        scaling = YarnScaling(
-            **{k: v for k, v in rope_scaling.items() if k != "type"}
-        )
+    ``rope_scaling``, of a type ``mla.YARN_TYPES`` lists, and ``use_qk_norm``)
+    and the deployment's: how many of the router's experts a rank holds, and
+    which rank this is."""
     first = expert_rank * num_experts_held
     return SarvamMLAConfig(
-        rope_scaling=scaling, qk_head_norm=use_qk_norm,
+        rope_scaling=yarn_scaling(rope_scaling), qk_head_norm=use_qk_norm,
         experts_held=(first, first + num_experts_held), **fields,
     )
 

@@ -60,9 +60,16 @@ KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's
 # kernels, and a scope that no operation carries is not in this list.)
 MLA_LATENT = "latent"  # inside mla: down-projection, norm, up-projection of K/V
 MLA_ROPE = "rope"  # inside mla (cfg.mla_rope): the frequency table, the rotation of q's and k's pe parts, the slices and concatenations around it
+MLA_Q_LATENT = "q_latent"  # inside mla (cfg.q_lora_rank): q's down-projection, its norm, the up-projection to the heads
+HC = "hc"  # a hyper-connection (models/hyper_connections.py), twice a layer: the three maps of the streams, the read before the sublayer, the write after it
+HC_PRE = "pre"  # inside hc: the streams' rms, x~ Phi, the three logits, H_pre and H_post, the read u = sum H_pre[i] X[i]
+HC_SINKHORN = "sinkhorn"  # inside hc: exp, the iterations of rows and columns, and their backward
+HC_POST = "post"  # inside hc: the write X'[i] = sum H_res[i, j] X[j] + H_post[i] y
+MTP = "mtp"  # the multi-token-prediction module (flax name, models/xing4.py) and, in the loss, its pass of the shared head
 SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
-          MLA_LATENT, MLA_ROPE)
+          MLA_LATENT, MLA_ROPE, MLA_Q_LATENT, HC, HC_PRE, HC_SINKHORN,
+          HC_POST, MTP)
 # Flax module names, bound in the model classes' ``blocks``.
 MIXERS = (KDA, MLA)
 
