@@ -40,8 +40,8 @@ from __future__ import annotations
 import threading
 import time
 import weakref
-from collections import OrderedDict
-from typing import Dict, List, Optional, Set, Tuple
+from collections import OrderedDict, deque
+from typing import Callable, Deque, Dict, List, Optional, Set, Tuple
 
 from .. import chaos as _chaos
 from .. import events as _events
@@ -56,6 +56,53 @@ RETRANSMIT_MAX = 20
 #: was delayed/reordered past the borrower_died sweep cannot re-add a
 #: borrow edge nothing will ever retract.
 DEAD_BORROWER_CAP = 256
+
+
+class DecrQueueLock:
+    """The lock of a tracker's bookkeeping, which ``decr`` never waits
+    for. ``decr`` is what ``ObjectRef.__del__`` calls, and the collector
+    can start a pass at any allocation: also inside one of the
+    tracker's locked regions, on the thread that holds the lock (seen on
+    the ref-flusher inside ``flush()`` during a head failover: a plain
+    lock taken there waits for itself, and every later ``incr``, so
+    every submit, behind it; a reentrant one lets the decrement change
+    the tables the region is reading). So ``decr`` only queues its oid
+    (``defer``). The queue is applied under the lock on the way into
+    every region, which therefore sees every decrement made before it,
+    and once more after the way out, for those that came while the
+    region ran."""
+
+    def __init__(self, apply: Callable[[bytes], None]):
+        self._lock = threading.Lock()
+        self._apply = apply
+        self._deferred: Deque[bytes] = deque()
+
+    def __enter__(self):
+        self._lock.acquire()
+        self._drain()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        self.settle()
+
+    def defer(self, oid: bytes) -> None:
+        self._deferred.append(oid)
+        self.settle()
+
+    def settle(self) -> None:
+        """Apply what is queued unless someone holds the lock: the
+        holder does it on its way out (its check follows its release,
+        so it sees whatever was queued while it held)."""
+        while self._deferred and self._lock.acquire(blocking=False):
+            try:
+                self._drain()
+            finally:
+                self._lock.release()
+
+    def _drain(self) -> None:
+        deferred = self._deferred
+        while deferred:
+            self._apply(deferred.popleft())
 
 
 class OwnerRefTracker:
@@ -75,7 +122,7 @@ class OwnerRefTracker:
         # First truthy owner wins: classification is stable per process.
         self._owner_of: Dict[bytes, bytes] = {}
         self._dirty: Set[bytes] = set()
-        self._lock = threading.Lock()
+        self._lock = DecrQueueLock(self._decr_locked)
         self._flusher: Optional[threading.Thread] = None
         self._wake = threading.Event()
         self._stopped = False
@@ -134,18 +181,20 @@ class OwnerRefTracker:
                 self._zeroed.discard(oid)
                 self._ensure_flusher()
 
-    # raylint: applier-only
     def decr(self, oid: bytes) -> None:
-        with self._lock:
-            n = self._counts.get(oid, 0) - 1
-            if n <= 0:
-                self._counts.pop(oid, None)
-                if not self._dirty:
-                    self._wake.set()
-                self._dirty.add(oid)
-                self._zeroed.add(oid)
-            else:
-                self._counts[oid] = n
+        self._lock.defer(oid)
+
+    # raylint: applier-only
+    def _decr_locked(self, oid: bytes) -> None:
+        n = self._counts.get(oid, 0) - 1
+        if n <= 0:
+            self._counts.pop(oid, None)
+            if not self._dirty:
+                self._wake.set()
+            self._dirty.add(oid)
+            self._zeroed.add(oid)
+        else:
+            self._counts[oid] = n
 
     def holds(self, oid: bytes) -> bool:
         with self._lock:

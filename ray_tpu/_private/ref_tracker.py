@@ -31,6 +31,7 @@ from typing import Dict, Optional, Set
 
 from .object_plane.owner_refs import (  # noqa: F401 - re-exports
     FLUSH_INTERVAL_S,
+    DecrQueueLock,
     OwnerRefTracker,
 )
 
@@ -66,7 +67,7 @@ class LegacyRefTracker:
         self._client = weakref.ref(client)
         self._counts: Dict[bytes, int] = {}
         self._dirty: Set[bytes] = set()
-        self._lock = threading.Lock()
+        self._lock = DecrQueueLock(self._decr_locked)
         self._flusher: Optional[threading.Thread] = None
         self._wake = threading.Event()
         self._stopped = False
@@ -93,16 +94,18 @@ class LegacyRefTracker:
                 self._ensure_flusher()
 
     def decr(self, oid: bytes) -> None:
-        with self._lock:
-            n = self._counts.get(oid, 0) - 1
-            if n <= 0:
-                self._counts.pop(oid, None)
-                if not self._dirty:
-                    self._wake.set()
-                self._dirty.add(oid)
-                self._zeroed.add(oid)
-            else:
-                self._counts[oid] = n
+        self._lock.defer(oid)
+
+    def _decr_locked(self, oid: bytes) -> None:
+        n = self._counts.get(oid, 0) - 1
+        if n <= 0:
+            self._counts.pop(oid, None)
+            if not self._dirty:
+                self._wake.set()
+            self._dirty.add(oid)
+            self._zeroed.add(oid)
+        else:
+            self._counts[oid] = n
 
     def holds(self, oid: bytes) -> bool:
         with self._lock:

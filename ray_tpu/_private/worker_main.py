@@ -36,7 +36,7 @@ def _finite(value, default: float, cap: float, floor: float = 0.0) -> float:
     return min(max(v, floor), cap)
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 import cloudpickle
 
@@ -340,6 +340,8 @@ class WorkerRuntime:
         # each actor alone stays serial.
         self.actors: Dict[bytes, Any] = {}
         self._actor_locks: Dict[bytes, threading.RLock] = {}
+        # The instances the last reconnect hello claimed (claim_actors).
+        self._claimed: Dict[bytes, Any] = {}
         # Set when a creation arrives marked packed: shared hosts stay
         # alive when their last actor exits (the GCS re-pools them).
         self._shared_host = False
@@ -372,6 +374,27 @@ class WorkerRuntime:
         # workers run exactly one task at a time no matter which path
         # delivered it.
         self._exec_lock = threading.RLock()
+
+    def claim_actors(self) -> List[bytes]:
+        """The actors a reconnect hello claims for this process."""
+        self._claimed = dict(self.actors)
+        return list(self._claimed)
+
+    def drop_refused_actors(self, refused) -> None:
+        """The restarted head refused to re-bind these claims (unknown,
+        dead, or already queued for creation again). Only the instance
+        that was claimed goes: a head restored from a table older than
+        the actor has its creation queued, can send it to this very
+        worker, idle again, before the hello's reply is acted on, and
+        the new instance lives under the same id: dropped by id, it
+        would leave the head holding an actor ALIVE here that answers
+        "actor is gone" for ever."""
+        for aid in refused:
+            inst = self._claimed.get(aid)
+            if inst is not None and self.actors.get(aid) is inst:
+                del self.actors[aid]
+                self._actor_locks.pop(aid, None)
+        self._claimed = {}
 
     def _actor_for(self, aid: Optional[bytes]):
         inst = self.actors.get(aid) if aid is not None else None
@@ -1550,7 +1573,7 @@ def main():
             else:
                 rt._sealed_locs.pop(oid, None)  # evicted/freed: stale
         return {
-            "actors": list(rt.actors.keys()),
+            "actors": rt.claim_actors(),
             "shared_host": rt._shared_host,
             "executing": [
                 (tid, list(oids))
@@ -1560,9 +1583,7 @@ def main():
         }
 
     def _on_reconnected(reply):
-        for aid in reply.get("drop_actors") or ():
-            rt.actors.pop(aid, None)
-            rt._actor_locks.pop(aid, None)
+        rt.drop_refused_actors(reply.get("drop_actors") or ())
         rt._done_batcher.on_reconnect()
 
     client.reconcile_info = _reconcile_info

@@ -73,8 +73,13 @@ class LongPollClient:
 
     def _loop(self):
         from ... import get
+        from ..._private.worker import _global
 
-        while not self._stopped.is_set():
+        # Ends with the session it was started in: in a later one the
+        # controller's handle names an actor that session never had,
+        # and every retry would be a task submitted to its head.
+        session = _global.client
+        while not self._stopped.is_set() and _global.client is session:
             try:
                 changes = get(
                     self._controller.listen_for_change.remote(self._snapshot_ids),

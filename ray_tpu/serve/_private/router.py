@@ -110,6 +110,7 @@ class Router:
         self._num_queued = 0
         self._queued_lock = threading.Lock()
         self._handle_id = uuid.uuid4().hex[:8]
+        self._stopped = threading.Event()
         self._loop = asyncio.new_event_loop()
         threading.Thread(target=self._run_loop, daemon=True).start()
         self._long_poll = LongPollClient(
@@ -128,6 +129,7 @@ class Router:
         self._loop.run_forever()
 
     def shutdown(self):
+        self._stopped.set()
         self._long_poll.stop()
         self._loop.call_soon_threadsafe(self._loop.stop)
 
@@ -253,21 +255,23 @@ class Router:
                 rs.inflight[rid] -= 1
 
     def _push_metrics_loop(self):
-        from ..._private.worker import is_initialized
+        from ..._private.worker import _global
 
-        while True:
-            # This daemon thread can outlive serve.shutdown() (handles
-            # are plain objects, nothing joins it): pushing through a
-            # dead session would auto-init a fresh one — exit instead.
-            if not is_initialized():
-                return
+        # This daemon thread ends with its router, and with the session
+        # the router was made in (handles are plain objects, nothing
+        # joins it): pushing through a dead session would auto-init a
+        # fresh one, and through a later one it submits, twice a
+        # second, to a controller that session never had.
+        session = _global.client
+        while session is not None and _global.client is session:
             try:
                 self._controller.record_handle_metrics.remote(
                     str(self._dep_id), self._handle_id, self._num_queued, time.time()
                 )
             except Exception:  # noqa: BLE001
                 pass
-            time.sleep(METRICS_PUSH_INTERVAL_S)
+            if self._stopped.wait(METRICS_PUSH_INTERVAL_S):
+                return
 
 
 async def _resolve_composed_args(args, kwargs):

@@ -326,6 +326,9 @@ def shutdown():
     try:
         controller = get_actor(CONTROLLER_NAME)
     except ValueError:
+        # Shut down from elsewhere (the dashboard's REST DELETE): this
+        # process's routers still poll and push to the controller.
+        shutdown_routers()
         return
     try:
         get(controller.graceful_shutdown.remote(), timeout=30)

@@ -191,6 +191,30 @@ def test_actors_survive_live_head_failover(tmp_path):
         head.stop()
 
 
+def test_a_refused_claim_drops_the_instance_that_was_claimed_and_no_other():
+    """A head restored from a table older than an actor queues its
+    creation again, refuses the surviving worker's claim, and may hand
+    that creation to the same worker before the worker acts on the
+    refusal: the instance made since then must stay (dropped by id, the
+    head held the actor ALIVE on a worker that answered "actor is gone"
+    for ever: test_actors_survive_live_head_failover, 3 of 20 under
+    load)."""
+    import types
+
+    from ray_tpu._private.worker_main import WorkerRuntime
+
+    old, kept, new = object(), object(), object()
+    rt = types.SimpleNamespace(
+        actors={b"again": old, b"kept": kept, b"gone": old},
+        _actor_locks={b"again": 1, b"kept": 2, b"gone": 3},
+    )
+    assert sorted(WorkerRuntime.claim_actors(rt)) == [b"again", b"gone", b"kept"]
+    rt.actors[b"again"], rt._actor_locks[b"again"] = new, 4  # the creation ran
+    WorkerRuntime.drop_refused_actors(rt, [b"again", b"gone", b"never"])
+    assert rt.actors == {b"again": new, b"kept": kept}
+    assert rt._actor_locks == {b"again": 4, b"kept": 2}
+
+
 # ---------------------------------------- membership fencing (ISSUE 18)
 
 

@@ -100,42 +100,52 @@ def test_local_dispatch_beats_remote_head_leasing(delayed_head_cluster):
     swamps the hop-count difference either way; the load-bearing claim
     (the head never sees intra-node dispatch) is the message-count test
     above. This test reports both rates and bounds the local path to
-    the same order of magnitude."""
-    delayed_head_cluster.add_node(num_cpus=4)
+    the same order of magnitude.
+
+    The head-leased burst asks for a custom resource: a shape with
+    anything but one CPU or one chip is outside the raylet's single-unit
+    slots, so the client leases it from the head by design
+    (``_acquire_lease``). Both bursts may land on one worker, and that
+    worker keeps its raylet's address throughout: every lease goes back
+    to whoever granted it."""
+    delayed_head_cluster.add_node(num_cpus=4, resources={"head_leased": 4.0})
 
     @ray_tpu.remote
     def burst(n, local):
-        import os
         import time as _t
 
         import ray_tpu as rt
         from ray_tpu._private.worker import global_client as gc
 
-        if not local:
-            os.environ.pop("RAY_TPU_LOCAL_RAYLET", None)
-        rt.get(leaf.remote(0))  # ship the blob once
+        task = leaf if local else leaf.options(resources={"head_leased": 1})
+        client = gc()
+        rt.get(task.remote(0))  # ship the blob once
         best = 0.0
         for _ in range(3):
-            # Cold burst: drop warm leases so each round pays dispatch.
-            client = gc()
-            with client._lease_lock:
-                leases = [l for pool in client._leases.values() for l in pool]
-                client._leases.clear()
-            for lease in leases:
-                lease["returned"] = True
-                lease["conn"].close()
-                client._send_lease_return(
-                    lease["worker_id"], lease.get("raylet", False)
-                )
+            # Cold burst: each round pays dispatch. The client's own
+            # reaper hands an idle lease back after 0.5 s; wait for it
+            # rather than tear the leases out of the client (a lease
+            # torn out while its grantor is out of reach is never
+            # returned, and its CPU is gone until the worker exits).
+            deadline = _t.monotonic() + 20
+            while any(client._leases.values()):
+                assert _t.monotonic() < deadline, "idle leases never returned"
+                _t.sleep(0.05)
             t0 = _t.perf_counter()
-            rt.get([leaf.remote(i) for i in range(n)])
+            rt.get([task.remote(i) for i in range(n)])
             best = max(best, n / (_t.perf_counter() - t0))
         return best
 
-    local = ray_tpu.get(burst.remote(100, True), timeout=240)
-    via_head = ray_tpu.get(burst.remote(100, False), timeout=240)
-    print(f"cold dispatch with 3ms head RTT: head-leased {via_head:,.0f}/s, "
+    # 100 trivial tasks, three rounds: seconds alone, tens under load.
+    local = ray_tpu.get(burst.remote(100, True), timeout=60)
+    before = _head_counts().get("lease_worker", 0)
+    via_head = ray_tpu.get(burst.remote(100, False), timeout=60)
+    head_leases = _head_counts().get("lease_worker", 0) - before
+    print(f"cold dispatch with 3ms head RTT: head-leased {via_head:,.0f}/s "
+          f"({head_leases} lease requests at the head), "
           f"raylet-leased {local:,.0f}/s")
+    # Each cold round of the second burst opens with a request to the head.
+    assert head_leases >= 3, head_leases
     # Same order of magnitude (per the docstring): on a 1-core shared
     # box the absolute ratio swings several x between runs (flaked at
     # 0.2 in a full-suite run) — the load-bearing no-head-hop property

@@ -272,14 +272,20 @@ def test_truncated_spill_file_never_returns_garbage(tmp_path):
         # the corruption and fail LOST, never return truncated bytes.
         with pytest.raises(ObjectLostError):
             ray_tpu.get(victim, timeout=30)
-        # The head validates the report (and unlinks the bad file) on a
-        # background thread — poll briefly for the drop to land.
+        # The head validates the report on a background thread, which
+        # unlinks the bad file and THEN takes the lock to clear the
+        # entry — poll briefly for both halves of the drop to land.
+        def entry_cleared():
+            entry = gcs.objects.get(victim.id().binary())
+            return entry is None or entry.spilled_path is None
+
         deadline = time.monotonic() + 10
-        while time.monotonic() < deadline and os.path.exists(path):
+        while time.monotonic() < deadline and (
+            os.path.exists(path) or not entry_cleared()
+        ):
             time.sleep(0.05)
         assert not os.path.exists(path), "corrupt spill file not dropped"
-        entry = gcs.objects.get(victim.id().binary())
-        assert entry is None or entry.spilled_path is None
+        assert entry_cleared()
         # Untouched spilled objects still restore bit-exact.
         for r in spilled[1:]:
             i = refs.index(r)

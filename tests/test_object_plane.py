@@ -17,6 +17,7 @@ from ray_tpu._private.ids import WorkerID
 from ray_tpu._private.object_plane import directory as objdir
 from ray_tpu._private.object_plane.directory import ShardedObjectDirectory
 from ray_tpu._private.object_plane.owner_refs import OwnerRefTracker
+from ray_tpu._private.ref_tracker import LegacyRefTracker
 from ray_tpu._private.worker import _global, global_client
 
 
@@ -66,6 +67,131 @@ def test_flap_within_flush_window_sends_nothing():
     for msg in c.conn.sent:
         assert not msg.get("release") and not msg.get("bdel"), msg
         assert not msg.get("remove"), msg
+
+
+def _garbage_that_drops(t, oid):
+    """A cycle whose finalizer does what ``ObjectRef.__del__`` does,
+    without the global tracker: the next collector pass runs ``decr``
+    on whatever thread it starts on, wherever that thread stands."""
+
+    class InACycle:
+        def __del__(self):
+            t.decr(oid)
+
+    gc.collect()
+    garbage = InACycle()
+    garbage.itself = garbage
+
+
+class _APassAtEveryRead(dict):
+    """The collector may start a pass at any allocation; this table
+    starts one at every read of it."""
+
+    def get(self, *args):
+        gc.collect()
+        return super().get(*args)
+
+
+def _on_a_thread_of_its_own(fn):
+    """``fn()``'s result; a failure, not a hang, where ``fn`` waits for
+    a lock that its own thread holds."""
+    result = []
+    thread = threading.Thread(target=lambda: result.append(fn()), daemon=True)
+    thread.start()
+    thread.join(5)
+    assert not thread.is_alive(), "decr waits for the lock its thread holds"
+    assert result, "raised: see the thread's exception in the warnings"
+    return result[0]
+
+
+@pytest.mark.parametrize("tracker", [OwnerRefTracker, LegacyRefTracker])
+def test_a_ref_the_collector_frees_inside_the_lock_does_not_deadlock(tracker):
+    """The collector may start a pass at any allocation, also inside one
+    of the tracker's own locked regions, and a cycle it frees there runs
+    ``ObjectRef.__del__`` -> ``decr`` on the thread that holds the lock
+    (the ref-flusher inside ``flush``, in a head failover under load:
+    the driver's next submit then waited for ever in ``incr``)."""
+    c = _FakeClient()
+    t = tracker(c)
+    t.incr(b"incycle0", c.worker_id.binary())
+
+
+    def a_pass_inside_the_lock():
+        _garbage_that_drops(t, b"incycle0")
+        with t._lock:
+            gc.collect()
+            return dict(t._counts)
+
+    # Queued, not applied under the region's feet; applied on the way
+    # out of it, with no further call.
+    assert _on_a_thread_of_its_own(a_pass_inside_the_lock) == {b"incycle0": 1}
+    assert t._counts == {}
+    assert not t.holds(b"incycle0")
+
+
+def test_a_ref_freed_during_on_reconnect_leaves_its_tables_alone():
+    """``on_reconnect`` (the head-failover path) walks ``_counts``; a
+    cycle freed inside the walk must not take an entry out of it."""
+    c = _FakeClient()
+    t = OwnerRefTracker(c)
+    t.stop()  # no flusher thread: the flush below is the only one
+    me = c.worker_id.binary()
+    for oid in (b"kept0000", b"dropped0", b"kept0001"):
+        t.incr(oid, me)
+        t.mark_advertised(oid)
+    t._owner_of = _APassAtEveryRead(t._owner_of)
+    _garbage_that_drops(t, b"dropped0")
+    owned = _on_a_thread_of_its_own(t.on_reconnect)
+    # The walk saw the ref alive, as it was when the walk began; the
+    # drop landed after it and goes out with the next flush.
+    assert sorted(owned) == [b"dropped0", b"kept0000", b"kept0001"]
+    assert not t.holds(b"dropped0") and t.holds(b"kept0000")
+    t.flush(c)
+    assert [m.get("release") for m in c.conn.sent] == [[b"dropped0"]]
+
+
+def test_a_ref_freed_inside_a_legacy_flush_is_not_added_and_removed():
+    """``LegacyRefTracker.flush`` reads ``_counts`` once for the adds
+    and once for the removes: a drop between the two must not put one
+    oid into both lists of one message."""
+    c = _FakeClient()
+    t = LegacyRefTracker(c)
+    t.stop()  # no flusher thread: the two flushes below are the only ones
+    t.mark_advertised(b"flapping")
+    t.incr(b"flapping")
+    t._counts = _APassAtEveryRead(t._counts)
+    _garbage_that_drops(t, b"flapping")
+    _on_a_thread_of_its_own(lambda: (t.flush(c), t.flush(c)))
+    sent = [(m["add"], m["remove"]) for m in c.conn.sent]
+    assert sent == [([b"flapping"], []), ([], [b"flapping"])]
+
+
+@pytest.mark.parametrize("tracker", [OwnerRefTracker, LegacyRefTracker])
+def test_a_decr_beside_a_held_lock_neither_waits_nor_is_lost(tracker):
+    """``decr`` never waits for the lock (it may run in a finalizer on
+    the holder's own thread); what it queues while another thread holds
+    the lock, that thread applies on its way out."""
+    c = _FakeClient()
+    t = tracker(c)
+    t.incr(b"beside00", c.worker_id.binary())
+    held, leave = threading.Event(), threading.Event()
+
+    def holder():
+        with t._lock:
+            held.set()
+            leave.wait(5)
+
+    beside = threading.Thread(target=holder, daemon=True)
+    beside.start()
+    assert held.wait(5)
+    t0 = time.monotonic()
+    t.decr(b"beside00")
+    assert time.monotonic() - t0 < 1
+    assert t._counts == {b"beside00": 1}
+    leave.set()
+    beside.join(5)
+    t.stop()
+    assert t._counts == {} and b"beside00" in t._zeroed
 
 
 def test_drop_within_window_unadvertised_sends_nothing():

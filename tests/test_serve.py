@@ -287,3 +287,35 @@ def test_replica_recovery_after_kill(serve_session):
             time.sleep(0.2)
     assert ok, "replica was not replaced after SIGKILL"
     serve.delete("sturdy")
+
+
+@pytest.mark.parametrize("shut_down", ["serve_after_its_controller", "the_session_alone"])
+def test_a_handles_router_ends_with_its_session(shut_down):
+    """A handle's router polls and pushes to its controller from daemon
+    threads. They end with ``serve.shutdown()``, also where the
+    controller is already gone (the dashboard's REST DELETE shuts Serve
+    down from another process), and with the session: in the next one
+    they would submit to a controller its head never had, four tasks a
+    second, for as long as the process lives."""
+    from ray_tpu._private.worker import _global
+    from ray_tpu.serve._private.common import CONTROLLER_NAME
+
+    ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
+    serve.start(proxy=False)
+
+    @serve.deployment
+    def double(x):
+        return x * 2
+
+    handle = serve.run(double.bind(), name="fn_app", route_prefix=None)
+    assert handle.remote(21).result(timeout_s=10) == 42
+    if shut_down == "serve_after_its_controller":
+        ray_tpu.kill(ray_tpu.get_actor(CONTROLLER_NAME))
+        serve.shutdown()
+    ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=1)
+    try:
+        time.sleep(2.0)
+        assert _global.node.gcs._table_versions["pending"] == 0
+    finally:
+        ray_tpu.shutdown()

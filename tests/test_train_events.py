@@ -271,10 +271,20 @@ def test_a_ring_overflow_is_counted_in_the_file(fit, tmp_path):
 # ------------------------------------------------ in one process, no cluster
 
 
+class TrainRing(events.FlightRecorder):
+    """Keeps the train category alone. The head that ``fit`` started lives
+    until the module ends, and its threads record into whatever recorder is
+    in place: an ``SHM_SWEEP`` once the last trainer's worker is reclaimed."""
+
+    def record(self, category, entity, event, attrs=None):
+        if category == events.TRAIN:
+            super().record(category, entity, event, attrs)
+
+
 @pytest.fixture
 def ring(monkeypatch):
     """A recorder of its own in the place of the process's."""
-    recorder = events.FlightRecorder(capacity=4096, enabled=True)
+    recorder = TrainRing(capacity=4096, enabled=True)
     monkeypatch.setattr(events, "_recorder", recorder)
     yield recorder
     tracing.record_spans_into(None)
