@@ -274,11 +274,11 @@ def _hyper_connected(layer: DecoderLayer, x, positions):
         cfg.hyper_connections, cfg.rms_eps, weight_init(cfg),
         cfg.param_dtype, name=name,
     )
-    u, maps = connection("mixer_hc")(x)
+    u, x, maps = connection("mixer_hc")(x, streams=True)
     h = write_streams(x, mixer(cfg, mesh=layer.mesh, name=mixer_name)(
         RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(u), positions
     ), *maps)
-    u, maps = connection("ffn_hc")(h)
+    u, h, maps = connection("ffn_hc")(h, streams=True)
     out = write_streams(h, ffn(cfg, name=ffn_name)(
         RMSNorm(cfg.rms_eps, cfg.param_dtype, name="post_attn_norm")(u)
     ), *maps)

@@ -351,3 +351,97 @@ def test_kimi_linears_step_leaves_no_norm_over_a_heads_channels_to_xla(kimi_line
     assert reduced and all("1" in dims.split(", ") for dims in reduced), reduced
     counts = checks.count_pallas_kernels(text, ("_kda_fwd_kernel", "_kda_bwd_kernel"))
     assert counts == {"_kda_fwd_kernel": 8, "_kda_bwd_kernel": 4}
+
+
+# One of Xing4's hyper-connections at the benchmark's real size: four streams
+# of b1 x s4096 tokens at the published 3584 channels.
+HC_STREAMS = ((4, 1, 4096, 3584), jnp.bfloat16)
+HC_ONE = ((1, 4096, 3584), jnp.bfloat16)
+HC_MAPS = {k: ((k, 1, 4096), jnp.float32) for k in (4, 24)}
+
+
+def hc_entries():
+    from ray_tpu.models import hyper_connections as hcs
+
+    phi = ((4 * 3584, 24), jnp.bfloat16)
+    return {
+        "_hc_pre_fwd_kernel": (
+            lambda x, phi, alpha, b: hcs._pre_fwd(x, phi, alpha, b, 1e-6),
+            HC_STREAMS, phi, ((), jnp.float32), ((4,), jnp.float32)),
+        "_hc_post_fwd_kernel": (
+            hcs._post_fwd, HC_STREAMS, HC_ONE, HC_MAPS[4], ((4, 4, 1, 4096), jnp.float32)),
+        "_hc_post_bwd_kernel": (
+            hcs._post_bwd, HC_STREAMS, HC_STREAMS, HC_ONE, HC_MAPS[4],
+            ((4, 4, 1, 4096), jnp.float32)),
+        "_hc_pre_sums_kernel": (hcs._pre_sums, HC_ONE, HC_STREAMS),
+        "_hc_pre_bwd_kernel": (
+            hcs._pre_bwd, HC_STREAMS, HC_STREAMS, HC_ONE, HC_MAPS[4],
+            ((1, 4096), jnp.float32), ((48, 1, 4096), jnp.bfloat16),
+            ((4, 3584, 24), jnp.bfloat16)),
+    }
+
+
+@pytest.mark.parametrize("kernel", [
+    "_hc_pre_fwd_kernel", "_hc_post_fwd_kernel", "_hc_post_bwd_kernel", "_hc_pre_sums_kernel", "_hc_pre_bwd_kernel"])
+def test_a_hyper_connections_kernel_compiles_for_v5e(v5e, kernel):
+    fn, *args = hc_entries()[kernel]
+    text = _compile_for(v5e, fn, *args)
+    # the streams' cotangent is written over the one that came down to it
+    if kernel == "_hc_pre_bwd_kernel":
+        assert "output_to_operand_aliasing={{}: (0, {})}" in text
+
+
+def test_xing4s_step_calls_each_hyper_connection_kernel_once_a_connection(v5e):
+    """Two layers and the module's, at the rehearsal's widths (128 channels)
+    over 256 tokens, which tile: six hyper-connections, each a read and a
+    write forward, the read's replay, the write's where the same layer reads
+    again, and the three backward kernels. A backward kernel's body stands
+    once in the text, behind its jitted entry; a forward one's twice, the
+    forward pass's and the replay's (remat's partial evaluation copies a
+    jitted function's jaxpr, once for all its replays). Every call
+    is under /hc/pre/ or /hc/post/, where the benchmark's model.hc_share and
+    model.hc_roofline look for it."""
+    import importlib
+    import re
+
+    import numpy as np
+
+    from benchmarks.lib import cells, checks
+    from benchmarks.loops.train_lm import make_loss_fn, make_optimizer
+    from ray_tpu import train
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    cell = cells.load_cell("xing4-29b-a4b-l5.pretrain-mtp-4k")
+    config, traffic = cell["config"], {**cell["traffic"], "seq": 256}
+    config = {**config, **config["rehearsal"], "num_hidden_layers": 2}
+    model = cells.resolve(config["program"]["model"])(cells.program_config(config))
+    shapes = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e), tree)
+
+    tx = make_optimizer(traffic)
+    batch = jax.ShapeDtypeStruct((1, traffic["seq"]), np.int32, sharding=v5e)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, "_on_tpu", lambda: True)
+        text = train.make_train_step(make_loss_fn(traffic, model), tx).lower(
+            placed(shapes), placed(jax.eval_shape(tx.init, shapes)), batch, batch
+        ).as_text(debug_info=True)
+    connections = 2 * (config["num_hidden_layers"] + config["num_nextn_predict_layers"])
+    # entry: (kernel, bodies, calls, scope)
+    entries = {"_pre_fwd": ("_hc_pre_fwd_kernel", 2, 2 * connections, "/hc/pre/"),
+               "_post_fwd": ("_hc_post_fwd_kernel", 2, connections + connections // 2,
+                             "/hc/post/"),
+               "_post_bwd": ("_hc_post_bwd_kernel", 1, connections, "/hc/post/"),
+               "_pre_sums": ("_hc_pre_sums_kernel", 1, connections, "/hc/pre/"),
+               "_pre_bwd": ("_hc_pre_bwd_kernel", 1, connections, "/hc/pre/")}
+    bodies = checks.count_pallas_kernels(text, [k for k, *_ in entries.values()])
+    assert bodies == {k: n for k, n, *_ in entries.values()}
+    locations = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+    for entry, (_, _, calls, scope) in entries.items():
+        sites = re.findall(rf"call @{entry}(?:_\d+)?\(.*loc\((#loc\d+)\)$", text, re.M)
+        assert len(sites) == calls, (entry, len(sites))
+        for site in sites:
+            assert scope in locations[site], (entry, locations[site])
