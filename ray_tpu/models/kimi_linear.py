@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
@@ -128,8 +128,8 @@ class NormWeight(nn.Module):
 
 
 class KDAMixer(nn.Module):
+    # One device's: the recurrence is not sharded over the sequence.
     cfg: KimiLinearConfig
-    mesh: Optional[Any] = None  # one device: the recurrence is not sharded
 
     @nn.compact
     def __call__(self, x, positions):

@@ -10,9 +10,10 @@ Sharding: parameters keep flax's standard naming so
 `parallel.mesh.spec_for_param` places them (kernel [in, out] ->
 (fsdp, tensor); embedding [vocab, embed] -> (tensor, fsdp)).
 Activations get in-graph constraints through
-`parallel.with_logical_constraint`. Attention dispatches to the pallas
-flash kernel on TPU and to ring attention when the mesh has a nontrivial
-`seq` axis (long-context sequence parallelism, net-new vs reference).
+`parallel.with_logical_constraint`. The mesh is the ambient one
+(`jax.set_mesh`), which everything here reads: a mixer calls
+`ops.attention.flash_attention`, which rings where that mesh splits the
+sequence (long-context sequence parallelism, net-new vs reference).
 
 Compute in bfloat16, parameters and reductions in float32 (MXU-friendly,
 HBM-light).
@@ -27,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import flash_attention
-from ..ops.ring_attention import ring_self_attention
 from ..parallel.mesh import logical_axis_shards, with_logical_constraint
 from ..util import tracing
 from .hyper_connections import (
@@ -59,8 +59,6 @@ class LlamaConfig:
     initializer_range: Optional[float] = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    # Sequence parallelism: run attention as a ring over the mesh `seq`
-    # axis (requires an ambient mesh passed to __call__ via module attr).
     remat: bool = True
     # "nothing": save only a layer's input (minimum memory). With
     # prevent_cse=False XLA merges the replay with its forward twin:
@@ -118,11 +116,6 @@ CONFIGS: Dict[str, LlamaConfig] = {
         vocab_size=32000, hidden_size=2048, intermediate_size=5504, num_layers=22,
         num_heads=16, num_kv_heads=16, max_seq_len=4096,
     ),
-    "llama-3b": LlamaConfig(
-        vocab_size=32000, hidden_size=2560, intermediate_size=6912, num_layers=32,
-        num_heads=20, num_kv_heads=20, max_seq_len=4096,
-    ),
-    "llama-2-7b": LlamaConfig(),  # the Llama-2-7B shape
 }
 
 
@@ -176,7 +169,6 @@ class RMSNorm(nn.Module):
 
 class Attention(nn.Module):
     cfg: LlamaConfig
-    mesh: Optional[Any] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -200,13 +192,7 @@ class Attention(nn.Module):
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         q = _rope(q, positions, rope_frequencies(hd, cfg.rope_theta))
         k = _rope(k, positions, rope_frequencies(hd, cfg.rope_theta))
-        use_ring = (
-            self.mesh is not None and self.mesh.shape.get("seq", 1) > 1
-        )
-        if use_ring:
-            o = ring_self_attention(q, k, v, self.mesh, causal=True)
-        else:
-            o = flash_attention(q, k, v, causal=True)
+        o = flash_attention(q, k, v, causal=True)
         o = o.transpose(0, 2, 1, 3)  # [B, T, H, D]
         out = nn.DenseGeneral(
             cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
@@ -241,11 +227,9 @@ class MLP(nn.Module):
 class DecoderLayer(nn.Module):
     cfg: LlamaConfig
     # The mixer's and the FFN's flax names and modules, called as
-    # module(cfg, mesh=mesh, name=name)(x, positions) and
-    # module(cfg, name=name)(x).
+    # module(cfg, name=name)(x, positions) and module(cfg, name=name)(x).
     mixer: Tuple[str, Any]
     ffn: Tuple[str, Any]
-    mesh: Optional[Any] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -254,7 +238,7 @@ class DecoderLayer(nn.Module):
         ffn_name, ffn = self.ffn
         if cfg.hyper_connections is not None:
             return _hyper_connected(self, x, positions)
-        h = x + mixer(cfg, mesh=self.mesh, name=mixer_name)(
+        h = x + mixer(cfg, name=mixer_name)(
             RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(x), positions
         )
         out = h + ffn(cfg, name=ffn_name)(
@@ -275,7 +259,7 @@ def _hyper_connected(layer: DecoderLayer, x, positions):
         cfg.param_dtype, name=name,
     )
     u, x, maps = connection("mixer_hc")(x, streams=True)
-    h = write_streams(x, mixer(cfg, mesh=layer.mesh, name=mixer_name)(
+    h = write_streams(x, mixer(cfg, name=mixer_name)(
         RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(u), positions
     ), *maps)
     u, h, maps = connection("ffn_hc")(h, streams=True)
@@ -317,6 +301,13 @@ def _through(model: "LlamaForCausalLM", layers, x, positions):
     inside its ``__call__``: on hyper-connections, copied to the streams
     before them and summed after."""
     cfg = model.cfg
+    ambient = jax.sharding.get_abstract_mesh()
+    if model.mesh is not None and dict(ambient.shape) != dict(model.mesh.shape):
+        raise ValueError(
+            f"the model was built with mesh={dict(model.mesh.shape)} and is "
+            f"applied under the ambient mesh {dict(ambient.shape)}: the layers "
+            "read the ambient one, so apply it inside `with jax.set_mesh(mesh)`"
+        )
     layer_cls = DecoderLayer
     if cfg.remat:
         layer_cls = nn.remat(
@@ -328,8 +319,7 @@ def _through(model: "LlamaForCausalLM", layers, x, positions):
         x = expand_streams(x, hc.mult)
     for name, mixer, ffn in layers:
         x = layer_cls(
-            cfg, (mixer, model.blocks[mixer]), (ffn, model.blocks[ffn]),
-            mesh=model.mesh, name=name,
+            cfg, (mixer, model.blocks[mixer]), (ffn, model.blocks[ffn]), name=name,
         )(x, positions)
     return x if hc is None else collapse_streams(x)
 
@@ -360,6 +350,8 @@ class LlamaForCausalLM(nn.Module):
     whose ``__call__`` has more to it (xing4.py) is made of the same."""
 
     cfg: LlamaConfig
+    # What the caller will `jax.set_mesh`: checked against the ambient mesh
+    # (`_through`), and read by nothing else.
     mesh: Optional[Any] = None
     # A class attribute, not a field: no caller sets it.
     blocks = {"attn": Attention, "mlp": MLP}

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Optional
+from typing import Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
@@ -113,7 +113,6 @@ def yarn_frequencies(dim: int, theta: float, scaling: YarnScaling) -> np.ndarray
 
 class MLAMixer(nn.Module):
     cfg: MLAConfig
-    mesh: Optional[Any] = None
 
     @nn.compact
     def __call__(self, x, positions):

@@ -2,7 +2,7 @@
 
 Policy (SURVEY.md §7): let XLA fuse elementwise/norm/rope into matmuls;
 hand-write kernels only where blockwise algorithms beat materialization
-— attention (flash) and its ring/sequence-parallel variant.
+— attention (flash), which rings where the ambient mesh splits the
+sequence.
 """
 from .attention import flash_attention, attention_reference  # noqa: F401
-from .ring_attention import ring_attention  # noqa: F401

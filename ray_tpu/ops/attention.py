@@ -11,7 +11,9 @@ Forward and backward are pallas kernels on a TPU backend (MXU matmuls
 in f32 accumulation; the backward recomputes probabilities from the
 saved log-sum-exp). On any other backend `flash_attention` is
 `attention_reference`; which one ran is visible in the lowered program
-(`tpu_custom_call`), and chip_smoke.py asserts it.
+(`tpu_custom_call`), and chip_smoke.py asserts it. Where the ambient mesh
+splits the sequence it is the ring of `ring_attention.py` over the same
+blocks (`_block_fwd`, `_block_bwd`).
 """
 from __future__ import annotations
 
@@ -23,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..parallel.mesh import ambient_axes, ambient_spec, logical_axis_shards
 
 NEG_INF = -1e30
 
@@ -404,50 +408,63 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
-# ------------------------------------------------------------------ custom vjp
+# ------------------------------------------------- one block, kernels or XLA
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k):
-    o, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
-    return o
-
-
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    o, lse = _flash_fwd_pallas(
-        q, k, v, causal=causal, sm_scale=sm_scale,
-        block_q=block_q, block_k=block_k,
+def _kernels_fit(tq: int, tk: int, d: int, d_v: int) -> bool:
+    """Where the Pallas kernels run: on the TPU, or under the interpreter,
+    at shapes their tiling takes (>= 8 x 128 blocks). Below them (unit
+    tests, short prompts, a short shard of a ring) the XLA mathematics."""
+    return (
+        (_on_tpu() or _interpret())
+        and tq >= 128 and tk >= 128 and d % 8 == 0 and d_v % 8 == 0
     )
-    return o, (q, k, v, o, lse)
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
-    o, res = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
-    return o, res
-
-
-def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, do):
-    q, k, v, o, lse = res
-    tq, tk = q.shape[1], k.shape[1]
-    if tq >= 128 and tk >= 128 and q.shape[2] % 8 == 0:
-        return _flash_bwd_pallas(
-            q, k, v, o, lse, do, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k,
-        )
-    # Shapes below the kernels' tiling (reached only through
-    # force_pallas): recompute probabilities from lse in XLA,
-    # p = exp(s - lse). Memory high-water is the [Tq, Tk] block per
-    # batch*head slice.
+def _scores(q, k, causal: bool, scale: float):
+    """Scaled scores [bh, tq, tk] in float32, the causal mask with the
+    ends aligned like the kernels' and attention_reference's (the plain
+    lower triangle for a ring's square block)."""
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    ) * sm_scale
-    tq, tk = s.shape[-2:]
+    ) * scale
     if causal:
-        # Ends aligned, like the kernels and attention_reference.
+        tq, tk = s.shape[-2:]
         qpos = jnp.arange(tq)[:, None] + (tk - tq)
-        kpos = jnp.arange(tk)[None, :]
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
-    p = jnp.exp(s - lse[..., :, None])  # [bh, tq, tk]
+        s = jnp.where(qpos >= jnp.arange(tk)[None, :], s, NEG_INF)
+    return s
+
+
+def _block_fwd(q, k, v, causal, scale, block_q, block_k):
+    """One block of attention on [bh, t, d] operands -> (o, lse [bh, tq])."""
+    if _kernels_fit(q.shape[1], k.shape[1], q.shape[2], v.shape[2]):
+        return _flash_fwd_pallas(
+            q, k, v, causal=causal, sm_scale=scale,
+            block_q=block_q, block_k=block_k,
+        )
+    s = _scores(q, k, causal, scale)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o = jax.lax.dot_general(
+        (p / l_safe), v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ).astype(q.dtype)
+    return o, (m + jnp.log(l_safe))[..., 0]
+
+
+def _block_bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k):
+    """(dq, dk, dv) of one block given the (o, lse) of the whole row, which
+    for a ring is the merged one: probabilities are recomputed from it,
+    p = exp(s - lse). In XLA the memory high-water is the [tq, tk] block
+    per batch*head slice."""
+    if _kernels_fit(q.shape[1], k.shape[1], q.shape[2], v.shape[2]):
+        return _flash_bwd_pallas(
+            q, k, v, o, lse, do, causal=causal, sm_scale=scale,
+            block_q=block_q, block_k=block_k,
+        )
+    p = jnp.exp(_scores(q, k, causal, scale) - lse[..., :, None])
     do_f = do.astype(jnp.float32)
     dv = jax.lax.dot_general(
         p, do_f, (((1,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
@@ -457,7 +474,7 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, do):
         do_f, v.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )
-    ds = p * (dp - delta) * sm_scale
+    ds = p * (dp - delta) * scale
     dq = jax.lax.dot_general(
         ds, k.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
@@ -467,6 +484,23 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, do):
         preferred_element_type=jnp.float32,
     )
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+# ------------------------------------------------------------------ custom vjp
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, block_q, block_k):
+    return _block_fwd(q, k, v, causal, sm_scale, block_q, block_k)[0]
+
+
+def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
+    o, lse = _block_fwd(q, k, v, causal, sm_scale, block_q, block_k)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, do):
+    return _block_bwd(*res, do, causal, sm_scale, block_q, block_k)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -484,17 +518,18 @@ def flash_attention(
     # softmax bookkeeping, and VMEM still holds q/k/v/acc comfortably.
     block_q: int = 1024,
     block_k: int = 1024,
-    force_pallas: bool = False,
 ) -> jax.Array:
-    """Blockwise (flash) attention.
+    """Blockwise (flash) attention, the one entry point of the mixers.
 
     q [B, H, Tq, D]; k [B, Hkv, Tk, D]; v [B, Hkv, Tk, Dv], GQA via
     H % Hkv == 0. Dv may differ from D; ``sm_scale`` is the caller's,
-    D ** -0.5 where none is given. Uses the pallas kernel on TPU, XLA
-    reference elsewhere.
+    D ** -0.5 where none is given. Which road it takes follows from what
+    it can observe: a ring over the ambient mesh (``jax.set_mesh``) where
+    that splits the sequence, else the Pallas kernels where they fit
+    (``_kernels_fit``), else the XLA reference.
     """
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[-1]
     if causal and tq > tk:
         # End-aligned (kv-cache) causal semantics put the first
         # tq - tk query rows before every key; their softmax is over an
@@ -503,19 +538,30 @@ def flash_attention(
             f"causal attention requires Tq <= Tk (got Tq={tq}, Tk={tk}): "
             "query rows are aligned to the END of the key sequence"
         )
-    # The kernel needs >=8x128-tileable blocks; tiny shapes (unit tests,
-    # short prompts) take the XLA path.
-    d_v = v.shape[-1]
-    shapes_ok = tq >= 128 and tk >= 128 and d % 8 == 0 and d_v % 8 == 0
-    if not ((_on_tpu() and shapes_ok) or force_pallas):
-        return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    hkv = k.shape[1]
     if h != hkv:
         k = jnp.repeat(k, h // hkv, axis=1)
         v = jnp.repeat(v, h // hkv, axis=1)
     scale = sm_scale if sm_scale is not None else 1.0 / d**0.5
-    qf = q.reshape(b * h, tq, d)
-    kf = k.reshape(b * h, -1, d)
-    vf = v.reshape(b * h, -1, d_v)
-    o = _flash(qf, kf, vf, causal, scale, block_q, block_k)
+    if logical_axis_shards("seq") > 1:
+        from .ring_attention import ring_attention  # it imports this module
+
+        axes = ambient_axes("seq")
+        if d_v != d:
+            raise ValueError(
+                f"the ring's blocks are [.., {d}] throughout: v {v.shape} "
+                f"has another head dim than q {q.shape}, and mesh axis "
+                f"{axes} splits the sequence"
+            )
+        spec = ambient_spec(("batch", "heads", "seq", None))
+        # Blocks of 512 where one device's kernels run 1,024: ROADMAP A13.
+        return jax.shard_map(
+            lambda *qkv: ring_attention(*qkv, axes, causal, scale, 512),
+            in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+        )(q, k, v)
+    if not _kernels_fit(tq, tk, d, d_v):
+        return attention_reference(q, k, v, causal=causal, sm_scale=scale)
+    o = _flash(
+        q.reshape(b * h, tq, d), k.reshape(b * h, tk, d),
+        v.reshape(b * h, tk, d_v), causal, scale, block_q, block_k,
+    )
     return o.reshape(b, h, tq, d_v)

@@ -43,16 +43,13 @@ def test_flash_fwd_bwd_matches_reference(tq, tk, bq, bk, causal):
 
     def f_flash(q, k, v):
         return flash_attention(
-            q, k, v, causal=causal, block_q=bq, block_k=bk,
-            force_pallas=True,
+            q, k, v, causal=causal, block_q=bq, block_k=bk
         ).sum()
 
     def f_ref(q, k, v):
         return attention_reference(q, k, v, causal=causal).sum()
 
-    o_flash = flash_attention(
-        q, k, v, causal=causal, block_q=bq, block_k=bk, force_pallas=True
-    )
+    o_flash = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
     o_ref = attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(
         np.asarray(o_flash), np.asarray(o_ref), atol=2e-3, rtol=2e-3
@@ -71,9 +68,7 @@ def test_flash_gqa_heads():
     q = _rand((B, H, T, D), 3)
     k = _rand((B, HKV, T, D), 4)
     v = _rand((B, HKV, T, D), 5)
-    o_flash = flash_attention(
-        q, k, v, causal=True, block_q=128, block_k=128, force_pallas=True
-    )
+    o_flash = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
     o_ref = attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(o_flash), np.asarray(o_ref), atol=2e-3, rtol=2e-3
@@ -85,4 +80,4 @@ def test_causal_rejects_more_queries_than_keys():
     k = _rand((1, 2, 128, 64), 7)
     v = _rand((1, 2, 128, 64), 8)
     with pytest.raises(ValueError, match="Tq <= Tk"):
-        flash_attention(q, k, v, causal=True, force_pallas=True)
+        flash_attention(q, k, v, causal=True)

@@ -43,7 +43,7 @@ def test_flash_kernels_take_v_of_another_head_dim(t, d, d_v):
 
     def flash(q, k, v):
         return flash_attention(q, k, v, causal=True, sm_scale=scale,
-                               block_q=128, block_k=128, force_pallas=True)
+                               block_q=128, block_k=128)
 
     def plain(q, k, v):
         return attention_reference(q, k, v, causal=True, sm_scale=scale)
@@ -60,10 +60,10 @@ def test_flash_kernels_take_v_of_another_head_dim(t, d, d_v):
 
 def test_the_scale_is_the_callers_and_defaults_to_q_and_ks_head_dim():
     q, k, v = qkv(128, 192, 128)
-    default = flash_attention(q, k, v, force_pallas=True)
+    default = flash_attention(q, k, v)
     np.testing.assert_allclose(
         default, attention_reference(q, k, v, sm_scale=192 ** -0.5), rtol=1e-4, atol=1e-5)
-    other = flash_attention(q, k, v, sm_scale=0.05, force_pallas=True)
+    other = flash_attention(q, k, v, sm_scale=0.05)
     np.testing.assert_allclose(
         other, attention_reference(q, k, v, sm_scale=0.05), rtol=1e-4, atol=1e-5)
     assert float(jnp.abs(other - default).max()) > 1e-3

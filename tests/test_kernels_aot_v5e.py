@@ -254,8 +254,8 @@ def test_mixtrals_step_takes_its_weight_gradients_from_the_grouped_matmul(topo):
         (traffic["batch"], traffic["seq"]), np.int32,
         sharding=logical_sharding(mesh, ("batch", "seq")))
     with pytest.MonkeyPatch.context() as patch, jax.set_mesh(mesh):
-        for module in ("ray_tpu.ops.attention", "ray_tpu.ops.ring_attention"):
-            patch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+        patch.setattr(
+            importlib.import_module("ray_tpu.ops.attention"), "_on_tpu", lambda: True)
         model = model_cls(cfg, mesh=mesh)
         text = train.make_train_step(make_loss_fn(traffic, model), tx).lower(
             jax.tree_util.tree_map_with_path(placed, shapes),

@@ -115,6 +115,18 @@ def test_seq_parallel_matches_single_device():
     )
 
 
+def test_a_model_given_a_mesh_is_applied_under_it(tiny_params):
+    """The layers read the ambient mesh: a model told of a mesh and applied
+    under none, or under another, would gather the sequence without a word."""
+    ids = jnp.zeros((2, 32), jnp.int32)
+    model = LlamaForCausalLM(CFG, mesh=MeshSpec(seq=4).build())
+    with pytest.raises(ValueError, match="jax.set_mesh"):
+        model.apply(tiny_params, ids)
+    with jax.set_mesh(MeshSpec(seq=2).build()):
+        with pytest.raises(ValueError, match="jax.set_mesh"):
+            model.apply(tiny_params, ids)
+
+
 @pytest.mark.parametrize(
     "axes, table_gathers",
     [(dict(fsdp=2, tensor=2), 0), (dict(seq=4), 1)],

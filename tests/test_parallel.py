@@ -1,14 +1,15 @@
 """Mesh/sharding layer + ring attention vs dense oracle on the 8-device
 CPU mesh (the reference tests multi-node on one box the same way —
 cluster_utils; here virtual XLA devices stand in for chips)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops import attention_reference, flash_attention, ring_attention
-from ray_tpu.ops.ring_attention import ring_self_attention
+from ray_tpu.ops import attention_reference, flash_attention
 from ray_tpu.parallel import MeshSpec, logical_sharding
 from ray_tpu.parallel.mesh import logical_to_spec
 
@@ -52,6 +53,15 @@ def test_flash_matches_reference_cpu():
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
 
+def ringed(mesh, q, k, v, causal):
+    """``flash_attention`` under the ambient ``mesh``, which has to take the
+    ring: a neighbour exchange is in what it traces."""
+    with jax.set_mesh(mesh):
+        attend = functools.partial(flash_attention, causal=causal)
+        assert "ppermute" in str(jax.make_jaxpr(attend)(q, k, v))
+        return attend(q, k, v)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_ring_attention_matches_dense(causal):
     mesh = MeshSpec(seq=4).build()
@@ -61,7 +71,7 @@ def test_ring_attention_matches_dense(causal):
         jax.random.normal(jax.random.PRNGKey(i), (b, h, t, d), jnp.float32)
         for i in range(3)
     )
-    out = ring_self_attention(q, k, v, mesh, causal=causal)
+    out = ringed(mesh, q, k, v, causal=causal)
     ref = attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -75,12 +85,15 @@ def test_ring_attention_grads_match_dense():
     )
 
     def loss_ring(q, k, v):
-        return jnp.sum(ring_self_attention(q, k, v, mesh, causal=True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, causal=True) ** 2)
 
     def loss_ref(q, k, v):
         return jnp.sum(attention_reference(q, k, v, causal=True) ** 2)
 
-    g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    with jax.set_mesh(mesh):
+        grad = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2))).lower(q, k, v).compile()
+        assert "collective-permute" in grad.as_text()
+        g_ring = grad(q, k, v)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g_ring, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=5e-5)
@@ -91,7 +104,7 @@ def test_ring_attention_gqa():
     q = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 64, 16))
     k = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 64, 16))
     v = jax.random.normal(jax.random.PRNGKey(2), (1, 2, 64, 16))
-    out = ring_self_attention(q, k, v, mesh, causal=True)
+    out = ringed(mesh, q, k, v, causal=True)
     ref = attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -147,3 +160,31 @@ def test_flash_pallas_backward_matches_reference_grads():
     np.testing.assert_allclose(np.asarray(dv), np.asarray(dv_ref), atol=2e-4)
     np.testing.assert_allclose(np.asarray(dk), np.asarray(dk_ref), atol=2e-4)
     np.testing.assert_allclose(np.asarray(dq), np.asarray(dq_ref), atol=2e-4)
+
+
+def test_latent_attention_rings_where_the_mesh_splits_the_sequence():
+    """``MLAMixer`` takes the road the dense mixer takes: under seq=2 it
+    rings and agrees with one device where v's heads are as wide as q's,
+    and says so where they are not (the ring's blocks are one width)."""
+    from ray_tpu.models.mla import MLAConfig, MLAMixer
+
+    widths = dict(
+        hidden_size=64, num_heads=2, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, mla_rope=True, dtype=jnp.float32,
+    )
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 64), jnp.float32)
+    positions = jnp.broadcast_to(jnp.arange(32)[None], (2, 32))
+    mesh = MeshSpec(seq=2).build()
+
+    mixer = MLAMixer(MLAConfig(v_head_dim=16, **widths))
+    params = mixer.init(jax.random.PRNGKey(1), x, positions)
+    alone = mixer.apply(params, x, positions)
+    with jax.set_mesh(mesh):
+        assert "ppermute" in str(jax.make_jaxpr(mixer.apply)(params, x, positions))
+        split = mixer.apply(params, x, positions)
+    np.testing.assert_allclose(np.asarray(split), np.asarray(alone), atol=2e-5)
+
+    narrow = MLAMixer(MLAConfig(v_head_dim=8, **widths))
+    params = narrow.init(jax.random.PRNGKey(1), x, positions)
+    with jax.set_mesh(mesh), pytest.raises(ValueError, match="splits the sequence"):
+        narrow.apply(params, x, positions)
