@@ -82,6 +82,14 @@ class LlamaConfig:
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    def attention(self, name: Optional[str]) -> "AttentionKind":
+        """What the ``Attention`` bound under the flax name ``name`` is. One
+        kind here; a family whose layers differ tells them apart by the
+        names its ``layers`` gives their mixers (laguna.py)."""
+        return AttentionKind(
+            self.num_heads, rope_frequencies(self.head_dim_, self.rope_theta)
+        )
+
     @property
     def layers(self) -> Tuple[Tuple[str, str], ...]:
         """Each layer's (mixer, ffn), by the names under which the model
@@ -100,6 +108,23 @@ class LlamaConfig:
             per_layer += (self.num_heads + self.num_kv_heads) * hd
         emb = v * h * (1 if self.tie_embeddings else 2)
         return l * per_layer + emb + h
+
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """What one layer's softmax attention is beside the widths every layer
+    shares (K/V heads, head dim), as ``LlamaConfig.attention`` gives it."""
+    num_heads: int
+    # One frequency a pair of turning channels (``rope_frequencies`` or a
+    # scaled table): the leading ``2 * len(freqs)`` channels of a head turn,
+    # the whole head where that is its width.
+    freqs: Any
+    # cos and sin times this (YaRN's ``attention_factor``).
+    rope_amplitude: float = 1.0
+    # Row i sees keys 0 <= i - j < window; None: every key up to its own.
+    window: Optional[int] = None
+    # o_h times sigmoid(x W_g)_h, one gate a head and token, before o_proj.
+    gate: bool = False
 
 
 CONFIGS: Dict[str, LlamaConfig] = {
@@ -138,21 +163,29 @@ def rope_frequencies(dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
-def _rope(x: jax.Array, positions: jax.Array, freqs: jax.Array) -> jax.Array:
+def _rope(x: jax.Array, positions: jax.Array, freqs: jax.Array, *,
+          leading: bool = False, amplitude: float = 1.0) -> jax.Array:
     """Rotary embeddings. x [B, H, T, D], positions [B, T]; ``freqs`` is
     the caller's table (``rope_frequencies``, or a scaled one), one
-    frequency a pair of channels. The trailing ``2 * len(freqs)`` channels
-    of a head turn, channel i of them with channel i + len(freqs), and the
-    ones before them pass as they are."""
+    frequency a pair of channels. ``2 * len(freqs)`` channels of a head
+    turn, the trailing ones (the leading ones under ``leading``), channel i
+    of them with channel i + len(freqs), and the others pass as they are.
+    cos and sin are times ``amplitude``."""
     d = 2 * freqs.shape[0]
     whole = d == x.shape[-1]
     angles = positions[:, None, :, None].astype(jnp.float32) * freqs  # [B,1,T,d/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    turned = x if whole else x[..., -d:]
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
+    turned = x if whole else x[..., :d] if leading else x[..., -d:]
     x1, x2 = jnp.split(turned.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     out = out.astype(x.dtype)
-    return out if whole else jnp.concatenate([x[..., :-d], out], axis=-1)
+    if whole:
+        return out
+    if leading:
+        return jnp.concatenate([out, x[..., d:]], axis=-1)
+    return jnp.concatenate([x[..., :-d], out], axis=-1)
 
 
 class RMSNorm(nn.Module):
@@ -168,18 +201,23 @@ class RMSNorm(nn.Module):
 
 
 class Attention(nn.Module):
+    """Softmax attention over grouped K/V heads. What the layer is beyond
+    the shared widths (its head count, its rotation, a window, an output
+    gate) it learns from the config by its own flax name
+    (``cfg.attention``): a Llama or Mistral layer is all one kind."""
     cfg: LlamaConfig
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
+        kind = cfg.attention(self.name)
         hd = cfg.head_dim_
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
             name=name,
         )
-        q = dense((cfg.num_heads, hd), "q_proj")(x)
+        q = dense((kind.num_heads, hd), "q_proj")(x)
         k = dense((cfg.num_kv_heads, hd), "k_proj")(x)
         v = dense((cfg.num_kv_heads, hd), "v_proj")(x)
         if cfg.qk_norm:
@@ -190,10 +228,18 @@ class Attention(nn.Module):
                 q, k = norm(q, "q_norm"), norm(k, "k_norm")
         # [B, T, H, D] -> [B, H, T, D]
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        q = _rope(q, positions, rope_frequencies(hd, cfg.rope_theta))
-        k = _rope(k, positions, rope_frequencies(hd, cfg.rope_theta))
-        o = flash_attention(q, k, v, causal=True)
+        with tracing.scope(tracing.ATTN_ROPE):
+            turn = lambda t: _rope(  # noqa: E731
+                t, positions, kind.freqs, leading=True,
+                amplitude=kind.rope_amplitude,
+            )
+            q, k = turn(q), turn(k)
+        o = flash_attention(q, k, v, causal=True, window=kind.window)
         o = o.transpose(0, 2, 1, 3)  # [B, T, H, D]
+        if kind.gate:
+            with tracing.scope(tracing.ATTN_GATE):
+                gate = nn.sigmoid(dense(kind.num_heads, "g_proj")(x).astype(jnp.float32))
+                o = o * gate[..., None].astype(o.dtype)
         out = nn.DenseGeneral(
             cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),

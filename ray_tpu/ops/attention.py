@@ -13,7 +13,9 @@ saved log-sum-exp). On any other backend `flash_attention` is
 `attention_reference`; which one ran is visible in the lowered program
 (`tpu_custom_call`), and chip_smoke.py asserts it. Where the ambient mesh
 splits the sequence it is the ring of `ring_attention.py` over the same
-blocks (`_block_fwd`, `_block_bwd`).
+blocks (`_block_fwd`, `_block_bwd`). Under a sliding window the kernels are
+three of their own (`_fwd_window_kernel` and its two backward kernels),
+whose grids walk only the blocks the band crosses.
 """
 from __future__ import annotations
 
@@ -23,6 +25,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -55,13 +58,15 @@ def attention_reference(
     v: jax.Array,
     *,
     causal: bool = True,
+    window: Optional[int] = None,
     sm_scale: Optional[float] = None,
 ) -> jax.Array:
     """Plain XLA attention; also the numerics oracle for kernel tests.
 
     Shapes: q [B, H, Tq, D]; k [B, Hkv, Tk, D]; v [B, Hkv, Tk, Dv] with
     H % Hkv == 0 (GQA). Dv may differ from D (latent attention: q/k heads
-    of 192, v heads of 128); the default scale is D ** -0.5.
+    of 192, v heads of 128); the default scale is D ** -0.5. ``window=w``
+    keeps, of the keys a causal row i sees, the w nearest: 0 <= i - j < w.
     """
     b, h, tq, d = q.shape
     hkv = k.shape[1]
@@ -72,12 +77,19 @@ def attention_reference(
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
     s = s * scale
     if causal:
-        tk = k.shape[2]
-        qpos = jnp.arange(tq)[:, None] + (tk - tq)  # align ends (kv cache)
-        kpos = jnp.arange(tk)[None, :]
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+        s = jnp.where(_visible(tq, k.shape[2], window), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+def _visible(tq: int, tk: int, window: Optional[int]) -> jax.Array:
+    """[tq, tk] causal mask with the ends aligned (kv-cache semantics: query
+    row i stands at key position i + tk - tq), cut to a band of ``window``
+    keys where one is given."""
+    qpos = jnp.arange(tq)[:, None] + (tk - tq)
+    kpos = jnp.arange(tk)[None, :]
+    seen = qpos >= kpos
+    return seen if window is None else seen & (qpos - kpos < window)
 
 
 # ----------------------------------------------------------------- pallas fwd
@@ -408,6 +420,331 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
+# ------------------------------------------------------------ pallas, a window
+# The same three kernels over a band: row i sees keys 0 <= i - j < window, so
+# a block of rows meets only the few blocks of keys its band crosses (and a
+# block of keys the few blocks of rows). The grid's last axis walks those and
+# no others; the index maps start each walk at the band's first block. Names
+# of their own: a trace prices a call by its kernel's name, and a windowed
+# call costs window / T of a causal one. K and V stay at their own heads (a
+# q head's group is found by the index maps), dk and dv sum over the group
+# inside the kernel, and the matmuls take their operands in the inputs' dtype.
+
+
+def _band(i, rows: int, cols: int, n_cols: int, lo_shift: int, hi_shift: int):
+    """(first, last) block of ``cols`` that the band of block ``i`` of
+    ``rows`` crosses: positions i * rows + lo_shift .. i * rows + hi_shift,
+    cut to [0, n_cols). Last < first: none. ``i`` is a numpy array for the
+    grid's static extent and a traced scalar inside index maps and kernels,
+    where the arithmetic is lax's primitives on numerators kept at or above
+    zero: jax.numpy's ``//`` and ``%`` are jitted functions whose cached
+    trace carries the first kernel's frames into the next kernel's Mosaic
+    module, and a profile names a kernel by the first such frame."""
+    if isinstance(i, np.ndarray):
+        lo = np.maximum(i * rows + lo_shift, 0) // cols
+        return lo, np.minimum((i * rows + hi_shift) // cols, n_cols - 1)
+    at = jax.lax.mul(i, jnp.int32(rows))
+    lo = jax.lax.div(jax.lax.max(at + lo_shift, jnp.int32(0)), jnp.int32(cols))
+    # floor((at + hi_shift) / cols) where that is -1 or more, else -1
+    hi = jax.lax.div(
+        jax.lax.max(at + (hi_shift + cols), jnp.int32(0)), jnp.int32(cols)
+    ) - 1
+    return lo, jax.lax.min(hi, jnp.int32(n_cols - 1))
+
+
+def _band_steps(n_rows: int, *band) -> int:
+    """The longest walk any block makes: the grid's last extent."""
+    lo, hi = _band(np.arange(n_rows), *band)
+    return max(int((hi - lo).max()) + 1, 1)
+
+
+def _band_mask(row_block, key_block, *, block_q: int, block_k: int,
+               seq_k: int, seq_q: int, window: int):
+    """[block_q, block_k]: which keys of block ``key_block`` the rows of
+    block ``row_block`` see, the ends aligned as in the causal kernels."""
+    shape = (block_q, block_k)
+    kpos = key_block * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    qpos = row_block * block_q + (seq_k - seq_q) + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0
+    )
+    return (kpos < seq_k) & (qpos >= kpos) & (qpos - kpos < window)
+
+
+def _key_block_map(keys, group: int):
+    """The index map of K and V where the grid's last axis walks a row
+    block's band: q head ``b``'s K/V head, the walk's block held at the
+    band's last once past it (no new copy, and the kernel skips it)."""
+    def index(b, i, j):
+        lo, hi = _band(i, *keys)
+        return jax.lax.div(b, jnp.int32(group)), jax.lax.min(lo + j, hi), 0
+
+    return index
+
+
+def _fwd_window_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                       acc_scr, *, sm_scale: float, band, **tile):
+    # ``tile``: _band_mask's block sizes, lengths and window.
+    iq, j = pl.program_id(1), pl.program_id(2)
+    lo, hi = _band(iq, *band)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(lo + j <= hi)
+    def _compute():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale
+        s = jnp.where(_band_mask(iq, lo + j, **tile), s, NEG_INF)
+        # A row that sees nothing of this block keeps m at NEG_INF and adds
+        # p = 1 a key: the first block it does see multiplies that by
+        # exp(NEG_INF - m) = 0, and every row sees its own position.
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_scr[:] = l_scr[:] * correction + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = m_new
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        l = l_scr[:]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        lse_ref[0] = m_scr[:] + jnp.log(l_safe)
+
+
+def _window_ds(q, k, v, do, lse, delta, mask, sm_scale):
+    """(p, ds) of one [block_q, block_k] tile, both in the inputs' dtype."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * sm_scale
+    s = jnp.where(mask, s, NEG_INF)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = p * (dp - delta) * sm_scale
+    return p.astype(do.dtype), ds.astype(q.dtype)
+
+
+def _bwd_dkv_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                           dk_ref, dv_ref, dk_scr, dv_scr,
+                           *, sm_scale: float, band, steps: int, **tile):
+    # The last axis walks the band's query blocks once for each q head of
+    # this K/V head's group.
+    ik, j = pl.program_id(1), pl.program_id(2)
+    lo, hi = _band(ik, *band)
+    jq = lo + jax.lax.rem(j, jnp.int32(steps))
+
+    @pl.when(j == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(jq <= hi)
+    def _compute():
+        q, do = q_ref[0], do_ref[0]
+        p, ds = _window_ds(
+            q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0],
+            _band_mask(jq, ik, **tile), sm_scale,
+        )
+        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _bwd_dq_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dq_ref, dq_scr, *, sm_scale: float, band, **tile):
+    iq, j = pl.program_id(1), pl.program_id(2)
+    lo, hi = _band(iq, *band)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(lo + j <= hi)
+    def _compute():
+        k = k_ref[0]
+        _, ds = _window_ds(
+            q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
+            _band_mask(iq, lo + j, **tile), sm_scale,
+        )
+        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _window_blocks(q, k, window: int, block_q: int, block_k: int):
+    """What the three windowed calls share: the blocks, the padded lengths
+    and the two bands, of keys a block of rows crosses and of rows a block
+    of keys is seen by."""
+    tq, tk = q.shape[1], k.shape[1]
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    tq_p = (tq + block_q - 1) // block_q * block_q
+    tk_p = (tk + block_k - 1) // block_k * block_k
+    nq, nk, off = tq_p // block_q, tk_p // block_k, tk - tq
+    keys = (block_q, block_k, nk, off - window + 1, off + block_q - 1)
+    rows = (block_k, block_q, nq, -off, block_k + window - 2 - off)
+    return block_q, block_k, tq_p, tk_p, nq, nk, keys, rows
+
+
+def _pad_rows(x, t_p: int):
+    pad = t_p - x.shape[1]
+    return x if not pad else jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+
+
+def _flash_fwd_window_pallas(q, k, v, *, window, sm_scale, block_q, block_k):
+    """q [b * h, tq, d]; k, v [b * hkv, tk, .]: q head n reads K/V head
+    n // (h // hkv)."""
+    bh, tq, d = q.shape
+    tk, group, d_v = k.shape[1], bh // k.shape[0], v.shape[2]
+    block_q, block_k, tq_p, tk_p, nq, nk, keys, _ = _window_blocks(
+        q, k, window, block_q, block_k
+    )
+    steps = _band_steps(nq, *keys)
+    q, k, v = _pad_rows(q, tq_p), _pad_rows(k, tk_p), _pad_rows(v, tk_p)
+
+    key_block = _key_block_map(keys, group)
+    o, lse = pl.pallas_call(
+        functools.partial(
+            _fwd_window_kernel, sm_scale=sm_scale, window=window,
+            block_q=block_q, block_k=block_k, seq_k=tk, seq_q=tq, band=keys,
+        ),
+        grid=(bh, nq, steps),
+        interpret=_interpret(),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, d), key_block),
+            pl.BlockSpec((1, block_k, d_v), key_block),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, tq_p, d_v), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq_p, 1), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * bh * tq_p * steps * block_k * (d + d_v),
+            bytes_accessed=(q.size + k.size + v.size + bh * tq_p * d_v) * 2,
+            transcendentals=bh * tq_p * steps * block_k,
+        ),
+    )(q, k, v)
+    return o[:, :tq], lse[:, :tq, 0]
+
+
+def _flash_bwd_window_pallas(q, k, v, o, lse, do, *, window, sm_scale,
+                             block_q, block_k):
+    bh, tq, d = q.shape
+    bkv, tk, d_v = k.shape[0], k.shape[1], v.shape[2]
+    group = bh // bkv
+    block_q, block_k, tq_p, tk_p, nq, nk, keys, rows = _window_blocks(
+        q, k, window, block_q, block_k
+    )
+    delta = jnp.sum(
+        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
+    )  # [bh, tq, 1]
+    q, do = _pad_rows(q, tq_p), _pad_rows(do, tq_p)
+    lse3, delta3 = _pad_rows(lse[..., None], tq_p), _pad_rows(delta, tq_p)
+    k, v = _pad_rows(k, tk_p), _pad_rows(v, tk_p)
+    static = dict(sm_scale=sm_scale, window=window, block_q=block_q,
+                  block_k=block_k, seq_k=tk, seq_q=tq)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+    )
+
+    steps = _band_steps(nk, *rows)
+
+    def row_block(b, i, j):
+        lo, hi = _band(i, *rows)
+        walk = jax.lax.rem(j, jnp.int32(steps))
+        at = jax.lax.min(lo + walk, jax.lax.max(hi, jnp.int32(0)))
+        return b * group + jax.lax.div(j, jnp.int32(steps)), at, 0
+
+    tile = lambda width, index: pl.BlockSpec((1, block_q, width), index)  # noqa: E731
+    k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
+    v_spec = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, i, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_dkv_window_kernel, band=rows, steps=steps, **static
+        ),
+        interpret=_interpret(),
+        grid=(bkv, nk, group * steps),
+        in_specs=[tile(d, row_block), k_spec, v_spec, tile(d_v, row_block),
+                  tile(1, row_block), tile(1, row_block)],
+        out_specs=[k_spec, v_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((bkv, tk_p, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, tk_p, d_v), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
+        ],
+        compiler_params=params,
+        cost_estimate=pl.CostEstimate(
+            flops=4 * bh * tk_p * steps * block_q * (d + d_v),
+            bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
+            transcendentals=bh * tk_p * steps * block_q,
+        ),
+    )(q, k, v, do, lse3, delta3)
+
+    steps = _band_steps(nq, *keys)
+
+    key_block = _key_block_map(keys, group)
+    here = lambda b, i, j: (b, i, 0)  # noqa: E731
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_window_kernel, band=keys, **static),
+        interpret=_interpret(),
+        grid=(bh, nq, steps),
+        in_specs=[
+            tile(d, here), pl.BlockSpec((1, block_k, d), key_block),
+            pl.BlockSpec((1, block_k, d_v), key_block), tile(d_v, here),
+            tile(1, here), tile(1, here),
+        ],
+        out_specs=tile(d, here),
+        out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=params,
+        cost_estimate=pl.CostEstimate(
+            flops=2 * bh * tq_p * steps * block_k * (2 * d + d_v),
+            bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
+            transcendentals=bh * tq_p * steps * block_k,
+        ),
+    )(q, k, v, do, lse3, delta3)
+    return dq[:, :tq], dk[:, :tk], dv[:, :tk]
+
+
 # ------------------------------------------------- one block, kernels or XLA
 
 
@@ -421,28 +758,33 @@ def _kernels_fit(tq: int, tk: int, d: int, d_v: int) -> bool:
     )
 
 
-def _scores(q, k, causal: bool, scale: float):
+def _scores(q, k, causal: bool, scale: float, window=None):
     """Scaled scores [bh, tq, tk] in float32, the causal mask with the
     ends aligned like the kernels' and attention_reference's (the plain
-    lower triangle for a ring's square block)."""
+    lower triangle for a ring's square block), and its band under a window."""
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
     ) * scale
     if causal:
-        tq, tk = s.shape[-2:]
-        qpos = jnp.arange(tq)[:, None] + (tk - tq)
-        s = jnp.where(qpos >= jnp.arange(tk)[None, :], s, NEG_INF)
+        s = jnp.where(_visible(*s.shape[-2:], window), s, NEG_INF)
     return s
 
 
-def _block_fwd(q, k, v, causal, scale, block_q, block_k):
-    """One block of attention on [bh, t, d] operands -> (o, lse [bh, tq])."""
+def _block_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
+    """One block of attention on [bh, t, d] operands -> (o, lse [bh, tq]).
+    Under a ``window`` the kernels are the windowed ones, which alone take
+    K and V at fewer heads than q's."""
     if _kernels_fit(q.shape[1], k.shape[1], q.shape[2], v.shape[2]):
+        if window is not None:
+            return _flash_fwd_window_pallas(
+                q, k, v, window=window, sm_scale=scale,
+                block_q=block_q, block_k=block_k,
+            )
         return _flash_fwd_pallas(
             q, k, v, causal=causal, sm_scale=scale,
             block_q=block_q, block_k=block_k,
         )
-    s = _scores(q, k, causal, scale)
+    s = _scores(q, k, causal, scale, window)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -454,17 +796,23 @@ def _block_fwd(q, k, v, causal, scale, block_q, block_k):
     return o, (m + jnp.log(l_safe))[..., 0]
 
 
-def _block_bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k):
+def _block_bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+               window=None):
     """(dq, dk, dv) of one block given the (o, lse) of the whole row, which
     for a ring is the merged one: probabilities are recomputed from it,
     p = exp(s - lse). In XLA the memory high-water is the [tq, tk] block
     per batch*head slice."""
     if _kernels_fit(q.shape[1], k.shape[1], q.shape[2], v.shape[2]):
+        if window is not None:
+            return _flash_bwd_window_pallas(
+                q, k, v, o, lse, do, window=window, sm_scale=scale,
+                block_q=block_q, block_k=block_k,
+            )
         return _flash_bwd_pallas(
             q, k, v, o, lse, do, causal=causal, sm_scale=scale,
             block_q=block_q, block_k=block_k,
         )
-    p = jnp.exp(_scores(q, k, causal, scale) - lse[..., :, None])
+    p = jnp.exp(_scores(q, k, causal, scale, window) - lse[..., :, None])
     do_f = do.astype(jnp.float32)
     dv = jax.lax.dot_general(
         p, do_f, (((1,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
@@ -489,21 +837,28 @@ def _block_bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k):
 # ------------------------------------------------------------------ custom vjp
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k):
-    return _block_fwd(q, k, v, causal, sm_scale, block_q, block_k)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, sm_scale, block_q, block_k, window):
+    return _block_fwd(q, k, v, causal, sm_scale, block_q, block_k, window)[0]
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
-    o, lse = _block_fwd(q, k, v, causal, sm_scale, block_q, block_k)
+def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, window):
+    o, lse = _block_fwd(q, k, v, causal, sm_scale, block_q, block_k, window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, do):
-    return _block_bwd(*res, do, causal, sm_scale, block_q, block_k)
+def _flash_bwd_rule(causal, sm_scale, block_q, block_k, window, res, do):
+    return _block_bwd(*res, do, causal, sm_scale, block_q, block_k, window)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+# The windowed kernels' blocks: a band of 512 fills a quarter of the two
+# 1,024-key blocks a 1,024-row block would need. (PERF.md §6, PR 45, has the
+# sweep on the chip.)
+WINDOW_BLOCK_Q = 512
+WINDOW_BLOCK_K = 512
 
 
 def flash_attention(
@@ -512,6 +867,7 @@ def flash_attention(
     v: jax.Array,
     *,
     causal: bool = True,
+    window: Optional[int] = None,
     sm_scale: Optional[float] = None,
     # 1024x1024 measured fastest across d=64/128, T=2048..16384 on v5e
     # (22-27% over 512x512): fewer grid steps amortize the per-block
@@ -526,7 +882,10 @@ def flash_attention(
     D ** -0.5 where none is given. Which road it takes follows from what
     it can observe: a ring over the ambient mesh (``jax.set_mesh``) where
     that splits the sequence, else the Pallas kernels where they fit
-    (``_kernels_fit``), else the XLA reference.
+    (``_kernels_fit``), else the XLA reference. ``window=w`` (causal only)
+    keeps keys 0 <= i - j < w of row i: the kernels' road takes it through
+    the windowed kernels, whose grids walk the band alone, at blocks of
+    their own, and the reference masks it; the ring refuses it.
     """
     b, h, tq, d = q.shape
     hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[-1]
@@ -538,20 +897,28 @@ def flash_attention(
             f"causal attention requires Tq <= Tk (got Tq={tq}, Tk={tk}): "
             "query rows are aligned to the END of the key sequence"
         )
-    if h != hkv:
-        k = jnp.repeat(k, h // hkv, axis=1)
-        v = jnp.repeat(v, h // hkv, axis=1)
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window} needs causal=True and at least the row itself"
+        )
     scale = sm_scale if sm_scale is not None else 1.0 / d**0.5
     if logical_axis_shards("seq") > 1:
         from .ring_attention import ring_attention  # it imports this module
 
         axes = ambient_axes("seq")
+        if window is not None:
+            raise ValueError(
+                f"window={window} under mesh axis {axes}, which splits the "
+                "sequence: the ring has no band"
+            )
         if d_v != d:
             raise ValueError(
                 f"the ring's blocks are [.., {d}] throughout: v {v.shape} "
                 f"has another head dim than q {q.shape}, and mesh axis "
                 f"{axes} splits the sequence"
             )
+        if h != hkv:
+            k, v = (jnp.repeat(t, h // hkv, axis=1) for t in (k, v))
         spec = ambient_spec(("batch", "heads", "seq", None))
         # Blocks of 512 where one device's kernels run 1,024: ROADMAP A13.
         return jax.shard_map(
@@ -559,9 +926,17 @@ def flash_attention(
             in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
         )(q, k, v)
     if not _kernels_fit(tq, tk, d, d_v):
-        return attention_reference(q, k, v, causal=causal, sm_scale=scale)
+        return attention_reference(
+            q, k, v, causal=causal, window=window, sm_scale=scale
+        )
+    if window is not None:
+        # K and V stay at their own heads: the windowed kernels' index maps
+        # find a q head's.
+        block_q, block_k = WINDOW_BLOCK_Q, WINDOW_BLOCK_K
+    elif h != hkv:
+        k, v = (jnp.repeat(t, h // hkv, axis=1) for t in (k, v))
     o = _flash(
-        q.reshape(b * h, tq, d), k.reshape(b * h, tk, d),
-        v.reshape(b * h, tk, d_v), causal, scale, block_q, block_k,
+        q.reshape(b * h, tq, d), k.reshape(-1, tk, d), v.reshape(-1, tk, d_v),
+        causal, scale, block_q, block_k, window,
     )
     return o.reshape(b, h, tq, d_v)

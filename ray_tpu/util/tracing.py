@@ -61,6 +61,10 @@ KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's
 MLA_LATENT = "latent"  # inside mla: down-projection, norm, up-projection of K/V
 MLA_ROPE = "rope"  # inside mla (cfg.mla_rope): the frequency table, the rotation of q's and k's pe parts, the slices and concatenations around it
 MLA_Q_LATENT = "q_latent"  # inside mla (cfg.q_lora_rank): q's down-projection, its norm, the up-projection to the heads
+ATTN = "attn"  # the softmax-attention mixer (models/llama.py Attention); in a model whose layers differ, the full-attention layers'
+SWA = "swa"  # the same module as a sliding-window layer's mixer (models/laguna.py): its own head count and rotation, flash_attention under a window
+ATTN_ROPE = "rotary"  # inside attn and swa: the angles, cos and sin, the rotation of q and of k (a part of each head where the layer's kind says so)
+ATTN_GATE = "out_gate"  # inside attn and swa (a kind with a gate): the gate's projection, its sigmoid, the product with each head's output
 HC = "hc"  # a hyper-connection (models/hyper_connections.py), twice a layer: the three maps of the streams, the read before the sublayer, the write after it
 HC_PRE = "pre"  # inside hc: the streams' rms, x~ Phi, the three logits, H_pre and H_post, the read u = sum H_pre[i] X[i]
 HC_SINKHORN = "sinkhorn"  # inside hc: exp, the iterations of rows and columns, and their backward
@@ -68,10 +72,10 @@ HC_POST = "post"  # inside hc: the write X'[i] = sum H_res[i, j] X[j] + H_post[i
 MTP = "mtp"  # the multi-token-prediction module (flax name, models/xing4.py) and, in the loss, its pass of the shared head
 SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
-          MLA_LATENT, MLA_ROPE, MLA_Q_LATENT, HC, HC_PRE, HC_SINKHORN,
-          HC_POST, MTP)
+          MLA_LATENT, MLA_ROPE, MLA_Q_LATENT, ATTN_ROPE, ATTN_GATE, HC, HC_PRE,
+          HC_SINKHORN, HC_POST, MTP)
 # Flax module names, bound in the model classes' ``blocks``.
-MIXERS = (KDA, MLA)
+MIXERS = (KDA, MLA, ATTN, SWA)
 
 _OFF = contextlib.nullcontext()
 # The flight recorder, while this process holds a train session.

@@ -8,7 +8,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops import kda
-from ray_tpu.ops.attention import _flash_bwd_pallas, _flash_fwd_pallas
+from ray_tpu.ops.attention import (
+    _flash_bwd_pallas, _flash_bwd_window_pallas, _flash_fwd_pallas,
+    _flash_fwd_window_pallas, flash_attention,
+)
 from ray_tpu.ops.gmm import _tgmm_pallas, gmm
 
 
@@ -65,6 +68,110 @@ def test_flash_bwd_compiles_for_v5e(v5e, bh, t, d, block):
         ),
         qkv, qkv, qkv, qkv, ((bh, t), jnp.float32), qkv,
     )
+
+
+# Laguna's sliding layers: 64 q heads over 8 K/V heads of 128, b1 x s16384,
+# a window of 512, at the blocks ops/attention.py runs and the others swept.
+@pytest.mark.parametrize("bq,bk", [(512, 512), (256, 512), (256, 256)])
+def test_windowed_flash_compiles_for_v5e(v5e, bq, bk):
+    q, kv = ((64, 16384, 128), jnp.bfloat16), ((8, 16384, 128), jnp.bfloat16)
+    static = dict(window=512, sm_scale=128**-0.5, block_q=bq, block_k=bk)
+    _compile_for(
+        v5e, lambda q, k, v: _flash_fwd_window_pallas(q, k, v, **static), q, kv, kv
+    )
+    _compile_for(
+        v5e,
+        lambda q, k, v, o, lse, do: _flash_bwd_window_pallas(
+            q, k, v, o, lse, do, **static),
+        q, kv, kv, q, ((64, 16384), jnp.float32), q,
+    )
+
+
+def test_windowed_kernels_lower_under_names_of_their_own(v5e, monkeypatch):
+    """A trace prices a call by its kernel's name: a windowed call is none
+    of the causal kernels', and without a window the causal kernels lower as
+    before. (The kernels lower where the backend is the TPU: the probe is
+    stood in for, as benchmarks/rehearse.py does.)"""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    q = jax.ShapeDtypeStruct((1, 64, 2048, 128), jnp.bfloat16, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((1, 8, 2048, 128), jnp.bfloat16, sharding=v5e)
+
+    def text(window):
+        return jax.jit(jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, window=window).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )).lower(q, kv, kv).as_text()
+
+    windowed, causal = text(512), text(None)
+    for name in ("_fwd", "_bwd_dkv", "_bwd_dq"):
+        assert windowed.count(f'kernel_name = "{name}_window_kernel"') == 1
+        assert f'kernel_name = "{name}_kernel"' not in windowed
+        assert causal.count(f'kernel_name = "{name}_kernel"') == 1
+    assert "window" not in causal
+    # K and V reach the windowed kernels at their own 8 heads
+    assert "8x2048x128xbf16" in windowed and "64x2048x128xbf16" in causal
+
+
+def test_a_profile_names_each_flash_kernel_of_a_remat_step_by_its_own_name(
+        v5e, monkeypatch):
+    """The benchmark's readers name a device event's kernel by the first
+    ``*_kernel`` identifier in its Mosaic module's string table
+    (``benchmarks/lib/trace.py kernel_name``), and a cached trace of a jitted
+    jax.numpy function (``//``, ``%``) carries the frames of the kernel that
+    traced it first into the next one's module: in a step with a full and a
+    sliding layer under remat, each of the six flash kernels still reads as
+    itself."""
+    import re
+
+    import numpy as np
+
+    from benchmarks.lib.trace import kernel_name
+    from ray_tpu.models.laguna import LagunaForCausalLM, laguna_config
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = laguna_config(
+        num_layers=2, layer_types=["full_attention", "sliding_attention"],
+        mlp_layer_types=["dense", "dense"], num_attention_heads_per_layer=[2, 4],
+        sliding_window=512,
+        rope_parameters={
+            "full_attention": {
+                "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+                "original_max_position_embeddings": 4096, "beta_slow": 1,
+                "beta_fast": 64, "attention_factor": 1.4158883,
+                "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                                  "partial_rotary_factor": 1}},
+        shared_expert_intermediate_size=128, moe_intermediate_size=128,
+        num_experts_held=8, num_experts=8, vocab_size=512, hidden_size=256,
+        intermediate_size=512, num_heads=2, num_kv_heads=2, head_dim=128,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+    )
+    model = LagunaForCausalLM(cfg)
+    ids = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=v5e)
+    shapes = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), shapes)
+    mesh = jax.sharding.Mesh(np.array([v5e._device]), ("data",))
+    with jax.set_mesh(mesh):  # as a cell's step is lowered
+        text = jax.jit(jax.grad(
+            lambda p, i: chunked_causal_lm_loss(model, p, i, i, chunk_size=1024)
+        )).lower(params, ids).compile().as_text()
+    named = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            mixer = re.search(r"/layers_\d/(attn|swa)/", line).group(1)
+            named.setdefault(mixer, []).append(kernel_name(line))
+    assert sorted(named["attn"]) == [
+        "_bwd_dkv_kernel", "_bwd_dq_kernel", "_fwd_kernel", "_fwd_kernel"]
+    assert sorted(named["swa"]) == [
+        "_bwd_dkv_window_kernel", "_bwd_dq_window_kernel",
+        "_fwd_window_kernel", "_fwd_window_kernel"]
 
 
 # mixtral-small: b2 x s2048 tokens x top-2 pairs padded to 128-row tiles

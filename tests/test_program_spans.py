@@ -207,6 +207,44 @@ def xing4_paths():
         del os.environ["RAY_TPU_PALLAS_INTERPRET"]
 
 
+@pytest.fixture(scope="module")
+def laguna_paths():
+    """Paths of a tiny laguna model's compiled train step: a full-attention
+    layer over a dense FFN and a sliding-window layer over the expert layer,
+    each mixer with its own head count, rotation and output gate."""
+    from ray_tpu.models.laguna import LagunaForCausalLM, laguna_config
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"  # read when gmm is traced
+    try:
+        cfg = laguna_config(
+            num_layers=2, layer_types=["full_attention", "sliding_attention"],
+            mlp_layer_types=["dense", "sparse"],
+            num_attention_heads_per_layer=[2, 4], sliding_window=16,
+            rope_parameters={
+                "full_attention": {
+                    "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+                    "original_max_position_embeddings": 4096, "beta_slow": 1,
+                    "beta_fast": 64, "attention_factor": 1.4158883,
+                    "partial_rotary_factor": 0.5},
+                "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                                      "partial_rotary_factor": 1}},
+            shared_expert_intermediate_size=16, num_experts_held=2,
+            vocab_size=128, hidden_size=32, intermediate_size=64,
+            moe_intermediate_size=16, num_heads=2, num_kv_heads=2, head_dim=16,
+            num_experts=8, num_experts_per_tok=2, routed_scaling_factor=2.5,
+        )
+        model = LagunaForCausalLM(cfg)
+        ids = jnp.zeros((1, 64), jnp.int32)
+        return paths_of(compiled_step(
+            model,
+            lambda p, i, t: chunked_causal_lm_loss(model, p, i, t, chunk_size=32),
+            ids,
+        ))
+    finally:
+        del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+
+
 # ----------------------------------------------------------- in-graph scopes
 
 
@@ -270,10 +308,33 @@ def test_latent_attention_carries_rope_and_qk_norm_where_a_model_has_them(
         assert not [p for p in kimi_paths if f"/mla/{name}/" in p], name
 
 
+def test_full_and_sliding_mixers_carry_their_names_rotation_and_gate(
+        laguna_paths, llama_paths):
+    """What the benchmark's model.swa_share selects by: a sliding layer's
+    mixer is /swa/ and a full layer's stays /attn/; inside each the rotation
+    and the output gate, forward and backward, the projections outside."""
+    for layer, mixer, other in (("layers_0", tracing.ATTN, tracing.SWA),
+                                ("layers_1", tracing.SWA, tracing.ATTN)):
+        mine = [p for p in laguna_paths if f"/{layer}/{mixer}/" in p]
+        assert not [p for p in laguna_paths if f"/{layer}/{other}/" in p]
+        for name in (tracing.ATTN_ROPE, tracing.ATTN_GATE):
+            assert {pass_of(p) for p in mine if f"/{mixer}/{name}/" in p} >= {
+                "forward", "backward"}, (layer, name)
+        assert any(f"/{mixer}/{tracing.ATTN_GATE}/g_proj/" in p for p in mine)
+        assert any(f"/{mixer}/q_proj/" in p for p in mine)
+        assert not [p for p in mine if f"/{tracing.ATTN_ROPE}/" in p and "proj" in p]
+    assert any("/layers_0/mlp/" in p for p in laguna_paths)
+    assert any(f"/layers_1/moe/{tracing.MOE_SHARED}/shared/" in p for p in laguna_paths)
+    # a Llama layer is all one kind: rotated under the same scope, no gate
+    assert any(f"/attn/{tracing.ATTN_ROPE}/" in p for p in llama_paths)
+    assert not [p for p in llama_paths
+                if f"/{tracing.ATTN_GATE}/" in p or f"/{tracing.SWA}/" in p]
+
+
 def test_the_hybrid_carries_its_mixers_names_and_scopes(kimi_paths):
     """What the benchmark's model.kda_share and model.mla_share select by,
     and the scopes inside the two mixers and the shared expert."""
-    for name in tracing.MIXERS:
+    for name in (tracing.KDA, tracing.MLA):  # the two of MIXERS it has
         assert any(f"/{name}/" in p for p in kimi_paths), name
     kda = [p for p in kimi_paths if "/kda/" in p]
     for name in (tracing.KDA_CONV, tracing.KDA_GATE, tracing.KDA_SCAN):
@@ -517,13 +578,13 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 
 def test_names_emitted_are_exactly_the_list(
     llama_paths, qk_norm_paths, moe_paths, kimi_paths, sarvam_paths,
-    xing4_paths, session_lines, actor_lines
+    xing4_paths, laguna_paths, session_lines, actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + [
+    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + [
         p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:
         assert any(f"/{name}/" in p for p in paths), name
