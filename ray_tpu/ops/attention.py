@@ -8,14 +8,15 @@ carrying a running (max, sum, acc) triple in VMEM so the full [Tq, Tk]
 score matrix never materializes in HBM.
 
 Forward and backward are pallas kernels on a TPU backend (MXU matmuls
-in f32 accumulation; the backward recomputes probabilities from the
-saved log-sum-exp). On any other backend `flash_attention` is
-`attention_reference`; which one ran is visible in the lowered program
-(`tpu_custom_call`), and chip_smoke.py asserts it. Where the ambient mesh
-splits the sequence it is the ring of `ring_attention.py` over the same
-blocks (`_block_fwd`, `_block_bwd`). Under a sliding window the kernels are
-three of their own (`_fwd_window_kernel` and its two backward kernels),
-whose grids walk only the blocks the band crosses.
+in f32 accumulation; the backward recomputes probabilities from the saved
+log-sum-exp; a grid step does what its tile's position needs,
+``_tile_class``). On any other backend
+`flash_attention` is `attention_reference`; which one ran is visible in the
+lowered program (`tpu_custom_call`), and chip_smoke.py asserts it. Where the
+ambient mesh splits the sequence it is the ring of `ring_attention.py` over
+the same blocks (`_block_fwd`, `_block_bwd`). Under a sliding window the
+kernels are three of their own (`_fwd_window_kernel` and its two backward
+kernels), whose grids walk only the blocks the band crosses.
 """
 from __future__ import annotations
 
@@ -92,12 +93,136 @@ def _visible(tq: int, tk: int, window: Optional[int]) -> jax.Array:
     return seen if window is None else seen & (qpos - kpos < window)
 
 
+# ----------------------------------------------------------- a tile's position
+# What a grid step of the three causal kernels has to do follows from where
+# its [block_q, block_k] tile lies, which the program ids and the static
+# lengths say: an interior tile (every key visible to every row, none of them
+# padding) needs no mask, an edge tile (crossed by the diagonal, or holding
+# padded keys) the masked body, a dead tile (wholly above the diagonal)
+# neither arithmetic nor a copy. A non-causal call is a causal one whose
+# diagonal lies beyond the last key.
+
+
+def _diagonal(causal: bool, seq_q: int, seq_k: int) -> int:
+    """Row r sees key c where c <= r + this: the ends aligned."""
+    return seq_k - seq_q if causal else seq_k
+
+
+def _tile_class(i, j, *, causal: bool, block_q: int, block_k: int,
+                seq_q: int, seq_k: int):
+    """(live, interior) of the tile of row block ``i`` and key block ``j``.
+    Numpy arrays for the counts, traced scalars inside the kernels."""
+    off = _diagonal(causal, seq_q, seq_k)
+    live = (i + 1) * block_q + off > j * block_k
+    interior = ((j + 1) * block_k <= seq_k) & (
+        i * block_q + off >= (j + 1) * block_k - 1
+    )
+    return live, interior
+
+
+def causal_tiles(tq: int, tk: int, block_q: int, block_k: int,
+                 causal: bool) -> tuple[int, int, int]:
+    """(interior, edge, dead) tiles of one head's grid: how often each body
+    of a kernel engages is a function of the shapes alone."""
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    live, interior = _tile_class(
+        np.arange(-(-tq // block_q))[:, None], np.arange(-(-tk // block_k))[None, :],
+        causal=causal, block_q=block_q, block_k=block_k, seq_q=tq, seq_k=tk,
+    )
+    return int(interior.sum()), int((live & ~interior).sum()), int((~live).sum())
+
+
+def _last_live_key(i, *, causal: bool, block_q: int, block_k: int,
+                   seq_q: int, seq_k: int):
+    """The last key block that row block ``i`` sees. (lax's primitives, as
+    in ``_band``.)"""
+    at = jax.lax.mul(i + 1, jnp.int32(block_q)) + (
+        _diagonal(causal, seq_q, seq_k) - 1
+    )
+    return jax.lax.min(
+        jax.lax.div(at, jnp.int32(block_k)), jnp.int32(-(-seq_k // block_k) - 1)
+    )
+
+
+def _first_live_row(j, *, causal: bool, block_q: int, block_k: int,
+                    seq_q: int, seq_k: int):
+    """The first row block that sees key block ``j``."""
+    at = jax.lax.mul(j, jnp.int32(block_k)) - _diagonal(causal, seq_q, seq_k)
+    return jax.lax.div(jax.lax.max(at, jnp.int32(0)), jnp.int32(block_q))
+
+
+def _key_walk(tile: dict):
+    """The index map of K and V where the grid's last axis walks a row
+    block's keys: a dead step is held at the last live block, which is
+    resident, so the pipeline issues no copy."""
+    def index(b, i, j):
+        return b, jax.lax.min(j, _last_live_key(i, **tile)), 0
+
+    return index
+
+
+def _row_walk(tile: dict):
+    """The same for q, do, lse and delta where the last axis walks a key
+    block's rows: the dead steps come first and wait at the first live
+    block."""
+    def index(b, i, j):
+        return b, jax.lax.max(j, _first_live_row(i, **tile)), 0
+
+    return index
+
+
+def _tile_mask(iq, ik, *, causal: bool, block_q: int, block_k: int,
+               seq_q: int, seq_k: int):
+    """[block_q, block_k]: the keys of block ``ik`` that are no padding and
+    that the rows of block ``iq`` see."""
+    shape = (block_q, block_k)
+    kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mask = kpos < seq_k  # padded keys
+    if causal:
+        # Ends aligned (kv-cache semantics, matching attention_reference):
+        # query row i attends keys up to i + (seq_k - seq_q).
+        qpos = iq * block_q + (seq_k - seq_q) + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0
+        )
+        mask = jnp.logical_and(mask, qpos >= kpos)
+    return mask
+
+
+def _by_position(body, iq, ik, tile: dict) -> None:
+    """Run ``body(masked)`` as the tile's position asks: unmasked on an
+    interior tile, masked on an edge tile, not at all on a dead one (a
+    skipped tile is exactly a p = 0 update). A body no tile of the grid
+    needs is not lowered."""
+    n_interior, n_edge, _ = causal_tiles(
+        tile["seq_q"], tile["seq_k"], tile["block_q"], tile["block_k"],
+        tile["causal"],
+    )
+    live, interior = _tile_class(iq, ik, **tile)
+    if n_interior:
+        pl.when(interior)(functools.partial(body, False))
+    if n_edge:
+        pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
+            functools.partial(body, True)
+        )
+
+
+def _causal_blocks(q, k, causal: bool, block_q: int, block_k: int):
+    """What the three causal calls share: the padded lengths and the tile's
+    statics (``_tile_class``'s keywords)."""
+    tq, tk = q.shape[1], k.shape[1]
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    tq_p = (tq + block_q - 1) // block_q * block_q
+    tk_p = (tk + block_k - 1) // block_k * block_k
+    tile = dict(causal=causal, block_q=block_q, block_k=block_k,
+                seq_q=tq, seq_k=tk)
+    return tq_p, tk_p, tile
+
+
 # ----------------------------------------------------------------- pallas fwd
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale: float, causal: bool, block_q: int, block_k: int,
-                seq_k: int, seq_q: int):
+                *, sm_scale: float, **tile):
     iq, ik = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -107,25 +232,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute():
-        q = q_ref[0]  # [block_q, d]
-        k = k_ref[0]  # [block_k, d]
+    def _tile(masked: bool):
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         s = s * sm_scale
-
-        kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < seq_k  # padded keys
-        if causal:
-            # Ends aligned (kv-cache semantics, matching
-            # attention_reference): query row i attends keys up to
-            # i + (seq_k - seq_q).
-            qpos = iq * block_q + (seq_k - seq_q) + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0
-            )
-            mask = jnp.logical_and(mask, qpos >= kpos)
-        s = jnp.where(mask, s, NEG_INF)
+        if masked:
+            s = jnp.where(_tile_mask(iq, ik, **tile), s, NEG_INF)
 
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -138,15 +252,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         )
         m_scr[:] = m_new
 
-    if causal:
-        # Blocks entirely above the diagonal are fully masked: skip
-        # their MXU work (a skipped block is exactly a p=0 update —
-        # m/l/acc unchanged). Halves attention compute at long T.
-        pl.when(
-            (iq + 1) * block_q + (seq_k - seq_q) > ik * block_k
-        )(_compute)
-    else:
-        _compute()
+    _by_position(_tile, iq, ik, tile)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -159,33 +265,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q, block_k):
     bh, tq, d = q.shape
     tk, d_v = k.shape[1], v.shape[2]
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
-    tq_p = (tq + block_q - 1) // block_q * block_q
-    tk_p = (tk + block_k - 1) // block_k * block_k
-    if tq_p != tq:
-        q = jnp.pad(q, ((0, 0), (0, tq_p - tq), (0, 0)))
-    if tk_p != tk:
-        k = jnp.pad(k, ((0, 0), (0, tk_p - tk), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, tk_p - tk), (0, 0)))
-    grid = (bh, tq_p // block_q, tk_p // block_k)
-    kernel = functools.partial(
-        _fwd_kernel,
-        sm_scale=sm_scale,
-        causal=causal,
-        block_q=block_q,
-        block_k=block_k,
-        seq_k=tk,
-        seq_q=tq,
-    )
+    tq_p, tk_p, tile = _causal_blocks(q, k, causal, block_q, block_k)
+    block_q, block_k = tile["block_q"], tile["block_k"]
+    q, k, v = _pad_rows(q, tq_p), _pad_rows(k, tk_p), _pad_rows(v, tk_p)
+
     o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, **tile),
+        grid=(bh, tq_p // block_q, tk_p // block_k),
         interpret=_interpret(),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), _key_walk(tile)),
+            pl.BlockSpec((1, block_k, d_v), _key_walk(tile)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
@@ -218,13 +309,36 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q, block_k):
 # FlashAttention-2 style backward: probabilities recomputed per block
 # from the saved log-sum-exp, two kernels so each output accumulates in
 # VMEM over its contraction dimension (dk/dv over q blocks, dq over kv
-# blocks) and the [Tq, Tk] score matrix never hits HBM.
+# blocks) and the [Tq, Tk] score matrix never hits HBM. p and ds are
+# computed in float32. dK/dV, which transposes both, casts them to the
+# inputs' dtype first, as the forward does its p: the MXU's one pass rounds
+# a float32 operand the same, so the result is the same and 4% sooner. dQ
+# transposes nothing and would only pay the cast (+2.6%), and an unmasked
+# body won it nothing: it keeps one masked body and float32 operands
+# (PERF.md §6, PR 46).
+
+
+def _tile_p(q, k, lse, mask, sm_scale: float):
+    """The tile's probabilities, float32 [block_q, block_k]."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * sm_scale
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    return jnp.exp(s - lse)
+
+
+def _tile_ds(p, do, v, delta, sm_scale: float):
+    """The scores' gradient, float32 [block_q, block_k]."""
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return p * (dp - delta) * sm_scale
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, sm_scale: float, causal: bool, block_q: int,
-                    block_k: int, seq_k: int, seq_q: int):
+                    *, sm_scale: float, **tile):
     ik, jq = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
 
@@ -233,48 +347,22 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute():
-        q = q_ref[0]  # [block_q, d]
-        k = k_ref[0]  # [block_k, d]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # [block_q, 1]
-        delta = delta_ref[0]  # [block_q, 1]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < seq_k
-        if causal:
-            qpos = jq * block_q + (seq_k - seq_q) + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0
-            )
-            mask = jnp.logical_and(mask, qpos >= kpos)
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)  # [block_q, block_k]
-
+    def _tile(masked: bool):
+        q, do = q_ref[0], do_ref[0]  # [block_q, d], [block_q, d_v]
+        mask = _tile_mask(jq, ik, **tile) if masked else None
+        p = _tile_p(q, k_ref[0], lse_ref[0], mask, sm_scale)
+        # dv before ds: p and ds are 4 MB each at 1,024 x 1,024
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta) * sm_scale
+        ds = _tile_ds(p, do, v_ref[0], delta_ref[0], sm_scale)
         dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        # q blocks entirely above this k block's diagonal contribute
-        # p=0 — skip their MXU work.
-        pl.when(
-            (jq + 1) * block_q + (seq_k - seq_q) > ik * block_k
-        )(_compute)
-    else:
-        _compute()
+    _by_position(_tile, jq, ik, tile)
 
     @pl.when(jq == nq - 1)
     def _finish():
@@ -283,9 +371,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_scr,
-                   *, sm_scale: float, causal: bool, block_q: int,
-                   block_k: int, seq_k: int, seq_q: int):
+                   dq_ref, dq_scr, *, sm_scale: float, **tile):
     iq, jk = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -293,42 +379,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _compute():
-        q = q_ref[0]
+    def _tile():
         k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        kpos = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < seq_k
-        if causal:
-            qpos = iq * block_q + (seq_k - seq_q) + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0
-            )
-            mask = jnp.logical_and(mask, qpos >= kpos)
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * sm_scale
+        p = _tile_p(q_ref[0], k, lse_ref[0], _tile_mask(iq, jk, **tile), sm_scale)
+        ds = _tile_ds(p, do_ref[0].astype(jnp.float32),
+                      v_ref[0].astype(jnp.float32), delta_ref[0], sm_scale)
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
             ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        pl.when(
-            (iq + 1) * block_q + (seq_k - seq_q) > jk * block_k
-        )(_compute)
-    else:
-        _compute()
+    pl.when(_tile_class(iq, jk, **tile)[0])(_tile)  # every live tile
 
     @pl.when(jk == nk - 1)
     def _finish():
@@ -339,41 +400,36 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
                       block_q, block_k):
     bh, tq, d = q.shape
     tk, d_v = k.shape[1], v.shape[2]
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
-    tq_p = (tq + block_q - 1) // block_q * block_q
-    tk_p = (tk + block_k - 1) // block_k * block_k
+    tq_p, tk_p, tile = _causal_blocks(q, k, causal, block_q, block_k)
+    block_q, block_k = tile["block_q"], tile["block_k"]
+    nq, nk = tq_p // block_q, tk_p // block_k
     delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-    )  # [bh, tq]
-    if tq_p != tq:
-        pad = ((0, 0), (0, tq_p - tq), (0, 0))
-        q = jnp.pad(q, pad)
-        do = jnp.pad(do, pad)
-        lse = jnp.pad(lse, ((0, 0), (0, tq_p - tq)))
-        delta = jnp.pad(delta, ((0, 0), (0, tq_p - tq)))
-    if tk_p != tk:
-        pad = ((0, 0), (0, tk_p - tk), (0, 0))
-        k = jnp.pad(k, pad)
-        v = jnp.pad(v, pad)
-    lse3 = lse[..., None]
-    delta3 = delta[..., None]
-
+        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
+    )  # [bh, tq, 1]
+    q, do = _pad_rows(q, tq_p), _pad_rows(do, tq_p)
+    lse3, delta3 = _pad_rows(lse[..., None], tq_p), _pad_rows(delta, tq_p)
+    k, v = _pad_rows(k, tk_p), _pad_rows(v, tk_p)
+    static = dict(sm_scale=sm_scale, **tile)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+    )
+    cost = pl.CostEstimate(
+        flops=5 * bh * tq_p * tk_p * (d + d_v) // 2,
+        bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
+        transcendentals=bh * tq_p * tk_p,
+    )
     # q and k (and their gradients) have d lanes; v, do and dv have d_v.
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))
-    do_spec = pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, j, 0))
-    kv_spec_i = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
-    v_spec_i = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
+    rows = lambda width, index: pl.BlockSpec((1, block_q, width), index)  # noqa: E731
+    keys = lambda width, index: pl.BlockSpec((1, block_k, width), index)  # noqa: E731
+    here = lambda b, i, j: (b, i, 0)  # noqa: E731
+    row_walk, key_walk = _row_walk(tile), _key_walk(tile)
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, seq_k=tk, seq_q=tq,
-        ),
+        functools.partial(_bwd_dkv_kernel, **static),
         interpret=_interpret(),
-        grid=(bh, tk_p // block_k, tq_p // block_q),
-        in_specs=[q_spec, kv_spec_i, v_spec_i, do_spec, row_spec, row_spec],
-        out_specs=[kv_spec_i, v_spec_i],
+        grid=(bh, nk, nq),
+        in_specs=[rows(d, row_walk), keys(d, here), keys(d_v, here),
+                  rows(d_v, row_walk), rows(1, row_walk), rows(1, row_walk)],
+        out_specs=[keys(d, here), keys(d_v, here)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk_p, d), k.dtype),
             jax.ShapeDtypeStruct((bh, tk_p, d_v), v.dtype),
@@ -382,40 +438,21 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=5 * bh * tq_p * tk_p * (d + d_v) // 2,
-            bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
-            transcendentals=bh * tq_p * tk_p,
-        ),
+        compiler_params=params,
+        cost_estimate=cost,
     )(q, k, v, do, lse3, delta3)
 
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    do_spec2 = pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0))
-    kv_spec_j = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    v_spec_j = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, j, 0))
-    row_spec2 = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, seq_k=tk, seq_q=tq,
-        ),
+        functools.partial(_bwd_dq_kernel, **static),
         interpret=_interpret(),
-        grid=(bh, tq_p // block_q, tk_p // block_k),
-        in_specs=[q_spec2, kv_spec_j, v_spec_j, do_spec2, row_spec2, row_spec2],
-        out_specs=q_spec2,
+        grid=(bh, nq, nk),
+        in_specs=[rows(d, here), keys(d, key_walk), keys(d_v, key_walk),
+                  rows(d_v, here), rows(1, here), rows(1, here)],
+        out_specs=rows(d, here),
         out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=5 * bh * tq_p * tk_p * (d + d_v) // 2,
-            bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
-            transcendentals=bh * tq_p * tk_p,
-        ),
+        compiler_params=params,
+        cost_estimate=cost,
     )(q, k, v, do, lse3, delta3)
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
@@ -869,9 +906,7 @@ def flash_attention(
     causal: bool = True,
     window: Optional[int] = None,
     sm_scale: Optional[float] = None,
-    # 1024x1024 measured fastest across d=64/128, T=2048..16384 on v5e
-    # (22-27% over 512x512): fewer grid steps amortize the per-block
-    # softmax bookkeeping, and VMEM still holds q/k/v/acc comfortably.
+    # 1,024 x 1,024: PERF.md §6, PR 46, has the sweep on the chip.
     block_q: int = 1024,
     block_k: int = 1024,
 ) -> jax.Array:

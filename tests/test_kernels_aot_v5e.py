@@ -70,6 +70,32 @@ def test_flash_bwd_compiles_for_v5e(v5e, bh, t, d, block):
     )
 
 
+# The two-body causal kernels (PR 46) at the shapes of the benchmark's cells
+# that the cases above and ``test_flash_at_192_and_128`` leave out, and where
+# the diagonal is moved or a key block padded: (bh, tq, tk, d, d_v, block).
+TWO_BODY_SHAPES = [
+    (32, 16384, 16384, 128, 128, 1024),  # long16k: 120 interior, 16 edge, 120 dead
+    (48, 16384, 16384, 128, 128, 1024),  # Laguna's full layers
+    (64, 512, 512, 128, 128, 1024),      # sft512: one tile, an edge one
+    (32, 2048, 4096, 128, 128, 1024),    # tq < tk: a prefill chunk behind a cache
+    (32, 2048, 3000, 128, 128, 1024),    # tq < tk and a padded last key block
+    (64, 3000, 3000, 192, 128, 1024),    # padded rows and keys at 192/128
+]
+
+
+@pytest.mark.parametrize("bh,tq,tk,d,d_v,block", TWO_BODY_SHAPES)
+def test_two_body_flash_kernels_compile_for_v5e(v5e, bh, tq, tk, d, d_v, block):
+    q, k = ((bh, tq, d), jnp.bfloat16), ((bh, tk, d), jnp.bfloat16)
+    v, o = ((bh, tk, d_v), jnp.bfloat16), ((bh, tq, d_v), jnp.bfloat16)
+    args = dict(causal=True, sm_scale=d**-0.5, block_q=block, block_k=block)
+    _compile_for(v5e, lambda q, k, v: _flash_fwd_pallas(q, k, v, **args), q, k, v)
+    _compile_for(
+        v5e,
+        lambda q, k, v, o, lse, do: _flash_bwd_pallas(q, k, v, o, lse, do, **args),
+        q, k, v, o, ((bh, tq), jnp.float32), o,
+    )
+
+
 # Laguna's sliding layers: 64 q heads over 8 K/V heads of 128, b1 x s16384,
 # a window of 512, at the blocks ops/attention.py runs and the others swept.
 @pytest.mark.parametrize("bq,bk", [(512, 512), (256, 512), (256, 256)])
