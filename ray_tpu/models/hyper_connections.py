@@ -57,6 +57,7 @@ from typing import Any, Callable, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -354,8 +355,10 @@ def _lanes_spec(rows):
 
 # One jitted entry a kernel, so that the step's text holds a body once
 # whatever the number of hyper-connections that call it (a forward kernel's
-# twice under remat, whose partial evaluation copies the entry's jaxpr for the
-# replays), and not a Mosaic lowering a call site.
+# twice under remat, whose partial evaluation copies the entry's jaxpr: for
+# the replays where the policy keeps nothing, and for a layer's second
+# connection, whose streams are a kept value, where it keeps what the kernels
+# wrote), and not a Mosaic lowering a call site.
 
 
 @functools.partial(jax.jit, static_argnums=4)
@@ -457,7 +460,11 @@ _write_by_kernels = jax.custom_vjp(_post_fwd)
 
 
 def _write_fwd(x, y, post, res):
-    return _post_fwd(x, y, post, res), (x, y, post, res)
+    # Named for the remat policy, like the read's outputs below (models/llama.py
+    # KERNEL_RESIDUALS): a layer's first write is its second connection's
+    # streams, and a replay that holds it runs no write.
+    out = checkpoint_name(_post_fwd(x, y, post, res), "hc_write")
+    return out, (x, y, post, res)
 
 
 def _write_bwd(residuals, g):
@@ -474,6 +481,7 @@ def _read_by_kernels(x, phi, alpha_pre, b_pre, rms_eps):
 
 def _read_fwd(x, phi, alpha_pre, b_pre, rms_eps):
     u, h = _pre_fwd(x, phi, alpha_pre, b_pre, rms_eps)
+    u, h = checkpoint_name(u, "hc_read"), checkpoint_name(h, "hc_maps")
     h, r = h[:-1], h[-1]
     return (u, h, x), (x, phi, alpha_pre, h, _h_pre(h, alpha_pre, b_pre), r)
 

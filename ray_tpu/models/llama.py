@@ -60,10 +60,13 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
-    # "nothing": save only a layer's input (minimum memory). With
-    # prevent_cse=False XLA merges the replay with its forward twin:
-    # step.remat_share reads 0.00-2.44% of busy time in the dense cells
-    # and the OLMoE cell, 10.53% in the Mixtral cell (PERF.md §5).
+    # "nothing": of what XLA computes, save only a layer's input. With
+    # prevent_cse=False that is all, and XLA merges the replay with its
+    # forward twin (step.remat_share reads 0.00-2.57% of busy time in the
+    # dense cells and the OLMoE cell, PERF.md §5). With the barrier, where
+    # a replay is executed, also what a Pallas forward kernel wrote for its
+    # own backward (KERNEL_RESIDUALS), so that no replay runs a forward
+    # kernel a second time.
     # "dots": save matmul outputs, recompute only elementwise — moves
     # memory, not time, where nothing is replayed.
     remat_policy: str = "nothing"
@@ -144,10 +147,37 @@ CONFIGS: Dict[str, LlamaConfig] = {
 }
 
 
+# The values a layer's replay does not make again: a Pallas forward kernel's
+# outputs that its own backward kernels read, tagged where the custom-vjp
+# forward rule returns them (``jax.ad_checkpoint.checkpoint_name`` in
+# ops/attention.py, ops/kda.py and models/hyper_connections.py). A value is
+# here by one rule: keeping it deletes a replayed kernel call. Under
+# ``nothing_saveable`` a replay runs each forward rule whole, so the kernel
+# that wrote o and lse (or o and the per-chunk states) runs twice a layer only
+# to hand its backward what it had already written once. The ring's rule
+# (ops/ring_attention.py) is not tagged: its cell has no memory to spare and
+# replays 1% of its step.
+KERNEL_RESIDUALS = ("flash_o", "flash_lse", "kda_o", "kda_states",
+                    "hc_read", "hc_maps", "hc_write")
+# One object for every caller: JAX caches a jitted function's partial
+# evaluation by the policy's identity, and a second ``_through`` (xing4.py's
+# module) with a policy of its own would lower every jitted kernel entry's
+# body again.
+_KEEP_KERNEL_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    *KERNEL_RESIDUALS)
+
+
 def remat_policy(cfg: LlamaConfig):
     if cfg.remat_policy == "dots":
         return jax.checkpoint_policies.checkpoint_dots
-    return jax.checkpoint_policies.nothing_saveable
+    if not cfg.remat_prevent_cse:
+        # Without the barrier no replay is executed (XLA merges it with its
+        # forward twin), so there is no kernel call to delete, and the names
+        # cost memory all the same: the compiled step's temporaries rose by
+        # every layer's o, 4.90 -> 5.15 GiB in mistral-7b-l4.short2k and
+        # 5.50 -> 5.59 in the OLMoE cell (AOT compiles for v5e, PR 47).
+        return jax.checkpoint_policies.nothing_saveable
+    return _KEEP_KERNEL_RESIDUALS
 
 
 def weight_init(cfg: LlamaConfig, default=nn.initializers.lecun_normal()):

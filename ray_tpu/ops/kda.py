@@ -66,8 +66,9 @@ they were 11 of a 19 ms call at 16k tokens and 32 heads of 128, at two 7 of
 interleave, did not overlap: a chain's latency is not hidden, it is shared.)
 At a chunk's first step the kernel takes G for all heads in one matmul.
 Called under a gradient it also writes the state at every chunk's start (256
-chunks x 32 heads x 64 KiB = 512 MiB a layer at 16k tokens, alive inside
-one layer's backward pass under the decoder's per-layer remat). The backward
+chunks x 32 heads x 64 KiB = 512 MiB a layer at 16k tokens, which the
+decoder's remat policy keeps from the forward pass to the layer's backward,
+so that the replay does not run this kernel again). The backward
 kernel walks the chunks in reverse with the states' cotangents in VMEM and
 differentiates ``_normed_chunk`` of the stacked pair where it stands
 (``jax.vjp`` inside the kernel, from the saved states: a replay of one chunk,
@@ -85,6 +86,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -508,10 +510,13 @@ def _kda_pallas(q, k, v, g, beta, gate, weight, heads, norm):
     dv], T a whole number of chunks; q and k raw, ``norm`` as
     ``_normed_chunk`` takes it.
 
-    Called outside a gradient it writes no states: a call that did would be
-    the twin of the one a remat replay makes, XLA would merge the two, and
-    every layer's 512 MiB of states would live from the forward pass to the
-    backward."""
+    Called outside a gradient it writes no states, because nobody reads
+    them. Under a gradient the forward rule writes them and names them and o
+    (``checkpoint_name``): a remat policy that keeps both names
+    (models/llama.py KERNEL_RESIDUALS) holds a layer's 512 MiB of states and
+    128 MiB of o from the forward pass to its backward and its replay runs
+    no forward kernel; one that keeps neither runs the kernel again in the
+    replay, and the states live for that layer's backward pass alone."""
     return _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm,
                            states=False)[0]
 
@@ -519,6 +524,10 @@ def _kda_pallas(q, k, v, g, beta, gate, weight, heads, norm):
 def _kda_pallas_fwd(q, k, v, g, beta, gate, weight, heads, norm):
     o, states = _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm,
                                 states=True)
+    # Named for the remat policy (models/llama.py KERNEL_RESIDUALS): the
+    # backward reads the states alone, and o is kept with them because a
+    # replay that has to make o runs this kernel whatever else it holds.
+    o, states = checkpoint_name(o, "kda_o"), checkpoint_name(states, "kda_states")
     return o, (q, k, v, g, beta, gate, weight, states)
 
 

@@ -193,11 +193,12 @@ def test_a_profile_names_each_flash_kernel_of_a_remat_step_by_its_own_name(
         if 'custom_call_target="tpu_custom_call"' in line:
             mixer = re.search(r"/layers_\d/(attn|swa)/", line).group(1)
             named.setdefault(mixer, []).append(kernel_name(line))
+    # One forward a layer: the replay holds none (models/llama.py
+    # KERNEL_RESIDUALS keeps what it wrote).
     assert sorted(named["attn"]) == [
-        "_bwd_dkv_kernel", "_bwd_dq_kernel", "_fwd_kernel", "_fwd_kernel"]
+        "_bwd_dkv_kernel", "_bwd_dq_kernel", "_fwd_kernel"]
     assert sorted(named["swa"]) == [
-        "_bwd_dkv_window_kernel", "_bwd_dq_window_kernel",
-        "_fwd_window_kernel", "_fwd_window_kernel"]
+        "_bwd_dkv_window_kernel", "_bwd_dq_window_kernel", "_fwd_window_kernel"]
 
 
 # mixtral-small: b2 x s2048 tokens x top-2 pairs padded to 128-row tiles
@@ -468,8 +469,9 @@ def test_kimi_linears_step_leaves_no_norm_over_a_heads_channels_to_xla(kimi_line
     [1, 16384, 32, 128] float32 array over a head's channels token by token
     (before the kernels took them: 36, forward, replay and backward of four
     layers; the sums over tokens that are left are the gradients of the
-    decay's per-head parameters), and the scan's call sites are still twelve,
-    a forward, its replay and a backward a layer."""
+    decay's per-head parameters), and the scan's call sites are eight, a
+    forward and a backward a layer: the replay holds none, the remat policy
+    keeps o and the states."""
     import re
 
     from benchmarks.lib import checks
@@ -483,7 +485,7 @@ def test_kimi_linears_step_leaves_no_norm_over_a_heads_channels_to_xla(kimi_line
         text)
     assert reduced and all("1" in dims.split(", ") for dims in reduced), reduced
     counts = checks.count_pallas_kernels(text, ("_kda_fwd_kernel", "_kda_bwd_kernel"))
-    assert counts == {"_kda_fwd_kernel": 8, "_kda_bwd_kernel": 4}
+    assert counts == {"_kda_fwd_kernel": 4, "_kda_bwd_kernel": 4}
 
 
 # One of Xing4's hyper-connections at the benchmark's real size: four streams
@@ -527,11 +529,14 @@ def test_a_hyper_connections_kernel_compiles_for_v5e(v5e, kernel):
 def test_xing4s_step_calls_each_hyper_connection_kernel_once_a_connection(v5e):
     """Two layers and the module's, at the rehearsal's widths (128 channels)
     over 256 tokens, which tile: six hyper-connections, each a read and a
-    write forward, the read's replay, the write's where the same layer reads
-    again, and the three backward kernels. A backward kernel's body stands
-    once in the text, behind its jitted entry; a forward one's twice, the
-    forward pass's and the replay's (remat's partial evaluation copies a
-    jitted function's jaxpr, once for all its replays). Every call
+    write forward and the three backward kernels; no replay holds a read or
+    a write, the remat policy keeps what they wrote (models/llama.py
+    KERNEL_RESIDUALS). A backward kernel's body stands once in the text,
+    behind its jitted entry; a forward one's twice, a layer's first
+    connection's and its second's, which remat's partial evaluation tells
+    apart because the second's streams are a kept value; with one policy
+    object for every ``_through`` the module's layer shares them
+    (``models.llama._KEEP_KERNEL_RESIDUALS``). Every call
     is under /hc/pre/ or /hc/post/, where the benchmark's model.hc_share and
     model.hc_roofline look for it."""
     import importlib
@@ -564,9 +569,8 @@ def test_xing4s_step_calls_each_hyper_connection_kernel_once_a_connection(v5e):
         ).as_text(debug_info=True)
     connections = 2 * (config["num_hidden_layers"] + config["num_nextn_predict_layers"])
     # entry: (kernel, bodies, calls, scope)
-    entries = {"_pre_fwd": ("_hc_pre_fwd_kernel", 2, 2 * connections, "/hc/pre/"),
-               "_post_fwd": ("_hc_post_fwd_kernel", 2, connections + connections // 2,
-                             "/hc/post/"),
+    entries = {"_pre_fwd": ("_hc_pre_fwd_kernel", 2, connections, "/hc/pre/"),
+               "_post_fwd": ("_hc_post_fwd_kernel", 2, connections, "/hc/post/"),
                "_post_bwd": ("_hc_post_bwd_kernel", 1, connections, "/hc/post/"),
                "_pre_sums": ("_hc_pre_sums_kernel", 1, connections, "/hc/pre/"),
                "_pre_bwd": ("_hc_pre_bwd_kernel", 1, connections, "/hc/pre/")}
