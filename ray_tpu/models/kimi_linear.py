@@ -8,14 +8,19 @@ the benchmark's configuration file lists what was assumed). No layer has
 a rotary embedding: KDA carries position in its state and MLA runs
 without one (``mla_use_nope``).
 
-KDA (``KDAMixer``): q, k, v projections, each through a causal depthwise
-convolution of 4 taps and SiLU; q and k L2-normalised per head; a
-per-channel log-decay g = -exp(A_log) softplus(W_f2 W_f1 x + dt_bias)
-through a low-rank map of the head dim; a write strength beta = sigmoid(W_b
-x) per head; the recurrence; a per-head RMSNorm gated by sigmoid(W_g2 W_g1
-x); the output projection. The three normalisations over a head's channels
-and the output gate are ``ops/kda.py``'s, on the blocks its kernels hold:
-the mixer hands it q and k raw and takes o normalised and gated.
+KDA (``KDAMixer``, with its fields in ``KDAConfig``: the one KDA mixer, of
+this model and of every other KDA hybrid, ``solar_open2.py``'s among them,
+whatever its full-attention layers are): q, k, v projections, each through a
+causal depthwise convolution of 4 taps and SiLU; q and k L2-normalised per
+head; a per-channel log-decay g = -exp(A_log) softplus(W_f2 W_f1 x +
+dt_bias) through a low-rank map of the head dim; a write strength per head,
+whose range is the configuration's: beta = sigmoid(W_b x) in (0, 1) here
+(Kimi-Linear), 2 sigmoid(W_b x) in (0, 2) where ``kda_allow_neg_eigval`` is
+set (a transition I - beta k k^T with an eigenvalue 1 - beta in (-1, 1));
+the recurrence; a per-head RMSNorm gated by sigmoid(W_g2 W_g1 x); the
+output projection. The three normalisations over a head's channels and the
+output gate are ``ops/kda.py``'s, on the blocks its kernels hold: the mixer
+hands it q and k raw and takes o normalised and gated.
 
 MLA (``mla.py``'s ``MLAMixer``, shared with ``sarvam_mla.py``): q heads of
 128 + 64; keys and values from a shared latent of 512 and one 64-wide key
@@ -43,12 +48,22 @@ from .mla import MLAConfig, MLAMixer
 
 
 @dataclass(frozen=True)
-class KimiLinearConfig(MLAConfig):
-    # Each layer's (mixer, ffn): "kda" or "mla", "mlp" or "moe".
-    layer_kinds: Tuple[Tuple[str, str], ...] = ()
+class KDAConfig:
+    """What ``KDAMixer`` reads of a config beside the decoder's own fields
+    (hidden size, dtypes, ``rms_eps``, the initialiser): a family with KDA
+    layers puts this before its decoder's config among its bases."""
     kda_num_heads: int = 32
     kda_head_dim: int = 128
     short_conv_kernel_size: int = 4
+    # The source's key: beta = 2 sigmoid(W_b x), in (0, 2) (fla's
+    # ``beta * 2``), where it is sigmoid(W_b x), in (0, 1).
+    kda_allow_neg_eigval: bool = False
+
+
+@dataclass(frozen=True)
+class KimiLinearConfig(KDAConfig, MLAConfig):
+    # Each layer's (mixer, ffn): "kda" or "mla", "mlp" or "moe".
+    layer_kinds: Tuple[Tuple[str, str], ...] = ()
     router_score: str = "sigmoid"
     moe_dispatch: str = "gmm"
     remat_policy: str = "nothing"
@@ -128,8 +143,11 @@ class NormWeight(nn.Module):
 
 
 class KDAMixer(nn.Module):
-    # One device's: the recurrence is not sharded over the sequence.
-    cfg: KimiLinearConfig
+    """The gated delta-rule mixer of a layer, whatever the model's other
+    layers are. One device's: the recurrence is not sharded over the
+    sequence."""
+    # A decoder's config with ``KDAConfig`` among its bases.
+    cfg: KDAConfig
 
     @nn.compact
     def __call__(self, x, positions):
@@ -161,6 +179,8 @@ class KDAMixer(nn.Module):
                 self.param("dt_bias", _dt_bias_init, (H * d,), f32).reshape(H, d),
             )
             beta = jax.nn.sigmoid(b)
+            if cfg.kda_allow_neg_eigval:
+                beta = 2.0 * beta
         gate = _dense(cfg, H * d, "g_b_proj", use_bias=True)(
             _dense(cfg, d, "g_a_proj")(x)
         )

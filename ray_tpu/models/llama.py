@@ -120,7 +120,8 @@ class AttentionKind:
     num_heads: int
     # One frequency a pair of turning channels (``rope_frequencies`` or a
     # scaled table): the leading ``2 * len(freqs)`` channels of a head turn,
-    # the whole head where that is its width.
+    # the whole head where that is its width. None: nothing turns (a layer
+    # that learns positions from the layers beside it).
     freqs: Any
     # cos and sin times this (YaRN's ``attention_factor``).
     rope_amplitude: float = 1.0
@@ -128,6 +129,9 @@ class AttentionKind:
     window: Optional[int] = None
     # o_h times sigmoid(x W_g)_h, one gate a head and token, before o_proj.
     gate: bool = False
+    # The gate has q's width, W_g [hidden, heads, head dim]: one value a
+    # channel of every head, where it is one a head.
+    gate_channels: bool = False
 
 
 CONFIGS: Dict[str, LlamaConfig] = {
@@ -232,8 +236,8 @@ class RMSNorm(nn.Module):
 
 class Attention(nn.Module):
     """Softmax attention over grouped K/V heads. What the layer is beyond
-    the shared widths (its head count, its rotation, a window, an output
-    gate) it learns from the config by its own flax name
+    the shared widths (its head count, its rotation or none, a window, an
+    output gate and its width) it learns from the config by its own flax name
     (``cfg.attention``): a Llama or Mistral layer is all one kind."""
     cfg: LlamaConfig
 
@@ -258,18 +262,22 @@ class Attention(nn.Module):
                 q, k = norm(q, "q_norm"), norm(k, "k_norm")
         # [B, T, H, D] -> [B, H, T, D]
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        with tracing.scope(tracing.ATTN_ROPE):
-            turn = lambda t: _rope(  # noqa: E731
-                t, positions, kind.freqs, leading=True,
-                amplitude=kind.rope_amplitude,
-            )
-            q, k = turn(q), turn(k)
+        if kind.freqs is not None:
+            with tracing.scope(tracing.ATTN_ROPE):
+                turn = lambda t: _rope(  # noqa: E731
+                    t, positions, kind.freqs, leading=True,
+                    amplitude=kind.rope_amplitude,
+                )
+                q, k = turn(q), turn(k)
         o = flash_attention(q, k, v, causal=True, window=kind.window)
         o = o.transpose(0, 2, 1, 3)  # [B, T, H, D]
         if kind.gate:
             with tracing.scope(tracing.ATTN_GATE):
-                gate = nn.sigmoid(dense(kind.num_heads, "g_proj")(x).astype(jnp.float32))
-                o = o * gate[..., None].astype(o.dtype)
+                width = (kind.num_heads, hd) if kind.gate_channels else kind.num_heads
+                gate = nn.sigmoid(dense(width, "g_proj")(x).astype(jnp.float32))
+                if not kind.gate_channels:
+                    gate = gate[..., None]
+                o = o * gate.astype(o.dtype)
         out = nn.DenseGeneral(
             cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),

@@ -5,10 +5,19 @@ Per head, with a float32 state S in R^{dk x dv}:
     S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T
     o_t = S_t^T q_t
 
-``a_t = exp(g_t)`` in (0, 1]^dk is the decay and ``b_t`` in (0, 1) the
-write strength. ``chunk_kda`` computes it in chunks of 64 tokens: inside a
-chunk the rule is a unit lower-triangular system (the WY form of the
-products of Householder-like factors), between chunks the state is carried.
+``a_t = exp(g_t)`` in (0, 1]^dk is the decay and ``b_t`` the write
+strength, whose range is the configuration's: (0, 1) where the mixer takes a
+sigmoid (Kimi-Linear), (0, 2) where it doubles it (``kda_allow_neg_eigval``,
+Solar-Open2: I - b k k^T then has the eigenvalue 1 - b in (-1, 1) along a
+unit k). Nothing here assumes b < 1: with unit keys the entries of the
+chunk's system are at most b in size, so up to 2, its inverse by doubling is
+exact at every level for any strictly lower-triangular A, and what the
+rounding of X to the matmuls' dtype at five levels costs grows with b by
+under a factor of two (PERF.md, Findings, PR 48; tests/test_kda_op.py holds
+the kernels to the recurrence over (0, 2)). ``chunk_kda`` computes it in
+chunks of 64 tokens: inside a chunk the rule is a unit lower-triangular
+system (the WY form of the products of Householder-like factors), between
+chunks the state is carried.
 
 Who normalises: this file. The mixer's three normalisations over a head's
 channels are sums over the lanes of a row the scan already holds, so they
@@ -100,7 +109,8 @@ _PAIR = 128 // CHUNK
 F32 = jnp.float32
 # What a kernel may take of a v5e core's 128 MiB of VMEM (Mosaic's default
 # lets it use 16): the running sums of every head are 4 MiB at 32 heads of
-# 128, their cotangents as much, the states 2 MiB.
+# 128, their cotangents as much, the states 2 MiB; twice each at 64 heads,
+# where g's block of every head is 2 MiB more and its cotangent's another.
 _VMEM_LIMIT = 64 * 2**20
 
 
@@ -580,7 +590,8 @@ def chunk_kda(q, k, v, g, beta, gate, weight, *, scale, rms_eps, l2_eps=1e-6):
     dtype, the matmuls'; o is normalised and gated where the forward kernel
     holds it in float32 and rounded once, to v's dtype, as it is stored. v,
     gate [B, T, H, dv], the gate before its sigmoid; g [B, T, H, dk] float32
-    log-decay (<= 0); beta [B, T, H] in (0, 1). Returns [B, T, H, dv].
+    log-decay (<= 0); beta [B, T, H] in (0, 1) or, where the mixer doubles
+    its sigmoid, in (0, 2). Returns [B, T, H, dv].
     Differentiable in all seven; the cotangents of q and k are those of the
     raw ones."""
     batch, t, heads, _ = q.shape

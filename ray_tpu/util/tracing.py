@@ -51,20 +51,20 @@ MOE_LAYOUT = "layout"  # inside dispatch, "gmm" only: sort, tile layout, inverse
 QK_NORM = "qk_norm"  # RMSNorm of q and k: the whole projections in attn (cfg.qk_norm), a head's channels inside mla (cfg.qk_head_norm)
 MOE_SHARED = "shared"  # inside moe: the shared expert every token passes
 # The mixers' flax names, which reach op_name as the attention's "attn" does.
-KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer)
+KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer; Solar-Open2's KDA layers too)
 MLA = "mla"  # the latent-attention mixer (models/mla.py MLAMixer)
 KDA_CONV = "conv"  # inside kda: the short convolutions of q, k, v and their SiLU
-KDA_GATE = "gate"  # inside kda: the log-decay g and the write strength beta
+KDA_GATE = "gate"  # inside kda: the log-decay g and the write strength beta, with its doubling where the config writes in (0, 2)
 KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's and o's norms and the output gate), v's rounding, beta's transpose
 # (No "out_norm": o's per-head RMSNorm and output gate left XLA for the scan's
 # kernels, and a scope that no operation carries is not in this list.)
 MLA_LATENT = "latent"  # inside mla: down-projection, norm, up-projection of K/V
 MLA_ROPE = "rope"  # inside mla (cfg.mla_rope): the frequency table, the rotation of q's and k's pe parts, the slices and concatenations around it
 MLA_Q_LATENT = "q_latent"  # inside mla (cfg.q_lora_rank): q's down-projection, its norm, the up-projection to the heads
-ATTN = "attn"  # the softmax-attention mixer (models/llama.py Attention); in a model whose layers differ, the full-attention layers'
+ATTN = "attn"  # the softmax-attention mixer (models/llama.py Attention); in a model whose layers differ, the full-attention layers' (Laguna's beside swa, Solar-Open2's beside kda)
 SWA = "swa"  # the same module as a sliding-window layer's mixer (models/laguna.py): its own head count and rotation, flash_attention under a window
-ATTN_ROPE = "rotary"  # inside attn and swa: the angles, cos and sin, the rotation of q and of k (a part of each head where the layer's kind says so)
-ATTN_GATE = "out_gate"  # inside attn and swa (a kind with a gate): the gate's projection, its sigmoid, the product with each head's output
+ATTN_ROPE = "rotary"  # inside attn and swa: the angles, cos and sin, the rotation of q and of k (a part of each head where the layer's kind says so); not opened by a kind that turns nothing
+ATTN_GATE = "out_gate"  # inside attn and swa (a kind with a gate): the gate's projection (one value a head, or of q's width), its sigmoid, the product with each head's output
 HC = "hc"  # a hyper-connection (models/hyper_connections.py), twice a layer: the three maps of the streams, the read before the sublayer, the write after it
 HC_PRE = "pre"  # inside hc: the streams' rms, x~ Phi, the three logits, H_pre and H_post, the read u = sum H_pre[i] X[i]
 HC_SINKHORN = "sinkhorn"  # inside hc: exp, the iterations of rows and columns, and their backward

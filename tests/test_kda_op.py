@@ -6,7 +6,11 @@ several sequence lengths and at a decay near 0 and near 1; the Pallas scan
 kernels in interpret mode against the same, two heads a grid step and, where
 the heads do not pair off, one; a head through the pair path against the
 same head alone, bit for bit; rows of zeros; the weight's gradient over
-batch rows; the short convolution and the gate beside it.
+batch rows; the short convolution and the gate beside it. The write strength
+runs over (0, 1) and, as a configuration with negative eigenvalues doubles
+it, over (0, 2): the kernels against the recurrence there, the state they
+carry against the recurrence's own, what a cap at 1 or a doubling left out
+would give, and the bfloat16 matmuls' distance from float32.
 """
 import functools
 
@@ -62,21 +66,26 @@ def recurrence(q, k, v, g, beta):
         return jax.vmap(heads)(q, k, v, g, beta)
 
 
-def inputs(t, decay, seed=0, heads=H):
+def inputs(t, decay, seed=0, heads=H, beta_max=1.0):
     """q, k raw, as the mixer's SiLU leaves them; g = -decay x uniform(0.5,
     1.5): exp(g) is near 1 at decay 1e-3 and under 1e-6 at decay 30; the
-    output gate before its sigmoid and the norm's weight."""
+    output gate before its sigmoid and the norm's weight. beta is a sigmoid
+    in (0, 1), or ``beta_max`` times one of logits three times as wide, so
+    that at 2 it passes 1.9 and falls under 0.1."""
     r = np.random.default_rng(seed)
     draw = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)  # noqa: E731
     q, k = draw(B, t, heads, DK), draw(B, t, heads, DK)
     v = draw(B, t, heads, DV)
     g = -jnp.asarray(r.uniform(0.5, 1.5, size=(B, t, heads, DK)), jnp.float32) * decay
-    beta = jax.nn.sigmoid(draw(B, t, heads))
+    logits = draw(B, t, heads)
+    beta = jax.nn.sigmoid(logits if beta_max == 1.0 else 3.0 * logits) * beta_max
     return q, k, v, g, beta, draw(B, t, heads, DV), 1.0 + 0.3 * draw(DV)
 
 
-def compare(t, decay, heads=H, args=None):
-    args = args or inputs(t, decay, heads=heads)
+def compare(t, decay, heads=H, args=None, beta_max=1.0):
+    args = args or inputs(t, decay, heads=heads, beta_max=beta_max)
+    if beta_max > 1.0:
+        assert float(args[4].max()) > 1.9 and float(args[4].min()) < 0.1
     w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
     want = oracle(*args)
     got = jax.jit(fresh())(*args)
@@ -99,13 +108,106 @@ def test_chunked_form_and_its_vjp_are_the_recurrence(t, decay):
 
 
 @pytest.mark.parametrize("heads", [2, 3], ids=["pair", "odd"])
-@pytest.mark.parametrize("t,decay", [(100, 0.3), (256, 1e-3), (192, 30.0)])
-def test_pallas_kernels_in_interpret_mode_are_the_recurrence(monkeypatch, t, decay, heads):
+@pytest.mark.parametrize("t,decay,beta_max", [
+    (100, 0.3, 1.0), (256, 1e-3, 1.0), (192, 30.0, 1.0),
+    # beta = 2 sigmoid, over (0, 2): a weak decay, where the chunk's system
+    # is furthest from the identity, and a length with a padded chunk
+    (256, 1e-3, 2.0), (100, 0.3, 2.0),
+], ids=["100-0.3", "256-0.001", "192-30.0", "256-0.001-beta<2", "100-0.3-beta<2"])
+def test_pallas_kernels_in_interpret_mode_are_the_recurrence(
+        monkeypatch, t, decay, beta_max, heads):
     """The forward kernel and, under its ``custom_vjp``, the backward kernel
     that differentiates ``_head_chunk`` where it stands: two heads a grid
-    step, and three heads one a step."""
+    step, and three heads one a step; the write strength in (0, 1) and in
+    (0, 2)."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    compare(t, decay, heads)
+    compare(t, decay, heads, beta_max=beta_max)
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+@pytest.mark.parametrize("wrong", ["capped", "undoubled"])
+def test_a_write_strength_capped_at_one_or_left_undoubled_is_another_function(
+        monkeypatch, path, wrong):
+    """With beta over (0, 2) the chunked form is the recurrence at that beta
+    and not at min(beta, 1) nor at beta / 2: nothing inside clips it."""
+    if path == "pallas":
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    q, k, v, g, beta, gate, weight = inputs(128, 1e-3, beta_max=2.0)
+    got = jax.jit(fresh())(q, k, v, g, beta, gate, weight)
+    scale = float(jnp.abs(got).max())
+    np.testing.assert_allclose(
+        got, oracle(q, k, v, g, beta, gate, weight), rtol=2e-4, atol=2e-5 * scale)
+    other = jnp.minimum(beta, 1.0) if wrong == "capped" else beta / 2
+    far = oracle(q, k, v, g, other, gate, weight)
+    assert float(jnp.abs(got - far).max()) > 0.1 * scale
+
+
+def states_of_the_recurrence(k, v, g, beta):
+    """The state before token t for every t, [B, T, H, dk, dv]."""
+    def one(k, v, g, beta):
+        def step(S, x):
+            k, v, g, b = x
+            S_next = jnp.exp(g)[:, None] * S
+            S_next = S_next + b * jnp.outer(k, v - S_next.T @ k)
+            return S_next, S
+
+        return jax.lax.scan(step, jnp.zeros((DK, DV)), (k, v, g, beta))[1]
+
+    heads = jax.vmap(one, in_axes=(1, 1, 1, 1), out_axes=1)
+    with jax.default_matmul_precision("highest"):
+        return jax.vmap(heads)(k, v, g, beta)
+
+
+@pytest.mark.parametrize("beta_max", [1.0, 2.0], ids=["beta<1", "beta<2"])
+def test_the_state_the_kernel_carries_is_the_recurrences(monkeypatch, beta_max):
+    """The forward kernel under a gradient writes the state at every chunk's
+    start (transposed, [dv, dk] a head): it is the token-by-token
+    recurrence's state before that chunk's first token, also where beta
+    passes 1 and a write overshoots what the key held (an eigenvalue 1 -
+    beta below zero)."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    t = 256
+    q, k, v, g, beta, gate, weight = inputs(t, 0.02, beta_max=beta_max)
+    flat = lambda x: x.reshape(B, t, -1)  # noqa: E731
+    _, states = kda._forward_pallas(
+        flat(q), flat(k), flat(v), flat(g), beta.transpose(0, 2, 1)[..., None],
+        flat(gate), weight[None], H, (SCALE, 1e-6, RMS_EPS), states=True)
+    want = states_of_the_recurrence(kda.l2norm(k), v, g, beta)[:, ::kda.CHUNK]
+    got = states.reshape(B, t // kda.CHUNK, DV, H, DK).transpose(0, 1, 3, 4, 2)
+    assert not np.asarray(got[:, 0]).any() and float(jnp.abs(want[:, -1]).max()) > 0.1
+    np.testing.assert_allclose(
+        got, want, rtol=2e-4, atol=2e-5 * float(jnp.abs(want).max()))
+
+
+def test_bfloat16_matmuls_hold_with_a_write_strength_up_to_two(monkeypatch):
+    """The inverse by doubling rounds X to the matmuls' dtype at five levels
+    and the chunk's system has entries up to beta in size: with bfloat16
+    operands, keys that repeat (eight directions and a little noise, so that
+    k_t k_s is near 1 inside a chunk) and next to no decay, the kernels'
+    output and gradients stay within a few hundredths of the float32
+    recurrence's, at beta in (0, 2) as at beta in (0, 1), and finite."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    t = 128
+    rel = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))  # noqa: E731
+    for beta_max, limit in ((1.0, 0.03), (2.0, 0.06)):
+        q, k, v, g, beta, gate, weight = inputs(t, 1e-3, beta_max=beta_max)
+        r = np.random.default_rng(7)
+        base, at = r.normal(size=(8, H, DK)), r.integers(0, 8, size=(B, t))
+        k = jnp.asarray(base[at] + 0.05 * r.normal(size=k.shape), jnp.float32)
+        q = jnp.asarray(base[at] + 0.05 * r.normal(size=q.shape), jnp.float32)
+        w = jnp.asarray(r.normal(size=v.shape), jnp.float32)
+        args = (q, k, v, g, beta, gate, weight)
+        run = lambda f, v_dtype: jax.jit(jax.value_and_grad(  # noqa: E731
+            lambda q, k, g, beta: jnp.sum(f(
+                q, k, v.astype(v_dtype), g, beta, gate, weight
+            ).astype(jnp.float32) * w), argnums=(0, 1, 2, 3)))(q, k, g, beta)
+        got = jax.jit(fresh())(q, k, v.astype(jnp.bfloat16), *args[3:])
+        assert got.dtype == jnp.bfloat16 and bool(jnp.isfinite(got).all())
+        assert rel(got, oracle(*args)) < limit
+        (_, grads), (_, wanted) = run(chunk_kda, jnp.bfloat16), run(oracle, jnp.float32)
+        for name, a, b in zip("q k g beta".split(), grads, wanted):
+            assert bool(jnp.isfinite(a).all()), name
+            assert rel(a, b) < 2 * limit, (beta_max, name, rel(a, b))
 
 
 def test_a_head_through_the_pair_path_is_the_head_alone_bit_for_bit(monkeypatch):

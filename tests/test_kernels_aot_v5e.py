@@ -80,6 +80,7 @@ TWO_BODY_SHAPES = [
     (32, 2048, 4096, 128, 128, 1024),    # tq < tk: a prefill chunk behind a cache
     (32, 2048, 3000, 128, 128, 1024),    # tq < tk and a padded last key block
     (64, 3000, 3000, 192, 128, 1024),    # padded rows and keys at 192/128
+    (64, 4096, 4096, 128, 128, 1024),    # Solar-Open2's GQA layer: K/V repeated 8 -> 64
 ]
 
 
@@ -139,6 +140,28 @@ def test_windowed_kernels_lower_under_names_of_their_own(v5e, monkeypatch):
     assert "window" not in causal
     # K and V reach the windowed kernels at their own 8 heads
     assert "8x2048x128xbf16" in windowed and "64x2048x128xbf16" in causal
+
+
+def test_grouped_query_attention_at_64_over_8_lowers_to_the_causal_kernels(
+        v5e, monkeypatch):
+    """Solar-Open2's GQA layer, 64 q heads over 8 K/V heads of 128 at 4,096
+    tokens, through ``flash_attention`` under a gradient: K and V reach the
+    causal kernels repeated to q's heads, each K/V head serving 8, and the
+    step compiles for the chip."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    q = jax.ShapeDtypeStruct((1, 64, 4096, 128), jnp.bfloat16, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((1, 8, 4096, 128), jnp.bfloat16, sharding=v5e)
+    lowered = jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=True).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2),
+    )).lower(q, kv, kv)
+    text = lowered.as_text()
+    for name in ("_fwd", "_bwd_dkv", "_bwd_dq"):
+        assert text.count(f'kernel_name = "{name}_kernel"') == 1
+    assert "64x4096x128xbf16" in text and "window" not in text
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 def test_a_profile_names_each_flash_kernel_of_a_remat_step_by_its_own_name(
@@ -281,9 +304,13 @@ def test_tgmm_over_the_capacity_ffns_trips_compiles_for_v5e(v5e, k, n):
 # The same cell's KDA layers: 32 heads of 128 over 16,384 tokens, q and k
 # raw in float32, the output gate and the norm's weight with them: the
 # forward kernel (with and without the states) and the backward kernel,
-# which differentiates a chunk and its normalisations inside the kernel.
-def test_kda_kernels_compile_for_v5e(v5e):
-    b, t, h, d = 1, 16384, 32, 128
+# which differentiates a chunk and its normalisations inside the kernel. And
+# Solar-Open2's (pretrain-4k): 64 heads of 128 over 4,096 tokens, where every
+# head's running sums are 8 MiB of VMEM, their cotangents as much, the states
+# 4 and g's block of every head 2, under the kernels' 64 MiB.
+@pytest.mark.parametrize("t,h", [(16384, 32), (4096, 64)])
+def test_kda_kernels_compile_for_v5e(v5e, t, h):
+    b, d = 1, 128
     raw, rows = ((b, t, h * d), jnp.float32), ((b, t, h * d), jnp.bfloat16)
     operands = (raw, raw, rows, raw, ((b, h, t, 1), jnp.float32), rows,
                 ((1, d), jnp.float32))

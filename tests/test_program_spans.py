@@ -245,6 +245,39 @@ def laguna_paths():
         del os.environ["RAY_TPU_PALLAS_INTERPRET"]
 
 
+@pytest.fixture(scope="module")
+def solar_paths():
+    """Paths of a tiny Solar-Open2's compiled train step: a softmax layer
+    without rotation under a gate of q's width, then a KDA layer that doubles
+    its write strength, each over the expert layer with its shared expert."""
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+    from ray_tpu.models.solar_open2 import (
+        SolarOpen2ForCausalLM, solar_open2_config,
+    )
+
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"  # read when gmm is traced
+    try:
+        cfg = solar_open2_config(
+            linear_attn_config={"num_heads": 2, "head_dim": 16,
+                                "short_conv_kernel_size": 4, "num_kv_heads": None},
+            gqa_layers=[0, 4], first_k_dense_replace=0, num_layers=2,
+            num_experts_held=2, vocab_size=128, hidden_size=32,
+            intermediate_size=64, moe_intermediate_size=16, num_heads=4,
+            num_kv_heads=2, head_dim=16, num_experts=10, num_experts_per_tok=2,
+            num_shared_experts=1, use_rope=False, use_gqa_gate=True,
+            kda_allow_neg_eigval=True,
+        )
+        model = SolarOpen2ForCausalLM(cfg)
+        ids = jnp.zeros((1, 64), jnp.int32)
+        return paths_of(compiled_step(
+            model,
+            lambda p, i, t: chunked_causal_lm_loss(model, p, i, t, chunk_size=32),
+            ids,
+        ))
+    finally:
+        del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+
+
 # ----------------------------------------------------------- in-graph scopes
 
 
@@ -329,6 +362,30 @@ def test_full_and_sliding_mixers_carry_their_names_rotation_and_gate(
     assert any(f"/attn/{tracing.ATTN_ROPE}/" in p for p in llama_paths)
     assert not [p for p in llama_paths
                 if f"/{tracing.ATTN_GATE}/" in p or f"/{tracing.SWA}/" in p]
+
+
+def test_a_kda_hybrid_over_unrotated_gated_attention_carries_its_scopes(solar_paths):
+    """What model.gqa_share, model.attn_gate_share and model.kda_share select
+    by in a model whose full layers are ``Attention`` of a kind that turns
+    nothing: /attn/ with its ``out_gate`` (the gate's projection inside) and
+    no ``rotary`` scope anywhere; /kda/ with ``conv``, ``gate`` (beta's
+    doubling lies there) and ``scan``; the expert layer's scopes in both."""
+    attn = [p for p in solar_paths if "/layers_0/attn/" in p]
+    kda = [p for p in solar_paths if "/layers_1/kda/" in p]
+    assert not [p for p in solar_paths if f"/{tracing.ATTN_ROPE}/" in p]
+    assert not [p for p in solar_paths
+                if "/layers_0/kda/" in p or "/layers_1/attn/" in p or "/mla/" in p]
+    assert {pass_of(p) for p in attn if f"/attn/{tracing.ATTN_GATE}/" in p} >= {
+        "forward", "backward"}
+    assert any(f"/attn/{tracing.ATTN_GATE}/g_proj/" in p for p in attn)
+    assert any("/attn/q_proj/" in p for p in attn)
+    for name in (tracing.KDA_CONV, tracing.KDA_GATE, tracing.KDA_SCAN):
+        assert any(f"/kda/{name}/" in p for p in kda), name
+    doubled = [p for p in kda if f"/kda/{tracing.KDA_GATE}/" in p and "mul" in p]
+    assert doubled and {pass_of(p) for p in kda} >= {"forward", "backward", "replay"}
+    for layer in ("layers_0", "layers_1"):
+        for name in (*MOE_SCOPES, tracing.MOE_SHARED):
+            assert any(f"/{layer}/moe/{name}/" in p for p in solar_paths), (layer, name)
 
 
 def test_the_hybrid_carries_its_mixers_names_and_scopes(kimi_paths):
@@ -578,13 +635,13 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 
 def test_names_emitted_are_exactly_the_list(
     llama_paths, qk_norm_paths, moe_paths, kimi_paths, sarvam_paths,
-    xing4_paths, laguna_paths, session_lines, actor_lines
+    xing4_paths, laguna_paths, solar_paths, session_lines, actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + [
+    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + [
         p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:
         assert any(f"/{name}/" in p for p in paths), name
