@@ -157,11 +157,11 @@ CONFIGS: Dict[str, LlamaConfig] = {
 # ops/attention.py, ops/kda.py and models/hyper_connections.py). A value is
 # here by one rule: keeping it deletes a replayed kernel call. Under
 # ``nothing_saveable`` a replay runs each forward rule whole, so the kernel
-# that wrote o and lse (or o and the per-chunk states) runs twice a layer only
-# to hand its backward what it had already written once. The ring's rule
-# (ops/ring_attention.py) is not tagged: its cell has no memory to spare and
-# replays 1% of its step.
-KERNEL_RESIDUALS = ("flash_o", "flash_lse", "kda_o", "kda_states",
+# that wrote o and lse (or o and the per-chunk states and inverses) runs twice
+# a layer only to hand its backward what it had already written once. The
+# ring's rule (ops/ring_attention.py) is not tagged: its cell has no memory to
+# spare and replays 1% of its step.
+KERNEL_RESIDUALS = ("flash_o", "flash_lse", "kda_o", "kda_states", "kda_t",
                     "hc_read", "hc_maps", "hc_write")
 # One object for every caller: JAX caches a jitted function's partial
 # evaluation by the policy's identity, and a second ``_through`` (xing4.py's

@@ -75,18 +75,28 @@ they were 11 of a 19 ms call at 16k tokens and 32 heads of 128, at two 7 of
 interleave, did not overlap: a chain's latency is not hidden, it is shared.)
 At a chunk's first step the kernel takes G for all heads in one matmul.
 Called under a gradient it also writes the state at every chunk's start (256
-chunks x 32 heads x 64 KiB = 512 MiB a layer at 16k tokens, which the
-decoder's remat policy keeps from the forward pass to the layer's backward,
-so that the replay does not run this kernel again). The backward
-kernel walks the chunks in reverse with the states' cotangents in VMEM and
-differentiates ``_normed_chunk`` of the stacked pair where it stands
-(``jax.vjp`` inside the kernel, from the saved states: a replay of one chunk,
-nothing of a chunk's interior ever in HBM), then takes G's cotangent back to
-g's in one matmul; the cotangent of the norm's weight adds up over a batch
-row's steps in the row's output block. Elsewhere the same function runs
-under ``lax.scan``, one head a call, and JAX differentiates it. Matmul
-operands are v's dtype (bfloat16 in the models), accumulation, the
-normalisations, the running sums and the state float32.
+chunks x 32 heads x 64 KiB = 512 MiB a layer at 16k tokens) and every
+chunk's T as it multiplied with it, in the matmuls' dtype, a pair's two [64,
+64] diagonal blocks side by side on 128 lanes (8 KiB a head and chunk, 64
+MiB a layer there: an eighth of the states'); the decoder's remat policy
+keeps both from the forward pass to the layer's backward, so that the replay
+does not run this kernel again. The backward kernel walks the chunks in
+reverse with the states' cotangents in VMEM and differentiates
+``_normed_chunk`` of the stacked pair where it stands (``jax.vjp`` inside
+the kernel, from the saved states: a replay of one chunk's twelve level
+products, its normalisations and its products with T and the state, nothing
+of a chunk's interior but T ever in HBM). The inverse is not replayed and
+not differentiated link by link: ``_unit_lower_inverse`` is handed the T the
+forward wrote, bit for bit what the doubling would remake, and its own rule
+gives A's cotangent as -T^T dT T^T, two matmuls where the chain's ten and
+their twenty gradients stood, exact for the inverse at that T (autodiff gave
+the gradient of the rounded chain: they differ by the order of one rounding
+to the matmuls' dtype). The kernel then takes G's cotangent back to g's in
+one matmul; the cotangent of the norm's weight adds up over a batch row's
+steps in the row's output block. Elsewhere the same function runs under
+``lax.scan``, one head a call, and JAX differentiates it, the inverse by the
+same rule. Matmul operands are v's dtype (bfloat16 in the models),
+accumulation, the normalisations, the running sums and the state float32.
 """
 from __future__ import annotations
 
@@ -216,19 +226,29 @@ def _exact_matmul(m, x, contract):
 # ------------------------------------------- one chunk of the stacked heads
 
 
+def _rows_cols(n):
+    """The row's and the column's index at every entry of an [n, n] matrix."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
 def _masks(n):
     """{b: [n, n] bool}: row t and column s lie in one block of 2b rows, t
     in its second half and s in its first (over the six levels, the strict
     lower triangle of every CHUNK x CHUNK diagonal block once), and the
     diagonal. A block of 2b <= CHUNK rows never spans two of the heads
     stacked on the n rows, so every mask is block-diagonal over heads."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    row, col = _rows_cols(n)
     masks = {}
     for level, b in enumerate(_LEVELS):
         same = (row >> (level + 1)) == (col >> (level + 1))
         masks[b] = same & (((row >> level) & 1) == 1) & (((col >> level) & 1) == 0)
     return masks, row == col
+
+
+def _same_head(row, col):
+    """Rows and columns of stacked heads: of one head's CHUNK."""
+    return row // CHUNK == col // CHUNK
 
 
 def _heads_of(x, p):
@@ -244,13 +264,57 @@ def _per_head(dot, a, b, p):
         [dot(x, y) for x, y in zip(_heads_of(a, p), _heads_of(b, p))])
 
 
-def _head_chunk(St, q, k, v, beta, G, last, last_dv, roll=_xla_roll):
+def _doubling(dt, A, masks, eye):
+    """(I + A)^-1 of a strictly lower-triangular A [n, n] float32 by
+    doubling, in ``dt``: at block size 1 the block diagonal is I, and the
+    inverse X of the block diagonal at block size b gives that of 2b as X -
+    X E X, operands rounded to ``dt`` at every level."""
+    X = jnp.where(eye, 1.0, 0.0) - jnp.where(masks[1], A, 0.0)
+    for b in _LEVELS[1:]:
+        E = jnp.where(masks[b], A, 0.0).astype(dt)
+        X = X - _nn(X.astype(dt), _nn(E, X.astype(dt)).astype(dt))
+    return X.astype(dt)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _unit_lower_inverse(dt, A, masks, eye, T):
+    """T = (I + A)^-1 in ``dt``, of the strict lower triangle of every
+    head's CHUNK x CHUNK diagonal block of A: ``_doubling``, or ``T`` itself
+    where the caller holds the value already (the backward kernel, which
+    reads what the forward kernel multiplied with). Either way its
+    derivative is the inverse's own and not the chain's: from T (I + A) = I,
+    dT = -T dA T, so A's cotangent is -T^T dT T^T, two matmuls that wait for
+    nothing, exact for whatever A gave T. Under autodiff the ten matmuls of
+    the doubling cost twenty more, most of them waiting for the one before."""
+    return _doubling(dt, A, masks, eye) if T is None else T
+
+
+def _unit_lower_inverse_fwd(dt, A, masks, eye, T):
+    T = _unit_lower_inverse(dt, A, masks, eye, T)
+    return T, T
+
+
+def _unit_lower_inverse_bwd(dt, T, dT):
+    row, col = _rows_cols(T.shape[0])
+    # The cross-head blocks of T^T are exact zeros, so a head's numbers are
+    # the sums they are alone; the mask makes what lies outside the entries
+    # T was made from an exact zero whatever dT holds there.
+    lower = (row > col) & _same_head(row, col)
+    dA = _nt(_tn(T, dT.astype(dt)).astype(dt), T)
+    return jnp.where(lower, -dA, 0.0), None, None, None
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _head_chunk(St, q, k, v, beta, G, last, last_dv, roll=_xla_roll, T=None):
     """A chunk of P heads stacked on rows (P = 1: of one head). St [P * dv,
     dk] float32, the states at its start, each transposed so that the decay
     scales lanes; q, k [P * C, dk]; v [P * C, dv]; beta [P * C, 1] float32;
     G [P * C, dk] the running sum of g, ``last`` its last row on C rows a
-    head and ``last_dv`` on dv rows a head. -> (the states at its end, o
-    [P * C, dv]).
+    head and ``last_dv`` on dv rows a head; ``T`` the chunk's inverse where
+    the caller has it (else it is made here). -> (the states at its end, o
+    [P * C, dv], T [P * C, P * C] in the matmuls' dtype).
 
     Every [P * C, P * C] matrix below (A, Aqk, each E and X, T) is
     block-diagonal over heads, its cross-head blocks exact zeros: the masks
@@ -278,23 +342,18 @@ def _head_chunk(St, q, k, v, beta, G, last, last_dv, roll=_xla_roll):
         start = jnp.where(second, roll(start, b), start)
     Aqk = Aqk + jnp.where(eye, _nt(q, k), 0.0)
     A = A * beta
-    # (I + A)^-1 by doubling; at block size 1 the block diagonal is I.
-    X = jnp.where(eye, 1.0, 0.0) - jnp.where(masks[1], A, 0.0)
-    for b in _LEVELS[1:]:
-        E = jnp.where(masks[b], A, 0.0).astype(dt)
-        X = X - _nn(X.astype(dt), _nn(E, X.astype(dt)).astype(dt))
-    T = X.astype(dt)
+    T = _unit_lower_inverse(dt, A, masks, eye, T)
     w = _nn(T, (kf * jnp.exp(G) * beta).astype(dt)).astype(dt)
     u0 = _nn(T, (v.astype(F32) * beta).astype(dt))
     sd = St.astype(dt)
     u = (u0 - _per_head(_nt, w, sd, p)).astype(dt)
     o = _per_head(_nt, (qf * jnp.exp(G)).astype(dt), sd, p) + _nn(Aqk.astype(dt), u)
     kd = (kf * jnp.exp(last - G)).astype(dt)
-    return jnp.exp(last_dv) * St + _per_head(_tn, u, kd, p), o
+    return jnp.exp(last_dv) * St + _per_head(_tn, u, kd, p), o, T
 
 
 def _normed_chunk(St, q, k, v, beta, G, last, last_dv, gate, weight, *, norm,
-                  roll=_xla_roll):
+                  roll=_xla_roll, T=None):
     """``_head_chunk`` between the mixer's normalisations, each over a row
     (a head's channels of one token) in float32. q and k come as the
     convolution and SiLU leave them: L2-normalised, q scaled, then the one
@@ -307,9 +366,9 @@ def _normed_chunk(St, q, k, v, beta, G, last, last_dv, gate, weight, *, norm,
     scale, l2_eps, rms_eps = norm
     q = (l2norm(q, l2_eps) * scale).astype(v.dtype)
     k = l2norm(k, l2_eps).astype(v.dtype)
-    St, o = _head_chunk(St, q, k, v, beta, G, last, last_dv, roll)
+    St, o, T = _head_chunk(St, q, k, v, beta, G, last, last_dv, roll, T)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + rms_eps)
-    return St, o * weight * jax.nn.sigmoid(gate.astype(F32))
+    return St, o * weight * jax.nn.sigmoid(gate.astype(F32)), T
 
 
 # ------------------------------------------------------------ Pallas kernels
@@ -358,10 +417,26 @@ def _stacked(refs, beta_ref, d_scr, sums, p):
             *(_stack(d_scr[at], p) for at in sums))
 
 
+def _diagonal(T, p):
+    """[p * C, p * C] block-diagonal over heads -> [C, p * C], the heads'
+    diagonal blocks side by side on lanes: the sum of T's row blocks, each
+    entry one of T's plus exact zeros."""
+    return sum(_heads_of(T, p)) if p > 1 else T
+
+
+def _block_diagonal(D, p):
+    """``_diagonal`` undone: D on every head's rows, zeros between heads."""
+    if p == 1:
+        return D
+    same = _same_head(*_rows_cols(p * CHUNK))
+    return jnp.where(same, jnp.concatenate([D] * p), jnp.zeros((), D.dtype))
+
+
 def _kda_fwd_kernel(m_ref, g_ref, q_ref, k_ref, v_ref, beta_ref, gate_ref,
                     w_ref, o_ref, *rest, norm):
-    # rest: (the states' output, the two scratches) or the scratches alone.
-    s_ref, d_scr, st_scr = rest if len(rest) == 3 else (None, *rest)
+    # rest: (the states' and the inverses' outputs, the two scratches) or the
+    # scratches alone.
+    s_ref, t_ref, d_scr, st_scr = rest if len(rest) == 4 else (None, None, *rest)
     step, p = pl.program_id(2), beta_ref.shape[1]
     dv, dk = st_scr.shape[1] // p, st_scr.shape[2]
 
@@ -373,18 +448,20 @@ def _kda_fwd_kernel(m_ref, g_ref, q_ref, k_ref, v_ref, beta_ref, gate_ref,
     St = st_scr[step]
     if s_ref is not None:
         s_ref[0, 0] = _unstack(St, p)
-    st_scr[step], o = _normed_chunk(
+    st_scr[step], o, T = _normed_chunk(
         St, *_stacked((q_ref, k_ref, v_ref), beta_ref, d_scr,
                       _step_sums(d_scr, p, dk, dv), p),
         _stack(gate_ref[0], p), w_ref[...], norm=norm, roll=_roll_here(),
     )
     o_ref[0] = _unstack(o, p).astype(o_ref.dtype)
+    if t_ref is not None:
+        t_ref[0, 0, 0] = _diagonal(T, p)
 
 
 def _kda_bwd_kernel(m_ref, mt_ref, g_ref, q_ref, k_ref, v_ref, beta_ref,
-                    gate_ref, w_ref, s_ref, do_ref, dq_ref, dk_ref, dv_ref,
-                    dbeta_ref, dg_ref, dgate_ref, dw_ref, d_scr, dd_scr,
-                    dst_scr, *, norm):
+                    gate_ref, w_ref, s_ref, t_ref, do_ref, dq_ref, dk_ref,
+                    dv_ref, dbeta_ref, dg_ref, dgate_ref, dw_ref, d_scr,
+                    dd_scr, dst_scr, *, norm):
     step, p = pl.program_id(2), beta_ref.shape[1]
     dv, dk = dst_scr.shape[1] // p, dst_scr.shape[2]
 
@@ -400,8 +477,10 @@ def _kda_bwd_kernel(m_ref, mt_ref, g_ref, q_ref, k_ref, v_ref, beta_ref,
 
     _take_sums(m_ref, g_ref, d_scr)
     sums = _step_sums(d_scr, p, dk, dv)
+    chunk = functools.partial(_normed_chunk, norm=norm, roll=_roll_here(),
+                              T=_block_diagonal(t_ref[0, 0, 0], p))
     _, vjp = jax.vjp(
-        functools.partial(_normed_chunk, norm=norm, roll=_roll_here()),
+        lambda *operands: chunk(*operands)[:2],
         _stack(s_ref[0, 0], p),
         *_stacked((q_ref, k_ref, v_ref), beta_ref, d_scr, sums, p),
         _stack(gate_ref[0], p), w_ref[...],
@@ -435,9 +514,11 @@ def _specs(heads, dk, dv, chunk_of):
     """BlockSpecs over grid (batch, step, heads // p), ``chunk_of(step)`` the
     chunk a step works on. Rows lie [B, T, H * d]: a block is one chunk's
     rows of p heads' lanes, or of all heads' (g, whose running sums are
-    taken for all heads at once). beta lies [B, H, T, 1] and the states
-    [B, N, dv, H * dk]. With them the grid's last extent (``steps``) and the
-    scratch that holds every head's state, p heads stacked a step."""
+    taken for all heads at once). beta lies [B, H, T, 1], the states [B, N,
+    dv, H * dk] and the chunks' inverses [B, N, H / p, CHUNK, p * CHUNK], a
+    step's block whole in its last two extents whatever p is. With them the
+    grid's last extent (``steps``) and the scratch that holds every head's
+    state, p heads stacked a step."""
     p = _heads_a_step(heads)
 
     def rows(d):
@@ -452,6 +533,8 @@ def _specs(heads, dk, dv, chunk_of):
                              lambda b, n, h: (b, h, chunk_of(n), 0)),
         "state": pl.BlockSpec((1, 1, dv, p * dk),
                               lambda b, n, h: (b, chunk_of(n), 0, h)),
+        "inverse": pl.BlockSpec((1, 1, 1, CHUNK, p * CHUNK),
+                                lambda b, n, h: (b, chunk_of(n), h, 0, 0)),
         "m": whole(2 * CHUNK + dv, CHUNK), "mt": whole(CHUNK, 2 * CHUNK + dv),
         "weight": whole(1, dv),
         "dweight": pl.BlockSpec((1, 1, dv), lambda b, n, h: (b, 0, 0)),
@@ -477,18 +560,21 @@ def _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm, states):
         grid=(batch, n, s["steps"]),
         in_specs=[s["m"], s["g"], s["k"], s["k"], s["v"], s["beta"], s["v"],
                   s["weight"]],
-        out_specs=[s["v"], s["state"]][:1 + states],
+        out_specs=[s["v"], s["state"], s["inverse"]][:1 + 2 * states],
         out_shape=[
             jax.ShapeDtypeStruct(v.shape, v.dtype),
             jax.ShapeDtypeStruct((batch, n, dv, heads * dk), F32),
-        ][:1 + states],
+            jax.ShapeDtypeStruct(
+                (batch, n, s["steps"], CHUNK, _heads_a_step(heads) * CHUNK), v.dtype),
+        ][:1 + 2 * states],
         scratch_shapes=[pltpu.VMEM((m.shape[0], heads * dk), F32), s["states"]],
         compiler_params=_params(),
         interpret=_attention._interpret(),
     )(m, g, q, k, v, beta, gate, weight)
 
 
-def _backward_pallas(q, k, v, g, beta, gate, weight, states, do, heads, norm):
+def _backward_pallas(q, k, v, g, beta, gate, weight, states, inverses, do,
+                     heads, norm):
     batch, t, _ = q.shape
     dk, dv, n = q.shape[2] // heads, v.shape[2] // heads, t // CHUNK
     s = _specs(heads, dk, dv, lambda i: n - 1 - i)
@@ -498,7 +584,7 @@ def _backward_pallas(q, k, v, g, beta, gate, weight, states, do, heads, norm):
         functools.partial(_kda_bwd_kernel, norm=norm),
         grid=(batch, n, s["steps"]),
         in_specs=[s["m"], s["mt"], s["g"], s["k"], s["k"], s["v"], s["beta"],
-                  s["v"], s["weight"], s["state"], s["v"]],
+                  s["v"], s["weight"], s["state"], s["inverse"], s["v"]],
         out_specs=[s["k"], s["k"], s["v"], s["beta"], s["g"], s["v"],
                    s["dweight"]],
         out_shape=[
@@ -510,7 +596,7 @@ def _backward_pallas(q, k, v, g, beta, gate, weight, states, do, heads, norm):
         compiler_params=_params(),
         interpret=_attention._interpret(),
     )(jnp.asarray(m, jnp.bfloat16), jnp.asarray(m.T, jnp.bfloat16),
-      g, q, k, v, beta, gate, weight, states, do)
+      g, q, k, v, beta, gate, weight, states, inverses, do)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
@@ -520,25 +606,29 @@ def _kda_pallas(q, k, v, g, beta, gate, weight, heads, norm):
     dv], T a whole number of chunks; q and k raw, ``norm`` as
     ``_normed_chunk`` takes it.
 
-    Called outside a gradient it writes no states, because nobody reads
-    them. Under a gradient the forward rule writes them and names them and o
-    (``checkpoint_name``): a remat policy that keeps both names
-    (models/llama.py KERNEL_RESIDUALS) holds a layer's 512 MiB of states and
-    128 MiB of o from the forward pass to its backward and its replay runs
-    no forward kernel; one that keeps neither runs the kernel again in the
-    replay, and the states live for that layer's backward pass alone."""
+    Called outside a gradient it writes neither the states nor the chunks'
+    inverses, because nobody reads them. Under a gradient the forward rule
+    writes both and names them and o (``checkpoint_name``): a remat policy
+    that keeps the three names (models/llama.py KERNEL_RESIDUALS) holds a
+    layer's 512 MiB of states, 64 MiB of inverses and 128 MiB of o (at 16k
+    tokens and 32 heads of 128) from the forward pass to its backward and
+    its replay runs no forward kernel; one that lacks any of them runs the
+    kernel again in the replay, and the states and inverses live for that
+    layer's backward pass alone."""
     return _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm,
                            states=False)[0]
 
 
 def _kda_pallas_fwd(q, k, v, g, beta, gate, weight, heads, norm):
-    o, states = _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm,
-                                states=True)
+    o, states, inverses = _forward_pallas(q, k, v, g, beta, gate, weight, heads,
+                                          norm, states=True)
     # Named for the remat policy (models/llama.py KERNEL_RESIDUALS): the
-    # backward reads the states alone, and o is kept with them because a
-    # replay that has to make o runs this kernel whatever else it holds.
+    # backward reads the states and the inverses, and o is kept with them
+    # because a replay that has to make o runs this kernel whatever else it
+    # holds.
     o, states = checkpoint_name(o, "kda_o"), checkpoint_name(states, "kda_states")
-    return o, (q, k, v, g, beta, gate, weight, states)
+    inverses = checkpoint_name(inverses, "kda_t")
+    return o, (q, k, v, g, beta, gate, weight, states, inverses)
 
 
 def _kda_pallas_bwd(heads, norm, residuals, do):
@@ -568,7 +658,7 @@ def _kda_xla(q, k, v, g, beta, gate, weight, heads, norm):
 
     def one(St, q, k, v, beta, d, gate):
         return _normed_chunk(St, q, k, v, beta, d[:CHUNK], d[CHUNK:2 * CHUNK],
-                             d[2 * CHUNK:], gate, weight, norm=norm)
+                             d[2 * CHUNK:], gate, weight, norm=norm)[:2]
 
     def step(St, chunk):
         return jax.vmap(jax.vmap(one))(St, *chunk)

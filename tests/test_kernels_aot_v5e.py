@@ -307,19 +307,24 @@ def test_tgmm_over_the_capacity_ffns_trips_compiles_for_v5e(v5e, k, n):
 # which differentiates a chunk and its normalisations inside the kernel. And
 # Solar-Open2's (pretrain-4k): 64 heads of 128 over 4,096 tokens, where every
 # head's running sums are 8 MiB of VMEM, their cotangents as much, the states
-# 4 and g's block of every head 2, under the kernels' 64 MiB.
-@pytest.mark.parametrize("t,h", [(16384, 32), (4096, 64)])
+# 4 and g's block of every head 2, under the kernels' 64 MiB. Under a
+# gradient the forward also writes every chunk's inverse T, a pair's two [64,
+# 64] blocks side by side on 128 lanes, and the backward reads it; at an odd
+# head count (one head a step) the block is one head's [64, 64].
+@pytest.mark.parametrize("t,h", [(16384, 32), (4096, 64), (1024, 3)])
 def test_kda_kernels_compile_for_v5e(v5e, t, h):
     b, d = 1, 128
     raw, rows = ((b, t, h * d), jnp.float32), ((b, t, h * d), jnp.bfloat16)
     operands = (raw, raw, rows, raw, ((b, h, t, 1), jnp.float32), rows,
                 ((1, d), jnp.float32))
     norm = (d ** -0.5, 1e-6, 1e-5)
+    p = kda._heads_a_step(h)
     _compile_for(v5e, lambda *a: kda._forward_pallas(*a, h, norm, states=False), *operands)
     _compile_for(v5e, lambda *a: kda._forward_pallas(*a, h, norm, states=True), *operands)
     _compile_for(
         v5e, lambda *a: kda._backward_pallas(*a, h, norm), *operands,
-        ((b, t // kda.CHUNK, d, h * d), jnp.float32), rows,
+        ((b, t // kda.CHUNK, d, h * d), jnp.float32),
+        ((b, t // kda.CHUNK, h // p, kda.CHUNK, p * kda.CHUNK), jnp.bfloat16), rows,
     )
 
 
@@ -432,11 +437,8 @@ def test_mixtrals_step_takes_its_weight_gradients_from_the_grouped_matmul(topo):
         assert f"tensor<{shape}xbf16>" in text and f"tensor<{shape}xf32>" not in text
 
 
-# Kimi-Linear's step at the benchmark's real size (b1 x s16384, five layers at
-# the published widths), lowered once for the tests below.
-@pytest.fixture(scope="module")
-def kimi_linears_step(v5e):
-    """(the cell, the lowered step's StableHLO)."""
+def _lowered_step(v5e, name):
+    """(the cell ``name``, its step's StableHLO as lowered for a v5e chip)."""
     import importlib
 
     import numpy as np
@@ -446,7 +448,7 @@ def kimi_linears_step(v5e):
     from ray_tpu import train
 
     attention = importlib.import_module("ray_tpu.ops.attention")
-    cell = cells.load_cell("kimi-linear-48b-a3b-l5.longctx-16k")
+    cell = cells.load_cell(name)
     config, traffic = cell["config"], cell["traffic"]
     model = cells.resolve(config["program"]["model"])(cells.program_config(config))
     shapes = jax.eval_shape(
@@ -465,6 +467,45 @@ def kimi_linears_step(v5e):
             placed(shapes), placed(jax.eval_shape(tx.init, shapes)), batch, batch
         ).as_text()
     return cell, text
+
+
+# Kimi-Linear's step at the benchmark's real size (b1 x s16384, five layers at
+# the published widths), lowered once for the tests below; and Solar-Open2's
+# (b1 x s4096, four layers).
+@pytest.fixture(scope="module")
+def kimi_linears_step(v5e):
+    return _lowered_step(v5e, "kimi-linear-48b-a3b-l5.longctx-16k")
+
+
+@pytest.fixture(scope="module")
+def solar_open2s_step(v5e):
+    return _lowered_step(v5e, "solar-open2-250b-l4.pretrain-4k")
+
+
+@pytest.mark.parametrize("step,layers", [("kimi_linears_step", 4), ("solar_open2s_step", 3)])
+def test_a_steps_replay_runs_no_kda_forward_and_its_backward_reads_the_inverses(
+        request, step, layers):
+    """A KDA layer is one ``_kda_fwd_kernel`` and one ``_kda_bwd_kernel`` in
+    the whole step, forward pass, replay and backward pass together: the
+    remat policy keeps o, the states and every chunk's inverse T
+    (``kda_o``, ``kda_states``, ``kda_t``), so no replay runs the forward
+    kernel to remake one of them. T leaves the forward kernel as [B, N, H /
+    2, 64, 128] in the matmuls' dtype, a pair's two blocks side by side, an
+    eighth of the states' bytes."""
+    from benchmarks.lib import checks
+
+    cell, text = request.getfixturevalue(step)
+    counts = checks.count_pallas_kernels(text, ("_kda_fwd_kernel", "_kda_bwd_kernel"))
+    assert counts == {"_kda_fwd_kernel": layers, "_kda_bwd_kernel": layers}
+    traffic, kda_cfg = cell["traffic"], cell["config"]["linear_attn_config"]
+    b, n, h, d = (traffic["batch"], traffic["seq"] // kda.CHUNK, kda_cfg["num_heads"],
+                  kda_cfg["head_dim"])
+    states, inverses = f"tensor<{b}x{n}x{d}x{h * d}xf32>", f"tensor<{b}x{n}x{h // 2}x64x128xbf16>"
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    # the forward's last two results; the backward's operands before do
+    wrote = sum(f"{states}, {inverses})" in line for line in calls)
+    read = sum(f"{states}, {inverses}," in line for line in calls)
+    assert (wrote, read) == (layers, layers)
 
 
 def test_kimi_linears_step_holds_its_kernels_and_no_gather_over_the_bound(kimi_linears_step):

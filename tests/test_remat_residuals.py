@@ -175,6 +175,18 @@ def test_replay_holds_no_forward_kernel(case):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("dropped", ["kda_o", "kda_states", "kda_t"])
+def test_a_kda_layer_needs_each_of_its_three_names_kept(dropped):
+    """The backward kernel reads the states and the chunks' inverses, and o
+    is the layer's own output: a policy that lacks one of the three runs
+    ``_kda_fwd_kernel`` in the replay to remake it, whatever else it holds."""
+    names = [name for name in KERNEL_RESIDUALS if name != dropped]
+    calls, _ = _run("kda", jax.checkpoint_policies.save_only_these_names(*names))
+    assert calls["_kda_fwd_kernel"] == 2 * LAYERS and calls["_kda_bwd_kernel"] == LAYERS
+    kept, _ = _run("kda", remat_policy(_cfg(remat_prevent_cse=True)))
+    assert kept["_kda_fwd_kernel"] == kept["_kda_bwd_kernel"] == LAYERS
+
+
 def test_the_other_policies_are_what_they_were():
     """``"dots"`` as ever; and without the barrier no replay is executed, so
     the names would cost memory and delete nothing (``remat_policy``)."""
