@@ -93,7 +93,8 @@ def kimi_linear_config(
         if i + 1 not in kda | full:
             raise ValueError(f"layer {i + 1} is neither a KDA nor a full-attention layer")
         sparse = i >= first_k_dense_replace and i % moe_layer_freq == 0
-        kinds.append(("kda" if i + 1 in kda else "mla", "moe" if sparse else "mlp"))
+        kinds.append((tracing.KDA if i + 1 in kda else tracing.MLA,
+                      tracing.MOE if sparse else tracing.MLP))
     first = expert_rank * num_experts_held
     return KimiLinearConfig(
         num_layers=num_layers, layer_kinds=tuple(kinds),

@@ -90,9 +90,9 @@ class LagunaConfig(MixtralConfig):
         first, past = self.experts_held or (0, self.num_experts)
         expert = 3 * h * self.expert_width
         ffn = {
-            "mlp": 3 * h * self.intermediate_size,
+            tracing.MLP: 3 * h * self.intermediate_size,
             # the router's weight and its selection bias
-            "moe": (h + 1) * self.num_experts
+            tracing.MOE: (h + 1) * self.num_experts
             + (past - first + self.num_shared_experts) * expert,
         }
         total = self.vocab_size * h * (1 if self.tie_embeddings else 2) + h
@@ -139,7 +139,7 @@ def laguna_config(
     first = expert_rank * num_experts_held
     return LagunaConfig(
         layer_kinds=tuple(
-            (MIXER_OF[kind], {"dense": "mlp", "sparse": "moe"}[ffn])
+            (MIXER_OF[kind], {"dense": tracing.MLP, "sparse": tracing.MOE}[ffn])
             for kind, ffn in zip(layer_types[:n], mlp_layer_types[:n])
         ),
         attentions=tuple(attentions.items()), num_shared_experts=shared,

@@ -99,7 +99,7 @@ class LlamaConfig:
         class binds their modules (``LlamaForCausalLM.blocks``), which are
         also their flax names. A family with one kind of layer says it
         once; one with several kinds gives the tuple from its file."""
-        return (("attn", "mlp"),) * self.num_layers
+        return ((tracing.ATTN, tracing.MLP),) * self.num_layers
 
     def num_params(self) -> int:
         h, i, v, l = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
@@ -323,10 +323,10 @@ class DecoderLayer(nn.Module):
         if cfg.hyper_connections is not None:
             return _hyper_connected(self, x, positions)
         h = x + mixer(cfg, name=mixer_name)(
-            RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(x), positions
+            RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.INPUT_NORM)(x), positions
         )
         out = h + ffn(cfg, name=ffn_name)(
-            RMSNorm(cfg.rms_eps, cfg.param_dtype, name="post_attn_norm")(h)
+            RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.POST_ATTN_NORM)(h)
         )
         return with_logical_constraint(out, ("batch", "seq", "embed"))
 
@@ -342,13 +342,13 @@ def _hyper_connected(layer: DecoderLayer, x, positions):
         cfg.hyper_connections, cfg.rms_eps, weight_init(cfg),
         cfg.param_dtype, name=name,
     )
-    u, x, maps = connection("mixer_hc")(x, streams=True)
+    u, x, maps = connection(tracing.MIXER_HC)(x, streams=True)
     h = write_streams(x, mixer(cfg, name=mixer_name)(
-        RMSNorm(cfg.rms_eps, cfg.param_dtype, name="input_norm")(u), positions
+        RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.INPUT_NORM)(u), positions
     ), *maps)
-    u, h, maps = connection("ffn_hc")(h, streams=True)
+    u, h, maps = connection(tracing.FFN_HC)(h, streams=True)
     out = write_streams(h, ffn(cfg, name=ffn_name)(
-        RMSNorm(cfg.rms_eps, cfg.param_dtype, name="post_attn_norm")(u)
+        RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.POST_ATTN_NORM)(u)
     ), *maps)
     return with_logical_constraint(out, (None, "batch", "seq", "embed"))
 
@@ -356,7 +356,7 @@ def _hyper_connected(layer: DecoderLayer, x, positions):
 def _embedding(cfg: LlamaConfig) -> nn.Embed:
     return nn.Embed(
         cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-        param_dtype=cfg.param_dtype, name="embed_tokens",
+        param_dtype=cfg.param_dtype, name=tracing.EMBED,
         embedding_init=weight_init(cfg, nn.linear.default_embed_init),
     )
 
@@ -371,13 +371,15 @@ def _lookup(cfg: LlamaConfig, emb: nn.Embed, input_ids):
         # XLA fuses the one-hot so the [B,S,V] operand is never
         # materialized. A whole table (one chip, or a mesh of data,
         # seq and expert axes alone) is read by a gather.
-        one_hot = jax.nn.one_hot(input_ids, cfg.vocab_size, dtype=cfg.dtype)
-        x = jnp.einsum(
-            "bsv,ve->bse", one_hot, emb.embedding.astype(cfg.dtype)
-        )
+        with tracing.scope(tracing.EMBED):  # outside the module's own scope
+            one_hot = jax.nn.one_hot(input_ids, cfg.vocab_size, dtype=cfg.dtype)
+            x = jnp.einsum(
+                "bsv,ve->bse", one_hot, emb.embedding.astype(cfg.dtype)
+            )
     else:
         x = emb(input_ids)
-    return with_logical_constraint(x, ("batch", "seq", "embed"))
+    with tracing.scope(tracing.EMBED):
+        return with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
 def _through(model: "LlamaForCausalLM", layers, x, positions):
@@ -400,21 +402,28 @@ def _through(model: "LlamaForCausalLM", layers, x, positions):
         )
     hc = cfg.hyper_connections
     if hc is not None:
-        x = expand_streams(x, hc.mult)
+        with tracing.scope(tracing.HC_STREAMS):
+            x = expand_streams(x, hc.mult)
     for name, mixer, ffn in layers:
         x = layer_cls(
             cfg, (mixer, model.blocks[mixer]), (ffn, model.blocks[ffn]), name=name,
         )(x, positions)
-    return x if hc is None else collapse_streams(x)
+    if hc is None:
+        return x
+    with tracing.scope(tracing.HC_STREAMS):
+        return collapse_streams(x)
 
 
 def _logits(cfg: LlamaConfig, emb: nn.Embed, x):
     if cfg.tie_embeddings:
-        return emb.attend(x.astype(cfg.param_dtype))
+        # flax names the method embed_tokens.attend: the head's name in front,
+        # so that a tied head is read where an untied one is.
+        with tracing.scope(tracing.LM_HEAD):
+            return emb.attend(x.astype(cfg.param_dtype))
     return nn.Dense(
         cfg.vocab_size, use_bias=False, dtype=jnp.float32,
         param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
-        name="lm_head",
+        name=tracing.LM_HEAD,
     )(x)
 
 
@@ -438,7 +447,7 @@ class LlamaForCausalLM(nn.Module):
     # (`_through`), and read by nothing else.
     mesh: Optional[Any] = None
     # A class attribute, not a field: no caller sets it.
-    blocks = {"attn": Attention, "mlp": MLP}
+    blocks = {tracing.ATTN: Attention, tracing.MLP: MLP}
 
     @nn.compact
     def __call__(self, input_ids, positions=None, return_hidden=False):
@@ -452,10 +461,10 @@ class LlamaForCausalLM(nn.Module):
             positions = _positions(input_ids)
         emb = _embedding(cfg)
         x = _through(
-            self, [(f"layers_{i}", *kinds) for i, kinds in enumerate(cfg.layers)],
+            self, [(f"{tracing.LAYER}{i}", *kinds) for i, kinds in enumerate(cfg.layers)],
             _lookup(cfg, emb, input_ids), positions,
         )
-        x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="final_norm")(x)
+        x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.FINAL_NORM)(x)
         return x if return_hidden else _logits(cfg, emb, x)
 
 
@@ -463,9 +472,10 @@ def lm_head_weight(params) -> jax.Array:
     """[V, H] output-projection weight from a param tree (tied
     embedding table, or the dedicated lm_head kernel transposed)."""
     p = params.get("params", params)
-    if "lm_head" in p:
-        return p["lm_head"]["kernel"].T
-    return p["embed_tokens"]["embedding"]
+    if tracing.LM_HEAD in p:
+        with tracing.scope(tracing.LOSS):  # the transpose is the loss's
+            return p[tracing.LM_HEAD]["kernel"].T
+    return p[tracing.EMBED]["embedding"]
 
 
 def chunked_causal_lm_loss(
@@ -492,6 +502,7 @@ def chunked_causal_lm_loss(
     )
 
 
+@tracing.scope(tracing.LOSS)
 def chunked_head_loss(
     hidden: jax.Array,
     head: jax.Array,
@@ -529,11 +540,12 @@ def chunked_head_loss(
         # f32 accumulation on the MXU regardless of param dtype — the
         # full path's lm_head computes f32 logits, and the two losses
         # must stay numerically comparable.
-        logits = jnp.matmul(
-            h.astype(head.dtype),
-            head.T,
-            preferred_element_type=jnp.float32,
-        )  # [B, C, V] f32
+        with tracing.scope(tracing.LOSS_HEAD):
+            logits = jnp.matmul(
+                h.astype(head.dtype),
+                head.T,
+                preferred_element_type=jnp.float32,
+            )  # [B, C, V] f32
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, tg[..., None], axis=-1)[..., 0]
         return jnp.sum((logz - gold) * m), jnp.sum(m)
@@ -549,6 +561,7 @@ def chunked_head_loss(
     return total / jnp.maximum(count, 1.0)
 
 
+@tracing.scope(tracing.LOSS)
 def causal_lm_loss(logits: jax.Array, targets: jax.Array,
                    mask: Optional[jax.Array] = None) -> jax.Array:
     """Next-token cross-entropy in f32. logits [B, T, V], targets [B, T]

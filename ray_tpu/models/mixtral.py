@@ -106,7 +106,7 @@ class MixtralConfig(LlamaConfig):
 
     @property
     def layers(self):
-        return (("attn", "moe"),) * self.num_layers
+        return ((tracing.ATTN, tracing.MOE),) * self.num_layers
 
     @property
     def expert_width(self) -> int:
@@ -1147,7 +1147,7 @@ class MoELayer(nn.Module):
 class MixtralForCausalLM(LlamaForCausalLM):
     """The decoder body of llama.py with the sparse FFN in every layer."""
 
-    blocks = {**LlamaForCausalLM.blocks, "moe": MoELayer}
+    blocks = {**LlamaForCausalLM.blocks, tracing.MOE: MoELayer}
 
 
 def moe_lm_loss(model: MixtralForCausalLM, params, input_ids, targets,
@@ -1162,7 +1162,8 @@ def moe_lm_loss(model: MixtralForCausalLM, params, input_ids, targets,
         state.get("intermediates", {})
     )
     if aux_terms:
-        loss = loss + model.cfg.router_aux_loss_coef * (
-            sum(aux_terms) / len(aux_terms)
-        )
+        with tracing.scope(tracing.LOSS):
+            loss = loss + model.cfg.router_aux_loss_coef * (
+                sum(aux_terms) / len(aux_terms)
+            )
     return loss

@@ -39,7 +39,7 @@ class SarvamMLAConfig(MLAConfig):
     @property
     def layers(self):
         return tuple(
-            (tracing.MLA, "mlp" if i < self.first_k_dense_replace else "moe")
+            (tracing.MLA, tracing.MLP if i < self.first_k_dense_replace else tracing.MOE)
             for i in range(self.num_layers)
         )
 

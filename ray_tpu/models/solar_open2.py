@@ -73,9 +73,9 @@ class SolarOpen2Config(KDAConfig, MixtralConfig):
             + 2 * h * self.num_kv_heads * hd,
         }
         ffn = {
-            "mlp": 3 * h * self.intermediate_size,
+            tracing.MLP: 3 * h * self.intermediate_size,
             # the router's weight and its selection bias
-            "moe": (h + 1) * self.num_experts
+            tracing.MOE: (h + 1) * self.num_experts
             + (past - first + self.num_shared_experts) * expert,
         }
         total = self.vocab_size * h * (1 if self.tie_embeddings else 2) + h
@@ -108,7 +108,7 @@ def solar_open2_config(
         num_layers=num_layers,
         layer_kinds=tuple(
             (tracing.ATTN if i in full else tracing.KDA,
-             "mlp" if i < first_k_dense_replace else "moe")
+             tracing.MLP if i < first_k_dense_replace else tracing.MOE)
             for i in range(num_layers)
         ),
         kda_num_heads=heads,

@@ -94,10 +94,10 @@ class Xing4ForCausalLM(SarvamMLAForCausalLM):
             positions = _positions(input_ids)
         emb = _embedding(cfg)
         h = _through(
-            self, [(f"layers_{i}", *kinds) for i, kinds in enumerate(cfg.layers)],
+            self, [(f"{tracing.LAYER}{i}", *kinds) for i, kinds in enumerate(cfg.layers)],
             _lookup(cfg, emb, input_ids), positions,
         )
-        x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="final_norm")(h)
+        x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.FINAL_NORM)(h)
         if next_ids is None and self.is_initializing():
             next_ids = jnp.roll(input_ids, -1, axis=1)
         if next_ids is not None:
@@ -114,16 +114,16 @@ def _predict_further(model: Xing4ForCausalLM, emb, h, next_ids, positions):
     norm = lambda name: RMSNorm(cfg.rms_eps, cfg.param_dtype, name=name)  # noqa: E731
     with tracing.scope(tracing.MTP):
         joined = jnp.concatenate([
-            norm("mtp_hidden_norm")(h),
-            norm("mtp_embed_norm")(_lookup(cfg, emb, next_ids)),
+            norm(tracing.MTP_HIDDEN_NORM)(h),
+            norm(tracing.MTP_EMBED_NORM)(_lookup(cfg, emb, next_ids)),
         ], axis=-1)
         x = nn.Dense(
             cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
-            name="mtp_proj",
+            name=tracing.MTP_PROJ,
         )(joined)
-        x = _through(model, [("mtp_layer", tracing.MLA, "moe")], x, positions)
-        return norm("mtp_norm")(x)
+        x = _through(model, [(tracing.MTP_LAYER, tracing.MLA, tracing.MOE)], x, positions)
+        return norm(tracing.MTP_NORM)(x)
 
 
 def mtp_chunked_lm_loss(model: Xing4ForCausalLM, params, input_ids, targets,
@@ -145,4 +145,5 @@ def mtp_chunked_lm_loss(model: Xing4ForCausalLM, params, input_ids, targets,
             predicted, head, jnp.roll(targets, -1, axis=1), has_target[None],
             chunk_size,
         )
-    return loss + mtp_weight * further
+    with tracing.scope(tracing.LOSS):
+        return loss + mtp_weight * further

@@ -8,7 +8,19 @@ the process that holds the chip a span costs under a microsecond while no
 profiler session is open and is recorded, on the device's clock and under
 the thread it ran on, while one is (``jax.profiler.start_trace``).
 ``scope(name)`` is ``jax.named_scope``: trace-time metadata that reaches
-every device operation's ``op_name``, at no run-time cost.
+every device operation's ``op_name``, at no run-time cost. ``SCOPES``,
+``MIXERS`` and ``BODY`` together name every part of a train step: an
+operation whose path holds none of them is one the program gave no name
+(``tests/test_program_spans.py`` lists the few that are exempt, the
+benchmark's ``step.unnamed_share`` reads their time).
+
+A new scope shows in a trace only from a fresh compile: JAX's persistent
+cache key leaves operation metadata out
+(``jax_compilation_cache_include_metadata_in_key`` is False), so a warm
+cache (``place_compile_cache``: ``JAX_COMPILATION_CACHE_DIR``, else the
+checkout's ``.jax_cache``) serves the executable compiled before the edit,
+old names and all. Empty it, or point that variable at a fresh directory,
+before a traced run that is to show a name added since.
 
 There is no switch: a profiler session is "on". A span goes around work,
 never around a blocking wait, except the one that is named as a wait.
@@ -69,13 +81,36 @@ HC = "hc"  # a hyper-connection (models/hyper_connections.py), twice a layer: th
 HC_PRE = "pre"  # inside hc: the streams' rms, x~ Phi, the three logits, H_pre and H_post, the read u = sum H_pre[i] X[i]
 HC_SINKHORN = "sinkhorn"  # inside hc: exp, the iterations of rows and columns, and their backward
 HC_POST = "post"  # inside hc: the write X'[i] = sum H_res[i, j] X[j] + H_post[i] y
+HC_STREAMS = "streams"  # outside hc and every layer (models/llama.py _through): the copy of the layers' input to the n streams and their sum after the last layer; no hyper-connection's, so no /hc/ reader takes it
 MTP = "mtp"  # the multi-token-prediction module (flax name, models/xing4.py) and, in the loss, its pass of the shared head
+LOSS = "loss"  # the loss functions of models/, called outside every flax module: the head's weight taken from the tree, padding, reshapes, the scan over chunks, the soft-max arithmetic, the mean, an auxiliary or second term added. Opened directly under a transform it renders in its brackets, jvp(loss), as mtp does
+LOSS_HEAD = "head"  # inside loss: a chunk's float32 matmul with the head, alone (the full-logit path's is the flax module lm_head)
 SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
           MLA_LATENT, MLA_ROPE, MLA_Q_LATENT, ATTN_ROPE, ATTN_GATE, HC, HC_PRE,
-          HC_SINKHORN, HC_POST, MTP)
+          HC_SINKHORN, HC_POST, HC_STREAMS, MTP, LOSS, LOSS_HEAD)
 # Flax module names, bound in the model classes' ``blocks``.
 MIXERS = (KDA, MLA, ATTN, SWA)
+# The decoder body's flax names (models/llama.py, xing4.py), a layer's and
+# above: parameter trees and checkpoints hold them, so none is ever renamed.
+EMBED = "embed_tokens"  # the embedding table's flax name; models/llama.py _lookup opens it as a scope around what it does outside the module (the one-hot product where a mesh splits the table, the constraint on the result)
+LAYER = "layers_"  # a decoder layer is LAYER + its index
+INPUT_NORM = "input_norm"  # a layer's RMSNorm before its mixer
+POST_ATTN_NORM = "post_attn_norm"  # a layer's RMSNorm before its FFN
+MLP = "mlp"  # the dense SwiGLU FFN
+MOE = "moe"  # the expert layer (models/mixtral.py MoELayer)
+MIXER_HC = "mixer_hc"  # a hyper-connected layer's connection around its mixer
+FFN_HC = "ffn_hc"  # and around its FFN
+FINAL_NORM = "final_norm"
+LM_HEAD = "lm_head"  # the head's float32 matmul in the full-logit path: an untied head's flax name, and a scope around a tied table's attend (embed_tokens.attend)
+MTP_HIDDEN_NORM = "mtp_hidden_norm"  # inside mtp: the norm of the body's last hidden states
+MTP_EMBED_NORM = "mtp_embed_norm"  # inside mtp: the norm of the next tokens' embeddings
+MTP_PROJ = "mtp_proj"  # inside mtp: the projection of the two joined
+MTP_LAYER = "mtp_layer"  # inside mtp: the module's decoder layer
+MTP_NORM = "mtp_norm"  # inside mtp: the norm before the shared head
+BODY = (EMBED, LAYER, INPUT_NORM, POST_ATTN_NORM, MLP, MOE, MIXER_HC, FFN_HC,
+        FINAL_NORM, LM_HEAD, MTP_HIDDEN_NORM, MTP_EMBED_NORM, MTP_PROJ,
+        MTP_LAYER, MTP_NORM)
 
 _OFF = contextlib.nullcontext()
 # The flight recorder, while this process holds a train session.

@@ -87,6 +87,21 @@ def qk_norm_paths():
 
 
 @pytest.fixture(scope="module")
+def tied_paths():
+    """Paths of a tiny Llama whose head is the embedding table's ``attend``
+    (the benchmark's Mixtral cell ties them)."""
+    from ray_tpu.models import CONFIGS, LlamaForCausalLM
+    from ray_tpu.models.llama import causal_lm_loss
+
+    cfg = dataclasses.replace(CONFIGS["llama-tiny"], remat=True, tie_embeddings=True)
+    model = LlamaForCausalLM(cfg)
+    ids = jnp.zeros((2, 32), jnp.int32)
+    return paths_of(compiled_step(
+        model, lambda p, i, t: causal_lm_loss(model.apply(p, i), t), ids
+    ))
+
+
+@pytest.fixture(scope="module")
 def moe_paths():
     """dispatch branch -> paths of a tiny Mixtral's compiled train step."""
     from ray_tpu.models.mixtral import CONFIGS, MixtralForCausalLM, moe_lm_loss
@@ -501,6 +516,114 @@ def test_expert_matmuls_are_under_experts_forward_and_backward(moe_paths, branch
     assert len(per_pass["backward"]) >= 6 * layers  # two gradients a matmul
 
 
+# Every family's compiled step, by fixture (and dispatch branch).
+FAMILIES = ("llama_paths", "qk_norm_paths", "tied_paths", "moe_paths:capacity", "moe_paths:gmm",
+            "moe_paths:ragged", "kimi_paths", "sarvam_paths", "xing4_paths",
+            "laguna_paths", "solar_paths")
+# Paths that may hold no name of the program, and why.
+EXEMPT = (
+    # _positions' arange, inside the model's __call__ and outside every part:
+    # integer positions shared by every layer, no device time of their own
+    (r"^jit\(train_step\)/jvp\(\w+ForCausalLM\)/iota$", "positions"),
+    # the zeros of the chunked loss's logsumexp (taken where a row's maximum
+    # is not finite), [B, chunk] float32: XLA hoists the constant out of the
+    # scan's loop and leaves it the jit's own level for a path; its one
+    # consumer is jvp(loss)/while/body/closed_call/select_n
+    (r"^jit\(train_step\)/broadcast_in_dim$", "hoisted zeros"),
+    # JAX's own, at a layer's checkpoint boundary: the transposed remat2
+    # equation rounds the residual stream's summed cotangent to the stream's
+    # dtype outside the layer's name, which flax opens inside the checkpoint.
+    # No line of the program emits it; the benchmark's step.unnamed_share
+    # reads what it costs on the chip (PERF.md 7)
+    (r"^jit\(train_step\)/transpose\(jvp\((\w+ForCausalLM|mtp)\)\)/(jvp\(\w+\)/|mtp/)*remat2$",
+     "remat boundary"),
+)
+
+
+def paths_in(request, family):
+    fixture, _, branch = family.partition(":")
+    paths = request.getfixturevalue(fixture)
+    return paths[branch] if branch else paths
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_every_instruction_path_names_a_part_of_the_program(request, family):
+    """The scope tree is closed over the step: the reader of the benchmark's
+    step table (benchmarks/lib/step_table.py, by tracing's lists alone) finds
+    a part for every instruction but the exempt, and a pass for each."""
+    from benchmarks.lib import step_table
+
+    nameless = [
+        p for p in paths_in(request, family)
+        if not step_table.part_of(p)[0]
+        and not any(re.search(pattern, p) for pattern, _ in EXEMPT)
+    ]
+    assert not nameless, sorted(set(nameless))[:40]
+
+
+LOSS_KINDS = {
+    "llama_paths": "full", "qk_norm_paths": "full", "tied_paths": "full",
+    "moe_paths:capacity": "full",
+    "moe_paths:gmm": "full", "moe_paths:ragged": "full", "kimi_paths": "chunked",
+    "sarvam_paths": "chunked", "laguna_paths": "chunked", "solar_paths": "chunked",
+    "xing4_paths": "mtp",
+}
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_loss_and_the_chunked_head_carry_their_scopes(request, family):
+    """What the benchmark's model.head_loss_share selects by. A loss function
+    is called outside every flax module, directly under the transform, so
+    JAX renders its scope in the brackets: jvp(loss), transpose(jvp(loss)).
+    The full-logit loss multiplies nothing (its matmul is the module
+    lm_head, or the tied table's attend); the chunked one multiplies under
+    ``head`` alone, forward, in its checkpoint's replay and backward; the
+    MTP loss opens ``loss`` inside ``mtp``, so its second pass of the head
+    reads jvp(mtp)/loss/ and "(mtp)" still finds it."""
+    paths = paths_in(request, family)
+    kind = LOSS_KINDS[family]
+    top = [p for p in paths if f"({tracing.LOSS})" in p]
+    assert {pass_of(p) for p in top} >= {"forward", "backward"}
+    assert all(p.startswith((f"jit(train_step)/jvp({tracing.LOSS})/",
+                             f"jit(train_step)/transpose(jvp({tracing.LOSS}))/"))
+               for p in top)
+    assert any(p.endswith("/reduce_max") for p in top)  # the logsumexp
+    assert not [p for p in top if "ForCausalLM" in p or "/layers_" in p]
+    matmuls = [p for p in top if p.endswith("/dot_general")]
+    if kind == "full":
+        assert not matmuls and not [p for p in paths if f"/{tracing.LOSS_HEAD}/" in p]
+        # the module lm_head, or the tied table's attend under the head's name
+        head = [p for p in paths if p.endswith("/dot_general")
+                and f"/{tracing.LM_HEAD}/" in p]
+        assert {pass_of(p) for p in head} >= {"forward", "backward"}
+        assert not [p for p in paths if ".attend/" in p and f"/{tracing.LM_HEAD}/" not in p]
+        assert (family == "tied_paths") == any(
+            f"/{tracing.LM_HEAD}/{tracing.EMBED}.attend/dot_general" in p for p in paths)
+        return
+    assert matmuls and all(f"/{tracing.LOSS_HEAD}/dot_general" in p for p in matmuls)
+    assert {pass_of(p) for p in matmuls} == {"forward", "replay", "backward"}
+    assert f"jit(train_step)/jvp({tracing.LOSS})/while/body/closed_call/head/dot_general" in matmuls
+    assert (f"jit(train_step)/transpose(jvp({tracing.LOSS}))/while/body/closed_call/"
+            "checkpoint/rematted_computation/head/dot_general") in matmuls
+    # nothing but the matmul (and the cast in front of it) is the head's
+    assert {p.rpartition("/")[2] for p in paths if f"/{tracing.LOSS_HEAD}/" in p} <= {
+        "dot_general", "convert_element_type", "transpose"}
+    second = [p for p in paths if f"({tracing.MTP})" in p]
+    if kind != "mtp":
+        assert not second
+        return
+    # (the mask of the positions that have a target and the targets' roll are
+    # the module's and outside the loss function)
+    assert all(f"({tracing.MTP})/{tracing.LOSS}/{tracing.LOSS_HEAD}/" in p or
+               f"/{tracing.LOSS}/while/" in p
+               for p in second if p.endswith("/dot_general"))
+    assert not [p for p in second if f"({tracing.LOSS})" in p]
+    again = [p for p in second if p.endswith(f"/{tracing.LOSS_HEAD}/dot_general")]
+    assert {pass_of(p) for p in again} == {"forward", "replay", "backward"}
+    # the sum of the two terms is the loss's, outside the module's scope
+    assert f"jit(train_step)/jvp({tracing.LOSS})/mul" in top
+
+
 # ---------------------------------------------------------------- host spans
 
 
@@ -643,8 +766,10 @@ def test_names_emitted_are_exactly_the_list(
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
     paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + [
         p for ps in moe_paths.values() for p in ps]
-    for name in tracing.SCOPES:
-        assert any(f"/{name}/" in p for p in paths), name
+    for name in tracing.SCOPES:  # a scope directly under a transform is in its brackets
+        assert any(f"/{name}/" in p or f"({name})/" in p for p in paths), name
+    for name in tracing.MIXERS + tracing.BODY:  # flax names; a layer's ends in its index
+        assert any(f"/{name}/" in p or f"/{name}0/" in p for p in paths), name
 
 
 def test_source_names_no_span_or_scope_outside_the_list():
@@ -654,7 +779,9 @@ def test_source_names_no_span_or_scope_outside_the_list():
                  if k.isupper() and isinstance(v, str)}
     assert {getattr(tracing, k) for k in constants} == (
         set(tracing.HOST_SPANS) | set(tracing.SCOPES) | set(tracing.MIXERS)
+        | set(tracing.BODY)
     )
+    literal = re.compile(r'name=f?"(?:%s)' % "|".join(tracing.MIXERS + tracing.BODY))
     calls = 0
     for path in glob.glob(os.path.join(REPO, "ray_tpu", "**", "*.py"), recursive=True):
         with open(path) as f:
@@ -662,6 +789,8 @@ def test_source_names_no_span_or_scope_outside_the_list():
         if path.endswith(os.path.join("util", "tracing.py")):
             continue
         assert not re.search(r"named_scope|TraceAnnotation|jax\.profiler", source), path
+        if os.sep + "models" + os.sep in path:  # a module is named from the lists
+            assert not literal.search(source), (path, literal.search(source))
         for arg in re.findall(r"\b_?tracing\.(?:span|scope)\(([^)]*)\)", source):
             calls += 1
             assert re.fullmatch(r"_?tracing\.([A-Z_]+)", arg), (path, arg)
