@@ -40,7 +40,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.kda import chunk_kda, kda_gate, short_conv
+from ..ops.kda import chunk_kda, conv_silu, kda_gate
 from ..util import tracing
 from .llama import weight_init
 from .mixtral import MixtralForCausalLM
@@ -157,12 +157,16 @@ class KDAMixer(nn.Module):
         B, T, _ = x.shape
         f32 = jnp.float32
         proj = {n: _dense(cfg, H * d, f"{n}_proj", dtype=f32)(x) for n in "qkv"}
+        # The convolution and SiLU are float32, one pass over a projection
+        # (``ops/kda.py`` ``conv_silu``). q and k leave it as they are, for
+        # the scan's kernels to normalise on the blocks they hold; v is
+        # rounded here, as that pass stores it.
         with tracing.scope(tracing.KDA_CONV):
             q, k, v = (
-                nn.silu(short_conv(proj[n], self.param(
+                conv_silu(proj[n], self.param(
                     f"{n}_conv", _conv_init,
                     (cfg.short_conv_kernel_size, H * d), cfg.param_dtype,
-                ))).reshape(B, T, H, d)
+                ), cfg.dtype if n == "v" else f32).reshape(B, T, H, d)
                 for n in "qkv"
             )
         # The low-rank maps of the gates have the head dim as their width.
@@ -185,14 +189,13 @@ class KDAMixer(nn.Module):
         gate = _dense(cfg, H * d, "g_b_proj", use_bias=True)(
             _dense(cfg, d, "g_a_proj")(x)
         )
-        # The convolution and SiLU stay in float32. q and k go to the scan
-        # as they are, and so does the output gate: its kernels normalise a
-        # head's channels on the blocks they hold (q's and k's L2 norm with
-        # one rounding to v's dtype; o's RMSNorm and gate with one rounding
-        # as it is stored). v is rounded here, in the pass that convolves it.
+        # q and k go to the scan raw, and so does the output gate: its
+        # kernels normalise a head's channels on the blocks they hold (q's
+        # and k's L2 norm with one rounding to v's dtype; o's RMSNorm and
+        # gate with one rounding as it is stored).
         with tracing.scope(tracing.KDA_SCAN):
             o = chunk_kda(
-                q, k, v.astype(cfg.dtype), g, beta, gate.reshape(B, T, H, d),
+                q, k, v, g, beta, gate.reshape(B, T, H, d),
                 NormWeight(cfg.param_dtype, name="o_norm")(d),
                 scale=d ** -0.5, rms_eps=cfg.rms_eps,
             )

@@ -29,6 +29,20 @@ through its RMSNorm and the output gate's sigmoid while it is float32 and
 is rounded once, as it is stored. The gradients are those of the raw q and
 k, of the gate and of the norm's weight.
 
+Who convolves: this file too. The mixer's causal depthwise convolution of
+four taps over each of q's, k's and v's float32 projections, the SiLU after
+it and, for v, the rounding to the matmuls' dtype are ``conv_silu``: on a
+TPU one Pallas pass over a projection forward (``_conv_fwd_kernel``: a block
+of rows with the 8 rows before it, the taps added in ``short_conv``'s order,
+one store in the dtype asked for, float32 for q and k) and one backward
+(``_conv_bwd_kernel``: it makes the pre-activation again from the projection,
+which with the filter is all it keeps, writes the projection's cotangent once
+and adds the filter's up in float32 over batch and time), each behind a
+jitted entry. They are bound by their bytes: XLA's form, four shifted slices
+of a padded copy differentiated tap by tap, moved 6.4 times the backward's.
+``short_conv`` with ``jax.nn.silu`` is the reference they are tested against
+and the path where there is no TPU or the shape does not tile.
+
 Inside a chunk, with G the running sum of g inside it:
 
     A[t, s]   = b_t sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c])      s < t
@@ -101,6 +115,7 @@ accumulation, the normalisations, the running sums and the state float32.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -131,6 +146,248 @@ def short_conv(x, w):
     taps, t = w.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(F32), ((0, 0), (taps - 1, 0), (0, 0)))
     return sum(padded[:, i:i + t] * w[i].astype(F32) for i in range(taps))
+
+
+# ------------------------------------------- the convolution as one pass
+# A block is [_CONV_ROWS, _CONV_LANES] of a [B, T, D] array (less where T or
+# D is less), worked through _CONV_TILE rows at a time so that a tile's
+# values stay in registers; its halo is the float32 sublane tile of 8 rows
+# before it (and, in the backward pass, after it) under a BlockSpec of its
+# own over the same array.
+# Inside the kernels a select is ``lax.select`` and SiLU's sigmoid
+# ``lax.logistic``: ``jnp.where`` and ``jax.nn.sigmoid`` are jitted, their
+# traces kept from the kernel traced first with its frames, and a profile's
+# reader names a kernel by the first ``.._kernel`` among its module's strings.
+_CONV_HALO = 8
+_CONV_ROWS, _CONV_LANES, _CONV_TILE = 512, 512, 64
+
+
+def _largest(most: int, least: int, n: int) -> int:
+    """The largest of most, most / 2, .., least that divides n, else 0."""
+    while most >= least and n % most:
+        most //= 2
+    return most if most >= least else 0
+
+
+class _ConvBlocks(NamedTuple):
+    """A call's static part: a block's rows and lanes, the rows of a tile
+    inside it, and whether the interpreter runs the kernels (the jitted
+    entries keep their traces by it)."""
+    rows: int
+    lanes: int
+    tile: int
+    interpret: bool
+
+
+def _conv_blocks(x, w):
+    """The convolution kernels' blocks over x [B, T, D] under a filter w [K,
+    D], or None where the shape does not tile or there is neither a TPU nor
+    the interpreter: whole 16-row tiles of a bfloat16 output in T, whole
+    vregs of lanes in D, and a filter that reaches no further back than the
+    halo."""
+    interpret = _attention._interpret()
+    if not (_attention._on_tpu() or interpret):
+        return None
+    rows = _largest(_CONV_ROWS, 16, x.shape[1])
+    lanes = _largest(_CONV_LANES, 128, x.shape[2])
+    if not rows or not lanes or w.shape[0] - 1 > _CONV_HALO:
+        return None
+    return _ConvBlocks(rows, lanes, min(rows, _CONV_TILE), interpret)
+
+
+def _taps(ext, w, roll):
+    """[the float32 ``ext`` moved down by K - 1 - i rows, for each tap i]:
+    where row r of ``ext`` is token t, row r of entry i is token t - (K - 1)
+    + i, the one tap i multiplies. The rows that wrap lie in the halo."""
+    taps = w.shape[0]
+    return [roll(ext, taps - 1 - i) if i < taps - 1 else ext for i in range(taps)]
+
+
+def _add_up(terms):
+    """The terms added in their order (``sum`` without its leading 0)."""
+    return functools.reduce(lambda a, b: a + b, terms)
+
+
+def _conv_of(shifted, w):
+    """sum_i w[i] x_{t - (K - 1) + i} in ``short_conv``'s order."""
+    return _add_up(x * w[i:i + 1] for i, x in enumerate(shifted))
+
+
+def _sublanes(dtype) -> int:
+    """Rows of a sublane tile: 8 of float32, 16 of bfloat16."""
+    return _CONV_HALO * 4 // jnp.dtype(dtype).itemsize
+
+
+def _edge(ref, at_edge):
+    """A halo block as float32, zeros where it lies outside the sequence
+    (its index is clamped there, to rows of the sequence's own)."""
+    rows = ref[0].astype(F32)
+    return jax.lax.select(at_edge, jnp.zeros_like(rows), rows)
+
+
+def _rows_before(x_ref, edge, i, tile):
+    """The 8 rows before tile ``i`` of the block ``x_ref`` [1, rows, lanes]
+    of float32: the block's own, or before its first ``edge``."""
+    lo = pl.multiple_of(jnp.maximum(i * tile - _CONV_HALO, 0), _CONV_HALO)
+    return jax.lax.select(i == 0, edge, x_ref[0, pl.ds(lo, _CONV_HALO), :])
+
+
+def _rows_after(x_ref, edge, i, tile):
+    """The 8 rows after tile ``i`` of the block ``x_ref`` as float32: the
+    first of the block's next sublane tile, or after its last ``edge``."""
+    n = _sublanes(x_ref.dtype)
+    hi = pl.multiple_of(jnp.minimum((i + 1) * tile, x_ref.shape[1] - n), n)
+    own = x_ref[0, pl.ds(hi, n), :].astype(F32)[:_CONV_HALO]
+    return jax.lax.select(i == x_ref.shape[1] // tile - 1, edge, own)
+
+
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, tile, roll):
+    w = w_ref[...].astype(F32)
+    before = _edge(before_ref, pl.program_id(2) == 0)
+
+    def one(i, carry):
+        at = pl.ds(pl.multiple_of(i * tile, tile), tile)
+        ext = jnp.concatenate([_rows_before(x_ref, before, i, tile), x_ref[0, at, :]])
+        c = _conv_of(_taps(ext, w, roll), w)[_CONV_HALO:]
+        y_ref[0, at, :] = (c * jax.lax.logistic(c)).astype(y_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[1] // tile, one, None)
+
+
+def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
+                     dx_ref, dw_ref, *, tile, roll):
+    taps = w_ref.shape[0]
+    w = w_ref[...].astype(F32)
+    first, last = pl.program_id(2) == 0, pl.program_id(2) == pl.num_programs(2) - 1
+    before, after = _edge(before_ref, first), _edge(after_ref, last)
+    dy_after = _edge(dy_after_ref, last)[:_CONV_HALO]
+
+    # The filter's gradient adds up over the batch and a sequence's blocks
+    # in its output block, which stays in VMEM while the block's index (the
+    # lanes) stands: 8 rows a tap, which the caller sums.
+    @pl.when((pl.program_id(1) == 0) & first)
+    def _init():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, F32)
+
+    def one(i, carry):
+        at = pl.ds(pl.multiple_of(i * tile, tile), tile)
+        ext = jnp.concatenate([_rows_before(x_ref, before, i, tile), x_ref[0, at, :],
+                               _rows_after(x_ref, after, i, tile)])
+        # The pre-activation again, on the tile's rows and the 8 after them.
+        shifted = [x[_CONV_HALO:] for x in _taps(ext, w, roll)]
+        c = _conv_of(shifted, w)
+        s = jax.lax.logistic(c)
+        dy = jnp.concatenate([dy_ref[0, at, :].astype(F32),
+                              _rows_after(dy_ref, dy_after, i, tile)])
+        dz = dy * (s * (1.0 + c * (1.0 - s)))
+        # dx_s = sum_i w[i] dz_{s + (K - 1) - i}: dz moved up, the rows that
+        # wrap among the 8 after the tile.
+        dx_ref[0, at, :] = _add_up(
+            (roll(dz, tap - (taps - 1)) if tap < taps - 1 else dz)[:tile] * w[tap:tap + 1]
+            for tap in range(taps))
+        for tap, x in enumerate(shifted):
+            prod = dz[:tile] * x[:tile]
+            dw_ref[pl.ds(tap * _CONV_HALO, _CONV_HALO), :] += _add_up(
+                prod[r:r + _CONV_HALO] for r in range(0, tile, _CONV_HALO))
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[1] // tile, one, None)
+
+
+def _conv_specs(x, blocks):
+    """Over x [B, T, D] and the grid (lanes, batch, a sequence's blocks): the
+    grid, a block's BlockSpec, that of the 8 rows before it, and ``after``,
+    which gives that of the sublane tile after it in an array of a dtype
+    (either clamped into the sequence at its ends); then a filter's, of
+    ``rows`` rows."""
+    rows, lanes = blocks.rows, blocks.lanes
+    grid = (x.shape[2] // lanes, x.shape[0], x.shape[1] // rows)
+    block = pl.BlockSpec((1, rows, lanes), lambda l, b, t: (b, t, l))
+    before = pl.BlockSpec(
+        (1, _CONV_HALO, lanes),
+        lambda l, b, t: (b, jnp.maximum(t * (rows // _CONV_HALO) - 1, 0), l))
+
+    def after(dtype):
+        n = _sublanes(dtype)
+        return pl.BlockSpec(
+            (1, n, lanes),
+            lambda l, b, t: (b, jnp.minimum((t + 1) * (rows // n), x.shape[1] // n - 1), l))
+
+    def filt(rows):
+        return pl.BlockSpec((rows, lanes), lambda l, b, t: (0, l))
+
+    return grid, block, before, after, filt
+
+
+def _conv_call(kernel, blocks, **kwargs):
+    # The interpreter runs a kernel's body as XLA does.
+    return pl.pallas_call(
+        functools.partial(kernel, tile=blocks.tile,
+                          roll=_xla_roll if blocks.interpret else _tpu_roll),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=blocks.interpret, **kwargs)
+
+
+# One jitted entry a kernel, so that a step's text holds a body once a dtype
+# (twice a forward one under remat, whose partial evaluation copies the
+# entry's jaxpr) and not a Mosaic lowering a call site: a KDA layer convolves
+# three projections, forward, replayed and backward.
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _conv_forward(x, w, dtype, blocks):
+    grid, block, before, _, filt = _conv_specs(x, blocks)
+    return _conv_call(
+        _conv_fwd_kernel, blocks, grid=grid,
+        in_specs=[block, before, filt(w.shape[0])], out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(x.shape, dtype),
+    )(x, x, w)
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _conv_backward(x, w, dy, blocks):
+    grid, block, before, after, filt = _conv_specs(x, blocks)
+    taps = w.shape[0]
+    dx, dw = _conv_call(
+        _conv_bwd_kernel, blocks, grid=grid,
+        in_specs=[block, before, after(x.dtype), block, after(dy.dtype), filt(taps)],
+        out_specs=[block, filt(taps * _CONV_HALO)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((taps * _CONV_HALO, x.shape[2]), F32)],
+    )(x, x, x, dy, dy, w)
+    return dx, dw.reshape(taps, _CONV_HALO, -1).sum(1).astype(w.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _conv_silu_pallas(x, w, dtype, blocks):
+    return _conv_forward(x, w, dtype, blocks)
+
+
+def _conv_silu_fwd(x, w, dtype, blocks):
+    return _conv_forward(x, w, dtype, blocks), (x, w)
+
+
+def _conv_silu_bwd(dtype, blocks, residuals, dy):
+    return _conv_backward(*residuals, dy, blocks)
+
+
+_conv_silu_pallas.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def conv_silu(x, w, dtype=F32):
+    """``silu(short_conv(x, w))`` rounded once to ``dtype``: x [B, T, D]
+    float32, w [K, D]. On a TPU (or under the interpreter) where the shape
+    tiles it is one Pallas pass over x forward and one over x and the
+    cotangent backward, which makes the pre-activation again from x (the
+    residuals are x and w) and adds the filter's gradient up in float32;
+    elsewhere it is what this line says, for XLA to differentiate."""
+    blocks = _conv_blocks(x, w)
+    if blocks is None:
+        return jax.nn.silu(short_conv(x, w)).astype(dtype)
+    return _conv_silu_pallas(x.astype(F32), w, jnp.dtype(dtype), blocks)
 
 
 def l2norm(x, eps: float = 1e-6):

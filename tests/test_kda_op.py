@@ -6,7 +6,11 @@ several sequence lengths and at a decay near 0 and near 1; the Pallas scan
 kernels in interpret mode against the same, two heads a grid step and, where
 the heads do not pair off, one; a head through the pair path against the
 same head alone, bit for bit; rows of zeros; the weight's gradient over
-batch rows; the short convolution and the gate beside it. The write strength
+batch rows; the short convolution and the gate beside it; the convolution
+with its SiLU and v's rounding as one Pallas pass forward and one backward
+(``conv_silu``) against ``silu(short_conv)`` and its gradients, across block
+and tile edges, at the sequence's start, over batch rows, rounded to
+bfloat16, and where a shape does not tile. The write strength
 runs over (0, 1) and, as a configuration with negative eigenvalues doubles
 it, over (0, 2): the kernels against the recurrence there, the state they
 carry against the recurrence's own, what a cap at 1 or a doubling left out
@@ -555,6 +559,92 @@ def test_short_conv_is_causal_and_depthwise():
     y2 = np.asarray(kda.short_conv(x.at[:, 7, 2].add(1.0), w))
     assert (y2[:, :7] == y[:, :7]).all()
     assert (np.delete(y2, 2, axis=2) == np.delete(y, 2, axis=2)).all()
+
+
+def conv_reference(x, w, dtype):
+    return jax.nn.silu(kda.short_conv(x, w)).astype(dtype)
+
+
+def conv_inputs(batch, t, channels, dtype, seed=0):
+    """A projection, a filter and a cotangent of the output's dtype."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(keys[0], (batch, t, channels), jnp.float32),
+            jax.random.uniform(keys[1], (4, channels), jnp.float32, -0.5, 0.5),
+            jax.random.normal(keys[2], (batch, t, channels), jnp.float32).astype(dtype))
+
+
+def conv_and_gradients(fn, x, w, dy):
+    y, vjp = jax.vjp(lambda x, w: fn(x, w, dy.dtype), x, w)
+    return (y, *vjp(dy))
+
+
+# (batch, tokens, channels, the output's dtype, the kernels' blocks or None):
+# three blocks of 512 rows and two of 128 lanes, every block eight tiles of 64
+# rows, so the halo crosses tile and block edges both ways; one tile of 16
+# rows, most of it the filter's reach from t < 0; two batch rows, over which
+# and over whose blocks the filter's gradient adds up; v's rounding to
+# bfloat16 (its cotangent arrives in bfloat16, 16 rows a sublane tile); and
+# shapes that do not tile, in tokens and in channels.
+CONV_CASES = {
+    "three-blocks": (1, 1536, 256, jnp.float32, (512, 256, 64, True)),
+    "one-tile": (1, 16, 128, jnp.float32, (16, 128, 16, True)),
+    "batch-of-2": (2, 256, 128, jnp.float32, (256, 128, 64, True)),
+    "bfloat16-out": (2, 192, 128, jnp.bfloat16, (64, 128, 64, True)),
+    "tokens-do-not-tile": (2, 100, 128, jnp.float32, None),
+    "lanes-do-not-tile": (2, 64, 96, jnp.bfloat16, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CONV_CASES))
+def test_the_fused_convolution_is_silu_of_short_conv_and_its_gradients(monkeypatch, case):
+    """Under the interpreter ``conv_silu`` is the Pallas pass where the shape
+    tiles and ``silu(short_conv(x, w))`` as XLA has it where it does not:
+    the values and the gradients in x and in w, to float32's reassociation
+    (the filter's gradient is a sum over every token, in another order)."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    batch, t, channels, dtype, blocks = CONV_CASES[case]
+    x, w, dy = conv_inputs(batch, t, channels, dtype)
+    assert kda._conv_blocks(x, w) == blocks
+    both = jax.make_jaxpr(lambda *a: conv_and_gradients(kda.conv_silu, *a))(x, w, dy)
+    names = [eqn.params["jaxpr"].debug_info.func_name for eqn in pallas_calls(both.jaxpr, [])]
+    assert names == (["_conv_fwd_kernel", "_conv_bwd_kernel"] if blocks else [])
+    y, dx, dw = conv_and_gradients(kda.conv_silu, x, w, dy)
+    y_ref, dx_ref, dw_ref = conv_and_gradients(conv_reference, x, w, dy)
+    assert (y.dtype, dx.dtype, dw.dtype) == (dtype, jnp.float32, jnp.float32)
+    if blocks is None:
+        assert all(bool((a == b).all()) for a, b in ((y, y_ref), (dx, dx_ref), (dw, dw_ref)))
+        return
+    if dtype == jnp.bfloat16:  # one rounding, of float32 values an ulp apart at most
+        assert float(jnp.mean(y != y_ref)) < 1e-3
+    np.testing.assert_allclose(
+        y.astype(jnp.float32), y_ref.astype(jnp.float32),
+        rtol=1e-2 if dtype == jnp.bfloat16 else 1e-5, atol=1e-6)
+    np.testing.assert_allclose(dx, dx_ref, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(dw, dw_ref, rtol=1e-5, atol=1e-5 * float(jnp.abs(dw_ref).max()))
+
+
+def test_the_fused_convolution_is_causal_across_its_blocks_and_depthwise(monkeypatch):
+    """A bump at token 7 moves nothing before it and nothing after token 10,
+    one at a block's last token moves the next block's first three (the
+    halo), and neither moves another channel or batch row; the gradient in x
+    reaches back as far and no further. Blocks of 32 rows in tiles of 16."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(kda, "_CONV_ROWS", 32)
+    monkeypatch.setattr(kda, "_CONV_TILE", 16)
+    x, w, _ = conv_inputs(2, 96, 128, jnp.float32, seed=1)
+    assert kda._conv_blocks(x, w) == (32, 128, 16, True)
+    y = np.asarray(kda.conv_silu(x, w))
+    np.testing.assert_allclose(y, conv_reference(x, w, jnp.float32), rtol=1e-5, atol=1e-6)
+    for token in (7, 15, 31, 95):
+        moved = np.asarray(kda.conv_silu(x.at[1, token, 2].add(1.0), w)) != y
+        assert moved[1, token:token + 4, 2].all()
+        moved[1, token:token + 4, 2] = False
+        assert not moved.any(), token
+        # dy at tokens token .. token + 3 reaches x at token, and at no other
+        reach = jax.grad(lambda x: kda.conv_silu(x, w)[1, token:token + 4, 2].sum())(x)
+        reached = np.argwhere(np.asarray(reach) != 0)
+        assert {tuple(at[[0, 2]]) for at in reached} == {(1, 2)}
+        assert set(reached[:, 1]) == set(range(max(token - 3, 0), min(token + 4, 96)))
 
 
 def test_the_gate_is_a_negative_log_decay_per_channel():

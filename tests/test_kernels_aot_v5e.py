@@ -328,6 +328,40 @@ def test_kda_kernels_compile_for_v5e(v5e, t, h):
     )
 
 
+# The KDA mixer's convolution, SiLU and rounding as one pass, over one
+# projection of longctx-16k's (b1 x s16384, 32 heads of 128) and of
+# Solar-Open2's (b1 x s4096, 64 heads of 128) at the blocks ``conv_silu``
+# gives them: float32 out for q and k, bfloat16 for v, whose cotangent comes
+# back in bfloat16 with a halo of 16 rows.
+@pytest.mark.parametrize("t,channels", [(16384, 4096), (4096, 8192)])
+def test_conv_kernels_compile_for_v5e(v5e, monkeypatch, t, channels):
+    import base64
+    import re
+
+    from benchmarks.lib import trace
+
+    monkeypatch.setattr(kda._attention, "_on_tpu", lambda: True)
+    x, w = ((1, t, channels), jnp.float32), ((4, channels), jnp.float32)
+    blocks = kda._conv_blocks(jax.ShapeDtypeStruct(*x), jax.ShapeDtypeStruct(*w))
+    assert blocks == (512, 512, 64, False)
+
+    def kernels(text):
+        """As a profile's reader names them: the backward's module holds no
+        frame of the forward's, which is traced first."""
+        return [trace.kernel_name(line) for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line]
+
+    for dtype in (jnp.float32, jnp.bfloat16):
+        forward = _compile_for(
+            v5e, lambda x, w: kda._conv_forward(x, w, jnp.dtype(dtype), blocks), x, w)
+        backward = _compile_for(
+            v5e, lambda x, w, dy: kda._conv_backward(x, w, dy, blocks), x, w, (x[0], dtype))
+        assert (kernels(forward), kernels(backward)) == (
+            ["_conv_fwd_kernel"], ["_conv_bwd_kernel"])
+        module = re.search(r'"body":"([^"]*)"', backward).group(1)
+        assert b"_conv_fwd_kernel" not in base64.b64decode(module)
+
+
 # The models the benchmark already had lower to the Pallas kernels they had
 # before a layer could choose its mixer and FFN: read by this same code at
 # commit 57913f4, each configuration file at its rehearsal size, b1 x s256.
@@ -506,6 +540,31 @@ def test_a_steps_replay_runs_no_kda_forward_and_its_backward_reads_the_inverses(
     wrote = sum(f"{states}, {inverses})" in line for line in calls)
     read = sum(f"{states}, {inverses}," in line for line in calls)
     assert (wrote, read) == (layers, layers)
+
+
+@pytest.mark.parametrize("step,layers", [("kimi_linears_step", 4), ("solar_open2s_step", 3)])
+def test_a_kda_layer_convolves_its_three_projections_by_the_kernels(request, step, layers):
+    """A layer's q, k and v each go through ``_conv_forward`` in the forward
+    pass and again in the replay (the remat policy keeps none of the
+    convolution's outputs: 0.67 GB a layer at 16k tokens) and through
+    ``_conv_backward`` once. The bodies stand behind the jitted entries: a
+    backward one a cotangent's dtype (float32 of q and k, bfloat16 of v), a
+    forward one a dtype and again for the replay, whose partial evaluation
+    copies the entry. No pad of a [B, T, H * d] projection is left to XLA."""
+    import re
+
+    from benchmarks.lib import checks
+
+    cell, text = request.getfixturevalue(step)
+    calls = {entry: len(re.findall(rf"call @{entry}(?:_\d+)?\(", text))
+             for entry in ("_conv_forward", "_conv_backward")}
+    assert calls == {"_conv_forward": 2 * 3 * layers, "_conv_backward": 3 * layers}
+    bodies = checks.count_pallas_kernels(text, ("_conv_fwd_kernel", "_conv_bwd_kernel"))
+    assert bodies == {"_conv_fwd_kernel": 4, "_conv_bwd_kernel": 2}
+    traffic, kda_cfg = cell["traffic"], cell["config"]["linear_attn_config"]
+    channels = kda_cfg["num_heads"] * kda_cfg["head_dim"]
+    padded = f"tensor<{traffic['batch']}x{traffic['seq'] + 3}x{channels}xf32>"
+    assert padded not in text
 
 
 def test_kimi_linears_step_holds_its_kernels_and_no_gather_over_the_bound(kimi_linears_step):
