@@ -80,6 +80,11 @@ class LlamaConfig:
     # weighted sum of them and writing back through two more maps. None: the
     # one stream of x + F(x).
     hyper_connections: Optional[HyperConnections] = None
+    # The OLMo 2/3 order: a layer norms what its sublayers give and not what
+    # they take, h = x + RMSNorm(mixer(x)), out = h + RMSNorm(ffn(h)), the
+    # sublayers reading the raw stream. False: the pre-norm of every other
+    # family, h = x + mixer(RMSNorm(x)).
+    norm_after: bool = False
 
     @property
     def head_dim_(self) -> int:
@@ -162,7 +167,8 @@ CONFIGS: Dict[str, LlamaConfig] = {
 # ring's rule (ops/ring_attention.py) is not tagged: its cell has no memory to
 # spare and replays 1% of its step.
 KERNEL_RESIDUALS = ("flash_o", "flash_lse", "kda_o", "kda_states", "kda_t",
-                    "hc_read", "hc_maps", "hc_write")
+                    "hc_read", "hc_maps", "hc_write", "gdn_o", "gdn_states",
+                    "gdn_t")
 # One object for every caller: JAX caches a jitted function's partial
 # evaluation by the policy's identity, and a second ``_through`` (xing4.py's
 # module) with a policy of its own would lower every jitted kernel entry's
@@ -322,6 +328,11 @@ class DecoderLayer(nn.Module):
         ffn_name, ffn = self.ffn
         if cfg.hyper_connections is not None:
             return _hyper_connected(self, x, positions)
+        if cfg.norm_after:
+            norm = lambda name, y: RMSNorm(cfg.rms_eps, cfg.param_dtype, name=name)(y)  # noqa: E731
+            h = x + norm(tracing.POST_MIXER_NORM, mixer(cfg, name=mixer_name)(x, positions))
+            out = h + norm(tracing.POST_FFN_NORM, ffn(cfg, name=ffn_name)(h))
+            return with_logical_constraint(out, ("batch", "seq", "embed"))
         h = x + mixer(cfg, name=mixer_name)(
             RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.INPUT_NORM)(x), positions
         )

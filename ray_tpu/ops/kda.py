@@ -189,7 +189,10 @@ def _conv_blocks(x, w):
     if not (_attention._on_tpu() or interpret):
         return None
     rows = _largest(_CONV_ROWS, 16, x.shape[1])
-    lanes = _largest(_CONV_LANES, 128, x.shape[2])
+    # The most whole vregs of lanes, up to _CONV_LANES, that divide D: 512 of
+    # 4,096 or 8,192 channels, 384 of 5,760 (45 vregs, which no power of two
+    # above one divides).
+    lanes = next((n for n in range(_CONV_LANES, 0, -128) if not x.shape[2] % n), 0)
     if not rows or not lanes or w.shape[0] - 1 > _CONV_HALO:
         return None
     return _ConvBlocks(rows, lanes, min(rows, _CONV_TILE), interpret)
@@ -609,6 +612,18 @@ def _head_chunk(St, q, k, v, beta, G, last, last_dv, roll=_xla_roll, T=None):
     return jnp.exp(last_dv) * St + _per_head(_tn, u, kd, p), o, T
 
 
+def _normed_qk(q, k, dt, scale, l2_eps):
+    """The raw float32 q and k of a chunk as the products take them:
+    ``l2norm(q) * scale`` and ``l2norm(k)``, each rounded once to ``dt``."""
+    return (l2norm(q, l2_eps) * scale).astype(dt), l2norm(k, l2_eps).astype(dt)
+
+
+def _rms_normed(o, rms_eps):
+    """The float32 o of a chunk over its root mean square, a row (a head's
+    channels of one token) at a time."""
+    return o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + rms_eps)
+
+
 def _normed_chunk(St, q, k, v, beta, G, last, last_dv, gate, weight, *, norm,
                   roll=_xla_roll, T=None):
     """``_head_chunk`` between the mixer's normalisations, each over a row
@@ -621,11 +636,9 @@ def _normed_chunk(St, q, k, v, beta, G, last, last_dv, gate, weight, *, norm,
     the cotangents are those of the raw q and k, of the gate and of the
     weight."""
     scale, l2_eps, rms_eps = norm
-    q = (l2norm(q, l2_eps) * scale).astype(v.dtype)
-    k = l2norm(k, l2_eps).astype(v.dtype)
+    q, k = _normed_qk(q, k, v.dtype, scale, l2_eps)
     St, o, T = _head_chunk(St, q, k, v, beta, G, last, last_dv, roll, T)
-    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + rms_eps)
-    return St, o * weight * jax.nn.sigmoid(gate.astype(F32)), T
+    return St, _rms_normed(o, rms_eps) * weight * jax.nn.sigmoid(gate.astype(F32)), T
 
 
 # ------------------------------------------------------------ Pallas kernels
@@ -954,3 +967,317 @@ def chunk_kda(q, k, v, g, beta, gate, weight, *, scale, rms_eps, l2_eps=1e-6):
     o = run(flat(q), flat(k), flat(v), flat(g.astype(F32)), beta, flat(gate),
             weight.astype(F32)[None], heads, (scale, l2_eps, rms_eps))
     return o.reshape(batch, t + pad, heads, -1)[:, :t]
+
+
+# ------------------------------------------- the scalar decay (Gated DeltaNet)
+# The same rule with one log-decay g a head and token where KDA has one a
+# channel, and key and value heads of widths of their own (dk != dv):
+#
+#     S_t = (I - b_t k_t k_t^T) e^{g_t} S_{t-1} + b_t k_t v_t^T,  o_t = S_t^T q_t
+#
+# With a scalar the chunk's decays are one [C, C] matrix a head, exp(G_t -
+# G_s) for s <= t with G the running sum of g inside the chunk: the exponent
+# is a difference of two sums of non-positive terms, masked to zero above the
+# diagonal before it is taken, so it is never positive and A and Aqk are one
+# product each (k k^T, q k^T) times that matrix, where the per-channel form
+# needs six level products each. g, its running sum and its cotangent stay
+# [B, H, T, 1]: no copy of the decay over a head's channels exists in HBM.
+#
+# Layout: every operand lies [B, H, T, d], a grid step's block p heads' chunk
+# whole in its last extent, so that a head dim that fills no vreg (96) or one
+# and a half (192) tiles without a lane slice; the mixer's [B, T, H, d] are
+# transposed around the call. The running sum is six sublane rolls and adds
+# on a lane-broadcast copy of g, its row form one transposition; nothing of
+# it is a matmul. Everything after the decays is ``_head_chunk``'s: the
+# inverse by ``_unit_lower_inverse`` (handed T in the backward kernel), W, U0,
+# and the state's three products a head.
+_LANES = 128
+
+
+def _running_sums(g, p, roll):
+    """g [p * C, 1] float32, p heads' chunk of log-decays on rows -> (G [p *
+    C, _LANES], the running sum inside each head's chunk on every lane; tot,
+    a list of p [1, _LANES]: each head's sum over the chunk)."""
+    n = g.shape[0]
+    gb = jnp.broadcast_to(g, (n, _LANES))
+    at = jax.lax.broadcasted_iota(jnp.int32, (n, _LANES), 0) % CHUNK
+    G = gb
+    for b in _LEVELS:
+        G = G + jnp.where(at >= b, roll(G, b), 0.0)
+    return G, [jnp.sum(part, 0, keepdims=True) for part in _heads_of(gb, p)]
+
+
+def _row_form(G, n):
+    """G [n, _LANES], every lane of a row the same -> [n, n] with entry (t,
+    s) = G[s]: one transposition of a [_LANES, _LANES] tile."""
+    tile = jnp.concatenate([G] * (_LANES // n)) if n < _LANES else G
+    return tile.T[:n, :n]
+
+
+def _lanes(x, d):
+    """x [r, _LANES], every lane of a row the same, as [r, d]."""
+    if d <= _LANES:
+        return x[:, :d]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], d))
+
+
+def _rows_of(tot, rows, lanes):
+    """The heads' [1, _LANES] values, each on ``rows`` rows, stacked."""
+    return jnp.concatenate(
+        [jnp.broadcast_to(_lanes(t, lanes), (rows, lanes)) for t in tot])
+
+
+def _gdn_head_chunk(St, q, k, v, beta, g, roll=_xla_roll, T=None):
+    """``_head_chunk`` with a scalar decay: St [P * dv, dk] float32, q, k [P *
+    C, dk], v [P * C, dv], beta and g [P * C, 1] float32, g the log-decays
+    themselves. -> (the states at the chunk's end, o [P * C, dv], T)."""
+    dt = q.dtype
+    n, dk = q.shape
+    p = n // CHUNK
+    dv = v.shape[1]
+    masks, eye = _masks(n)
+    row, col = _rows_cols(n)
+    lower = (row >= col) & _same_head(row, col)
+    G, tot = _running_sums(g, p, roll)
+    # exp(G_t - G_s) for s <= t of one head: the exponent is zero elsewhere
+    # before it is taken, and non-positive where it stands.
+    Gn = _lanes(G, n)
+    decay = jnp.where(lower, jnp.exp(jnp.where(
+        lower, jnp.minimum(Gn - _row_form(G, n), 0.0), 0.0)), 0.0)
+    kf, qf = k.astype(F32), q.astype(F32)
+    A = jnp.where(eye, 0.0, _nt(k, k) * decay) * beta
+    Aqk = _nt(q, k) * decay
+    T = _unit_lower_inverse(dt, A, masks, eye, T)
+    to_row = jnp.exp(_lanes(G, dk))
+    w = _nn(T, (kf * to_row * beta).astype(dt)).astype(dt)
+    u0 = _nn(T, (v.astype(F32) * beta).astype(dt))
+    sd = St.astype(dt)
+    u = (u0 - _per_head(_nt, w, sd, p)).astype(dt)
+    o = _per_head(_nt, (qf * to_row).astype(dt), sd, p) + _nn(Aqk.astype(dt), u)
+    # From a row to the chunk's end: the sum of the g after it.
+    ahead = jnp.minimum(_rows_of(tot, CHUNK, dk) - _lanes(G, dk), 0.0)
+    kd = (kf * jnp.exp(ahead)).astype(dt)
+    return jnp.exp(_rows_of(tot, dv, dk)) * St + _per_head(_tn, u, kd, p), o, T
+
+
+def _normed_gdn_chunk(St, q, k, v, beta, g, gate, weight, *, norm,
+                      roll=_xla_roll, T=None):
+    """``_gdn_head_chunk`` between the mixer's normalisations, as
+    ``_normed_chunk`` has them, with the Gated DeltaNet's output gate: o
+    leaves through the per-head RMSNorm times SiLU(gate)."""
+    scale, l2_eps, rms_eps = norm
+    q, k = _normed_qk(q, k, v.dtype, scale, l2_eps)
+    St, o, T = _gdn_head_chunk(St, q, k, v, beta, g, roll, T)
+    return St, _rms_normed(o, rms_eps) * weight * jax.nn.silu(gate.astype(F32)), T
+
+
+def _heads_on_rows(ref):
+    """A block [1, .., p, r, d] of p heads -> [p * r, d], the heads on rows."""
+    lead = (0,) * (len(ref.shape) - 3)
+    return jnp.concatenate([ref[(*lead, i)] for i in range(ref.shape[-3])])
+
+
+def _write_heads(ref, x):
+    """``_heads_on_rows`` undone into a block [1, .., p, r, d]."""
+    lead = (0,) * (len(ref.shape) - 3)
+    for i, part in enumerate(_heads_of(x, ref.shape[-3])):
+        ref[(*lead, i)] = part.astype(ref.dtype)
+
+
+def _gdn_fwd_kernel(g_ref, q_ref, k_ref, v_ref, beta_ref, gate_ref, w_ref,
+                    o_ref, *rest, norm):
+    # rest: (the states' and the inverses' outputs, the scratch) or the
+    # scratch alone.
+    s_ref, t_ref, st_scr = rest if len(rest) == 3 else (None, None, *rest)
+    step, p = pl.program_id(2), beta_ref.shape[1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        st_scr[step] = jnp.zeros(st_scr.shape[1:], F32)
+
+    St = st_scr[step]
+    if s_ref is not None:
+        _write_heads(s_ref, St)
+    st_scr[step], o, T = _normed_gdn_chunk(
+        St, *(_heads_on_rows(ref) for ref in
+              (q_ref, k_ref, v_ref, beta_ref, g_ref, gate_ref)),
+        w_ref[...], norm=norm, roll=_roll_here(),
+    )
+    _write_heads(o_ref, o)
+    if t_ref is not None:
+        t_ref[0, 0, 0] = _diagonal(T, p)
+
+
+def _gdn_bwd_kernel(g_ref, q_ref, k_ref, v_ref, beta_ref, gate_ref, w_ref,
+                    s_ref, t_ref, do_ref, dq_ref, dk_ref, dv_ref, dbeta_ref,
+                    dg_ref, dgate_ref, dw_ref, dst_scr, *, norm):
+    step, p = pl.program_id(2), beta_ref.shape[1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        dst_scr[step] = jnp.zeros(dst_scr.shape[1:], F32)
+
+    # The weight's cotangent adds up over a batch row's steps in its output
+    # block, which stays in VMEM while the block's index (the row) stands.
+    @pl.when((pl.program_id(1) == 0) & (step == 0))
+    def _init_dw():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, F32)
+
+    chunk = functools.partial(_normed_gdn_chunk, norm=norm, roll=_roll_here(),
+                              T=_block_diagonal(t_ref[0, 0, 0], p))
+    _, vjp = jax.vjp(
+        lambda *operands: chunk(*operands)[:2],
+        _heads_on_rows(s_ref),
+        *(_heads_on_rows(ref) for ref in
+          (q_ref, k_ref, v_ref, beta_ref, g_ref, gate_ref)),
+        w_ref[...],
+    )
+    dst_scr[step], dq, dk_, dv_, dbeta, dg, dgate, dw = vjp(
+        (dst_scr[step], _heads_on_rows(do_ref).astype(F32))
+    )
+    for ref, x in ((dq_ref, dq), (dk_ref, dk_), (dv_ref, dv_), (dbeta_ref, dbeta),
+                   (dg_ref, dg), (dgate_ref, dgate)):
+        _write_heads(ref, x)
+    dw_ref[0] += dw
+
+
+def _gdn_specs(heads, dk, dv, chunk_of):
+    """BlockSpecs over grid (batch, step, heads // p), as ``_specs``, for
+    operands that lie [B, H, T, d]: a block is p heads' chunk, whole in its
+    last extent (d, or 1 for beta and g). The states lie [B, N, H, dv, dk];
+    the chunks' inverses and the norm's weight are ``_specs``'s."""
+    p = _heads_a_step(heads)
+    shared = _specs(heads, dk, dv, chunk_of)
+
+    def rows(d):
+        return pl.BlockSpec((1, p, CHUNK, d), lambda b, n, h: (b, h, chunk_of(n), 0))
+
+    return {
+        "k": rows(dk), "v": rows(dv), "scalar": rows(1),
+        "state": pl.BlockSpec((1, 1, p, dv, dk),
+                              lambda b, n, h: (b, chunk_of(n), h, 0, 0)),
+        "inverse": shared["inverse"], "weight": shared["weight"],
+        "dweight": shared["dweight"], "steps": shared["steps"],
+        "states": shared["states"],
+    }
+
+
+def _gdn_forward_pallas(q, k, v, g, beta, gate, weight, norm, states):
+    batch, heads, t, dk = q.shape
+    dv, n = v.shape[3], t // CHUNK
+    s = _gdn_specs(heads, dk, dv, lambda i: i)
+    return pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, norm=norm),
+        grid=(batch, n, s["steps"]),
+        in_specs=[s["scalar"], s["k"], s["k"], s["v"], s["scalar"], s["v"],
+                  s["weight"]],
+        out_specs=[s["v"], s["state"], s["inverse"]][:1 + 2 * states],
+        out_shape=[
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((batch, n, heads, dv, dk), F32),
+            jax.ShapeDtypeStruct(
+                (batch, n, s["steps"], CHUNK, _heads_a_step(heads) * CHUNK), v.dtype),
+        ][:1 + 2 * states],
+        scratch_shapes=[s["states"]],
+        compiler_params=_params(),
+        interpret=_attention._interpret(),
+    )(g, q, k, v, beta, gate, weight)
+
+
+def _gdn_backward_pallas(q, k, v, g, beta, gate, weight, states, inverses, do,
+                         norm):
+    batch, heads, t, dk = q.shape
+    dv, n = v.shape[3], t // CHUNK
+    s = _gdn_specs(heads, dk, dv, lambda i: n - 1 - i)
+    return pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, norm=norm),
+        grid=(batch, n, s["steps"]),
+        in_specs=[s["scalar"], s["k"], s["k"], s["v"], s["scalar"], s["v"],
+                  s["weight"], s["state"], s["inverse"], s["v"]],
+        out_specs=[s["k"], s["k"], s["v"], s["scalar"], s["scalar"], s["v"],
+                   s["dweight"]],
+        out_shape=[
+            *(jax.ShapeDtypeStruct(x.shape, x.dtype)
+              for x in (q, k, v, beta, g, gate)),
+            jax.ShapeDtypeStruct((batch, *weight.shape), F32),
+        ],
+        scratch_shapes=[s["states"]],
+        compiler_params=_params(),
+        interpret=_attention._interpret(),
+    )(g, q, k, v, beta, gate, weight, states, inverses, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _gdn_pallas(q, k, v, g, beta, gate, weight, norm):
+    """The gated, normalised o [B, H, T, dv] from q, k [B, H, T, dk] raw, v
+    and the gate [B, H, T, dv], g and beta [B, H, T, 1] and the norm's weight
+    [1, dv], T a whole number of chunks. As ``_kda_pallas``: outside a
+    gradient neither states nor inverses are written; under one the forward
+    rule writes and names both and o (``gdn_o``, ``gdn_states``, ``gdn_t``:
+    models/llama.py KERNEL_RESIDUALS), so that a replay which keeps the three
+    runs no forward kernel."""
+    return _gdn_forward_pallas(q, k, v, g, beta, gate, weight, norm,
+                               states=False)[0]
+
+
+def _gdn_pallas_fwd(q, k, v, g, beta, gate, weight, norm):
+    o, states, inverses = _gdn_forward_pallas(q, k, v, g, beta, gate, weight,
+                                              norm, states=True)
+    o, states = checkpoint_name(o, "gdn_o"), checkpoint_name(states, "gdn_states")
+    inverses = checkpoint_name(inverses, "gdn_t")
+    return o, (q, k, v, g, beta, gate, weight, states, inverses)
+
+
+def _gdn_pallas_bwd(norm, residuals, do):
+    dq, dk, dv, dbeta, dg, dgate, dw = _gdn_backward_pallas(
+        *residuals, do.astype(residuals[2].dtype), norm
+    )
+    return dq, dk, dv, dg, dbeta, dgate, dw.sum(0)
+
+
+_gdn_pallas.defvjp(_gdn_pallas_fwd, _gdn_pallas_bwd)
+
+
+def _gdn_xla(q, k, v, g, beta, gate, weight, norm):
+    """The same function of the same layouts under ``lax.scan``, one head a
+    call, for JAX to differentiate: where there is no TPU."""
+    batch, heads, t, dk = q.shape
+
+    def chunks(x):  # [B, H, T, d] -> [N, B, H, C, d]
+        return jnp.moveaxis(x.reshape(batch, heads, t // CHUNK, CHUNK, -1), 2, 0)
+
+    def one(St, *operands):
+        return _normed_gdn_chunk(St, *operands, weight, norm=norm)[:2]
+
+    def step(St, chunk):
+        return jax.vmap(jax.vmap(one))(St, *chunk)
+
+    _, o = jax.lax.scan(
+        step, jnp.zeros((batch, heads, v.shape[3], dk), F32),
+        tuple(chunks(x) for x in (q, k, v, beta, g, gate)))
+    return jnp.moveaxis(o, 0, 2).reshape(v.shape).astype(v.dtype)
+
+
+def chunk_gdn(q, k, v, g, beta, gate, weight, *, scale, rms_eps, l2_eps=1e-6):
+    """A Gated DeltaNet mixer from its convolutions' outputs to its output
+    projection's input, as ``chunk_kda`` is KDA's: the scalar-decay
+    recurrence above, chunked, of ``l2norm(q, l2_eps) * scale`` and
+    ``l2norm(k, l2_eps)``, then o's RMSNorm over a head's channels
+    (``weight`` [dv]) times ``silu(gate)``. q, k [B, T, H, dk] raw float32;
+    v, gate [B, T, H, dv], the gate before its SiLU; g [B, T, H] float32
+    log-decay (<= 0), one a head and token; beta [B, T, H] in (0, 2). Returns
+    [B, T, H, dv] in v's dtype. Differentiable in all seven."""
+    t = q.shape[1]
+    pad = -t % CHUNK
+    if pad:
+        # Padding tokens write nothing (beta 0) and decay nothing (g 0).
+        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+        q, k, v, gate = (jnp.pad(x, widths) for x in (q, k, v, gate))
+        g, beta = (jnp.pad(x, widths[:3]) for x in (g, beta))
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    scalar = lambda x: x.astype(F32).transpose(0, 2, 1)[..., None]  # noqa: E731
+    run = _gdn_pallas if _attention._on_tpu() or _attention._interpret() else _gdn_xla
+    o = run(heads_first(q), heads_first(k), heads_first(v), scalar(g),
+            scalar(beta), heads_first(gate), weight.astype(F32)[None],
+            (scale, l2_eps, rms_eps))
+    return heads_first(o)[:, :t]

@@ -65,6 +65,7 @@ MOE_SHARED = "shared"  # inside moe: the shared expert every token passes
 # The mixers' flax names, which reach op_name as the attention's "attn" does.
 KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer; Solar-Open2's KDA layers too)
 MLA = "mla"  # the latent-attention mixer (models/mla.py MLAMixer)
+GDN = "gdn"  # the scalar-decay gated delta-rule mixer (models/olmo_hybrid.py GDNMixer); conv, gate and scan inside it as inside kda, scan holding ops/kda.py chunk_gdn and the transpositions around it
 KDA_CONV = "conv"  # inside kda: the short convolutions of q, k, v and their SiLU
 KDA_GATE = "gate"  # inside kda: the log-decay g and the write strength beta, with its doubling where the config writes in (0, 2)
 KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's and o's norms and the output gate), v's rounding, beta's transpose
@@ -90,13 +91,15 @@ SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MLA_LATENT, MLA_ROPE, MLA_Q_LATENT, ATTN_ROPE, ATTN_GATE, HC, HC_PRE,
           HC_SINKHORN, HC_POST, HC_STREAMS, MTP, LOSS, LOSS_HEAD)
 # Flax module names, bound in the model classes' ``blocks``.
-MIXERS = (KDA, MLA, ATTN, SWA)
+MIXERS = (KDA, MLA, ATTN, SWA, GDN)
 # The decoder body's flax names (models/llama.py, xing4.py), a layer's and
 # above: parameter trees and checkpoints hold them, so none is ever renamed.
 EMBED = "embed_tokens"  # the embedding table's flax name; models/llama.py _lookup opens it as a scope around what it does outside the module (the one-hot product where a mesh splits the table, the constraint on the result)
 LAYER = "layers_"  # a decoder layer is LAYER + its index
 INPUT_NORM = "input_norm"  # a layer's RMSNorm before its mixer
 POST_ATTN_NORM = "post_attn_norm"  # a layer's RMSNorm before its FFN
+POST_MIXER_NORM = "post_mixer_norm"  # where cfg.norm_after: a layer's RMSNorm of its mixer's output, before the residual sum
+POST_FFN_NORM = "post_ffn_norm"  # where cfg.norm_after: a layer's RMSNorm of its FFN's output, before the residual sum
 MLP = "mlp"  # the dense SwiGLU FFN
 MOE = "moe"  # the expert layer (models/mixtral.py MoELayer)
 MIXER_HC = "mixer_hc"  # a hyper-connected layer's connection around its mixer
@@ -110,7 +113,7 @@ MTP_LAYER = "mtp_layer"  # inside mtp: the module's decoder layer
 MTP_NORM = "mtp_norm"  # inside mtp: the norm before the shared head
 BODY = (EMBED, LAYER, INPUT_NORM, POST_ATTN_NORM, MLP, MOE, MIXER_HC, FFN_HC,
         FINAL_NORM, LM_HEAD, MTP_HIDDEN_NORM, MTP_EMBED_NORM, MTP_PROJ,
-        MTP_LAYER, MTP_NORM)
+        MTP_LAYER, MTP_NORM, POST_MIXER_NORM, POST_FFN_NORM)
 
 _OFF = contextlib.nullcontext()
 # The flight recorder, while this process holds a train session.

@@ -657,3 +657,139 @@ def test_the_gate_is_a_negative_log_decay_per_channel():
         g, -jnp.asarray([1.0, 4.0, 16.0])[:, None] * jax.nn.softplus(f), rtol=1e-6)
     x = jnp.asarray(np.random.default_rng(1).normal(size=(3, 8)), jnp.float32)
     np.testing.assert_allclose(jnp.sum(kda.l2norm(x) ** 2, -1), 1.0, rtol=1e-4)
+
+
+# ------------------------------------------- the scalar decay (``chunk_gdn``)
+# Gated DeltaNet's road: one log-decay a head and token, key and value heads
+# of widths of their own that fill no vreg, SiLU for the output gate's
+# sigmoid. Against the same token-by-token recurrence, fed g broadcast.
+GDK, GDV = 24, 48
+chunk_gdn = functools.partial(kda.chunk_gdn, scale=GDK ** -0.5, rms_eps=RMS_EPS)
+
+
+def gdn_inputs(t, decay, seed=0, heads=H):
+    """As ``inputs`` with beta over (0, 2), g [B, T, H] and dk != dv."""
+    r = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)  # noqa: E731
+    q, k, v = draw(B, t, heads, GDK), draw(B, t, heads, GDK), draw(B, t, heads, GDV)
+    g = -jnp.asarray(r.uniform(0.5, 1.5, size=(B, t, heads)), jnp.float32) * decay
+    beta = 2.0 * jax.nn.sigmoid(3.0 * draw(B, t, heads))
+    return q, k, v, g, beta, draw(B, t, heads, GDV), 1.0 + 0.3 * draw(GDV)
+
+
+def gdn_oracle(q, k, v, g, beta, gate, weight):
+    """What ``chunk_gdn`` computes, the plain way."""
+    def one(q, k, v, g, b):  # one (batch, head): [T, d]
+        def step(S, x):
+            q, k, v, g, b = x
+            S = jnp.exp(g) * S
+            S = S + b * jnp.outer(k, v - S.T @ k)
+            return S, S.T @ q
+
+        return jax.lax.scan(step, jnp.zeros((GDK, GDV)), (q, k, v, g, b))[1]
+
+    heads = jax.vmap(one, in_axes=(1, 1, 1, 1, 1), out_axes=1)
+    with jax.default_matmul_precision("highest"):
+        o = jax.vmap(heads)(kda.l2norm(q) * GDK ** -0.5, kda.l2norm(k), v, g, beta)
+    normed = RMSNorm(RMS_EPS).apply({"params": {"scale": weight}}, o)
+    return normed * jax.nn.silu(gate)
+
+
+def gdn_compare(t, decay, heads=H):
+    args = gdn_inputs(t, decay, heads=heads)
+    assert float(args[4].max()) > 1.9 and float(args[4].min()) < 0.1
+    w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
+    want = gdn_oracle(*args)
+    got = jax.jit(lambda *a: chunk_gdn(*a))(*args)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5 * float(jnp.abs(want).max()))
+    grads = jax.jit(jax.grad(
+        lambda *a: jnp.sum(chunk_gdn(*a) * w), argnums=range(7)))(*args)
+    wanted = jax.grad(lambda *a: jnp.sum(gdn_oracle(*a) * w), argnums=range(7))(*args)
+    for name, a, b in zip(NAMES, grads, wanted):
+        assert a.shape == b.shape and bool(jnp.isfinite(a).all()), name
+        # Under a strong decay g's cotangent is what cancellation leaves of
+        # terms a thousand times its size.
+        atol = (2e-3 if name == "g" else 2e-4) * float(jnp.abs(b).max())
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=atol, err_msg=name)
+
+
+@pytest.mark.parametrize("t,decay", [(64, 0.3), (100, 1e-3), (192, 30.0)],
+                         ids=["64-0.3", "100-0.001", "192-30.0"])
+def test_the_scalar_decay_chunked_form_and_its_vjp_are_the_recurrence(t, decay):
+    """The XLA form (``lax.scan`` over ``_normed_gdn_chunk``), beta over (0,
+    2), a weak and a strong decay, a length that is no whole number of chunks."""
+    gdn_compare(t, decay)
+
+
+@pytest.mark.parametrize("heads", [2, 3], ids=["pair", "odd"])
+@pytest.mark.parametrize("t,decay", [(100, 0.3), (128, 1e-3), (128, 30.0)],
+                         ids=["100-0.3", "128-0.001", "128-30.0"])
+def test_the_scalar_decay_kernels_in_interpret_mode_are_the_recurrence(
+        monkeypatch, t, decay, heads):
+    """``_gdn_fwd_kernel`` and, under the ``custom_vjp``, ``_gdn_bwd_kernel``:
+    forward and all seven cotangents, two heads a grid step and, where they do
+    not pair off, one; heads of 24/48 lanes."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    gdn_compare(t, decay, heads)
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_the_scalar_road_is_the_kda_road_fed_g_broadcast_over_channels(monkeypatch, path):
+    """One function two ways: ``chunk_kda`` given the scalar on every channel
+    and its sigmoid gate times the gate is SiLU's. (Six times the level
+    products and dk times g's bytes: why the scalar has kernels of its own.)"""
+    if path == "pallas":
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    q, k, v, g, beta, gate, weight = gdn_inputs(128, 0.3)
+    got = jax.jit(lambda *a: chunk_gdn(*a))(q, k, v, g, beta, gate, weight)
+    channels = jnp.broadcast_to(g[..., None], q.shape)
+    want = kda.chunk_kda(q, k, v, channels, beta, gate, weight,
+                         scale=GDK ** -0.5, rms_eps=RMS_EPS) * gate
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5 * float(jnp.abs(want).max()))
+
+
+def test_the_scalar_kernels_take_one_decay_a_head_and_token(monkeypatch):
+    """Forward (with its states and inverses under a gradient, o alone outside
+    one) and backward, under names of their own, two heads a step; no operand
+    or result of either is g on a head's channels: the decay and its cotangent
+    are [B, H, T, 1]."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    args = gdn_inputs(128, 0.3, heads=4)
+    forward = jax.make_jaxpr(lambda *a: chunk_gdn(*a))(*args)
+    assert pallas_outputs(forward.jaxpr) == [1]
+    both = jax.make_jaxpr(jax.grad(lambda *a: chunk_gdn(*a).sum()))(*args)
+    calls = pallas_calls(both.jaxpr, [])
+    assert [len(eqn.outvars) for eqn in calls] == [3, 7]
+    assert [eqn.params["grid_mapping"].grid for eqn in calls] == [(B, 2, 2)] * 2
+    names = [eqn.params["jaxpr"].debug_info.func_name for eqn in calls]
+    assert names == ["_gdn_fwd_kernel", "_gdn_bwd_kernel"]
+    for eqn in calls:
+        shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+        assert shapes.count((B, 4, 128, 1)) == (2 if eqn is calls[0] else 4)  # g, beta (and theirs)
+        assert (B, 4, 128, GDK) in shapes and (B, 128, 4 * GDK) not in shapes
+
+
+def test_a_strong_scalar_decay_neither_overflows_nor_loses_the_state():
+    """exp(-50) a step with a weak one every seventh: every exponent the
+    chunk's decay matrix takes is masked to <= 0 before it is taken."""
+    q, k, v, g, beta, gate, weight = gdn_inputs(128, 1.0)
+    g = jnp.full_like(g, -50.0).at[:, ::7].set(-1e-4)
+    got = chunk_gdn(q, k, v, g, beta, gate, weight)
+    assert bool(jnp.isfinite(got).all())
+    want = gdn_oracle(q, k, v, g, beta, gate, weight)
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-4 * float(jnp.abs(want).max()))
+
+
+def test_the_convolutions_lanes_are_the_most_vregs_that_divide_the_channels(monkeypatch):
+    """5,760 channels (Olmo-Hybrid's q with k, and its v) are 45 vregs, which
+    no power of two above one divides: blocks of 384 lanes; 2,880 alone do not
+    tile; 4,096 and 8,192 keep their 512."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    w = jax.ShapeDtypeStruct((4, 1), jnp.float32)
+    lanes = lambda d: kda._conv_blocks(jax.ShapeDtypeStruct((1, 8192, d), jnp.float32), w)  # noqa: E731
+    assert (lanes(5760).lanes, lanes(4096).lanes, lanes(8192).lanes, lanes(384).lanes) == (
+        384, 512, 512, 384)
+    assert lanes(2880) is None
+    x, wts, _ = conv_inputs(1, 64, 384, jnp.float32)
+    np.testing.assert_allclose(
+        kda.conv_silu(x, wts), conv_reference(x, wts, jnp.float32), rtol=1e-6, atol=1e-6)

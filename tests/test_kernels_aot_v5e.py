@@ -328,6 +328,30 @@ def test_kda_kernels_compile_for_v5e(v5e, t, h):
     )
 
 
+# Olmo-Hybrid's scalar-decay scan kernels (pretrain-8k): 30 heads whose key
+# heads are 96 lanes and value heads 192, neither a whole number of vregs, over
+# 8,192 tokens, every operand [B, H, T, d] with a block whole in its last
+# extent, the decay one float a head and token; at an odd head count the block
+# is one head's. No width is padded in what the caller hands over.
+@pytest.mark.parametrize("t,h", [(8192, 30), (1024, 15)])
+def test_gdn_kernels_compile_for_v5e(v5e, t, h):
+    b, dk, dv = 1, 96, 192
+    raw, rows = ((b, h, t, dk), jnp.float32), ((b, h, t, dv), jnp.bfloat16)
+    scalar = ((b, h, t, 1), jnp.float32)
+    operands = (raw, raw, rows, scalar, scalar, rows, ((1, dv), jnp.float32))
+    norm = (dk ** -0.5, 1e-6, 1e-6)
+    p = kda._heads_a_step(h)
+    _compile_for(v5e, lambda *a: kda._gdn_forward_pallas(*a, norm, states=False), *operands)
+    text = _compile_for(
+        v5e, lambda *a: kda._gdn_forward_pallas(*a, norm, states=True), *operands)
+    assert f"f32[{b},{t // kda.CHUNK},{h},{dv},{dk}]" in text  # the states, as published
+    _compile_for(
+        v5e, lambda *a: kda._gdn_backward_pallas(*a, norm), *operands,
+        ((b, t // kda.CHUNK, h, dv, dk), jnp.float32),
+        ((b, t // kda.CHUNK, h // p, kda.CHUNK, p * kda.CHUNK), jnp.bfloat16), rows,
+    )
+
+
 # The KDA mixer's convolution, SiLU and rounding as one pass, over one
 # projection of longctx-16k's (b1 x s16384, 32 heads of 128) and of
 # Solar-Open2's (b1 x s4096, 64 heads of 128) at the blocks ``conv_silu``
@@ -613,6 +637,66 @@ def test_kimi_linears_step_leaves_no_norm_over_a_heads_channels_to_xla(kimi_line
     assert reduced and all("1" in dims.split(", ") for dims in reduced), reduced
     counts = checks.count_pallas_kernels(text, ("_kda_fwd_kernel", "_kda_bwd_kernel"))
     assert counts == {"_kda_fwd_kernel": 4, "_kda_bwd_kernel": 4}
+
+
+@pytest.fixture(scope="module")
+def olmo_hybrids_step(v5e):
+    return _lowered_step(v5e, "olmo-hybrid-7b-l4.pretrain-8k")
+
+
+def test_olmo_hybrids_step_holds_its_kernels_and_its_replay_runs_no_scan(olmo_hybrids_step):
+    """Olmo-Hybrid's step at the benchmark's real size (b1 x s8192, four layers
+    at the published widths): every kernel its configuration states; a linear
+    layer is one ``_gdn_fwd_kernel`` and one ``_gdn_bwd_kernel`` in the whole
+    step (the remat policy keeps ``gdn_o``, ``gdn_states``, ``gdn_t``), the
+    states [B, N, H, 192, 96] float32 and the inverses a pair's two blocks side
+    by side; and no KDA kernel."""
+    from benchmarks.lib import cells, checks
+
+    cell, text = olmo_hybrids_step
+    stated = cells.stated_kernels(cell)
+    counts = checks.count_pallas_kernels(text, stated)
+    assert checks.holds_stated_kernels(counts, stated), (counts, stated)
+    assert (counts["_gdn_fwd_kernel"], counts["_gdn_bwd_kernel"]) == (3, 3)
+    assert (counts["_fwd_kernel"], counts["_bwd_dkv_kernel"], counts["_bwd_dq_kernel"]) == (1, 1, 1)
+    assert checks.count_pallas_kernels(text, ("_kda_fwd_kernel", "_kda_bwd_kernel")) == {
+        "_kda_fwd_kernel": 0, "_kda_bwd_kernel": 0}
+    states, inverses = "tensor<1x128x30x192x96xf32>", "tensor<1x128x15x64x128xbf16>"
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    wrote = sum(f"{states}, {inverses})" in line for line in calls)
+    read = sum(f"{states}, {inverses}," in line for line in calls)
+    assert (wrote, read) == (3, 3)
+
+
+def test_olmo_hybrids_step_convolves_by_the_kernels_and_broadcasts_no_decay(olmo_hybrids_step):
+    """A linear layer's q with k (5,760 channels, float32 out) and its v (5,760,
+    bfloat16 out) each go through ``_conv_forward`` in the forward pass and in
+    the replay and through ``_conv_backward`` once: no ``short_conv`` fallback
+    at these widths (no padded [B, T + 3, 5760] or [B, T + 3, 2880] copy). The
+    decay reaches the scan as [B, H, T, 1]: no [B, T, H, 96] or [B, H, T, 96]
+    array is made from it by a broadcast. And no norm over a head's channels
+    is left to XLA: no float32 [1, 8192, 30, d] or [1, 30, 8192, d] array is
+    reduced over its last axis."""
+    import re
+
+    from benchmarks.lib import checks
+
+    _, text = olmo_hybrids_step
+    calls = {entry: len(re.findall(rf"call @{entry}(?:_\d+)?\(", text))
+             for entry in ("_conv_forward", "_conv_backward")}
+    assert calls == {"_conv_forward": 2 * 2 * 3, "_conv_backward": 2 * 3}
+    bodies = checks.count_pallas_kernels(text, ("_conv_fwd_kernel", "_conv_bwd_kernel"))
+    assert bodies == {"_conv_fwd_kernel": 4, "_conv_bwd_kernel": 2}
+    for channels in (2880, 5760):
+        assert f"tensor<1x8195x{channels}xf32>" not in text
+    broadcasts = re.findall(
+        r"stablehlo\.broadcast_in_dim.*\(tensor<1x(?:8192x30|30x8192)(?:x1)?xf32>\) -> "
+        r"tensor<1x(?:8192x30|30x8192)x96xf32>", text)
+    assert not broadcasts, broadcasts[:2]
+    reduced = re.findall(
+        r"stablehlo\.reduce.* across dimensions = \[3\] : "
+        r"\(tensor<1x(?:8192x30|30x8192)x(?:96|192)xf32>", text)
+    assert not reduced, reduced[:2]
 
 
 # One of Xing4's hyper-connections at the benchmark's real size: four streams

@@ -293,6 +293,37 @@ def solar_paths():
         del os.environ["RAY_TPU_PALLAS_INTERPRET"]
 
 
+@pytest.fixture(scope="module")
+def olmo_paths():
+    """Paths of a tiny Olmo-Hybrid's compiled train step: a Gated DeltaNet
+    layer (key heads of 16, value heads of 32) and a rotation-free, QK-normed
+    full layer, each over the dense MLP, the norms after the sublayers."""
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+    from ray_tpu.models.olmo_hybrid import (
+        OlmoHybridForCausalLM, olmo_hybrid_config,
+    )
+
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"  # the scan's kernels, as on the chip
+    try:
+        cfg = olmo_hybrid_config(
+            layer_types=["linear_attention", "full_attention"], num_layers=2,
+            linear_num_key_heads=4, linear_num_value_heads=4,
+            linear_key_head_dim=16, linear_value_head_dim=32,
+            linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+            rope_parameters={"rope_theta": None}, vocab_size=128, hidden_size=32,
+            intermediate_size=64, num_heads=4, num_kv_heads=4, head_dim=8,
+        )
+        model = OlmoHybridForCausalLM(cfg)
+        ids = jnp.zeros((1, 64), jnp.int32)
+        return paths_of(compiled_step(
+            model,
+            lambda p, i, t: chunked_causal_lm_loss(model, p, i, t, chunk_size=32),
+            ids,
+        ))
+    finally:
+        del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+
+
 # ----------------------------------------------------------- in-graph scopes
 
 
@@ -401,6 +432,30 @@ def test_a_kda_hybrid_over_unrotated_gated_attention_carries_its_scopes(solar_pa
     for layer in ("layers_0", "layers_1"):
         for name in (*MOE_SCOPES, tracing.MOE_SHARED):
             assert any(f"/{layer}/moe/{name}/" in p for p in solar_paths), (layer, name)
+
+
+def test_a_scalar_decay_hybrid_whose_norms_follow_the_sublayers_carries_its_scopes(olmo_paths):
+    """What model.gdn_share selects by (/gdn/ with ``conv``, ``gate`` and
+    ``scan`` inside it, forward, replay and backward) and what names the two
+    norms a layer of the reordered kind has: ``post_mixer_norm`` and
+    ``post_ffn_norm`` in every layer, no ``input_norm`` or ``post_attn_norm``
+    anywhere; the full layer under /attn/ with ``qk_norm`` and no ``rotary``;
+    no /kda/."""
+    gdn = [p for p in olmo_paths if "/layers_0/gdn/" in p]
+    attn = [p for p in olmo_paths if "/layers_1/attn/" in p]
+    assert gdn and attn and not [
+        p for p in olmo_paths
+        if "/layers_1/gdn/" in p or "/layers_0/attn/" in p or "/kda/" in p]
+    for name in (tracing.KDA_CONV, tracing.KDA_GATE, tracing.KDA_SCAN):
+        assert any(f"/gdn/{name}/" in p for p in gdn), name
+    assert {pass_of(p) for p in gdn} >= {"forward", "backward", "replay"}
+    assert any(f"/attn/{tracing.QK_NORM}/" in p for p in attn)
+    assert not [p for p in olmo_paths if f"/{tracing.ATTN_ROPE}/" in p]
+    for layer in ("layers_0", "layers_1"):
+        for name in (tracing.POST_MIXER_NORM, tracing.POST_FFN_NORM, tracing.MLP):
+            assert any(f"/{layer}/{name}/" in p for p in olmo_paths), (layer, name)
+    assert not [p for p in olmo_paths
+                if f"/{tracing.INPUT_NORM}/" in p or f"/{tracing.POST_ATTN_NORM}/" in p]
 
 
 def test_the_hybrid_carries_its_mixers_names_and_scopes(kimi_paths):
@@ -519,7 +574,7 @@ def test_expert_matmuls_are_under_experts_forward_and_backward(moe_paths, branch
 # Every family's compiled step, by fixture (and dispatch branch).
 FAMILIES = ("llama_paths", "qk_norm_paths", "tied_paths", "moe_paths:capacity", "moe_paths:gmm",
             "moe_paths:ragged", "kimi_paths", "sarvam_paths", "xing4_paths",
-            "laguna_paths", "solar_paths")
+            "laguna_paths", "solar_paths", "olmo_paths")
 # Paths that may hold no name of the program, and why.
 EXEMPT = (
     # _positions' arange, inside the model's __call__ and outside every part:
@@ -566,6 +621,7 @@ LOSS_KINDS = {
     "moe_paths:capacity": "full",
     "moe_paths:gmm": "full", "moe_paths:ragged": "full", "kimi_paths": "chunked",
     "sarvam_paths": "chunked", "laguna_paths": "chunked", "solar_paths": "chunked",
+    "olmo_paths": "chunked",
     "xing4_paths": "mtp",
 }
 
@@ -758,13 +814,13 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 
 def test_names_emitted_are_exactly_the_list(
     llama_paths, qk_norm_paths, moe_paths, kimi_paths, sarvam_paths,
-    xing4_paths, laguna_paths, solar_paths, session_lines, actor_lines
+    xing4_paths, laguna_paths, solar_paths, olmo_paths, session_lines, actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + [
+    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + olmo_paths + [
         p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:  # a scope directly under a transform is in its brackets
         assert any(f"/{name}/" in p or f"({name})/" in p for p in paths), name

@@ -24,7 +24,7 @@ import ray_tpu
 from ray_tpu.models import hyper_connections
 from ray_tpu.models.llama import KERNEL_RESIDUALS, LlamaConfig, remat_policy
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.kda import chunk_kda
+from ray_tpu.ops.kda import chunk_gdn, chunk_kda
 
 LAYERS = 2
 T = 256
@@ -93,6 +93,32 @@ class _KDA(nn.Module):
         return x + nn.Dense(c, use_bias=False, name="o")(o.reshape(b, t, -1))
 
 
+class _GDN(nn.Module):
+    """``_KDA`` with one decay a head and token and value heads twice as wide
+    as the key heads, through ``chunk_gdn``."""
+    heads: int = 2
+    dk: int = 32
+    dv: int = 64
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, c = x.shape
+
+        def heads(name, width, dtype=jnp.float32):
+            y = nn.Dense(self.heads * width, use_bias=False, name=name)(x)
+            return y.reshape(b, t, self.heads, width).astype(dtype)
+
+        g = -jax.nn.softplus(nn.Dense(self.heads, use_bias=False, name="g")(x))
+        beta = 2.0 * jax.nn.sigmoid(nn.Dense(self.heads, use_bias=False, name="beta")(x))
+        weight = self.param("norm", nn.initializers.ones, (self.dv,))
+        o = chunk_gdn(
+            heads("q", self.dk), heads("k", self.dk), heads("v", self.dv, x.dtype),
+            g, beta, heads("gate", self.dv, x.dtype), weight,
+            scale=self.dk ** -0.5, rms_eps=1e-6,
+        )
+        return x + nn.Dense(c, use_bias=False, name="o")(o.reshape(b, t, -1))
+
+
 class _HyperConnected(nn.Module):
     """A layer's two rounds of read, sublayer, write on [n, B, T, C] streams,
     as ``models.llama._hyper_connected`` makes them."""
@@ -132,6 +158,7 @@ CASES = {
     "mla-192-128": ((_Attention, dict(heads=2, kv_heads=2, d=192, d_v=128)),
                     (1, T, 64), {"_fwd_kernel": (1, 2)}),
     "kda": ((_KDA, {}), (1, T, 64), {"_kda_fwd_kernel": (1, 2)}),
+    "gdn": ((_GDN, {}), (1, T, 64), {"_gdn_fwd_kernel": (1, 2)}),
     # A layer's second write is its output, which no replay makes.
     "hyper-connections": ((_HyperConnected, {}), (4, 1, T, 128), {
         "_hc_pre_fwd_kernel": (2, 4), "_hc_post_fwd_kernel": (2, 3)}),
@@ -175,16 +202,20 @@ def test_replay_holds_no_forward_kernel(case):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("dropped", ["kda_o", "kda_states", "kda_t"])
+@pytest.mark.parametrize("dropped", [
+    "kda_o", "kda_states", "kda_t", "gdn_o", "gdn_states", "gdn_t"])
 def test_a_kda_layer_needs_each_of_its_three_names_kept(dropped):
-    """The backward kernel reads the states and the chunks' inverses, and o
-    is the layer's own output: a policy that lacks one of the three runs
-    ``_kda_fwd_kernel`` in the replay to remake it, whatever else it holds."""
+    """o, the per-chunk states and the chunks' inverses leave the forward
+    kernel together (KDA's, and the scalar-decay kernel's under names of its
+    own): a policy that lacks any one of them runs the forward kernel in the
+    replay to remake it, whatever else it holds."""
+    case = dropped.partition("_")[0]
+    fwd, bwd = f"_{case}_fwd_kernel", f"_{case}_bwd_kernel"
     names = [name for name in KERNEL_RESIDUALS if name != dropped]
-    calls, _ = _run("kda", jax.checkpoint_policies.save_only_these_names(*names))
-    assert calls["_kda_fwd_kernel"] == 2 * LAYERS and calls["_kda_bwd_kernel"] == LAYERS
-    kept, _ = _run("kda", remat_policy(_cfg(remat_prevent_cse=True)))
-    assert kept["_kda_fwd_kernel"] == kept["_kda_bwd_kernel"] == LAYERS
+    calls, _ = _run(case, jax.checkpoint_policies.save_only_these_names(*names))
+    assert calls[fwd] == 2 * LAYERS and calls[bwd] == LAYERS
+    kept, _ = _run(case, remat_policy(_cfg(remat_prevent_cse=True)))
+    assert kept[fwd] == kept[bwd] == LAYERS
 
 
 def test_the_other_policies_are_what_they_were():
