@@ -461,7 +461,7 @@ _write_by_kernels = jax.custom_vjp(_post_fwd)
 
 def _write_fwd(x, y, post, res):
     # Named for the remat policy, like the read's outputs below (models/llama.py
-    # KERNEL_RESIDUALS): a layer's first write is its second connection's
+    # REPLAY_KEEPS): a layer's first write is its second connection's
     # streams, and a replay that holds it runs no write.
     out = checkpoint_name(_post_fwd(x, y, post, res), "hc_write")
     return out, (x, y, post, res)

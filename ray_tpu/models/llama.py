@@ -26,6 +26,7 @@ from typing import Any, Dict, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import flash_attention
 from ..parallel.mesh import logical_axis_shards, with_logical_constraint
@@ -64,9 +65,14 @@ class LlamaConfig:
     # prevent_cse=False that is all, and XLA merges the replay with its
     # forward twin (step.remat_share reads 0.00-2.57% of busy time in the
     # dense cells and the OLMoE cell, PERF.md §5). With the barrier, where
-    # a replay is executed, also what a Pallas forward kernel wrote for its
-    # own backward (KERNEL_RESIDUALS), so that no replay runs a forward
-    # kernel a second time.
+    # a replay is executed, also the values REPLAY_KEEPS names, by two
+    # rules: what a Pallas forward kernel wrote for its own backward, so
+    # that no replay runs a forward kernel a second time, and a matmul's
+    # output that only an element-wise consumer reads (a SwiGLU's two
+    # products, a sublayer's output), so that no replay runs gate_proj,
+    # up_proj, o_proj or down_proj. A kept float costs its bytes from the
+    # forward pass to its layer's backward and one reduce_precision pass
+    # over them.
     # "dots": save matmul outputs, recompute only elementwise — moves
     # memory, not time, where nothing is replayed.
     remat_policy: str = "nothing"
@@ -156,25 +162,44 @@ CONFIGS: Dict[str, LlamaConfig] = {
 }
 
 
-# The values a layer's replay does not make again: a Pallas forward kernel's
-# outputs that its own backward kernels read, tagged where the custom-vjp
-# forward rule returns them (``jax.ad_checkpoint.checkpoint_name`` in
-# ops/attention.py, ops/kda.py and models/hyper_connections.py). A value is
-# here by one rule: keeping it deletes a replayed kernel call. Under
-# ``nothing_saveable`` a replay runs each forward rule whole, so the kernel
-# that wrote o and lse (or o and the per-chunk states and inverses) runs twice
-# a layer only to hand its backward what it had already written once. The
-# ring's rule (ops/ring_attention.py) is not tagged: its cell has no memory to
-# spare and replays 1% of its step.
-KERNEL_RESIDUALS = ("flash_o", "flash_lse", "kda_o", "kda_states", "kda_t",
-                    "hc_read", "hc_maps", "hc_write", "gdn_o", "gdn_states",
-                    "gdn_t")
+# The values a layer's replay does not make again, each tagged where it is
+# made (``jax.ad_checkpoint.checkpoint_name``). A value is here by one of two
+# rules.
+#
+# Keeping it deletes a replayed kernel call: a Pallas forward kernel's outputs
+# that its own backward kernels read, tagged where the custom-vjp forward rule
+# returns them (ops/attention.py, ops/kda.py, models/hyper_connections.py).
+# Under ``nothing_saveable`` a replay runs each forward rule whole, so the
+# kernel that wrote o and lse (or o and the per-chunk states and inverses)
+# runs twice a layer only to hand its backward what it had already written
+# once. The ring's rule (ops/ring_attention.py) is not tagged: its cell has no
+# memory to spare and replays 1% of its step.
+#
+# It is a matmul's output that the replay would make again only to hand it to
+# an element-wise consumer, tagged in this file: a SwiGLU's two products
+# (``MLP``: the dense layers and, as ``shared``, the shared experts; silu(gate)
+# * up is one fused pass from the two and is not kept), and a sublayer's output
+# (``DecoderLayer``), which with the kernel's o kept leaves no replay an o_proj,
+# an output gate or a down_proj to run. Partial evaluation drops a kept value
+# that no backward reads: an FFN's output is read by the norm after it
+# (``norm_after``) or by a hyper-connection's write, and by nothing in a
+# pre-norm layer, where it is not kept.
+#
+# What a name costs: its bytes from the forward pass to its layer's backward,
+# and one pass over them, because JAX hands every float a remat keeps through
+# ``reduce_precision``, a read and a write the TPU compiler does not elide
+# (PERF.md §6, PR 47). Every matmul here is eight or more such passes. The
+# routed experts' products, the kernels' operands (q, k, v, the rotations, the
+# convolutions) and the router are not here: they are 1.25-2.7 GiB a step and
+# do not fit in every replaying cell, and a choice by cell would be a knob.
+REPLAY_KEEPS = ("flash_o", "flash_lse", "kda_o", "kda_states", "kda_t",
+                "hc_read", "hc_maps", "hc_write", "gdn_o", "gdn_states",
+                "gdn_t", "mlp_gate", "mlp_up", "mixer_out", "ffn_out")
 # One object for every caller: JAX caches a jitted function's partial
 # evaluation by the policy's identity, and a second ``_through`` (xing4.py's
 # module) with a policy of its own would lower every jitted kernel entry's
 # body again.
-_KEEP_KERNEL_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
-    *KERNEL_RESIDUALS)
+_KEEP = jax.checkpoint_policies.save_only_these_names(*REPLAY_KEEPS)
 
 
 def remat_policy(cfg: LlamaConfig):
@@ -182,12 +207,12 @@ def remat_policy(cfg: LlamaConfig):
         return jax.checkpoint_policies.checkpoint_dots
     if not cfg.remat_prevent_cse:
         # Without the barrier no replay is executed (XLA merges it with its
-        # forward twin), so there is no kernel call to delete, and the names
-        # cost memory all the same: the compiled step's temporaries rose by
+        # forward twin), so there is nothing to delete, and the names cost
+        # memory all the same: the compiled step's temporaries rose by
         # every layer's o, 4.90 -> 5.15 GiB in mistral-7b-l4.short2k and
         # 5.50 -> 5.59 in the OLMoE cell (AOT compiles for v5e, PR 47).
         return jax.checkpoint_policies.nothing_saveable
-    return _KEEP_KERNEL_RESIDUALS
+    return _KEEP
 
 
 def weight_init(cfg: LlamaConfig, default=nn.initializers.lecun_normal()):
@@ -307,8 +332,10 @@ class MLP(nn.Module):
             param_dtype=cfg.param_dtype, kernel_init=weight_init(cfg),
             name=name,
         )
-        gate = dense(width, "gate_proj")(x)
-        up = dense(width, "up_proj")(x)
+        # Named for the remat policy (REPLAY_KEEPS): with the two products
+        # kept a replay makes h from them in one pass and runs no matmul.
+        gate = checkpoint_name(dense(width, "gate_proj")(x), "mlp_gate")
+        up = checkpoint_name(dense(width, "up_proj")(x), "mlp_up")
         h = nn.silu(gate) * up
         h = with_logical_constraint(h, ("batch", "seq", "mlp"))
         return dense(cfg.hidden_size, "down_proj")(h)
@@ -326,41 +353,37 @@ class DecoderLayer(nn.Module):
         cfg = self.cfg
         mixer_name, mixer = self.mixer
         ffn_name, ffn = self.ffn
+        # The sublayers, their outputs named for the remat policy
+        # (REPLAY_KEEPS): a replay that holds them ends at the kernel's o and
+        # at the SwiGLU's h, before o_proj and down_proj.
+        mix = lambda u: checkpoint_name(  # noqa: E731
+            mixer(cfg, name=mixer_name)(u, positions), "mixer_out")
+        feed = lambda u: checkpoint_name(  # noqa: E731
+            ffn(cfg, name=ffn_name)(u), "ffn_out")
+        norm = lambda name, y: RMSNorm(cfg.rms_eps, cfg.param_dtype, name=name)(y)  # noqa: E731
         if cfg.hyper_connections is not None:
-            return _hyper_connected(self, x, positions)
+            return _hyper_connected(cfg, x, mix, feed, norm)
         if cfg.norm_after:
-            norm = lambda name, y: RMSNorm(cfg.rms_eps, cfg.param_dtype, name=name)(y)  # noqa: E731
-            h = x + norm(tracing.POST_MIXER_NORM, mixer(cfg, name=mixer_name)(x, positions))
-            out = h + norm(tracing.POST_FFN_NORM, ffn(cfg, name=ffn_name)(h))
+            h = x + norm(tracing.POST_MIXER_NORM, mix(x))
+            out = h + norm(tracing.POST_FFN_NORM, feed(h))
             return with_logical_constraint(out, ("batch", "seq", "embed"))
-        h = x + mixer(cfg, name=mixer_name)(
-            RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.INPUT_NORM)(x), positions
-        )
-        out = h + ffn(cfg, name=ffn_name)(
-            RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.POST_ATTN_NORM)(h)
-        )
+        h = x + mix(norm(tracing.INPUT_NORM, x))
+        out = h + feed(norm(tracing.POST_ATTN_NORM, h))
         return with_logical_constraint(out, ("batch", "seq", "embed"))
 
 
-def _hyper_connected(layer: DecoderLayer, x, positions):
-    """The layer's two sublayers on the streams x [n, B, T, C], inside its
-    ``__call__``: each reads through its hyper-connection (``mixer_hc``,
-    ``ffn_hc``) and writes back through it."""
-    cfg = layer.cfg
-    mixer_name, mixer = layer.mixer
-    ffn_name, ffn = layer.ffn
+def _hyper_connected(cfg: LlamaConfig, x, mix, feed, norm):
+    """A layer's two sublayers ``mix`` and ``feed`` on the streams x
+    [n, B, T, C], inside ``DecoderLayer.__call__``: each reads through its
+    hyper-connection (``mixer_hc``, ``ffn_hc``) and writes back through it."""
     connection = lambda name: HyperConnection(  # noqa: E731
         cfg.hyper_connections, cfg.rms_eps, weight_init(cfg),
         cfg.param_dtype, name=name,
     )
     u, x, maps = connection(tracing.MIXER_HC)(x, streams=True)
-    h = write_streams(x, mixer(cfg, name=mixer_name)(
-        RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.INPUT_NORM)(u), positions
-    ), *maps)
+    h = write_streams(x, mix(norm(tracing.INPUT_NORM, u)), *maps)
     u, h, maps = connection(tracing.FFN_HC)(h, streams=True)
-    out = write_streams(h, ffn(cfg, name=ffn_name)(
-        RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.POST_ATTN_NORM)(u)
-    ), *maps)
+    out = write_streams(h, feed(norm(tracing.POST_ATTN_NORM, u)), *maps)
     return with_logical_constraint(out, (None, "batch", "seq", "embed"))
 
 

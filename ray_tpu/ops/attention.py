@@ -883,7 +883,7 @@ def _flash(q, k, v, causal, sm_scale, block_q, block_k, window):
 def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, window):
     o, lse = _block_fwd(q, k, v, causal, sm_scale, block_q, block_k, window)
     # Named so that a remat policy can keep them (models/llama.py
-    # KERNEL_RESIDUALS): with both kept a layer's replay holds no forward
+    # REPLAY_KEEPS): with both kept a layer's replay holds no forward
     # kernel, only q, k and v for the backward ones. lse as [bh, tq], not the
     # kernel's [bh, tq, 1] column, which HBM would pad to 128 lanes.
     o, lse = checkpoint_name(o, "flash_o"), checkpoint_name(lse, "flash_lse")

@@ -879,7 +879,7 @@ def _kda_pallas(q, k, v, g, beta, gate, weight, heads, norm):
     Called outside a gradient it writes neither the states nor the chunks'
     inverses, because nobody reads them. Under a gradient the forward rule
     writes both and names them and o (``checkpoint_name``): a remat policy
-    that keeps the three names (models/llama.py KERNEL_RESIDUALS) holds a
+    that keeps the three names (models/llama.py REPLAY_KEEPS) holds a
     layer's 512 MiB of states, 64 MiB of inverses and 128 MiB of o (at 16k
     tokens and 32 heads of 128) from the forward pass to its backward and
     its replay runs no forward kernel; one that lacks any of them runs the
@@ -892,7 +892,7 @@ def _kda_pallas(q, k, v, g, beta, gate, weight, heads, norm):
 def _kda_pallas_fwd(q, k, v, g, beta, gate, weight, heads, norm):
     o, states, inverses = _forward_pallas(q, k, v, g, beta, gate, weight, heads,
                                           norm, states=True)
-    # Named for the remat policy (models/llama.py KERNEL_RESIDUALS): the
+    # Named for the remat policy (models/llama.py REPLAY_KEEPS): the
     # backward reads the states and the inverses, and o is kept with them
     # because a replay that has to make o runs this kernel whatever else it
     # holds.
@@ -1214,7 +1214,7 @@ def _gdn_pallas(q, k, v, g, beta, gate, weight, norm):
     [1, dv], T a whole number of chunks. As ``_kda_pallas``: outside a
     gradient neither states nor inverses are written; under one the forward
     rule writes and names both and o (``gdn_o``, ``gdn_states``, ``gdn_t``:
-    models/llama.py KERNEL_RESIDUALS), so that a replay which keeps the three
+    models/llama.py REPLAY_KEEPS), so that a replay which keeps the three
     runs no forward kernel."""
     return _gdn_forward_pallas(q, k, v, g, beta, gate, weight, norm,
                                states=False)[0]

@@ -217,7 +217,7 @@ def test_a_profile_names_each_flash_kernel_of_a_remat_step_by_its_own_name(
             mixer = re.search(r"/layers_\d/(attn|swa)/", line).group(1)
             named.setdefault(mixer, []).append(kernel_name(line))
     # One forward a layer: the replay holds none (models/llama.py
-    # KERNEL_RESIDUALS keeps what it wrote).
+    # REPLAY_KEEPS keeps what it wrote).
     assert sorted(named["attn"]) == [
         "_bwd_dkv_kernel", "_bwd_dq_kernel", "_fwd_kernel"]
     assert sorted(named["swa"]) == [
@@ -742,12 +742,12 @@ def test_xing4s_step_calls_each_hyper_connection_kernel_once_a_connection(v5e):
     over 256 tokens, which tile: six hyper-connections, each a read and a
     write forward and the three backward kernels; no replay holds a read or
     a write, the remat policy keeps what they wrote (models/llama.py
-    KERNEL_RESIDUALS). A backward kernel's body stands once in the text,
+    REPLAY_KEEPS). A backward kernel's body stands once in the text,
     behind its jitted entry; a forward one's twice, a layer's first
     connection's and its second's, which remat's partial evaluation tells
     apart because the second's streams are a kept value; with one policy
     object for every ``_through`` the module's layer shares them
-    (``models.llama._KEEP_KERNEL_RESIDUALS``). Every call
+    (``models.llama._KEEP``). Every call
     is under /hc/pre/ or /hc/post/, where the benchmark's model.hc_share and
     model.hc_roofline look for it."""
     import importlib

@@ -1,16 +1,22 @@
-"""What the one remat policy keeps of the Pallas forward kernels.
+"""What the one remat policy keeps, by its two rules.
 
 Under ``nothing_saveable`` a layer's replay runs each custom-vjp forward rule
 whole: the kernel that wrote o and lse (or o and the per-chunk states) runs a
 second time only to hand its own backward kernels what it had written once.
-Behind the barrier the replaying cells run (``prevent_cse=True``)
-``models.llama.remat_policy`` keeps the values ``KERNEL_RESIDUALS`` names, and
-the forward rules tag them. Here, on the CPU with the kernels interpreted,
-through two ``nn.remat`` layers behind that barrier: the gradient's jaxpr
-holds each forward kernel once a layer where ``nothing_saveable`` holds it
-twice, and loss and gradients are the same to the bit.
+And it runs every projection again: a SwiGLU's gate_proj and up_proj for the
+element-wise pass after them, o_proj and down_proj for the add or the norm
+after the sublayer. Behind the barrier the replaying cells run
+(``prevent_cse=True``) ``models.llama.remat_policy`` keeps the values
+``REPLAY_KEEPS`` names; the forward rules tag the kernels' and
+``models/llama.py`` the matmuls'. Here, on the CPU with the kernels
+interpreted, through two ``nn.remat`` layers behind that barrier: the
+gradient's jaxpr holds each forward kernel and each of those matmuls once a
+layer where ``nothing_saveable`` holds it twice, a layer's replay is handed
+the names a backward reads and no other, and loss and gradients are the same
+to the bit.
 """
 import ast
+import collections
 import pathlib
 
 import flax.linen as nn
@@ -18,13 +24,18 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 import ray_tpu
 from ray_tpu.models import hyper_connections
-from ray_tpu.models.llama import KERNEL_RESIDUALS, LlamaConfig, remat_policy
+from ray_tpu.models.llama import (
+    MLP, REPLAY_KEEPS, Attention, DecoderLayer, LlamaConfig, remat_policy,
+)
+from ray_tpu.models.mixtral import MixtralConfig, MoELayer
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.kda import chunk_gdn, chunk_kda
+from ray_tpu.util import tracing
 
 LAYERS = 2
 T = 256
@@ -135,14 +146,30 @@ class _HyperConnected(nn.Module):
         return x
 
 
+class _Decoder(nn.Module):
+    """``models.llama.DecoderLayer`` as ``_through`` binds it, around softmax
+    attention and the FFN ``ffn`` names."""
+    cfg: LlamaConfig
+    ffn: str = tracing.MLP
+
+    @nn.compact
+    def __call__(self, x):
+        positions = jnp.broadcast_to(jnp.arange(x.shape[-2]), x.shape[-3:-1])
+        ffn = {tracing.MLP: MLP, tracing.MOE: MoELayer}[self.ffn]
+        return DecoderLayer(
+            self.cfg, (tracing.ATTN, Attention), (self.ffn, ffn), name="layer",
+        )(x, positions)
+
+
 class _Stack(nn.Module):
     layer: object  # (the layer's nn.Module class, its fields)
     policy: object
+    barrier: bool = True
 
     @nn.compact
     def __call__(self, x):
         cls, fields = self.layer
-        layer_cls = nn.remat(cls, prevent_cse=True, policy=self.policy)
+        layer_cls = nn.remat(cls, prevent_cse=self.barrier, policy=self.policy)
         for i in range(LAYERS):
             x = layer_cls(**fields, name=f"layers_{i}")(x)
         return jnp.sum(x.astype(jnp.float32) ** 2)
@@ -165,26 +192,39 @@ CASES = {
 }
 
 
-def _kernel_calls(jaxpr, counts):
-    """Every ``pallas_call`` of ``jaxpr`` and of the jaxprs its equations
-    hold, by the kernel's name."""
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            name = eqn.params["jaxpr"].debug_info.func_name
-            counts[name] = counts.get(name, 0) + 1
+        yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _kernel_calls(sub, counts)
-    return counts
+            yield from _equations(sub)
+
+
+def _kernel_calls(jaxpr):
+    """Every ``pallas_call`` under ``jaxpr``, counted by the kernel's name."""
+    return collections.Counter(
+        eqn.params["jaxpr"].debug_info.func_name for eqn in _equations(jaxpr)
+        if eqn.primitive.name == "pallas_call")
+
+
+def _gradient(layer, shape, policy, barrier=True):
+    """The jaxpr of loss and gradients through ``_Stack``, and their values."""
+    model = _Stack(layer, policy, barrier)
+    x = jnp.asarray(np.random.RandomState(0).randn(*shape), jnp.float32)
+    params = model.init(jax.random.PRNGKey(0), x)
+    grad = jax.value_and_grad(lambda p, x: model.apply(p, x), argnums=(0, 1))
+    return jax.make_jaxpr(grad)(params, x).jaxpr, jax.jit(grad)(params, x)
 
 
 def _run(case, policy):
     layer, shape, _ = CASES[case]
-    model = _Stack(layer, policy)
-    x = jnp.asarray(np.random.RandomState(0).randn(*shape), jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x)
-    grad = jax.value_and_grad(lambda p, x: model.apply(p, x), argnums=(0, 1))
-    calls = _kernel_calls(jax.make_jaxpr(grad)(params, x).jaxpr, {})
-    return calls, jax.jit(grad)(params, x)
+    jaxpr, values = _gradient(layer, shape, policy)
+    return _kernel_calls(jaxpr), values
+
+
+def _same_to_the_bit(kept, bare):
+    for a, b in zip(jax.tree_util.tree_leaves(kept), jax.tree_util.tree_leaves(bare)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -198,8 +238,7 @@ def test_replay_holds_no_forward_kernel(case):
     # Nothing but the forward kernels left the replay.
     for kernel in set(bare_calls) - set(forward):
         assert kept_calls[kernel] == bare_calls[kernel], (kept_calls, bare_calls)
-    for a, b in zip(jax.tree_util.tree_leaves(kept), jax.tree_util.tree_leaves(bare)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _same_to_the_bit(kept, bare)
 
 
 @pytest.mark.parametrize("dropped", [
@@ -211,11 +250,133 @@ def test_a_kda_layer_needs_each_of_its_three_names_kept(dropped):
     replay to remake it, whatever else it holds."""
     case = dropped.partition("_")[0]
     fwd, bwd = f"_{case}_fwd_kernel", f"_{case}_bwd_kernel"
-    names = [name for name in KERNEL_RESIDUALS if name != dropped]
+    names = [name for name in REPLAY_KEEPS if name != dropped]
     calls, _ = _run(case, jax.checkpoint_policies.save_only_these_names(*names))
     assert calls[fwd] == 2 * LAYERS and calls[bwd] == LAYERS
     kept, _ = _run(case, remat_policy(_cfg(remat_prevent_cse=True)))
     assert kept[fwd] == kept[bwd] == LAYERS
+
+
+def _decoder(ffn=tracing.MLP, config=LlamaConfig, **fields):
+    cfg = config(
+        vocab_size=64, hidden_size=128, intermediate_size=256, num_layers=LAYERS,
+        num_heads=2, num_kv_heads=2, max_seq_len=T, **fields,
+    )
+    return _Decoder, dict(cfg=cfg, ffn=ffn)
+
+
+_KERNEL = {"flash_o", "flash_lse"}
+_PRODUCTS = {"mlp_gate", "mlp_up"}
+_SHARED = dict(
+    config=MixtralConfig, num_experts=4, num_experts_per_tok=2,
+    moe_intermediate_size=128, num_shared_experts=1,
+)
+MATMUL_CASES = {
+    # name: (layer, x's shape, {a projection: its forward matmuls a layer in
+    # the gradient under the policy, and under nothing_saveable}, the names a
+    # layer's replay is handed)
+    #
+    # Nothing reads a pre-norm layer's FFN output but the add after it: no
+    # replay makes it under either policy, and it is not kept.
+    "pre-norm": (_decoder(), (1, T, 128), {
+        "mlp/gate_proj": (1, 2), "mlp/up_proj": (1, 2), "attn/o_proj": (1, 2),
+        "mlp/down_proj": (1, 1),
+    }, _KERNEL | _PRODUCTS | {"mixer_out"}),
+    # The norm after a sublayer reads the sublayer's output in its backward.
+    "norm-after": (_decoder(norm_after=True), (1, T, 128), {
+        "mlp/gate_proj": (1, 2), "mlp/up_proj": (1, 2), "attn/o_proj": (1, 2),
+        "mlp/down_proj": (1, 2),
+    }, _KERNEL | _PRODUCTS | {"mixer_out", "ffn_out"}),
+    # A write's backward reads what the sublayer gave it (the gradient of the
+    # map it is spread by). A layer's second write is its output.
+    "hyper-connections": (
+        _decoder(hyper_connections=hyper_connections.HyperConnections()),
+        (4, 1, T, 128), {
+            "mlp/gate_proj": (1, 2), "mlp/up_proj": (1, 2),
+            "attn/o_proj": (1, 2), "mlp/down_proj": (1, 2),
+        }, _KERNEL | _PRODUCTS | {"mixer_out", "ffn_out", "hc_read", "hc_maps",
+                                  "hc_write"}),
+    # ``MLP(..., name="shared")`` inside the expert layer (models/mixtral.py).
+    "shared-expert": (_decoder(tracing.MOE, **_SHARED), (1, T, 128), {
+        "shared/gate_proj": (1, 2), "shared/up_proj": (1, 2),
+        "attn/o_proj": (1, 2), "shared/down_proj": (1, 1),
+    }, _KERNEL | _PRODUCTS | {"mixer_out"}),
+}
+
+
+def _forward_matmuls(jaxpr):
+    """Every ``dot_general`` under ``jaxpr`` that a forward pass or a replay
+    runs (and no backward), counted by the two flax names its name stack ends
+    in."""
+    def forward(stack):
+        return ("jvp(" in stack and "transpose" not in stack
+                or "rematted_computation" in stack)
+
+    stacks = (str(eqn.source_info.name_stack) for eqn in _equations(jaxpr)
+              if eqn.primitive.name == "dot_general")
+    return collections.Counter(
+        "/".join(stack.split("/")[-2:]) for stack in stacks if forward(stack))
+
+
+def _handed_to_the_replays(jaxpr):
+    """The names of the kept values each layer's replay takes: the operands of
+    the gradient's ``remat2`` equations that a ``checkpoint_name`` made. JAX
+    passes a kept float through ``reduce_precision`` on its way, and a kept
+    operand of a jitted function (``nn.silu``) through that function's known
+    half, which returns it as it came."""
+    named = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            named[eqn.outvars[0]] = eqn.params["name"]
+        elif eqn.primitive.name == "reduce_precision" and eqn.invars[0] in named:
+            named[eqn.outvars[0]] = named[eqn.invars[0]]
+        elif eqn.primitive.name == "jit":
+            inner = eqn.params["jaxpr"].jaxpr
+            came = dict(zip(inner.invars, eqn.invars))
+            for out, inner_out in zip(eqn.outvars, inner.outvars):
+                if came.get(inner_out) in named:
+                    named[out] = named[came[inner_out]]
+    return [
+        sorted({named[v] for v in eqn.invars
+                if isinstance(v, jax.extend.core.Var) and v in named})
+        for eqn in jaxpr.eqns if eqn.primitive.name == "remat2"
+    ]
+
+
+@pytest.mark.parametrize("case", list(MATMUL_CASES))
+def test_replay_holds_no_matmul_for_an_elementwise_consumer(case):
+    layer, shape, matmuls, names = MATMUL_CASES[case]
+    kept_jaxpr, kept = _gradient(layer, shape, remat_policy(_cfg(remat_prevent_cse=True)))
+    bare_jaxpr, bare = _gradient(layer, shape, NOTHING)
+    kept_dots = _forward_matmuls(kept_jaxpr)
+    bare_dots = _forward_matmuls(bare_jaxpr)
+    for name, (once, replayed) in matmuls.items():
+        assert kept_dots[name] == LAYERS * once, kept_dots
+        assert bare_dots[name] == LAYERS * replayed, bare_dots
+    # Nothing else left the replay: the kernels' operands are made again.
+    for name in set(bare_dots) - set(matmuls):
+        assert kept_dots[name] == bare_dots[name], (name, kept_dots, bare_dots)
+    assert _handed_to_the_replays(kept_jaxpr) == [sorted(names)] * LAYERS
+    # (A hyper-connected layer's input is the write before it, whatever the
+    # policy.)
+    assert all(set(layer) <= {"hc_write"}
+               for layer in _handed_to_the_replays(bare_jaxpr))
+    _same_to_the_bit(kept, bare)
+
+
+@pytest.mark.parametrize("case", ["pre-norm", "norm-after"])
+def test_without_the_barrier_the_names_are_inert(case):
+    """A configuration that executes no replay (``remat_prevent_cse`` false)
+    takes ``nothing_saveable``: its gradient holds every projection forward
+    and in the replay, as before the names, and no replay is handed one."""
+    layer, shape, matmuls, _ = MATMUL_CASES[case]
+    policy = remat_policy(layer[1]["cfg"])
+    assert policy is NOTHING
+    jaxpr, _ = _gradient(layer, shape, policy, barrier=False)
+    dots = _forward_matmuls(jaxpr)
+    for name, (_, replayed) in matmuls.items():
+        assert dots[name] == LAYERS * replayed, dots
+    assert _handed_to_the_replays(jaxpr) == [[]] * LAYERS
 
 
 def test_the_other_policies_are_what_they_were():
@@ -245,5 +406,5 @@ def _tagged_names():
 def test_every_name_is_tagged_and_every_tag_is_kept():
     tagged = _tagged_names()
     assert len(tagged) == len(set(tagged)), tagged  # a name has one site
-    assert set(tagged) == set(KERNEL_RESIDUALS)
-    assert len(KERNEL_RESIDUALS) == len(set(KERNEL_RESIDUALS))
+    assert set(tagged) == set(REPLAY_KEEPS)
+    assert len(REPLAY_KEEPS) == len(set(REPLAY_KEEPS))

@@ -18,6 +18,7 @@ import dataclasses
 import hashlib
 import importlib
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -425,12 +426,15 @@ def tree_digest(tree) -> tuple:
 # ``KDAMixer`` convolves through ``ops/kda.py`` ``conv_silu``, whose two
 # Pallas passes are interpreted here with the others ("6740a415a90863fc"
 # before, with ``silu(short_conv)`` as XLA has it; tests/test_kda_op.py holds
-# the two to each other).
+# the two to each other). The text is read without the counters JAX gives its
+# private functions (``@silu_158``): a ``checkpoint_name`` lowers to nothing
+# and moves them (models/llama.py REPLAY_KEEPS; these four digests read the
+# same at that change's parent and after it).
 BEFORE = {
-    "kimi-linear-48b-a3b-l5": ("4ed2711bc2778250", 117, "5d4e6bfcad737261"),
-    "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "ca534f8e0f6dd20b"),
-    "mistral-7b-l4": ("06a35641bbb39a58", 21, "f4fe23e5a759b5d1"),
-    "sarvam-105b-l5": ("c710f6841e29dd3a", 83, "9647e70eea4cb618"),
+    "kimi-linear-48b-a3b-l5": ("4ed2711bc2778250", 117, "c16ef491925e5adb"),
+    "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "cc97cf680f1bedbe"),
+    "mistral-7b-l4": ("06a35641bbb39a58", 21, "6ac84cd0523ca00f"),
+    "sarvam-105b-l5": ("c710f6841e29dd3a", 83, "2a9ffca6aec4a4c6"),
 }
 
 
@@ -443,5 +447,6 @@ def test_the_models_that_share_the_mixers_are_bit_for_bit_what_they_were(name):
         model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
     text = jax.jit(model.apply).lower(
         shapes, jax.ShapeDtypeStruct((1, 128), np.int32)).as_text()
+    text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
     digest = hashlib.sha1(text.encode()).hexdigest()[:16]
     assert (*tree_digest(shapes), digest) == BEFORE[name]
