@@ -1,0 +1,10 @@
+"""Device time of operations whose metadata path holds the flax scope of the
+block-sparse top-k softmax mixer (/sparse/: projections, the norm of q and k
+over a head's channels, the selection, the sparse kernels, the output gate,
+output projection; forward, backward and replay) over device busy time,
+device 0. Nothing to read in a model without one."""
+from benchmarks.lib.kernel_readers import share_of_busy
+
+
+def read(run):
+    return share_of_busy(run, lambda event: "/sparse/" in event.path)

@@ -73,6 +73,10 @@ class LlamaConfig:
     # up_proj, o_proj or down_proj. A kept float costs its bytes from the
     # forward pass to its layer's backward and one reduce_precision pass
     # over them.
+    # "kernels": "nothing" without the SwiGLU's two products, for a layer
+    # whose intermediate width times its tokens does not fit: at 16,384
+    # tokens of 16,384 channels the two are 1 GiB a layer. The replay then
+    # runs gate_proj and up_proj again and nothing else of REPLAY_KEEPS.
     # "dots": save matmul outputs, recompute only elementwise — moves
     # memory, not time, where nothing is replayed.
     remat_policy: str = "nothing"
@@ -91,6 +95,14 @@ class LlamaConfig:
     # sublayers reading the raw stream. False: the pre-norm of every other
     # family, h = x + mixer(RMSNorm(x)).
     norm_after: bool = False
+    # MiniCPM's muP, each 1.0 where a family has none (nothing is then
+    # lowered for it): the embedding times ``embed_scale``; each sublayer's
+    # output times ``residual_scale`` before the residual sum; the final
+    # norm's output over ``logit_divisor`` before the head, in the full-logit
+    # and the chunked loss alike.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
 
     @property
     def head_dim_(self) -> int:
@@ -194,12 +206,19 @@ CONFIGS: Dict[str, LlamaConfig] = {
 # do not fit in every replaying cell, and a choice by cell would be a knob.
 REPLAY_KEEPS = ("flash_o", "flash_lse", "kda_o", "kda_states", "kda_t",
                 "hc_read", "hc_maps", "hc_write", "gdn_o", "gdn_states",
-                "gdn_t", "mlp_gate", "mlp_up", "mixer_out", "ffn_out")
+                "gdn_t", "mlp_gate", "mlp_up", "mixer_out", "ffn_out",
+                "lightning_o", "lightning_states", "sparse_o", "sparse_lse",
+                "sparse_blocks")
+# What does not fit in a layer of 16,384 tokens by 16,384 channels
+# (``remat_policy`` "kernels").
+_WIDE = ("mlp_gate", "mlp_up")
 # One object for every caller: JAX caches a jitted function's partial
 # evaluation by the policy's identity, and a second ``_through`` (xing4.py's
 # module) with a policy of its own would lower every jitted kernel entry's
 # body again.
 _KEEP = jax.checkpoint_policies.save_only_these_names(*REPLAY_KEEPS)
+_KEEP_NARROW = jax.checkpoint_policies.save_only_these_names(
+    *(name for name in REPLAY_KEEPS if name not in _WIDE))
 
 
 def remat_policy(cfg: LlamaConfig):
@@ -212,7 +231,7 @@ def remat_policy(cfg: LlamaConfig):
         # every layer's o, 4.90 -> 5.15 GiB in mistral-7b-l4.short2k and
         # 5.50 -> 5.59 in the OLMoE cell (AOT compiles for v5e, PR 47).
         return jax.checkpoint_policies.nothing_saveable
-    return _KEEP
+    return _KEEP_NARROW if cfg.remat_policy == "kernels" else _KEEP
 
 
 def weight_init(cfg: LlamaConfig, default=nn.initializers.lecun_normal()):
@@ -367,9 +386,20 @@ class DecoderLayer(nn.Module):
             h = x + norm(tracing.POST_MIXER_NORM, mix(x))
             out = h + norm(tracing.POST_FFN_NORM, feed(h))
             return with_logical_constraint(out, ("batch", "seq", "embed"))
+        if cfg.residual_scale != 1.0:
+            mix, feed = _scaled(mix, cfg.residual_scale), _scaled(feed, cfg.residual_scale)
         h = x + mix(norm(tracing.INPUT_NORM, x))
         out = h + feed(norm(tracing.POST_ATTN_NORM, h))
         return with_logical_constraint(out, ("batch", "seq", "embed"))
+
+
+def _scaled(sublayer, scale: float):
+    """``sublayer``'s output times ``scale`` in its own dtype (muP's
+    ``scale_depth / sqrt(num_hidden_layers)``)."""
+    def call(u):
+        y = sublayer(u)
+        return y * jnp.asarray(scale, y.dtype)
+    return call
 
 
 def _hyper_connected(cfg: LlamaConfig, x, mix, feed, norm):
@@ -413,6 +443,8 @@ def _lookup(cfg: LlamaConfig, emb: nn.Embed, input_ids):
     else:
         x = emb(input_ids)
     with tracing.scope(tracing.EMBED):
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, x.dtype)
         return with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
@@ -499,6 +531,9 @@ class LlamaForCausalLM(nn.Module):
             _lookup(cfg, emb, input_ids), positions,
         )
         x = RMSNorm(cfg.rms_eps, cfg.param_dtype, name=tracing.FINAL_NORM)(x)
+        if cfg.logit_divisor != 1.0:
+            with tracing.scope(tracing.FINAL_NORM):  # outside the module's own scope
+                x = x * jnp.asarray(1.0 / cfg.logit_divisor, x.dtype)
         return x if return_hidden else _logits(cfg, emb, x)
 
 
@@ -581,7 +616,17 @@ def chunked_head_loss(
                 preferred_element_type=jnp.float32,
             )  # [B, C, V] f32
         logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tg[..., None], axis=-1)[..., 0]
+        if logits.shape[-1] % 128:
+            # A vocabulary that fills no whole number of lanes (an eighth of
+            # 73,448 is 9,181): the gather takes the chunk's logits flat, and
+            # [B, C, V] -> [B * C * V] is then a copy the compiler makes in a
+            # loop of its own, under no name, forward, replayed and backward
+            # (8.5 ms a step at 16k tokens: PERF.md §6, PR 54). A select and a
+            # sum ride the passes logsumexp makes; the value is the same.
+            hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2) == tg[..., None]
+            gold = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        else:
+            gold = jnp.take_along_axis(logits, tg[..., None], axis=-1)[..., 0]
         return jnp.sum((logz - gold) * m), jnp.sum(m)
 
     def body(carry, inp):

@@ -783,6 +783,406 @@ def _flash_bwd_window_pallas(q, k, v, o, lse, do, *, window, sm_scale,
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
+# ------------------------------------------------- pallas, a list of blocks a row
+# Block-sparse top-k attention (InfLLM-V2): row i of K/V group g sees, of the
+# keys up to its own, those in the blocks of ``block_size`` keys that
+# ``select_blocks`` chose for (i, g), a set that differs from row to row and
+# from group to group. The three kernels are the causal ones' tiles with a
+# mask made from a bitmap and never from a [T, T] array: the chosen blocks of
+# a key tile are the bits of one int32 a row (``_pack``: [B * G, T, key tiles]
+# words, 16 MiB at 16k tokens and two groups), which a grid step takes from its
+# row tile's words by its key tile's lane and shifts by each column's block. A
+# tile above the diagonal, or one in which no row chose a block, runs nothing.
+# What a tile costs is a dense tile's matmuls: the rows of a tile choose
+# differently, and together they choose nearly every block below them (a
+# kernel that gathered each row's 64 blocks would move 2 MiB of K and V a row
+# and group, 64 GiB a forward at 16k, and put 16 rows on the MXU: PERF.md §6,
+# PR 54). K and V stay at their own heads, as in the windowed kernels; dk and
+# dv sum over a group's heads inside the kernel.
+# 1,024 x 1,024, the causal kernels' tile: PERF.md §6, PR 54, has the sweep on
+# the chip (forward and backward 86 ms at 16k tokens where 512 x 512 took 116).
+SPARSE_BLOCK_Q = 1024
+SPARSE_BLOCK_K = 1024
+
+
+def select_blocks(q, k, *, block_size: int, topk: int, window: int,
+                  init_blocks: int, kernel_size: int, kernel_stride: int,
+                  sm_scale: Optional[float] = None, rows: int = 512):
+    """The blocks of keys each row and K/V group attends: [B, G, T, blocks]
+    bool. q [B, H, T, D]; k [B, G, T, D]. Compressed keys are means of
+    ``kernel_size`` keys every ``kernel_stride``; a head's row scores those
+    that end at or before it, soft-maxed in float32; a group's heads' scores
+    are summed; a block's score is the largest among the compressed keys
+    that overlap it. Forced: the first ``init_blocks`` blocks and the
+    ``window // block_size`` that end with the row's own. Chosen: the forced
+    and the highest scores among the other visible blocks, ``topk`` in all
+    (ties to the lower index), every visible block where there are fewer.
+    An integer set: nothing here is differentiated."""
+    q, k = jax.lax.stop_gradient(q), jax.lax.stop_gradient(k)
+    b, h, t, d = q.shape
+    g = k.shape[1]
+    f32 = jnp.float32
+    scale = sm_scale if sm_scale is not None else 1.0 / d**0.5
+    n_blocks = -(-t // block_size)
+    m = (t - kernel_size) // kernel_stride + 1
+    starts = np.arange(m) * kernel_stride
+    kc = k.astype(f32)[:, :, starts[:, None] + np.arange(kernel_size)].mean(3)
+    # The compressed keys that overlap block j are lo[j] .. lo[j] + width - 1
+    # where they exist.
+    blocks = np.arange(n_blocks)
+    lo = np.maximum(-(-(blocks * block_size - kernel_size + 1) // kernel_stride), 0)
+    hi = np.minimum(((blocks + 1) * block_size - 1) // kernel_stride, m - 1)
+    width = max(int((hi - lo).max()) + 1, 1)
+    over = lo[:, None] + np.arange(width)  # [blocks, width]
+    real = over <= hi[:, None]
+    over = np.minimum(over, m - 1)
+    rows = min(rows, t)
+    t_p = -(-t // rows) * rows
+    qg = _pad_rows(q.reshape(b * h, t, d), t_p).reshape(b, g, h // g, t_p, d)
+
+    def some_rows(start):
+        qb = jax.lax.dynamic_slice_in_dim(qg, start, rows, axis=3).astype(f32)
+        s = jnp.einsum("bgjrd,bgmd->bgjrm", qb, kc,
+                       precision=jax.lax.Precision.HIGHEST) * scale
+        at = start + jnp.arange(rows)
+        seen = (starts + kernel_size)[None, :] <= at[:, None] + 1  # [rows, m]
+        s = jnp.where(seen, s, -jnp.inf)
+        top = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.where(seen, jnp.exp(s - jnp.where(jnp.isfinite(top), top, 0.0)), 0.0)
+        total = jnp.sum(e, axis=-1, keepdims=True)
+        p = (e / jnp.where(total == 0.0, 1.0, total)).sum(2)  # [b, g, rows, m]
+        pooled = jnp.where(real, p[..., over], 0.0).max(-1)  # [b, g, rows, blocks]
+        own = (at // block_size)[:, None]
+        visible = blocks[None, :] <= own
+        forced = visible & ((blocks[None, :] < init_blocks)
+                            | (blocks[None, :] > own - window // block_size))
+        score = jnp.where(visible, jnp.where(forced, jnp.inf, pooled), -jnp.inf)
+        # A block's rank among its row's: how many lie ahead of it, by a
+        # higher score or, of equal scores, a lower index. (One compare and
+        # one sum a pair of blocks, fused; ``lax.top_k`` sorts, 50 ms a step
+        # at 16k where this is 5: PERF.md §6, PR 54.)
+        ahead = (score[..., None, :] > score[..., :, None]) | (
+            (score[..., None, :] == score[..., :, None])
+            & (blocks[None, :] < blocks[:, None]))
+        return (ahead.sum(-1) < topk) & visible
+
+    chosen = jax.lax.map(some_rows, jnp.arange(t_p // rows) * rows)
+    # [chunks, b, g, rows, blocks] -> [b, g, t, blocks]
+    return jnp.moveaxis(chosen, 0, 2).reshape(b, g, t_p, n_blocks)[:, :, :t]
+
+
+def _sparse_blocks(t: int, block_size: int):
+    """(block_q, block_k, the padded length): a key tile is a whole number
+    of ``block_size`` blocks, at most 16 of them (a word's low bits)."""
+    block_k = min(SPARSE_BLOCK_K, 16 * block_size)
+    block_k = max(block_k // block_size, 1) * block_size
+    block_q = min(SPARSE_BLOCK_Q, -(-t // 8) * 8)
+    step = block_q * block_k // np.gcd(block_q, block_k)
+    return block_q, block_k, -(-t // step) * step
+
+
+def _pack(blocks, block_size: int, block_k: int, t_p: int):
+    """[B, G, T, blocks] bool -> [B * G, t_p, lanes] int32: lane j of a row
+    holds, bit u, whether the row chose block u of key tile j."""
+    b, g, t, n = blocks.shape
+    bits, tiles = block_k // block_size, t_p // block_k
+    x = jnp.pad(blocks, ((0, 0), (0, 0), (0, t_p - t), (0, tiles * bits - n)))
+    words = (x.reshape(b * g, t_p, tiles, bits).astype(jnp.int32)
+             << np.arange(bits, dtype=np.int32)).sum(-1)
+    return jnp.pad(words, ((0, 0), (0, 0), (0, -tiles % 128)))
+
+
+def _tile_words(words, ik):
+    """[block_q, 1]: each row's word of key tile ``ik``, from the rows' words
+    [block_q, lanes]. Zero throughout: no row of the tile chose a block of
+    that key tile."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, words.shape, 1)
+    return jnp.sum(jnp.where(lane == ik, words, 0), axis=1, keepdims=True)
+
+
+def _sparse_mask(mine, iq, ik, *, block_q: int, block_k: int, block_size: int):
+    """[block_q, block_k]: which keys of tile ``ik`` the rows of tile ``iq``
+    see, from the rows' words of that tile. (lax's primitives, as in
+    ``_band``.)"""
+    shape = (block_q, block_k)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    bit = jax.lax.shift_right_logical(
+        jnp.broadcast_to(mine, shape), jax.lax.div(col, jnp.int32(block_size)))
+    chosen = jax.lax.bitwise_and(bit, jnp.int32(1)) == 1
+    qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return jnp.logical_and(chosen, qpos >= ik * block_k + col)
+
+
+def _sparse_live(iq, ik, block_q: int, block_k: int):
+    """The tile holds a key at or before one of its rows."""
+    return ik * block_k < (iq + 1) * block_q
+
+
+def _sparse_fwd_kernel(q_ref, k_ref, v_ref, w_ref, o_ref, lse_ref, m_scr, l_scr,
+                       acc_scr, *, sm_scale: float, **tile):
+    iq, ik = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(_sparse_live(iq, ik, tile["block_q"], tile["block_k"]))
+    def _compute():
+        mine = _tile_words(w_ref[0], ik)
+
+        @pl.when(jnp.max(mine) != 0)
+        def _chosen():
+            mask = _sparse_mask(mine, iq, ik, **tile)
+            s = jax.lax.dot_general(
+                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale
+            s = jnp.where(mask, s, NEG_INF)
+            # As in the windowed kernel: a row that sees nothing of this tile
+            # adds p = 1 a key at m = NEG_INF, which the first tile it does see
+            # multiplies by 0; every row sees its own position.
+            m_prev = m_scr[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            l_scr[:] = l_scr[:] * correction + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[:] = m_new
+
+    @pl.when(ik == pl.num_programs(2) - 1)
+    def _finish():
+        l = l_scr[:]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        lse_ref[0] = m_scr[:] + jnp.log(l_safe)
+
+
+def _bwd_dkv_sparse_kernel(q_ref, k_ref, v_ref, w_ref, do_ref, lse_ref,
+                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                           *, sm_scale: float, steps: int, **tile):
+    # The last axis walks the row tiles once for each q head of this K/V
+    # head's group.
+    ik, j = pl.program_id(1), pl.program_id(2)
+    jq = jax.lax.rem(j, jnp.int32(steps))
+
+    @pl.when(j == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(_sparse_live(jq, ik, tile["block_q"], tile["block_k"]))
+    def _compute():
+        mine = _tile_words(w_ref[0], ik)
+
+        @pl.when(jnp.max(mine) != 0)
+        def _chosen():
+            q, do = q_ref[0], do_ref[0]
+            p, ds = _window_ds(q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0],
+                               _sparse_mask(mine, jq, ik, **tile), sm_scale)
+            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _bwd_dq_sparse_kernel(q_ref, k_ref, v_ref, w_ref, do_ref, lse_ref,
+                          delta_ref, dq_ref, dq_scr, *, sm_scale: float, **tile):
+    iq, ik = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ik == 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(_sparse_live(iq, ik, tile["block_q"], tile["block_k"]))
+    def _compute():
+        mine = _tile_words(w_ref[0], ik)
+
+        @pl.when(jnp.max(mine) != 0)
+        def _chosen():
+            k = k_ref[0]
+            _, ds = _window_ds(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
+                               _sparse_mask(mine, iq, ik, **tile), sm_scale)
+            dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+
+    @pl.when(ik == pl.num_programs(2) - 1)
+    def _finish():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _sparse_specs(q, k, v, words, block_size: int):
+    """What the three sparse calls share: the tile's statics, the grid's
+    extents and the BlockSpecs of a walk over a row tile's keys."""
+    bh, t_p, d = q.shape
+    group, d_v = bh // k.shape[0], v.shape[2]
+    block_q, block_k, _ = _sparse_blocks(t_p, block_size)
+    nq, nk = t_p // block_q, t_p // block_k
+    tile = dict(block_q=block_q, block_k=block_k, block_size=block_size)
+
+    def last_key(i):  # the last key tile a row tile sees
+        return jax.lax.div((i + 1) * block_q - 1, jnp.int32(block_k))
+
+    def key_walk(b, i, j):
+        return jax.lax.div(b, jnp.int32(group)), jax.lax.min(j, last_key(i)), 0
+
+    here = lambda b, i, j: (b, i, 0)  # noqa: E731
+    rows = lambda width, index: pl.BlockSpec((1, block_q, width), index)  # noqa: E731
+    keys = lambda width, index: pl.BlockSpec((1, block_k, width), index)  # noqa: E731
+    lanes = words.shape[2]
+    return dict(
+        tile=tile, group=group, nq=nq, nk=nk, rows=rows, keys=keys, here=here,
+        key_walk=key_walk, lanes=lanes, d=d, d_v=d_v,
+        words_here=pl.BlockSpec(
+            (1, block_q, lanes),
+            lambda b, i, j: (jax.lax.div(b, jnp.int32(group)), i, 0)),
+        params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 2**20),
+    )
+
+
+def _sparse_fwd_pallas(q, k, v, words, *, sm_scale, block_size):
+    """q [b * h, t, d]; k, v [b * g, t, .]; words [b * g, t, lanes]; t
+    padded to the tiles. -> (o, lse [bh, t])."""
+    bh, t_p, d = q.shape
+    s = _sparse_specs(q, k, v, words, block_size)
+    d_v, tile = s["d_v"], s["tile"]
+    o, lse = pl.pallas_call(
+        functools.partial(_sparse_fwd_kernel, sm_scale=sm_scale, **tile),
+        grid=(bh, s["nq"], s["nk"]),
+        interpret=_interpret(),
+        in_specs=[s["rows"](d, s["here"]), s["keys"](d, s["key_walk"]),
+                  s["keys"](d_v, s["key_walk"]), s["words_here"]],
+        out_specs=[s["rows"](d_v, s["here"]), s["rows"](1, s["here"])],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t_p, d_v), q.dtype),
+            jax.ShapeDtypeStruct((bh, t_p, 1), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((tile["block_q"], 1), jnp.float32),
+            pltpu.VMEM((tile["block_q"], 1), jnp.float32),
+            pltpu.VMEM((tile["block_q"], d_v), jnp.float32),
+        ],
+        compiler_params=s["params"],
+    )(q, k, v, words)
+    return o, lse[..., 0]
+
+
+def _sparse_bwd_pallas(q, k, v, words, o, lse, do, *, sm_scale, block_size):
+    bh, t_p, d = q.shape
+    s = _sparse_specs(q, k, v, words, block_size)
+    d_v, tile, group, nq = s["d_v"], s["tile"], s["group"], s["nq"]
+    block_q, block_k = tile["block_q"], tile["block_k"]
+    rows, keys, here = s["rows"], s["keys"], s["here"]
+    delta = jnp.sum(
+        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
+    )  # [bh, t, 1]
+    lse3 = lse[..., None]
+    static = dict(sm_scale=sm_scale, **tile)
+
+    def row_tile(i, j):  # a dead step waits at the first live row tile
+        first = jax.lax.div(i * block_k, jnp.int32(block_q))
+        return jax.lax.max(jax.lax.rem(j, jnp.int32(nq)), first)
+
+    def row_walk(b, i, j):
+        return b * group + jax.lax.div(j, jnp.int32(nq)), row_tile(i, j), 0
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_sparse_kernel, steps=nq, **static),
+        interpret=_interpret(),
+        grid=(k.shape[0], s["nk"], group * nq),
+        in_specs=[rows(d, row_walk), keys(d, here), keys(d_v, here),
+                  pl.BlockSpec((1, block_q, s["lanes"]),
+                               lambda b, i, j: (b, row_tile(i, j), 0)),
+                  rows(d_v, row_walk), rows(1, row_walk), rows(1, row_walk)],
+        out_specs=[keys(d, here), keys(d_v, here)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
+        ],
+        compiler_params=s["params"],
+    )(q, k, v, words, do, lse3, delta)
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_sparse_kernel, **static),
+        interpret=_interpret(),
+        grid=(bh, nq, s["nk"]),
+        in_specs=[rows(d, here), keys(d, s["key_walk"]),
+                  keys(d_v, s["key_walk"]), s["words_here"], rows(d_v, here),
+                  rows(1, here), rows(1, here)],
+        out_specs=rows(d, here),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=s["params"],
+    )(q, k, v, words, do, lse3, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _sparse_flash(q, k, v, words, sm_scale, block_size):
+    return _sparse_fwd_pallas(q, k, v, words, sm_scale=sm_scale,
+                              block_size=block_size)[0]
+
+
+def _sparse_flash_fwd(q, k, v, words, sm_scale, block_size):
+    o, lse = _sparse_fwd_pallas(q, k, v, words, sm_scale=sm_scale,
+                                block_size=block_size)
+    # Named for the remat policy, as ``_flash``'s are.
+    o, lse = checkpoint_name(o, "sparse_o"), checkpoint_name(lse, "sparse_lse")
+    return o, (q, k, v, words, o, lse)
+
+
+def _sparse_flash_bwd(sm_scale, block_size, res, do):
+    dq, dk, dv = _sparse_bwd_pallas(*res, do, sm_scale=sm_scale,
+                                    block_size=block_size)
+    return dq, dk, dv, np.zeros(res[3].shape, jax.dtypes.float0)
+
+
+_sparse_flash.defvjp(_sparse_flash_fwd, _sparse_flash_bwd)
+
+
+def _sparse_xla(q, k, v, blocks, block_size: int, scale: float):
+    """The same attention by a [T, T] mask: the CPU's road, at test sizes."""
+    b, h, t, d = q.shape
+    g = k.shape[1]
+    seen = jnp.repeat(blocks, block_size, axis=-1)[..., :t] & _visible(t, t, None)
+    s = jnp.einsum("bgjtd,bgsd->bgjts", q.reshape(b, g, h // g, t, d), k,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(seen[:, :, None], s, NEG_INF), axis=-1)
+    o = jnp.einsum("bgjts,bgsd->bgjtd", p.astype(v.dtype), v)
+    return o.reshape(b, h, t, v.shape[-1])
+
+
+def _sparse_attention(q, k, v, blocks, block_size: int, scale: float):
+    """``flash_attention``'s road under ``blocks``."""
+    b, h, t, d = q.shape
+    g, d_v = k.shape[1], v.shape[-1]
+    if not _kernels_fit(t, t, d, d_v):
+        return _sparse_xla(q, k, v, blocks, block_size, scale)
+    _, block_k, t_p = _sparse_blocks(t, block_size)
+    o = _sparse_flash(
+        _pad_rows(q.reshape(b * h, t, d), t_p),
+        _pad_rows(k.reshape(b * g, t, d), t_p),
+        _pad_rows(v.reshape(b * g, t, d_v), t_p),
+        _pack(blocks, block_size, block_k, t_p), scale, block_size,
+    )
+    return o[:, :t].reshape(b, h, t, d_v)
+
+
 # ------------------------------------------------- one block, kernels or XLA
 
 
@@ -915,6 +1315,8 @@ def flash_attention(
     # 1,024 x 1,024: PERF.md §6, PR 46, has the sweep on the chip.
     block_q: int = 1024,
     block_k: int = 1024,
+    blocks: Optional[jax.Array] = None,
+    block_size: Optional[int] = None,
 ) -> jax.Array:
     """Blockwise (flash) attention, the one entry point of the mixers.
 
@@ -927,6 +1329,10 @@ def flash_attention(
     keeps keys 0 <= i - j < w of row i: the kernels' road takes it through
     the windowed kernels, whose grids walk the band alone, at blocks of
     their own, and the reference masks it; the ring refuses it.
+    ``blocks`` [B, Hkv, T, ceil(T / block_size)] bool (causal self-attention
+    only; ``select_blocks`` makes it) keeps, of the keys row i of a K/V group
+    sees, those in the blocks of ``block_size`` keys it marks: the sparse
+    kernels where they fit, a masked soft-max elsewhere; the ring refuses it.
     """
     b, h, tq, d = q.shape
     hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[-1]
@@ -943,6 +1349,19 @@ def flash_attention(
             f"window={window} needs causal=True and at least the row itself"
         )
     scale = sm_scale if sm_scale is not None else 1.0 / d**0.5
+    if blocks is not None:
+        if not causal or window is not None or tq != tk or not block_size:
+            raise ValueError(
+                "blocks= is causal self-attention's, with its block_size and "
+                f"no window (causal={causal}, window={window}, Tq={tq}, "
+                f"Tk={tk}, block_size={block_size})"
+            )
+        if logical_axis_shards("seq") > 1:
+            raise ValueError(
+                f"blocks= under mesh axis {ambient_axes('seq')}, which splits "
+                "the sequence: the ring has no selection"
+            )
+        return _sparse_attention(q, k, v, blocks, block_size, scale)
     if logical_axis_shards("seq") > 1:
         from .ring_attention import ring_attention  # it imports this module
 

@@ -1281,3 +1281,233 @@ def chunk_gdn(q, k, v, g, beta, gate, weight, *, scale, rms_eps, l2_eps=1e-6):
             scalar(beta), heads_first(gate), weight.astype(F32)[None],
             (scale, l2_eps, rms_eps))
     return heads_first(o)[:, :t]
+
+
+# ------------------------------------------- a fixed decay a head: Lightning
+# Lightning Attention-2's recurrence, S_t = lambda_h S_{t-1} + k_t v_t^T, o_t
+# = scale S_t^T q_t: the delta rule's chunk without its write strength, its
+# learned decay and its triangular system. lambda_h = exp(-slope_h) is a
+# constant of the head, so the decay between two rows of a chunk is a function
+# of their distance: D[t, s] = exp(-slope (t - s)), made from the slope and two
+# iotas where it is used, never an exponent of a running sum and never
+# positive. With S the state at the chunk's start, t a row's index in it:
+#
+#     O  = scale ((Q K^T * D) V + (Q exp(-slope (t + 1))) S)
+#     S' = exp(-slope C) S + (K exp(-slope (C - 1 - t)))^T V
+#
+# A chunk is 256 rows of one head: there is no inverse whose chain of small
+# products wanted 64, a [256, 128] operand fills the MXU's rows twice over,
+# and the states kept for the backward are a quarter of what 64 would write
+# (on the chip, forward and backward of 32 heads of 128 at 16k tokens: 14.8 ms
+# at 64, 11.0 at 128, 8.9 at 256: PERF.md §6, PR 54). q and k arrive normed and
+# turned (the mixer's, under its scopes); o leaves through ``_rms_normed`` and
+# the output gate's sigmoid while it is float32, as ``chunk_gdn``'s does. The
+# grid is (batch, head, chunk), the state of the one head in VMEM over its
+# chunks; the backward walks the chunks in reverse with the state's cotangent
+# there and differentiates ``_lightning_chunk`` where it stands from the saved
+# state, as ``_gdn_bwd_kernel`` does.
+LIGHTNING_CHUNK = 256
+
+
+def _lightning_chunk(St, q, k, v, gate, weight, slope, *, norm):
+    """One head's chunk: St [dv, dk] float32, q, k [C, dk], v and the gate
+    (before its sigmoid) [C, dv], the norm's weight [1, dv] and the head's
+    slope [1, 1] float32; ``norm`` is (o's scale, the RMSNorm's epsilon). ->
+    (the state at the chunk's end, the normed and gated o [C, dv] float32)."""
+    scale, rms_eps = norm
+    dt = v.dtype
+    c = q.shape[0]
+    row, col = _rows_cols(c)
+    apart = jnp.maximum(row - col, 0).astype(F32)
+    decay = jnp.where(row >= col, jnp.exp(-slope * apart), 0.0)
+    at = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0).astype(F32)
+    since_start = jnp.exp(-slope * (at + 1.0))
+    to_end = jnp.exp(-slope * (c - 1.0 - at))
+    sd = St.astype(dt)
+    o = _nn((_nt(q, k) * decay).astype(dt), v) + _nt(
+        (q.astype(F32) * since_start).astype(dt), sd)
+    St = jnp.exp(-slope * c) * St + _tn(v, (k.astype(F32) * to_end).astype(dt))
+    o = _rms_normed(o * scale, rms_eps) * weight * jax.nn.sigmoid(gate.astype(F32))
+    return St, o
+
+
+def _lightning_fwd_kernel(slope_ref, q_ref, k_ref, v_ref, gate_ref, w_ref,
+                          o_ref, *rest, norm):
+    # rest: (the states' output, the scratch) or the scratch alone.
+    s_ref, st_scr = rest if len(rest) == 2 else (None, *rest)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        st_scr[...] = jnp.zeros(st_scr.shape, F32)
+
+    St = st_scr[...]
+    if s_ref is not None:
+        s_ref[0, 0, 0] = St
+    st_scr[...], o = _lightning_chunk(
+        St, q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], gate_ref[0, 0], w_ref[...],
+        slope_ref[0, :, :1], norm=norm)
+    o_ref[0, 0] = o.astype(o_ref.dtype)
+
+
+def _lightning_bwd_kernel(slope_ref, q_ref, k_ref, v_ref, gate_ref, w_ref,
+                          s_ref, do_ref, dq_ref, dk_ref, dv_ref, dgate_ref,
+                          dw_ref, dst_scr, *, norm):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dst_scr[...] = jnp.zeros(dst_scr.shape, F32)
+        # The weight's cotangent adds up over a head's chunks in its output
+        # block, which stays in VMEM while the block's index stands.
+        dw_ref[...] = jnp.zeros(dw_ref.shape, F32)
+
+    slope = slope_ref[0, :, :1]
+    _, vjp = jax.vjp(
+        lambda *operands: _lightning_chunk(*operands, slope, norm=norm),
+        s_ref[0, 0, 0], q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], gate_ref[0, 0],
+        w_ref[...],
+    )
+    dst_scr[...], dq, dk_, dv_, dgate, dw = vjp(
+        (dst_scr[...], do_ref[0, 0].astype(F32)))
+    for ref, x in ((dq_ref, dq), (dk_ref, dk_), (dv_ref, dv_), (dgate_ref, dgate)):
+        ref[0, 0] = x.astype(ref.dtype)
+    dw_ref[0, 0] += dw
+
+
+def _lightning_specs(dk, dv, chunk_of):
+    """BlockSpecs over grid (batch, head, step) for operands that lie [B, H,
+    T, d], ``chunk_of(step)`` the chunk a step works on. The states lie [B, H,
+    N, dv, dk], the slopes [H, 1, _LANES] (a head's on every lane), the
+    weight's cotangent [B, H, 1, dv]."""
+    c = LIGHTNING_CHUNK
+
+    def rows(d):
+        return pl.BlockSpec((1, 1, c, d), lambda b, h, n: (b, h, chunk_of(n), 0))
+
+    return {
+        "k": rows(dk), "v": rows(dv),
+        "slope": pl.BlockSpec((1, 1, _LANES), lambda b, h, n: (h, 0, 0)),
+        "weight": pl.BlockSpec((1, dv), lambda b, h, n: (0, 0)),
+        "dweight": pl.BlockSpec((1, 1, 1, dv), lambda b, h, n: (b, h, 0, 0)),
+        "state": pl.BlockSpec((1, 1, 1, dv, dk),
+                              lambda b, h, n: (b, h, chunk_of(n), 0, 0)),
+        "scratch": pltpu.VMEM((dv, dk), F32),
+        "params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+    }
+
+
+def _lightning_forward_pallas(q, k, v, gate, weight, slopes, norm, states):
+    batch, heads, t, dk = q.shape
+    dv, n = v.shape[3], t // LIGHTNING_CHUNK
+    s = _lightning_specs(dk, dv, lambda i: i)
+    return pl.pallas_call(
+        functools.partial(_lightning_fwd_kernel, norm=norm),
+        grid=(batch, heads, n),
+        in_specs=[s["slope"], s["k"], s["k"], s["v"], s["v"], s["weight"]],
+        out_specs=[s["v"], s["state"]][:1 + states],
+        out_shape=[
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((batch, heads, n, dv, dk), F32),
+        ][:1 + states],
+        scratch_shapes=[s["scratch"]],
+        compiler_params=s["params"],
+        interpret=_attention._interpret(),
+    )(slopes, q, k, v, gate, weight)
+
+
+def _lightning_backward_pallas(q, k, v, gate, weight, slopes, states, do, norm):
+    batch, heads, t, dk = q.shape
+    dv, n = v.shape[3], t // LIGHTNING_CHUNK
+    s = _lightning_specs(dk, dv, lambda i: n - 1 - i)
+    return pl.pallas_call(
+        functools.partial(_lightning_bwd_kernel, norm=norm),
+        grid=(batch, heads, n),
+        in_specs=[s["slope"], s["k"], s["k"], s["v"], s["v"], s["weight"],
+                  s["state"], s["v"]],
+        out_specs=[s["k"], s["k"], s["v"], s["v"], s["dweight"]],
+        out_shape=[
+            *(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v, gate)),
+            jax.ShapeDtypeStruct((batch, heads, 1, dv), F32),
+        ],
+        scratch_shapes=[s["scratch"]],
+        compiler_params=s["params"],
+        interpret=_attention._interpret(),
+    )(slopes, q, k, v, gate, weight, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _lightning_pallas(q, k, v, gate, weight, slopes, norm):
+    """The normed, gated o [B, H, T, dv] from q, k [B, H, T, dk], v and the
+    gate [B, H, T, dv], the norm's weight [1, dv] and the slopes [H, 1,
+    _LANES], T a whole number of chunks. As ``_gdn_pallas``: under a gradient
+    the forward rule writes and names o and every chunk's first state
+    (``lightning_o``, ``lightning_states``: models/llama.py REPLAY_KEEPS)."""
+    return _lightning_forward_pallas(q, k, v, gate, weight, slopes, norm,
+                                     states=False)[0]
+
+
+def _lightning_pallas_fwd(q, k, v, gate, weight, slopes, norm):
+    o, states = _lightning_forward_pallas(q, k, v, gate, weight, slopes, norm,
+                                          states=True)
+    o = checkpoint_name(o, "lightning_o")
+    states = checkpoint_name(states, "lightning_states")
+    return o, (q, k, v, gate, weight, slopes, states)
+
+
+def _lightning_pallas_bwd(norm, residuals, do):
+    *operands, slopes, states = residuals
+    dq, dk, dv, dgate, dw = _lightning_backward_pallas(
+        *operands, slopes, states, do.astype(operands[2].dtype), norm)
+    # The slopes are the layer's constants: nothing learns them.
+    return dq, dk, dv, dgate, dw.sum((0, 1)), jnp.zeros_like(slopes)
+
+
+_lightning_pallas.defvjp(_lightning_pallas_fwd, _lightning_pallas_bwd)
+
+
+def _lightning_xla(q, k, v, gate, weight, slopes, norm):
+    """The same function of the same layouts under ``lax.scan``, one head a
+    call, for JAX to differentiate: where there is no TPU."""
+    batch, heads, t, dk = q.shape
+    c = LIGHTNING_CHUNK
+
+    def chunks(x):  # [B, H, T, d] -> [N, B, H, C, d]
+        return jnp.moveaxis(x.reshape(batch, heads, t // c, c, -1), 2, 0)
+
+    def one(St, q, k, v, gate, slope):
+        return _lightning_chunk(St, q, k, v, gate, weight, slope, norm=norm)
+
+    def step(St, chunk):
+        heads_of = jax.vmap(one, in_axes=(0, 0, 0, 0, 0, 0))
+        return jax.vmap(heads_of, in_axes=(0, 0, 0, 0, 0, None))(
+            St, *chunk, jax.lax.stop_gradient(slopes[:, :, :1]))
+
+    _, o = jax.lax.scan(
+        step, jnp.zeros((batch, heads, v.shape[3], dk), F32),
+        tuple(chunks(x) for x in (q, k, v, gate)))
+    return jnp.moveaxis(o, 0, 2).reshape(v.shape).astype(v.dtype)
+
+
+def chunk_lightning(q, k, v, gate, weight, slopes, *, scale, rms_eps):
+    """A Lightning (decay-only linear attention) mixer from its normed and
+    turned q and k to its output projection's input, in ``chunk_gdn``'s
+    layout: S_t = exp(-slopes_h) S_{t-1} + k_t v_t^T in float32, o_t = scale
+    S_t^T q_t, then o's RMSNorm over a head's channels (``weight`` [dv]) times
+    ``sigmoid(gate)``. q, k [B, T, H, dk]; v, gate [B, T, H, dv], the gate
+    before its sigmoid; slopes [H] float32 >= 0, constants of the layer.
+    Returns [B, T, H, dv] in v's dtype. Differentiable in q, k, v, the gate
+    and the weight."""
+    t = q.shape[1]
+    pad = -t % LIGHTNING_CHUNK
+    if pad:
+        # Padding tokens follow the last real one and write zeros.
+        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+        q, k, v, gate = (jnp.pad(x, widths) for x in (q, k, v, gate))
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    run = (_lightning_pallas if _attention._on_tpu() or _attention._interpret()
+           else _lightning_xla)
+    lanes = jnp.broadcast_to(
+        slopes.astype(F32)[:, None, None], (slopes.shape[0], 1, _LANES))
+    o = run(heads_first(q), heads_first(k), heads_first(v), heads_first(gate),
+            weight.astype(F32)[None], lanes, (scale, rms_eps))
+    return heads_first(o)[:, :t]

@@ -60,12 +60,15 @@ MOE_DISPATCH = "dispatch"  # positions, slot map, gather into the expert buffer
 MOE_EXPERTS = "experts"  # the three expert matmuls and the activation
 MOE_COMBINE = "combine"  # gather back, gate scaling, the reduction over k
 MOE_LAYOUT = "layout"  # inside dispatch, "gmm" only: sort, tile layout, inverse map
-QK_NORM = "qk_norm"  # RMSNorm of q and k: the whole projections in attn (cfg.qk_norm), a head's channels inside mla (cfg.qk_head_norm)
+QK_NORM = "qk_norm"  # RMSNorm of q and k: the whole projections in attn (cfg.qk_norm), a head's channels inside mla (cfg.qk_head_norm), inside sparse and inside lightning
 MOE_SHARED = "shared"  # inside moe: the shared expert every token passes
 # The mixers' flax names, which reach op_name as the attention's "attn" does.
 KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer; Solar-Open2's KDA layers too)
 MLA = "mla"  # the latent-attention mixer (models/mla.py MLAMixer)
 GDN = "gdn"  # the scalar-decay gated delta-rule mixer (models/olmo_hybrid.py GDNMixer); conv, gate and scan inside it as inside kda, scan holding ops/kda.py chunk_gdn and the transpositions around it
+LIGHTNING = "lightning"  # the decay-only linear-attention mixer (models/minicpm_sala.py LightningMixer): projections, qk_norm and rotary scopes, ops/kda.py chunk_lightning (its kernels hold o's norm and the output gate) and the transpositions around it
+SPARSE = "sparse"  # the block-sparse top-k softmax mixer (models/minicpm_sala.py SparseAttention): projections, qk_norm, select, ops/attention.py sparse_attention's kernels, out_gate
+SPARSE_SELECT = "select"  # inside sparse, where T > dense_len: ops/attention.py select_blocks (compressed keys, the scores of every head against them, their soft-max, the sum over a group's heads, the max-pool to blocks, the forced blocks, top-k, the packed bitmap); nothing of it is differentiated
 KDA_CONV = "conv"  # inside kda: the short convolutions of q, k, v and their SiLU
 KDA_GATE = "gate"  # inside kda: the log-decay g and the write strength beta, with its doubling where the config writes in (0, 2)
 KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's and o's norms and the output gate), v's rounding, beta's transpose
@@ -76,8 +79,8 @@ MLA_ROPE = "rope"  # inside mla (cfg.mla_rope): the frequency table, the rotatio
 MLA_Q_LATENT = "q_latent"  # inside mla (cfg.q_lora_rank): q's down-projection, its norm, the up-projection to the heads
 ATTN = "attn"  # the softmax-attention mixer (models/llama.py Attention); in a model whose layers differ, the full-attention layers' (Laguna's beside swa, Solar-Open2's beside kda)
 SWA = "swa"  # the same module as a sliding-window layer's mixer (models/laguna.py): its own head count and rotation, flash_attention under a window
-ATTN_ROPE = "rotary"  # inside attn and swa: the angles, cos and sin, the rotation of q and of k (a part of each head where the layer's kind says so); not opened by a kind that turns nothing
-ATTN_GATE = "out_gate"  # inside attn and swa (a kind with a gate): the gate's projection (one value a head, or of q's width), its sigmoid, the product with each head's output
+ATTN_ROPE = "rotary"  # inside attn, swa and lightning: the angles, cos and sin, the rotation of q and of k (a part of each head where the layer's kind says so); not opened by a kind that turns nothing
+ATTN_GATE = "out_gate"  # inside attn and swa (a kind with a gate) and inside sparse: the gate's projection (one value a head, or of q's width), its sigmoid, the product with each head's output
 HC = "hc"  # a hyper-connection (models/hyper_connections.py), twice a layer: the three maps of the streams, the read before the sublayer, the write after it
 HC_PRE = "pre"  # inside hc: the streams' rms, x~ Phi, the three logits, H_pre and H_post, the read u = sum H_pre[i] X[i]
 HC_SINKHORN = "sinkhorn"  # inside hc: exp, the iterations of rows and columns, and their backward
@@ -89,9 +92,9 @@ LOSS_HEAD = "head"  # inside loss: a chunk's float32 matmul with the head, alone
 SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
           MLA_LATENT, MLA_ROPE, MLA_Q_LATENT, ATTN_ROPE, ATTN_GATE, HC, HC_PRE,
-          HC_SINKHORN, HC_POST, HC_STREAMS, MTP, LOSS, LOSS_HEAD)
+          HC_SINKHORN, HC_POST, HC_STREAMS, MTP, LOSS, LOSS_HEAD, SPARSE_SELECT)
 # Flax module names, bound in the model classes' ``blocks``.
-MIXERS = (KDA, MLA, ATTN, SWA, GDN)
+MIXERS = (KDA, MLA, ATTN, SWA, GDN, LIGHTNING, SPARSE)
 # The decoder body's flax names (models/llama.py, xing4.py), a layer's and
 # above: parameter trees and checkpoints hold them, so none is ever renamed.
 EMBED = "embed_tokens"  # the embedding table's flax name; models/llama.py _lookup opens it as a scope around what it does outside the module (the one-hot product where a mesh splits the table, the constraint on the result)

@@ -699,6 +699,102 @@ def test_olmo_hybrids_step_convolves_by_the_kernels_and_broadcasts_no_decay(olmo
     assert not reduced, reduced[:2]
 
 
+# MiniCPM-SALA's kernels at the benchmark's real size (b1 x s16384): the
+# fixed-decay scan at 32 heads of 128 in chunks of 256 rows (and at an odd
+# head count), the three sparse kernels at 32 query heads over K and V at
+# their own 2, the chosen blocks as [2, T, 128] words.
+@pytest.mark.parametrize("t,h", [(16384, 32), (1024, 3)])
+def test_lightning_kernels_compile_for_v5e(v5e, t, h):
+    b, d = 1, 128
+    rows = ((b, h, t, d), jnp.bfloat16)
+    operands = (rows, rows, rows, rows, ((1, d), jnp.float32),
+                ((h, 1, 128), jnp.float32))
+    norm = (d ** -0.5, 1e-6)
+    _compile_for(v5e, lambda *a: kda._lightning_forward_pallas(*a, norm, states=False), *operands)
+    text = _compile_for(
+        v5e, lambda *a: kda._lightning_forward_pallas(*a, norm, states=True), *operands)
+    states = (b, h, t // kda.LIGHTNING_CHUNK, d, d)
+    assert "f32[%s]" % ",".join(map(str, states)) in text  # float32, a chunk's first
+    _compile_for(
+        v5e, lambda *a: kda._lightning_backward_pallas(*a, norm), *operands,
+        (states, jnp.float32), rows)
+
+
+@pytest.mark.parametrize("t,block_size", [(16384, 64), (2048, 16)])
+def test_sparse_kernels_compile_for_v5e_with_k_and_v_at_two_heads(v5e, t, block_size):
+    from ray_tpu.ops.attention import (
+        _sparse_blocks, _sparse_bwd_pallas, _sparse_fwd_pallas,
+    )
+
+    h, g, d = 32, 2, 128
+    _, block_k, t_p = _sparse_blocks(t, block_size)
+    assert t_p == t and t // block_k <= 128
+    q, kv = ((h, t, d), jnp.bfloat16), ((g, t, d), jnp.bfloat16)
+    words = ((g, t, 128), jnp.int32)
+    static = dict(sm_scale=d ** -0.5, block_size=block_size)
+    text = _compile_for(
+        v5e, lambda *a: _sparse_fwd_pallas(*a, **static), q, kv, kv, words)
+    assert f"bf16[{h},{t},{t}]" not in text and f"f32[{h},{t},{t}]" not in text
+    _compile_for(
+        v5e, lambda *a: _sparse_bwd_pallas(*a, **static), q, kv, kv, words, q,
+        ((h, t), jnp.float32), q)
+
+
+@pytest.fixture(scope="module")
+def minicpm_salas_step(v5e):
+    return _lowered_step(v5e, "minicpm-sala-9b-l4.long16k")
+
+
+def test_minicpm_salas_step_holds_its_kernels_under_their_names(minicpm_salas_step):
+    """MiniCPM-SALA's step at the benchmark's real size (b1 x s16384, four
+    layers at the published widths): every kernel its configuration states and
+    no more of any (the remat policy keeps ``sparse_o``, ``sparse_lse``,
+    ``lightning_o``, ``lightning_states``: no replay runs a forward kernel);
+    the Lightning states [1, 32, 64, 128, 128] float32, written thrice and
+    read thrice; no causal flash kernel, no delta-rule kernel."""
+    from benchmarks.lib import cells, checks
+
+    cell, text = minicpm_salas_step
+    stated = cells.stated_kernels(cell)
+    counts = checks.count_pallas_kernels(text, stated)
+    assert counts == {k: s["least"] for k, s in stated.items()} == {
+        "_sparse_fwd_kernel": 1, "_bwd_dkv_sparse_kernel": 1,
+        "_bwd_dq_sparse_kernel": 1, "_lightning_fwd_kernel": 3,
+        "_lightning_bwd_kernel": 3}
+    others = ("_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel",
+              "_gdn_fwd_kernel", "_kda_fwd_kernel")
+    assert not any(checks.count_pallas_kernels(text, others).values())
+    states = f"tensor<1x32x{16384 // kda.LIGHTNING_CHUNK}x128x128xf32>"
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sum(f"{states})" in line for line in calls) == 3
+    assert sum(f"{states}," in line for line in calls) == 3
+
+
+def test_minicpm_salas_step_repeats_no_k_or_v_and_makes_no_t_by_t_array(minicpm_salas_step):
+    """K and V reach the sparse kernels at their own 2 heads: no [1, 32, 16384,
+    128] array is made from a [1, 2, ...] one by a broadcast (``jnp.repeat``'s
+    lowering), and the kernels' K and V operands are [2, 16384, 128]. No array
+    of scores or of a mask is [.., 16384, 16384] (the SwiGLU's [1, 16384,
+    16384] bfloat16 products are the only ones of that extent: the
+    intermediate size is the sequence's length here): the selection is [1, 2,
+    16384, 256] bits and [2, 16384, 128] words. The replay is handed the set and
+    chooses nothing again: one while loop of the selection in the step."""
+    import re
+
+    _, text = minicpm_salas_step
+    assert not re.findall(r"16384x16384x(?:f32|i1|i8|i32)|(?:32|16|2)x16384x16384x", text)
+    repeats = re.findall(
+        r"stablehlo\.broadcast_in_dim.*\(tensor<1x2x(?:1x)?16384x128xbf16>\) -> "
+        r"tensor<1x2x16x16384x128xbf16>", text)
+    assert not repeats, repeats[:2]
+    sparse = [line for line in text.splitlines()
+              if "tpu_custom_call" in line and "_sparse_fwd_kernel" in line]
+    assert len(sparse) == 1 and sparse[0].count("tensor<2x16384x128xbf16>") >= 2
+    assert "tensor<2x16384x128xi32>" in sparse[0]  # the words, a lane a key tile
+    assert "tensor<1x2x16384x256xi1>" in text  # the chosen blocks
+    assert text.count("tensor<1x2x16384x256xi1>") >= 2
+
+
 # One of Xing4's hyper-connections at the benchmark's real size: four streams
 # of b1 x s4096 tokens at the published 3584 channels.
 HC_STREAMS = ((4, 1, 4096, 3584), jnp.bfloat16)

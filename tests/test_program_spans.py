@@ -324,7 +324,68 @@ def olmo_paths():
         del os.environ["RAY_TPU_PALLAS_INTERPRET"]
 
 
+@pytest.fixture(scope="module")
+def sala_paths():
+    """Paths of a tiny MiniCPM-SALA's compiled train step: a block-sparse
+    top-k layer (4 heads over 2 K/V heads, sparse from 33 tokens on) and a
+    Lightning layer, each over the dense MLP, under the muP scales."""
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+    from ray_tpu.models.minicpm_sala import (
+        MiniCPMSalaForCausalLM, minicpm_sala_config,
+    )
+
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"  # the kernels, as on the chip
+    try:
+        cfg = minicpm_sala_config(
+            mixer_types=["minicpm4", "lightning-attn"], num_layers=2,
+            published_layers=32, scale_emb=12, scale_depth=1.4, dim_model_base=16,
+            lightning_nh=4, lightning_nkv=4, lightning_head_dim=8,
+            sparse_config={"kernel_size": 8, "kernel_stride": 4, "block_size": 16,
+                           "topk": 4, "init_blocks": 1, "window_size": 32,
+                           "dense_len": 32},
+            vocab_size=100, hidden_size=32, intermediate_size=64, num_heads=4,
+            num_kv_heads=2, head_dim=8,
+        )
+        model = MiniCPMSalaForCausalLM(cfg)
+        ids = jnp.zeros((1, 128), jnp.int32)
+        return paths_of(compiled_step(
+            model,
+            lambda p, i, t: chunked_causal_lm_loss(model, p, i, t, chunk_size=64),
+            ids,
+        ))
+    finally:
+        del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+
+
 # ----------------------------------------------------------- in-graph scopes
+
+
+def test_a_sparse_and_lightning_hybrid_carries_its_scopes(sala_paths):
+    """What model.sparse_share, model.sparse_select_share and
+    model.lightning_share select by: /sparse/ with ``select`` (forward alone:
+    nothing of the choice is differentiated, and the replay is handed the
+    set), ``qk_norm`` and ``out_gate`` inside it and no ``rotary``;
+    /lightning/ with ``qk_norm`` and ``rotary``; the muP scales under the
+    names of what they scale."""
+    sparse = [p for p in sala_paths if "/layers_0/sparse/" in p]
+    lightning = [p for p in sala_paths if "/layers_1/lightning/" in p]
+    assert sparse and lightning and not [
+        p for p in sala_paths
+        if "/layers_1/sparse/" in p or "/layers_0/lightning/" in p or "/attn/" in p]
+    for name in (tracing.SPARSE_SELECT, tracing.QK_NORM, tracing.ATTN_GATE):
+        assert any(f"/sparse/{name}/" in p for p in sparse), name
+    assert not [p for p in sparse if f"/{tracing.ATTN_ROPE}/" in p]
+    select = [p for p in sparse if f"/sparse/{tracing.SPARSE_SELECT}/" in p]
+    assert {pass_of(p) for p in select} == {"forward"}
+    for name in (tracing.QK_NORM, tracing.ATTN_ROPE):
+        assert any(f"/lightning/{name}/" in p for p in lightning), name
+    assert not [p for p in lightning if f"/{tracing.SPARSE_SELECT}/" in p]
+    for mixer in (sparse, lightning):
+        assert {pass_of(p) for p in mixer} >= {"forward", "backward", "replay"}
+    # the vocabulary of 100 fills no lane: the loss takes its gold logit by a
+    # select and a sum, and gathers nothing
+    loss = [p for p in sala_paths if f"({tracing.LOSS})" in p]
+    assert loss and not [p for p in loss if p.endswith("/gather")]
 
 
 def test_hyper_connections_q_latent_and_the_mtp_module_carry_their_scopes(
@@ -574,7 +635,7 @@ def test_expert_matmuls_are_under_experts_forward_and_backward(moe_paths, branch
 # Every family's compiled step, by fixture (and dispatch branch).
 FAMILIES = ("llama_paths", "qk_norm_paths", "tied_paths", "moe_paths:capacity", "moe_paths:gmm",
             "moe_paths:ragged", "kimi_paths", "sarvam_paths", "xing4_paths",
-            "laguna_paths", "solar_paths", "olmo_paths")
+            "laguna_paths", "solar_paths", "olmo_paths", "sala_paths")
 # Paths that may hold no name of the program, and why.
 EXEMPT = (
     # _positions' arange, inside the model's __call__ and outside every part:
@@ -585,6 +646,12 @@ EXEMPT = (
     # scan's loop and leaves it the jit's own level for a path; its one
     # consumer is jvp(loss)/while/body/closed_call/select_n
     (r"^jit\(train_step\)/broadcast_in_dim$", "hoisted zeros"),
+    # the same of a vocabulary that fills no whole number of lanes
+    # (chunked_head_loss takes the gold logit by a select and a sum there): the
+    # column index and the select's zeros, constants of the scan's body that
+    # JAX computes once outside it. The TPU compiler fuses both into their
+    # consumer (step.unnamed_share read 0.00006% in that cell, PERF.md 6, PR 54)
+    (r"^jit\(train_step\)/(iota|jit\(_where\)/broadcast_in_dim)$", "hoisted constants"),
     # JAX's own, at a layer's checkpoint boundary: the transposed remat2
     # equation rounds the residual stream's summed cotangent to the stream's
     # dtype outside the layer's name, which flax opens inside the checkpoint.
@@ -621,7 +688,7 @@ LOSS_KINDS = {
     "moe_paths:capacity": "full",
     "moe_paths:gmm": "full", "moe_paths:ragged": "full", "kimi_paths": "chunked",
     "sarvam_paths": "chunked", "laguna_paths": "chunked", "solar_paths": "chunked",
-    "olmo_paths": "chunked",
+    "olmo_paths": "chunked", "sala_paths": "chunked",
     "xing4_paths": "mtp",
 }
 
@@ -814,13 +881,14 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 
 def test_names_emitted_are_exactly_the_list(
     llama_paths, qk_norm_paths, moe_paths, kimi_paths, sarvam_paths,
-    xing4_paths, laguna_paths, solar_paths, olmo_paths, session_lines, actor_lines
+    xing4_paths, laguna_paths, solar_paths, olmo_paths, sala_paths, session_lines,
+    actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + olmo_paths + [
+    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + olmo_paths + sala_paths + [
         p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:  # a scope directly under a transform is in its brackets
         assert any(f"/{name}/" in p or f"({name})/" in p for p in paths), name

@@ -33,8 +33,8 @@ from ray_tpu.models.llama import (
     MLP, REPLAY_KEEPS, Attention, DecoderLayer, LlamaConfig, remat_policy,
 )
 from ray_tpu.models.mixtral import MixtralConfig, MoELayer
-from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.kda import chunk_gdn, chunk_kda
+from ray_tpu.ops.attention import flash_attention, select_blocks
+from ray_tpu.ops.kda import chunk_gdn, chunk_kda, chunk_lightning
 from ray_tpu.util import tracing
 
 LAYERS = 2
@@ -130,6 +130,51 @@ class _GDN(nn.Module):
         return x + nn.Dense(c, use_bias=False, name="o")(o.reshape(b, t, -1))
 
 
+class _Lightning(nn.Module):
+    """``_KDA`` without a write strength or a learned decay, through
+    ``chunk_lightning``: one slope a head."""
+    heads: int = 2
+    d: int = 64
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, c = x.shape
+
+        def heads(name):
+            y = nn.Dense(self.heads * self.d, use_bias=False, name=name)(x)
+            return y.reshape(b, t, self.heads, self.d)
+
+        weight = self.param("norm", nn.initializers.ones, (self.d,))
+        o = chunk_lightning(
+            heads("q"), heads("k"), heads("v"), heads("gate"), weight,
+            jnp.asarray([0.5, 0.01], jnp.float32), scale=self.d ** -0.5, rms_eps=1e-6,
+        )
+        return x + nn.Dense(c, use_bias=False, name="o")(o.reshape(b, t, -1))
+
+
+class _Sparse(nn.Module):
+    """``_Attention`` over the blocks ``select_blocks`` chooses, K and V at
+    half of q's heads."""
+    heads: int = 4
+    kv_heads: int = 2
+    d: int = 32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, c = x.shape
+
+        def heads(name, n):
+            y = nn.Dense(n * self.d, use_bias=False, name=name)(x)
+            return y.reshape(b, t, n, self.d).transpose(0, 2, 1, 3)
+
+        q, k, v = heads("q", self.heads), heads("k", self.kv_heads), heads("v", self.kv_heads)
+        blocks = select_blocks(q, k, block_size=16, topk=4, window=32,
+                               init_blocks=1, kernel_size=8, kernel_stride=4)
+        o = flash_attention(q, k, v, blocks=blocks, block_size=16)
+        o = o.transpose(0, 2, 1, 3).reshape(b, t, -1)
+        return x + nn.Dense(c, use_bias=False, name="o")(o)
+
+
 class _HyperConnected(nn.Module):
     """A layer's two rounds of read, sublayer, write on [n, B, T, C] streams,
     as ``models.llama._hyper_connected`` makes them."""
@@ -186,6 +231,8 @@ CASES = {
                     (1, T, 64), {"_fwd_kernel": (1, 2)}),
     "kda": ((_KDA, {}), (1, T, 64), {"_kda_fwd_kernel": (1, 2)}),
     "gdn": ((_GDN, {}), (1, T, 64), {"_gdn_fwd_kernel": (1, 2)}),
+    "lightning": ((_Lightning, {}), (1, T, 64), {"_lightning_fwd_kernel": (1, 2)}),
+    "sparse": ((_Sparse, {}), (1, T, 64), {"_sparse_fwd_kernel": (1, 2)}),
     # A layer's second write is its output, which no replay makes.
     "hyper-connections": ((_HyperConnected, {}), (4, 1, T, 128), {
         "_hc_pre_fwd_kernel": (2, 4), "_hc_post_fwd_kernel": (2, 3)}),
@@ -242,12 +289,14 @@ def test_replay_holds_no_forward_kernel(case):
 
 
 @pytest.mark.parametrize("dropped", [
-    "kda_o", "kda_states", "kda_t", "gdn_o", "gdn_states", "gdn_t"])
+    "kda_o", "kda_states", "kda_t", "gdn_o", "gdn_states", "gdn_t",
+    "lightning_o", "lightning_states"])
 def test_a_kda_layer_needs_each_of_its_three_names_kept(dropped):
     """o, the per-chunk states and the chunks' inverses leave the forward
     kernel together (KDA's, and the scalar-decay kernel's under names of its
-    own): a policy that lacks any one of them runs the forward kernel in the
-    replay to remake it, whatever else it holds."""
+    own; the fixed-decay kernel's o and states, which has no inverse): a
+    policy that lacks any one of them runs the forward kernel in the replay to
+    remake it, whatever else it holds."""
     case = dropped.partition("_")[0]
     fwd, bwd = f"_{case}_fwd_kernel", f"_{case}_bwd_kernel"
     names = [name for name in REPLAY_KEEPS if name != dropped]
@@ -255,6 +304,38 @@ def test_a_kda_layer_needs_each_of_its_three_names_kept(dropped):
     assert calls[fwd] == 2 * LAYERS and calls[bwd] == LAYERS
     kept, _ = _run(case, remat_policy(_cfg(remat_prevent_cse=True)))
     assert kept[fwd] == kept[bwd] == LAYERS
+
+
+@pytest.mark.parametrize("dropped", ["sparse_o", "sparse_lse"])
+def test_a_sparse_layer_needs_both_of_its_kernels_names_kept(dropped):
+    """As the causal kernels' o and lse: without either the replay runs
+    ``_sparse_fwd_kernel`` again. (The chosen blocks are the mixer's to name,
+    ``sparse_blocks`` in models/minicpm_sala.py: this skeleton chooses again.)"""
+    names = [name for name in REPLAY_KEEPS if name != dropped]
+    calls, _ = _run("sparse", jax.checkpoint_policies.save_only_these_names(*names))
+    assert calls["_sparse_fwd_kernel"] == 2 * LAYERS
+    kept, _ = _run("sparse", remat_policy(_cfg(remat_prevent_cse=True)))
+    assert kept["_sparse_fwd_kernel"] == LAYERS
+    assert kept["_bwd_dkv_sparse_kernel"] == kept["_bwd_dq_sparse_kernel"] == LAYERS
+
+
+def test_the_kernels_policy_keeps_the_kernels_names_and_not_the_two_products():
+    """``remat_policy`` "kernels" behind the barrier: what "nothing" keeps but
+    for a SwiGLU's two products, which a layer of 16,384 tokens by 16,384
+    channels cannot hold: the replay runs gate_proj and up_proj again and
+    still no o_proj, no forward kernel; loss and gradients to the bit."""
+    layer, shape, _, _ = MATMUL_CASES["pre-norm"]
+    narrow = remat_policy(_cfg(remat_policy="kernels", remat_prevent_cse=True))
+    assert narrow is not remat_policy(_cfg(remat_prevent_cse=True))
+    assert remat_policy(_cfg(remat_policy="kernels")) is NOTHING  # no barrier
+    jaxpr, kept = _gradient(layer, shape, narrow)
+    dots = _forward_matmuls(jaxpr)
+    assert dots["mlp/gate_proj"] == dots["mlp/up_proj"] == 2 * LAYERS
+    assert dots["attn/o_proj"] == dots["mlp/down_proj"] == LAYERS
+    assert _kernel_calls(jaxpr)["_fwd_kernel"] == LAYERS
+    assert _handed_to_the_replays(jaxpr) == [sorted(_KERNEL | {"mixer_out"})] * LAYERS
+    _, bare = _gradient(layer, shape, NOTHING)
+    _same_to_the_bit(kept, bare)
 
 
 def _decoder(ffn=tracing.MLP, config=LlamaConfig, **fields):
