@@ -73,10 +73,8 @@ class LlamaConfig:
     # up_proj, o_proj or down_proj. A kept float costs its bytes from the
     # forward pass to its layer's backward and one reduce_precision pass
     # over them.
-    # "kernels": "nothing" without the SwiGLU's two products, for a layer
-    # whose intermediate width times its tokens does not fit: at 16,384
-    # tokens of 16,384 channels the two are 1 GiB a layer. The replay then
-    # runs gate_proj and up_proj again and nothing else of REPLAY_KEEPS.
+    # "kernels": a word one configuration's file still carries; the same as
+    # "nothing" since PR 55.
     # "dots": save matmul outputs, recompute only elementwise — moves
     # memory, not time, where nothing is replayed.
     remat_policy: str = "nothing"
@@ -209,16 +207,11 @@ REPLAY_KEEPS = ("flash_o", "flash_lse", "kda_o", "kda_states", "kda_t",
                 "gdn_t", "mlp_gate", "mlp_up", "mixer_out", "ffn_out",
                 "lightning_o", "lightning_states", "sparse_o", "sparse_lse",
                 "sparse_blocks")
-# What does not fit in a layer of 16,384 tokens by 16,384 channels
-# (``remat_policy`` "kernels").
-_WIDE = ("mlp_gate", "mlp_up")
 # One object for every caller: JAX caches a jitted function's partial
 # evaluation by the policy's identity, and a second ``_through`` (xing4.py's
 # module) with a policy of its own would lower every jitted kernel entry's
 # body again.
 _KEEP = jax.checkpoint_policies.save_only_these_names(*REPLAY_KEEPS)
-_KEEP_NARROW = jax.checkpoint_policies.save_only_these_names(
-    *(name for name in REPLAY_KEEPS if name not in _WIDE))
 
 
 def remat_policy(cfg: LlamaConfig):
@@ -231,7 +224,7 @@ def remat_policy(cfg: LlamaConfig):
         # every layer's o, 4.90 -> 5.15 GiB in mistral-7b-l4.short2k and
         # 5.50 -> 5.59 in the OLMoE cell (AOT compiles for v5e, PR 47).
         return jax.checkpoint_policies.nothing_saveable
-    return _KEEP_NARROW if cfg.remat_policy == "kernels" else _KEEP
+    return _KEEP
 
 
 def weight_init(cfg: LlamaConfig, default=nn.initializers.lecun_normal()):

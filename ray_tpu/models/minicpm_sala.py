@@ -78,8 +78,7 @@ class MiniCPMSalaConfig(LlamaConfig):
     attn_use_rope: bool = False
     sparse: SparseSelection = SparseSelection()
     rms_eps: float = 1e-6
-    # The SwiGLU's two products are 1 GiB a layer at 16k tokens: not kept.
-    remat_policy: str = "kernels"
+    remat_policy: str = "nothing"
     remat_prevent_cse: bool = True
 
     @property

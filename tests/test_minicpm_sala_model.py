@@ -28,6 +28,7 @@ from ray_tpu.models.minicpm_sala import (
 )
 from ray_tpu.ops.attention import select_blocks
 from ray_tpu.util import tracing
+from remat_jaxpr import forward_matmuls
 
 SEQ = 256
 CELL = "minicpm-sala-9b-l4.long16k"
@@ -120,6 +121,17 @@ def test_the_builder_reads_the_sources_own_keys():
     assert cfg.lightning_slopes(0)[0] == pytest.approx(2 ** -0.25 * (1 + 1e-5))
     assert cfg.lightning_slopes(3)[31] == pytest.approx(2 ** -8 * (1 - 3 / 31 + 1e-5))
     assert cfg.lightning_slopes(31)[0] == pytest.approx(2 ** -0.25 * 1e-5)
+
+
+def test_under_its_own_remat_fields_no_replay_runs_a_swiglu_matmul(sala):
+    """The file's "kernels" is "nothing" since PR 55: ahead of the backward
+    pass the gradient holds each of the SwiGLU's three matmuls once a layer."""
+    _, model, params, ids = sala
+    grad = jax.grad(lambda p: chunked_causal_lm_loss(
+        model, p, ids[None], np.roll(ids, -1)[None], chunk_size=64))
+    dots = forward_matmuls(jax.make_jaxpr(grad)(params).jaxpr)
+    layers = len(model.cfg.layers)
+    assert [dots[f"mlp/{name}"] for name in ("gate_proj", "up_proj", "down_proj")] == [layers] * 3
 
 
 def test_the_files_numbers_are_the_catalogs_but_for_the_two_it_reduces():

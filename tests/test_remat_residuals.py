@@ -16,7 +16,6 @@ the names a backward reads and no other, and loss and gradients are the same
 to the bit.
 """
 import ast
-import collections
 import pathlib
 
 import flax.linen as nn
@@ -28,7 +27,7 @@ import jax.extend
 import jax.numpy as jnp
 
 import ray_tpu
-from ray_tpu.models import hyper_connections
+from ray_tpu.models import hyper_connections, llama
 from ray_tpu.models.llama import (
     MLP, REPLAY_KEEPS, Attention, DecoderLayer, LlamaConfig, remat_policy,
 )
@@ -36,6 +35,7 @@ from ray_tpu.models.mixtral import MixtralConfig, MoELayer
 from ray_tpu.ops.attention import flash_attention, select_blocks
 from ray_tpu.ops.kda import chunk_gdn, chunk_kda, chunk_lightning
 from ray_tpu.util import tracing
+from remat_jaxpr import forward_matmuls, kernel_calls
 
 LAYERS = 2
 T = 256
@@ -239,21 +239,6 @@ CASES = {
 }
 
 
-def _equations(jaxpr):
-    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _equations(sub)
-
-
-def _kernel_calls(jaxpr):
-    """Every ``pallas_call`` under ``jaxpr``, counted by the kernel's name."""
-    return collections.Counter(
-        eqn.params["jaxpr"].debug_info.func_name for eqn in _equations(jaxpr)
-        if eqn.primitive.name == "pallas_call")
-
-
 def _gradient(layer, shape, policy, barrier=True):
     """The jaxpr of loss and gradients through ``_Stack``, and their values."""
     model = _Stack(layer, policy, barrier)
@@ -266,7 +251,7 @@ def _gradient(layer, shape, policy, barrier=True):
 def _run(case, policy):
     layer, shape, _ = CASES[case]
     jaxpr, values = _gradient(layer, shape, policy)
-    return _kernel_calls(jaxpr), values
+    return kernel_calls(jaxpr), values
 
 
 def _same_to_the_bit(kept, bare):
@@ -319,21 +304,25 @@ def test_a_sparse_layer_needs_both_of_its_kernels_names_kept(dropped):
     assert kept["_bwd_dkv_sparse_kernel"] == kept["_bwd_dq_sparse_kernel"] == LAYERS
 
 
-def test_the_kernels_policy_keeps_the_kernels_names_and_not_the_two_products():
-    """``remat_policy`` "kernels" behind the barrier: what "nothing" keeps but
-    for a SwiGLU's two products, which a layer of 16,384 tokens by 16,384
-    channels cannot hold: the replay runs gate_proj and up_proj again and
-    still no o_proj, no forward kernel; loss and gradients to the bit."""
+@pytest.mark.parametrize("word", ["nothing", "kernels"])
+def test_behind_the_barrier_either_word_is_the_one_policy(word):
+    """The words a configuration may carry where a replay is executed give the
+    one policy object (JAX caches a jitted kernel entry's partial evaluation
+    by its identity): no replay runs a projection or the forward kernel, each
+    is handed the kernel's names, the sublayer's output and the SwiGLU's two
+    products; loss and gradients to the bit. Without the barrier either word
+    saves nothing."""
     layer, shape, _, _ = MATMUL_CASES["pre-norm"]
-    narrow = remat_policy(_cfg(remat_policy="kernels", remat_prevent_cse=True))
-    assert narrow is not remat_policy(_cfg(remat_prevent_cse=True))
-    assert remat_policy(_cfg(remat_policy="kernels")) is NOTHING  # no barrier
-    jaxpr, kept = _gradient(layer, shape, narrow)
-    dots = _forward_matmuls(jaxpr)
-    assert dots["mlp/gate_proj"] == dots["mlp/up_proj"] == 2 * LAYERS
+    policy = remat_policy(_cfg(remat_policy=word, remat_prevent_cse=True))
+    assert policy is llama._KEEP
+    assert remat_policy(_cfg(remat_policy=word)) is NOTHING
+    jaxpr, kept = _gradient(layer, shape, policy)
+    dots = forward_matmuls(jaxpr)
+    assert dots["mlp/gate_proj"] == dots["mlp/up_proj"] == LAYERS
     assert dots["attn/o_proj"] == dots["mlp/down_proj"] == LAYERS
-    assert _kernel_calls(jaxpr)["_fwd_kernel"] == LAYERS
-    assert _handed_to_the_replays(jaxpr) == [sorted(_KERNEL | {"mixer_out"})] * LAYERS
+    assert kernel_calls(jaxpr)["_fwd_kernel"] == LAYERS
+    assert _handed_to_the_replays(jaxpr) == [
+        sorted(_KERNEL | _PRODUCTS | {"mixer_out"})] * LAYERS
     _, bare = _gradient(layer, shape, NOTHING)
     _same_to_the_bit(kept, bare)
 
@@ -385,20 +374,6 @@ MATMUL_CASES = {
 }
 
 
-def _forward_matmuls(jaxpr):
-    """Every ``dot_general`` under ``jaxpr`` that a forward pass or a replay
-    runs (and no backward), counted by the two flax names its name stack ends
-    in."""
-    def forward(stack):
-        return ("jvp(" in stack and "transpose" not in stack
-                or "rematted_computation" in stack)
-
-    stacks = (str(eqn.source_info.name_stack) for eqn in _equations(jaxpr)
-              if eqn.primitive.name == "dot_general")
-    return collections.Counter(
-        "/".join(stack.split("/")[-2:]) for stack in stacks if forward(stack))
-
-
 def _handed_to_the_replays(jaxpr):
     """The names of the kept values each layer's replay takes: the operands of
     the gradient's ``remat2`` equations that a ``checkpoint_name`` made. JAX
@@ -429,8 +404,8 @@ def test_replay_holds_no_matmul_for_an_elementwise_consumer(case):
     layer, shape, matmuls, names = MATMUL_CASES[case]
     kept_jaxpr, kept = _gradient(layer, shape, remat_policy(_cfg(remat_prevent_cse=True)))
     bare_jaxpr, bare = _gradient(layer, shape, NOTHING)
-    kept_dots = _forward_matmuls(kept_jaxpr)
-    bare_dots = _forward_matmuls(bare_jaxpr)
+    kept_dots = forward_matmuls(kept_jaxpr)
+    bare_dots = forward_matmuls(bare_jaxpr)
     for name, (once, replayed) in matmuls.items():
         assert kept_dots[name] == LAYERS * once, kept_dots
         assert bare_dots[name] == LAYERS * replayed, bare_dots
@@ -454,7 +429,7 @@ def test_without_the_barrier_the_names_are_inert(case):
     policy = remat_policy(layer[1]["cfg"])
     assert policy is NOTHING
     jaxpr, _ = _gradient(layer, shape, policy, barrier=False)
-    dots = _forward_matmuls(jaxpr)
+    dots = forward_matmuls(jaxpr)
     for name, (_, replayed) in matmuls.items():
         assert dots[name] == LAYERS * replayed, dots
     assert _handed_to_the_replays(jaxpr) == [[]] * LAYERS
