@@ -9,20 +9,21 @@ score matrix never materializes in HBM.
 
 Forward and backward are pallas kernels on a TPU backend (MXU matmuls
 in f32 accumulation; the backward recomputes probabilities from the saved
-log-sum-exp; a grid step does what its tile's position needs,
-``_tile_class``). On any other backend
+log-sum-exp). Which keys a row sees is a mask (``_Mask``: causal, a band, a
+bitmap of chosen blocks), and a mask is data: each pass's tile update is
+written once, a grid step runs it as its tile's position under the mask
+asks (``_by_position``), and one forward and one backward call take any
+mask. On any other backend
 `flash_attention` is `attention_reference`; which one ran is visible in the
 lowered program (`tpu_custom_call`), and chip_smoke.py asserts it. Where the
 ambient mesh splits the sequence it is the ring of `ring_attention.py` over
-the same blocks (`_block_fwd`, `_block_bwd`). Under a sliding window the
-kernels are three of their own (`_fwd_window_kernel` and its two backward
-kernels), whose grids walk only the blocks the band crosses.
+the same blocks (`_block_fwd`, `_block_bwd`).
 """
 from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -94,14 +95,85 @@ def _visible(tq: int, tk: int, window: Optional[int]) -> jax.Array:
     return seen if window is None else seen & (qpos - kpos < window)
 
 
-# ----------------------------------------------------------- a tile's position
-# What a grid step of the three causal kernels has to do follows from where
-# its [block_q, block_k] tile lies, which the program ids and the static
-# lengths say: an interior tile (every key visible to every row, none of them
-# padding) needs no mask, an edge tile (crossed by the diagonal, or holding
-# padded keys) the masked body, a dead tile (wholly above the diagonal)
-# neither arithmetic nor a copy. A non-causal call is a causal one whose
-# diagonal lies beyond the last key.
+# ---------------------------------------------------------------------- a mask
+# Which keys a row sees. The kernels below know nothing else of it than what
+# a ``_Mask`` says, at trace time (nothing of it reaches the device but the
+# arithmetic its functions trace):
+#
+# - the two walks a grid's last axis makes: over a row block's keys (the
+#   forward and dQ) and over a key block's rows (dK/dV). For each, the grid's
+#   last extent (``key_steps``; ``row_steps``, once for each of the ``group``
+#   q heads that a K/V head serves, 1 where K and V come repeated), the
+#   block the walked operands' index maps fetch at step j (``key_index``,
+#   ``row_index``: a step that is off the walk is held at a block that is
+#   resident, so the pipeline issues no copy), and the tile step j stands on
+#   with its class (``keys``, ``rows``: the block, ``live``, and ``interior``
+#   or None): an interior tile (every key visible to every row, none of them
+#   padding) needs no mask, an edge tile the masked body, a dead tile neither
+#   arithmetic nor a copy;
+# - ``sees``: an edge tile's [block_q, block_k] boolean. A mask may read an
+#   operand of its own for it (the bitmap's words, [K/V heads, rows, lanes]:
+#   the calls hand a step its row block's);
+# - its three kernels' names, what XLA is told its calls cost (``flops``: how
+#   many tiles a walk visits is the mask's) and the VMEM they may take.
+#
+# Causal (``_causal_mask``): the tile's position against the diagonal, which
+# the program ids and the static lengths say. Both walks cover the whole
+# grid, dead steps waiting at the first or last live block, and it alone
+# declares interior tiles (PERF.md §6, PR 46). A non-causal call is a causal
+# one whose diagonal lies beyond the last key. K and V come repeated to q's
+# heads (ROADMAP A5b).
+#
+# A band (``_window_mask``): row i sees keys 0 <= i - j < window, so a block
+# of rows meets only the few blocks of keys its band crosses (and a block of
+# keys the few blocks of rows). The walks visit those and no others, from the
+# band's first block. Names of their own: a trace prices a call by its
+# kernel's name, and a windowed call costs window / T of a causal one. K and
+# V stay at their own heads (a q head's group is found by the index maps), and
+# dk and dv sum over the group inside the kernel.
+#
+# A bitmap (``_bitmap_mask``), block-sparse top-k attention (InfLLM-V2): row
+# i of K/V group g sees, of the keys up to its own, those in the blocks of
+# ``block_size`` keys that ``select_blocks`` chose for (i, g), a set that
+# differs from row to row and from group to group. The mask is made from a
+# bitmap and never from a [T, T] array: the chosen blocks of a key tile are
+# the bits of one int32 a row (``_pack``: [B * G, T, key tiles] words, 16 MiB
+# at 16k tokens and two groups), which a grid step takes from its row tile's
+# words by its key tile's lane and shifts by each column's block. A tile above
+# the diagonal, or one in which no row chose a block, runs nothing. What a
+# tile costs is a dense tile's matmuls: the rows of a tile choose differently,
+# and together they choose nearly every block below them (a kernel that
+# gathered each row's 64 blocks would move 2 MiB of K and V a row and group,
+# 64 GiB a forward at 16k, and put 16 rows on the MXU: PERF.md §6, PR 54). K
+# and V stay at their own heads, as under a band.
+
+
+class _Mask(NamedTuple):
+    block_q: int
+    block_k: int
+    tq_p: int  # the lengths padded to whole blocks
+    tk_p: int
+    group: int
+    key_steps: int
+    row_steps: int
+    keys: Callable  # (iq, j) -> (ik, live, interior)
+    rows: Callable  # (ik, j) -> (jq, live, interior)
+    key_index: Callable  # (i, j) -> key block
+    row_index: Callable  # (i, j) -> row block
+    sees: Callable  # (iq, ik[, the rows' words of tile ik]) -> bool [block_q, block_k]
+    kernels: tuple  # forward, dK/dV, dQ
+    flops: Optional[tuple] = None  # of the three calls; None: no cost is stated
+    bodies: tuple = (False, True)  # some tile of the grid is (interior, edge)
+    vmem_limit_bytes: Optional[int] = None
+
+
+def _blocks(q, k, block_q: int, block_k: int):
+    """The blocks cut to the lengths, and the lengths padded to them."""
+    tq, tk = q.shape[1], k.shape[1]
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    tq_p = (tq + block_q - 1) // block_q * block_q
+    tk_p = (tk + block_k - 1) // block_k * block_k
+    return block_q, block_k, tq_p, tk_p
 
 
 def _diagonal(causal: bool, seq_q: int, seq_k: int) -> int:
@@ -152,321 +224,54 @@ def _first_live_row(j, *, causal: bool, block_q: int, block_k: int,
     return jax.lax.div(jax.lax.max(at, jnp.int32(0)), jnp.int32(block_q))
 
 
-def _key_walk(tile: dict):
-    """The index map of K and V where the grid's last axis walks a row
-    block's keys: a dead step is held at the last live block, which is
-    resident, so the pipeline issues no copy."""
-    def index(b, i, j):
-        return b, jax.lax.min(j, _last_live_key(i, **tile)), 0
-
-    return index
-
-
-def _row_walk(tile: dict):
-    """The same for q, do, lse and delta where the last axis walks a key
-    block's rows: the dead steps come first and wait at the first live
-    block."""
-    def index(b, i, j):
-        return b, jax.lax.max(j, _first_live_row(i, **tile)), 0
-
-    return index
-
-
-def _tile_mask(iq, ik, *, causal: bool, block_q: int, block_k: int,
-               seq_q: int, seq_k: int):
-    """[block_q, block_k]: the keys of block ``ik`` that are no padding and
-    that the rows of block ``iq`` see."""
-    shape = (block_q, block_k)
-    kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    mask = kpos < seq_k  # padded keys
-    if causal:
-        # Ends aligned (kv-cache semantics, matching attention_reference):
-        # query row i attends keys up to i + (seq_k - seq_q).
-        qpos = iq * block_q + (seq_k - seq_q) + jax.lax.broadcasted_iota(
-            jnp.int32, shape, 0
-        )
-        mask = jnp.logical_and(mask, qpos >= kpos)
-    return mask
-
-
-def _by_position(body, iq, ik, tile: dict) -> None:
-    """Run ``body(masked)`` as the tile's position asks: unmasked on an
-    interior tile, masked on an edge tile, not at all on a dead one (a
-    skipped tile is exactly a p = 0 update). A body no tile of the grid
-    needs is not lowered."""
-    n_interior, n_edge, _ = causal_tiles(
-        tile["seq_q"], tile["seq_k"], tile["block_q"], tile["block_k"],
-        tile["causal"],
-    )
-    live, interior = _tile_class(iq, ik, **tile)
-    if n_interior:
-        pl.when(interior)(functools.partial(body, False))
-    if n_edge:
-        pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
-            functools.partial(body, True)
-        )
-
-
-def _causal_blocks(q, k, causal: bool, block_q: int, block_k: int):
-    """What the three causal calls share: the padded lengths and the tile's
-    statics (``_tile_class``'s keywords)."""
-    tq, tk = q.shape[1], k.shape[1]
-    block_q, block_k = min(block_q, tq), min(block_k, tk)
-    tq_p = (tq + block_q - 1) // block_q * block_q
-    tk_p = (tk + block_k - 1) // block_k * block_k
+def _causal_mask(q, k, v, causal: bool, block_q: int, block_k: int) -> _Mask:
+    """q [bh, tq, d]; k [bh, tk, d]; v [bh, tk, d_v]."""
+    bh, tq, d = q.shape
+    tk, d_v = k.shape[1], v.shape[2]
+    block_q, block_k, tq_p, tk_p = _blocks(q, k, block_q, block_k)
     tile = dict(causal=causal, block_q=block_q, block_k=block_k,
                 seq_q=tq, seq_k=tk)
-    return tq_p, tk_p, tile
+    n_interior, n_edge, _ = causal_tiles(tq, tk, block_q, block_k, causal)
+    pairs = bh * tq_p * tk_p
 
+    def sees(iq, ik):
+        """The keys of block ``ik`` that are no padding and that the rows of
+        block ``iq`` see."""
+        shape = (block_q, block_k)
+        kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = kpos < tk  # padded keys
+        if causal:
+            # Ends aligned (kv-cache semantics, matching attention_reference):
+            # query row i attends keys up to i + (tk - tq).
+            qpos = iq * block_q + (tk - tq) + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 0
+            )
+            mask = jnp.logical_and(mask, qpos >= kpos)
+        return mask
 
-# ----------------------------------------------------------------- pallas fwd
-
-
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale: float, **tile):
-    iq, ik = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    def _tile(masked: bool):
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        s = s * sm_scale
-        if masked:
-            s = jnp.where(_tile_mask(iq, ik, **tile), s, NEG_INF)
-
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        correction = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * correction + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = m_new
-
-    _by_position(_tile, iq, ik, tile)
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        l = l_scr[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l_safe)
-
-
-def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q, block_k):
-    bh, tq, d = q.shape
-    tk, d_v = k.shape[1], v.shape[2]
-    tq_p, tk_p, tile = _causal_blocks(q, k, causal, block_q, block_k)
-    block_q, block_k = tile["block_q"], tile["block_k"]
-    q, k, v = _pad_rows(q, tq_p), _pad_rows(k, tk_p), _pad_rows(v, tk_p)
-
-    o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, sm_scale=sm_scale, **tile),
-        grid=(bh, tq_p // block_q, tk_p // block_k),
-        interpret=_interpret(),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), _key_walk(tile)),
-            pl.BlockSpec((1, block_k, d_v), _key_walk(tile)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
-            # lse kept 3-D (bh, tq, 1) so the trailing dims satisfy TPU
-            # tiling (block_q % 8, last dim == full dim).
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tq_p, d_v), q.dtype),
-            jax.ShapeDtypeStruct((bh, tq_p, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d_v), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * bh * tq_p * tk_p * (d + d_v),
-            bytes_accessed=(q.size + k.size + v.size + bh * tq_p * d_v) * 2,
-            transcendentals=bh * tq_p * tk_p,
-        ),
-    )(q, k, v)
-    return o[:, :tq], lse[:, :tq, 0]
-
-
-# ----------------------------------------------------------------- pallas bwd
-# FlashAttention-2 style backward: probabilities recomputed per block
-# from the saved log-sum-exp, two kernels so each output accumulates in
-# VMEM over its contraction dimension (dk/dv over q blocks, dq over kv
-# blocks) and the [Tq, Tk] score matrix never hits HBM. p and ds are
-# computed in float32. dK/dV, which transposes both, casts them to the
-# inputs' dtype first, as the forward does its p: the MXU's one pass rounds
-# a float32 operand the same, so the result is the same and 4% sooner. dQ
-# transposes nothing and would only pay the cast (+2.6%), and an unmasked
-# body won it nothing: it keeps one masked body and float32 operands
-# (PERF.md §6, PR 46).
-
-
-def _tile_p(q, k, lse, mask, sm_scale: float):
-    """The tile's probabilities, float32 [block_q, block_k]."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    if mask is not None:
-        s = jnp.where(mask, s, NEG_INF)
-    return jnp.exp(s - lse)
-
-
-def _tile_ds(p, do, v, delta, sm_scale: float):
-    """The scores' gradient, float32 [block_q, block_k]."""
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    return _Mask(
+        block_q, block_k, tq_p, tk_p, group=1,
+        key_steps=tk_p // block_k, row_steps=tq_p // block_q,
+        keys=lambda iq, j: (j, *_tile_class(iq, j, **tile)),
+        rows=lambda ik, j: (j, *_tile_class(j, ik, **tile)),
+        # A dead step of a row block's walk is held at its last live key
+        # block; those of a key block's walk come first and wait at its first
+        # live row block.
+        key_index=lambda i, j: jax.lax.min(j, _last_live_key(i, **tile)),
+        row_index=lambda i, j: jax.lax.max(j, _first_live_row(i, **tile)),
+        sees=sees,
+        kernels=(_fwd_kernel, _bwd_dkv_kernel, _bwd_dq_kernel),
+        flops=(2 * pairs * (d + d_v), 5 * pairs * (d + d_v) // 2,
+               5 * pairs * (d + d_v) // 2),
+        bodies=(n_interior > 0, n_edge > 0),
     )
-    return p * (dp - delta) * sm_scale
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, sm_scale: float, **tile):
-    ik, jq = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(jq == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    def _tile(masked: bool):
-        q, do = q_ref[0], do_ref[0]  # [block_q, d], [block_q, d_v]
-        mask = _tile_mask(jq, ik, **tile) if masked else None
-        p = _tile_p(q, k_ref[0], lse_ref[0], mask, sm_scale)
-        # dv before ds: p and ds are 4 MB each at 1,024 x 1,024
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = _tile_ds(p, do, v_ref[0], delta_ref[0], sm_scale)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    _by_position(_tile, jq, ik, tile)
-
-    @pl.when(jq == nq - 1)
-    def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_scr, *, sm_scale: float, **tile):
-    iq, jk = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(jk == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    def _tile():
-        k = k_ref[0]
-        p = _tile_p(q_ref[0], k, lse_ref[0], _tile_mask(iq, jk, **tile), sm_scale)
-        ds = _tile_ds(p, do_ref[0].astype(jnp.float32),
-                      v_ref[0].astype(jnp.float32), delta_ref[0], sm_scale)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    pl.when(_tile_class(iq, jk, **tile)[0])(_tile)  # every live tile
-
-    @pl.when(jk == nk - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-
-
-def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale,
-                      block_q, block_k):
-    bh, tq, d = q.shape
-    tk, d_v = k.shape[1], v.shape[2]
-    tq_p, tk_p, tile = _causal_blocks(q, k, causal, block_q, block_k)
-    block_q, block_k = tile["block_q"], tile["block_k"]
-    nq, nk = tq_p // block_q, tk_p // block_k
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
-    )  # [bh, tq, 1]
-    q, do = _pad_rows(q, tq_p), _pad_rows(do, tq_p)
-    lse3, delta3 = _pad_rows(lse[..., None], tq_p), _pad_rows(delta, tq_p)
-    k, v = _pad_rows(k, tk_p), _pad_rows(v, tk_p)
-    static = dict(sm_scale=sm_scale, **tile)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    )
-    cost = pl.CostEstimate(
-        flops=5 * bh * tq_p * tk_p * (d + d_v) // 2,
-        bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
-        transcendentals=bh * tq_p * tk_p,
-    )
-    # q and k (and their gradients) have d lanes; v, do and dv have d_v.
-    rows = lambda width, index: pl.BlockSpec((1, block_q, width), index)  # noqa: E731
-    keys = lambda width, index: pl.BlockSpec((1, block_k, width), index)  # noqa: E731
-    here = lambda b, i, j: (b, i, 0)  # noqa: E731
-    row_walk, key_walk = _row_walk(tile), _key_walk(tile)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **static),
-        interpret=_interpret(),
-        grid=(bh, nk, nq),
-        in_specs=[rows(d, row_walk), keys(d, here), keys(d_v, here),
-                  rows(d_v, row_walk), rows(1, row_walk), rows(1, row_walk)],
-        out_specs=[keys(d, here), keys(d_v, here)],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk_p, d_v), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d_v), jnp.float32),
-        ],
-        compiler_params=params,
-        cost_estimate=cost,
-    )(q, k, v, do, lse3, delta3)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **static),
-        interpret=_interpret(),
-        grid=(bh, nq, nk),
-        in_specs=[rows(d, here), keys(d, key_walk), keys(d_v, key_walk),
-                  rows(d_v, here), rows(1, here), rows(1, here)],
-        out_specs=rows(d, here),
-        out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=params,
-        cost_estimate=cost,
-    )(q, k, v, do, lse3, delta3)
-    return dq[:, :tq], dk[:, :tk], dv[:, :tk]
-
-
-# ------------------------------------------------------------ pallas, a window
-# The same three kernels over a band: row i sees keys 0 <= i - j < window, so
-# a block of rows meets only the few blocks of keys its band crosses (and a
-# block of keys the few blocks of rows). The grid's last axis walks those and
-# no others; the index maps start each walk at the band's first block. Names
-# of their own: a trace prices a call by its kernel's name, and a windowed
-# call costs window / T of a causal one. K and V stay at their own heads (a
-# q head's group is found by the index maps), dk and dv sum over the group
-# inside the kernel, and the matmuls take their operands in the inputs' dtype.
+# The windowed kernels' blocks: a band of 512 fills a quarter of the two
+# 1,024-key blocks a 1,024-row block would need. (PERF.md §6, PR 45, has the
+# sweep on the chip.)
+WINDOW_BLOCK_Q = 512
+WINDOW_BLOCK_K = 512
 
 
 def _band(i, rows: int, cols: int, n_cols: int, lo_shift: int, hi_shift: int):
@@ -496,309 +301,62 @@ def _band_steps(n_rows: int, *band) -> int:
     return max(int((hi - lo).max()) + 1, 1)
 
 
-def _band_mask(row_block, key_block, *, block_q: int, block_k: int,
-               seq_k: int, seq_q: int, window: int):
-    """[block_q, block_k]: which keys of block ``key_block`` the rows of
-    block ``row_block`` see, the ends aligned as in the causal kernels."""
-    shape = (block_q, block_k)
-    kpos = key_block * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    qpos = row_block * block_q + (seq_k - seq_q) + jax.lax.broadcasted_iota(
-        jnp.int32, shape, 0
-    )
-    return (kpos < seq_k) & (qpos >= kpos) & (qpos - kpos < window)
-
-
-def _key_block_map(keys, group: int):
-    """The index map of K and V where the grid's last axis walks a row
-    block's band: q head ``b``'s K/V head, the walk's block held at the
-    band's last once past it (no new copy, and the kernel skips it)."""
-    def index(b, i, j):
-        lo, hi = _band(i, *keys)
-        return jax.lax.div(b, jnp.int32(group)), jax.lax.min(lo + j, hi), 0
-
-    return index
-
-
-def _fwd_window_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                       acc_scr, *, sm_scale: float, band, **tile):
-    # ``tile``: _band_mask's block sizes, lengths and window.
-    iq, j = pl.program_id(1), pl.program_id(2)
-    lo, hi = _band(iq, *band)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    @pl.when(lo + j <= hi)
-    def _compute():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-        s = jnp.where(_band_mask(iq, lo + j, **tile), s, NEG_INF)
-        # A row that sees nothing of this block keeps m at NEG_INF and adds
-        # p = 1 a key: the first block it does see multiplies that by
-        # exp(NEG_INF - m) = 0, and every row sees its own position.
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        correction = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * correction + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = m_new
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        l = l_scr[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l_safe)
-
-
-def _window_ds(q, k, v, do, lse, delta, mask, sm_scale):
-    """(p, ds) of one [block_q, block_k] tile, both in the inputs' dtype."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ds = p * (dp - delta) * sm_scale
-    return p.astype(do.dtype), ds.astype(q.dtype)
-
-
-def _bwd_dkv_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dk_ref, dv_ref, dk_scr, dv_scr,
-                           *, sm_scale: float, band, steps: int, **tile):
-    # The last axis walks the band's query blocks once for each q head of
-    # this K/V head's group.
-    ik, j = pl.program_id(1), pl.program_id(2)
-    lo, hi = _band(ik, *band)
-    jq = lo + jax.lax.rem(j, jnp.int32(steps))
-
-    @pl.when(j == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    @pl.when(jq <= hi)
-    def _compute():
-        q, do = q_ref[0], do_ref[0]
-        p, ds = _window_ds(
-            q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0],
-            _band_mask(jq, ik, **tile), sm_scale,
-        )
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _bwd_dq_window_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dq_ref, dq_scr, *, sm_scale: float, band, **tile):
-    iq, j = pl.program_id(1), pl.program_id(2)
-    lo, hi = _band(iq, *band)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    @pl.when(lo + j <= hi)
-    def _compute():
-        k = k_ref[0]
-        _, ds = _window_ds(
-            q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
-            _band_mask(iq, lo + j, **tile), sm_scale,
-        )
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-
-
-def _window_blocks(q, k, window: int, block_q: int, block_k: int):
-    """What the three windowed calls share: the blocks, the padded lengths
-    and the two bands, of keys a block of rows crosses and of rows a block
-    of keys is seen by."""
-    tq, tk = q.shape[1], k.shape[1]
-    block_q, block_k = min(block_q, tq), min(block_k, tk)
-    tq_p = (tq + block_q - 1) // block_q * block_q
-    tk_p = (tk + block_k - 1) // block_k * block_k
-    nq, nk, off = tq_p // block_q, tk_p // block_k, tk - tq
-    keys = (block_q, block_k, nk, off - window + 1, off + block_q - 1)
-    rows = (block_k, block_q, nq, -off, block_k + window - 2 - off)
-    return block_q, block_k, tq_p, tk_p, nq, nk, keys, rows
-
-
-def _pad_rows(x, t_p: int):
-    pad = t_p - x.shape[1]
-    return x if not pad else jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-
-
-def _flash_fwd_window_pallas(q, k, v, *, window, sm_scale, block_q, block_k):
+def _window_mask(q, k, v, window: int, block_q: int, block_k: int) -> _Mask:
     """q [b * h, tq, d]; k, v [b * hkv, tk, .]: q head n reads K/V head
     n // (h // hkv)."""
     bh, tq, d = q.shape
-    tk, group, d_v = k.shape[1], bh // k.shape[0], v.shape[2]
-    block_q, block_k, tq_p, tk_p, nq, nk, keys, _ = _window_blocks(
-        q, k, window, block_q, block_k
-    )
-    steps = _band_steps(nq, *keys)
-    q, k, v = _pad_rows(q, tq_p), _pad_rows(k, tk_p), _pad_rows(v, tk_p)
+    tk, d_v = k.shape[1], v.shape[2]
+    block_q, block_k, tq_p, tk_p = _blocks(q, k, block_q, block_k)
+    nq, nk, off = tq_p // block_q, tk_p // block_k, tk - tq
+    # The two bands (``_band``'s arguments): of keys a block of rows crosses,
+    # and of rows a block of keys is seen by.
+    keys = (block_q, block_k, nk, off - window + 1, off + block_q - 1)
+    rows = (block_k, block_q, nq, -off, block_k + window - 2 - off)
+    key_steps, row_steps = _band_steps(nq, *keys), _band_steps(nk, *rows)
+    key_pairs = bh * tq_p * key_steps * block_k
+    row_pairs = bh * tk_p * row_steps * block_q
 
-    key_block = _key_block_map(keys, group)
-    o, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_window_kernel, sm_scale=sm_scale, window=window,
-            block_q=block_q, block_k=block_k, seq_k=tk, seq_q=tq, band=keys,
-        ),
-        grid=(bh, nq, steps),
-        interpret=_interpret(),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), key_block),
-            pl.BlockSpec((1, block_k, d_v), key_block),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tq_p, d_v), q.dtype),
-            jax.ShapeDtypeStruct((bh, tq_p, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d_v), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * bh * tq_p * steps * block_k * (d + d_v),
-            bytes_accessed=(q.size + k.size + v.size + bh * tq_p * d_v) * 2,
-            transcendentals=bh * tq_p * steps * block_k,
-        ),
-    )(q, k, v)
-    return o[:, :tq], lse[:, :tq, 0]
+    def walk_keys(iq, j):
+        lo, hi = _band(iq, *keys)
+        ik = lo + j
+        return ik, ik <= hi, None
 
+    def walk_rows(ik, j):
+        lo, hi = _band(ik, *rows)
+        jq = lo + jax.lax.rem(j, jnp.int32(row_steps))
+        return jq, jq <= hi, None
 
-def _flash_bwd_window_pallas(q, k, v, o, lse, do, *, window, sm_scale,
-                             block_q, block_k):
-    bh, tq, d = q.shape
-    bkv, tk, d_v = k.shape[0], k.shape[1], v.shape[2]
-    group = bh // bkv
-    block_q, block_k, tq_p, tk_p, nq, nk, keys, rows = _window_blocks(
-        q, k, window, block_q, block_k
-    )
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
-    )  # [bh, tq, 1]
-    q, do = _pad_rows(q, tq_p), _pad_rows(do, tq_p)
-    lse3, delta3 = _pad_rows(lse[..., None], tq_p), _pad_rows(delta, tq_p)
-    k, v = _pad_rows(k, tk_p), _pad_rows(v, tk_p)
-    static = dict(sm_scale=sm_scale, window=window, block_q=block_q,
-                  block_k=block_k, seq_k=tk, seq_q=tq)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    )
+    def key_index(i, j):  # held at the band's last block once past it
+        lo, hi = _band(i, *keys)
+        return jax.lax.min(lo + j, hi)
 
-    steps = _band_steps(nk, *rows)
-
-    def row_block(b, i, j):
+    def row_index(i, j):
         lo, hi = _band(i, *rows)
-        walk = jax.lax.rem(j, jnp.int32(steps))
-        at = jax.lax.min(lo + walk, jax.lax.max(hi, jnp.int32(0)))
-        return b * group + jax.lax.div(j, jnp.int32(steps)), at, 0
+        walk = jax.lax.rem(j, jnp.int32(row_steps))
+        return jax.lax.min(lo + walk, jax.lax.max(hi, jnp.int32(0)))
 
-    tile = lambda width, index: pl.BlockSpec((1, block_q, width), index)  # noqa: E731
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
-    v_spec = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_window_kernel, band=rows, steps=steps, **static
-        ),
-        interpret=_interpret(),
-        grid=(bkv, nk, group * steps),
-        in_specs=[tile(d, row_block), k_spec, v_spec, tile(d_v, row_block),
-                  tile(1, row_block), tile(1, row_block)],
-        out_specs=[k_spec, v_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((bkv, tk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((bkv, tk_p, d_v), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d_v), jnp.float32),
-        ],
-        compiler_params=params,
-        cost_estimate=pl.CostEstimate(
-            flops=4 * bh * tk_p * steps * block_q * (d + d_v),
-            bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
-            transcendentals=bh * tk_p * steps * block_q,
-        ),
-    )(q, k, v, do, lse3, delta3)
+    def sees(iq, ik):
+        """Which keys of block ``ik`` the rows of block ``iq`` see, the ends
+        aligned as under the causal mask."""
+        shape = (block_q, block_k)
+        kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        qpos = iq * block_q + (tk - tq) + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0
+        )
+        return (kpos < tk) & (qpos >= kpos) & (qpos - kpos < window)
 
-    steps = _band_steps(nq, *keys)
-
-    key_block = _key_block_map(keys, group)
-    here = lambda b, i, j: (b, i, 0)  # noqa: E731
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_window_kernel, band=keys, **static),
-        interpret=_interpret(),
-        grid=(bh, nq, steps),
-        in_specs=[
-            tile(d, here), pl.BlockSpec((1, block_k, d), key_block),
-            pl.BlockSpec((1, block_k, d_v), key_block), tile(d_v, here),
-            tile(1, here), tile(1, here),
-        ],
-        out_specs=tile(d, here),
-        out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=params,
-        cost_estimate=pl.CostEstimate(
-            flops=2 * bh * tq_p * steps * block_k * (2 * d + d_v),
-            bytes_accessed=(q.size + k.size + v.size + do.size) * 2,
-            transcendentals=bh * tq_p * steps * block_k,
-        ),
-    )(q, k, v, do, lse3, delta3)
-    return dq[:, :tq], dk[:, :tk], dv[:, :tk]
+    return _Mask(
+        block_q, block_k, tq_p, tk_p, group=bh // k.shape[0],
+        key_steps=key_steps, row_steps=row_steps,
+        keys=walk_keys, rows=walk_rows, key_index=key_index,
+        row_index=row_index, sees=sees,
+        kernels=(_fwd_window_kernel, _bwd_dkv_window_kernel,
+                 _bwd_dq_window_kernel),
+        flops=(2 * key_pairs * (d + d_v), 4 * row_pairs * (d + d_v),
+               2 * key_pairs * (2 * d + d_v)),
+    )
 
 
-# ------------------------------------------------- pallas, a list of blocks a row
-# Block-sparse top-k attention (InfLLM-V2): row i of K/V group g sees, of the
-# keys up to its own, those in the blocks of ``block_size`` keys that
-# ``select_blocks`` chose for (i, g), a set that differs from row to row and
-# from group to group. The three kernels are the causal ones' tiles with a
-# mask made from a bitmap and never from a [T, T] array: the chosen blocks of
-# a key tile are the bits of one int32 a row (``_pack``: [B * G, T, key tiles]
-# words, 16 MiB at 16k tokens and two groups), which a grid step takes from its
-# row tile's words by its key tile's lane and shifts by each column's block. A
-# tile above the diagonal, or one in which no row chose a block, runs nothing.
-# What a tile costs is a dense tile's matmuls: the rows of a tile choose
-# differently, and together they choose nearly every block below them (a
-# kernel that gathered each row's 64 blocks would move 2 MiB of K and V a row
-# and group, 64 GiB a forward at 16k, and put 16 rows on the MXU: PERF.md §6,
-# PR 54). K and V stay at their own heads, as in the windowed kernels; dk and
-# dv sum over a group's heads inside the kernel.
 # 1,024 x 1,024, the causal kernels' tile: PERF.md §6, PR 54, has the sweep on
 # the chip (forward and backward 86 ms at 16k tokens where 512 x 512 took 116).
 SPARSE_BLOCK_Q = 1024
@@ -900,61 +458,117 @@ def _tile_words(words, ik):
     return jnp.sum(jnp.where(lane == ik, words, 0), axis=1, keepdims=True)
 
 
-def _sparse_mask(mine, iq, ik, *, block_q: int, block_k: int, block_size: int):
-    """[block_q, block_k]: which keys of tile ``ik`` the rows of tile ``iq``
-    see, from the rows' words of that tile. (lax's primitives, as in
-    ``_band``.)"""
-    shape = (block_q, block_k)
-    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    bit = jax.lax.shift_right_logical(
-        jnp.broadcast_to(mine, shape), jax.lax.div(col, jnp.int32(block_size)))
-    chosen = jax.lax.bitwise_and(bit, jnp.int32(1)) == 1
-    qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    return jnp.logical_and(chosen, qpos >= ik * block_k + col)
+def _bitmap_mask(q, k, block_size: int) -> _Mask:
+    """q [b * h, t, d]; k [b * g, t, d]; t padded to the tiles
+    (``_sparse_blocks``). The rows' words, [b * g, t, lanes] (``_pack``), are
+    the calls' operand."""
+    t_p = q.shape[1]
+    block_q, block_k, _ = _sparse_blocks(t_p, block_size)
+    nq = t_p // block_q
+
+    def live(iq, ik):
+        """The tile holds a key at or before one of its rows."""
+        return ik * block_k < (iq + 1) * block_q
+
+    def walk_rows(ik, j):
+        jq = jax.lax.rem(j, jnp.int32(nq))
+        return jq, live(jq, ik), None
+
+    def key_index(i, j):  # held at the last key tile the row tile sees
+        return jax.lax.min(
+            j, jax.lax.div((i + 1) * block_q - 1, jnp.int32(block_k)))
+
+    def row_index(i, j):  # a dead step waits at the first live row tile
+        return jax.lax.max(jax.lax.rem(j, jnp.int32(nq)),
+                           jax.lax.div(i * block_k, jnp.int32(block_q)))
+
+    def sees(iq, ik, mine):
+        """Which keys of tile ``ik`` the rows of tile ``iq`` see, from the
+        rows' words of that tile. (lax's primitives, as in ``_band``.)"""
+        shape = (block_q, block_k)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        bit = jax.lax.shift_right_logical(
+            jnp.broadcast_to(mine, shape), jax.lax.div(col, jnp.int32(block_size)))
+        chosen = jax.lax.bitwise_and(bit, jnp.int32(1)) == 1
+        qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        return jnp.logical_and(chosen, qpos >= ik * block_k + col)
+
+    return _Mask(
+        block_q, block_k, t_p, t_p, group=q.shape[0] // k.shape[0],
+        key_steps=t_p // block_k, row_steps=nq,
+        keys=lambda iq, j: (j, live(iq, j), None), rows=walk_rows,
+        key_index=key_index, row_index=row_index, sees=sees,
+        kernels=(_sparse_fwd_kernel, _bwd_dkv_sparse_kernel,
+                 _bwd_dq_sparse_kernel),
+        # As PR 54 set it. (They compile for v5e without it too.)
+        vmem_limit_bytes=64 * 2**20,
+    )
 
 
-def _sparse_live(iq, ik, block_q: int, block_k: int):
-    """The tile holds a key at or before one of its rows."""
-    return ik * block_k < (iq + 1) * block_q
+# ------------------------------------------------------- one tile update a pass
+# Each pass (the forward, dK/dV, dQ) writes its tile update, its start and
+# its finish once; a kernel is a walk that runs them as the mask says.
 
 
-def _sparse_fwd_kernel(q_ref, k_ref, v_ref, w_ref, o_ref, lse_ref, m_scr, l_scr,
-                       acc_scr, *, sm_scale: float, **tile):
-    iq, ik = pl.program_id(1), pl.program_id(2)
+def _by_position(mask: _Mask, body, iq, ik, live, interior, words_ref) -> None:
+    """Run ``body(sees)`` as the tile's position asks: with ``sees`` None
+    (unmasked) on an interior tile, with the function that makes the tile's
+    boolean on an edge tile, not at all on a dead one (a skipped tile is
+    exactly a p = 0 update). ``interior`` None: every live tile is an edge
+    one. A body no tile of the grid needs is not lowered."""
+    edge = live
+    if interior is not None:
+        some_interior, some_edge = mask.bodies
+        if some_interior:
+            pl.when(interior)(functools.partial(body, None))
+        if not some_edge:
+            return
+        edge = jnp.logical_and(live, jnp.logical_not(interior))
 
-    @pl.when(ik == 0)
+    @pl.when(edge)
+    def _edge():
+        if words_ref is None:
+            return body(lambda: mask.sees(iq, ik))
+        # A mask with words of its own: a tile in which no row chose a block
+        # runs nothing either.
+        mine = _tile_words(words_ref[0], ik)
+        pl.when(jnp.max(mine) != 0)(
+            lambda: body(lambda: mask.sees(iq, ik, mine)))
+
+
+def _softmax_start(first, m_scr, l_scr, acc_scr) -> None:
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(_sparse_live(iq, ik, tile["block_q"], tile["block_k"]))
-    def _compute():
-        mine = _tile_words(w_ref[0], ik)
 
-        @pl.when(jnp.max(mine) != 0)
-        def _chosen():
-            mask = _sparse_mask(mine, iq, ik, **tile)
-            s = jax.lax.dot_general(
-                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * sm_scale
-            s = jnp.where(mask, s, NEG_INF)
-            # As in the windowed kernel: a row that sees nothing of this tile
-            # adds p = 1 a key at m = NEG_INF, which the first tile it does see
-            # multiplies by 0; every row sees its own position.
-            m_prev = m_scr[:]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            correction = jnp.exp(m_prev - m_new)
-            l_scr[:] = l_scr[:] * correction + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_scr[:] = m_new
+def _softmax_update(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, sm_scale, sees):
+    s = jax.lax.dot_general(
+        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    s = s * sm_scale
+    if sees is not None:
+        s = jnp.where(sees(), s, NEG_INF)
+    # A row that sees nothing of this tile keeps m at NEG_INF and adds p = 1
+    # a key: the first tile it does see multiplies that by
+    # exp(NEG_INF - m) = 0, and every row sees its own position.
+    m_prev = m_scr[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    correction = jnp.exp(m_prev - m_new)
+    l_scr[:] = l_scr[:] * correction + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[:] = m_new
 
-    @pl.when(ik == pl.num_programs(2) - 1)
+
+def _softmax_finish(last, o_ref, lse_ref, m_scr, l_scr, acc_scr) -> None:
+    @pl.when(last)
     def _finish():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -962,194 +576,353 @@ def _sparse_fwd_kernel(q_ref, k_ref, v_ref, w_ref, o_ref, lse_ref, m_scr, l_scr,
         lse_ref[0] = m_scr[:] + jnp.log(l_safe)
 
 
-def _bwd_dkv_sparse_kernel(q_ref, k_ref, v_ref, w_ref, do_ref, lse_ref,
-                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                           *, sm_scale: float, steps: int, **tile):
-    # The last axis walks the row tiles once for each q head of this K/V
-    # head's group.
-    ik, j = pl.program_id(1), pl.program_id(2)
-    jq = jax.lax.rem(j, jnp.int32(steps))
+# FlashAttention-2 style backward: probabilities recomputed per block
+# from the saved log-sum-exp, two kernels so each output accumulates in
+# VMEM over its contraction dimension (dk/dv over q blocks, dq over kv
+# blocks) and the [Tq, Tk] score matrix never hits HBM. p and ds are
+# computed in float32.
 
-    @pl.when(j == 0)
+
+def _tile_p(q, k, lse, mask, sm_scale: float):
+    """The tile's probabilities, float32 [block_q, block_k]."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * sm_scale
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    return jnp.exp(s - lse)
+
+
+def _tile_ds(p, do, v, delta, sm_scale: float):
+    """The scores' gradient, float32 [block_q, block_k]."""
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return p * (dp - delta) * sm_scale
+
+
+def _zero(first, *scratch) -> None:
+    """A backward pass's start."""
+    @pl.when(first)
     def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        for scr in scratch:
+            scr[:] = jnp.zeros_like(scr)
 
-    @pl.when(_sparse_live(jq, ik, tile["block_q"], tile["block_k"]))
-    def _compute():
-        mine = _tile_words(w_ref[0], ik)
 
-        @pl.when(jnp.max(mine) != 0)
-        def _chosen():
-            q, do = q_ref[0], do_ref[0]
-            p, ds = _window_ds(q, k_ref[0], v_ref[0], do, lse_ref[0], delta_ref[0],
-                               _sparse_mask(mine, jq, ik, **tile), sm_scale)
-            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-                p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-
-    @pl.when(j == pl.num_programs(2) - 1)
+def _write(last, *outs) -> None:
+    """A backward pass's finish: each (ref, its float32 sum)."""
+    @pl.when(last)
     def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        for ref, scr in outs:
+            ref[0] = scr[:].astype(ref.dtype)
 
 
-def _bwd_dq_sparse_kernel(q_ref, k_ref, v_ref, w_ref, do_ref, lse_ref,
-                          delta_ref, dq_ref, dq_scr, *, sm_scale: float, **tile):
-    iq, ik = pl.program_id(1), pl.program_id(2)
+def _dkv_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_scr, dv_scr,
+                sm_scale, sees):
+    """dK/dV transposes p and ds, and casts them to the inputs' dtype first,
+    as the forward does its p: the MXU's one pass rounds a float32 operand
+    the same, so the result is the same and 4% sooner (PERF.md §6, PR 46)."""
+    q, do = q_ref[0], do_ref[0]  # [block_q, d], [block_q, d_v]
+    mask = None if sees is None else sees()
+    p = _tile_p(q, k_ref[0], lse_ref[0], mask, sm_scale)
 
-    @pl.when(ik == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+    def add_dv():
+        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    @pl.when(_sparse_live(iq, ik, tile["block_q"], tile["block_k"]))
-    def _compute():
-        mine = _tile_words(w_ref[0], ik)
-
-        @pl.when(jnp.max(mine) != 0)
-        def _chosen():
-            k = k_ref[0]
-            _, ds = _window_ds(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
-                               _sparse_mask(mine, iq, ik, **tile), sm_scale)
-            dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-
-    @pl.when(ik == pl.num_programs(2) - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-
-
-def _sparse_specs(q, k, v, words, block_size: int):
-    """What the three sparse calls share: the tile's statics, the grid's
-    extents and the BlockSpecs of a walk over a row tile's keys."""
-    bh, t_p, d = q.shape
-    group, d_v = bh // k.shape[0], v.shape[2]
-    block_q, block_k, _ = _sparse_blocks(t_p, block_size)
-    nq, nk = t_p // block_q, t_p // block_k
-    tile = dict(block_q=block_q, block_k=block_k, block_size=block_size)
-
-    def last_key(i):  # the last key tile a row tile sees
-        return jax.lax.div((i + 1) * block_q - 1, jnp.int32(block_k))
-
-    def key_walk(b, i, j):
-        return jax.lax.div(b, jnp.int32(group)), jax.lax.min(j, last_key(i)), 0
-
-    here = lambda b, i, j: (b, i, 0)  # noqa: E731
-    rows = lambda width, index: pl.BlockSpec((1, block_q, width), index)  # noqa: E731
-    keys = lambda width, index: pl.BlockSpec((1, block_k, width), index)  # noqa: E731
-    lanes = words.shape[2]
-    return dict(
-        tile=tile, group=group, nq=nq, nk=nk, rows=rows, keys=keys, here=here,
-        key_walk=key_walk, lanes=lanes, d=d, d_v=d_v,
-        words_here=pl.BlockSpec(
-            (1, block_q, lanes),
-            lambda b, i, j: (jax.lax.div(b, jnp.int32(group)), i, 0)),
-        params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 2**20),
+    # dv before ds where p and ds are 4 MB each (1,024 x 1,024: PR 46). In a
+    # smaller tile both fit beside each other, and dv's matmul between them
+    # cost a band's 512 x 512 dK/dV 12.7% (PERF.md §6, PR 56).
+    dv_first = p.size >= 1024 * 1024
+    if dv_first:
+        add_dv()
+    ds = _tile_ds(p, do, v_ref[0], delta_ref[0], sm_scale)
+    if not dv_first:
+        add_dv()
+    dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
     )
 
 
-def _sparse_fwd_pallas(q, k, v, words, *, sm_scale, block_size):
-    """q [b * h, t, d]; k, v [b * g, t, .]; words [b * g, t, lanes]; t
-    padded to the tiles. -> (o, lse [bh, t])."""
-    bh, t_p, d = q.shape
-    s = _sparse_specs(q, k, v, words, block_size)
-    d_v, tile = s["d_v"], s["tile"]
-    o, lse = pl.pallas_call(
-        functools.partial(_sparse_fwd_kernel, sm_scale=sm_scale, **tile),
-        grid=(bh, s["nq"], s["nk"]),
+def _dq_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_scr,
+               sm_scale, operands, sees):
+    """``operands``: the dtype ds, do, v and k reach the MXU in, the inputs'
+    where None. Under the causal mask it is float32, and the difference is
+    meant: dQ transposes nothing and would only pay the cast there (+2.6%),
+    and an unmasked body won it nothing, so every dQ runs the one masked body
+    on every live tile (PERF.md §6, PR 46). Under a band and a bitmap the
+    operands are the inputs' dtype, as PR 45 and PR 54 measured them."""
+    k = k_ref[0]
+    to = operands or k.dtype
+    p = _tile_p(q_ref[0], k, lse_ref[0], sees(), sm_scale)
+    ds = _tile_ds(p, do_ref[0].astype(to), v_ref[0].astype(to), delta_ref[0],
+                  sm_scale)
+    dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+        ds.astype(to), k.astype(to), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _forward(mask: _Mask, sm_scale, q_ref, k_ref, v_ref, words_ref, o_ref,
+             lse_ref, *scratch) -> None:
+    """The forward's walk over a row block's keys. ``scratch``: m, l, acc."""
+    iq, j = pl.program_id(1), pl.program_id(2)
+    _softmax_start(j == 0, *scratch)
+    update = functools.partial(
+        _softmax_update, q_ref, k_ref, v_ref, *scratch, sm_scale)
+    _by_position(mask, update, iq, *mask.keys(iq, j), words_ref)
+    _softmax_finish(j == pl.num_programs(2) - 1, o_ref, lse_ref, *scratch)
+
+
+def _dkv(mask: _Mask, sm_scale, q_ref, k_ref, v_ref, words_ref, do_ref, lse_ref,
+         delta_ref, dk_ref, dv_ref, dk_scr, dv_scr) -> None:
+    """dK/dV's walk over a key block's rows, once for each q head of the K/V
+    head's group."""
+    ik, j = pl.program_id(1), pl.program_id(2)
+    _zero(j == 0, dk_scr, dv_scr)
+    update = functools.partial(
+        _dkv_update, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_scr,
+        dv_scr, sm_scale)
+    jq, live, interior = mask.rows(ik, j)
+    _by_position(mask, update, jq, ik, live, interior, words_ref)
+    _write(j == pl.num_programs(2) - 1, (dk_ref, dk_scr), (dv_ref, dv_scr))
+
+
+def _dq(mask: _Mask, sm_scale, q_ref, k_ref, v_ref, words_ref, do_ref, lse_ref,
+        delta_ref, dq_ref, dq_scr, operands=None) -> None:
+    """dQ's walk over a row block's keys."""
+    iq, j = pl.program_id(1), pl.program_id(2)
+    _zero(j == 0, dq_scr)
+    update = functools.partial(
+        _dq_update, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_scr,
+        sm_scale, operands)
+    ik, live, _ = mask.keys(iq, j)
+    _by_position(mask, update, iq, ik, live, None, words_ref)
+    _write(j == pl.num_programs(2) - 1, (dq_ref, dq_scr))
+
+
+# The nine functions handed to ``pallas_call``, a name a mask and pass: a
+# trace names a call by the first ``*_kernel`` identifier in its Mosaic module
+# (benchmarks/lib/trace.py) and the benchmark's FLOP tables price it by that
+# name (benchmarks/lib/flops*.py), so no other function here ends in
+# ``_kernel``. Only the bitmap's take the rows' words, after v.
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, mask, sm_scale):
+    _forward(mask, sm_scale, q_ref, k_ref, v_ref, None, *refs)
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *refs, mask, sm_scale):
+    _dkv(mask, sm_scale, q_ref, k_ref, v_ref, None, *refs)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, *refs, mask, sm_scale):
+    _dq(mask, sm_scale, q_ref, k_ref, v_ref, None, *refs, operands=jnp.float32)
+
+
+def _fwd_window_kernel(q_ref, k_ref, v_ref, *refs, mask, sm_scale):
+    _forward(mask, sm_scale, q_ref, k_ref, v_ref, None, *refs)
+
+
+def _bwd_dkv_window_kernel(q_ref, k_ref, v_ref, *refs, mask, sm_scale):
+    _dkv(mask, sm_scale, q_ref, k_ref, v_ref, None, *refs)
+
+
+def _bwd_dq_window_kernel(q_ref, k_ref, v_ref, *refs, mask, sm_scale):
+    _dq(mask, sm_scale, q_ref, k_ref, v_ref, None, *refs)
+
+
+def _sparse_fwd_kernel(*refs, mask, sm_scale):
+    _forward(mask, sm_scale, *refs)
+
+
+def _bwd_dkv_sparse_kernel(*refs, mask, sm_scale):
+    _dkv(mask, sm_scale, *refs)
+
+
+def _bwd_dq_sparse_kernel(*refs, mask, sm_scale):
+    _dq(mask, sm_scale, *refs)
+
+
+# ------------------------------------------------------ the two calls, any mask
+# Grids are (head, the block the outputs stay on, the walk). q and k (and
+# their gradients) have d lanes; v, o, do and dv have d_v; lse and delta one,
+# kept 3-D ([bh, tq, 1]) so the trailing dims satisfy TPU tiling
+# (block_q % 8, last dim == full dim).
+
+
+def _pad_rows(x, t_p: int):
+    pad = t_p - x.shape[1]
+    return x if not pad else jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+
+
+def _tiles(mask: _Mask):
+    """(rows, keys): the BlockSpec of a block of rows, and of keys, ``width``
+    lanes wide under an index map."""
+    return (lambda width, index: pl.BlockSpec((1, mask.block_q, width), index),
+            lambda width, index: pl.BlockSpec((1, mask.block_k, width), index))
+
+
+def _here(b, i, j):
+    return b, i, 0
+
+
+def _key_walk(mask: _Mask):
+    """The index map of K and V where the last axis walks a row block's keys
+    for q head ``b``: at K/V head b // group (no arithmetic where K and V
+    come repeated)."""
+    def index(b, i, j):
+        head = b if mask.group == 1 else jax.lax.div(b, jnp.int32(mask.group))
+        return head, mask.key_index(i, j), 0
+
+    return index
+
+
+def _row_walk(mask: _Mask):
+    """The same for q, do, lse and delta where the last axis walks a key
+    block's rows for K/V head ``b``: once for each q head of its group."""
+    def index(b, i, j):
+        head = b if mask.group == 1 else (
+            b * mask.group + jax.lax.div(j, jnp.int32(mask.row_steps)))
+        return head, mask.row_index(i, j), 0
+
+    return index
+
+
+def _words_spec(mask: _Mask, words, walked: bool) -> list:
+    """The spec of a mask's words [K/V heads, rows, lanes], or none: the rows
+    of the step's own tile at q head b's K/V head, or, where K/V head b's
+    rows are ``walked``, the walk's."""
+    if words is None:
+        return []
+
+    def index(b, i, j):
+        if walked:
+            return b, mask.row_index(i, j), 0
+        return jax.lax.div(b, jnp.int32(mask.group)), i, 0
+
+    return [pl.BlockSpec((1, mask.block_q, words.shape[2]), index)]
+
+
+def _call(mask: _Mask, which: int, sm_scale: float, q, k, v, **call):
+    """``pallas_call`` of the mask's kernel ``which`` (0 the forward, 1
+    dK/dV, 2 dQ) with what the three share. q, k, v: padded."""
+    bh, tq_p, d_v = q.shape[0], q.shape[1], v.shape[2]
+    walked = (k.shape[1] * mask.row_steps * mask.block_q if which == 1
+              else tq_p * mask.key_steps * mask.block_k)
+    return pl.pallas_call(
+        functools.partial(mask.kernels[which], mask=mask, sm_scale=sm_scale),
         interpret=_interpret(),
-        in_specs=[s["rows"](d, s["here"]), s["keys"](d, s["key_walk"]),
-                  s["keys"](d_v, s["key_walk"]), s["words_here"]],
-        out_specs=[s["rows"](d_v, s["here"]), s["rows"](1, s["here"])],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=mask.vmem_limit_bytes,
+        ),
+        # XLA schedules around a call by what it is told here.
+        cost_estimate=mask.flops and pl.CostEstimate(
+            flops=mask.flops[which],
+            bytes_accessed=(q.size + k.size + v.size + bh * tq_p * d_v) * 2,
+            transcendentals=bh * walked,
+        ),
+        **call,
+    )
+
+
+def _forward_call(mask: _Mask, q, k, v, sm_scale: float, words=None):
+    """q [bh, tq, d]; k, v [bh // mask.group, tk, .]; ``words`` where the
+    mask has them. -> (o, lse [bh, tq])."""
+    bh, tq, d = q.shape
+    d_v, block_q = v.shape[2], mask.block_q
+    q = _pad_rows(q, mask.tq_p)
+    k, v = _pad_rows(k, mask.tk_p), _pad_rows(v, mask.tk_p)
+    rows, keys = _tiles(mask)
+    key_walk = _key_walk(mask)
+    o, lse = _call(
+        mask, 0, sm_scale, q, k, v,
+        grid=(bh, mask.tq_p // block_q, mask.key_steps),
+        in_specs=[rows(d, _here), keys(d, key_walk), keys(d_v, key_walk),
+                  *_words_spec(mask, words, False)],
+        out_specs=[rows(d_v, _here), rows(1, _here)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_p, d_v), q.dtype),
-            jax.ShapeDtypeStruct((bh, t_p, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, mask.tq_p, d_v), q.dtype),
+            jax.ShapeDtypeStruct((bh, mask.tq_p, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tile["block_q"], 1), jnp.float32),
-            pltpu.VMEM((tile["block_q"], 1), jnp.float32),
-            pltpu.VMEM((tile["block_q"], d_v), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
-        compiler_params=s["params"],
-    )(q, k, v, words)
-    return o, lse[..., 0]
+    )(q, k, v, *(() if words is None else (words,)))
+    return o[:, :tq], lse[:, :tq, 0]
 
 
-def _sparse_bwd_pallas(q, k, v, words, o, lse, do, *, sm_scale, block_size):
-    bh, t_p, d = q.shape
-    s = _sparse_specs(q, k, v, words, block_size)
-    d_v, tile, group, nq = s["d_v"], s["tile"], s["group"], s["nq"]
-    block_q, block_k = tile["block_q"], tile["block_k"]
-    rows, keys, here = s["rows"], s["keys"], s["here"]
+def _backward_call(mask: _Mask, q, k, v, o, lse, do, sm_scale: float,
+                   words=None):
+    """(dq, dk, dv) of ``_forward_call``'s operands, given its (o, lse) or a
+    ring's merged ones; dk and dv at K and V's own heads."""
+    bh, tq, d = q.shape
+    tk, d_v = k.shape[1], v.shape[2]
+    block_q, block_k = mask.block_q, mask.block_k
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
-    )  # [bh, t, 1]
-    lse3 = lse[..., None]
-    static = dict(sm_scale=sm_scale, **tile)
-
-    def row_tile(i, j):  # a dead step waits at the first live row tile
-        first = jax.lax.div(i * block_k, jnp.int32(block_q))
-        return jax.lax.max(jax.lax.rem(j, jnp.int32(nq)), first)
-
-    def row_walk(b, i, j):
-        return b * group + jax.lax.div(j, jnp.int32(nq)), row_tile(i, j), 0
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_sparse_kernel, steps=nq, **static),
-        interpret=_interpret(),
-        grid=(k.shape[0], s["nk"], group * nq),
-        in_specs=[rows(d, row_walk), keys(d, here), keys(d_v, here),
-                  pl.BlockSpec((1, block_q, s["lanes"]),
-                               lambda b, i, j: (b, row_tile(i, j), 0)),
-                  rows(d_v, row_walk), rows(1, row_walk), rows(1, row_walk)],
-        out_specs=[keys(d, here), keys(d_v, here)],
+    )  # [bh, tq, 1]
+    q, do = _pad_rows(q, mask.tq_p), _pad_rows(do, mask.tq_p)
+    lse3, delta3 = _pad_rows(lse[..., None], mask.tq_p), _pad_rows(delta, mask.tq_p)
+    k, v = _pad_rows(k, mask.tk_p), _pad_rows(v, mask.tk_p)
+    operands = (q, k, v, *(() if words is None else (words,)), do, lse3, delta3)
+    rows, keys = _tiles(mask)
+    row_walk, key_walk = _row_walk(mask), _key_walk(mask)
+    dk, dv = _call(
+        mask, 1, sm_scale, q, k, v,
+        grid=(k.shape[0], mask.tk_p // block_k, mask.group * mask.row_steps),
+        in_specs=[rows(d, row_walk), keys(d, _here), keys(d_v, _here),
+                  *_words_spec(mask, words, True), rows(d_v, row_walk),
+                  rows(1, row_walk), rows(1, row_walk)],
+        out_specs=[keys(d, _here), keys(d_v, _here)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
-        compiler_params=s["params"],
-    )(q, k, v, words, do, lse3, delta)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_sparse_kernel, **static),
-        interpret=_interpret(),
-        grid=(bh, nq, s["nk"]),
-        in_specs=[rows(d, here), keys(d, s["key_walk"]),
-                  keys(d_v, s["key_walk"]), s["words_here"], rows(d_v, here),
-                  rows(1, here), rows(1, here)],
-        out_specs=rows(d, here),
+    )(*operands)
+    dq = _call(
+        mask, 2, sm_scale, q, k, v,
+        grid=(bh, mask.tq_p // block_q, mask.key_steps),
+        in_specs=[rows(d, _here), keys(d, key_walk), keys(d_v, key_walk),
+                  *_words_spec(mask, words, False), rows(d_v, _here),
+                  rows(1, _here), rows(1, _here)],
+        out_specs=rows(d, _here),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=s["params"],
-    )(q, k, v, words, do, lse3, delta)
-    return dq, dk, dv
+    )(*operands)
+    return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
+# The bitmap's road has a ``custom_vjp`` of its own: its residuals carry other
+# names (a policy keeps a sparse layer's apart from a full one's), and the
+# words are an operand with no gradient.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _sparse_flash(q, k, v, words, sm_scale, block_size):
-    return _sparse_fwd_pallas(q, k, v, words, sm_scale=sm_scale,
-                              block_size=block_size)[0]
+    """q [b * h, t, d]; k, v [b * g, t, .]; words [b * g, t, lanes]; t
+    padded to the tiles."""
+    return _forward_call(_bitmap_mask(q, k, block_size), q, k, v, sm_scale, words)[0]
 
 
 def _sparse_flash_fwd(q, k, v, words, sm_scale, block_size):
-    o, lse = _sparse_fwd_pallas(q, k, v, words, sm_scale=sm_scale,
-                                block_size=block_size)
+    o, lse = _forward_call(_bitmap_mask(q, k, block_size), q, k, v, sm_scale, words)
     # Named for the remat policy, as ``_flash``'s are.
     o, lse = checkpoint_name(o, "sparse_o"), checkpoint_name(lse, "sparse_lse")
     return o, (q, k, v, words, o, lse)
 
 
 def _sparse_flash_bwd(sm_scale, block_size, res, do):
-    dq, dk, dv = _sparse_bwd_pallas(*res, do, sm_scale=sm_scale,
-                                    block_size=block_size)
-    return dq, dk, dv, np.zeros(res[3].shape, jax.dtypes.float0)
+    q, k, v, words, o, lse = res
+    dq, dk, dv = _backward_call(
+        _bitmap_mask(q, k, block_size), q, k, v, o, lse, do, sm_scale, words)
+    return dq, dk, dv, np.zeros(words.shape, jax.dtypes.float0)
 
 
 _sparse_flash.defvjp(_sparse_flash_fwd, _sparse_flash_bwd)
@@ -1208,20 +981,20 @@ def _scores(q, k, causal: bool, scale: float, window=None):
     return s
 
 
+def _mask_of(q, k, v, causal, block_q, block_k, window):
+    """The mask of one block of the kernels' road: a band under a ``window``
+    (which alone takes K and V at fewer heads than q's), else the causal
+    one."""
+    if window is not None:
+        return _window_mask(q, k, v, window, block_q, block_k)
+    return _causal_mask(q, k, v, causal, block_q, block_k)
+
+
 def _block_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
-    """One block of attention on [bh, t, d] operands -> (o, lse [bh, tq]).
-    Under a ``window`` the kernels are the windowed ones, which alone take
-    K and V at fewer heads than q's."""
+    """One block of attention on [bh, t, d] operands -> (o, lse [bh, tq])."""
     if _kernels_fit(q.shape[1], k.shape[1], q.shape[2], v.shape[2]):
-        if window is not None:
-            return _flash_fwd_window_pallas(
-                q, k, v, window=window, sm_scale=scale,
-                block_q=block_q, block_k=block_k,
-            )
-        return _flash_fwd_pallas(
-            q, k, v, causal=causal, sm_scale=scale,
-            block_q=block_q, block_k=block_k,
-        )
+        mask = _mask_of(q, k, v, causal, block_q, block_k, window)
+        return _forward_call(mask, q, k, v, scale)
     s = _scores(q, k, causal, scale, window)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -1241,15 +1014,8 @@ def _block_bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     p = exp(s - lse). In XLA the memory high-water is the [tq, tk] block
     per batch*head slice."""
     if _kernels_fit(q.shape[1], k.shape[1], q.shape[2], v.shape[2]):
-        if window is not None:
-            return _flash_bwd_window_pallas(
-                q, k, v, o, lse, do, window=window, sm_scale=scale,
-                block_q=block_q, block_k=block_k,
-            )
-        return _flash_bwd_pallas(
-            q, k, v, o, lse, do, causal=causal, sm_scale=scale,
-            block_q=block_q, block_k=block_k,
-        )
+        mask = _mask_of(q, k, v, causal, block_q, block_k, window)
+        return _backward_call(mask, q, k, v, o, lse, do, scale)
     p = jnp.exp(_scores(q, k, causal, scale, window) - lse[..., :, None])
     do_f = do.astype(jnp.float32)
     dv = jax.lax.dot_general(
@@ -1297,13 +1063,6 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, window, res, do):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-# The windowed kernels' blocks: a band of 512 fills a quarter of the two
-# 1,024-key blocks a 1,024-row block would need. (PERF.md §6, PR 45, has the
-# sweep on the chip.)
-WINDOW_BLOCK_Q = 512
-WINDOW_BLOCK_K = 512
-
-
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -1325,14 +1084,14 @@ def flash_attention(
     D ** -0.5 where none is given. Which road it takes follows from what
     it can observe: a ring over the ambient mesh (``jax.set_mesh``) where
     that splits the sequence, else the Pallas kernels where they fit
-    (``_kernels_fit``), else the XLA reference. ``window=w`` (causal only)
-    keeps keys 0 <= i - j < w of row i: the kernels' road takes it through
-    the windowed kernels, whose grids walk the band alone, at blocks of
-    their own, and the reference masks it; the ring refuses it.
-    ``blocks`` [B, Hkv, T, ceil(T / block_size)] bool (causal self-attention
-    only; ``select_blocks`` makes it) keeps, of the keys row i of a K/V group
-    sees, those in the blocks of ``block_size`` keys it marks: the sparse
-    kernels where they fit, a masked soft-max elsewhere; the ring refuses it.
+    (``_kernels_fit``), else the XLA reference. The kernels' road takes
+    one of three masks (``_Mask``). ``window=w`` (causal only) keeps keys
+    0 <= i - j < w of row i: a band, at blocks of its own; the reference
+    masks it; the ring refuses it. ``blocks`` [B, Hkv, T, ceil(T /
+    block_size)] bool (causal self-attention only; ``select_blocks`` makes
+    it) keeps, of the keys row i of a K/V group sees, those in the blocks of
+    ``block_size`` keys it marks: a bitmap where the kernels fit, a masked
+    soft-max elsewhere; the ring refuses it. Else the causal mask.
     """
     b, h, tq, d = q.shape
     hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[-1]
@@ -1390,8 +1149,8 @@ def flash_attention(
             q, k, v, causal=causal, window=window, sm_scale=scale
         )
     if window is not None:
-        # K and V stay at their own heads: the windowed kernels' index maps
-        # find a q head's.
+        # K and V stay at their own heads: the band's index maps find a q
+        # head's.
         block_q, block_k = WINDOW_BLOCK_Q, WINDOW_BLOCK_K
     elif h != hkv:
         k, v = (jnp.repeat(t, h // hkv, axis=1) for t in (k, v))

@@ -9,8 +9,8 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops import kda
 from ray_tpu.ops.attention import (
-    _flash_bwd_pallas, _flash_bwd_window_pallas, _flash_fwd_pallas,
-    _flash_fwd_window_pallas, flash_attention,
+    _backward_call, _bitmap_mask, _causal_mask, _forward_call, _window_mask,
+    flash_attention,
 )
 from ray_tpu.ops.gmm import _tgmm_pallas, gmm
 
@@ -40,6 +40,19 @@ def _compile_for(sharding, fn, *args):
     return text
 
 
+def _causal_fwd(block):
+    """The one forward call under the causal mask at ``block`` x ``block``,
+    the default scale."""
+    return lambda q, k, v: _forward_call(
+        _causal_mask(q, k, v, True, block, block), q, k, v, q.shape[2] ** -0.5)
+
+
+def _causal_bwd(block):
+    return lambda q, k, v, o, lse, do: _backward_call(
+        _causal_mask(q, k, v, True, block, block), q, k, v, o, lse, do,
+        q.shape[2] ** -0.5)
+
+
 # (batch*heads, seq, head_dim, block): llama-1b b2 x s2048 as the model
 # runs it; mixtral-small's head_dim; ring attention's 512 blocks.
 FLASH_SHAPES = [(32, 2048, 128, 1024), (32, 2048, 64, 1024), (32, 2048, 128, 512)]
@@ -48,26 +61,14 @@ FLASH_SHAPES = [(32, 2048, 128, 1024), (32, 2048, 64, 1024), (32, 2048, 128, 512
 @pytest.mark.parametrize("bh,t,d,block", FLASH_SHAPES)
 def test_flash_fwd_compiles_for_v5e(v5e, bh, t, d, block):
     qkv = ((bh, t, d), jnp.bfloat16)
-    _compile_for(
-        v5e,
-        lambda q, k, v: _flash_fwd_pallas(
-            q, k, v, causal=True, sm_scale=d**-0.5, block_q=block, block_k=block
-        ),
-        qkv, qkv, qkv,
-    )
+    _compile_for(v5e, _causal_fwd(block), qkv, qkv, qkv)
 
 
 @pytest.mark.parametrize("bh,t,d,block", FLASH_SHAPES)
 def test_flash_bwd_compiles_for_v5e(v5e, bh, t, d, block):
     qkv = ((bh, t, d), jnp.bfloat16)
     _compile_for(
-        v5e,
-        lambda q, k, v, o, lse, do: _flash_bwd_pallas(
-            q, k, v, o, lse, do, causal=True, sm_scale=d**-0.5,
-            block_q=block, block_k=block,
-        ),
-        qkv, qkv, qkv, qkv, ((bh, t), jnp.float32), qkv,
-    )
+        v5e, _causal_bwd(block), qkv, qkv, qkv, qkv, ((bh, t), jnp.float32), qkv)
 
 
 # The two-body causal kernels (PR 46) at the shapes of the benchmark's cells
@@ -88,13 +89,8 @@ TWO_BODY_SHAPES = [
 def test_two_body_flash_kernels_compile_for_v5e(v5e, bh, tq, tk, d, d_v, block):
     q, k = ((bh, tq, d), jnp.bfloat16), ((bh, tk, d), jnp.bfloat16)
     v, o = ((bh, tk, d_v), jnp.bfloat16), ((bh, tq, d_v), jnp.bfloat16)
-    args = dict(causal=True, sm_scale=d**-0.5, block_q=block, block_k=block)
-    _compile_for(v5e, lambda q, k, v: _flash_fwd_pallas(q, k, v, **args), q, k, v)
-    _compile_for(
-        v5e,
-        lambda q, k, v, o, lse, do: _flash_bwd_pallas(q, k, v, o, lse, do, **args),
-        q, k, v, o, ((bh, tq), jnp.float32), o,
-    )
+    _compile_for(v5e, _causal_fwd(block), q, k, v)
+    _compile_for(v5e, _causal_bwd(block), q, k, v, o, ((bh, tq), jnp.float32), o)
 
 
 # Laguna's sliding layers: 64 q heads over 8 K/V heads of 128, b1 x s16384,
@@ -102,14 +98,15 @@ def test_two_body_flash_kernels_compile_for_v5e(v5e, bh, tq, tk, d, d_v, block):
 @pytest.mark.parametrize("bq,bk", [(512, 512), (256, 512), (256, 256)])
 def test_windowed_flash_compiles_for_v5e(v5e, bq, bk):
     q, kv = ((64, 16384, 128), jnp.bfloat16), ((8, 16384, 128), jnp.bfloat16)
-    static = dict(window=512, sm_scale=128**-0.5, block_q=bq, block_k=bk)
+    mask = lambda q, k, v: _window_mask(q, k, v, 512, bq, bk)  # noqa: E731
     _compile_for(
-        v5e, lambda q, k, v: _flash_fwd_window_pallas(q, k, v, **static), q, kv, kv
+        v5e, lambda q, k, v: _forward_call(mask(q, k, v), q, k, v, 128**-0.5),
+        q, kv, kv,
     )
     _compile_for(
         v5e,
-        lambda q, k, v, o, lse, do: _flash_bwd_window_pallas(
-            q, k, v, o, lse, do, **static),
+        lambda q, k, v, o, lse, do: _backward_call(
+            mask(q, k, v), q, k, v, o, lse, do, 128**-0.5),
         q, kv, kv, q, ((64, 16384), jnp.float32), q,
     )
 
@@ -258,13 +255,8 @@ def test_gmm_and_its_gradient_compile_for_v5e(v5e, m, experts, k, n):
 def test_flash_at_192_and_128_compiles_for_v5e(v5e, bh, t):
     d, d_v, block = 192, 128, 1024
     qk, v = ((bh, t, d), jnp.bfloat16), ((bh, t, d_v), jnp.bfloat16)
-    args = dict(causal=True, sm_scale=d**-0.5, block_q=block, block_k=block)
-    _compile_for(v5e, lambda q, k, v: _flash_fwd_pallas(q, k, v, **args), qk, qk, v)
-    _compile_for(
-        v5e,
-        lambda q, k, v, o, lse, do: _flash_bwd_pallas(q, k, v, o, lse, do, **args),
-        qk, qk, v, v, ((bh, t), jnp.float32), v,
-    )
+    _compile_for(v5e, _causal_fwd(block), qk, qk, v)
+    _compile_for(v5e, _causal_bwd(block), qk, qk, v, v, ((bh, t), jnp.float32), v)
 
 
 # The same cell's expert layer: 16 held experts of 2304 x 1024 over a
@@ -722,22 +714,24 @@ def test_lightning_kernels_compile_for_v5e(v5e, t, h):
 
 @pytest.mark.parametrize("t,block_size", [(16384, 64), (2048, 16)])
 def test_sparse_kernels_compile_for_v5e_with_k_and_v_at_two_heads(v5e, t, block_size):
-    from ray_tpu.ops.attention import (
-        _sparse_blocks, _sparse_bwd_pallas, _sparse_fwd_pallas,
-    )
+    from ray_tpu.ops.attention import _sparse_blocks
 
     h, g, d = 32, 2, 128
     _, block_k, t_p = _sparse_blocks(t, block_size)
     assert t_p == t and t // block_k <= 128
     q, kv = ((h, t, d), jnp.bfloat16), ((g, t, d), jnp.bfloat16)
     words = ((g, t, 128), jnp.int32)
-    static = dict(sm_scale=d ** -0.5, block_size=block_size)
+    mask = lambda q, k: _bitmap_mask(q, k, block_size)  # noqa: E731
     text = _compile_for(
-        v5e, lambda *a: _sparse_fwd_pallas(*a, **static), q, kv, kv, words)
+        v5e,
+        lambda q, k, v, words: _forward_call(mask(q, k), q, k, v, d ** -0.5, words),
+        q, kv, kv, words)
     assert f"bf16[{h},{t},{t}]" not in text and f"f32[{h},{t},{t}]" not in text
     _compile_for(
-        v5e, lambda *a: _sparse_bwd_pallas(*a, **static), q, kv, kv, words, q,
-        ((h, t), jnp.float32), q)
+        v5e,
+        lambda q, k, v, words, o, lse, do: _backward_call(
+            mask(q, k), q, k, v, o, lse, do, d ** -0.5, words),
+        q, kv, kv, words, q, ((h, t), jnp.float32), q)
 
 
 @pytest.fixture(scope="module")

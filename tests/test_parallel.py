@@ -120,8 +120,8 @@ def test_flash_pallas_interpret_matches_reference():
     from jax.experimental.pallas import tpu as pltpu
 
     with pltpu.force_tpu_interpret_mode():
-        o, lse = A._flash_fwd_pallas(
-            q, k, v, causal=True, sm_scale=0.25, block_q=32, block_k=32
+        o, lse = A._forward_call(
+            A._causal_mask(q, k, v, True, 32, 32), q, k, v, 0.25
         )
     # Treat the leading dim as heads of a single batch element.
     ref = attention_reference(q[None], k[None], v[None], causal=True, sm_scale=0.25)[0]
@@ -150,13 +150,9 @@ def test_flash_pallas_backward_matches_reference_grads():
     dq_ref, dk_ref, dv_ref = jax.grad(ref_out, argnums=(0, 1, 2))(q, k, v)
 
     with pltpu.force_tpu_interpret_mode():
-        o, lse = A._flash_fwd_pallas(
-            q, k, v, causal=True, sm_scale=0.25, block_q=32, block_k=32
-        )
-        dq, dk, dv = A._flash_bwd_pallas(
-            q, k, v, o, lse, do, causal=True, sm_scale=0.25,
-            block_q=32, block_k=32,
-        )
+        mask = A._causal_mask(q, k, v, True, 32, 32)
+        o, lse = A._forward_call(mask, q, k, v, 0.25)
+        dq, dk, dv = A._backward_call(mask, q, k, v, o, lse, do, 0.25)
     np.testing.assert_allclose(np.asarray(dv), np.asarray(dv_ref), atol=2e-4)
     np.testing.assert_allclose(np.asarray(dk), np.asarray(dk_ref), atol=2e-4)
     np.testing.assert_allclose(np.asarray(dq), np.asarray(dq_ref), atol=2e-4)

@@ -429,10 +429,17 @@ def tree_digest(tree) -> tuple:
 # the two to each other). The text is read without the counters JAX gives its
 # private functions (``@silu_158``): a ``checkpoint_name`` lowers to nothing
 # and moves them (models/llama.py REPLAY_KEEPS; these four digests read the
-# same at that change's parent and after it).
+# same at that change's parent and after it). Laguna's text since
+# ``ops/attention.py`` walks every mask by one forward (PR 56): its windowed
+# forward kernel, interpreted here, finds its band's first and last block
+# after the start and not before it, adds the step to the first once and not
+# twice, and takes q's K/V head first in K's and V's index maps; the tile's
+# operations are where they were ("cc97cf680f1bedbe" before; the logits and
+# every gradient at this size are the parent's bit for bit, PERF.md §6,
+# PR 56). The three others, which run the causal kernels alone, read the same.
 BEFORE = {
     "kimi-linear-48b-a3b-l5": ("4ed2711bc2778250", 117, "c16ef491925e5adb"),
-    "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "cc97cf680f1bedbe"),
+    "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "0e16e4b782b6a5a6"),
     "mistral-7b-l4": ("06a35641bbb39a58", 21, "6ac84cd0523ca00f"),
     "sarvam-105b-l5": ("c710f6841e29dd3a", 83, "2a9ffca6aec4a4c6"),
 }
