@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import flash_attention
+from ..ops.rotary import rotate
 from ..parallel.mesh import logical_axis_shards, with_logical_constraint
 from ..util import tracing
 from .hyper_connections import (
@@ -307,7 +308,7 @@ class Attention(nn.Module):
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         if kind.freqs is not None:
             with tracing.scope(tracing.ATTN_ROPE):
-                turn = lambda t: _rope(  # noqa: E731
+                turn = lambda t: rotate(  # noqa: E731
                     t, positions, kind.freqs, leading=True,
                     amplitude=kind.rope_amplitude,
                 )

@@ -43,10 +43,11 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import flash_attention, select_blocks
 from ..ops.kda import chunk_lightning
+from ..ops.rotary import rotate
 from ..util import tracing
 from .kimi_linear import NormWeight, _dense
 from .llama import (
-    LlamaConfig, LlamaForCausalLM, RMSNorm, _rope, rope_frequencies, weight_init,
+    LlamaConfig, LlamaForCausalLM, RMSNorm, rope_frequencies, weight_init,
 )
 
 LIGHTNING, MINICPM4 = "lightning-attn", "minicpm4"
@@ -157,7 +158,7 @@ def _turned(cfg, q, k, positions, dim):
     head."""
     with tracing.scope(tracing.ATTN_ROPE):
         freqs = rope_frequencies(dim, cfg.rope_theta)
-        return _rope(q, positions, freqs), _rope(k, positions, freqs)
+        return rotate(q, positions, freqs), rotate(k, positions, freqs)
 
 
 class LightningMixer(nn.Module):
@@ -174,6 +175,8 @@ class LightningMixer(nn.Module):
         q, k, v = (heads(_dense(cfg, H * d, f"{n}_proj")(x)) for n in "qkv")
         q, k = _head_normed(cfg, q, k)
         if cfg.lightning_use_rope:
+            # [B, H, T, d] is how chunk_lightning's kernels take q and k, so
+            # XLA moves nothing here and back.
             turn = lambda y: y.transpose(0, 2, 1, 3)  # noqa: E731
             q, k = map(turn, _turned(cfg, turn(q), turn(k), positions, d))
         gate = heads(_dense(cfg, H * d, "g_proj")(x))

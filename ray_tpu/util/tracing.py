@@ -79,7 +79,7 @@ MLA_ROPE = "rope"  # inside mla (cfg.mla_rope): the frequency table, the rotatio
 MLA_Q_LATENT = "q_latent"  # inside mla (cfg.q_lora_rank): q's down-projection, its norm, the up-projection to the heads
 ATTN = "attn"  # the softmax-attention mixer (models/llama.py Attention); in a model whose layers differ, the full-attention layers' (Laguna's beside swa, Solar-Open2's beside kda)
 SWA = "swa"  # the same module as a sliding-window layer's mixer (models/laguna.py): its own head count and rotation, flash_attention under a window
-ATTN_ROPE = "rotary"  # inside attn, swa and lightning: the angles, cos and sin, the rotation of q and of k (a part of each head where the layer's kind says so); not opened by a kind that turns nothing
+ATTN_ROPE = "rotary"  # inside attn, swa and lightning: the angles, cos and sin, the rotation of q and of k (a part of each head where the layer's kind says so) by ops/rotary.py rotate: its tables and _rotary_kernel where a head is whole vregs of lanes, _rope elsewhere; not opened by a kind that turns nothing
 ATTN_GATE = "out_gate"  # inside attn and swa (a kind with a gate) and inside sparse: the gate's projection (one value a head, or of q's width), its sigmoid, the product with each head's output
 HC = "hc"  # a hyper-connection (models/hyper_connections.py), twice a layer: the three maps of the streams, the read before the sublayer, the write after it
 HC_PRE = "pre"  # inside hc: the streams' rms, x~ Phi, the three logits, H_pre and H_post, the read u = sum H_pre[i] X[i]

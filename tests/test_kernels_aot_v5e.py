@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops import kda
+from ray_tpu.ops import kda, rotary
 from ray_tpu.ops.attention import (
     _backward_call, _bitmap_mask, _causal_mask, _forward_call, _window_mask,
     flash_attention,
@@ -214,11 +214,13 @@ def test_a_profile_names_each_flash_kernel_of_a_remat_step_by_its_own_name(
             mixer = re.search(r"/layers_\d/(attn|swa)/", line).group(1)
             named.setdefault(mixer, []).append(kernel_name(line))
     # One forward a layer: the replay holds none (models/llama.py
-    # REPLAY_KEEPS keeps what it wrote).
+    # REPLAY_KEEPS keeps what it wrote). q and k turn through
+    # ops/rotary.py's kernel forward, replayed and backward.
+    turns = ["_rotary_kernel"] * 6
     assert sorted(named["attn"]) == [
-        "_bwd_dkv_kernel", "_bwd_dq_kernel", "_fwd_kernel"]
+        "_bwd_dkv_kernel", "_bwd_dq_kernel", "_fwd_kernel", *turns]
     assert sorted(named["swa"]) == [
-        "_bwd_dkv_window_kernel", "_bwd_dq_window_kernel", "_fwd_window_kernel"]
+        "_bwd_dkv_window_kernel", "_bwd_dq_window_kernel", "_fwd_window_kernel", *turns]
 
 
 # mixtral-small: b2 x s2048 tokens x top-2 pairs padded to 128-row tiles
@@ -378,6 +380,34 @@ def test_conv_kernels_compile_for_v5e(v5e, monkeypatch, t, channels):
         assert b"_conv_fwd_kernel" not in base64.b64decode(module)
 
 
+# q's and k's rotation as one pass (``ops/rotary.py``), heads first in and
+# out, and the pass back, which is the same kernel against the tables with
+# the sines' sign turned: the Laguna cell's sliding layer (b1 x s16384, q at
+# 64 heads and k at 8, the whole head), its full layer (q at 48, the leading
+# half under YaRN's amplitude: two lane rotations against three tables) and
+# the MiniCPM-SALA cell's Lightning layer (q and k at 32), at the blocks
+# ``rotate`` gives them.
+@pytest.mark.parametrize("heads,half,leading,amplitude", [
+    (64, 64, True, 1.0), (8, 64, True, 1.0), (48, 32, True, 1.4158883),
+    (32, 64, False, 1.0),
+], ids=["laguna_swa_q", "laguna_swa_k", "laguna_attn_q", "lightning_q_and_k"])
+def test_rotary_kernel_and_its_pass_back_compile_for_v5e(v5e, heads, half, leading, amplitude):
+    from benchmarks.lib import trace
+
+    shape = (1, heads, 16384, 128)
+    turn = rotary._Turn(leading, amplitude, *rotary._blocks(shape), False)
+    assert turn[2:4] == (rotary.ROWS, min(heads, rotary.HEADS))
+    for back in (False, True):
+        text = _compile_for(
+            v5e, lambda x, p, f: rotary._turned(x, p, f, turn, back),  # noqa: B023
+            (shape, jnp.bfloat16), ((1, 16384), jnp.int32), ((half,), jnp.float32))
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert [trace.kernel_name(line) for line in calls] == ["_rotary_kernel"]
+        # the whole of x in and out as it lies: no copy, no transposition
+        assert " copy(" not in text and " transpose(" not in text
+
+
 # The models the benchmark already had lower to the Pallas kernels they had
 # before a layer could choose its mixer and FFN: read by this same code at
 # commit 57913f4, each configuration file at its rehearsal size, b1 x s256.
@@ -426,7 +456,9 @@ def test_the_models_that_were_there_lower_to_the_kernels_they_had(v5e, monkeypat
         placed(shapes), placed(jax.eval_shape(tx.init, shapes)), batch, batch
     ).as_text()
     names = ("_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel", "_gmm_kernel",
-             "_tgmm_kernel", "_kda_fwd_kernel", "_kda_bwd_kernel", "_unwritten_kernel")
+             "_tgmm_kernel", "_kda_fwd_kernel", "_kda_bwd_kernel", "_unwritten_kernel",
+             # none at a rehearsal's heads of 32 lanes: ROTARY_STEPS has the cells'
+             "_rotary_kernel")
     counts = {k: n for k, n in checks.count_pallas_kernels(text, names).items() if n}
     assert counts == KERNELS_BEFORE[name]
 
@@ -481,6 +513,8 @@ def test_mixtrals_step_takes_its_weight_gradients_from_the_grouped_matmul(topo):
     layers, width = config["num_hidden_layers"], config["intermediate_size"]
     counts = checks.count_pallas_kernels(text, ("_tgmm_kernel", "_unwritten_kernel"))
     assert counts == {"_tgmm_kernel": 3 * layers, "_unwritten_kernel": 5 * layers}
+    # Its mesh splits the sequence: q and k at 128 lanes a head turn by _rope.
+    assert "_rotary_kernel" not in text and "_turned" not in text
     hidden = config["hidden_size"]
     assert f"tensor<{19 * 512}x{width}xbf16>" in text  # a stack of 19 trips
     for shape in (f"4x{hidden}x{width}", f"4x{width}x{hidden}"):
@@ -517,6 +551,44 @@ def _lowered_step(v5e, name):
             placed(shapes), placed(jax.eval_shape(tx.init, shapes)), batch, batch
         ).as_text()
     return cell, text
+
+
+# (bodies, call sites) of ``_rotary_kernel`` in a cell's lowered step: a body
+# a jitted entry (``ops/rotary.py`` ``_turned``: a shape, a part of a head, a
+# direction, and a replay's copy of a forward one), a call for q and for k of
+# every layer that turns heads of 128 lanes, forward, replayed where the cell
+# replays, and backward. The Laguna cell: sliding q and k, full q and k. The
+# MiniCPM-SALA cell: q and k are one shape, and its sparse layer turns
+# nothing. The Mistral cells' lowered text holds the replay's calls too; no
+# barrier stands there, XLA merges them with the forward's, and a trace
+# counts 16 a step. sarvam's rotated part is 64 lanes (``models/mla.py``
+# keeps ``_rope``).
+ROTARY_STEPS = {
+    "laguna-xs2-33b-a3b-l8.longctx-16k": (12, 48),
+    "minicpm-sala-9b-l4.long16k": (3, 18),
+    "mistral-7b-l4.short2k": (6, 24),
+    "sarvam-105b-l5.pretrain-4k": (0, 0),
+}
+
+
+def _turns(text):
+    from benchmarks.lib import checks
+
+    bodies = checks.count_pallas_kernels(text, ("_rotary_kernel",))["_rotary_kernel"]
+    return bodies, text.count("call @_turned")
+
+
+@pytest.mark.parametrize("name", [
+    "laguna-xs2-33b-a3b-l8.longctx-16k", "mistral-7b-l4.short2k",
+    "sarvam-105b-l5.pretrain-4k"])
+def test_a_step_turns_q_and_k_by_the_kernel_where_a_head_is_128_lanes(v5e, name):
+    from benchmarks.lib import cells, checks
+
+    cell, text = _lowered_step(v5e, name)
+    assert _turns(text) == ROTARY_STEPS[name]
+    # and lost none of the kernels its configuration states
+    stated = cells.stated_kernels(cell)
+    assert checks.holds_stated_kernels(checks.count_pallas_kernels(text, stated), stated)
 
 
 # Kimi-Linear's step at the benchmark's real size (b1 x s16384, five layers at
@@ -755,6 +827,7 @@ def test_minicpm_salas_step_holds_its_kernels_under_their_names(minicpm_salas_st
         "_sparse_fwd_kernel": 1, "_bwd_dkv_sparse_kernel": 1,
         "_bwd_dq_sparse_kernel": 1, "_lightning_fwd_kernel": 3,
         "_lightning_bwd_kernel": 3}
+    assert _turns(text) == ROTARY_STEPS[cell["name"]]
     others = ("_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel",
               "_gdn_fwd_kernel", "_kda_fwd_kernel")
     assert not any(checks.count_pallas_kernels(text, others).values())

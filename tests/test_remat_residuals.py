@@ -420,6 +420,24 @@ def test_replay_holds_no_matmul_for_an_elementwise_consumer(case):
     _same_to_the_bit(kept, bare)
 
 
+def test_the_replay_keeps_no_residual_for_the_rotation():
+    """At 128 lanes a head q and k turn through ``ops/rotary.py``'s kernel,
+    whose pass back keeps positions and the table's frequencies: the replay
+    turns q and k again, as it makes every operand of the kernels again, and
+    is handed the names a layer that turns through XLA is handed."""
+    layer, shape, _, names = MATMUL_CASES["pre-norm"]
+    wide = _decoder(head_dim=128)
+    policy = remat_policy(_cfg(remat_prevent_cse=True))
+    kept_jaxpr, kept = _gradient(wide, shape, policy)
+    # q and k, forward, replayed and backward
+    assert kernel_calls(kept_jaxpr)["_rotary_kernel"] == LAYERS * 6
+    assert kernel_calls(_gradient(layer, shape, policy)[0])["_rotary_kernel"] == 0
+    assert _handed_to_the_replays(kept_jaxpr) == [sorted(names)] * LAYERS
+    bare_jaxpr, bare = _gradient(wide, shape, NOTHING)
+    assert kernel_calls(bare_jaxpr)["_rotary_kernel"] == LAYERS * 6
+    _same_to_the_bit(kept, bare)
+
+
 @pytest.mark.parametrize("case", ["pre-norm", "norm-after"])
 def test_without_the_barrier_the_names_are_inert(case):
     """A configuration that executes no replay (``remat_prevent_cse`` false)
