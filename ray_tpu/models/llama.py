@@ -94,8 +94,9 @@ class LlamaConfig:
     # sublayers reading the raw stream. False: the pre-norm of every other
     # family, h = x + mixer(RMSNorm(x)).
     norm_after: bool = False
-    # MiniCPM's muP, each 1.0 where a family has none (nothing is then
-    # lowered for it): the embedding times ``embed_scale``; each sublayer's
+    # MiniCPM's muP and Granite's multipliers, each 1.0 where a family has
+    # none (nothing is then lowered for it): the embedding times
+    # ``embed_scale``; each sublayer's
     # output times ``residual_scale`` before the residual sum; the final
     # norm's output over ``logit_divisor`` before the head, in the full-logit
     # and the chunked loss alike.
@@ -154,6 +155,9 @@ class AttentionKind:
     # The gate has q's width, W_g [hidden, heads, head dim]: one value a
     # channel of every head, where it is one a head.
     gate_channels: bool = False
+    # What the scores are multiplied by before the soft-max (Granite's
+    # ``attention_multiplier``); None: ``head_dim ** -0.5``.
+    scale: Optional[float] = None
 
 
 CONFIGS: Dict[str, LlamaConfig] = {
@@ -207,7 +211,7 @@ REPLAY_KEEPS = ("flash_o", "flash_lse", "kda_o", "kda_states", "kda_t",
                 "hc_read", "hc_maps", "hc_write", "gdn_o", "gdn_states",
                 "gdn_t", "mlp_gate", "mlp_up", "mixer_out", "ffn_out",
                 "lightning_o", "lightning_states", "sparse_o", "sparse_lse",
-                "sparse_blocks")
+                "sparse_blocks", "ssd_y", "ssd_states")
 # One object for every caller: JAX caches a jitted function's partial
 # evaluation by the policy's identity, and a second ``_through`` (xing4.py's
 # module) with a policy of its own would lower every jitted kernel entry's
@@ -313,7 +317,8 @@ class Attention(nn.Module):
                     amplitude=kind.rope_amplitude,
                 )
                 q, k = turn(q), turn(k)
-        o = flash_attention(q, k, v, causal=True, window=kind.window)
+        o = flash_attention(q, k, v, causal=True, window=kind.window,
+                            sm_scale=kind.scale)
         o = o.transpose(0, 2, 1, 3)  # [B, T, H, D]
         if kind.gate:
             with tracing.scope(tracing.ATTN_GATE):

@@ -244,7 +244,9 @@ def _rows_after(x_ref, edge, i, tile):
     return jax.lax.select(i == x_ref.shape[1] // tile - 1, edge, own)
 
 
-def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, tile, roll):
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, *rest, tile, roll):
+    # rest: (the bias, the output) or the output alone.
+    b_ref, y_ref = rest if len(rest) == 2 else (None, *rest)
     w = w_ref[...].astype(F32)
     before = _edge(before_ref, pl.program_id(2) == 0)
 
@@ -252,6 +254,8 @@ def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, tile, roll):
         at = pl.ds(pl.multiple_of(i * tile, tile), tile)
         ext = jnp.concatenate([_rows_before(x_ref, before, i, tile), x_ref[0, at, :]])
         c = _conv_of(_taps(ext, w, roll), w)[_CONV_HALO:]
+        if b_ref is not None:
+            c = c + b_ref[...].astype(F32)
         y_ref[0, at, :] = (c * jax.lax.logistic(c)).astype(y_ref.dtype)
         return carry
 
@@ -259,7 +263,9 @@ def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, tile, roll):
 
 
 def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
-                     dx_ref, dw_ref, *, tile, roll):
+                     *rest, tile, roll):
+    # rest: (the bias, dx, dw, the bias's cotangent) or (dx, dw).
+    b_ref, dx_ref, dw_ref, db_ref = rest if len(rest) == 4 else (None, *rest, None)
     taps = w_ref.shape[0]
     w = w_ref[...].astype(F32)
     first, last = pl.program_id(2) == 0, pl.program_id(2) == pl.num_programs(2) - 1
@@ -272,6 +278,8 @@ def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
     @pl.when((pl.program_id(1) == 0) & first)
     def _init():
         dw_ref[...] = jnp.zeros(dw_ref.shape, F32)
+        if db_ref is not None:
+            db_ref[...] = jnp.zeros(db_ref.shape, F32)
 
     def one(i, carry):
         at = pl.ds(pl.multiple_of(i * tile, tile), tile)
@@ -280,6 +288,8 @@ def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
         # The pre-activation again, on the tile's rows and the 8 after them.
         shifted = [x[_CONV_HALO:] for x in _taps(ext, w, roll)]
         c = _conv_of(shifted, w)
+        if b_ref is not None:
+            c = c + b_ref[...].astype(F32)
         s = jax.lax.logistic(c)
         dy = jnp.concatenate([dy_ref[0, at, :].astype(F32),
                               _rows_after(dy_ref, dy_after, i, tile)])
@@ -293,6 +303,10 @@ def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
             prod = dz[:tile] * x[:tile]
             dw_ref[pl.ds(tap * _CONV_HALO, _CONV_HALO), :] += _add_up(
                 prod[r:r + _CONV_HALO] for r in range(0, tile, _CONV_HALO))
+        if db_ref is not None:
+            # The bias's gradient is dz's own sum, where the filter's is.
+            db_ref[...] += _add_up(
+                dz[r:r + _CONV_HALO] for r in range(0, tile, _CONV_HALO))
         return carry
 
     jax.lax.fori_loop(0, x_ref.shape[1] // tile, one, None)
@@ -340,57 +354,73 @@ def _conv_call(kernel, blocks, **kwargs):
 # three projections, forward, replayed and backward.
 
 
+# ``b`` is the bias [1, D] or None; with None a call's operands, its kernel's
+# body and so its lowered text are what they were before there was one.
+
+
 @functools.partial(jax.jit, static_argnums=(2, 3))
-def _conv_forward(x, w, dtype, blocks):
+def _conv_forward(x, w, dtype, blocks, b=None):
     grid, block, before, _, filt = _conv_specs(x, blocks)
+    bias = [] if b is None else [b]
     return _conv_call(
         _conv_fwd_kernel, blocks, grid=grid,
-        in_specs=[block, before, filt(w.shape[0])], out_specs=block,
-        out_shape=jax.ShapeDtypeStruct(x.shape, dtype),
-    )(x, x, w)
+        in_specs=[block, before, filt(w.shape[0])] + [filt(1)] * len(bias),
+        out_specs=block, out_shape=jax.ShapeDtypeStruct(x.shape, dtype),
+    )(x, x, w, *bias)
 
 
 @functools.partial(jax.jit, static_argnums=3)
-def _conv_backward(x, w, dy, blocks):
+def _conv_backward(x, w, dy, blocks, b=None):
     grid, block, before, after, filt = _conv_specs(x, blocks)
     taps = w.shape[0]
-    dx, dw = _conv_call(
+    bias = [] if b is None else [b]
+    dx, dw, *db = _conv_call(
         _conv_bwd_kernel, blocks, grid=grid,
-        in_specs=[block, before, after(x.dtype), block, after(dy.dtype), filt(taps)],
-        out_specs=[block, filt(taps * _CONV_HALO)],
+        in_specs=[block, before, after(x.dtype), block, after(dy.dtype), filt(taps)]
+        + [filt(1)] * len(bias),
+        out_specs=[block, filt(taps * _CONV_HALO)] + [filt(_CONV_HALO)] * len(bias),
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct((taps * _CONV_HALO, x.shape[2]), F32)],
-    )(x, x, x, dy, dy, w)
-    return dx, dw.reshape(taps, _CONV_HALO, -1).sum(1).astype(w.dtype)
+                   jax.ShapeDtypeStruct((taps * _CONV_HALO, x.shape[2]), F32)]
+        + [jax.ShapeDtypeStruct((_CONV_HALO, x.shape[2]), F32)] * len(bias),
+    )(x, x, x, dy, dy, w, *bias)
+    dw = dw.reshape(taps, _CONV_HALO, -1).sum(1).astype(w.dtype)
+    return (dx, dw) if b is None else (dx, dw, db[0].sum(0, keepdims=True).astype(b.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _conv_silu_pallas(x, w, dtype, blocks):
-    return _conv_forward(x, w, dtype, blocks)
+def _conv_silu_pallas(x, w, dtype, blocks, b=None):
+    return _conv_forward(x, w, dtype, blocks, b)
 
 
-def _conv_silu_fwd(x, w, dtype, blocks):
-    return _conv_forward(x, w, dtype, blocks), (x, w)
+def _conv_silu_fwd(x, w, dtype, blocks, b=None):
+    return _conv_forward(x, w, dtype, blocks, b), (x, w, b)
 
 
 def _conv_silu_bwd(dtype, blocks, residuals, dy):
-    return _conv_backward(*residuals, dy, blocks)
+    x, w, b = residuals
+    grads = _conv_backward(x, w, dy, blocks, b)
+    return grads if b is not None else (*grads, None)
 
 
 _conv_silu_pallas.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
-def conv_silu(x, w, dtype=F32):
-    """``silu(short_conv(x, w))`` rounded once to ``dtype``: x [B, T, D]
-    float32, w [K, D]. On a TPU (or under the interpreter) where the shape
-    tiles it is one Pallas pass over x forward and one over x and the
-    cotangent backward, which makes the pre-activation again from x (the
-    residuals are x and w) and adds the filter's gradient up in float32;
-    elsewhere it is what this line says, for XLA to differentiate."""
+def conv_silu(x, w, dtype=F32, bias=None):
+    """``silu(short_conv(x, w) + bias)`` rounded once to ``dtype``: x [B, T,
+    D] float32, w [K, D], ``bias`` [D] or None (no term). On a TPU (or under
+    the interpreter) where the shape tiles it is one Pallas pass over x
+    forward and one over x and the cotangent backward, which makes the
+    pre-activation again from x (the residuals are x, w and the bias) and adds
+    the filter's gradient, and the bias's beside it, up in float32; elsewhere
+    it is what this line says, for XLA to differentiate."""
     blocks = _conv_blocks(x, w)
     if blocks is None:
-        return jax.nn.silu(short_conv(x, w)).astype(dtype)
-    return _conv_silu_pallas(x.astype(F32), w, jnp.dtype(dtype), blocks)
+        c = short_conv(x, w)
+        if bias is not None:
+            c = c + bias.astype(F32)
+        return jax.nn.silu(c).astype(dtype)
+    return _conv_silu_pallas(x.astype(F32), w, jnp.dtype(dtype), blocks,
+                             None if bias is None else bias[None])
 
 
 def l2norm(x, eps: float = 1e-6):
@@ -994,15 +1024,17 @@ def chunk_kda(q, k, v, g, beta, gate, weight, *, scale, rms_eps, l2_eps=1e-6):
 _LANES = 128
 
 
-def _running_sums(g, p, roll):
+def _running_sums(g, p, roll, chunk=CHUNK):
     """g [p * C, 1] float32, p heads' chunk of log-decays on rows -> (G [p *
     C, _LANES], the running sum inside each head's chunk on every lane; tot,
-    a list of p [1, _LANES]: each head's sum over the chunk)."""
+    a list of p [1, _LANES]: each head's sum over the chunk). A g of _LANES
+    columns is as many sequences, one a lane (``_ssd_chunk``: a lane a head).
+    ``chunk`` is C, a power of two."""
     n = g.shape[0]
     gb = jnp.broadcast_to(g, (n, _LANES))
-    at = jax.lax.broadcasted_iota(jnp.int32, (n, _LANES), 0) % CHUNK
+    at = jax.lax.broadcasted_iota(jnp.int32, (n, _LANES), 0) % chunk
     G = gb
-    for b in _LEVELS:
+    for b in (1 << level for level in range(chunk.bit_length() - 1)):
         G = G + jnp.where(at >= b, roll(G, b), 0.0)
     return G, [jnp.sum(part, 0, keepdims=True) for part in _heads_of(gb, p)]
 
@@ -1511,3 +1543,370 @@ def chunk_lightning(q, k, v, gate, weight, slopes, *, scale, rms_eps):
     o = run(heads_first(q), heads_first(k), heads_first(v), heads_first(gate),
             weight.astype(F32)[None], lanes, (scale, rms_eps))
     return heads_first(o)[:, :t]
+
+
+# ------------------------------------- a step-scaled scalar decay: Mamba-2's SSD
+# The state-space duality form of Mamba-2's mixer. A head has P value channels
+# and a float32 state S in R^{P x N}; B and C, the "key" and the "query" of N
+# channels, are one pair a token for every head (one group):
+#
+#     S_t = exp(dl_t A) S_{t-1} + dl_t u_t B_t^T,   y_t = S_t C_t + D u_t
+#
+# with dl_t > 0 the head's step (the mixer's softplus), A < 0 its rate and D
+# its skip. No delta rule and no inverse: Lightning's chunk with a decay that
+# is data. With L the running sum of dl A inside a chunk of 256 rows (float32,
+# never positive, differences taken before the exponent as ``_gdn_head_chunk``
+# takes them) and x = dl u:
+#
+#     Y  = ((C B^T) * exp(L_t - L_s)[s <= t]) X + exp(L_t) (C S^T) + D U
+#     S' = exp(L_last) S + (X exp(L_last - L_t))^T B
+#
+# C B^T is the same [256, 256] matrix for every head: the kernels make it once
+# a chunk (``g_scr``, at the chunk's first grid step) and a head multiplies it
+# by its own decays. Layout: u and y lie [B, T, H * P] as the mixer's
+# convolution leaves them and its norm takes them (nothing is transposed in
+# HBM); a grid step is a chunk of ``_SSD_GROUP`` = 8 heads, their 512 lanes of u;
+# two heads of 64 share a vreg's 128 lanes (a *tile*), and a tile's state lies
+# transposed and side by side, [N, 2 P], so that C S^T and X^T B are one [.,
+# 128] x [128, 128] product a tile and only the masked product is a head's
+# own. The steps come as rows, [B, H / 8, 8, T] float32 (2 MiB at 8k tokens and
+# 64 heads, moved once): the kernel turns the block to columns (one [128,
+# 256] transposition), takes the running sums of all 8 heads at once with a
+# head a lane (``_running_sums``: sublane rolls, exact float32, no matmul) and
+# turns them back for the row form. The grid is (batch, chunk, group), the
+# groups innermost, every head's state in VMEM over the sequence (2 MiB at 64
+# heads), as ``_kda_fwd_kernel`` holds them. Under a gradient the forward
+# writes the state at every chunk's start (64 MiB a layer at 8k tokens and 64
+# heads); the backward kernel walks the chunks in reverse with the states'
+# cotangents in VMEM and differentiates ``_ssd_chunk`` where it stands
+# (``jax.vjp`` inside the kernel), the cotangent of C B^T added up over a
+# chunk's groups and taken to B's and C's at its last. The skip and the
+# step's product with u are inside (neither costs a pass over u in HBM); the
+# gate and the norm over all heads' channels are the mixer's, outside.
+SSD_CHUNK = 256
+_SSD_GROUP = 8
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _lane(x, i):
+    """x[:, i:i + 1] of x [r, _LANES]; its transpose is a select, not a pad."""
+    return x[:, i:i + 1]
+
+
+def _lane_fwd(x, i):
+    return _lane(x, i), None
+
+
+def _lane_bwd(i, _, g):
+    at = jax.lax.broadcasted_iota(jnp.int32, (g.shape[0], _LANES), 1)
+    return (jnp.where(at == i, g, 0.0),)
+
+
+_lane.defvjp(_lane_fwd, _lane_bwd)
+
+
+def _row(x, i):
+    """x[i:i + 1] of a 2-D x; its transpose is a select, not a pad."""
+    return _row_of(x, i, x.shape[0])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _row_of(x, i, rows):
+    return x[i:i + 1]
+
+
+def _row_fwd(x, i, rows):
+    return _row_of(x, i, rows), None
+
+
+def _row_bwd(i, rows, _, g):
+    at = jax.lax.broadcasted_iota(jnp.int32, (rows, g.shape[1]), 0)
+    return (jnp.where(at == i, g, 0.0),)
+
+
+_row_of.defvjp(_row_fwd, _row_bwd)
+
+
+def _ssd_tile(p: int) -> int:
+    """Heads whose P channels share a tile of lanes: two of 64; a power of
+    two, so that a group is whole tiles."""
+    return min(_SSD_GROUP, 1 << max(_LANES // p, 1).bit_length() - 1)
+
+
+def _ssd_chunk(St, u, dt, a, D, Bm, Cm, G, roll=_xla_roll):
+    """A chunk of one group of 8 heads, q of them a tile of q * P lanes. St
+    [8 / q, N, q * P] float32, the tiles' states at its start, each head's
+    transposed; u [C, 8 * P] in the matmuls' dtype; dt [8, C] float32, a
+    head's steps a row; a [1, _LANES] float32, head j's rate A on lane j; D
+    [8 / q, q * P] float32, a tile's skips a row, a head's on its lanes; Bm, Cm [C, N] float32; G
+    [C, C] float32, C B^T. -> (the states at its end, y [C, 8 * P] float32)."""
+    dtype = u.dtype
+    c = u.shape[0]
+    tiles, _, lanes = St.shape
+    q = _SSD_GROUP // tiles
+    p = lanes // q
+    # Head j's steps on lane j, then the running sum of dl A down the rows.
+    steps = jnp.concatenate([dt, jnp.zeros((_LANES - _SSD_GROUP, c), F32)]).T
+    L, (last,) = _running_sums(steps * a, 1, roll, c)
+    Lrow = L.T
+    row, col = _rows_cols(c)
+    head_of = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1) // p
+
+    def on_lanes(x, tile):
+        """x [r, _LANES], head j on lane j -> [r, q * P]: the tile's heads,
+        each on its P lanes."""
+        out = jnp.broadcast_to(_lane(x, tile * q), (x.shape[0], lanes))
+        for i in range(1, q):
+            out = jnp.where(head_of == i, _lane(x, tile * q + i), out)
+        return out
+
+    Bd, Cd = Bm.astype(dtype), Cm.astype(dtype)
+    states, ys = [], []
+    for tile in range(tiles):
+        uf = u[:, tile * lanes:(tile + 1) * lanes].astype(F32)
+        Lt, end = on_lanes(L, tile), on_lanes(last, tile)
+        x = uf * on_lanes(steps, tile)
+        xd = x.astype(dtype)
+        y = None
+        for i in range(q):
+            h = tile * q + i
+            # exp(L_t - L_s) for s <= t: the difference is taken first and
+            # is never positive where it stands.
+            decay = jnp.where(row >= col, jnp.exp(jnp.minimum(
+                _lane(L, h) - _row(Lrow, h), 0.0)), 0.0)
+            mine = _nn((G * decay).astype(dtype), xd)
+            y = mine if y is None else jnp.where(head_of == i, mine, y)
+        y = y + jnp.exp(Lt) * _nn(Cd, St[tile].astype(dtype))
+        ys.append(y + _row(D, tile) * uf)
+        states.append(jnp.exp(end) * St[tile]
+                      + _tn(Bd, (x * jnp.exp(end - Lt)).astype(dtype)))
+    return jnp.stack(states), jnp.concatenate(ys, axis=1)
+
+
+def _shared_scores(b_ref, c_ref):
+    """C B^T of a chunk, float32: every head's, before its decays."""
+    return _nt(c_ref[0], b_ref[0])
+
+
+def _ssd_fwd_kernel(u_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, y_ref, *rest):
+    # rest: (the states' output, the two scratches) or the scratches alone.
+    s_ref, g_scr, st_scr = rest if len(rest) == 3 else (None, *rest)
+    step = pl.program_id(2)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        st_scr[step] = jnp.zeros(st_scr.shape[1:], F32)
+
+    @pl.when(step == 0)
+    def _scores():
+        g_scr[...] = _shared_scores(b_ref, c_ref)
+
+    St = st_scr[step]
+    if s_ref is not None:
+        s_ref[0, 0, 0] = St
+    st_scr[step], y = _ssd_chunk(
+        St, u_ref[0], dt_ref[0, 0], a_ref[0], d_ref[0], b_ref[0].astype(F32),
+        c_ref[0].astype(F32), g_scr[...], roll=_roll_here())
+    y_ref[0] = y.astype(y_ref.dtype)
+
+
+def _ssd_bwd_kernel(u_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, s_ref, dy_ref,
+                    du_ref, ddt_ref, da_ref, dd_ref, db_ref, dc_ref, g_scr,
+                    dg_scr, dst_scr):
+    step = pl.program_id(2)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        dst_scr[step] = jnp.zeros(dst_scr.shape[1:], F32)
+
+    # The rates' and the skips' cotangents add up over a batch row's steps in
+    # their output blocks, which stay in VMEM while the row stands.
+    @pl.when((pl.program_id(1) == 0) & (step == 0))
+    def _init_heads():
+        da_ref[...] = jnp.zeros(da_ref.shape, F32)
+        dd_ref[...] = jnp.zeros(dd_ref.shape, F32)
+
+    # B's and C's add up over a chunk's groups, C B^T's in a scratch.
+    @pl.when(step == 0)
+    def _scores():
+        g_scr[...] = _shared_scores(b_ref, c_ref)
+        dg_scr[...] = jnp.zeros(dg_scr.shape, F32)
+        db_ref[...] = jnp.zeros(db_ref.shape, F32)
+        dc_ref[...] = jnp.zeros(dc_ref.shape, F32)
+
+    _, vjp = jax.vjp(
+        functools.partial(_ssd_chunk, roll=_roll_here()),
+        s_ref[0, 0, 0], u_ref[0], dt_ref[0, 0], a_ref[0], d_ref[0],
+        b_ref[0].astype(F32), c_ref[0].astype(F32), g_scr[...])
+    dst_scr[step], du, ddt, da, dd, dB, dC, dG = vjp(
+        (dst_scr[step], dy_ref[0].astype(F32)))
+    du_ref[0] = du.astype(du_ref.dtype)
+    ddt_ref[0, 0] = ddt
+    da_ref[0, step] += da
+    dd_ref[0, step] += dd
+    db_ref[0] += dB
+    dc_ref[0] += dC
+    dg_scr[...] += dG
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _to_b_and_c():
+        dG = dg_scr[...].astype(b_ref.dtype)
+        dc_ref[0] += _nn(dG, b_ref[0])
+        db_ref[0] += _tn(dG, c_ref[0])
+
+
+def _ssd_specs(groups, p, n_state, chunk_of):
+    """BlockSpecs over grid (batch, step, group), ``chunk_of(step)`` the chunk
+    a step works on: u and y lie [B, T, H * P], a block a chunk's rows of a
+    group's lanes; the steps [B, H / 8, 8, T]; the rates [H / 8, 1, _LANES]
+    and the skips [H / 8, tiles, q * P]; B and C [B, T, N], a chunk's block the
+    same for every group; the states [B, chunks, H / 8, tiles, N, q * P]."""
+    c, q = SSD_CHUNK, _ssd_tile(p)
+    tiles, lanes, width = _SSD_GROUP // q, q * p, _SSD_GROUP * p
+    whole = lambda *block: pl.BlockSpec(  # noqa: E731
+        (1, groups, *block), lambda b, n, g: (b, 0, 0, 0))
+    return {
+        "u": pl.BlockSpec((1, c, width), lambda b, n, g: (b, chunk_of(n), g)),
+        "dt": pl.BlockSpec((1, 1, _SSD_GROUP, c),
+                           lambda b, n, g: (b, g, 0, chunk_of(n))),
+        "a": pl.BlockSpec((1, 1, _LANES), lambda b, n, g: (g, 0, 0)),
+        "d": pl.BlockSpec((1, tiles, lanes), lambda b, n, g: (g, 0, 0)),
+        "bc": pl.BlockSpec((1, c, n_state), lambda b, n, g: (b, chunk_of(n), 0)),
+        "state": pl.BlockSpec((1, 1, 1, tiles, n_state, lanes),
+                              lambda b, n, g: (b, chunk_of(n), g, 0, 0, 0)),
+        "da": whole(1, _LANES), "dd": whole(tiles, lanes),
+        "scores": pltpu.VMEM((c, c), F32),
+        "states": pltpu.VMEM((groups, tiles, n_state, lanes), F32),
+    }
+
+
+def _ssd_forward_pallas(u, dt, a, D, Bm, Cm, states):
+    batch, t, _ = u.shape
+    groups, p = dt.shape[1], D.shape[1] * D.shape[2] // _SSD_GROUP
+    n, n_state = t // SSD_CHUNK, Bm.shape[2]
+    s = _ssd_specs(groups, p, n_state, lambda i: i)
+    tiles, lanes = s["states"].shape[1], s["states"].shape[3]
+    return pl.pallas_call(
+        _ssd_fwd_kernel, grid=(batch, n, groups),
+        in_specs=[s["u"], s["dt"], s["a"], s["d"], s["bc"], s["bc"]],
+        out_specs=[s["u"], s["state"]][:1 + states],
+        out_shape=[
+            jax.ShapeDtypeStruct(u.shape, u.dtype),
+            jax.ShapeDtypeStruct((batch, n, groups, tiles, n_state, lanes), F32),
+        ][:1 + states],
+        scratch_shapes=[s["scores"], s["states"]],
+        compiler_params=_params(),
+        interpret=_attention._interpret(),
+    )(u, dt, a, D, Bm, Cm)
+
+
+def _ssd_backward_pallas(u, dt, a, D, Bm, Cm, states, dy):
+    batch, t, _ = u.shape
+    groups, p = dt.shape[1], D.shape[1] * D.shape[2] // _SSD_GROUP
+    n, n_state = t // SSD_CHUNK, Bm.shape[2]
+    s = _ssd_specs(groups, p, n_state, lambda i: n - 1 - i)
+    return pl.pallas_call(
+        _ssd_bwd_kernel, grid=(batch, n, groups),
+        in_specs=[s["u"], s["dt"], s["a"], s["d"], s["bc"], s["bc"], s["state"],
+                  s["u"]],
+        out_specs=[s["u"], s["dt"], s["da"], s["dd"], s["bc"], s["bc"]],
+        out_shape=[
+            jax.ShapeDtypeStruct(u.shape, u.dtype),
+            jax.ShapeDtypeStruct(dt.shape, F32),
+            jax.ShapeDtypeStruct((batch, *a.shape), F32),
+            jax.ShapeDtypeStruct((batch, *D.shape), F32),
+            jax.ShapeDtypeStruct(Bm.shape, F32),
+            jax.ShapeDtypeStruct(Cm.shape, F32),
+        ],
+        scratch_shapes=[s["scores"], s["scores"], s["states"]],
+        compiler_params=_params(),
+        interpret=_attention._interpret(),
+    )(u, dt, a, D, Bm, Cm, states, dy)
+
+
+@jax.custom_vjp
+def _ssd_pallas(u, dt, a, D, Bm, Cm):
+    """y [B, T, H * P] from u in the same layout, the steps dt [B, H / 8, 8,
+    T], the rates a [H / 8, 1, _LANES], the skips D [H / 8, tiles, q * P] and B,
+    C [B, T, N], T a whole number of chunks and H of groups. As
+    ``_lightning_pallas``: under a gradient the forward rule writes and names
+    y and every chunk's first states (``ssd_y``, ``ssd_states``:
+    models/llama.py REPLAY_KEEPS)."""
+    return _ssd_forward_pallas(u, dt, a, D, Bm, Cm, states=False)[0]
+
+
+def _ssd_pallas_fwd(u, dt, a, D, Bm, Cm):
+    y, states = _ssd_forward_pallas(u, dt, a, D, Bm, Cm, states=True)
+    y, states = checkpoint_name(y, "ssd_y"), checkpoint_name(states, "ssd_states")
+    return y, (u, dt, a, D, Bm, Cm, states)
+
+
+def _ssd_pallas_bwd(residuals, dy):
+    u, Bm = residuals[0], residuals[4]
+    du, ddt, da, dd, dB, dC = _ssd_backward_pallas(*residuals, dy.astype(u.dtype))
+    return du, ddt, da.sum(0), dd.sum(0), dB.astype(Bm.dtype), dC.astype(Bm.dtype)
+
+
+_ssd_pallas.defvjp(_ssd_pallas_fwd, _ssd_pallas_bwd)
+
+
+def _ssd_xla(u, dt, a, D, Bm, Cm):
+    """The same function of the same layouts under ``lax.scan``, a group a
+    call, for JAX to differentiate: where there is no TPU."""
+    batch, t, _ = u.shape
+    groups, p = dt.shape[1], D.shape[1] * D.shape[2] // _SSD_GROUP
+    c, q = SSD_CHUNK, _ssd_tile(p)
+    n = t // c
+
+    def chunks(x):  # [B, T, ...] -> [N, B, C, ...]
+        return jnp.moveaxis(x.reshape(batch, n, c, *x.shape[2:]), 1, 0)
+
+    def one(St, u, dt, Bm, Cm):  # a batch row's chunk: every group
+        G = _nt(Cm.astype(u.dtype), Bm.astype(u.dtype))
+        group = lambda St, u, dt, a, D: _ssd_chunk(  # noqa: E731
+            St, u, dt, a, D, Bm.astype(F32), Cm.astype(F32), G)
+        St, y = jax.vmap(group, in_axes=(0, 1, 0, 0, 0), out_axes=(0, 1))(
+            St, u.reshape(c, groups, -1), dt, a, D)
+        return St, y.reshape(c, -1)
+
+    _, y = jax.lax.scan(
+        lambda St, chunk: jax.vmap(one)(St, *chunk),
+        jnp.zeros((batch, groups, _SSD_GROUP // q, Bm.shape[2], q * p), F32),
+        (chunks(u), jnp.moveaxis(dt.reshape(batch, groups, _SSD_GROUP, n, c), 3, 0),
+         chunks(Bm), chunks(Cm)))
+    return jnp.moveaxis(y, 0, 1).reshape(u.shape).astype(u.dtype)
+
+
+def ssd_road(p: int, n_state: int) -> str:
+    """Which road ``chunk_ssd`` takes at P value channels a head and a state
+    of N: "pallas" on a TPU where a group's lanes and the state fill whole
+    vregs, and under the interpreter; else "xla"."""
+    if _attention._interpret():
+        return "pallas"
+    tiles = not (_SSD_GROUP * p) % _LANES and not n_state % _LANES
+    return "pallas" if _attention._on_tpu() and tiles else "xla"
+
+
+def chunk_ssd(u, dt, a_log, Bm, Cm, D):
+    """A Mamba-2 mixer's recurrence from its convolution's outputs to its
+    gate's input, chunked: S_t = exp(dt_t A) S_{t-1} + dt_t u_t B_t^T in
+    float32, A = -exp(a_log), y_t = S_t C_t + D u_t. u [B, T, H, P]; dt [B, T,
+    H] float32, the steps after their softplus (> 0); a_log, D [H]; Bm, Cm
+    [B, T, N], one pair a token for every head. Returns [B, T, H, P] in u's
+    dtype. Differentiable in all six."""
+    batch, t, heads, p = u.shape
+    pad, more = -t % SSD_CHUNK, -heads % _SSD_GROUP
+    # Padding tokens and heads step nowhere (dt 0) and write zeros.
+    u = jnp.pad(u, ((0, 0), (0, pad), (0, more), (0, 0)))
+    dt = jnp.pad(dt.astype(F32), ((0, 0), (0, pad), (0, more)))
+    Bm, Cm = (jnp.pad(x.astype(u.dtype), ((0, 0), (0, pad), (0, 0))) for x in (Bm, Cm))
+    groups = (heads + more) // _SSD_GROUP
+    rates = jnp.pad(-jnp.exp(a_log.astype(F32)), (0, more)).reshape(groups, 1, -1)
+    rates = jnp.pad(rates, ((0, 0), (0, 0), (0, _LANES - _SSD_GROUP)))
+    skips = jnp.repeat(jnp.pad(D.astype(F32), (0, more)), p).reshape(
+        groups, -1, _ssd_tile(p) * p)
+    run = _ssd_pallas if ssd_road(p, Bm.shape[2]) == "pallas" else _ssd_xla
+    y = run(u.reshape(batch, t + pad, -1),
+            dt.transpose(0, 2, 1).reshape(batch, groups, _SSD_GROUP, t + pad),
+            rates, skips, Bm, Cm)
+    return y.reshape(batch, t + pad, heads + more, p)[:, :t, :heads]

@@ -69,7 +69,10 @@ GDN = "gdn"  # the scalar-decay gated delta-rule mixer (models/olmo_hybrid.py GD
 LIGHTNING = "lightning"  # the decay-only linear-attention mixer (models/minicpm_sala.py LightningMixer): projections, qk_norm and rotary scopes, ops/kda.py chunk_lightning (its kernels hold o's norm and the output gate) and the transpositions around it
 SPARSE = "sparse"  # the block-sparse top-k softmax mixer (models/minicpm_sala.py SparseAttention): projections, qk_norm, select, ops/attention.py sparse_attention's kernels, out_gate
 SPARSE_SELECT = "select"  # inside sparse, where T > dense_len: ops/attention.py select_blocks (compressed keys, the scores of every head against them, their soft-max, the sum over a group's heads, the max-pool to blocks, the forced blocks, top-k, the packed bitmap); nothing of it is differentiated
-KDA_CONV = "conv"  # inside kda: the short convolutions of q, k, v and their SiLU
+MAMBA = "mamba"  # the Mamba-2 state-space mixer (models/granite_hybrid.py Mamba2Mixer): the three input projections, conv, step, ops/kda.py chunk_ssd's kernels (they hold the step's product with u and the skip) and the slices around them, norm, the output projection
+MAMBA_STEP = "step"  # inside mamba: the step's softplus with its bias
+MAMBA_NORM = "norm"  # inside mamba: y times SiLU(z), then the one RMSNorm over every head's channels
+KDA_CONV = "conv"  # inside kda: the short convolutions of q, k, v and their SiLU; inside mamba: the one convolution of x, B and C with its bias and its SiLU
 KDA_GATE = "gate"  # inside kda: the log-decay g and the write strength beta, with its doubling where the config writes in (0, 2)
 KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's and o's norms and the output gate), v's rounding, beta's transpose
 # (No "out_norm": o's per-head RMSNorm and output gate left XLA for the scan's
@@ -92,9 +95,10 @@ LOSS_HEAD = "head"  # inside loss: a chunk's float32 matmul with the head, alone
 SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
           MLA_LATENT, MLA_ROPE, MLA_Q_LATENT, ATTN_ROPE, ATTN_GATE, HC, HC_PRE,
-          HC_SINKHORN, HC_POST, HC_STREAMS, MTP, LOSS, LOSS_HEAD, SPARSE_SELECT)
+          HC_SINKHORN, HC_POST, HC_STREAMS, MTP, LOSS, LOSS_HEAD, SPARSE_SELECT,
+          MAMBA_STEP, MAMBA_NORM)
 # Flax module names, bound in the model classes' ``blocks``.
-MIXERS = (KDA, MLA, ATTN, SWA, GDN, LIGHTNING, SPARSE)
+MIXERS = (KDA, MLA, ATTN, SWA, GDN, LIGHTNING, SPARSE, MAMBA)
 # The decoder body's flax names (models/llama.py, xing4.py), a layer's and
 # above: parameter trees and checkpoints hold them, so none is ever renamed.
 EMBED = "embed_tokens"  # the embedding table's flax name; models/llama.py _lookup opens it as a scope around what it does outside the module (the one-hot product where a mesh splits the table, the constraint on the result)

@@ -261,7 +261,7 @@ def test_float32_gradients_are_bit_for_bit_the_masked_everywhere_result(
 # prints a ``pallas_call`` whole (kernel jaxpr, grid, block shapes, cost
 # estimate, compiler parameters) and no source location, and held to digests.
 
-# Every distinct attention call of the benchmark's twelve cells, bfloat16:
+# Every distinct attention call of the benchmark's thirteen cells, bfloat16:
 # (batch, heads, K/V heads, tokens, q/k head dim, v head dim, the mask's
 # keywords), from benchmarks/configs/*.json and benchmarks/traffic/*.json.
 # The Mixtral cell's calls are the ring's blocks (seq=2: 2,048 rows a device
@@ -279,6 +279,7 @@ ATTENTION_CALLS = {
     "solar-gqa-4k": (1, 64, 8, 4096, 128, 128, {}),
     "olmo-hybrid-8k": (1, 30, 30, 8192, 128, 128, {}),
     "minicpm-sparse-16k": (1, 32, 2, 16384, 128, 128, {"block_size": 64}),
+    "granite-gqa-8k": (1, 32, 8, 8192, 64, 64, {"sm_scale": 0.015625}),
     "ring-diagonal": (1, 32, 32, 2048, 128, 128, {"ring": True}),
     "ring-rotated": (1, 32, 32, 2048, 128, 128, {"ring": False}),
 }
@@ -391,6 +392,10 @@ HELD = {
                        "f155d0926136fb3a"),
     "minicpm-sparse-16k": ("1aa754956f4ca60b", "23a3f86171426f7b", "60b4a1f96b09a277",
                            "7778ac37a0af3a71"),
+    # the Granite cell's one layer (PR 58): 32 heads of 64 over 8 K/V heads
+    # at the config's own scale of 1/64, read as the tree of that PR reads it
+    "granite-gqa-8k": ("4a31791c23ec669b", "99237a0c02ae0147", "78c0c034c0c14aff",
+                       "7864cd6c7190f85e"),
     "ring-diagonal": ("4f37ad660cf9ba13", "deddd842bc479ee4", "07e7512d9d037b65",
                       "39c4053850660d16"),
     "ring-rotated": ("5bdfd8d4696da1c0", "444f8125842bae66", "b7924c31d3e2cda8",

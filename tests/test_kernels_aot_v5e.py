@@ -956,3 +956,101 @@ def test_xing4s_step_calls_each_hyper_connection_kernel_once_a_connection(v5e):
         assert len(sites) == calls, (entry, len(sites))
         for site in sites:
             assert scope in locations[site], (entry, locations[site])
+
+
+# The Granite cell's kernels at the benchmark's real size (b1 x s8192): the
+# Mamba-2 scan at 64 heads of 64 over a state of 128, u as [1, 8192, 64 x 64]
+# (what the kernels take of ``chunk_ssd``'s [1, 8192, 64, 64]; nothing is
+# transposed), B and C one [1, 8192, 128] pair, the steps as rows [1, 8, 8,
+# 8192]; and at an odd number of groups. Each reads its own name as a
+# profile's reader names it.
+def _kernels(text):
+    from benchmarks.lib import trace
+
+    return [trace.kernel_name(line) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("t,h", [(8192, 64), (1024, 24)])
+def test_ssd_kernels_compile_for_v5e(v5e, t, h):
+    b, p, n, groups = 1, 64, 128, h // 8
+    rows, shared = ((b, t, h * p), jnp.bfloat16), ((b, t, n), jnp.bfloat16)
+    operands = (rows, ((b, groups, 8, t), jnp.float32), ((groups, 1, 128), jnp.float32),
+                ((groups, 4, 128), jnp.float32), shared, shared)
+    _compile_for(v5e, lambda *a: kda._ssd_forward_pallas(*a, states=False), *operands)
+    forward = _compile_for(
+        v5e, lambda *a: kda._ssd_forward_pallas(*a, states=True), *operands)
+    states = (b, t // kda.SSD_CHUNK, groups, 4, n, 2 * p)
+    assert "f32[%s]" % ",".join(map(str, states)) in forward  # float32, a chunk's first
+    backward = _compile_for(
+        v5e, kda._ssd_backward_pallas, *operands, (states, jnp.float32), rows)
+    assert (_kernels(forward), _kernels(backward)) == (
+        ["_ssd_fwd_kernel"], ["_ssd_bwd_kernel"])
+
+
+def test_the_biased_convolution_compiles_for_v5e_at_4352_channels(v5e, monkeypatch):
+    """x, B and C of a Granite layer together: 34 vregs of lanes, which no
+    512 and no 384 divide, in blocks of 256; the bias an operand of both
+    kernels and its cotangent an output of the backward one."""
+    monkeypatch.setattr(kda._attention, "_on_tpu", lambda: True)
+    t, channels = 8192, 4352
+    x, w, b = ((1, t, channels), jnp.float32), ((4, channels), jnp.float32), (
+        (1, channels), jnp.float32)
+    blocks = kda._conv_blocks(jax.ShapeDtypeStruct(*x), jax.ShapeDtypeStruct(*w))
+    assert blocks == (512, 256, 64, False)
+    forward = _compile_for(
+        v5e, lambda x, w, b: kda._conv_forward(x, w, jnp.dtype(jnp.bfloat16), blocks, b),
+        x, w, b)
+    backward = _compile_for(
+        v5e, lambda x, w, dy, b: kda._conv_backward(x, w, dy, blocks, b),
+        x, w, (x[0], jnp.bfloat16), b)
+    assert (_kernels(forward), _kernels(backward)) == (
+        ["_conv_fwd_kernel"], ["_conv_bwd_kernel"])
+    assert f"f32[1,{channels}]" in backward  # the bias's cotangent
+
+
+def test_causal_flash_at_32_heads_of_64_compiles_for_v5e(v5e):
+    """The Granite cell's one attention layer: K and V repeated from 8 to 32
+    heads of 64 lanes, b1 x s8192, at the blocks ``flash_attention`` runs."""
+    qkv = ((32, 8192, 64), jnp.bfloat16)
+    _compile_for(v5e, _causal_fwd(1024), qkv, qkv, qkv)
+    _compile_for(
+        v5e, _causal_bwd(1024), qkv, qkv, qkv, qkv, ((32, 8192), jnp.float32), qkv)
+
+
+@pytest.fixture(scope="module")
+def granites_step(v5e):
+    return _lowered_step(v5e, "granite-4-h-micro-l10.pretrain-8k")
+
+
+def test_granites_step_holds_its_kernels_and_its_replay_runs_no_scan(granites_step):
+    """The Granite cell's step at the benchmark's real size (b1 x s8192, ten
+    layers at the published widths): every kernel its configuration states; a
+    mamba layer is one ``_ssd_fwd_kernel`` and one ``_ssd_bwd_kernel`` in the
+    whole step (the remat policy keeps ``ssd_y`` and ``ssd_states``), the
+    states [1, 32, 8, 4, 128, 128] float32 written nine times and read nine
+    times; B and C reach the kernels as [1, 8192, 128], never a head's copy;
+    the one attention layer's three causal kernels; no other scan's kernel."""
+    import re
+
+    from benchmarks.lib import cells, checks
+
+    cell, text = granites_step
+    stated = cells.stated_kernels(cell)
+    counts = checks.count_pallas_kernels(text, stated)
+    assert checks.holds_stated_kernels(counts, stated), (counts, stated)
+    assert (counts["_ssd_fwd_kernel"], counts["_ssd_bwd_kernel"]) == (9, 9)
+    assert (counts["_fwd_kernel"], counts["_bwd_dkv_kernel"], counts["_bwd_dq_kernel"]) == (1, 1, 1)
+    others = ("_kda_fwd_kernel", "_gdn_fwd_kernel", "_lightning_fwd_kernel",
+              "_sparse_fwd_kernel", "_fwd_window_kernel")
+    assert not any(checks.count_pallas_kernels(text, others).values())
+    states = f"tensor<1x{8192 // kda.SSD_CHUNK}x8x4x128x128xf32>"
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sum(f"{states})" in line for line in calls) == 9
+    assert sum(f"{states}," in line for line in calls) == 9
+    assert "tensor<1x8192x64x128x" not in text  # no B or C a head
+    # the convolution by the kernels, forward, replayed and backward, a layer
+    entries = {entry: len(re.findall(rf"call @{entry}(?:_\d+)?\(", text))
+               for entry in ("_conv_forward", "_conv_backward")}
+    assert entries == {"_conv_forward": 2 * 9, "_conv_backward": 9}
+    assert "tensor<1x8195x4352xf32>" not in text  # no short_conv fallback

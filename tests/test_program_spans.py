@@ -357,6 +357,37 @@ def sala_paths():
         del os.environ["RAY_TPU_PALLAS_INTERPRET"]
 
 
+@pytest.fixture(scope="module")
+def granite_paths():
+    """Paths of a tiny Granite 4.0-H's compiled train step: a Mamba-2 layer (4
+    heads of 16 over a state of 16, a biased filter) and a NoPE attention
+    layer at a scale of its own, each over the dense MLP, under the three
+    multipliers, the head tied."""
+    from ray_tpu.models.granite_hybrid import (
+        GraniteHybridForCausalLM, granite_hybrid_config,
+    )
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"  # the kernels, as on the chip
+    try:
+        cfg = granite_hybrid_config(
+            layer_types=["mamba", "attention"], num_layers=2,
+            embedding_multiplier=12, residual_multiplier=0.22, logits_scaling=8,
+            attention_multiplier=0.0625, shared_intermediate_size=64,
+            mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16, mamba_d_conv=4,
+            vocab_size=128, hidden_size=32, num_heads=4, num_kv_heads=2, head_dim=8,
+        )
+        model = GraniteHybridForCausalLM(cfg)
+        ids = jnp.zeros((1, 64), jnp.int32)
+        return paths_of(compiled_step(
+            model,
+            lambda p, i, t: chunked_causal_lm_loss(model, p, i, t, chunk_size=32),
+            ids,
+        ))
+    finally:
+        del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+
+
 # ----------------------------------------------------------- in-graph scopes
 
 
@@ -386,6 +417,29 @@ def test_a_sparse_and_lightning_hybrid_carries_its_scopes(sala_paths):
     # select and a sum, and gathers nothing
     loss = [p for p in sala_paths if f"({tracing.LOSS})" in p]
     assert loss and not [p for p in loss if p.endswith("/gather")]
+
+
+def test_a_state_space_hybrid_carries_its_scopes(granite_paths):
+    """What model.mamba_share, model.mamba_conv_share and model.gqa_share
+    select by: /mamba/ with ``conv`` (the filter, its bias and SiLU), ``step``
+    (the softplus) and ``norm`` (the gate and the one norm over every head's
+    channels) inside it; /attn/ with no ``rotary`` and no ``qk_norm``; the
+    multipliers under the names of what they scale."""
+    mamba = [p for p in granite_paths if "/layers_0/mamba/" in p]
+    attn = [p for p in granite_paths if "/layers_1/attn/" in p]
+    assert mamba and attn and not [
+        p for p in granite_paths if "/layers_1/mamba/" in p or "/layers_0/attn/" in p]
+    for name in (tracing.KDA_CONV, tracing.MAMBA_STEP, tracing.MAMBA_NORM):
+        assert any(f"/mamba/{name}/" in p for p in mamba), name
+        assert not [p for p in attn if f"/{name}/" in p], name
+    for name in (tracing.ATTN_ROPE, tracing.QK_NORM, tracing.ATTN_GATE):
+        assert not [p for p in granite_paths if f"/{name}/" in p], name
+    for mixer in (mamba, attn):
+        assert {pass_of(p) for p in mixer} >= {"forward", "backward", "replay"}
+    # the softplus is the step's and the logistic of the gate the norm's
+    assert any(f"/mamba/{tracing.MAMBA_NORM}/" in p and p.endswith("/rsqrt") for p in mamba)
+    assert any(f"/{tracing.EMBED}/mul" in p for p in granite_paths)  # embedding x 12
+    assert any(f"/{tracing.FINAL_NORM}/mul" in p for p in granite_paths)  # / 8
 
 
 def test_hyper_connections_q_latent_and_the_mtp_module_carry_their_scopes(
@@ -635,7 +689,7 @@ def test_expert_matmuls_are_under_experts_forward_and_backward(moe_paths, branch
 # Every family's compiled step, by fixture (and dispatch branch).
 FAMILIES = ("llama_paths", "qk_norm_paths", "tied_paths", "moe_paths:capacity", "moe_paths:gmm",
             "moe_paths:ragged", "kimi_paths", "sarvam_paths", "xing4_paths",
-            "laguna_paths", "solar_paths", "olmo_paths", "sala_paths")
+            "laguna_paths", "solar_paths", "olmo_paths", "sala_paths", "granite_paths")
 # Paths that may hold no name of the program, and why.
 EXEMPT = (
     # _positions' arange, inside the model's __call__ and outside every part:
@@ -688,7 +742,7 @@ LOSS_KINDS = {
     "moe_paths:capacity": "full",
     "moe_paths:gmm": "full", "moe_paths:ragged": "full", "kimi_paths": "chunked",
     "sarvam_paths": "chunked", "laguna_paths": "chunked", "solar_paths": "chunked",
-    "olmo_paths": "chunked", "sala_paths": "chunked",
+    "olmo_paths": "chunked", "sala_paths": "chunked", "granite_paths": "chunked",
     "xing4_paths": "mtp",
 }
 
@@ -881,14 +935,14 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 
 def test_names_emitted_are_exactly_the_list(
     llama_paths, qk_norm_paths, moe_paths, kimi_paths, sarvam_paths,
-    xing4_paths, laguna_paths, solar_paths, olmo_paths, sala_paths, session_lines,
-    actor_lines
+    xing4_paths, laguna_paths, solar_paths, olmo_paths, sala_paths, granite_paths,
+    session_lines, actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + olmo_paths + sala_paths + [
+    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + olmo_paths + sala_paths + granite_paths + [
         p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:  # a scope directly under a transform is in its brackets
         assert any(f"/{name}/" in p or f"({name})/" in p for p in paths), name

@@ -33,7 +33,7 @@ from ray_tpu.models.llama import (
 )
 from ray_tpu.models.mixtral import MixtralConfig, MoELayer
 from ray_tpu.ops.attention import flash_attention, select_blocks
-from ray_tpu.ops.kda import chunk_gdn, chunk_kda, chunk_lightning
+from ray_tpu.ops.kda import chunk_gdn, chunk_kda, chunk_lightning, chunk_ssd
 from ray_tpu.util import tracing
 from remat_jaxpr import forward_matmuls, kernel_calls
 
@@ -152,6 +152,27 @@ class _Lightning(nn.Module):
         return x + nn.Dense(c, use_bias=False, name="o")(o.reshape(b, t, -1))
 
 
+class _SSD(nn.Module):
+    """A Mamba-2 scan between projections, through ``chunk_ssd``: a step a
+    head and token, B and C one pair for every head, a skip a head."""
+    heads: int = 2
+    p: int = 16
+    n: int = 16
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, c = x.shape
+        proj = lambda name, width: nn.Dense(width, use_bias=False, name=name)(x)  # noqa: E731
+        y = chunk_ssd(
+            proj("u", self.heads * self.p).reshape(b, t, self.heads, self.p),
+            jax.nn.softplus(proj("dt", self.heads)),
+            self.param("A_log", nn.initializers.zeros, (self.heads,)),
+            proj("B", self.n), proj("C", self.n),
+            self.param("D", nn.initializers.ones, (self.heads,)),
+        )
+        return x + nn.Dense(c, use_bias=False, name="o")(y.reshape(b, t, -1))
+
+
 class _Sparse(nn.Module):
     """``_Attention`` over the blocks ``select_blocks`` chooses, K and V at
     half of q's heads."""
@@ -233,6 +254,7 @@ CASES = {
     "gdn": ((_GDN, {}), (1, T, 64), {"_gdn_fwd_kernel": (1, 2)}),
     "lightning": ((_Lightning, {}), (1, T, 64), {"_lightning_fwd_kernel": (1, 2)}),
     "sparse": ((_Sparse, {}), (1, T, 64), {"_sparse_fwd_kernel": (1, 2)}),
+    "ssd": ((_SSD, {}), (1, T, 64), {"_ssd_fwd_kernel": (1, 2)}),
     # A layer's second write is its output, which no replay makes.
     "hyper-connections": ((_HyperConnected, {}), (4, 1, T, 128), {
         "_hc_pre_fwd_kernel": (2, 4), "_hc_post_fwd_kernel": (2, 3)}),
@@ -275,11 +297,12 @@ def test_replay_holds_no_forward_kernel(case):
 
 @pytest.mark.parametrize("dropped", [
     "kda_o", "kda_states", "kda_t", "gdn_o", "gdn_states", "gdn_t",
-    "lightning_o", "lightning_states"])
+    "lightning_o", "lightning_states", "ssd_y", "ssd_states"])
 def test_a_kda_layer_needs_each_of_its_three_names_kept(dropped):
     """o, the per-chunk states and the chunks' inverses leave the forward
     kernel together (KDA's, and the scalar-decay kernel's under names of its
-    own; the fixed-decay kernel's o and states, which has no inverse): a
+    own; the fixed-decay kernel's o and states and the Mamba-2 kernel's y and
+    states, which have no inverse): a
     policy that lacks any one of them runs the forward kernel in the replay to
     remake it, whatever else it holds."""
     case = dropped.partition("_")[0]
