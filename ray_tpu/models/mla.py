@@ -25,6 +25,7 @@ runs its MLA layers without any of them, ``mla_use_nope``):
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional
@@ -34,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import flash_attention
+from ..ops.rotary import latent_qkv, latent_road
 from ..util import tracing
 from .llama import RMSNorm, _rope, rope_frequencies, weight_init
 from .mixtral import MixtralConfig
@@ -111,6 +113,17 @@ def yarn_frequencies(dim: int, theta: float, scaling: YarnScaling) -> np.ndarray
     return (plain / scaling.factor * ramp + plain * (1 - ramp)).astype(np.float32)
 
 
+class _NormWeight(nn.Module):
+    """``RMSNorm``'s weight under ``RMSNorm``'s name, for the road that norms
+    inside the kernel: the parameter tree is one whichever road runs."""
+    width: int
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.width,), self.param_dtype)
+
+
 class MLAMixer(nn.Module):
     cfg: MLAConfig
 
@@ -145,19 +158,10 @@ class MLAMixer(nn.Module):
                 latent[..., :rank]
             )
             kv = heads(nope + dv, "kv_b_proj")(c)  # [B, T, H, 256]: k nope | v
-            # The 64-wide key part is one for all heads.
-            k_pe = jnp.broadcast_to(
-                latent[..., None, rank:], (*kv.shape[:3], pe)
-            )
-            k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
-            v = kv[..., nope:]
-        if cfg.qk_head_norm:
-            with tracing.scope(tracing.QK_NORM):
-                q = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="q_norm")(q)
-                k = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="k_norm")(k)
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        norms, turns = cfg.qk_head_norm, cfg.mla_rope
         sm_scale = (nope + pe) ** -0.5
-        if cfg.mla_rope:
+        freqs = None
+        if turns:
             with tracing.scope(tracing.MLA_ROPE):
                 scaling = cfg.rope_scaling
                 if scaling is None:
@@ -167,8 +171,45 @@ class MLAMixer(nn.Module):
                         yarn_frequencies(pe, cfg.rope_theta, scaling)
                     )
                     sm_scale *= yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
-                q = _rope(q, positions, freqs)
-                k = _rope(k, positions, freqs)
+        # Which road follows from what the layer is and what it can see
+        # (``ops/rotary.py`` ``latent_road``): one Pallas pass each for q and
+        # for k where a head is 128 | 64 lanes, the lines below elsewhere.
+        heads_first = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
+        B, T = x.shape[:2]
+        if latent_road((B, H, T, nope + pe), (B, H, T, nope + dv), pe,
+                       norms=norms, turns=turns) == "kernel":
+            weights = (None, None)
+            if norms:
+                with tracing.scope(tracing.QK_NORM):
+                    weights = tuple(
+                        _NormWeight(nope + pe, cfg.param_dtype, name=name)()
+                        for name in ("q_norm", "k_norm"))
+            passes = functools.partial(
+                latent_qkv, heads_first(q), heads_first(kv), latent[..., rank:],
+                positions, freqs, *weights, cfg.rms_eps)
+            if turns:
+                with tracing.scope(tracing.MLA_ROPE):
+                    q, k, v = passes()
+            else:
+                with tracing.scope(tracing.QK_NORM):
+                    q, k, v = passes()
+        else:
+            with tracing.scope(tracing.MLA_LATENT):
+                # The 64-wide key part is one for all heads.
+                k_pe = jnp.broadcast_to(
+                    latent[..., None, rank:], (*kv.shape[:3], pe)
+                )
+                k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+                v = kv[..., nope:]
+            if norms:
+                with tracing.scope(tracing.QK_NORM):
+                    q = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="q_norm")(q)
+                    k = RMSNorm(cfg.rms_eps, cfg.param_dtype, name="k_norm")(k)
+            q, k, v = (heads_first(t) for t in (q, k, v))
+            if turns:
+                with tracing.scope(tracing.MLA_ROPE):
+                    q = _rope(q, positions, freqs)
+                    k = _rope(k, positions, freqs)
         o = flash_attention(q, k, v, causal=True, sm_scale=sm_scale)
         return nn.DenseGeneral(
             cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,

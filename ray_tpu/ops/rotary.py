@@ -203,3 +203,432 @@ def rotate(x, positions, freqs, *, leading: bool = False, amplitude: float = 1.0
     from ..models.llama import _rope  # it imports this package
 
     return _rope(x, positions, freqs, leading=leading, amplitude=amplitude)
+
+
+# Latent attention's q and k (``models/mla.py``), each from the projection's
+# output to the array ``flash_attention`` reads in one pass, and each
+# cotangent back in one. A head is ``nope`` lanes that never turn (whole
+# vregs) and then ``pe`` lanes that may (64: half a vreg); what the mixer did
+# in XLA around ``_rope`` happens in registers, in its order:
+#
+# - the RMSNorm over a head's nope + pe channels with a learned weight
+#   (``cfg.qk_head_norm``; ``eps`` is None where the layer has none), float32
+#   from the projection's rounding to the one rounding at the end;
+# - ``_rope``'s rotation of the pe lanes (``cfg.mla_rope``; ``freqs`` is None
+#   where nothing turns) against ``_tables``' float32 tables for a whole
+#   64-wide head: channel i meets channel i + 32 by a [64, 64] matmul of 0s
+#   and 1s on the values as they lie (``_swapped``: the MXU is idle here and
+#   the XLU is what a pass of this kind waits for; PERF.md §6, PR 59);
+# - k's assembly: a head's nope lanes are the first of ``kv_b_proj``'s
+#   output [B, H, T, nope + dv] (a block reads those lanes alone), its pe
+#   lanes the one shared part [B, T, pe] read once a row block (the heads are
+#   the grid's innermost axis), normed by the head's own rsqrt: no
+#   ``broadcast_to`` and no ``concatenate`` is made in HBM. v's lanes leave by
+#   the same pass, so that the pass back hands ``kv_b_proj``'s backward
+#   matmuls one whole array and XLA neither slices nor pads.
+#
+# The pass back turns the cotangent back (the sines' sign turned), then the
+# norm's transpose from the projection's output read again (dn = g w; dx =
+# r (dn - n mean(dn n)) with n the normed value before its weight), the
+# weights' gradients as a grid step's partial sums [tile, width] that XLA
+# adds up, and k's shared lanes' cotangent summed over the heads in the
+# output block that stands while a row block's heads pass.
+
+
+# The lanes of a head that may turn: half a vreg, which is what the kernels
+# below are written for (``latent_road`` sends any other width to XLA).
+_PE = 64
+
+
+class _Fuse(NamedTuple):
+    """A latent call's static part. ``eps``: the norm's, None for no norm.
+    ``turns``: the pe lanes rotate. ``rows``, ``heads``, ``interpret``: as
+    ``_Turn``'s."""
+    eps: Optional[float]
+    turns: bool
+    rows: int
+    heads: int
+    interpret: bool
+
+
+def latent_road(q_shape, kv_shape, pe: int, *, norms: bool, turns: bool) -> str:
+    """"kernel" or "xla": the road ``latent_qkv`` takes for q [B, H, T,
+    nope + pe] and kv [B, H, T, nope + dv] of a layer that ``norms`` a head,
+    ``turns`` its pe lanes, both or neither, on this platform and under the
+    ambient mesh. Nothing runs."""
+    nope = q_shape[3] - pe
+    fits = (
+        (_attention._on_tpu() or _attention._interpret())
+        and (norms or turns)
+        and pe == _PE and nope > 0 and nope % 128 == 0 and kv_shape[3] == 2 * nope
+        and _blocks(q_shape) is not None
+        and all(logical_axis_shards(axis) == 1 for axis in ("batch", "seq", "heads"))
+    )
+    return "kernel" if fits else "xla"
+
+
+def _swap_matrix(dtype):
+    """[64, 64] of 0 and 1 in ``dtype``: x @ it is x with its two halves of
+    32 lanes changed over."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (_PE, _PE), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (_PE, _PE), 1)
+    return ((i + _PE // 2) % _PE == j).astype(dtype)
+
+
+def _swapped(raw, swap):
+    """raw [tile, 64], as it lies in its own dtype, with its two halves
+    changed over, in float32: a matmul against ``_swap_matrix``, which is
+    exact on bfloat16 values in one pass of the MXU, where a lane rotation
+    of half a vreg is a concatenation and a roll of the XLU a head (the
+    norm's rsqrt is a row's scalar and its weight a lane's, so they come
+    after it)."""
+    exact = jax.lax.Precision.HIGHEST if raw.dtype == F32 else None
+    return jnp.dot(raw, swap, precision=exact, preferred_element_type=F32)
+
+
+def _row_mean(x, width):
+    return jnp.sum(x, -1, keepdims=True) / width
+
+
+def _split_refs(refs, fuse: _Fuse, n_in: int):
+    """(the n_in operands, the weight's ref or None, the tables' or None, the
+    outputs) of a latent kernel's refs."""
+    refs = list(refs)
+    operands, refs = refs[:n_in], refs[n_in:]
+    w_ref = refs.pop(0) if fuse.eps is not None else None
+    tab_ref = refs.pop(0) if fuse.turns else None
+    return operands, w_ref, tab_ref, refs
+
+
+def _tiles(ref, tile, body, carry=None):
+    """``body(at, carry)`` over a block's tiles of rows."""
+    return jax.lax.fori_loop(
+        0, ref.shape[-2] // tile,
+        lambda i, c: body(pl.ds(pl.multiple_of(i * tile, tile), tile), c), carry)
+
+
+def _turn(p, raw, tabs, swap, scale=None):
+    """p [tile, 64] float32 turned against a tile of the tables (cos, sin).
+    ``raw`` is what p was made of, before any scaling by a row's scalar or a
+    lane's weight: its swapped lanes are times ``scale`` instead."""
+    other = _swapped(raw, swap)
+    return p * tabs[0] + (other if scale is None else other * scale) * tabs[1]
+
+
+def _tables_tile(tab_ref, at):
+    return None if tab_ref is None else (tab_ref[0, 0, at, :], tab_ref[1, 0, at, :])
+
+
+def _latent_q_kernel(*refs, fuse, tile):
+    (x_ref,), w_ref, tab_ref, (o_ref,) = _split_refs(refs, fuse, 1)
+    width = x_ref.shape[3]
+    nope = width - _PE
+    swap = _swap_matrix(x_ref.dtype)
+
+    def one(at, carry):
+        tabs = _tables_tile(tab_ref, at)
+        for h in range(x_ref.shape[1]):
+            raw = x_ref[0, h, at, nope:]
+            if fuse.eps is None:
+                o_ref[0, h, at, :nope] = x_ref[0, h, at, :nope]
+                o_ref[0, h, at, nope:] = _turn(
+                    raw.astype(F32), raw, tabs, swap).astype(o_ref.dtype)
+                continue
+            x = x_ref[0, h, at, :].astype(F32)
+            r = jax.lax.rsqrt(_row_mean(x * x, width) + fuse.eps)
+            x = x * r * w_ref[0:1, :]
+            if not fuse.turns:
+                o_ref[0, h, at, :] = x.astype(o_ref.dtype)
+                continue
+            o_ref[0, h, at, :nope] = x[:, :nope].astype(o_ref.dtype)
+            o_ref[0, h, at, nope:] = _turn(
+                x[:, nope:], raw, tabs, swap, r * w_ref[1:2, nope:]).astype(o_ref.dtype)
+        return carry
+
+    _tiles(x_ref, tile, one)
+
+
+def _latent_k_kernel(*refs, fuse, tile):
+    (a_ref, v_ref, p_ref), w_ref, tab_ref, (k_ref, vo_ref) = _split_refs(refs, fuse, 3)
+    nope = a_ref.shape[3]
+    width = nope + _PE
+    swap = _swap_matrix(p_ref.dtype)
+
+    def one(at, carry):
+        # The shared lanes' weight and rotation once a tile: a head's rsqrt is
+        # a row's scalar, so it comes after them.
+        raw = p_ref[0, at, :]
+        p = raw.astype(F32)
+        if fuse.eps is not None:
+            p_squares = jnp.sum(p * p, -1, keepdims=True)
+            p = p * w_ref[0:1, nope:]
+        if fuse.turns:
+            p = _turn(p, raw, _tables_tile(tab_ref, at), swap,
+                      None if fuse.eps is None else w_ref[1:2, nope:])
+        shared = p.astype(k_ref.dtype)
+        for h in range(a_ref.shape[1]):
+            if fuse.eps is None:
+                k_ref[0, h, at, :nope] = a_ref[0, h, at, :]
+                k_ref[0, h, at, nope:] = shared
+                continue
+            a = a_ref[0, h, at, :].astype(F32)
+            r = jax.lax.rsqrt(
+                (jnp.sum(a * a, -1, keepdims=True) + p_squares) / width + fuse.eps)
+            k_ref[0, h, at, :nope] = (a * r * w_ref[0:1, :nope]).astype(k_ref.dtype)
+            k_ref[0, h, at, nope:] = (p * r).astype(k_ref.dtype)
+        return carry
+
+    _tiles(a_ref, tile, one)
+    vo_ref[...] = v_ref[...]
+
+
+def _norm_back(g, n, r, w_ref, width):
+    """The norm's transpose for one head's tile, [tile, width] each: g the
+    cotangent of the normed and weighted value, n the normed value before its
+    weight. (dx, the weight's gradient before its sum over rows)."""
+    dn = g * w_ref[0:1, :]
+    return r * (dn - n * _row_mean(dn * n, width)), g * n
+
+
+def _no_sums(fuse, tile, width):
+    return None if fuse.eps is None else jnp.zeros((tile, width), F32)
+
+
+def _latent_q_back_kernel(*refs, fuse, tile):
+    # The projection's output is read again only for the norm's transpose.
+    (g_ref, *x_ref), w_ref, tab_ref, (dx_ref, *dw_ref) = _split_refs(
+        refs, fuse, 1 if fuse.eps is None else 2)
+    width = g_ref.shape[3]
+    nope = width - _PE
+    swap = _swap_matrix(g_ref.dtype)
+
+    def one(at, sums):
+        tabs = _tables_tile(tab_ref, at)
+        for h in range(g_ref.shape[1]):
+            raw = g_ref[0, h, at, nope:]
+            gp = raw.astype(F32)
+            if fuse.turns:
+                gp = _turn(gp, raw, tabs, swap)
+            if fuse.eps is None:
+                dx_ref[0, h, at, :nope] = g_ref[0, h, at, :nope]
+                dx_ref[0, h, at, nope:] = gp.astype(dx_ref.dtype)
+                continue
+            g = jnp.concatenate([g_ref[0, h, at, :nope].astype(F32), gp], -1)
+            x = x_ref[0][0, h, at, :].astype(F32)
+            r = jax.lax.rsqrt(_row_mean(x * x, width) + fuse.eps)
+            dx, dw = _norm_back(g, x * r, r, w_ref, width)
+            dx_ref[0, h, at, :] = dx.astype(dx_ref.dtype)
+            sums = sums + dw
+        return sums
+
+    sums = _tiles(g_ref, tile, one, _no_sums(fuse, tile, width))
+    if fuse.eps is not None:
+        dw_ref[0][0, 0, 0] = sums
+
+
+def _latent_k_back_kernel(*refs, fuse, tile):
+    (g_ref, gv_ref, *read_again), w_ref, tab_ref, (dkv_ref, dp_ref, *dw_ref) = _split_refs(
+        refs, fuse, 2 if fuse.eps is None else 4)
+    nope = gv_ref.shape[3]
+    width = nope + _PE
+    swap = _swap_matrix(g_ref.dtype)
+
+    # The shared part's block stands while a row block's heads pass.
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dp_ref[...] = jnp.zeros(dp_ref.shape, F32)
+
+    def one(at, sums):
+        tabs = _tables_tile(tab_ref, at)
+        dp = jnp.zeros((tile, _PE), F32)
+        if fuse.eps is not None:
+            a_ref, p_ref = read_again
+            p = p_ref[0, at, :].astype(F32)
+            p_squares = jnp.sum(p * p, -1, keepdims=True)
+        for h in range(g_ref.shape[1]):
+            raw = g_ref[0, h, at, nope:]
+            gp = raw.astype(F32)
+            if fuse.turns:
+                gp = _turn(gp, raw, tabs, swap)
+            if fuse.eps is None:
+                dkv_ref[0, h, at, :nope] = g_ref[0, h, at, :nope]
+                dp = dp + gp
+                continue
+            g = jnp.concatenate([g_ref[0, h, at, :nope].astype(F32), gp], -1)
+            a = a_ref[0, h, at, :].astype(F32)
+            r = jax.lax.rsqrt(
+                (jnp.sum(a * a, -1, keepdims=True) + p_squares) / width + fuse.eps)
+            dx, dw = _norm_back(g, jnp.concatenate([a, p], -1) * r, r, w_ref, width)
+            dkv_ref[0, h, at, :nope] = dx[:, :nope].astype(dkv_ref.dtype)
+            dp = dp + dx[:, nope:]
+            sums = sums + dw
+        dp_ref[0, at, :] += dp
+        return sums
+
+    sums = _tiles(g_ref, tile, one, _no_sums(fuse, tile, width))
+    if fuse.eps is not None:
+        dw_ref[0][0, 0, 0] = sums
+    dkv_ref[:, :, :, nope:] = gv_ref[...]
+
+
+def _latent_call(kernel, fuse: _Fuse, shape, operands, w, positions, freqs, back, outs):
+    """One latent kernel over the grid (batch, blocks of rows, a row block's
+    heads). ``operands`` and ``outs`` are (array or shape, its block, its
+    index map) and ``shape`` is q's or k's [B, H, T, width]."""
+    b, h, t, width = shape
+    rows, heads = fuse.rows, fuse.heads
+    tile = min(rows, _TILE)
+    arrays = [x for x, _, _ in operands]
+    specs = [pl.BlockSpec(block, at) for _, block, at in operands]
+    if fuse.eps is not None:
+        # The weight, and the weight as the swapped lanes meet it.
+        arrays.append(jnp.stack([w, jnp.concatenate([w[:-_PE], jnp.roll(w[-_PE:], _PE // 2)])]))
+        specs.append(pl.BlockSpec((2, width), lambda i, j, k: (0, 0)))
+    if fuse.turns:
+        tables, _ = _tables(positions, freqs, _PE,
+                            _Turn(False, 1.0, rows, heads, fuse.interpret), back)
+        arrays.append(tables)
+        specs.append(pl.BlockSpec((2, 1, rows, _PE), lambda i, j, k: (0, i, j, 0)))
+    if back and fuse.eps is not None:
+        steps = (b, t // rows, h // heads)
+        outs = [*outs, (jax.ShapeDtypeStruct((*steps, tile, width), F32),
+                        (1, 1, 1, tile, width), lambda i, j, k: (i, j, k, 0, 0))]
+    moved = sum(x.size * x.dtype.itemsize for x in arrays) + sum(
+        o.size * o.dtype.itemsize for o, _, _ in outs)
+    return pl.pallas_call(
+        functools.partial(kernel, fuse=fuse, tile=tile),
+        grid=(b, t // rows, h // heads),
+        in_specs=specs,
+        out_specs=[pl.BlockSpec(block, at) for _, block, at in outs],
+        out_shape=[o for o, _, _ in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=(12 if back else 6) * b * h * t * width,
+            transcendentals=b * h * t if fuse.eps is not None else 0,
+            bytes_accessed=moved),
+        interpret=fuse.interpret,
+    )(*arrays)
+
+
+def _head_blocks(fuse: _Fuse, *lanes):
+    """(block, index map) of [B, H, T, .] arrays read ``lanes`` = (width,
+    which block of that width along the lanes) at a time."""
+    return [((1, fuse.heads, fuse.rows, width), lambda i, j, k, n=n: (i, k, j, n))
+            for width, n in lanes]
+
+
+def _row_block(fuse: _Fuse):
+    """(block, index map) of the shared part [B, T, 64]."""
+    return (1, fuse.rows, _PE), lambda i, j, k: (i, j, 0)
+
+
+# One jitted entry a kernel, as ``_turned``: a step's text holds a Mosaic body
+# once a (shape, kind of layer) and not once a layer.
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _latent_q_forward(x, w, positions, freqs, fuse: _Fuse):
+    (whole,) = _head_blocks(fuse, (x.shape[3], 0))
+    (q,) = _latent_call(_latent_q_kernel, fuse, x.shape, [(x, *whole)], w, positions, freqs,
+                        False, [(jax.ShapeDtypeStruct(x.shape, x.dtype), *whole)])
+    return q
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _latent_q_backward(g, x, w, positions, freqs, fuse: _Fuse):
+    """(dx, dw or None). ``x``: the projection's output where the layer
+    norms, None elsewhere."""
+    (whole,) = _head_blocks(fuse, (g.shape[3], 0))
+    read_again = [] if x is None else [(x, *whole)]
+    dx, *dw = _latent_call(_latent_q_back_kernel, fuse, g.shape, [(g, *whole), *read_again], w,
+                           positions, freqs, True,
+                           [(jax.ShapeDtypeStruct(g.shape, g.dtype), *whole)])
+    return dx, dw[0].sum((0, 1, 2, 3)) if dw else None
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _latent_k_forward(kv, shared, w, positions, freqs, fuse: _Fuse):
+    """(k [B, H, T, nope + 64], v [B, H, T, nope]) of kv [B, H, T, 2 nope]
+    and the shared part [B, T, 64]."""
+    b, h, t, nope = *kv.shape[:3], kv.shape[3] // 2
+    first, second, whole = _head_blocks(fuse, (nope, 0), (nope, 1), (nope + _PE, 0))
+    return _latent_call(
+        _latent_k_kernel, fuse, (b, h, t, nope + _PE),
+        [(kv, *first), (kv, *second), (shared, *_row_block(fuse))], w, positions, freqs, False,
+        [(jax.ShapeDtypeStruct((b, h, t, nope + _PE), kv.dtype), *whole),
+         (jax.ShapeDtypeStruct((b, h, t, nope), kv.dtype), *first)])
+
+
+@functools.partial(jax.jit, static_argnums=(7,))
+def _latent_k_backward(g, gv, kv, shared, w, positions, freqs, fuse: _Fuse):
+    """(dkv, the shared part's cotangent, dw or None). ``kv``, ``shared``:
+    the forward's operands where the layer norms, None elsewhere."""
+    b, h, t, nope = gv.shape
+    first, whole, both = _head_blocks(fuse, (nope, 0), (nope + _PE, 0), (2 * nope, 0))
+    read_again = [] if kv is None else [(kv, *first), (shared, *_row_block(fuse))]
+    dkv, dshared, *dw = _latent_call(
+        _latent_k_back_kernel, fuse, g.shape, [(g, *whole), (gv, *first), *read_again], w,
+        positions, freqs, True,
+        [(jax.ShapeDtypeStruct((b, h, t, 2 * nope), g.dtype), *both),
+         (jax.ShapeDtypeStruct((b, t, _PE), F32), *_row_block(fuse))])
+    return dkv, dshared.astype(g.dtype), dw[0].sum((0, 1, 2, 3)) if dw else None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _latent_q_pallas(x, w, positions, freqs, fuse):
+    return _latent_q_forward(x, w, positions, freqs, fuse)
+
+
+def _latent_q_fwd(x, w, positions, freqs, fuse):
+    # Kept for the pass back: the projection's output where the norm's
+    # transpose reads it again, nothing of it elsewhere.
+    kept = None if fuse.eps is None else x
+    return _latent_q_forward(x, w, positions, freqs, fuse), (kept, w, positions, freqs)
+
+
+def _latent_q_bwd(fuse, residuals, g):
+    dx, dw = _latent_q_backward(g, *residuals, fuse)
+    return dx, dw, None, None
+
+
+_latent_q_pallas.defvjp(_latent_q_fwd, _latent_q_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _latent_k_pallas(kv, shared, w, positions, freqs, fuse):
+    return _latent_k_forward(kv, shared, w, positions, freqs, fuse)
+
+
+def _latent_k_fwd(kv, shared, w, positions, freqs, fuse):
+    kept = (None, None) if fuse.eps is None else (kv, shared)
+    return (_latent_k_forward(kv, shared, w, positions, freqs, fuse),
+            (*kept, w, positions, freqs))
+
+
+def _latent_k_bwd(fuse, residuals, cotangents):
+    dkv, dshared, dw = _latent_k_backward(*cotangents, *residuals, fuse)
+    return dkv, dshared, dw, None, None
+
+
+_latent_k_pallas.defvjp(_latent_k_fwd, _latent_k_bwd)
+
+
+def latent_qkv(q, kv, shared, positions, freqs, q_weight, k_weight, eps):
+    """(q, k, v) as ``flash_attention`` takes them, of ``models/mla.py``'s
+    projections heads first: q [B, H, T, nope + 64], kv [B, H, T, 2 nope]
+    (k's nope lanes | v) and the one shared key part [B, T, 64]. ``freqs``:
+    ``_rope``'s table for the 64 lanes, None where nothing turns.
+    ``q_weight``, ``k_weight``: the per-head norms' [nope + 64], None (both)
+    where the layer has none; ``eps`` theirs. For a call whose
+    ``latent_road`` is "kernel"."""
+    norms = q_weight is not None
+    fuse = _Fuse(float(eps) if norms else None, freqs is not None,
+                 *_blocks(q.shape), _attention._interpret())
+    if fuse.turns:
+        freqs = jnp.asarray(freqs, F32)
+    if norms:
+        q_weight, k_weight = q_weight.astype(F32), k_weight.astype(F32)
+    k, v = _latent_k_pallas(kv, shared, k_weight, positions, freqs, fuse)
+    return _latent_q_pallas(q, q_weight, positions, freqs, fuse), k, v

@@ -60,7 +60,7 @@ MOE_DISPATCH = "dispatch"  # positions, slot map, gather into the expert buffer
 MOE_EXPERTS = "experts"  # the three expert matmuls and the activation
 MOE_COMBINE = "combine"  # gather back, gate scaling, the reduction over k
 MOE_LAYOUT = "layout"  # inside dispatch, "gmm" only: sort, tile layout, inverse map
-QK_NORM = "qk_norm"  # RMSNorm of q and k: the whole projections in attn (cfg.qk_norm), a head's channels inside mla (cfg.qk_head_norm), inside sparse and inside lightning
+QK_NORM = "qk_norm"  # RMSNorm of q and k: the whole projections in attn (cfg.qk_norm), inside sparse and inside lightning; a head's channels inside mla (cfg.qk_head_norm) on the XLA road, and on the kernel road (ops/rotary.py latent_road) the weights' casts alone, or the latent kernels where the layer norms and turns nothing
 MOE_SHARED = "shared"  # inside moe: the shared expert every token passes
 # The mixers' flax names, which reach op_name as the attention's "attn" does.
 KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer; Solar-Open2's KDA layers too)
@@ -78,7 +78,7 @@ KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's
 # (No "out_norm": o's per-head RMSNorm and output gate left XLA for the scan's
 # kernels, and a scope that no operation carries is not in this list.)
 MLA_LATENT = "latent"  # inside mla: down-projection, norm, up-projection of K/V
-MLA_ROPE = "rope"  # inside mla (cfg.mla_rope): the frequency table, the rotation of q's and k's pe parts, the slices and concatenations around it
+MLA_ROPE = "rope"  # inside mla (cfg.mla_rope): the frequency table and, on the XLA road, the rotation of q's and k's pe parts with the slices and concatenations around it; on the kernel road the four latent kernels (the per-head norm, the rotation, k's assembly, v's slice, and their passes back), the tables' fusion and the sums of the weights' partial gradients
 MLA_Q_LATENT = "q_latent"  # inside mla (cfg.q_lora_rank): q's down-projection, its norm, the up-projection to the heads
 ATTN = "attn"  # the softmax-attention mixer (models/llama.py Attention); in a model whose layers differ, the full-attention layers' (Laguna's beside swa, Solar-Open2's beside kda)
 SWA = "swa"  # the same module as a sliding-window layer's mixer (models/laguna.py): its own head count and rotation, flash_attention under a window

@@ -408,6 +408,82 @@ def test_rotary_kernel_and_its_pass_back_compile_for_v5e(v5e, heads, half, leadi
         assert " copy(" not in text and " transpose(" not in text
 
 
+# Latent attention's q and k from the projections to the flash kernels in one
+# pass each and the two passes back (``ops/rotary.py`` ``latent_qkv``) at the
+# cells' real sizes (b1 x s4096): sarvam's 64 heads under the per-head norm and
+# the rotation, Xing4's 32 under the rotation alone, and the norm alone (what
+# ``benchmarks/tools/wrong_sarvam.py``'s program without the rotation runs); q [1, H, 4096, 128 | 64],
+# kv [1, H, 4096, 128 | 128], the shared key part [1, 4096, 64]. Each kernel
+# reads its own name as a profile's reader names it.
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("heads,eps,turns", [(64, 1e-6, True), (32, None, True), (64, 1e-6, False)],
+                         ids=["sarvam", "xing4", "the_norm_alone"])
+def test_latent_kernels_compile_for_v5e(v5e, heads, eps, turns, direction):
+    from benchmarks.lib import trace
+
+    t = 4096
+    q, kv, v = ((1, heads, t, lanes) for lanes in (192, 256, 128))
+    fuse = rotary._Fuse(eps, turns, *rotary._blocks(q), False)
+    assert fuse[2:4] == (rotary.ROWS, rotary.HEADS)
+    bf16 = jnp.bfloat16
+    # positions and, where the layer turns, the table (None for a layer that does not)
+    table = [((1, t), jnp.int32), ((32,), jnp.float32)][:1 + turns]
+    weight = [((192,), jnp.float32)] if eps else []
+    shared = ((1, t, 64), bf16)
+    if direction == "forward":
+        entries = {"_latent_q_kernel": (rotary._latent_q_forward, [(q, bf16)], []),
+                   "_latent_k_kernel": (rotary._latent_k_forward, [(kv, bf16), shared], [])}
+    else:  # cotangents, then what the norm's transpose reads again
+        entries = {
+            "_latent_q_back_kernel": (rotary._latent_q_backward, [(q, bf16)], [(q, bf16)]),
+            "_latent_k_back_kernel": (rotary._latent_k_backward, [(q, bf16), (v, bf16)],
+                                      [(kv, bf16), shared])}
+    for kernel, (entry, arrays, read_again) in entries.items():
+        # an entry takes None for what a layer without the norm has not
+        norms = [*read_again, *weight] if eps else [None] * (len(read_again) + 1)
+        given = [a for a in norms if a is not None]
+
+        def call(*a, entry=entry, n=len(arrays), norms=norms, given=given):
+            last = a[n + len(given):] if turns else (a[-1], None)
+            return entry(*a[:n], *(a[n:n + len(given)] or norms), *last, fuse)
+
+        text = _compile_for(v5e, call, *arrays, *given, *table)
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert [trace.kernel_name(line) for line in calls] == [kernel]
+
+
+@pytest.fixture(scope="module")
+def sarvams_step(v5e):
+    return _lowered_step(v5e, "sarvam-105b-l5.pretrain-4k")
+
+
+def test_sarvams_step_takes_q_and_k_to_the_flash_kernels_by_the_latent_kernels(sarvams_step):
+    """Five layers: a forward body and the replay's copy of it behind each
+    forward entry, one behind each backward entry; q's and k's pass a layer
+    forward, replayed and backward. Nothing of the XLA road is left: no
+    [., 64, 4096, 192] array is concatenated (``_rope``'s two and k's
+    assembly were 30 in the parent's text), the shared key part is broadcast
+    to no [1, 4096, 64, 64], and no ``_rope`` product stands in float32."""
+    import re
+
+    from benchmarks.lib import checks
+
+    _, text = sarvams_step
+    bodies = checks.count_pallas_kernels(text, (
+        "_latent_q_kernel", "_latent_k_kernel", "_latent_q_back_kernel",
+        "_latent_k_back_kernel"))
+    assert bodies == {"_latent_q_kernel": 2, "_latent_k_kernel": 2,
+                      "_latent_q_back_kernel": 1, "_latent_k_back_kernel": 1}
+    calls = {entry: len(re.findall(rf"call @{entry}(?:_\d+)?\(", text)) for entry in (
+        "_latent_q_forward", "_latent_k_forward", "_latent_q_backward", "_latent_k_backward")}
+    assert calls == {"_latent_q_forward": 10, "_latent_k_forward": 10,
+                     "_latent_q_backward": 5, "_latent_k_backward": 5}
+    assert not re.findall(r"stablehlo\.concatenate.*(1x64x4096x192|4096x64x192)x", text)
+    assert "tensor<1x4096x64x64xbf16>" not in text
+    assert "tensor<1x64x4096x32xf32>" not in text
+
+
 # The models the benchmark already had lower to the Pallas kernels they had
 # before a layer could choose its mixer and FFN: read by this same code at
 # commit 57913f4, each configuration file at its rehearsal size, b1 x s256.
@@ -561,8 +637,8 @@ def _lowered_step(v5e, name):
 # MiniCPM-SALA cell: q and k are one shape, and its sparse layer turns
 # nothing. The Mistral cells' lowered text holds the replay's calls too; no
 # barrier stands there, XLA merges them with the forward's, and a trace
-# counts 16 a step. sarvam's rotated part is 64 lanes (``models/mla.py``
-# keeps ``_rope``).
+# counts 16 a step. sarvam's rotated part is 64 lanes of a 192-wide head:
+# ``models/mla.py`` turns it inside ``latent_qkv``'s kernels, not this one.
 ROTARY_STEPS = {
     "laguna-xs2-33b-a3b-l8.longctx-16k": (12, 48),
     "minicpm-sala-9b-l4.long16k": (3, 18),
