@@ -93,15 +93,20 @@ class MixtralConfig(LlamaConfig):
     # part of the result for the pairs routed to them and nothing stands
     # in for the rest ("gmm" only). None: every expert.
     experts_held: Optional[Tuple[int, int]] = None
-    # How a held share's rows reach their slots and come back. "walk":
-    # over the slots of the tiles that hold rows, a window a trip
-    # (_held_ffn): it reads and writes the pairs that came, at 3.7 us a
-    # marginal row (scatter-adds; PERF.md §6, PR 37), so the step follows
-    # the routing. "gather": every pair is laid out and rows move by
-    # gathers over all the pairs, as in the whole layer: the same cost
-    # whatever the routing, each gather a copy's, and only the grouped
-    # matmuls follow the pairs that came. A held quarter gathers four times
-    # the rows it needs, a sixteenth sixteen times: for a large share.
+    # How a held share's rows come back to tokens. Either way they reach
+    # their slots, pass the experts and take their gradients over the
+    # tiles that hold rows, a window a trip (_held_ffn), so those passes
+    # follow the routing as the grouped matmuls do: 0.3 us a marginal row
+    # and layer at 2,048 columns and 0.8 at 4,096, the matmuls' 0.1 and
+    # 0.5 included (PERF.md §6, PR 60). "walk": back to tokens over the
+    # same slots, each row added into its token's (scatter-adds: 3.7 us a
+    # marginal row all told, PERF.md §6, PR 37), so the step follows the
+    # routing and nothing is paid for a pair held elsewhere. "gather": by
+    # one gather over every (token, k) pair, forward and backward, a pair
+    # held elsewhere reading zeros: the same cost whatever the routing,
+    # each a copy's, and nothing that costs a scatter-add follows the
+    # pairs that came. A held quarter gathers four times the rows it
+    # needs there, a fortieth forty times, and that is all it overpays.
     held_rows: str = "walk"
 
     @property
@@ -519,34 +524,6 @@ def _slots_to_rows_bwd(residuals, g):
 _slots_to_rows.defvjp(_slots_to_rows_fwd, _slots_to_rows_bwd)
 
 
-def _used_rows(rows, tiles_used):
-    """``rows`` [m_pad, .] of a layout that ``ops.gmm.gmm`` filled up to
-    ``tiles_used`` row tiles, zeros in the rows past them, which are
-    uninitialised memory: a select, so that whatever they hold goes no
-    further."""
-    used = jnp.arange(rows.shape[0], dtype=jnp.int32) < tiles_used[0] * 128
-    return jnp.where(used[:, None], rows, jnp.zeros((), rows.dtype))
-
-
-@jax.custom_vjp
-def _used_rows_back(rows, tiles_used):
-    """``rows`` as they are; their gradient through ``_used_rows``: what
-    ``gmm`` hands back for its left operand is written up to ``tiles_used``
-    and no further."""
-    return rows
-
-
-def _used_rows_back_fwd(rows, tiles_used):
-    return rows, tiles_used
-
-
-def _used_rows_back_bwd(tiles_used, g):
-    return _used_rows(g, tiles_used), None
-
-
-_used_rows_back.defvjp(_used_rows_back_fwd, _used_rows_back_bwd)
-
-
 # Row tiles a trip of the held share's loops covers (2,048 rows). The
 # loops run over the tiles that hold rows, so their last trip may cover up
 # to a window less one tile that hold none.
@@ -567,24 +544,26 @@ def _windows(used, size, total, trip, carry):
     return jax.lax.fori_loop(0, -(-used // size), body, carry)
 
 
-def _held_pair_of_slot(order, dst, n_here, m_pad):
-    """``pair_of_slot`` [m_pad] of a layout whose last group is the pairs
-    held elsewhere (``aligned_group_layout``'s ``order`` and ``dst``; the
-    first ``n_here`` sorted pairs are this device's): the pair, counted
-    token-major, in each slot of a held expert's tiles, and the pair count
-    in a padding slot and in every slot past those tiles. Written a window
-    of sorted pairs at a time, this device's alone."""
-    N = order.shape[0]
+def _held_index(keys, values, n_here, size, fill):
+    """[size] int32 holding ``values[i]`` at ``keys[i]`` for the first
+    ``n_here`` sorted pairs i, which are this device's, and ``fill``
+    everywhere else: ``aligned_group_layout``'s ``order`` and ``dst`` of a
+    layout whose last group is the pairs held elsewhere, either way round.
+    By ``dst``, the pair (counted token-major) in each slot of a held
+    expert's tiles, and ``fill`` in a padding slot and in every slot past
+    those tiles; by ``order``, the slot of each pair that is here. Written a
+    window of sorted pairs at a time, this device's alone."""
 
-    def trip(at, size, pair_of_slot):
-        here = at + jnp.arange(size, dtype=jnp.int32) < n_here
-        slot = _window(dst, at, size)
-        return pair_of_slot.at[jnp.where(here, slot, m_pad)].set(
-            _window(order, at, size), mode="drop"
+    def trip(at, span, index):
+        here = at + jnp.arange(span, dtype=jnp.int32) < n_here
+        key = _window(keys, at, span)
+        return index.at[jnp.where(here, key, size)].set(
+            _window(values, at, span), mode="drop"
         )
 
     return _windows(
-        n_here, _WINDOW * 128, N, trip, jnp.full((m_pad,), N, jnp.int32)
+        n_here, _WINDOW * 128, keys.shape[0], trip,
+        jnp.full((size,), fill, jnp.int32),
     )
 
 
@@ -610,8 +589,23 @@ def _swiglu_rows(h, u):
     return nn.silu(h) * u
 
 
+def _pairs_summed(rows, slot_of_pair, gates=None):
+    """Each token's sum over its K pairs' rows of the layout ``rows``
+    [m_pad, D], weighted by ``gates`` [S, K] where they are given, added up
+    in float32 and rounded once (``_slots_to_rows``' arithmetic, and
+    ``_rows_to_slots``' gradient's): one gather over every pair.
+    ``slot_of_pair`` [S, K] names a slot past the layout for a pair that is
+    not here, which reads zeros by its index: no row past the used tiles
+    is read."""
+    pairs = rows.at[slot_of_pair].get(mode="fill", fill_value=0)
+    pairs = pairs.astype(jnp.float32)
+    if gates is not None:
+        pairs = pairs * gates[..., None]
+    return pairs.sum(1).astype(rows.dtype)
+
+
 def _held_ffn_fwd(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
-                  tiles_used):
+                  tiles_used, slot_of_pair=None):
     from ..ops import gmm as G
 
     (S, D), K, F = x2.shape, gates.shape[1], w_gate.shape[2]
@@ -636,26 +630,29 @@ def _held_ffn_fwd(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
         )
         eo = grouped(act, w_down)
     with tracing.scope(tracing.MOE_COMBINE):
-        gate_of_pair = gates.reshape(S * K)
+        if slot_of_pair is not None:
+            out = _pairs_summed(eo, slot_of_pair, gates)
+        else:
+            gate_of_pair = gates.reshape(S * K)
 
-        def add(at, size, out):
-            # A padding slot's pair and token lie past the arrays: its gate
-            # reads zero and its row, whatever it holds, is dropped.
-            pair = _window(pair_of_slot, at, size)
-            gate = gate_of_pair.at[pair].get(mode="fill", fill_value=0)
-            scaled = _window(eo, at, size).astype(jnp.float32) * gate[:, None]
-            return out.at[pair // K].add(scaled, mode="drop")
+            def add(at, size, out):
+                # A padding slot's pair and token lie past the arrays: its
+                # gate reads zero and its row, whatever it holds, is dropped.
+                pair = _window(pair_of_slot, at, size)
+                gate = gate_of_pair.at[pair].get(mode="fill", fill_value=0)
+                scaled = _window(eo, at, size).astype(jnp.float32) * gate[:, None]
+                return out.at[pair // K].add(scaled, mode="drop")
 
-        out = _windows(
-            used, span, m_pad, add, jnp.zeros((S, D), jnp.float32)
-        ).astype(eo.dtype)
+            out = _windows(
+                used, span, m_pad, add, jnp.zeros((S, D), jnp.float32)
+            ).astype(eo.dtype)
     return out, (x2, h, u, eo, gates, w_gate, w_up, w_down, pair_of_slot,
-                 tile_group, tiles_used)
+                 tile_group, tiles_used, slot_of_pair)
 
 
 @jax.custom_vjp
 def _held_ffn(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
-              tiles_used):
+              tiles_used, slot_of_pair=None):
     """The held experts' SwiGLU of tokens ``x2`` [S, D] over a layout that
     is bounded at every pair and filled by the pairs that are here, and the
     sum of each token's pairs weighted by ``gates`` [S, K]: what
@@ -666,17 +663,24 @@ def _held_ffn(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
     the used tiles of each are ever written or read (it costs no time): a
     row of a later tile is uninitialised memory. Rows move to slots by
     gathers over the used tiles, a window of them a trip (``_windows``;
-    ``m_pad`` is whole windows), and back to tokens by walking the same
-    slots and adding each into its token's float32 row, where a gather over
-    tokens would read 131,072 rows to find the 8,192 that hold a pair. The
-    backward is written out for the same reason: JAX's would add the two
-    up-projections' row gradients over the whole bound.
+    ``m_pad`` is whole windows), and every pass over slots runs over the
+    same windows. Back to tokens they go one of two ways
+    (``MixtralConfig.held_rows``). Without ``slot_of_pair``, by walking the
+    same slots and adding each into its token's float32 row: scatter-adds,
+    of the rows that are here alone. With it ([S, K] int32, a slot at or
+    past ``m_pad`` for a pair that is not here), by one gather over every
+    pair, summed over K in float32 (``_pairs_summed``), forward for the
+    result and backward for x's gradient: the two passes of the layer that
+    do not stop at the used tiles, and no scatter-add. The backward is
+    written out because JAX's would pass over the whole bound: the sum of
+    the two up-projections' row gradients, the SwiGLU's, the gates'.
 
     ``pair_of_slot`` [m_pad] int32: a slot's (token, k) pair counted
-    token-major, S * K in a padding slot (``_held_pair_of_slot``);
+    token-major, S * K in a padding slot (``_held_index``);
     ``tile_group`` and ``tiles_used`` as ``ops.gmm.gmm`` takes them."""
     return _held_ffn_fwd(
-        x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group, tiles_used
+        x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group, tiles_used,
+        slot_of_pair,
     )[0]
 
 
@@ -684,7 +688,7 @@ def _held_ffn_bwd(residuals, g):
     from ..ops import gmm as G
 
     (x2, h, u, eo, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
-     tiles_used) = residuals
+     tiles_used, slot_of_pair) = residuals
     (S, K), (m_pad, D) = gates.shape, eo.shape
     used, span = tiles_used[0] * 128, _WINDOW * 128
 
@@ -750,19 +754,43 @@ def _held_ffn_bwd(residuals, g):
         )
         d_lhs = to_rows(d_h, w_gate), to_rows(d_u, w_up)
     with tracing.scope(tracing.MOE_DISPATCH):
-        def add(at, size, dx):
-            token = _window(pair_of_slot, at, size) // K
-            both = sum(_window(d, at, size).astype(jnp.float32) for d in d_lhs)
-            return dx.at[token].add(both, mode="drop")
+        if slot_of_pair is not None:
+            def both(at, size, rows):
+                return _put(
+                    rows, _window(rows, at, size) + _window(d_lhs[1], at, size), at
+                )
 
-        dx = _windows(
-            used, span, m_pad, add, jnp.zeros((S, D), jnp.float32)
-        ).astype(x2.dtype)
+            dx = _pairs_summed(
+                _windows(used, span, m_pad, both, d_lhs[0]), slot_of_pair
+            )
+        else:
+            def add(at, size, dx):
+                token = _window(pair_of_slot, at, size) // K
+                both = sum(_window(d, at, size).astype(jnp.float32) for d in d_lhs)
+                return dx.at[token].add(both, mode="drop")
+
+            dx = _windows(
+                used, span, m_pad, add, jnp.zeros((S, D), jnp.float32)
+            ).astype(x2.dtype)
     d_gates = d_gate.reshape(S, K).astype(gates.dtype)
-    return dx, d_gates, d_w_gate, d_w_up, d_w_down, None, None, None
+    return dx, d_gates, d_w_gate, d_w_up, d_w_down, None, None, None, None
 
 
 _held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
+
+
+# ``_held_ffn`` behind a jitted entry that JAX inlines as it traces: a model's
+# expert layers are one shape, so the function, its forward rule and the
+# loops in both are traced once and not once a layer (each layer's own cost
+# the Laguna cell 8 s of a 58 s set-up: PERF.md §6, PR 60), and the step's
+# text holds what it held, a layer at a time, scopes and kernels where they
+# were. ``traced_under`` is what a trace depends on beside its arguments (the
+# kernels' interpreter, the window), so that a trace is not taken for
+# another's. The road that gathers takes it; the road that walks calls
+# ``_held_ffn`` as it did, and its text is the one it had (ROADMAP A7b).
+@partial(jax.jit, static_argnums=(0,), inline=True)
+def _held_inlined(traced_under, *args):
+    return _held_ffn(*args)
 
 
 CONFIGS = {
@@ -804,10 +832,12 @@ class MoELayer(nn.Module):
     pair order, so what would be a scatter-add is a gather and a sum
     over K (_rows_to_slots, _slots_to_rows). Where the device holds a share
     of the experts (cfg.experts_held) the layout is bounded at every pair
-    and a sixteenth of it is filled: there every pass stops at the tiles
-    that hold rows, and rows go back to tokens by walking those tiles'
-    slots and adding each into its token's row (_held_ffn), or, where
-    cfg.held_rows says "gather", by the gathers above over every pair.
+    and a sixteenth of it is filled: there every pass over slots stops at
+    the tiles that hold rows (_held_ffn: the gathers to slots, the SwiGLU,
+    the grouped matmuls, every gradient), and rows go back to tokens by
+    walking those tiles' slots and adding each into its token's row, or,
+    where cfg.held_rows says "gather", by a gather over every pair and a
+    sum over K, forward and backward: the two passes that stay whole.
 
     "ragged": (token, k) pairs argsorted by expert feed
     `lax.ragged_dot` with exact group sizes — zero capacity padding and
@@ -944,41 +974,6 @@ class MoELayer(nn.Module):
                         tile_group < E_w, dtype=jnp.int32
                     ).reshape(1)
                     tile_group = jnp.minimum(tile_group, E_w - 1)
-                    walks = cfg.held_rows == "walk"
-                    if not walks:
-                        slot_of_pair, pair_of_slot = _pair_slots(
-                            order, dst, m_pad, K
-                        )
-            if not walks:
-                # Every pair in the layout, the grouped matmuls over the
-                # tiles of the pairs that are here: the rows past them
-                # are never written, and `_used_rows` keeps them out of
-                # both gathers back to tokens.
-                from ..ops.gmm import gmm
-
-                tiles_used = jnp.maximum(tiles_used, 1)
-                with tracing.scope(tracing.MOE_DISPATCH):
-                    lhs = _used_rows_back(
-                        _rows_to_slots(x2, slot_of_pair, pair_of_slot),
-                        tiles_used,
-                    )
-                with tracing.scope(tracing.MOE_EXPERTS):
-                    def grouped(rows, w):
-                        return gmm(
-                            rows, w.astype(cfg.dtype), tile_group,
-                            tiles_used=tiles_used,
-                        )
-
-                    act = nn.silu(grouped(lhs, w_gate)) * grouped(lhs, w_up)
-                    eo = _used_rows(grouped(act, w_down), tiles_used)
-                with tracing.scope(tracing.MOE_COMBINE):
-                    gates = jnp.where(
-                        here, gate_vals.astype(cfg.dtype).reshape(N), 0
-                    ).reshape(B * T, K)
-                    out2 = _slots_to_rows(eo, gates, slot_of_pair, pair_of_slot)
-                return finish(out2.reshape(B, T, D))
-            with tracing.scope(tracing.MOE_DISPATCH):
-                with tracing.scope(tracing.MOE_LAYOUT):
                     # Whole windows of tiles, each past the used ones
                     # named for the last expert that is here.
                     tiles = -(-m_pad // (_WINDOW * 128)) * _WINDOW
@@ -986,13 +981,28 @@ class MoELayer(nn.Module):
                         tile_group, (0, tiles - m_pad // 128),
                         constant_values=E_w - 1,
                     )
-                    pair_of_slot = _held_pair_of_slot(
-                        order, dst, jnp.sum(here, dtype=jnp.int32), tiles * 128
+                    n_here = jnp.sum(here, dtype=jnp.int32)
+                    pair_of_slot = _held_index(
+                        dst, order, n_here, tiles * 128, N
                     )
-            out2 = _held_ffn(
+                    slot_of_pair = None
+                    if cfg.held_rows == "gather":
+                        # The same map the other way: rows come back to
+                        # tokens by a gather over every pair, and a pair
+                        # held elsewhere names a slot past the layout.
+                        slot_of_pair = _held_index(
+                            order, dst, n_here, N, tiles * 128
+                        ).reshape(B * T, K)
+            share = _held_ffn
+            if slot_of_pair is not None:
+                from ..ops.gmm import _interpret
+
+                share = partial(_held_inlined, (_interpret(), _WINDOW))
+            out2 = share(
                 x2, gate_vals.astype(cfg.dtype).reshape(B * T, K),
                 w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
                 w_down.astype(cfg.dtype), pair_of_slot, tile_group, tiles_used,
+                slot_of_pair,
             )
             return finish(out2.reshape(B, T, D))
 

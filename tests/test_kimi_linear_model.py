@@ -332,7 +332,7 @@ def test_the_bias_moves_the_selection_and_not_the_gates(whole_layer):
 
 
 def whole_bound_ffn(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
-                    tiles_used):
+                    tiles_used, slot_of_pair=None):
     """``_held_ffn``'s result the plain way, as the layer computed it before
     its cost followed the pairs that are here: every row move a gather over
     the whole bound, every tile of the layout computed. A pair held
@@ -424,7 +424,7 @@ def test_the_share_is_the_whole_bounds_at_the_cost_of_its_pairs(
     want, pull = jax.vjp(lambda *a: whole_bound_ffn(*a, *args[5:]), *args[:5])
     want = (want, *pull(w.reshape(want.shape)))
 
-    pair_of_slot, tile_group, tiles_used = (np.asarray(a) for a in args[5:])
+    pair_of_slot, tile_group, tiles_used = (np.asarray(a) for a in args[5:8])
     pairs, tiles = x.shape[0] * x.shape[1] * 4, int(tiles_used[0])
     here = int((pair_of_slot < pairs).sum())
     assert not (pair_of_slot[tiles * 128:] < pairs).any()

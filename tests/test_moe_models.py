@@ -395,6 +395,80 @@ def test_moe_dispatch_auto_resolution(tiny_moe, monkeypatch):
         )
 
 
+# ------------------------------------------- a held share's two roads
+#
+# One expert layer, forward and backward, at a router of 16 experts, top-4,
+# over 256 tokens of 32: the whole layer, and a rank's quarter by either
+# road (``held_rows``).
+
+
+def _layer_step(**over):
+    """(the layer's loss and gradients as a function, its arguments' shapes)."""
+    from ray_tpu.models.mixtral import MixtralConfig, MoELayer
+
+    layer = MoELayer(MixtralConfig(
+        hidden_size=32, intermediate_size=64, num_experts=16,
+        num_experts_per_tok=4, num_shared_experts=1, router_score="sigmoid",
+        moe_dispatch="gmm", dtype=jnp.bfloat16, **over,
+    ))
+    x = jax.ShapeDtypeStruct((1, 256, 32), jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+
+    def step(params, x):
+        return layer.apply(params, x).astype(jnp.float32).sum()
+
+    return jax.value_and_grad(step, (0, 1)), (params, x)
+
+
+# sha1 of the lowered text (without the counters JAX gives its private
+# functions), read by this code at the parent of the PR that gave the
+# "gather" road ``_held_ffn``'s slot-side loops (commit 6ab58fe): a layer that
+# walks (Kimi-Linear's and sarvam's) and a layer that holds every expert
+# (OLMoE's) lower to the text they lowered to, so their steps cannot have
+# moved with it.
+LAYER_TEXTS_BEFORE = {
+    "walk": (dict(experts_held=(4, 8), held_rows="walk"), "c3f6f0628800c651"),
+    "whole": ({}, "119ae5fb0bbf48d7"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LAYER_TEXTS_BEFORE))
+def test_the_layers_off_the_gather_road_lower_to_what_they_did(name, monkeypatch):
+    import hashlib
+    import re
+
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    over, before = LAYER_TEXTS_BEFORE[name]
+    step, shapes = _layer_step(**over)
+    text = jax.jit(step).lower(*shapes).as_text()
+    text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+    assert hashlib.sha1(text.encode()).hexdigest()[:16] == before
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(inner)
+
+
+@pytest.mark.parametrize("held_rows, row_adds", [("gather", 0), ("walk", 2)])
+def test_no_row_is_scatter_added_on_the_gather_road(held_rows, row_adds, monkeypatch):
+    """A scatter-add into [., 32] arrays (rows of tokens or of slots): the
+    walk's two, forward into the result and backward into x's gradient, and
+    none where rows are gathered; there the traced step holds two gathers
+    of every pair's row, [256, 4, 32], forward and backward."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    step, shapes = _layer_step(experts_held=(4, 8), held_rows=held_rows)
+    eqns = list(_equations(jax.make_jaxpr(step)(*shapes).jaxpr))
+    adds = [e for e in eqns if e.primitive.name == "scatter-add"
+            and e.outvars[0].aval.shape[-1:] == (32,)]
+    assert len(adds) == row_adds
+    whole = [e for e in eqns if e.primitive.name == "gather"
+             and e.outvars[0].aval.shape == (256, 4, 32)]
+    assert len(whole) == (2 if held_rows == "gather" else 0)
+
+
 # ------------------------------------------------------ one decoder body
 #
 # The parameter names below are the ones benchmarks/reference/*.py read.

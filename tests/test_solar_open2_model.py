@@ -283,13 +283,25 @@ def layer_config(held) -> dict:
             "n_shared_experts": 1}
 
 
-def test_the_five_ranks_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("taker", [None, 2], ids=["as-scored", "one-rank-takes-all"])
+def test_the_five_ranks_shares_add_up_to_the_uncut_layer(taker):
     """Five ranks of four experts each, a rank count that is no power of two
     under a router whose width is no multiple of 128: the routed parts they
     give, with the shared expert (which every rank computes alike) counted
-    once, are the uncut reference's expert layer."""
+    once, are the uncut reference's expert layer; as the router scores at its
+    initial values, and with a router that sends every pair to one rank's four
+    experts and none to the sixteen others (the layout's bound, and a rank
+    whose tiles hold padding alone)."""
     x = jnp.asarray(np.random.default_rng(1).normal(size=(2, 48, 32)), jnp.float32)
     params = expert_layer(None).init(jax.random.PRNGKey(1), x)["params"]
+    if taker is not None:
+        # The reference reads no selection bias: one feature that every token
+        # holds, and a router that scores it for one rank's experts alone.
+        x = x.at[..., 0].set(4.0)
+        scores = np.full(20, -5.0, np.float32)
+        scores[4 * taker:4 * taker + 4] = 5.0
+        kernel = params["router"]["kernel"].at[0].set(scores)
+        params = {**params, "router": {"kernel": kernel}}
     tokens = x.reshape(-1, 32)
     with jax.default_matmul_precision("highest"):
         uncut = reference.moe(params, tokens, layer_config(None))
@@ -304,7 +316,10 @@ def test_the_five_ranks_shares_add_up_to_the_uncut_layer():
         with jax.default_matmul_precision("highest"):
             want = reference.moe(mine, tokens, layer_config(held))
         np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
-        pairs += int((gates[:, held[0]:held[1]] > 0).sum())
+        mine_pairs = int((gates[:, held[0]:held[1]] > 0).sum())
+        if taker is not None:
+            assert mine_pairs == (96 * 4 if rank == taker else 0)
+        pairs += mine_pairs
         total = total + (out - shared)
     assert pairs == 96 * 4  # every pair is held by exactly one rank
     np.testing.assert_allclose(total + shared, uncut, rtol=1e-4, atol=2e-5)
@@ -437,9 +452,13 @@ def tree_digest(tree) -> tuple:
 # operations are where they were ("cc97cf680f1bedbe" before; the logits and
 # every gradient at this size are the parent's bit for bit, PERF.md §6,
 # PR 56). The three others, which run the causal kernels alone, read the same.
+# Laguna's again since its held eighth's rows, ``held_rows`` "gather", reach
+# their slots over the used tiles alone (``_held_ffn`` with a gather back to
+# tokens; "0e16e4b782b6a5a6" before, with every pair laid out): Kimi-Linear's
+# and sarvam's, which walk, read what they read.
 BEFORE = {
     "kimi-linear-48b-a3b-l5": ("4ed2711bc2778250", 117, "c16ef491925e5adb"),
-    "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "0e16e4b782b6a5a6"),
+    "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "ec64c261a184e22c"),
     "mistral-7b-l4": ("06a35641bbb39a58", 21, "6ac84cd0523ca00f"),
     "sarvam-105b-l5": ("c710f6841e29dd3a", 83, "2a9ffca6aec4a4c6"),
 }

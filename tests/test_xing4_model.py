@@ -505,11 +505,40 @@ def test_the_four_ranks_shares_add_up_to_the_uncut_layer(held_rows):
     assert ((np.asarray(gates) > 0).sum(-1) == 4).all()
 
 
-@pytest.mark.parametrize("rank", [0, 1, 3])
-def test_gathered_rows_give_what_walked_rows_give(rank, monkeypatch):
-    """``held_rows`` "gather" against "walk": the same result and the same
-    gradients, with NaN in every row the grouped matmuls leave unwritten
-    (past ``tiles_used``), so that a gather that read one would show."""
+@pytest.fixture
+def fresh_traces():
+    """The road that gathers keeps what it traced (``mixtral._held_inlined``):
+    a test that patches what a trace calls starts from none and leaves none.
+    Named before ``monkeypatch``, it is torn down after the patches are."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def routed(params, rank, routing):
+    """``params`` with a selection bias that sends a rank of sixteen experts
+    no pair, its share as the router scores, or every pair."""
+    bias = np.zeros(64, np.float32)
+    if routing == "none-here":
+        bias[16 * (rank ^ 1):16 * (rank ^ 1) + 16] = 10.0
+    elif routing == "every-pair-here":
+        bias[16 * rank:16 * rank + 16] = 10.0
+    return {**params, "router_bias": jnp.asarray(bias)}
+
+
+@pytest.mark.parametrize("rank, routing", [
+    (0, "expected-share"), (1, "expected-share"), (3, "expected-share"),
+    (1, "none-here"), (3, "every-pair-here"),
+])
+def test_gathered_rows_give_what_walked_rows_give(rank, routing, fresh_traces, monkeypatch):
+    """``held_rows`` "gather" against "walk" and against the uncut layer: the
+    same result and the same gradients (x, the router through the gates, the
+    three expert matrices), with NaN in every row of a bounded buffer that
+    the road should leave alone (past ``tiles_used`` in what the grouped
+    matmuls return, everywhere in what the loops are handed to fill), so
+    that a pass that read one would show. The uncut layer is the 64 experts'
+    with a zero down-projection in the 48 that are elsewhere: they add
+    nothing and pass no gradient."""
     from ray_tpu.ops import gmm as G
 
     plain, bounded = G._gmm_pallas, []
@@ -524,31 +553,46 @@ def test_gathered_rows_give_what_walked_rows_give(rank, monkeypatch):
         return jnp.where(past, jnp.nan, out)
 
     x = jnp.asarray(np.random.default_rng(7).normal(size=(1, 192, 32)), jnp.float32)
-    params = expert_layer(None).init(jax.random.PRNGKey(2), x)["params"]
+    params = routed(
+        expert_layer(None).init(jax.random.PRNGKey(2), x)["params"], rank, routing)
     held = (16 * rank, 16 * rank + 16)
-    mine = {**params, **{k: params[k][held[0]:held[1]]
-                         for k in ("w_gate", "w_up", "w_down")}}
+    here = (np.arange(64) >= held[0]) & (np.arange(64) < held[1])
+    params["w_down"] = params["w_down"] * here[:, None, None]
     w = jnp.asarray(np.random.default_rng(8).normal(size=x.shape), jnp.float32)
 
-    def readings(held_rows):
-        layer = expert_layer(held, held_rows=held_rows)
+    def share(tree):
+        return {**tree, **{k: tree[k][held[0]:held[1]]
+                           for k in ("w_gate", "w_up", "w_down")}}
+
+    mine = share(params)
+
+    def readings(held, p, **over):
+        layer = expert_layer(held, **over)
         return jax.value_and_grad(
             lambda p, x: (layer.apply({"params": p}, x) * w).sum(), (0, 1)
-        )(mine, x)
+        )(p, x)
 
-    want, want_grads = readings("walk")
+    want, want_grads = readings(held, mine, held_rows="walk")
+    uncut, (uncut_params, uncut_x) = readings(None, params)
+    uncut_grads = (share(uncut_params), uncut_x)
     monkeypatch.setattr(G, "_gmm_pallas", poisoned)
-    got, got_grads = readings("gather")
-    # three grouped matmuls forward, three back to rows: all told where to stop
-    assert bounded == [False] * 3 + [True] * 3
+    monkeypatch.setattr(
+        G, "unwritten", lambda shape, dtype, after: jnp.full(shape, jnp.nan, dtype))
+    got, got_grads = readings(held, mine, held_rows="gather")
+    # three grouped matmuls forward (traced as the function and again as its
+    # forward rule), three back to rows: all told where to stop
+    assert bounded == [False] * 6 + [True] * 3
     assert float(got) == pytest.approx(float(want), rel=1e-5)
+    assert float(got) == pytest.approx(float(uncut), rel=1e-4)
     flat = jax.tree_util.tree_leaves_with_path
-    for (path, a), (_, b) in zip(flat(got_grads), flat(want_grads)):
-        assert np.isfinite(np.asarray(a)).all(), path
-        np.testing.assert_allclose(
-            a, b, rtol=1e-4, atol=1e-5 * max(float(np.abs(b).max()), 1e-9),
-            err_msg=jax.tree_util.keystr(path))
-    assert np.abs(np.asarray(got_grads[0]["router"]["kernel"])).max() > 0
+    for other, rtol in ((want_grads, 1e-4), (uncut_grads, 1e-3)):
+        for (path, a), (_, b) in zip(flat(got_grads), flat(other)):
+            assert np.isfinite(np.asarray(a)).all(), path
+            np.testing.assert_allclose(
+                a, b, rtol=rtol, atol=1e-5 * max(float(np.abs(b).max()), 1e-9),
+                err_msg=jax.tree_util.keystr(path))
+    reached = np.abs(np.asarray(got_grads[0]["router"]["kernel"])).max() > 0
+    assert reached == (routing != "none-here")
 
 
 def test_held_rows_takes_one_of_two_names():
