@@ -31,6 +31,19 @@ q's columns first: a fused and a separate projection are one function, and 2
 x 30 x 96 channels are 45 vregs of lanes where 30 x 96 are 22.5, so the one
 convolution pass over q and k tiles (``ops/kda.py`` ``conv_silu``) where two
 would fall to XLA's passes. v's 30 x 192 channels are 45 vregs alone.
+
+Layouts. The projections come out of their matmuls [B, T, channels]. q's and
+k's convolution pass writes [B, 2 H, T, dk], a head at a time (``conv_silu``'s
+``heads``: a block of 384 lanes is four key heads), which is what
+``chunk_gdn``'s kernels read, a head of 96 lanes (or a grid step's two) being
+no whole number of vregs; q and k stay the one array [B, 2, H, T, dk], of
+which a scan block holds both, and their cotangents come back the same way.
+v, the gate and o stay [B, T, H dv] from the convolution and ``g_proj``
+through the scan to ``o_proj``: a grid step's two value heads are 384 lanes,
+three vregs side by side, and the kernels take them apart in VMEM. So no
+slice, transposed copy or reshape copy stands between the Pallas calls or
+between them and the matmuls, in either direction; only the decay and beta,
+one float a head and token, are laid out for the kernels by XLA.
 """
 from __future__ import annotations
 
@@ -147,12 +160,14 @@ class GDNMixer(nn.Module):
                 "v": _dense(cfg, H * dv, "v_proj", dtype=f32)(x)}
         # As KDAMixer's: float32 passes of ``conv_silu``, q and k left raw for
         # the scan's kernels to normalise, v rounded as its pass stores it.
+        # q's and k's pass writes a head at a time, [B, 2 H, T, dk], q's heads
+        # then k's: what the scan's kernels read. v's stays [B, T, H dv].
         with tracing.scope(tracing.KDA_CONV):
             qk, v = (
                 conv_silu(y, self.param(
                     f"{n}_conv", _conv_init,
                     (cfg.linear_conv_kernel_dim, y.shape[-1]), cfg.param_dtype,
-                ), cfg.dtype if n == "v" else f32)
+                ), cfg.dtype if n == "v" else f32, heads=None if n == "v" else dk)
                 for n, y in proj.items()
             )
         # The decay's map comes out in float32, as KDA's does: exp(A_log) is
@@ -168,9 +183,8 @@ class GDNMixer(nn.Module):
         gate = _dense(cfg, H * dv, "g_proj")(x)
         with tracing.scope(tracing.KDA_SCAN):
             o = chunk_gdn(
-                qk[..., :H * dk].reshape(B, T, H, dk),
-                qk[..., H * dk:].reshape(B, T, H, dk),
-                v.reshape(B, T, H, dv), g, beta, gate.reshape(B, T, H, dv),
+                qk.reshape(B, 2, H, T, dk), v.reshape(B, T, H, dv), g, beta,
+                gate.reshape(B, T, H, dv),
                 NormWeight(cfg.param_dtype, name="o_norm")(dv),
                 scale=dk ** -0.5, rms_eps=cfg.rms_eps,
             )

@@ -43,6 +43,25 @@ of a padded copy differentiated tap by tap, moved 6.4 times the backward's.
 ``short_conv`` with ``jax.nn.silu`` is the reference they are tested against
 and the path where there is no TPU or the shape does not tile.
 
+Who lays out: the kernel that produces, for the kernel that reads. The
+convolution's output lies as the projection does, [B, T, D], unless the
+caller says what its next kernel reads: ``conv_silu(..., heads=d)`` writes [B,
+D / d, T, d], a head at a time, and takes the cotangent there. ``chunk_gdn``'s
+kernels need that of q and k (a key head of 96 lanes is no whole number of
+vregs, nor are a grid step's two, so a block must be whole in its last
+extent), and between two Pallas calls XLA fuses nothing: the slice of q from
+k, the transposed copy and the reshape copy it made for each, and as many
+back, were a tenth of a Gated DeltaNet mixer's time. A convolution block's
+lanes are whole heads (384: four of 96), so the forward tile's store is one
+lane slice a head and the backward's tile is put together from a load a head,
+in VMEM; q and k, one projection and one convolution, stay one array [B, 2,
+H, T, dk] through the scan and back. Where a grid step's heads are whole
+vregs side by side a kernel reads [B, T, H * d] as it lies and takes the
+heads apart itself: ``chunk_kda`` and ``chunk_ssd`` (a BlockSpec picks a
+step's heads' lanes), and ``chunk_gdn`` for v, the gate and o (two value
+heads of 192 lanes are three vregs), so their convolutions are called
+without ``heads``.
+
 Inside a chunk, with G the running sum of g inside it:
 
     A[t, s]   = b_t sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c])      s < t
@@ -171,31 +190,35 @@ def _largest(most: int, least: int, n: int) -> int:
 
 class _ConvBlocks(NamedTuple):
     """A call's static part: a block's rows and lanes, the rows of a tile
-    inside it, and whether the interpreter runs the kernels (the jitted
-    entries keep their traces by it)."""
+    inside it, whether the interpreter runs the kernels (the jitted entries
+    keep their traces by it), and the lanes of a head where the output lies
+    heads first (0: it lies as x does)."""
     rows: int
     lanes: int
     tile: int
     interpret: bool
+    heads: int = 0
 
 
-def _conv_blocks(x, w):
+def _conv_blocks(x, w, heads=None):
     """The convolution kernels' blocks over x [B, T, D] under a filter w [K,
     D], or None where the shape does not tile or there is neither a TPU nor
     the interpreter: whole 16-row tiles of a bfloat16 output in T, whole
-    vregs of lanes in D, and a filter that reaches no further back than the
-    halo."""
+    vregs of lanes in D that are whole heads of ``heads`` lanes where the
+    output lies heads first, and a filter that reaches no further back than
+    the halo."""
     interpret = _attention._interpret()
     if not (_attention._on_tpu() or interpret):
         return None
     rows = _largest(_CONV_ROWS, 16, x.shape[1])
     # The most whole vregs of lanes, up to _CONV_LANES, that divide D: 512 of
     # 4,096 or 8,192 channels, 384 of 5,760 (45 vregs, which no power of two
-    # above one divides).
-    lanes = next((n for n in range(_CONV_LANES, 0, -128) if not x.shape[2] % n), 0)
+    # above one divides: four heads of 96 lanes, two of 192).
+    lanes = next((n for n in range(_CONV_LANES, 0, -128)
+                  if not x.shape[2] % n and not n % (heads or n)), 0)
     if not rows or not lanes or w.shape[0] - 1 > _CONV_HALO:
         return None
-    return _ConvBlocks(rows, lanes, min(rows, _CONV_TILE), interpret)
+    return _ConvBlocks(rows, lanes, min(rows, _CONV_TILE), interpret, heads or 0)
 
 
 def _taps(ext, w, roll):
@@ -221,10 +244,32 @@ def _sublanes(dtype) -> int:
     return _CONV_HALO * 4 // jnp.dtype(dtype).itemsize
 
 
+def _read(ref, at):
+    """Rows ``at`` of a block as [rows, lanes] float32: of [1, rows, lanes] as
+    they lie; of [1, lanes / d, rows, d], heads first, each head's d lanes
+    beside the last's (whole vregs where d is a multiple of 128, else lane
+    rotations and selects in VMEM)."""
+    if len(ref.shape) == 3:
+        return ref[0, at, :].astype(F32)
+    return jnp.concatenate(
+        [ref[0, j, at, :].astype(F32) for j in range(ref.shape[1])], axis=1)
+
+
+def _write(ref, at, y):
+    """``_read`` undone, rounded once to the block's dtype: a store a head
+    where the block lies heads first."""
+    if len(ref.shape) == 3:
+        ref[0, at, :] = y.astype(ref.dtype)
+        return
+    d = ref.shape[3]
+    for j in range(ref.shape[1]):
+        ref[0, j, at, :] = y[:, j * d:(j + 1) * d].astype(ref.dtype)
+
+
 def _edge(ref, at_edge):
     """A halo block as float32, zeros where it lies outside the sequence
     (its index is clamped there, to rows of the sequence's own)."""
-    rows = ref[0].astype(F32)
+    rows = _read(ref, slice(None))
     return jax.lax.select(at_edge, jnp.zeros_like(rows), rows)
 
 
@@ -238,10 +283,10 @@ def _rows_before(x_ref, edge, i, tile):
 def _rows_after(x_ref, edge, i, tile):
     """The 8 rows after tile ``i`` of the block ``x_ref`` as float32: the
     first of the block's next sublane tile, or after its last ``edge``."""
-    n = _sublanes(x_ref.dtype)
-    hi = pl.multiple_of(jnp.minimum((i + 1) * tile, x_ref.shape[1] - n), n)
-    own = x_ref[0, pl.ds(hi, n), :].astype(F32)[:_CONV_HALO]
-    return jax.lax.select(i == x_ref.shape[1] // tile - 1, edge, own)
+    n, rows = _sublanes(x_ref.dtype), x_ref.shape[-2]
+    hi = pl.multiple_of(jnp.minimum((i + 1) * tile, rows - n), n)
+    own = _read(x_ref, pl.ds(hi, n))[:_CONV_HALO]
+    return jax.lax.select(i == rows // tile - 1, edge, own)
 
 
 def _conv_fwd_kernel(x_ref, before_ref, w_ref, *rest, tile, roll):
@@ -256,7 +301,7 @@ def _conv_fwd_kernel(x_ref, before_ref, w_ref, *rest, tile, roll):
         c = _conv_of(_taps(ext, w, roll), w)[_CONV_HALO:]
         if b_ref is not None:
             c = c + b_ref[...].astype(F32)
-        y_ref[0, at, :] = (c * jax.lax.logistic(c)).astype(y_ref.dtype)
+        _write(y_ref, at, c * jax.lax.logistic(c))
         return carry
 
     jax.lax.fori_loop(0, x_ref.shape[1] // tile, one, None)
@@ -291,8 +336,7 @@ def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
         if b_ref is not None:
             c = c + b_ref[...].astype(F32)
         s = jax.lax.logistic(c)
-        dy = jnp.concatenate([dy_ref[0, at, :].astype(F32),
-                              _rows_after(dy_ref, dy_after, i, tile)])
+        dy = jnp.concatenate([_read(dy_ref, at), _rows_after(dy_ref, dy_after, i, tile)])
         dz = dy * (s * (1.0 + c * (1.0 - s)))
         # dx_s = sum_i w[i] dz_{s + (K - 1) - i}: dz moved up, the rows that
         # wrap among the 8 after the tile.
@@ -317,19 +361,27 @@ def _conv_specs(x, blocks):
     grid, a block's BlockSpec, that of the 8 rows before it, and ``after``,
     which gives that of the sublane tile after it in an array of a dtype
     (either clamped into the sequence at its ends); then a filter's, of
-    ``rows`` rows."""
+    ``rows`` rows. ``block`` and ``after`` told ``heads``, the lanes d of a
+    head, are those of an array that lies [B, D / d, T, d]: the same rows of
+    the block's lanes / d heads, whole in the last extent."""
     rows, lanes = blocks.rows, blocks.lanes
     grid = (x.shape[2] // lanes, x.shape[0], x.shape[1] // rows)
-    block = pl.BlockSpec((1, rows, lanes), lambda l, b, t: (b, t, l))
-    before = pl.BlockSpec(
-        (1, _CONV_HALO, lanes),
-        lambda l, b, t: (b, jnp.maximum(t * (rows // _CONV_HALO) - 1, 0), l))
 
-    def after(dtype):
+    def spec(n, at, heads=0):
+        if heads:
+            return pl.BlockSpec((1, lanes // heads, n, heads),
+                                lambda l, b, t: (b, l, at(t), 0))
+        return pl.BlockSpec((1, n, lanes), lambda l, b, t: (b, at(t), l))
+
+    def block(heads=0):
+        return spec(rows, lambda t: t, heads)
+
+    before = spec(_CONV_HALO, lambda t: jnp.maximum(t * (rows // _CONV_HALO) - 1, 0))
+
+    def after(dtype, heads=0):
         n = _sublanes(dtype)
-        return pl.BlockSpec(
-            (1, n, lanes),
-            lambda l, b, t: (b, jnp.minimum((t + 1) * (rows // n), x.shape[1] // n - 1), l))
+        return spec(
+            n, lambda t: jnp.minimum((t + 1) * (rows // n), x.shape[1] // n - 1), heads)
 
     def filt(rows):
         return pl.BlockSpec((rows, lanes), lambda l, b, t: (0, l))
@@ -355,30 +407,35 @@ def _conv_call(kernel, blocks, **kwargs):
 
 
 # ``b`` is the bias [1, D] or None; with None a call's operands, its kernel's
-# body and so its lowered text are what they were before there was one.
+# body and so its lowered text are what they were before there was one. So
+# with ``blocks.heads``: at 0 the output and its cotangent lie as x does, and
+# the call is what it was before an output could lie heads first.
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
 def _conv_forward(x, w, dtype, blocks, b=None):
     grid, block, before, _, filt = _conv_specs(x, blocks)
     bias = [] if b is None else [b]
+    batch, t, width = x.shape
+    d = blocks.heads
     return _conv_call(
         _conv_fwd_kernel, blocks, grid=grid,
-        in_specs=[block, before, filt(w.shape[0])] + [filt(1)] * len(bias),
-        out_specs=block, out_shape=jax.ShapeDtypeStruct(x.shape, dtype),
+        in_specs=[block(), before, filt(w.shape[0])] + [filt(1)] * len(bias),
+        out_specs=block(d), out_shape=jax.ShapeDtypeStruct(
+            (batch, width // d, t, d) if d else x.shape, dtype),
     )(x, x, w, *bias)
 
 
 @functools.partial(jax.jit, static_argnums=3)
 def _conv_backward(x, w, dy, blocks, b=None):
     grid, block, before, after, filt = _conv_specs(x, blocks)
-    taps = w.shape[0]
+    taps, d = w.shape[0], blocks.heads
     bias = [] if b is None else [b]
     dx, dw, *db = _conv_call(
         _conv_bwd_kernel, blocks, grid=grid,
-        in_specs=[block, before, after(x.dtype), block, after(dy.dtype), filt(taps)]
+        in_specs=[block(), before, after(x.dtype), block(d), after(dy.dtype, d), filt(taps)]
         + [filt(1)] * len(bias),
-        out_specs=[block, filt(taps * _CONV_HALO)] + [filt(_CONV_HALO)] * len(bias),
+        out_specs=[block(), filt(taps * _CONV_HALO)] + [filt(_CONV_HALO)] * len(bias),
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct((taps * _CONV_HALO, x.shape[2]), F32)]
         + [jax.ShapeDtypeStruct((_CONV_HALO, x.shape[2]), F32)] * len(bias),
@@ -405,20 +462,28 @@ def _conv_silu_bwd(dtype, blocks, residuals, dy):
 _conv_silu_pallas.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
-def conv_silu(x, w, dtype=F32, bias=None):
+def conv_silu(x, w, dtype=F32, bias=None, heads=None):
     """``silu(short_conv(x, w) + bias)`` rounded once to ``dtype``: x [B, T,
-    D] float32, w [K, D], ``bias`` [D] or None (no term). On a TPU (or under
-    the interpreter) where the shape tiles it is one Pallas pass over x
-    forward and one over x and the cotangent backward, which makes the
+    D] float32, w [K, D], ``bias`` [D] or None (no term). [B, T, D] where
+    ``heads`` is None; told the lanes d of a head, the layout its caller's
+    next kernel reads, [B, D / d, T, d]: channel h * d + c at [h, :, c]. On a
+    TPU (or under the interpreter) where the shape tiles it is one Pallas pass
+    over x forward and one over x and the cotangent backward, which makes the
     pre-activation again from x (the residuals are x, w and the bias) and adds
-    the filter's gradient, and the bias's beside it, up in float32; elsewhere
-    it is what this line says, for XLA to differentiate."""
-    blocks = _conv_blocks(x, w)
+    the filter's gradient, and the bias's beside it, up in float32; the passes
+    write the output and read its cotangent a head at a time where they lie
+    heads first, so no transposed copy stands between this kernel and the
+    next. Elsewhere it is what this line says (and a transposition), for XLA
+    to differentiate."""
+    blocks = _conv_blocks(x, w, heads)
     if blocks is None:
         c = short_conv(x, w)
         if bias is not None:
             c = c + bias.astype(F32)
-        return jax.nn.silu(c).astype(dtype)
+        y = jax.nn.silu(c).astype(dtype)
+        if heads:
+            y = y.reshape(*y.shape[:2], -1, heads).transpose(0, 2, 1, 3)
+        return y
     return _conv_silu_pallas(x.astype(F32), w, jnp.dtype(dtype), blocks,
                              None if bias is None else bias[None])
 
@@ -1103,20 +1168,48 @@ def _normed_gdn_chunk(St, q, k, v, beta, g, gate, weight, *, norm,
     return St, _rms_normed(o, rms_eps) * weight * jax.nn.silu(gate.astype(F32)), T
 
 
-def _heads_on_rows(ref):
-    """A block [1, .., p, r, d] of p heads -> [p * r, d], the heads on rows."""
-    lead = (0,) * (len(ref.shape) - 3)
+def _heads_on_rows(ref, *lead):
+    """A block [1, .., p, r, d] of p heads -> [p * r, d], the heads on rows:
+    at the leading indices ``lead``, zeros where none are given."""
+    lead = lead or (0,) * (len(ref.shape) - 3)
     return jnp.concatenate([ref[(*lead, i)] for i in range(ref.shape[-3])])
 
 
-def _write_heads(ref, x):
+def _write_heads(ref, x, *lead):
     """``_heads_on_rows`` undone into a block [1, .., p, r, d]."""
-    lead = (0,) * (len(ref.shape) - 3)
+    lead = lead or (0,) * (len(ref.shape) - 3)
     for i, part in enumerate(_heads_of(x, ref.shape[-3])):
         ref[(*lead, i)] = part.astype(ref.dtype)
 
 
-def _gdn_fwd_kernel(g_ref, q_ref, k_ref, v_ref, beta_ref, gate_ref, w_ref,
+def _lanes_on_rows(ref, p):
+    """``_heads_on_rows`` of a block that lies tokens first, [1, r, p * d],
+    the p heads side by side on lanes; of a block [1, p, r, d] as it is."""
+    if len(ref.shape) == 4:
+        return _heads_on_rows(ref)
+    x = ref[0]
+    d = x.shape[1] // p
+    return jnp.concatenate([x[:, i * d:(i + 1) * d] for i in range(p)])
+
+
+def _write_lanes(ref, x, p):
+    """``_lanes_on_rows`` undone, into either block."""
+    if len(ref.shape) == 4:
+        return _write_heads(ref, x)
+    ref[0] = jnp.concatenate(_heads_of(x, p), axis=1).astype(ref.dtype)
+
+
+def _gdn_operands(qk_ref, v_ref, beta_ref, g_ref, gate_ref):
+    """A step's blocks as ``_normed_gdn_chunk`` takes them, heads on rows: q
+    and k from the one block [1, 2, p, C, dk] of both, beta and g from theirs
+    [1, p, C, 1], v and the gate from theirs, which may lie tokens first."""
+    p = beta_ref.shape[1]
+    return (_heads_on_rows(qk_ref, 0, 0), _heads_on_rows(qk_ref, 0, 1),
+            _lanes_on_rows(v_ref, p), _heads_on_rows(beta_ref),
+            _heads_on_rows(g_ref), _lanes_on_rows(gate_ref, p))
+
+
+def _gdn_fwd_kernel(g_ref, qk_ref, v_ref, beta_ref, gate_ref, w_ref,
                     o_ref, *rest, norm):
     # rest: (the states' and the inverses' outputs, the scratch) or the
     # scratch alone.
@@ -1131,17 +1224,16 @@ def _gdn_fwd_kernel(g_ref, q_ref, k_ref, v_ref, beta_ref, gate_ref, w_ref,
     if s_ref is not None:
         _write_heads(s_ref, St)
     st_scr[step], o, T = _normed_gdn_chunk(
-        St, *(_heads_on_rows(ref) for ref in
-              (q_ref, k_ref, v_ref, beta_ref, g_ref, gate_ref)),
+        St, *_gdn_operands(qk_ref, v_ref, beta_ref, g_ref, gate_ref),
         w_ref[...], norm=norm, roll=_roll_here(),
     )
-    _write_heads(o_ref, o)
+    _write_lanes(o_ref, o, p)
     if t_ref is not None:
         t_ref[0, 0, 0] = _diagonal(T, p)
 
 
-def _gdn_bwd_kernel(g_ref, q_ref, k_ref, v_ref, beta_ref, gate_ref, w_ref,
-                    s_ref, t_ref, do_ref, dq_ref, dk_ref, dv_ref, dbeta_ref,
+def _gdn_bwd_kernel(g_ref, qk_ref, v_ref, beta_ref, gate_ref, w_ref,
+                    s_ref, t_ref, do_ref, dqk_ref, dv_ref, dbeta_ref,
                     dg_ref, dgate_ref, dw_ref, dst_scr, *, norm):
     step, p = pl.program_id(2), beta_ref.shape[1]
 
@@ -1160,24 +1252,38 @@ def _gdn_bwd_kernel(g_ref, q_ref, k_ref, v_ref, beta_ref, gate_ref, w_ref,
     _, vjp = jax.vjp(
         lambda *operands: chunk(*operands)[:2],
         _heads_on_rows(s_ref),
-        *(_heads_on_rows(ref) for ref in
-          (q_ref, k_ref, v_ref, beta_ref, g_ref, gate_ref)),
+        *_gdn_operands(qk_ref, v_ref, beta_ref, g_ref, gate_ref),
         w_ref[...],
     )
     dst_scr[step], dq, dk_, dv_, dbeta, dg, dgate, dw = vjp(
-        (dst_scr[step], _heads_on_rows(do_ref).astype(F32))
+        (dst_scr[step], _lanes_on_rows(do_ref, p).astype(F32))
     )
-    for ref, x in ((dq_ref, dq), (dk_ref, dk_), (dv_ref, dv_), (dbeta_ref, dbeta),
-                   (dg_ref, dg), (dgate_ref, dgate)):
-        _write_heads(ref, x)
+    _write_heads(dqk_ref, dq, 0, 0)
+    _write_heads(dqk_ref, dk_, 0, 1)
+    _write_heads(dbeta_ref, dbeta)
+    _write_heads(dg_ref, dg)
+    _write_lanes(dv_ref, dv_, p)
+    _write_lanes(dgate_ref, dgate, p)
     dw_ref[0] += dw
 
 
-def _gdn_specs(heads, dk, dv, chunk_of):
+def _values_lie_tokens_first(heads, dv):
+    """Whether v, the gate and o (and their cotangents) go through the scan's
+    kernels as [B, T, H * dv], as the convolution and the matmuls that write
+    and read them have them: where a grid step's heads are whole vregs of
+    lanes side by side (two of 192 are three). A block [64, 384] lies in HBM
+    and in VMEM as it is; two of [64, 192] are padded to 256 lanes each."""
+    return (_heads_a_step(heads) * dv) % _LANES == 0
+
+
+def _gdn_specs(heads, dk, dv, chunk_of, tokens_first=False):
     """BlockSpecs over grid (batch, step, heads // p), as ``_specs``, for
     operands that lie [B, H, T, d]: a block is p heads' chunk, whole in its
-    last extent (d, or 1 for beta and g). The states lie [B, N, H, dv, dk];
-    the chunks' inverses and the norm's weight are ``_specs``'s."""
+    last extent (d, or 1 for beta and g). q and k are one array [B, 2, H, T,
+    dk], as their one convolution writes them, and one block of both. v, the
+    gate and o lie [B, H, T, dv] too or, ``tokens_first``, [B, T, H * dv]: a
+    block is a chunk's rows of the p heads' lanes. The states lie [B, N, H,
+    dv, dk]; the chunks' inverses and the norm's weight are ``_specs``'s."""
     p = _heads_a_step(heads)
     shared = _specs(heads, dk, dv, chunk_of)
 
@@ -1185,7 +1291,11 @@ def _gdn_specs(heads, dk, dv, chunk_of):
         return pl.BlockSpec((1, p, CHUNK, d), lambda b, n, h: (b, h, chunk_of(n), 0))
 
     return {
-        "k": rows(dk), "v": rows(dv), "scalar": rows(1),
+        "qk": pl.BlockSpec((1, 2, p, CHUNK, dk),
+                           lambda b, n, h: (b, 0, h, chunk_of(n), 0)),
+        "scalar": rows(1),
+        "v": pl.BlockSpec((1, CHUNK, p * dv), lambda b, n, h: (b, chunk_of(n), h))
+        if tokens_first else rows(dv),
         "state": pl.BlockSpec((1, 1, p, dv, dk),
                               lambda b, n, h: (b, chunk_of(n), h, 0, 0)),
         "inverse": shared["inverse"], "weight": shared["weight"],
@@ -1194,15 +1304,14 @@ def _gdn_specs(heads, dk, dv, chunk_of):
     }
 
 
-def _gdn_forward_pallas(q, k, v, g, beta, gate, weight, norm, states):
-    batch, heads, t, dk = q.shape
-    dv, n = v.shape[3], t // CHUNK
-    s = _gdn_specs(heads, dk, dv, lambda i: i)
+def _gdn_forward_pallas(qk, v, g, beta, gate, weight, norm, states):
+    batch, _, heads, t, dk = qk.shape
+    dv, n = weight.shape[1], t // CHUNK
+    s = _gdn_specs(heads, dk, dv, lambda i: i, v.ndim == 3)
     return pl.pallas_call(
         functools.partial(_gdn_fwd_kernel, norm=norm),
         grid=(batch, n, s["steps"]),
-        in_specs=[s["scalar"], s["k"], s["k"], s["v"], s["scalar"], s["v"],
-                  s["weight"]],
+        in_specs=[s["scalar"], s["qk"], s["v"], s["scalar"], s["v"], s["weight"]],
         out_specs=[s["v"], s["state"], s["inverse"]][:1 + 2 * states],
         out_shape=[
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -1213,66 +1322,67 @@ def _gdn_forward_pallas(q, k, v, g, beta, gate, weight, norm, states):
         scratch_shapes=[s["states"]],
         compiler_params=_params(),
         interpret=_attention._interpret(),
-    )(g, q, k, v, beta, gate, weight)
+    )(g, qk, v, beta, gate, weight)
 
 
-def _gdn_backward_pallas(q, k, v, g, beta, gate, weight, states, inverses, do,
+def _gdn_backward_pallas(qk, v, g, beta, gate, weight, states, inverses, do,
                          norm):
-    batch, heads, t, dk = q.shape
-    dv, n = v.shape[3], t // CHUNK
-    s = _gdn_specs(heads, dk, dv, lambda i: n - 1 - i)
+    batch, _, heads, t, dk = qk.shape
+    dv, n = weight.shape[1], t // CHUNK
+    s = _gdn_specs(heads, dk, dv, lambda i: n - 1 - i, v.ndim == 3)
     return pl.pallas_call(
         functools.partial(_gdn_bwd_kernel, norm=norm),
         grid=(batch, n, s["steps"]),
-        in_specs=[s["scalar"], s["k"], s["k"], s["v"], s["scalar"], s["v"],
+        in_specs=[s["scalar"], s["qk"], s["v"], s["scalar"], s["v"],
                   s["weight"], s["state"], s["inverse"], s["v"]],
-        out_specs=[s["k"], s["k"], s["v"], s["scalar"], s["scalar"], s["v"],
+        out_specs=[s["qk"], s["v"], s["scalar"], s["scalar"], s["v"],
                    s["dweight"]],
         out_shape=[
             *(jax.ShapeDtypeStruct(x.shape, x.dtype)
-              for x in (q, k, v, beta, g, gate)),
+              for x in (qk, v, beta, g, gate)),
             jax.ShapeDtypeStruct((batch, *weight.shape), F32),
         ],
         scratch_shapes=[s["states"]],
         compiler_params=_params(),
         interpret=_attention._interpret(),
-    )(g, q, k, v, beta, gate, weight, states, inverses, do)
+    )(g, qk, v, beta, gate, weight, states, inverses, do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _gdn_pallas(q, k, v, g, beta, gate, weight, norm):
-    """The gated, normalised o [B, H, T, dv] from q, k [B, H, T, dk] raw, v
-    and the gate [B, H, T, dv], g and beta [B, H, T, 1] and the norm's weight
-    [1, dv], T a whole number of chunks. As ``_kda_pallas``: outside a
-    gradient neither states nor inverses are written; under one the forward
-    rule writes and names both and o (``gdn_o``, ``gdn_states``, ``gdn_t``:
-    models/llama.py REPLAY_KEEPS), so that a replay which keeps the three
-    runs no forward kernel."""
-    return _gdn_forward_pallas(q, k, v, g, beta, gate, weight, norm,
-                               states=False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _gdn_pallas(qk, v, g, beta, gate, weight, norm):
+    """The gated, normalised o from q and k [B, 2, H, T, dk] raw, g and beta
+    [B, H, T, 1], the norm's weight [1, dv], and v and the gate, which o lies
+    as: both [B, H, T, dv] or both, tokens first, [B, T, H * dv]; T a whole
+    number of chunks. As ``_kda_pallas``: outside a gradient neither states
+    nor inverses are written; under one the forward rule writes and names
+    both and o (``gdn_o``, ``gdn_states``, ``gdn_t``: models/llama.py
+    REPLAY_KEEPS), so that a replay which keeps the three runs no forward
+    kernel."""
+    return _gdn_forward_pallas(qk, v, g, beta, gate, weight, norm, states=False)[0]
 
 
-def _gdn_pallas_fwd(q, k, v, g, beta, gate, weight, norm):
-    o, states, inverses = _gdn_forward_pallas(q, k, v, g, beta, gate, weight,
+def _gdn_pallas_fwd(qk, v, g, beta, gate, weight, norm):
+    o, states, inverses = _gdn_forward_pallas(qk, v, g, beta, gate, weight,
                                               norm, states=True)
     o, states = checkpoint_name(o, "gdn_o"), checkpoint_name(states, "gdn_states")
     inverses = checkpoint_name(inverses, "gdn_t")
-    return o, (q, k, v, g, beta, gate, weight, states, inverses)
+    return o, (qk, v, g, beta, gate, weight, states, inverses)
 
 
 def _gdn_pallas_bwd(norm, residuals, do):
-    dq, dk, dv, dbeta, dg, dgate, dw = _gdn_backward_pallas(
-        *residuals, do.astype(residuals[2].dtype), norm
+    dqk, dv, dbeta, dg, dgate, dw = _gdn_backward_pallas(
+        *residuals, do.astype(residuals[1].dtype), norm
     )
-    return dq, dk, dv, dg, dbeta, dgate, dw.sum(0)
+    return dqk, dv, dg, dbeta, dgate, dw.sum(0)
 
 
 _gdn_pallas.defvjp(_gdn_pallas_fwd, _gdn_pallas_bwd)
 
 
-def _gdn_xla(q, k, v, g, beta, gate, weight, norm):
+def _gdn_xla(qk, v, g, beta, gate, weight, norm):
     """The same function of the same layouts under ``lax.scan``, one head a
     call, for JAX to differentiate: where there is no TPU."""
+    q, k = qk[:, 0], qk[:, 1]
     batch, heads, t, dk = q.shape
 
     def chunks(x):  # [B, H, T, d] -> [N, B, H, C, d]
@@ -1290,28 +1400,42 @@ def _gdn_xla(q, k, v, g, beta, gate, weight, norm):
     return jnp.moveaxis(o, 0, 2).reshape(v.shape).astype(v.dtype)
 
 
-def chunk_gdn(q, k, v, g, beta, gate, weight, *, scale, rms_eps, l2_eps=1e-6):
+def chunk_gdn(qk, v, g, beta, gate, weight, *, scale, rms_eps, l2_eps=1e-6):
     """A Gated DeltaNet mixer from its convolutions' outputs to its output
     projection's input, as ``chunk_kda`` is KDA's: the scalar-decay
     recurrence above, chunked, of ``l2norm(q, l2_eps) * scale`` and
     ``l2norm(k, l2_eps)``, then o's RMSNorm over a head's channels
-    (``weight`` [dv]) times ``silu(gate)``. q, k [B, T, H, dk] raw float32;
-    v, gate [B, T, H, dv], the gate before its SiLU; g [B, T, H] float32
-    log-decay (<= 0), one a head and token; beta [B, T, H] in (0, 2). Returns
-    [B, T, H, dv] in v's dtype. Differentiable in all seven."""
-    t = q.shape[1]
+    (``weight`` [dv]) times ``silu(gate)``. q and k come as their one
+    convolution writes them for the kernels, heads first (a key head of 96
+    lanes is no whole number of vregs, nor are two, so a block must be whole
+    in its last extent): qk [B, 2, H, T, dk] raw float32, q then k
+    (``conv_silu(..., heads=dk)``'s [B, 2 H, T, dk], its major extent split).
+    Everything else comes and goes tokens first, as the convolution and the
+    matmuls around the scan have it: v and the gate (before its SiLU) [B, T,
+    H, dv]; g [B, T, H] float32 log-decay (<= 0), one a head and token; beta
+    [B, T, H] in (0, 2). Returns [B, T, H, dv] in v's dtype. Where a grid
+    step's value heads are whole vregs side by side
+    (``_values_lie_tokens_first``) the kernels read v and the gate and write
+    o as they lie; elsewhere they are transposed here. Differentiable in all
+    six (qk's cotangent lies as qk does)."""
+    batch, t, heads, dv = v.shape
     pad = -t % CHUNK
     if pad:
         # Padding tokens write nothing (beta 0) and decay nothing (g 0).
         widths = ((0, 0), (0, pad), (0, 0), (0, 0))
-        q, k, v, gate = (jnp.pad(x, widths) for x in (q, k, v, gate))
+        qk = jnp.pad(qk, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0)))
+        v, gate = (jnp.pad(x, widths) for x in (v, gate))
         g, beta = (jnp.pad(x, widths[:3]) for x in (g, beta))
     heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     scalar = lambda x: x.astype(F32).transpose(0, 2, 1)[..., None]  # noqa: E731
-    run = _gdn_pallas if _attention._on_tpu() or _attention._interpret() else _gdn_xla
-    o = run(heads_first(q), heads_first(k), heads_first(v), scalar(g),
-            scalar(beta), heads_first(gate), weight.astype(F32)[None],
-            (scale, l2_eps, rms_eps))
+    closing = (weight.astype(F32)[None], (scale, l2_eps, rms_eps))
+    kernels = _attention._on_tpu() or _attention._interpret()
+    if kernels and _values_lie_tokens_first(heads, dv):
+        flat = lambda x: x.reshape(batch, t + pad, heads * dv)  # noqa: E731
+        o = _gdn_pallas(qk, flat(v), scalar(g), scalar(beta), flat(gate), *closing)
+        return o.reshape(gate.shape)[:, :t]
+    run = _gdn_pallas if kernels else _gdn_xla
+    o = run(qk, heads_first(v), scalar(g), scalar(beta), heads_first(gate), *closing)
     return heads_first(o)[:, :t]
 
 

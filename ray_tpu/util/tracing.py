@@ -65,7 +65,7 @@ MOE_SHARED = "shared"  # inside moe: the shared expert every token passes
 # The mixers' flax names, which reach op_name as the attention's "attn" does.
 KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer; Solar-Open2's KDA layers too)
 MLA = "mla"  # the latent-attention mixer (models/mla.py MLAMixer)
-GDN = "gdn"  # the scalar-decay gated delta-rule mixer (models/olmo_hybrid.py GDNMixer); conv, gate and scan inside it as inside kda, scan holding ops/kda.py chunk_gdn and the transpositions around it
+GDN = "gdn"  # the scalar-decay gated delta-rule mixer (models/olmo_hybrid.py GDNMixer); conv, gate and scan inside it as inside kda, scan holding ops/kda.py chunk_gdn and the decay's and beta's layout for its kernels (q and k come heads first from conv; v, the gate and o go through as they lie)
 LIGHTNING = "lightning"  # the decay-only linear-attention mixer (models/minicpm_sala.py LightningMixer): projections, qk_norm and rotary scopes, ops/kda.py chunk_lightning (its kernels hold o's norm and the output gate) and the transpositions around it
 SPARSE = "sparse"  # the block-sparse top-k softmax mixer (models/minicpm_sala.py SparseAttention): projections, qk_norm, select, ops/attention.py sparse_attention's kernels, out_gate
 SPARSE_SELECT = "select"  # inside sparse, where T > dense_len: ops/attention.py select_blocks (compressed keys, the scores of every head against them, their soft-max, the sum over a group's heads, the max-pool to blocks, the forced blocks, top-k, the packed bitmap); nothing of it is differentiated

@@ -22,6 +22,8 @@ that T against one that remakes it, and the matmuls a backward grid step
 holds.
 """
 import functools
+import hashlib
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -561,37 +563,58 @@ def test_short_conv_is_causal_and_depthwise():
     assert (np.delete(y2, 2, axis=2) == np.delete(y, 2, axis=2)).all()
 
 
-def conv_reference(x, w, dtype):
-    return jax.nn.silu(kda.short_conv(x, w)).astype(dtype)
+def tokens_first(y):
+    """[B, D / d, T, d], heads first, as [B, T, D]: channel h * d + c from [h, :, c]."""
+    return y.transpose(0, 2, 1, 3).reshape(y.shape[0], y.shape[2], -1)
 
 
-def conv_inputs(batch, t, channels, dtype, seed=0):
-    """A projection, a filter and a cotangent of the output's dtype."""
+def heads_first(y, d):
+    """``tokens_first`` undone (d None: nothing)."""
+    return y if d is None else y.reshape(*y.shape[:2], -1, d).transpose(0, 2, 1, 3)
+
+
+def conv_reference(x, w, dtype, heads=None):
+    return heads_first(jax.nn.silu(kda.short_conv(x, w)).astype(dtype), heads)
+
+
+def conv_inputs(batch, t, channels, dtype, seed=0, heads=None):
+    """A projection, a filter and a cotangent of the output's dtype and
+    layout."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    dy = jax.random.normal(keys[2], (batch, t, channels), jnp.float32).astype(dtype)
     return (jax.random.normal(keys[0], (batch, t, channels), jnp.float32),
             jax.random.uniform(keys[1], (4, channels), jnp.float32, -0.5, 0.5),
-            jax.random.normal(keys[2], (batch, t, channels), jnp.float32).astype(dtype))
+            heads_first(dy, heads))
 
 
-def conv_and_gradients(fn, x, w, dy):
-    y, vjp = jax.vjp(lambda x, w: fn(x, w, dy.dtype), x, w)
+def conv_and_gradients(fn, x, w, dy, **layout):
+    y, vjp = jax.vjp(lambda x, w: fn(x, w, dy.dtype, **layout), x, w)
     return (y, *vjp(dy))
 
 
-# (batch, tokens, channels, the output's dtype, the kernels' blocks or None):
-# three blocks of 512 rows and two of 128 lanes, every block eight tiles of 64
-# rows, so the halo crosses tile and block edges both ways; one tile of 16
-# rows, most of it the filter's reach from t < 0; two batch rows, over which
-# and over whose blocks the filter's gradient adds up; v's rounding to
-# bfloat16 (its cotangent arrives in bfloat16, 16 rows a sublane tile); and
-# shapes that do not tile, in tokens and in channels.
+# (batch, tokens, channels, the output's dtype, the lanes of a head where the
+# output lies heads first, the kernels' blocks or None): three blocks of 512
+# rows and two of 128 lanes, every block eight tiles of 64 rows, so the halo
+# crosses tile and block edges both ways; one tile of 16 rows, most of it the
+# filter's reach from t < 0; two batch rows, over which and over whose blocks
+# the filter's gradient adds up; v's rounding to bfloat16 (its cotangent
+# arrives in bfloat16, 16 rows a sublane tile); and shapes that do not tile,
+# in tokens and in channels. Heads first: Olmo-Hybrid's key heads, four of 96
+# lanes to a block of 384 (a head's lanes begin inside a vreg), two blocks of
+# rows and two of lanes; its value heads, two of 192 to a block, bfloat16 out
+# and back, two batch rows of three blocks; heads of whole vregs; and 192
+# channels, where no whole vregs are whole heads of 96.
 CONV_CASES = {
-    "three-blocks": (1, 1536, 256, jnp.float32, (512, 256, 64, True)),
-    "one-tile": (1, 16, 128, jnp.float32, (16, 128, 16, True)),
-    "batch-of-2": (2, 256, 128, jnp.float32, (256, 128, 64, True)),
-    "bfloat16-out": (2, 192, 128, jnp.bfloat16, (64, 128, 64, True)),
-    "tokens-do-not-tile": (2, 100, 128, jnp.float32, None),
-    "lanes-do-not-tile": (2, 64, 96, jnp.bfloat16, None),
+    "three-blocks": (1, 1536, 256, jnp.float32, None, (512, 256, 64, True, 0)),
+    "one-tile": (1, 16, 128, jnp.float32, None, (16, 128, 16, True, 0)),
+    "batch-of-2": (2, 256, 128, jnp.float32, None, (256, 128, 64, True, 0)),
+    "bfloat16-out": (2, 192, 128, jnp.bfloat16, None, (64, 128, 64, True, 0)),
+    "tokens-do-not-tile": (2, 100, 128, jnp.float32, None, None),
+    "lanes-do-not-tile": (2, 64, 96, jnp.bfloat16, None, None),
+    "heads-of-96": (1, 1024, 768, jnp.float32, 96, (512, 384, 64, True, 96)),
+    "heads-of-192-bfloat16": (2, 192, 384, jnp.bfloat16, 192, (64, 384, 64, True, 192)),
+    "heads-of-128": (1, 256, 256, jnp.float32, 128, (256, 256, 64, True, 128)),
+    "heads-do-not-tile": (2, 64, 192, jnp.float32, 96, None),
 }
 
 
@@ -600,17 +623,22 @@ def test_the_fused_convolution_is_silu_of_short_conv_and_its_gradients(monkeypat
     """Under the interpreter ``conv_silu`` is the Pallas pass where the shape
     tiles and ``silu(short_conv(x, w))`` as XLA has it where it does not:
     the values and the gradients in x and in w, to float32's reassociation
-    (the filter's gradient is a sum over every token, in another order)."""
+    (the filter's gradient is a sum over every token, in another order).
+    Told a head's lanes, the output and its cotangent lie [B, D / d, T, d]:
+    ``silu(short_conv)`` transposed, either way."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    batch, t, channels, dtype, blocks = CONV_CASES[case]
-    x, w, dy = conv_inputs(batch, t, channels, dtype)
-    assert kda._conv_blocks(x, w) == blocks
-    both = jax.make_jaxpr(lambda *a: conv_and_gradients(kda.conv_silu, *a))(x, w, dy)
+    batch, t, channels, dtype, heads, blocks = CONV_CASES[case]
+    x, w, dy = conv_inputs(batch, t, channels, dtype, heads=heads)
+    assert kda._conv_blocks(x, w, heads) == blocks
+    both = jax.make_jaxpr(
+        lambda *a: conv_and_gradients(kda.conv_silu, *a, heads=heads))(x, w, dy)
     names = [eqn.params["jaxpr"].debug_info.func_name for eqn in pallas_calls(both.jaxpr, [])]
     assert names == (["_conv_fwd_kernel", "_conv_bwd_kernel"] if blocks else [])
-    y, dx, dw = conv_and_gradients(kda.conv_silu, x, w, dy)
-    y_ref, dx_ref, dw_ref = conv_and_gradients(conv_reference, x, w, dy)
+    y, dx, dw = conv_and_gradients(kda.conv_silu, x, w, dy, heads=heads)
+    y_ref, dx_ref, dw_ref = conv_and_gradients(conv_reference, x, w, dy, heads=heads)
     assert (y.dtype, dx.dtype, dw.dtype) == (dtype, jnp.float32, jnp.float32)
+    assert y.shape == dy.shape == (
+        (batch, channels // heads, t, heads) if heads else (batch, t, channels))
     if blocks is None:
         assert all(bool((a == b).all()) for a, b in ((y, y_ref), (dx, dx_ref), (dw, dw_ref)))
         return
@@ -623,25 +651,33 @@ def test_the_fused_convolution_is_silu_of_short_conv_and_its_gradients(monkeypat
     np.testing.assert_allclose(dw, dw_ref, rtol=1e-5, atol=1e-5 * float(jnp.abs(dw_ref).max()))
 
 
-def test_the_fused_convolution_is_causal_across_its_blocks_and_depthwise(monkeypatch):
+@pytest.mark.parametrize("heads", [None, 64], ids=["tokens-first", "heads-of-64"])
+def test_the_fused_convolution_is_causal_across_its_blocks_and_depthwise(monkeypatch, heads):
     """A bump at token 7 moves nothing before it and nothing after token 10,
     one at a block's last token moves the next block's first three (the
     halo), and neither moves another channel or batch row; the gradient in x
-    reaches back as far and no further. Blocks of 32 rows in tiles of 16."""
+    reaches back as far and no further. Blocks of 32 rows in tiles of 16;
+    heads first, two heads of 64 lanes to the block's 128, read back as they
+    lie tokens first."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setattr(kda, "_CONV_ROWS", 32)
     monkeypatch.setattr(kda, "_CONV_TILE", 16)
     x, w, _ = conv_inputs(2, 96, 128, jnp.float32, seed=1)
-    assert kda._conv_blocks(x, w) == (32, 128, 16, True)
-    y = np.asarray(kda.conv_silu(x, w))
+    assert kda._conv_blocks(x, w, heads) == (32, 128, 16, True, heads or 0)
+
+    def conv(x):
+        y = kda.conv_silu(x, w, heads=heads)
+        return tokens_first(y) if heads else y
+
+    y = np.asarray(conv(x))
     np.testing.assert_allclose(y, conv_reference(x, w, jnp.float32), rtol=1e-5, atol=1e-6)
     for token in (7, 15, 31, 95):
-        moved = np.asarray(kda.conv_silu(x.at[1, token, 2].add(1.0), w)) != y
+        moved = np.asarray(conv(x.at[1, token, 2].add(1.0))) != y
         assert moved[1, token:token + 4, 2].all()
         moved[1, token:token + 4, 2] = False
         assert not moved.any(), token
         # dy at tokens token .. token + 3 reaches x at token, and at no other
-        reach = jax.grad(lambda x: kda.conv_silu(x, w)[1, token:token + 4, 2].sum())(x)
+        reach = jax.grad(lambda x: conv(x)[1, token:token + 4, 2].sum())(x)
         reached = np.argwhere(np.asarray(reach) != 0)
         assert {tuple(at[[0, 2]]) for at in reached} == {(1, 2)}
         assert set(reached[:, 1]) == set(range(max(token - 3, 0), min(token + 4, 96)))
@@ -664,7 +700,17 @@ def test_the_gate_is_a_negative_log_decay_per_channel():
 # of widths of their own that fill no vreg, SiLU for the output gate's
 # sigmoid. Against the same token-by-token recurrence, fed g broadcast.
 GDK, GDV = 24, 48
-chunk_gdn = functools.partial(kda.chunk_gdn, scale=GDK ** -0.5, rms_eps=RMS_EPS)
+
+
+def chunk_gdn(q, k, v, g, beta, gate, weight):
+    """``kda.chunk_gdn`` of q and k that lie tokens first, [B, T, H, dk], as
+    every oracle here has them: they go in as the one array [B, 2, H, T, dk],
+    heads first, as the mixer's convolution writes them, and the seven
+    gradients come back through the transpositions."""
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    return kda.chunk_gdn(
+        jnp.stack([heads_first(q), heads_first(k)], 1), v, g, beta, gate, weight,
+        scale=GDK ** -0.5, rms_eps=RMS_EPS)
 
 
 def gdn_inputs(t, decay, seed=0, heads=H):
@@ -733,6 +779,31 @@ def test_the_scalar_decay_kernels_in_interpret_mode_are_the_recurrence(
     gdn_compare(t, decay, heads)
 
 
+def test_v_the_gate_and_o_stay_tokens_first_where_a_steps_heads_are_whole_vregs(monkeypatch):
+    """Two value heads of 64 lanes are one vreg side by side: v and the gate
+    go into both kernels and o and their cotangents come out of them as [B,
+    T, H * dv], as the convolution and the matmuls around the scan have
+    them, and a grid step takes its two heads' lanes apart and puts them
+    together in VMEM. The same recurrence, forward and all seven cotangents;
+    at 48 lanes a head (every other case here) the three lie heads first, [B,
+    H, T, dv], transposed by XLA."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(sys.modules[__name__], "GDV", 64)
+    lie = kda._values_lie_tokens_first
+    assert lie(2, 64) and not lie(2, 48) and lie(30, 192) and not lie(15, 192)
+    gdn_compare(128, 0.3, heads=2)
+    args = gdn_inputs(128, 0.3, heads=2)
+    both = jax.make_jaxpr(jax.grad(lambda *a: chunk_gdn(*a).sum(), argnums=(2, 5)))(*args)
+    forward, backward = pallas_calls(both.jaxpr, [])
+    flat, heads_first = (B, 128, 2 * 64), (B, 2, 128, 64)
+    assert [v.aval.shape for v in forward.invars].count(flat) == 2  # v, the gate
+    assert forward.outvars[0].aval.shape == flat  # o
+    assert [v.aval.shape for v in backward.invars].count(flat) == 3  # and do
+    assert [v.aval.shape for v in backward.outvars].count(flat) == 2  # v's, the gate's
+    for call in (forward, backward):
+        assert heads_first not in [v.aval.shape for v in (*call.invars, *call.outvars)]
+
+
 @pytest.mark.parametrize("path", ["xla", "pallas"])
 def test_the_scalar_road_is_the_kda_road_fed_g_broadcast_over_channels(monkeypatch, path):
     """One function two ways: ``chunk_kda`` given the scalar on every channel
@@ -752,21 +823,84 @@ def test_the_scalar_kernels_take_one_decay_a_head_and_token(monkeypatch):
     """Forward (with its states and inverses under a gradient, o alone outside
     one) and backward, under names of their own, two heads a step; no operand
     or result of either is g on a head's channels: the decay and its cotangent
-    are [B, H, T, 1]."""
+    are [B, H, T, 1]. q and k are one operand [B, 2, H, T, dk], and their
+    cotangents one result."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     args = gdn_inputs(128, 0.3, heads=4)
     forward = jax.make_jaxpr(lambda *a: chunk_gdn(*a))(*args)
     assert pallas_outputs(forward.jaxpr) == [1]
     both = jax.make_jaxpr(jax.grad(lambda *a: chunk_gdn(*a).sum()))(*args)
     calls = pallas_calls(both.jaxpr, [])
-    assert [len(eqn.outvars) for eqn in calls] == [3, 7]
+    assert [len(eqn.invars) for eqn in calls] == [6, 9]
+    assert [len(eqn.outvars) for eqn in calls] == [3, 6]
     assert [eqn.params["grid_mapping"].grid for eqn in calls] == [(B, 2, 2)] * 2
     names = [eqn.params["jaxpr"].debug_info.func_name for eqn in calls]
     assert names == ["_gdn_fwd_kernel", "_gdn_bwd_kernel"]
     for eqn in calls:
         shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
         assert shapes.count((B, 4, 128, 1)) == (2 if eqn is calls[0] else 4)  # g, beta (and theirs)
-        assert (B, 4, 128, GDK) in shapes and (B, 128, 4 * GDK) not in shapes
+        assert shapes.count((B, 2, 4, 128, GDK)) == (1 if eqn is calls[0] else 2)
+        assert (B, 4, 128, GDK) not in shapes and (B, 128, 4 * GDK) not in shapes
+        # two heads of 48 lanes fill no vreg: v, the gate and o heads first
+        assert shapes.count((B, 4, 128, GDV)) == (3 if eqn is calls[0] else 5)
+
+
+def test_the_convolutions_heads_first_output_is_what_the_scalar_kernels_read(monkeypatch):
+    """The mixer's road, projections to o: ``conv_silu(..., heads=dk)`` of the
+    fused q-with-k projection, a reshape of its major extent, ``conv_silu`` of
+    v's as it lies, ``chunk_gdn``. It is ``silu(short_conv)`` sliced into q
+    and k, split into heads and transposed by XLA, then the same scan: o and
+    the gradients in both projections, both filters and the four other
+    operands. And no transposition of a q, k or v stands in its trace,
+    forward or backward, nor (two heads of 64 lanes being a vreg) of the gate
+    or o: only the decay and beta turn."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    heads, dk, dv, t = 2, 32, 64, 128
+    r = np.random.default_rng(3)
+    draw = lambda *shape: jnp.asarray(r.normal(size=shape), jnp.float32)  # noqa: E731
+    filt = lambda d: jnp.asarray(r.uniform(-0.5, 0.5, size=(4, d)), jnp.float32)  # noqa: E731
+    operands = (
+        draw(B, t, 2 * heads * dk), filt(2 * heads * dk), draw(B, t, heads * dv),
+        filt(heads * dv), -0.3 * jnp.asarray(r.uniform(0.5, 1.5, size=(B, t, heads)), jnp.float32),
+        2.0 * jax.nn.sigmoid(draw(B, t, heads)), draw(B, t, heads, dv), 1.0 + 0.3 * draw(dv))
+    scan = functools.partial(kda.chunk_gdn, scale=dk ** -0.5, rms_eps=RMS_EPS)
+
+    def by_the_kernels(qk, qk_filter, v, v_filter, *rest):
+        qk = kda.conv_silu(qk, qk_filter, heads=dk).reshape(B, 2, heads, t, dk)
+        return scan(qk, kda.conv_silu(v, v_filter).reshape(B, t, heads, dv), *rest)
+
+    def by_xla(qk, qk_filter, v, v_filter, *rest):
+        qk = conv_reference(qk, qk_filter, jnp.float32).reshape(B, t, 2, heads, dk)
+        v = conv_reference(v, v_filter, jnp.float32).reshape(B, t, heads, dv)
+        return scan(qk.transpose(0, 2, 3, 1, 4), v, *rest)
+
+    w = draw(B, t, heads, dv)
+    got, got_grads = jax.value_and_grad(
+        lambda *a: jnp.sum(by_the_kernels(*a) * w), argnums=range(8))(*operands)
+    want, want_grads = jax.value_and_grad(
+        lambda *a: jnp.sum(by_xla(*a) * w), argnums=range(8))(*operands)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    for a, b in zip(got_grads, want_grads):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4 * float(jnp.abs(b).max()))
+
+    def transposed(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "transpose":
+                found.append(eqn.invars[0].aval.shape)
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    transposed(sub, found)
+        return found
+
+    both = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(by_the_kernels(*a) * w), argnums=range(8)))(*operands)
+    turned = transposed(both.jaxpr, [])
+    assert turned and set(turned) <= {(B, t, heads), (B, heads, t)}, turned
+    names = [eqn.params["jaxpr"].debug_info.func_name for eqn in pallas_calls(both.jaxpr, [])]
+    assert sorted(names) == sorted(
+        ["_conv_fwd_kernel"] * 2 + ["_gdn_fwd_kernel", "_gdn_bwd_kernel"]
+        + ["_conv_bwd_kernel"] * 2)
 
 
 def test_a_strong_scalar_decay_neither_overflows_nor_loses_the_state():
@@ -790,6 +924,13 @@ def test_the_convolutions_lanes_are_the_most_vregs_that_divide_the_channels(monk
     assert (lanes(5760).lanes, lanes(4096).lanes, lanes(8192).lanes, lanes(384).lanes) == (
         384, 512, 512, 384)
     assert lanes(2880) is None
+    # whole heads too, where the output lies heads first: four of 96 or two of
+    # 192 are the 384, 128 fill the 512, and 96 of 4,096 channels fit no block
+    heads = lambda d, n: kda._conv_blocks(  # noqa: E731
+        jax.ShapeDtypeStruct((1, 8192, d), jnp.float32), w, n)
+    assert (heads(5760, 96).lanes, heads(5760, 192).lanes, heads(4096, 128).lanes) == (
+        384, 384, 512)
+    assert heads(4096, 96) is None
     x, wts, _ = conv_inputs(1, 64, 384, jnp.float32)
     np.testing.assert_allclose(
         kda.conv_silu(x, wts), conv_reference(x, wts, jnp.float32), rtol=1e-6, atol=1e-6)
@@ -916,17 +1057,19 @@ def biased_reference(x, w, b, dtype):
 
 
 @pytest.mark.parametrize("case", ["three-blocks", "batch-of-2", "bfloat16-out",
-                                  "tokens-do-not-tile"])
+                                  "tokens-do-not-tile", "heads-of-96",
+                                  "heads-of-192-bfloat16", "heads-do-not-tile"])
 def test_the_fused_convolution_adds_its_bias_before_the_silu(monkeypatch, case):
     """``conv_silu(..., bias=b)`` is ``silu(short_conv(x, w) + b)``: the value
     and the gradients in x, in the filter and in the bias (dz's own sum over
     batch and time, added up in float32 where the filter's is), by the kernels
-    where the shape tiles and by XLA where it does not."""
+    where the shape tiles and by XLA where it does not, the output tokens
+    first or heads first."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    batch, t, channels, dtype, blocks = CONV_CASES[case]
-    x, w, dy = conv_inputs(batch, t, channels, dtype)
+    batch, t, channels, dtype, heads, blocks = CONV_CASES[case]
+    x, w, dy = conv_inputs(batch, t, channels, dtype, heads=heads)
     b = jax.random.uniform(jax.random.PRNGKey(7), (channels,), jnp.float32, -0.5, 0.5)
-    run = lambda x, w, b: kda.conv_silu(x, w, dtype, b)  # noqa: E731
+    run = lambda x, w, b: kda.conv_silu(x, w, dtype, b, heads)  # noqa: E731
     both = jax.make_jaxpr(lambda *a: jax.vjp(run, *a)[1](dy))(x, w, b)
     calls = pallas_calls(both.jaxpr, [])
     names = [eqn.params["jaxpr"].debug_info.func_name for eqn in calls]
@@ -935,7 +1078,7 @@ def test_the_fused_convolution_adds_its_bias_before_the_silu(monkeypatch, case):
         assert [len(eqn.invars) for eqn in calls] == [4, 7]
         assert [len(eqn.outvars) for eqn in calls] == [1, 3]
     y, vjp = jax.vjp(run, x, w, b)
-    y_ref, vjp_ref = jax.vjp(lambda *a: biased_reference(*a, dtype), x, w, b)
+    y_ref, vjp_ref = jax.vjp(lambda *a: heads_first(biased_reference(*a, dtype), heads), x, w, b)
     np.testing.assert_allclose(
         y.astype(jnp.float32), y_ref.astype(jnp.float32),
         rtol=1e-2 if dtype == jnp.bfloat16 else 1e-5, atol=1e-6)
@@ -945,7 +1088,7 @@ def test_the_fused_convolution_adds_its_bias_before_the_silu(monkeypatch, case):
             got, want, rtol=1e-5, atol=1e-5 * float(jnp.abs(want).max()), err_msg=name)
     # and the bias is not nothing: without it the output is another
     assert float(jnp.abs(y.astype(jnp.float32)
-                         - kda.conv_silu(x, w, dtype).astype(jnp.float32)).max()) > 0.1
+                         - kda.conv_silu(x, w, dtype, heads=heads).astype(jnp.float32)).max()) > 0.1
 
 
 def test_without_a_bias_the_convolution_lowers_what_it_lowered(monkeypatch):
@@ -967,6 +1110,58 @@ def test_without_a_bias_the_convolution_lowers_what_it_lowered(monkeypatch):
     y_ref, dx_ref, dw_ref = conv_and_gradients(conv_reference, x, w, dy)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(dx, dx_ref, rtol=1e-5, atol=1e-6)
+
+
+def conv_calls_text(t, channels, dtype, biased):
+    """What ``conv_silu`` and its gradient trace to at a shape, kernels and
+    all: each ``pallas_call``'s kernel as a jaxpr, its grid, and every
+    operand's and result's block, index map and array."""
+    x = jax.ShapeDtypeStruct((1, t, channels), jnp.float32)
+    w = jax.ShapeDtypeStruct((4, channels), jnp.float32)
+    b = [jax.ShapeDtypeStruct((channels,), jnp.float32)] * biased
+    dy = jax.ShapeDtypeStruct((1, t, channels), dtype)
+    both = jax.make_jaxpr(lambda x, w, dy, *b: jax.vjp(
+        lambda x, w, *b: kda.conv_silu(x, w, dtype, *b), x, w, *b)[1](dy))(x, w, dy, *b)
+    text = []
+    for eqn in pallas_calls(both.jaxpr, []):
+        mapping = eqn.params["grid_mapping"]
+        text += [str(eqn.params["jaxpr"]), str(mapping.grid)]
+        text += [f"{m.block_shape} {m.index_map_jaxpr} {m.array_aval}"
+                 for m in mapping.block_mappings]
+    return "\n".join(text)
+
+
+# Read by this same code at the parent of the PR that gave ``conv_silu`` its
+# ``heads`` (commit 2399a98), at the widths of the cells that call it without:
+# Kimi-Linear's q and k (b1 x s16384, 32 heads of 128, float32 out) and its v
+# (bfloat16 out and back), Solar-Open2's (b1 x s4096, 64 heads of 128) and
+# Granite's biased pass over x, B and C (b1 x s8192, 4,352 channels, bfloat16).
+CONV_BEFORE = {
+    "kimi-linear-q-and-k": ((16384, 4096, jnp.float32, 0), "61ec1ae74855cfa9"),
+    "kimi-linear-v": ((16384, 4096, jnp.bfloat16, 0), "53a9bfa1e55431ea"),
+    "solar-open2-q-and-k": ((4096, 8192, jnp.float32, 0), "937a2f889da98451"),
+    "granite-xbc": ((8192, 4352, jnp.bfloat16, 1), "88c48436988f48c2"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CONV_BEFORE))
+def test_without_heads_the_convolution_lowers_what_it_lowered(monkeypatch, name):
+    """``heads=None`` changes no operand, block, index map or operation of
+    either kernel: the forward and backward calls at the widths of the three
+    cells that convolve tokens first are, as text, what they were before the
+    output could lie heads first. And told a head's lanes the same shape
+    traces to another text: the digest sees the layout."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    shape, before = CONV_BEFORE[name]
+    text = conv_calls_text(*shape)
+    assert hashlib.sha1(text.encode()).hexdigest()[:16] == before
+    t, channels, dtype, biased = shape
+    x = jax.ShapeDtypeStruct((1, t, channels), jnp.float32)
+    w = jax.ShapeDtypeStruct((4, channels), jnp.float32)
+    first = jax.make_jaxpr(lambda x, w: kda.conv_silu(x, w, dtype, heads=128))(x, w)
+    (call,) = pallas_calls(first.jaxpr, [])
+    assert str(call.params["jaxpr"]) not in text
+    assert call.outvars[0].aval.shape == (1, channels // 128, t, 128)
 
 
 # ------------------------------ the step-scaled scalar decay (``chunk_ssd``)

@@ -325,14 +325,18 @@ def test_kda_kernels_compile_for_v5e(v5e, t, h):
 # Olmo-Hybrid's scalar-decay scan kernels (pretrain-8k): 30 heads whose key
 # heads are 96 lanes and value heads 192, neither a whole number of vregs, over
 # 8,192 tokens, every operand [B, H, T, d] with a block whole in its last
-# extent, the decay one float a head and token; at an odd head count the block
-# is one head's. No width is padded in what the caller hands over.
+# extent (q and k the one array [B, 2, H, T, dk] their convolution writes, a
+# block of it both), the decay one float a head and token; v, the gate, o and
+# their cotangents [B, T, H * dv], two heads' 384 lanes a block; at an odd head
+# count the block is one head's and those lie [B, H, T, dv]. No width is
+# padded in what the caller hands over.
 @pytest.mark.parametrize("t,h", [(8192, 30), (1024, 15)])
 def test_gdn_kernels_compile_for_v5e(v5e, t, h):
     b, dk, dv = 1, 96, 192
-    raw, rows = ((b, h, t, dk), jnp.float32), ((b, h, t, dv), jnp.bfloat16)
-    scalar = ((b, h, t, 1), jnp.float32)
-    operands = (raw, raw, rows, scalar, scalar, rows, ((1, dv), jnp.float32))
+    raw, scalar = ((b, 2, h, t, dk), jnp.float32), ((b, h, t, 1), jnp.float32)
+    assert kda._values_lie_tokens_first(h, dv) == (h == 30)
+    rows = ((b, t, h * dv) if h == 30 else (b, h, t, dv), jnp.bfloat16)
+    operands = (raw, rows, scalar, scalar, rows, ((1, dv), jnp.float32))
     norm = (dk ** -0.5, 1e-6, 1e-6)
     p = kda._heads_a_step(h)
     _compile_for(v5e, lambda *a: kda._gdn_forward_pallas(*a, norm, states=False), *operands)
@@ -361,7 +365,7 @@ def test_conv_kernels_compile_for_v5e(v5e, monkeypatch, t, channels):
     monkeypatch.setattr(kda._attention, "_on_tpu", lambda: True)
     x, w = ((1, t, channels), jnp.float32), ((4, channels), jnp.float32)
     blocks = kda._conv_blocks(jax.ShapeDtypeStruct(*x), jax.ShapeDtypeStruct(*w))
-    assert blocks == (512, 512, 64, False)
+    assert blocks == (512, 512, 64, False, 0)
 
     def kernels(text):
         """As a profile's reader names them: the backward's module holds no
@@ -378,6 +382,26 @@ def test_conv_kernels_compile_for_v5e(v5e, monkeypatch, t, channels):
             ["_conv_fwd_kernel"], ["_conv_bwd_kernel"])
         module = re.search(r'"body":"([^"]*)"', backward).group(1)
         assert b"_conv_fwd_kernel" not in base64.b64decode(module)
+
+
+# Olmo-Hybrid's two convolution passes (b1 x s8192, 5,760 channels) writing
+# heads first, [B, D / d, T, d], and reading their cotangents there: q with k
+# at 60 heads of 96 lanes, float32, four heads to a block of 384 lanes (a
+# head's lanes begin inside a vreg: the store is a lane rotation and a masked
+# store, the cotangent's tile is put together in VMEM); v at 30 heads of 192,
+# bfloat16 out and back, two heads to a block.
+@pytest.mark.parametrize("d,dtype", [(96, jnp.float32), (192, jnp.bfloat16)])
+def test_conv_kernels_compile_for_v5e_heads_first(v5e, monkeypatch, d, dtype):
+    monkeypatch.setattr(kda._attention, "_on_tpu", lambda: True)
+    t, channels = 8192, 5760
+    x, w = ((1, t, channels), jnp.float32), ((4, channels), jnp.float32)
+    blocks = kda._conv_blocks(jax.ShapeDtypeStruct(*x), jax.ShapeDtypeStruct(*w), d)
+    assert blocks == (512, 384, 64, False, d)
+    forward = _compile_for(
+        v5e, lambda x, w: kda._conv_forward(x, w, jnp.dtype(dtype), blocks), x, w)
+    assert f"[1,{channels // d},{t},{d}]" in forward
+    _compile_for(v5e, lambda x, w, dy: kda._conv_backward(x, w, dy, blocks), x, w,
+                 ((1, channels // d, t, d), dtype))
 
 
 # q's and k's rotation as one pass (``ops/rotary.py``), heads first in and
@@ -839,6 +863,34 @@ def test_olmo_hybrids_step_convolves_by_the_kernels_and_broadcasts_no_decay(olmo
     assert not reduced, reduced[:2]
 
 
+def test_olmo_hybrids_step_moves_no_operand_of_the_scan_but_the_decay_and_beta(
+        olmo_hybrids_step):
+    """The convolution writes q with k as [1, 60, 8192, 96], which the scan's
+    kernels read as it lies (one operand [1, 2, 30, 8192, 96], a reshape of
+    major extents), and their cotangents come back the same way: the lowered
+    step transposes no float32 array of 96-wide heads, slices no [1, 8192,
+    5760] projection to q's or k's 2,880 lanes and pads or joins none back,
+    and no [1, 8192, 2880] array exists. v, the gate, o and their cotangents
+    go through the kernels [1, 8192, 5760], as v's convolution and ``g_proj``
+    write and ``o_proj`` reads them: no array of 192-wide heads is transposed
+    either (there were 15 one way and 12 the other: v's, the gate's and o's,
+    forward, replayed and backward)."""
+    import re
+
+    _, text = olmo_hybrids_step
+    turned = re.findall(
+        r"stablehlo\.transpose.*: \(tensor<([\dx]+)x(f32|bf16)>\) -> tensor<([\dx]+)x", text)
+    assert turned
+    assert not [t for t in turned if t[0].endswith(("x96", "x192"))], turned
+    assert "x2880xf32>" not in text
+    # the scan's calls take what the convolution's return, a reshape apart
+    scans = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "tensor<1x2x30x8192x96xf32>" in line]
+    assert len(scans) == 2 * 3
+    assert all("tensor<1x8192x5760xbf16>" in line for line in scans)
+    assert text.count("-> tensor<1x60x8192x96xf32>") >= 2 * 3  # ``_conv_forward``'s
+
+
 # MiniCPM-SALA's kernels at the benchmark's real size (b1 x s16384): the
 # fixed-decay scan at 32 heads of 128 in chunks of 256 rows (and at an odd
 # head count), the three sparse kernels at 32 query heads over K and V at
@@ -1073,7 +1125,7 @@ def test_the_biased_convolution_compiles_for_v5e_at_4352_channels(v5e, monkeypat
     x, w, b = ((1, t, channels), jnp.float32), ((4, channels), jnp.float32), (
         (1, channels), jnp.float32)
     blocks = kda._conv_blocks(jax.ShapeDtypeStruct(*x), jax.ShapeDtypeStruct(*w))
-    assert blocks == (512, 256, 64, False)
+    assert blocks == (512, 256, 64, False, 0)
     forward = _compile_for(
         v5e, lambda x, w, b: kda._conv_forward(x, w, jnp.dtype(jnp.bfloat16), blocks, b),
         x, w, b)

@@ -122,8 +122,10 @@ class _GDN(nn.Module):
         g = -jax.nn.softplus(nn.Dense(self.heads, use_bias=False, name="g")(x))
         beta = 2.0 * jax.nn.sigmoid(nn.Dense(self.heads, use_bias=False, name="beta")(x))
         weight = self.param("norm", nn.initializers.ones, (self.dv,))
+        heads_first = lambda y: y.transpose(0, 2, 1, 3)  # noqa: E731
         o = chunk_gdn(
-            heads("q", self.dk), heads("k", self.dk), heads("v", self.dv, x.dtype),
+            jnp.stack([heads_first(heads("q", self.dk)), heads_first(heads("k", self.dk))], 1),
+            heads("v", self.dv, x.dtype),
             g, beta, heads("gate", self.dv, x.dtype), weight,
             scale=self.dk ** -0.5, rms_eps=1e-6,
         )
