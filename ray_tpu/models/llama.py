@@ -158,6 +158,10 @@ class AttentionKind:
     # What the scores are multiplied by before the soft-max (Granite's
     # ``attention_multiplier``); None: ``head_dim ** -0.5``.
     scale: Optional[float] = None
+    # LFM2's QK norm: an RMSNorm over each head's channels of q and of k, one
+    # weight [head_dim] for q and one for k shared by their heads, before the
+    # rotation (``cfg.qk_norm`` is OLMoE's, over the whole projection).
+    qk_head_norm: bool = False
 
 
 CONFIGS: Dict[str, LlamaConfig] = {
@@ -284,8 +288,9 @@ class RMSNorm(nn.Module):
 
 class Attention(nn.Module):
     """Softmax attention over grouped K/V heads. What the layer is beyond
-    the shared widths (its head count, its rotation or none, a window, an
-    output gate and its width) it learns from the config by its own flax name
+    the shared widths (its head count, a norm over each head's channels of q
+    and k, its rotation or none, a window, an output gate and its width) it
+    learns from the config by its own flax name
     (``cfg.attention``): a Llama or Mistral layer is all one kind."""
     cfg: LlamaConfig
 
@@ -307,6 +312,11 @@ class Attention(nn.Module):
                 norm = lambda t, name: RMSNorm(  # noqa: E731
                     cfg.rms_eps, cfg.param_dtype, name=name
                 )(t.reshape(*t.shape[:2], -1)).reshape(t.shape)
+                q, k = norm(q, "q_norm"), norm(k, "k_norm")
+        if kind.qk_head_norm:
+            with tracing.scope(tracing.QK_NORM):
+                norm = lambda t, name: RMSNorm(  # noqa: E731
+                    cfg.rms_eps, cfg.param_dtype, name=name)(t)
                 q, k = norm(q, "q_norm"), norm(k, "k_norm")
         # [B, T, H, D] -> [B, H, T, D]
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))

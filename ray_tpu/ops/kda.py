@@ -389,13 +389,13 @@ def _conv_specs(x, blocks):
     return grid, block, before, after, filt
 
 
-def _conv_call(kernel, blocks, **kwargs):
+def _conv_call(kernel, blocks, semantics=("parallel", "arbitrary", "arbitrary"), **kwargs):
     # The interpreter runs a kernel's body as XLA does.
     return pl.pallas_call(
         functools.partial(kernel, tile=blocks.tile,
                           roll=_xla_roll if blocks.interpret else _tpu_roll),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=semantics,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=blocks.interpret, **kwargs)
 
@@ -2034,3 +2034,200 @@ def chunk_ssd(u, dt, a_log, Bm, Cm, D):
             dt.transpose(0, 2, 1).reshape(batch, groups, _SSD_GROUP, t + pad),
             rates, skips, Bm, Cm)
     return y.reshape(batch, t + pad, heads + more, p)[:, :t, :heads]
+
+
+# ----------------------------------- the gated convolution as one pass
+# LFM2's mixer: p = x W_in is [B, T, 3 D], its thirds a gate B, a gate C and
+# the convolved x~ (in that order); y = C * short_conv(B * x~, w), with no
+# activation. The thirds are read where they lie, three BlockSpecs over the
+# one array, in its own dtype (a bfloat16 sublane tile is 16 rows, so the
+# halo's block is 16 and its last 8 are taken); the products and the taps are
+# float32 in registers and y is rounded once. The pass back writes p's
+# cotangent whole: its grid has one more axis, innermost, over the thirds,
+# whose first step computes all three, writes B's block and holds C's and
+# x~'s in VMEM for the two steps that hand them over (an output has one block
+# a step; the inputs' blocks stand while that axis moves, so nothing is read
+# twice): 4 arrays of [T, D] read and 3 written, no slice and no
+# concatenation in XLA.
+
+
+def _gated_blocks(p, w):
+    """The gated kernels' blocks over p [B, T, 3 D] under a filter w [K, D],
+    or None where ``_conv_blocks`` would say so of a third."""
+    if p.shape[2] != 3 * w.shape[1]:
+        raise ValueError(f"p {p.shape} is not three thirds of {w.shape[1]} channels")
+    return _conv_blocks(jax.ShapeDtypeStruct((*p.shape[:2], w.shape[1]), p.dtype), w)
+
+
+def _gated_specs(p, blocks):
+    """Over p [B, T, 3 D] and the grid (a third's lanes, batch, a sequence's
+    blocks, and backward the thirds): ``third(j)`` a block of third j,
+    ``before(j)`` the sublane tile of p's dtype before it and ``after(j,
+    dtype)`` the one after it in an array of ``dtype`` (clamped into the
+    sequence at its ends; j None: an array of D channels, y's), ``filt``
+    a filter's."""
+    rows, lanes = blocks.rows, blocks.lanes
+    steps = p.shape[2] // 3 // lanes
+    grid = (steps, p.shape[0], p.shape[1] // rows)
+
+    def spec(n, at, j):
+        return pl.BlockSpec((1, n, lanes),
+                            lambda l, b, t, *_: (b, at(t), l + (j or 0) * steps))
+
+    def third(j):
+        return spec(rows, lambda t: t, j)
+
+    def before(j):
+        n = _sublanes(p.dtype)
+        return spec(n, lambda t: jnp.maximum(t * (rows // n) - 1, 0), j)
+
+    def after(j, dtype):
+        n = _sublanes(dtype)
+        return spec(
+            n, lambda t: jnp.minimum((t + 1) * (rows // n), p.shape[1] // n - 1), j)
+
+    def filt(rows):
+        return pl.BlockSpec((rows, lanes), lambda l, *_: (0, l))
+
+    return grid, third, before, after, filt
+
+
+def _gated_input(b_ref, x_ref, before, i, tile):
+    """u = B * x~ on tile ``i`` of a block with the 8 rows before it, float32
+    [8 + tile, lanes], then the tile's B and x~; ``before`` is u on the
+    sublane tile before the block."""
+    n = before.shape[0]
+    at = pl.ds(pl.multiple_of(i * tile, tile), tile)
+    lo = pl.ds(pl.multiple_of(jnp.maximum(i * tile - n, 0), n), n)
+    own = _read(b_ref, lo) * _read(x_ref, lo)
+    gate, x = _read(b_ref, at), _read(x_ref, at)
+    return jnp.concatenate(
+        [jax.lax.select(i == 0, before, own)[n - _CONV_HALO:], gate * x]), gate, x
+
+
+def _gated_conv_fwd_kernel(b_ref, c_ref, x_ref, b_before_ref, x_before_ref, w_ref,
+                           y_ref, *, tile, roll):
+    w = w_ref[...].astype(F32)
+    first = pl.program_id(2) == 0
+    before = _edge(b_before_ref, first) * _edge(x_before_ref, first)
+
+    def one(i, carry):
+        at = pl.ds(pl.multiple_of(i * tile, tile), tile)
+        ext, _, _ = _gated_input(b_ref, x_ref, before, i, tile)
+        c = _conv_of(_taps(ext, w, roll), w)[_CONV_HALO:]
+        _write(y_ref, at, _read(c_ref, at) * c)
+        return carry
+
+    jax.lax.fori_loop(0, y_ref.shape[1] // tile, one, None)
+
+
+def _gated_conv_bwd_kernel(b_ref, c_ref, x_ref, b_before_ref, x_before_ref,
+                           c_after_ref, dy_ref, dy_after_ref, w_ref, dp_ref, dw_ref,
+                           held_ref, *, tile, roll):
+    # held_ref [2, rows, lanes]: C's and x~'s cotangents until their steps.
+    taps = w_ref.shape[0]
+    third = pl.program_id(3)
+    first, last = pl.program_id(2) == 0, pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when((pl.program_id(1) == 0) & first & (third == 0))
+    def _init():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, F32)
+
+    @pl.when(third == 0)
+    def _all_three():
+        w = w_ref[...].astype(F32)
+        before = _edge(b_before_ref, first) * _edge(x_before_ref, first)
+        c_after, dy_after = _edge(c_after_ref, last), _edge(dy_after_ref, last)
+
+        def one(i, carry):
+            at = pl.ds(pl.multiple_of(i * tile, tile), tile)
+            ext, gate, x = _gated_input(b_ref, x_ref, before, i, tile)
+            shifted = [u[_CONV_HALO:] for u in _taps(ext, w, roll)]
+            dy = _read(dy_ref, at)
+            # The convolution's cotangent on the tile's rows and the 8 after.
+            dc = jnp.concatenate([
+                dy * _read(c_ref, at),
+                _rows_after(dy_ref, dy_after[:_CONV_HALO], i, tile)
+                * _rows_after(c_ref, c_after[:_CONV_HALO], i, tile)])
+            # du_s = sum_i w[i] dc_{s + (K - 1) - i}: dc moved up, the rows
+            # that wrap among the 8 after the tile.
+            du = _add_up(
+                (roll(dc, tap - (taps - 1)) if tap < taps - 1 else dc)[:tile] * w[tap:tap + 1]
+                for tap in range(taps))
+            _write(dp_ref, at, du * x)
+            held_ref[0, at, :] = (dy * _conv_of(shifted, w)).astype(held_ref.dtype)
+            held_ref[1, at, :] = (du * gate).astype(held_ref.dtype)
+            for tap, u in enumerate(shifted):
+                prod = dc[:tile] * u
+                dw_ref[pl.ds(tap * _CONV_HALO, _CONV_HALO), :] += _add_up(
+                    prod[r:r + _CONV_HALO] for r in range(0, tile, _CONV_HALO))
+            return carry
+
+        jax.lax.fori_loop(0, dy_ref.shape[1] // tile, one, None)
+
+    @pl.when(third > 0)
+    def _hand_over():
+        dp_ref[0] = held_ref[third - 1]
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _gated_forward(p, w, dtype, blocks):
+    grid, third, before, _, filt = _gated_specs(p, blocks)
+    return _conv_call(
+        _gated_conv_fwd_kernel, blocks, grid=grid,
+        in_specs=[third(0), third(1), third(2), before(0), before(2), filt(w.shape[0])],
+        out_specs=third(None),
+        out_shape=jax.ShapeDtypeStruct((*p.shape[:2], w.shape[1]), dtype),
+    )(p, p, p, p, p, w)
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _gated_backward(p, w, dy, blocks):
+    grid, third, before, after, filt = _gated_specs(p, blocks)
+    taps, steps = w.shape[0], grid[0]
+    dp, dw = _conv_call(
+        _gated_conv_bwd_kernel, blocks, grid=(*grid, 3),
+        semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
+        in_specs=[third(0), third(1), third(2), before(0), before(2),
+                  after(1, p.dtype), third(None), after(None, dy.dtype), filt(taps)],
+        out_specs=[
+            pl.BlockSpec((1, blocks.rows, blocks.lanes),
+                         lambda l, b, t, j: (b, t, l + j * steps)),
+            filt(taps * _CONV_HALO)],
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype),
+                   jax.ShapeDtypeStruct((taps * _CONV_HALO, w.shape[1]), F32)],
+        scratch_shapes=[pltpu.VMEM((2, blocks.rows, blocks.lanes), p.dtype)],
+    )(p, p, p, p, p, p, dy, dy, w)
+    return dp, dw.reshape(taps, _CONV_HALO, -1).sum(1).astype(w.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _gated_conv_pallas(p, w, dtype, blocks):
+    return _gated_forward(p, w, dtype, blocks)
+
+
+def _gated_conv_fwd(p, w, dtype, blocks):
+    return _gated_forward(p, w, dtype, blocks), (p, w)
+
+
+def _gated_conv_bwd(dtype, blocks, residuals, dy):
+    return _gated_backward(*residuals, dy, blocks)
+
+
+_gated_conv_pallas.defvjp(_gated_conv_fwd, _gated_conv_bwd)
+
+
+def gated_conv(p, w, dtype=None):
+    """``C * short_conv(B * x~, w)`` rounded once to ``dtype`` (p's where None
+    is given): p [B, T, 3 D], its thirds B, C and x~ in that order; w [K, D],
+    tap K - 1 on the current token. No activation. On a TPU (or under the
+    interpreter) where a third tiles it is one Pallas pass over p forward and
+    one over p and the cotangent backward, which writes p's cotangent whole
+    and adds the filter's up in float32; the residuals are p and w. Elsewhere
+    it is what this line says, in float32, for XLA to differentiate."""
+    dtype = jnp.dtype(dtype or p.dtype)
+    blocks = _gated_blocks(p, w)
+    if blocks is None:
+        b, c, x = jnp.split(p.astype(F32), 3, axis=-1)
+        return (c * short_conv(b * x, w)).astype(dtype)
+    return _gated_conv_pallas(p, w, dtype, blocks)

@@ -60,7 +60,7 @@ MOE_DISPATCH = "dispatch"  # positions, slot map, gather into the expert buffer
 MOE_EXPERTS = "experts"  # the three expert matmuls and the activation
 MOE_COMBINE = "combine"  # gather back, gate scaling, the reduction over k
 MOE_LAYOUT = "layout"  # inside dispatch, "gmm" only: sort, tile layout, inverse map
-QK_NORM = "qk_norm"  # RMSNorm of q and k: the whole projections in attn (cfg.qk_norm), inside sparse and inside lightning; a head's channels inside mla (cfg.qk_head_norm) on the XLA road, and on the kernel road (ops/rotary.py latent_road) the weights' casts alone, or the latent kernels where the layer norms and turns nothing
+QK_NORM = "qk_norm"  # RMSNorm of q and k: the whole projections in attn (cfg.qk_norm), or a head's channels there under one weight [head_dim] for q and one for k (AttentionKind.qk_head_norm: models/lfm2.py), inside sparse and inside lightning; a head's channels inside mla (cfg.qk_head_norm) on the XLA road, and on the kernel road (ops/rotary.py latent_road) the weights' casts alone, or the latent kernels where the layer norms and turns nothing
 MOE_SHARED = "shared"  # inside moe: the shared expert every token passes
 # The mixers' flax names, which reach op_name as the attention's "attn" does.
 KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer; Solar-Open2's KDA layers too)
@@ -70,9 +70,13 @@ LIGHTNING = "lightning"  # the decay-only linear-attention mixer (models/minicpm
 SPARSE = "sparse"  # the block-sparse top-k softmax mixer (models/minicpm_sala.py SparseAttention): projections, qk_norm, select, ops/attention.py sparse_attention's kernels, out_gate
 SPARSE_SELECT = "select"  # inside sparse, where T > dense_len: ops/attention.py select_blocks (compressed keys, the scores of every head against them, their soft-max, the sum over a group's heads, the max-pool to blocks, the forced blocks, top-k, the packed bitmap); nothing of it is differentiated
 MAMBA = "mamba"  # the Mamba-2 state-space mixer (models/granite_hybrid.py Mamba2Mixer): the three input projections, conv, step, ops/kda.py chunk_ssd's kernels (they hold the step's product with u and the skip) and the slices around them, norm, the output projection
+SHORTCONV = "shortconv"  # the gated short-convolution mixer (models/lfm2.py ShortConvMixer): the whole mixer of an LFM2 conv layer, its three scopes below and nothing else
+SHORTCONV_IN = "conv_in"  # inside shortconv: the one input projection to the gates B and C and the convolved x~, [hidden, 3 hidden]
+SHORTCONV_GATED = "gated_conv"  # inside shortconv: ops/kda.py gated_conv, C * conv(B * x~) with no activation: its two Pallas kernels (_gated_conv_fwd_kernel, _gated_conv_bwd_kernel) where a third tiles, XLA's lines elsewhere, and the filter's casts
+SHORTCONV_OUT = "conv_out"  # inside shortconv: the output projection
 MAMBA_STEP = "step"  # inside mamba: the step's softplus with its bias
 MAMBA_NORM = "norm"  # inside mamba: y times SiLU(z), then the one RMSNorm over every head's channels
-KDA_CONV = "conv"  # inside kda: the short convolutions of q, k, v and their SiLU; inside mamba: the one convolution of x, B and C with its bias and its SiLU
+KDA_CONV = "conv"  # inside kda (models/kimi_linear.py KDAMixer) and inside gdn (models/olmo_hybrid.py GDNMixer): the short convolutions of q, k, v and their SiLU; inside mamba (models/granite_hybrid.py Mamba2Mixer): the one convolution of x, B and C with its bias and its SiLU. Not opened inside shortconv, whose convolution has gates and no SiLU and a scope of its own (SHORTCONV_GATED)
 KDA_GATE = "gate"  # inside kda: the log-decay g and the write strength beta, with its doubling where the config writes in (0, 2)
 KDA_SCAN = "scan"  # inside kda: ops/kda.py chunk_kda (its kernels hold q's, k's and o's norms and the output gate), v's rounding, beta's transpose
 # (No "out_norm": o's per-head RMSNorm and output gate left XLA for the scan's
@@ -96,9 +100,9 @@ SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
           MLA_LATENT, MLA_ROPE, MLA_Q_LATENT, ATTN_ROPE, ATTN_GATE, HC, HC_PRE,
           HC_SINKHORN, HC_POST, HC_STREAMS, MTP, LOSS, LOSS_HEAD, SPARSE_SELECT,
-          MAMBA_STEP, MAMBA_NORM)
+          MAMBA_STEP, MAMBA_NORM, SHORTCONV_IN, SHORTCONV_GATED, SHORTCONV_OUT)
 # Flax module names, bound in the model classes' ``blocks``.
-MIXERS = (KDA, MLA, ATTN, SWA, GDN, LIGHTNING, SPARSE, MAMBA)
+MIXERS = (KDA, MLA, ATTN, SWA, GDN, LIGHTNING, SPARSE, MAMBA, SHORTCONV)
 # The decoder body's flax names (models/llama.py, xing4.py), a layer's and
 # above: parameter trees and checkpoints hold them, so none is ever renamed.
 EMBED = "embed_tokens"  # the embedding table's flax name; models/llama.py _lookup opens it as a scope around what it does outside the module (the one-hot product where a mesh splits the table, the constraint on the result)

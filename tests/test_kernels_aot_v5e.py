@@ -1182,3 +1182,77 @@ def test_granites_step_holds_its_kernels_and_its_replay_runs_no_scan(granites_st
                for entry in ("_conv_forward", "_conv_backward")}
     assert entries == {"_conv_forward": 2 * 9, "_conv_backward": 9}
     assert "tensor<1x8195x4352xf32>" not in text  # no short_conv fallback
+
+
+# LFM2's gated convolution over the cell's projection (b2 x s4096, three thirds
+# of 2,048 channels, bfloat16 in and out): the thirds read where they lie under
+# a halo of 16 rows, and the pass back over a grid with the thirds as its
+# innermost axis, at the blocks ``gated_conv`` gives them.
+def test_gated_conv_kernels_compile_for_v5e(v5e, monkeypatch):
+    import base64
+    import re
+
+    from benchmarks.lib import trace
+
+    monkeypatch.setattr(kda._attention, "_on_tpu", lambda: True)
+    p, w = ((2, 4096, 6144), jnp.bfloat16), ((3, 2048), jnp.bfloat16)
+    y = ((2, 4096, 2048), jnp.bfloat16)
+    blocks = kda._gated_blocks(jax.ShapeDtypeStruct(*p), jax.ShapeDtypeStruct(*w))
+    assert blocks == (512, 512, 64, False, 0)
+
+    def kernels(text):
+        return [trace.kernel_name(line) for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line]
+
+    forward = _compile_for(
+        v5e, lambda p, w: kda._gated_forward(p, w, jnp.dtype(jnp.bfloat16), blocks), p, w)
+    backward = _compile_for(
+        v5e, lambda p, w, dy: kda._gated_backward(p, w, dy, blocks), p, w, y)
+    assert (kernels(forward), kernels(backward)) == (
+        ["_gated_conv_fwd_kernel"], ["_gated_conv_bwd_kernel"])
+    # the cotangent of the projection's output leaves whole, in its own dtype
+    assert "bf16[2,4096,6144]" in backward and "f32[2,4096,6144]" not in backward
+    module = re.search(r'"body":"([^"]*)"', backward).group(1)
+    assert b"_conv_fwd_kernel" not in base64.b64decode(module).replace(
+        b"_gated_conv_fwd_kernel", b"")
+
+
+@pytest.fixture(scope="module")
+def lfm2s_step(v5e):
+    return _lowered_step(v5e, "lfm2-8b-a1b-l5.dropfree-4k")
+
+
+def test_lfm2s_step_holds_its_kernels_and_reads_the_projections_thirds_in_place(lfm2s_step):
+    """The LFM2 cell's step at the benchmark's real size (b2 x s4096, layers
+    1-5 at the published widths, all 32 experts): every kernel its
+    configuration states; the four conv layers' gated convolutions by the
+    kernels, forward, replayed and backward, each reading the one [2, 4096,
+    6144] array and the pass back writing its cotangent whole (no slice of a
+    third, no float32 copy, no concatenation, no padded copy of the XLA
+    road); four expert layers of six ``_gmm_kernel`` and three
+    ``_tgmm_kernel`` calls and a replay's three; the attention layer's three
+    causal kernels; no scan's kernel and no un-gated convolution."""
+    import re
+
+    from benchmarks.lib import cells, checks
+
+    cell, text = lfm2s_step
+    stated = cells.stated_kernels(cell)
+    counts = checks.count_pallas_kernels(text, stated)
+    assert checks.holds_stated_kernels(counts, stated), (counts, stated)
+    assert counts == {
+        "_fwd_kernel": 2, "_bwd_dkv_kernel": 1, "_bwd_dq_kernel": 1,
+        "_gmm_kernel": 36, "_tgmm_kernel": 12,
+        "_gated_conv_fwd_kernel": 2, "_gated_conv_bwd_kernel": 1}
+    others = ("_conv_fwd_kernel", "_conv_bwd_kernel", "_kda_fwd_kernel", "_gdn_fwd_kernel",
+              "_ssd_fwd_kernel", "_lightning_fwd_kernel", "_rotary_kernel")
+    assert not any(checks.count_pallas_kernels(text, others).values())
+    entries = {entry: len(re.findall(rf"call @{entry}(?:_\d+)?\(", text))
+               for entry in ("_gated_forward", "_gated_backward")}
+    assert entries == {"_gated_forward": 2 * 4, "_gated_backward": 4}
+    assert "tensor<2x4096x6144xf32>" not in text and "tensor<2x4098x2048xf32>" not in text
+    thirds = [line for line in text.splitlines()
+              if "stablehlo.slice" in line and "tensor<2x4096x6144xbf16>" in line]
+    joined = [line for line in text.splitlines()
+              if "stablehlo.concatenate" in line and "tensor<2x4096x6144xbf16>" in line]
+    assert not thirds and not joined

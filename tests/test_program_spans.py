@@ -388,6 +388,35 @@ def granite_paths():
         del os.environ["RAY_TPU_PALLAS_INTERPRET"]
 
 
+@pytest.fixture(scope="module")
+def lfm2_paths():
+    """Paths of a tiny LFM2-MoE's compiled train step: source layers 1 and 2,
+    a gated short convolution over the dense MLP and an attention layer under
+    a per-head QK norm over the expert layer with every expert held, the head
+    tied."""
+    from ray_tpu.models.lfm2 import Lfm2ForCausalLM, lfm2_config
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"  # the kernels, as on the chip
+    try:
+        cfg = lfm2_config(
+            layer_types=["conv", "conv", "full_attention"], num_dense_layers=2,
+            first_layer=1, num_layers=2, conv_L_cache=3, vocab_size=128,
+            hidden_size=128, intermediate_size=64, moe_intermediate_size=128,
+            num_heads=4, num_kv_heads=2, head_dim=32, num_experts=4,
+            num_experts_per_tok=2,
+        )
+        model = Lfm2ForCausalLM(cfg)
+        ids = jnp.zeros((1, 64), jnp.int32)
+        return paths_of(compiled_step(
+            model,
+            lambda p, i, t: chunked_causal_lm_loss(model, p, i, t, chunk_size=32),
+            ids,
+        ))
+    finally:
+        del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+
+
 # ----------------------------------------------------------- in-graph scopes
 
 
@@ -417,6 +446,40 @@ def test_a_sparse_and_lightning_hybrid_carries_its_scopes(sala_paths):
     # select and a sum, and gathers nothing
     loss = [p for p in sala_paths if f"({tracing.LOSS})" in p]
     assert loss and not [p for p in loss if p.endswith("/gather")]
+
+
+def test_a_short_convolution_hybrid_carries_its_scopes(lfm2_paths):
+    """What model.shortconv_share, model.gqa_share, model.mlp_share and
+    model.moe_share select by: /shortconv/ with ``conv_in``, ``gated_conv``
+    and ``conv_out`` inside it (the projections' flax names under them) and no
+    ``conv`` scope of the KDA, GDN and Mamba mixers' readers; /attn/ with
+    ``qk_norm`` (a head's channels) and ``rotary``; the dense MLP in the layer
+    the source counts below ``num_dense_layers`` and the expert layer's scopes
+    in the other."""
+    conv = [p for p in lfm2_paths if "/layers_0/shortconv/" in p]
+    attn = [p for p in lfm2_paths if "/layers_1/attn/" in p]
+    assert conv and attn and not [
+        p for p in lfm2_paths if "/layers_1/shortconv/" in p or "/layers_0/attn/" in p]
+    for name in (tracing.SHORTCONV_IN, tracing.SHORTCONV_GATED, tracing.SHORTCONV_OUT):
+        assert any(f"/shortconv/{name}/" in p for p in conv), name
+        assert not [p for p in attn if f"/{name}/" in p], name
+    assert any(f"/shortconv/{tracing.SHORTCONV_IN}/in_proj/" in p for p in conv)
+    assert any(f"/shortconv/{tracing.SHORTCONV_OUT}/out_proj/" in p for p in conv)
+    assert not [p for p in lfm2_paths if f"/{tracing.KDA_CONV}/" in p]
+    # every operation of the mixer lies under one of the three
+    inside = (tracing.SHORTCONV_IN, tracing.SHORTCONV_GATED, tracing.SHORTCONV_OUT)
+    assert not [p for p in conv if not any(f"/shortconv/{n}/" in p for n in inside)]
+    for name in (tracing.QK_NORM, tracing.ATTN_ROPE):
+        assert any(f"/attn/{name}/" in p for p in attn), name
+    assert any(f"/attn/{tracing.QK_NORM}/q_norm/" in p for p in attn)
+    assert not [p for p in lfm2_paths if f"/{tracing.ATTN_GATE}/" in p]
+    for mixer in (conv, attn):
+        assert {pass_of(p) for p in mixer} >= {"forward", "backward"}
+    assert any("/layers_0/mlp/" in p for p in lfm2_paths)
+    assert not [p for p in lfm2_paths if "/layers_0/moe/" in p or "/layers_1/mlp/" in p]
+    for name in MOE_SCOPES:
+        assert any(f"/layers_1/moe/{name}/" in p for p in lfm2_paths), name
+    assert not [p for p in lfm2_paths if f"/{tracing.MOE_SHARED}/" in p]
 
 
 def test_a_state_space_hybrid_carries_its_scopes(granite_paths):
@@ -689,7 +752,8 @@ def test_expert_matmuls_are_under_experts_forward_and_backward(moe_paths, branch
 # Every family's compiled step, by fixture (and dispatch branch).
 FAMILIES = ("llama_paths", "qk_norm_paths", "tied_paths", "moe_paths:capacity", "moe_paths:gmm",
             "moe_paths:ragged", "kimi_paths", "sarvam_paths", "xing4_paths",
-            "laguna_paths", "solar_paths", "olmo_paths", "sala_paths", "granite_paths")
+            "laguna_paths", "solar_paths", "olmo_paths", "sala_paths", "granite_paths",
+            "lfm2_paths")
 # Paths that may hold no name of the program, and why.
 EXEMPT = (
     # _positions' arange, inside the model's __call__ and outside every part:
@@ -743,6 +807,7 @@ LOSS_KINDS = {
     "moe_paths:gmm": "full", "moe_paths:ragged": "full", "kimi_paths": "chunked",
     "sarvam_paths": "chunked", "laguna_paths": "chunked", "solar_paths": "chunked",
     "olmo_paths": "chunked", "sala_paths": "chunked", "granite_paths": "chunked",
+    "lfm2_paths": "chunked",
     "xing4_paths": "mtp",
 }
 
@@ -936,13 +1001,13 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 def test_names_emitted_are_exactly_the_list(
     llama_paths, qk_norm_paths, moe_paths, kimi_paths, sarvam_paths,
     xing4_paths, laguna_paths, solar_paths, olmo_paths, sala_paths, granite_paths,
-    session_lines, actor_lines
+    lfm2_paths, session_lines, actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + olmo_paths + sala_paths + granite_paths + [
+    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + olmo_paths + sala_paths + granite_paths + lfm2_paths + [
         p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:  # a scope directly under a transform is in its brackets
         assert any(f"/{name}/" in p or f"({name})/" in p for p in paths), name
