@@ -569,10 +569,11 @@ def chunked_causal_lm_loss(
     The [B, T, V] logits tensor is the largest activation at long T
     (f32 T=32k, V=32k is 4.2 GB — bigger than the whole remat'd
     transformer). Scanning the LM head + softmax-xent over sequence
-    chunks keeps only [B, chunk, V] alive; jax.checkpoint recomputes
-    each chunk's logits in the backward, so the memory bound holds
-    end-to-end. Net-new vs the reference (its torch trainers
-    materialize logits); the standard long-context recipe on TPU.
+    chunks keeps only [B, chunk, V] alive, and the scan makes each chunk's
+    gradients while its logits are (``chunked_head_loss``), so the memory
+    bound holds end-to-end and no chunk is computed twice. Net-new vs the
+    reference (its torch trainers materialize logits); the standard
+    long-context recipe on TPU.
     """
     hidden = model.apply(params, input_ids, return_hidden=True)
     return chunked_head_loss(
@@ -590,7 +591,10 @@ def chunked_head_loss(
 ) -> jax.Array:
     """``chunked_causal_lm_loss`` from the final-norm hidden states [B, T, H]
     on: the head [V, H] and the cross-entropy a chunk of the sequence at a
-    time, the mean over the positions ``mask`` keeps."""
+    time, the mean over the positions ``mask`` keeps.
+
+    Differentiated by a rule of its own (``_chunked_nll``): reverse mode
+    only, with respect to ``hidden`` and ``head``; ``jax.jvp`` of it raises."""
     b, t = targets.shape
     if mask is None:
         m_full = jnp.ones((b, t), jnp.float32)
@@ -612,41 +616,105 @@ def chunked_head_loss(
     h_c = hidden.reshape(b, n_chunks, chunk_size, -1).swapaxes(0, 1)
     t_c = targets.reshape(b, n_chunks, chunk_size).swapaxes(0, 1)
     m_c = m_full.reshape(b, n_chunks, chunk_size).swapaxes(0, 1)
+    return _chunked_nll(h_c, head, t_c, m_c)
 
-    @jax.checkpoint
-    def chunk_nll(h, tg, m):
-        # f32 accumulation on the MXU regardless of param dtype — the
-        # full path's lm_head computes f32 logits, and the two losses
-        # must stay numerically comparable.
-        with tracing.scope(tracing.LOSS_HEAD):
-            logits = jnp.matmul(
-                h.astype(head.dtype),
-                head.T,
-                preferred_element_type=jnp.float32,
-            )  # [B, C, V] f32
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        if logits.shape[-1] % 128:
-            # A vocabulary that fills no whole number of lanes (an eighth of
-            # 73,448 is 9,181): the gather takes the chunk's logits flat, and
-            # [B, C, V] -> [B * C * V] is then a copy the compiler makes in a
-            # loop of its own, under no name, forward, replayed and backward
-            # (8.5 ms a step at 16k tokens: PERF.md §6, PR 54). A select and a
-            # sum ride the passes logsumexp makes; the value is the same.
-            hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2) == tg[..., None]
-            gold = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
-        else:
-            gold = jnp.take_along_axis(logits, tg[..., None], axis=-1)[..., 0]
-        return jnp.sum((logz - gold) * m), jnp.sum(m)
+
+def _chunk_nll(h, head, tg, m):
+    """One chunk's summed negative log-likelihood over the rows ``m`` keeps,
+    and what its gradient is made of: the logits [B, C, V] float32, each
+    row's maximum and the sum of its exponentials below it."""
+    # f32 accumulation on the MXU regardless of param dtype — the
+    # full path's lm_head computes f32 logits, and the two losses
+    # must stay numerically comparable.
+    with tracing.scope(tracing.LOSS_HEAD):
+        logits = jnp.matmul(
+            h.astype(head.dtype),
+            head.T,
+            preferred_element_type=jnp.float32,
+        )  # [B, C, V] f32
+    # jax.nn.logsumexp's own lines (the value is its, bit for bit), written
+    # out so that the soft-max of the rule below divides the same
+    # exponentials by the same sum as autodiff's does
+    top = jnp.max(logits, axis=-1)
+    top = jnp.where(jnp.isfinite(top), top, 0.0)
+    sums = jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+    logz = jnp.log(sums) + top
+    if logits.shape[-1] % 128:
+        # A vocabulary that fills no whole number of lanes (an eighth of
+        # 73,448 is 9,181): the gather takes the chunk's logits flat, and
+        # [B, C, V] -> [B * C * V] is then a copy the compiler makes in a
+        # loop of its own, under no name (8.5 ms a step at 16k tokens:
+        # PERF.md §6, PR 54). A select and a sum ride the passes logsumexp
+        # makes; the value is the same.
+        gold = jnp.sum(jnp.where(_hits(logits, tg), logits, 0.0), axis=-1)
+    else:
+        gold = jnp.take_along_axis(logits, tg[..., None], axis=-1)[..., 0]
+    return jnp.sum((logz - gold) * m), logits, top, sums
+
+
+def _hits(logits, tg):
+    """[B, C, V] bool: the target's column of each row."""
+    return jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2) == tg[..., None]
+
+
+@jax.custom_vjp
+def _chunked_nll(h_c, head, t_c, m_c):
+    """The mean over the kept rows of the cross-entropy of ``h_c``
+    [chunks, B, C, H] through ``head`` [V, H], a chunk at a time."""
+    def body(total, inp):
+        h, tg, m = inp
+        return total + _chunk_nll(h, head, tg, m)[0], None
+
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (h_c, t_c, m_c))
+    return total / jnp.maximum(jnp.sum(m_c), 1.0)
+
+
+def _chunked_nll_fwd(h_c, head, t_c, m_c):
+    """The same loss, and each chunk's gradients made while its logits are
+    alive: nothing in them needs a cotangent from upstream but the loss's own
+    scalar, so no chunk's head matmul or soft-max runs again for the backward
+    pass. The two products and the sum over chunks are autodiff's own of the
+    forward matmul: float32 cotangent by the operand in ``head.dtype``,
+    float32 out, rounded to ``head.dtype`` and summed there."""
+    count = jnp.maximum(jnp.sum(m_c), 1.0)
 
     def body(carry, inp):
-        nll, cnt = chunk_nll(*inp)
-        return (carry[0] + nll, carry[1] + cnt), None
+        total, d_head, rows = carry
+        i, tg, m = inp
+        h = rows[i]
+        nll, logits, top, sums = _chunk_nll(h, head, tg, m)
+        weight = m / count  # the mean's cotangent, a row
+        d_logits = jnp.exp(logits - top[..., None]) * (weight / sums)[..., None] - (
+            jnp.where(_hits(logits, tg), weight[..., None], 0.0))
+        with tracing.scope(tracing.LOSS_HEAD):
+            d_h = jax.lax.dot_general(
+                d_logits, head, (((2,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ).astype(rows.dtype)
+            d_head = d_head + jax.lax.dot_general(
+                d_logits, h.astype(head.dtype), (((0, 1), (0, 1)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ).astype(head.dtype)
+        # The chunk's rows are spent, and their gradient takes their place: a
+        # second [chunks, B, C, H] stack beside the hidden states would be
+        # alive with every layer's residuals (128 MiB at 16k tokens of 4,096).
+        return (total + nll, d_head, rows.at[i].set(d_h)), None
 
-    (total, count), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (h_c, t_c, m_c),
+    (total, d_head, d_h), _ = jax.lax.scan(
+        body,
+        (jnp.zeros((), jnp.float32), jnp.zeros_like(head), h_c),
+        (jnp.arange(h_c.shape[0]), t_c, m_c),
     )
-    return total / jnp.maximum(count, 1.0)
+    return total / count, (d_h, d_head)
+
+
+def _chunked_nll_bwd(gradients, g):
+    # (traced under the scope the loss was called in: JAX names these lines
+    # transpose(jvp(loss))/ without a scope opened here)
+    return (*((g * d).astype(d.dtype) for d in gradients), None, None)
+
+
+_chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
 
 
 @tracing.scope(tracing.LOSS)

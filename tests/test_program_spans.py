@@ -759,17 +759,6 @@ EXEMPT = (
     # _positions' arange, inside the model's __call__ and outside every part:
     # integer positions shared by every layer, no device time of their own
     (r"^jit\(train_step\)/jvp\(\w+ForCausalLM\)/iota$", "positions"),
-    # the zeros of the chunked loss's logsumexp (taken where a row's maximum
-    # is not finite), [B, chunk] float32: XLA hoists the constant out of the
-    # scan's loop and leaves it the jit's own level for a path; its one
-    # consumer is jvp(loss)/while/body/closed_call/select_n
-    (r"^jit\(train_step\)/broadcast_in_dim$", "hoisted zeros"),
-    # the same of a vocabulary that fills no whole number of lanes
-    # (chunked_head_loss takes the gold logit by a select and a sum there): the
-    # column index and the select's zeros, constants of the scan's body that
-    # JAX computes once outside it. The TPU compiler fuses both into their
-    # consumer (step.unnamed_share read 0.00006% in that cell, PERF.md 6, PR 54)
-    (r"^jit\(train_step\)/(iota|jit\(_where\)/broadcast_in_dim)$", "hoisted constants"),
     # JAX's own, at a layer's checkpoint boundary: the transposed remat2
     # equation rounds the residual stream's summed cotangent to the stream's
     # dtype outside the layer's name, which flax opens inside the checkpoint.
@@ -819,13 +808,19 @@ def test_the_loss_and_the_chunked_head_carry_their_scopes(request, family):
     JAX renders its scope in the brackets: jvp(loss), transpose(jvp(loss)).
     The full-logit loss multiplies nothing (its matmul is the module
     lm_head, or the tied table's attend); the chunked one multiplies under
-    ``head`` alone, forward, in its checkpoint's replay and backward; the
-    MTP loss opens ``loss`` inside ``mtp``, so its second pass of the head
-    reads jvp(mtp)/loss/ and "(mtp)" still finds it."""
+    ``head`` alone, three times a chunk and all of them forward: its rule
+    (models/llama.py ``_chunked_nll``) makes a chunk's gradients in the
+    forward scan, replays nothing and leaves the backward pass the cotangent's
+    two scalings, under ``loss``; the MTP loss opens ``loss`` inside ``mtp``,
+    so its second pass of the head reads jvp(mtp)/loss/ and "(mtp)" still
+    finds it."""
     paths = paths_in(request, family)
     kind = LOSS_KINDS[family]
     top = [p for p in paths if f"({tracing.LOSS})" in p]
-    assert {pass_of(p) for p in top} >= {"forward", "backward"}
+    # (a cotangent of 1.0 folds the chunked rule's scalings away, and with a
+    # batch of one the reshapes back to [B, T, H] too)
+    assert {pass_of(p) for p in top} - {"backward"} == {"forward"}
+    assert kind != "full" or "backward" in {pass_of(p) for p in top}
     assert all(p.startswith((f"jit(train_step)/jvp({tracing.LOSS})/",
                              f"jit(train_step)/transpose(jvp({tracing.LOSS}))/"))
                for p in top)
@@ -842,14 +837,16 @@ def test_the_loss_and_the_chunked_head_carry_their_scopes(request, family):
         assert (family == "tied_paths") == any(
             f"/{tracing.LM_HEAD}/{tracing.EMBED}.attend/dot_general" in p for p in paths)
         return
-    assert matmuls and all(f"/{tracing.LOSS_HEAD}/dot_general" in p for p in matmuls)
-    assert {pass_of(p) for p in matmuls} == {"forward", "replay", "backward"}
-    assert f"jit(train_step)/jvp({tracing.LOSS})/while/body/closed_call/head/dot_general" in matmuls
-    assert (f"jit(train_step)/transpose(jvp({tracing.LOSS}))/while/body/closed_call/"
-            "checkpoint/rematted_computation/head/dot_general") in matmuls
-    # nothing but the matmul (and the cast in front of it) is the head's
+    the_heads = f"jit(train_step)/jvp({tracing.LOSS})/while/body/closed_call/head/dot_general"
+    assert set(matmuls) == {the_heads} and pass_of(the_heads) == "forward"
+    assert not [p for p in paths if "rematted_computation" in p and tracing.LOSS in p]
+    # nothing but the matmuls, the casts around them and the sum into the
+    # head's gradient (the product's own output fusion) is the head's
     assert {p.rpartition("/")[2] for p in paths if f"/{tracing.LOSS_HEAD}/" in p} <= {
-        "dot_general", "convert_element_type", "transpose"}
+        "dot_general", "convert_element_type", "transpose", "add"}
+    # what is left for the backward pass is the loss's, and no loop
+    assert not [p for p in top if pass_of(p) == "backward"
+                and ("/while" in p or f"/{tracing.LOSS_HEAD}/" in p)]
     second = [p for p in paths if f"({tracing.MTP})" in p]
     if kind != "mtp":
         assert not second
@@ -861,7 +858,9 @@ def test_the_loss_and_the_chunked_head_carry_their_scopes(request, family):
                for p in second if p.endswith("/dot_general"))
     assert not [p for p in second if f"({tracing.LOSS})" in p]
     again = [p for p in second if p.endswith(f"/{tracing.LOSS_HEAD}/dot_general")]
-    assert {pass_of(p) for p in again} == {"forward", "replay", "backward"}
+    assert {pass_of(p) for p in again} == {"forward"}
+    # its cotangent is mtp_weight, not 1.0: the rule's two scalings stay
+    assert f"jit(train_step)/transpose(jvp({tracing.MTP}))/{tracing.LOSS}/mul" in second
     # the sum of the two terms is the loss's, outside the module's scope
     assert f"jit(train_step)/jvp({tracing.LOSS})/mul" in top
 
