@@ -127,6 +127,18 @@ EVENTS_BY_CATEGORY = {
             "ray_tpu.train.report", "ray_tpu.train.next_result",
             "ray_tpu.train.result_wait", "ray_tpu.worker.reply",
             "ray_tpu.worker.recv",
+            # Set-up (util/tracing.py SETUP_SPANS), laid out as the host
+            # spans are, attrs a dict {"m_start", "fun_name"}: what JAX
+            # reports of each trace, lowering, backend compile and
+            # persistent-cache read of the process (watch_compiles), the
+            # cache's hits and misses as spans of no length, one span
+            # of no length as the record ends whose attrs also hold
+            # {stage: [count, seconds]} of the durations too short for an
+            # event, and parallel/mesh.py shard_params.
+            "ray_tpu.compile.trace", "ray_tpu.compile.lower",
+            "ray_tpu.compile.backend", "ray_tpu.compile.cache_read",
+            "ray_tpu.compile.cache_hit", "ray_tpu.compile.cache_miss",
+            "ray_tpu.compile.short", "ray_tpu.parallel.shard_params",
         }
     ),
 }

@@ -62,9 +62,12 @@ TASK, WORKER, LEASE, OBJECT, TRANSFER, SCHED, REFS, CHAOS, HEAD, TRAIN = (
 #: by the thread that takes a report off the session's queue (a system
 #: call costs microseconds on some hosts, so none is made on the loop's
 #: thread): readers take differences between two of them. Every other
-#: ``train`` event is a host span (util/tracing.py): its name is the
-#: event, attrs its monotonic start, the stamps its end. The entity is
-#: the thread the event is about.
+#: ``train`` event is a span (util/tracing.py): its name is the event,
+#: the stamps its end, and attrs its monotonic start, bare for a host
+#: span and, for one of set-up's (``SETUP_SPANS``, built when something
+#: compiles and never in a turn of the loop), in a dict under ``m_start``
+#: beside what else the span says (``fun_name``). _expand gives both
+#: the same shape. The entity is the thread the event is about.
 TRAIN_FIELDS = {
     "REPORT": ("ordinal",),
     "USAGE": (

@@ -367,9 +367,10 @@ def train_rows(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     loop thread's CPU ms, the process's involuntary switches and major
     faults in it), collector pauses as slices nested in it and
     overdue-report samples as instants with the loop thread's frames;
-    the host spans of util/tracing.py as slices on a row for the thread
-    that ran each. Durations are monotonic; a slice is placed by its
-    wall-clock end."""
+    the host spans of util/tracing.py, and set-up's compile and placing
+    spans with what they say of themselves (``fun_name``) as the slice's
+    args, as slices on a row for the thread that ran each. Durations are
+    monotonic; a slice is placed by its wall-clock end."""
     rows: List[Dict[str, Any]] = []
     usage = {
         (ev.get("source", ""), ev["attrs"]["ordinal"]): ev["attrs"]
@@ -413,11 +414,12 @@ def train_rows(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
                 **base, "name": "OVERDUE", "ph": "i", "s": "t",
                 "ts": ev["timestamp"] * 1e6, "args": attrs,
             })
-        elif name != "USAGE":  # a host span: the event is its name
+        elif name != "USAGE":  # a span: the event is its name
             dur = ev["monotonic"] - attrs["m_start"]
             rows.append({
                 **base, "name": name, "tid": f"thread {ev['entity']}",
                 "ph": "X", "ts": (ev["timestamp"] - dur) * 1e6,
-                "dur": dur * 1e6, "args": {},
+                "dur": dur * 1e6,
+                "args": {k: v for k, v in attrs.items() if k != "m_start"},
             })
     return rows

@@ -28,6 +28,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..util import tracing
+
 AXIS_ORDER = ("data", "fsdp", "seq", "tensor", "expert")
 
 # logical axis -> mesh axis (or tuple of mesh axes). First matching rule
@@ -193,8 +195,6 @@ def spec_for_param(path: Tuple[str, ...], shape: Tuple[int, ...]) -> P:
 def shard_params(params, mesh: Mesh, rules=None):
     """Place a parameter pytree on the mesh: explicit flax
     ``nn.with_partitioning`` metadata wins; otherwise spec_for_param."""
-    flat = jax.tree_util.tree_flatten_with_path(params)[0]
-
     def place(path, leaf):
         spec = spec_for_param(
             tuple(getattr(p, "key", getattr(p, "idx", "")) for p in path),
@@ -202,9 +202,13 @@ def shard_params(params, mesh: Mesh, rules=None):
         )
         return jax.device_put(leaf, NamedSharding(mesh, spec))
 
-    leaves = [place(path, leaf) for path, leaf in flat]
-    treedef = jax.tree_util.tree_structure(params)
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+    # The span is the host placing the leaves: device_put returns before a
+    # copy ends, and nothing here waits for one.
+    with tracing.span(tracing.SHARD_PARAMS):
+        flat = jax.tree_util.tree_flatten_with_path(params)[0]
+        leaves = [place(path, leaf) for path, leaf in flat]
+        treedef = jax.tree_util.tree_structure(params)
+        return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def pad_to_multiple(n: int, k: int) -> int:

@@ -15,7 +15,12 @@ and fault counters; a ``GC_PAUSE`` a collection, from a ``gc.callbacks``
 hook; and from one watchdog thread an ``OVERDUE`` with the loop thread's
 innermost frames when a report is late, and again as the wait doubles.
 While a session is open the host spans of ``util/tracing.py`` record there
-too. The events ride the worker's ``task_done`` flushes;
+too, and so does set-up: every trace, lowering, backend compile and
+persistent-cache read of the process as JAX reports it
+(``tracing.watch_compiles``, ``tracing.SETUP_SPANS``), one event each from
+a millisecond up and one ``ray_tpu.compile.short`` event at the record's end
+for the count and the sum of the shorter ones. The events ride the worker's
+``task_done`` flushes;
 ``RAY_TPU_events_enabled=0`` turns all of it off with the recorder.
 """
 from __future__ import annotations
@@ -71,9 +76,16 @@ class TrainSession:
         # Monotonic stamps of the latest reports, for the running median.
         self._stamps: deque = deque(maxlen=self.MEDIAN_OVER + 1)
         self._gc_started = 0.0
+        # stage -> [count, seconds] of the compile durations under
+        # tracing.COMPILE_FLOOR_S, which leave no event of their own.
+        self._short_compiles: Dict[str, list] = {}
         self._closed = threading.Event()
         gc.callbacks.append(self._on_gc)
-        tracing.record_spans_into(self._recorder)
+        # Never imported for the record's sake: ray_tpu.train loads jax today
+        # (config.py names MeshSpec), and ray_tpu.parallel watches as it does.
+        if "jax" in sys.modules:
+            tracing.watch_compiles()
+        tracing.record_spans_into(self._recorder, self._short_compiles)
         threading.Thread(
             target=self._watch, name="train-overdue", daemon=True
         ).start()
@@ -152,6 +164,12 @@ class TrainSession:
         self._closed.set()
         gc.callbacks.remove(self._on_gc)
         tracing.record_spans_into(None)
+        if self._short_compiles:
+            m = time.monotonic()
+            self._recorder.record_at(
+                time.time(), m, _events.TRAIN, str(threading.get_ident()),
+                tracing.COMPILE_SHORT, {"m_start": m, **self._short_compiles},
+            )
 
     def finish(self, error: Optional[BaseException] = None):
         self.error = error

@@ -19,6 +19,8 @@ def make_train_step(loss_fn: Callable[..., Any], tx) -> Callable[..., Any]:
 
     from ..util import tracing
 
+    tracing.watch_compiles()
+
     @partial(jax.jit, donate_argnums=(0, 1))
     def train_step(params, opt_state, *batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, *batch)

@@ -1,6 +1,6 @@
 """Program spans on the profiler's clock: the one place that names them.
 
-Two things and a list. ``span(name)`` is a host span: a
+Three things and a list. ``span(name)`` is a host span: a
 ``jax.profiler.TraceAnnotation`` when ``jax`` is already in ``sys.modules``
 and nothing otherwise. It never imports jax, so the head, a raylet and a
 driver that opens no backend pay a dictionary lookup and nothing else; in
@@ -31,6 +31,13 @@ also leaves one event in that recorder's ``train`` category when it ends,
 profiler session or none: the thread, the name and both ends on the
 monotonic clock. ``ray_tpu.worker.exec`` leaves none: the ``task``
 category's ``EXEC_SPAN`` already holds its interval and thread.
+
+``watch_compiles()`` is the third: JAX's own account of every trace,
+lowering, backend compile and persistent-cache read of the process
+(``jax.monitoring``), as events of the same layout in the same category
+while a session is held, so that set-up is on the record the turns are on.
+Their attrs are a dict, ``{"m_start": ..., "fun_name": ...}``: it is built
+when something compiles, never in a turn of the loop.
 """
 from __future__ import annotations
 
@@ -51,6 +58,22 @@ WORKER_REPLY = "ray_tpu.worker.reply"  # results packed, reply and task_done han
 WORKER_RECV = "ray_tpu.worker.recv"  # one received frame dispatched, not the socket wait
 HOST_SPANS = (TRAIN_REPORT, TRAIN_NEXT_RESULT, TRAIN_RESULT_WAIT,
               WORKER_EXEC, WORKER_REPLY, WORKER_RECV)
+# Set-up's spans, on the thread that compiled or placed. The compile ones are
+# stamped by watch_compiles()'s listeners as JAX reports each duration (its
+# end is the report, its start the end less the duration); a jit called while
+# another is traced sends an interval inside its caller's, so a reader takes
+# unions, never sums.
+COMPILE_TRACE = "ray_tpu.compile.trace"  # a function traced to a jaxpr; fun_name is the function's
+COMPILE_LOWER = "ray_tpu.compile.lower"  # a jaxpr lowered to its module, the Mosaic bodies inside it; fun_name jit(<function>)
+COMPILE_BACKEND = "ray_tpu.compile.backend"  # an executable made: the backend's compile, or the persistent cache's read on a hit; fun_name jit(<function>)
+COMPILE_CACHE_READ = "ray_tpu.compile.cache_read"  # inside backend, on a hit: the read and the executable's load
+COMPILE_CACHE_HIT = "ray_tpu.compile.cache_hit"  # inside backend, of no length: the persistent cache held the executable
+COMPILE_CACHE_MISS = "ray_tpu.compile.cache_miss"  # inside backend, of no length: it did not, and the compiled one was written there
+COMPILE_SHORT = "ray_tpu.compile.short"  # of no length, once, as the session's record ends: {stage: [count, seconds]} of the durations under COMPILE_FLOOR_S, which leave no event of their own
+SHARD_PARAMS = "ray_tpu.parallel.shard_params"  # parallel/mesh.py shard_params: the host placing the leaves (device_put returns before a copy ends)
+SETUP_SPANS = (COMPILE_TRACE, COMPILE_LOWER, COMPILE_BACKEND,
+               COMPILE_CACHE_READ, COMPILE_CACHE_HIT, COMPILE_CACHE_MISS,
+               COMPILE_SHORT, SHARD_PARAMS)
 
 # In-graph scopes. Forward and backward are already told apart by JAX's
 # ``jvp(`` / ``transpose(`` and a remat replay by ``rematted_computation``.
@@ -127,15 +150,18 @@ BODY = (EMBED, LAYER, INPUT_NORM, POST_ATTN_NORM, MLP, MOE, MIXER_HC, FFN_HC,
         MTP_LAYER, MTP_NORM, POST_MIXER_NORM, POST_FFN_NORM)
 
 _OFF = contextlib.nullcontext()
-# The flight recorder, while this process holds a train session.
+# The flight recorder, while this process holds a train session, and the
+# session's tally of compile durations too short for an event of their own.
 _recorder = None
+_short = {}
 
 
-def record_spans_into(recorder) -> None:
-    """``train/session.py`` hands over the process's flight recorder when a
+def record_spans_into(recorder, short=None) -> None:
+    """``train/session.py`` hands over the process's flight recorder, and the
+    dict it tallies short compile durations in (``COMPILE_SHORT``), when a
     session starts and None when it ends."""
-    global _recorder
-    _recorder = recorder
+    global _recorder, _short
+    _recorder, _short = recorder, {} if short is None else short
 
 
 class _RecordedSpan:
@@ -171,6 +197,74 @@ def span(name: str):
     if recorder is None or not recorder.enabled or name == WORKER_EXEC:
         return _OFF if annotation is None else annotation
     return _RecordedSpan(name, annotation, recorder)
+
+
+#: A compile duration under this leaves no event: a model's step traces
+#: thousands of ``jnp`` functions in well under a millisecond each, and the
+#: recorder's ring holds 8,192 events between two flushes, none of which
+#: comes before set-up's report. They are counted (``COMPILE_SHORT``).
+COMPILE_FLOOR_S = 1e-3
+_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": COMPILE_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": COMPILE_LOWER,
+    "/jax/core/compile/backend_compile_duration": COMPILE_BACKEND,
+    "/jax/compilation_cache/cache_retrieval_time_sec": COMPILE_CACHE_READ,
+}
+_POINTS = {
+    "/jax/compilation_cache/cache_hits": COMPILE_CACHE_HIT,
+    "/jax/compilation_cache/cache_misses": COMPILE_CACHE_MISS,
+}
+_watch_lock = threading.Lock()
+_watching = False
+
+
+def _record_compile(name, duration, fun_name=None):
+    recorder = _recorder
+    if recorder is None or not recorder.enabled:
+        return
+    if 0.0 < duration < COMPILE_FLOOR_S:
+        tally = _short.setdefault(name.rpartition(".")[2], [0, 0.0])
+        tally[0] += 1
+        tally[1] += duration
+        return
+    m = time.monotonic()
+    attrs = {"m_start": m - duration}
+    if fun_name is not None:
+        attrs["fun_name"] = fun_name
+    recorder.record_at(
+        time.time(), m, _events.TRAIN, str(threading.get_ident()), name, attrs
+    )
+
+
+def _on_duration(event, duration, fun_name=None, **_):
+    if event in _DURATIONS:
+        _record_compile(_DURATIONS[event], duration, fun_name)
+
+
+def _on_point(event, **_):
+    if event in _POINTS:
+        _record_compile(_POINTS[event], 0.0)  # a span of no length
+
+
+def watch_compiles() -> None:
+    """Registers, once a process, the two ``jax.monitoring`` listeners that
+    turn JAX's compile durations and cache events into ``SETUP_SPANS``
+    events. It imports jax: ``ray_tpu.parallel`` calls it as it is imported
+    and ``train.make_train_step`` as it is called, and a train session as
+    it starts where jax is already loaded. A loop that compiles before it
+    touches any of these is seen from that touch on. The two stay for the
+    life of the process and send nowhere while no session is held
+    (``record_spans_into``): sessions that follow one another in a process
+    stack nothing, and there is nothing to take off."""
+    global _watching
+    with _watch_lock:
+        if _watching:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_point)
+        _watching = True
 
 
 def scope(name: str):

@@ -1020,8 +1020,8 @@ def test_source_names_no_span_or_scope_outside_the_list():
     constants = {k for k, v in vars(tracing).items()
                  if k.isupper() and isinstance(v, str)}
     assert {getattr(tracing, k) for k in constants} == (
-        set(tracing.HOST_SPANS) | set(tracing.SCOPES) | set(tracing.MIXERS)
-        | set(tracing.BODY)
+        set(tracing.HOST_SPANS) | set(tracing.SETUP_SPANS) | set(tracing.SCOPES)
+        | set(tracing.MIXERS) | set(tracing.BODY)
     )
     literal = re.compile(r'name=f?"(?:%s)' % "|".join(tracing.MIXERS + tracing.BODY))
     calls = 0

@@ -8,6 +8,7 @@ import gc
 import glob
 import json
 import os
+import sys
 import threading
 import time
 
@@ -268,6 +269,253 @@ def test_a_ring_overflow_is_counted_in_the_file(fit, tmp_path):
     assert header["source"] != fit["header"]["source"]
 
 
+# ----------------------------------------------------- set-up on the record
+
+STAGES = ("trace", "lower", "backend", "cache_read", "cache_hit", "cache_miss")
+JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+
+
+def count_compiles(into):
+    """A listener of the test's own on JAX's hooks: stage -> count."""
+    import jax
+
+    def on_event(event, *_, **__):
+        if event in JAX_EVENTS:
+            into[JAX_EVENTS[event]] = into.get(JAX_EVENTS[event], 0) + 1
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+
+
+def use_cache(directory):
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
+def compiling_loop(config):
+    """Jits one function at one shape before set-up's report and at another
+    in the turn after it. The sleeps run while it is traced, so its trace
+    spans are well over the floor; ``inner`` is a jit called from a jit."""
+    at_start = {"jax_loaded": "jax" in sys.modules, "watching": tracing._watching}
+    import jax
+    import jax.numpy as jnp
+
+    if config["root"] not in sys.path:
+        sys.path.insert(0, config["root"])
+    from benchmarks.lib.checks import CompileCounter
+
+    use_cache(config["cache"])
+    own, theirs = {}, CompileCounter().install()
+    count_compiles(own)
+
+    @jax.jit
+    def inner(x):
+        time.sleep(0.005)
+        return jnp.sin(x)
+
+    @jax.jit
+    def outer(x):
+        time.sleep(0.01)
+        for _ in range(64):
+            x = inner(x) * 1.0001
+        return x.sum()
+
+    outer(jnp.ones((4,)))
+    rt_train.report({"own": dict(own), "theirs": theirs.snapshot(), **at_start})
+    outer(jnp.ones((8,)))
+    rt_train.report({"own": dict(own), "theirs": theirs.snapshot()})
+
+
+@pytest.fixture(scope="module")
+def compiled(fit, tmp_path_factory):
+    """Two fits of ``compiling_loop`` over one persistent cache, a new worker
+    process each: the first finds it empty, the second full."""
+    from ray_tpu._private import state
+    from ray_tpu.util.state import list_cluster_events
+
+    cache = str(tmp_path_factory.mktemp("jax_cache"))
+    runs = []
+    for name in ("cold", "warm"):
+        storage = str(tmp_path_factory.mktemp(name))
+        result = JaxTrainer(
+            compiling_loop,
+            train_loop_config={"cache": cache, "root": os.path.dirname(
+                os.path.dirname(os.path.abspath(__file__)))},
+            scaling_config=ScalingConfig(num_workers=1),
+            run_config=RunConfig(name=name, storage_path=storage),
+        ).fit()
+        assert result.error is None
+        header, lines = read_file(storage)
+        runs.append({"header": header, "lines": lines, "storage": storage,
+                     "history": result.metrics_history})
+    return {"cold": runs[0], "warm": runs[1],
+            "listed": list_cluster_events(category="train", limit=100_000),
+            "timeline": state.timeline()}
+
+
+def stage_spans(run, stage, fun_name=None):
+    return [e for e in named(run, f"ray_tpu.compile.{stage}")
+            if fun_name is None or e["attrs"].get("fun_name") == fun_name]
+
+
+def test_a_loop_that_jits_leaves_its_trace_lower_and_backend_spans(compiled):
+    for run in (compiled["cold"], compiled["warm"]):
+        reports = named(run, "REPORT")
+        loop_thread = reports[0]["entity"]
+        for stage, fun_name in (("trace", "outer"), ("lower", "jit(outer)"),
+                                ("backend", "jit(outer)")):
+            spans = stage_spans(run, stage, fun_name)
+            # One shape in set-up, the other in the turn after it.
+            assert [e["monotonic"] < reports[0]["monotonic"] for e in spans] \
+                == [True, False], (stage, spans)
+            assert spans[1]["monotonic"] < reports[1]["monotonic"]
+            assert {e["entity"] for e in spans} == {loop_thread}
+            for e in spans:
+                assert set(e["attrs"]) == {"m_start", "fun_name"}
+                assert 0 < e["monotonic"] - e["attrs"]["m_start"] < 30
+        # The sleeps are inside the traces: 64 calls of inner, one trace.
+        for e in stage_spans(run, "trace", "outer"):
+            assert e["monotonic"] - e["attrs"]["m_start"] >= 0.015
+        assert run["header"]["dropped"] == 0
+
+
+def test_a_cold_cache_is_a_miss_an_executable_and_a_warm_one_a_hit_and_a_read(compiled):
+    cold, warm = compiled["cold"], compiled["warm"]
+    assert not named(cold, tracing.COMPILE_CACHE_HIT)
+    assert not named(warm, tracing.COMPILE_CACHE_MISS)
+    assert not named(cold, tracing.COMPILE_CACHE_READ)
+    for run, point in ((cold, tracing.COMPILE_CACHE_MISS),
+                       (warm, tracing.COMPILE_CACHE_HIT)):
+        points = named(run, point)
+        # Every executable of the run asked the cache: each point lies inside
+        # one backend span of its thread, outer's two among them.
+        backends = stage_spans(run, "backend")
+        assert len(points) == run["history"][-1]["own"]["backend"] >= 2
+        for p in points:
+            assert p["attrs"] == {"m_start": p["monotonic"]}
+            assert sum(1 for b in backends if b["entity"] == p["entity"]
+                       and b["attrs"]["m_start"] <= p["monotonic"] <= b["monotonic"]) <= 1
+        for b in stage_spans(run, "backend", "jit(outer)"):
+            assert sum(1 for p in points
+                       if b["attrs"]["m_start"] <= p["monotonic"] <= b["monotonic"]) == 1
+    # A read for every hit, of a millisecond or more or in the short tally.
+    reads = named(warm, tracing.COMPILE_CACHE_READ)
+    short = named(warm, tracing.COMPILE_SHORT)[0]["attrs"]
+    assert len(reads) + short.get("cache_read", [0])[0] \
+        == len(named(warm, tracing.COMPILE_CACHE_HIT))
+    for e in reads:
+        assert "fun_name" not in e["attrs"]
+        assert tracing.COMPILE_FLOOR_S <= e["monotonic"] - e["attrs"]["m_start"] < 30
+
+
+def test_the_spans_counts_agree_with_a_listener_of_the_tests_own(compiled):
+    for run in (compiled["cold"], compiled["warm"]):
+        own, theirs = run["history"][-1]["own"], run["history"][-1]["theirs"]
+        shorts = named(run, tracing.COMPILE_SHORT)
+        assert len(shorts) == 1  # once, as the record ends
+        short = shorts[0]["attrs"]
+        assert shorts[0]["monotonic"] == short["m_start"] \
+            >= named(run, "REPORT")[-1]["monotonic"]
+        assert set(short) - {"m_start"} <= set(STAGES[:4])
+        for stage in STAGES:
+            recorded = len(stage_spans(run, stage))
+            count, seconds = short.get(stage, [0, 0.0])
+            assert recorded + count == own.get(stage, 0), stage
+            # A short one is under the floor; one with an event is not.
+            assert 0 <= seconds <= count * tracing.COMPILE_FLOOR_S
+            if stage in STAGES[:4]:
+                assert all(e["monotonic"] - e["attrs"]["m_start"]
+                           >= tracing.COMPILE_FLOOR_S * 0.999
+                           for e in stage_spans(run, stage))
+        # A model's inner jnp traces are the short ones.
+        assert short["trace"][0] >= 64
+        # The benchmark's counter stays the benchmark's, and agrees.
+        assert theirs["backend_compiles"] == own["backend"]
+        assert theirs["hits"] == own.get("cache_hit", 0) \
+            == len(named(run, tracing.COMPILE_CACHE_HIT))
+        assert theirs["requests"] == own["backend"]
+
+
+def test_an_inner_jits_interval_lies_inside_its_callers(compiled):
+    for run in (compiled["cold"], compiled["warm"]):
+        outers = stage_spans(run, "trace", "outer")
+        inners = stage_spans(run, "trace", "inner")
+        assert len(outers) == len(inners) == 2  # traced once a shape
+        for outer, inner in zip(outers, inners):
+            assert outer["attrs"]["m_start"] < inner["attrs"]["m_start"]
+            assert inner["monotonic"] < outer["monotonic"]
+            assert inner["monotonic"] - inner["attrs"]["m_start"] >= 0.005
+
+
+def test_timeline_lays_the_compile_spans_on_the_compiling_threads_row(compiled):
+    source = compiled["cold"]["header"]["source"]
+    loop_thread = named(compiled["cold"], "REPORT")[0]["entity"]
+    rows = [r for r in compiled["timeline"]
+            if r.get("cat") == "train" and r["pid"] == "train " + source
+            and r["name"].startswith("ray_tpu.compile.")]
+    assert {r["tid"] for r in rows} == {f"thread {loop_thread}"}
+    traced = [r for r in rows if r["name"] == tracing.COMPILE_TRACE
+              and r["args"] == {"fun_name": "outer"}]
+    assert len(traced) == 2 and all(r["ph"] == "X" for r in traced)
+    assert all(r["dur"] >= 0.015e6 for r in traced)
+    backend = [r for r in rows if r["name"] == tracing.COMPILE_BACKEND
+               and r["args"] == {"fun_name": "jit(outer)"}]
+    misses = [r for r in rows if r["name"] == tracing.COMPILE_CACHE_MISS]
+    assert len(backend) == 2 and all(r["dur"] == 0 and r["args"] == {} for r in misses)
+    for b in backend:  # the miss lies in its executable's slice
+        assert sum(1 for r in misses if b["ts"] - 1 <= r["ts"] <= b["ts"] + b["dur"] + 1) == 1
+    short = [r for r in rows if r["name"] == tracing.COMPILE_SHORT]
+    assert len(short) == 1 and short[0]["args"]["trace"][0] >= 64
+    # The host spans' slices say nothing more than they did.
+    assert all(r["args"] == {} for r in compiled["timeline"]
+               if r.get("cat") == "train" and r["name"] in tracing.HOST_SPANS)
+
+
+def test_events_cli_lists_the_compile_spans(compiled, monkeypatch, capsys):
+    from ray_tpu.scripts import cli
+
+    monkeypatch.setattr(cli, "_connect", lambda: None)
+    cli.main(["events", "--category", "train", "--limit", "100000"])
+    table = capsys.readouterr().out
+    for name in tracing.SETUP_SPANS:
+        if name != tracing.SHARD_PARAMS:  # the loop places nothing
+            assert name in table
+    assert '"fun_name": "jit(outer)"' in table
+    cli.main(["events", "--category", "train", "--limit", "100000", "--json"])
+    rows = json.loads(capsys.readouterr().out)
+    warm = compiled["warm"]["header"]["source"]
+    assert sum(1 for r in rows if r["source"] == warm
+               and r["event"] == tracing.COMPILE_CACHE_HIT) \
+        == len(named(compiled["warm"], tracing.COMPILE_CACHE_HIT))
+    traced = [r for r in rows if r["source"] == warm
+              and r["event"] == tracing.COMPILE_TRACE
+              and r["attrs"]["fun_name"] == "outer"]
+    assert len(traced) == 2 and all("m_start" in r["attrs"] for r in traced)
+
+
+def test_jax_is_loaded_when_a_workers_loop_starts(compiled):
+    """``ray_tpu.train`` imports ``ray_tpu.parallel`` (``train/config.py``
+    names ``MeshSpec``), which imports jax and registers the listeners: a
+    worker's session finds jax loaded (it never imports it itself,
+    ``test_a_session_that_starts_without_jax_imports_and_registers_nothing``),
+    and set-up is seen from its first compile, the eager ones before the
+    loop's first jit among them."""
+    for run in (compiled["cold"], compiled["warm"]):
+        assert run["history"][0]["jax_loaded"] and run["history"][0]["watching"]
+        first = min(stage_spans(run, "backend"), key=lambda e: e["monotonic"])
+        assert first["monotonic"] < stage_spans(run, "backend", "jit(outer)")[0]["monotonic"]
+
+
 # ------------------------------------------------ in one process, no cluster
 
 
@@ -300,9 +548,13 @@ def test_every_new_name_is_in_the_registry():
     assert "TRAIN" in event_names.CATEGORY_CONSTS
     assert set(events.TRAIN_FIELDS) == {"REPORT", "USAGE", "GC_PAUSE", "OVERDUE"}
     assert set(events.TRAIN_FIELDS) <= registered
-    # Every host span but the one the task category's EXEC_SPAN holds.
+    # Every host span but the one the task category's EXEC_SPAN holds, and
+    # set-up's spans.
     assert registered - set(events.TRAIN_FIELDS) \
-        == set(tracing.HOST_SPANS) - {tracing.WORKER_EXEC}
+        == (set(tracing.HOST_SPANS) - {tracing.WORKER_EXEC}) | set(tracing.SETUP_SPANS)
+    assert len(tracing.SETUP_SPANS) == 8
+    assert all(n.startswith(("ray_tpu.compile.", "ray_tpu.parallel."))
+               for n in tracing.SETUP_SPANS)
     assert all(event_names.is_registered(n) for n in registered)
 
 
@@ -456,6 +708,169 @@ def test_a_disabled_recorder_records_nothing_of_a_session(ring):
         session.close_record()
     assert len(ring) == 0 and len(session._stamps) == 0
     assert session.next_result(timeout=1)[0] == "report"
+
+
+def compile_events(ring):
+    """The ring's compile events, expanded as the head expands them."""
+    return [e for e in (events._expand(i, "here")[0] for i in ring.drain()[0])
+            if e["event"].startswith("ray_tpu.compile.")]
+
+
+def jit_something_new(scale):
+    """A function no test has jitted: its trace holds a sleep of 5 ms."""
+    import jax
+    import jax.numpy as jnp
+
+    def fresh(x):
+        time.sleep(0.005)
+        return jnp.cos(x) * scale
+
+    fresh.__name__ = f"fresh_{scale}"
+    return float(jax.jit(fresh)(jnp.ones((3,)))[0])
+
+
+def test_durations_under_the_floor_reach_the_record_as_a_count_and_a_sum(ring, monkeypatch):
+    import jax
+
+    seen = []
+
+    def own(event, duration, **_):
+        if event in JAX_EVENTS:
+            seen.append((JAX_EVENTS[event], duration))
+
+    monkeypatch.setattr(tracing, "COMPILE_FLOOR_S", 60.0)
+    session = TrainSession(context())
+    jax.monitoring.register_event_duration_secs_listener(own)
+    try:
+        jit_something_new(2)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(own)
+        session.close_record()
+        session.close_record()  # said once
+    said = compile_events(ring)
+    assert [e["event"] for e in said] == [tracing.COMPILE_SHORT]
+    short = said[0]["attrs"]
+    assert short.pop("m_start") == said[0]["monotonic"]
+    assert said[0]["entity"] == str(threading.get_ident())
+    assert set(short) == {"trace", "lower", "backend"} == {s for s, _ in seen}
+    for stage, (count, seconds) in short.items():
+        assert count == sum(1 for s, _ in seen if s == stage) >= 1
+        assert seconds == pytest.approx(sum(d for s, d in seen if s == stage))
+    assert short["trace"][1] >= 0.005
+
+
+def test_two_sessions_in_one_process_register_one_listener(ring):
+    from jax._src import monitoring
+
+    first = init_session(context())
+    jit_something_new(3)
+    second = init_session(context())  # ends the first's record
+    try:
+        jit_something_new(4)
+    finally:
+        second.close_record()
+    assert monitoring.get_event_duration_listeners().count(tracing._on_duration) == 1
+    assert monitoring.get_event_listeners().count(tracing._on_point) == 1
+    said = compile_events(ring)
+    for scale in (3, 4):  # each compile once, whichever session held it
+        assert [e["attrs"]["fun_name"] for e in said
+                if e["event"] == tracing.COMPILE_TRACE
+                and e["attrs"]["fun_name"].startswith("fresh_")].count(f"fresh_{scale}") == 1
+    # Each session's tally of short ones is its own, said as it ends.
+    assert [e["event"] for e in said].count(tracing.COMPILE_SHORT) == 2
+    assert first._short_compiles is not second._short_compiles
+
+
+def test_no_session_or_a_disabled_recorder_records_nothing_that_compiles(ring):
+    tracing.watch_compiles()
+    jit_something_new(5)  # no session is held
+    assert len(ring) == 0
+    ring.enabled = False
+    session = TrainSession(context())
+    try:
+        jit_something_new(6)
+    finally:
+        session.close_record()
+    assert len(ring) == 0 and session._short_compiles == {}
+
+
+def test_a_session_that_starts_without_jax_imports_and_registers_nothing(ring, monkeypatch):
+    monkeypatch.delitem(sys.modules, "jax")
+    monkeypatch.setattr(tracing, "_watching", False)
+    session = TrainSession(context())
+    try:
+        assert "jax" not in sys.modules and tracing._watching is False
+    finally:
+        session.close_record()
+
+
+def test_shard_params_leaves_its_span(ring):
+    import numpy as np
+    from ray_tpu.parallel import MeshSpec, shard_params
+
+    mesh = MeshSpec().build()
+    tree = {"layer": {"w": np.ones((8, 8), np.float32)}, "b": np.zeros((8,), np.float32)}
+    session = TrainSession(context())
+    try:
+        before = time.monotonic()
+        placed = shard_params(tree, mesh)
+        after = time.monotonic()
+    finally:
+        session.close_record()
+    assert placed["layer"]["w"].shape == (8, 8)
+    spans = [i for i in ring.drain()[0] if i[4] == tracing.SHARD_PARAMS]
+    assert len(spans) == 1
+    t_wall, t_mono, category, entity, event, m_start = spans[0]
+    assert category == events.TRAIN and entity == str(threading.get_ident())
+    assert before <= m_start < t_mono <= after
+    assert events._expand(spans[0], "here")[0]["attrs"] == {"m_start": m_start}
+    shard_params(tree, mesh)  # no session: the placing is not recorded
+    assert not [i for i in ring.drain()[0] if i[4] == tracing.SHARD_PARAMS]
+
+
+def test_the_benchmarks_readers_take_a_file_that_holds_the_new_events(tmp_path):
+    """``benchmarks/lib/train_events.py`` reads ``attrs["m_start"]`` of every
+    ``train`` event it does not know: set-up's events are laid out so, and
+    its five metrics of a recorded window are what they were without them."""
+    from benchmarks.lib import train_events
+    from benchmarks.tests import test_train_events_readers as recorded
+
+    def metrics(directory):
+        return dict(train_events.metrics(recorded.a_run(directory)))
+
+    plain, held = tmp_path / "plain", tmp_path / "held"
+    recorded.write_record(str(plain))
+    recorded.write_record(str(held))
+    path = os.path.join(str(held), train_events.FILE)
+    with open(path) as f:
+        lines = [json.loads(text) for text in f]
+
+    def event(name, entity, mono, attrs):
+        return {"category": "train", "event": name, "entity": entity,
+                "timestamp": 1000.0 + mono, "monotonic": mono,
+                "attrs": attrs, "source": "worker-x"}
+
+    loop, m = recorded.LOOP, 50.0  # the recorded set-up's report is at 50.0
+    added = [
+        event(tracing.COMPILE_TRACE, loop, m - 3.0, {"m_start": m - 4.0, "fun_name": "train_step"}),
+        event(tracing.COMPILE_LOWER, loop, m - 2.0, {"m_start": m - 3.0, "fun_name": "jit(train_step)"}),
+        event(tracing.COMPILE_CACHE_MISS, loop, m - 1.5, {"m_start": m - 1.5}),
+        event(tracing.COMPILE_BACKEND, loop, m - 1.0, {"m_start": m - 2.0, "fun_name": "jit(train_step)"}),
+        event(tracing.SHARD_PARAMS, loop, m - 4.5, {"m_start": m - 5.0}),
+        # A recompile inside the window's third turn, on the loop's thread
+        # and on another.
+        event(tracing.COMPILE_BACKEND, loop, m + 0.025, {"m_start": m + 0.021, "fun_name": "jit(f)"}),
+        event(tracing.COMPILE_TRACE, recorded.POOL, m + 0.025, {"m_start": m + 0.021, "fun_name": "f"}),
+        event(tracing.COMPILE_SHORT, loop, m + 1.0, {"m_start": m + 1.0, "trace": [7, 0.001]}),
+    ]
+    with open(path, "w") as f:
+        f.writelines(json.dumps(e) + "\n" for e in lines[:1] + added + lines[1:])
+    record = train_events._load(path)
+    assert len(record["spans"]) == len(train_events._load(
+        os.path.join(str(plain), train_events.FILE))["spans"]) + len(added)
+    assert [tracing.COMPILE_TRACE, loop, m - 4.0, m - 3.0, 1000.0 + m - 3.0] in record["spans"]
+    assert metrics(held) == metrics(plain)
+    assert set(metrics(held)) == set(train_events.NAMES)
 
 
 def test_the_watchdog_wakes_less_than_once_a_turn(ring, monkeypatch):
