@@ -134,11 +134,15 @@ EVENTS_BY_CATEGORY = {
             # cache's hits and misses as spans of no length, one span
             # of no length as the record ends whose attrs also hold
             # {stage: [count, seconds]} of the durations too short for an
-            # event, and parallel/mesh.py shard_params.
+            # event, another there whose attrs hold {entry: [calls,
+            # traces]} of the kernels' inlined jitted entries
+            # (ops/attention.py kernel_entry), and parallel/mesh.py
+            # shard_params.
             "ray_tpu.compile.trace", "ray_tpu.compile.lower",
             "ray_tpu.compile.backend", "ray_tpu.compile.cache_read",
             "ray_tpu.compile.cache_hit", "ray_tpu.compile.cache_miss",
-            "ray_tpu.compile.short", "ray_tpu.parallel.shard_params",
+            "ray_tpu.compile.short", "ray_tpu.compile.entries",
+            "ray_tpu.parallel.shard_params",
         }
     ),
 }

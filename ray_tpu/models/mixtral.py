@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.attention import kernel_entry
 from ..parallel import (
     ambient_axes, ambient_spec, logical_axis_shards, with_logical_constraint,
 )
@@ -779,18 +780,20 @@ def _held_ffn_bwd(residuals, g):
 _held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
 
 
-# ``_held_ffn`` behind a jitted entry that JAX inlines as it traces: a model's
-# expert layers are one shape, so the function, its forward rule and the
-# loops in both are traced once and not once a layer (each layer's own cost
-# the Laguna cell 8 s of a 58 s set-up: PERF.md §6, PR 60), and the step's
-# text holds what it held, a layer at a time, scopes and kernels where they
-# were. ``traced_under`` is what a trace depends on beside its arguments (the
-# kernels' interpreter, the window), so that a trace is not taken for
-# another's. The road that gathers takes it; the road that walks calls
+# ``_held_ffn`` behind the kernels' kind of entry (``ops/attention.py``
+# ``kernel_entry``: jitted, inlined as JAX traces): a model's expert layers
+# are one shape, so the function, its forward rule and the loops in both are
+# traced once and not once a layer (each layer's own cost the Laguna cell 8 s
+# of a 58 s set-up: PERF.md §6, PR 60), and the step's text holds what it
+# held, a layer at a time, scopes and kernels where they were. What a trace
+# depends on beside its arguments is the entry's to know; this one reads the
+# window too. The road that gathers takes it; the road that walks calls
 # ``_held_ffn`` as it did, and its text is the one it had (ROADMAP A7b).
-@partial(jax.jit, static_argnums=(0,), inline=True)
-def _held_inlined(traced_under, *args):
-    return _held_ffn(*args)
+@kernel_entry(reads=lambda: (_WINDOW,))
+def _held_inlined(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
+                  tiles_used, slot_of_pair):
+    return _held_ffn(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
+                     tiles_used, slot_of_pair)
 
 
 CONFIGS = {
@@ -993,11 +996,7 @@ class MoELayer(nn.Module):
                         slot_of_pair = _held_index(
                             order, dst, n_here, N, tiles * 128
                         ).reshape(B * T, K)
-            share = _held_ffn
-            if slot_of_pair is not None:
-                from ..ops.gmm import _interpret
-
-                share = partial(_held_inlined, (_interpret(), _WINDOW))
+            share = _held_ffn if slot_of_pair is None else _held_inlined
             out2 = share(
                 x2, gate_vals.astype(cfg.dtype).reshape(B * T, K),
                 w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),

@@ -22,6 +22,7 @@ the same blocks (`_block_fwd`, `_block_bwd`).
 from __future__ import annotations
 
 import functools
+import inspect
 import os
 from typing import Callable, NamedTuple, Optional
 
@@ -33,6 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..parallel.mesh import ambient_axes, ambient_spec, logical_axis_shards
+from ..util import tracing
 
 NEG_INF = -1e30
 
@@ -53,6 +55,60 @@ def _interpret() -> bool:
             "is for the CPU tests; unset it to run the compiled kernels"
         )
     return on
+
+
+def kernel_entry(*static: str, reads: Optional[Callable[[], tuple]] = None):
+    """A kernel's call site behind one jitted entry that JAX inlines as it
+    traces: ``@kernel_entry("heads", "norm")`` over a function whose body ends
+    in ``pl.pallas_call(...)(...)``. JAX keeps one jaxpr a (function, shapes
+    and dtypes of its arrays, values of its ``static`` arguments), and at an
+    inlined call writes that jaxpr's equations again under the caller's name
+    stack: a model's second layer of a shape runs no Python of the wrapper or
+    of the kernel's body (nine Mamba layers traced ``_ssd_bwd_kernel``, whose
+    body differentiates a chunk, nine times: 24 of the Granite cell's 29 s of
+    tracing, PERF.md §6, PR 68), and the step's text holds every call where it
+    was, under its layer's scopes: the equations are the caller's, no ``jit``
+    is left in the jaxpr.
+
+    Arrays (and None) are arguments; everything else a trace follows is named
+    in ``static`` and hashes by value, so it is a number, a string, a dtype or
+    a tuple of them, never a closure made at the call (a ``_Mask``): a wrapper
+    that builds ``functools.partial(kernel, norm=norm)`` does so inside the
+    entry, from the static ``norm``. What a trace depends on beside its
+    arguments is part of the key too, here and nowhere else: the
+    interpreter's switch, the backend's probe (benchmarks/rehearse.py and
+    tests replace ``_on_tpu``), and ``reads()``, the module constants and
+    functions the body reads that a test patches. Under a key that differs
+    the body is traced anew, never taken for another's.
+
+    The entry counts its calls and its traces (``util/tracing.py``
+    ``count_entry``): "52 calls, 6 traces" is what the mechanism saved."""
+    def wrap(fn):
+        name = f"{fn.__module__.rpartition('.')[2]}.{fn.__name__}"
+        parameters = inspect.signature(fn).parameters
+        names = tuple(parameters)
+        defaults = {k: p.default for k, p in parameters.items()
+                    if p.default is not p.empty}
+
+        def body(*, traced_under, **operands):
+            tracing.count_entry(name, True)
+            return fn(**operands)
+
+        # What JAX reports a trace under (``ray_tpu.compile.trace``).
+        body.__name__ = body.__qualname__ = fn.__name__
+        entry = jax.jit(body, static_argnames=("traced_under", *static),
+                        inline=True)
+
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            tracing.count_entry(name, False)
+            under = (_interpret(), _on_tpu(), *(reads() if reads else ()))
+            return entry(traced_under=under,
+                         **{**defaults, **dict(zip(names, args)), **kwargs})
+
+        return call
+
+    return wrap
 
 
 def attention_reference(
@@ -901,6 +957,24 @@ def _backward_call(mask: _Mask, q, k, v, o, lse, do, sm_scale: float,
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
+# The entries (``kernel_entry``) stand above the mask, here for the bitmap and
+# at ``_block_fwd`` and ``_block_bwd`` for the other two: a ``_Mask`` is a
+# tuple of closures made afresh at every call, which as a static argument
+# would hash by what it is and not by what it says, and no second layer would
+# find the first's trace. What makes the mask is plain values.
+
+
+@kernel_entry("sm_scale", "block_size")
+def _sparse_fwd(q, k, v, words, sm_scale, block_size):
+    return _forward_call(_bitmap_mask(q, k, block_size), q, k, v, sm_scale, words)
+
+
+@kernel_entry("sm_scale", "block_size")
+def _sparse_bwd(q, k, v, words, o, lse, do, sm_scale, block_size):
+    return _backward_call(
+        _bitmap_mask(q, k, block_size), q, k, v, o, lse, do, sm_scale, words)
+
+
 # The bitmap's road has a ``custom_vjp`` of its own: its residuals carry other
 # names (a policy keeps a sparse layer's apart from a full one's), and the
 # words are an operand with no gradient.
@@ -908,11 +982,11 @@ def _backward_call(mask: _Mask, q, k, v, o, lse, do, sm_scale: float,
 def _sparse_flash(q, k, v, words, sm_scale, block_size):
     """q [b * h, t, d]; k, v [b * g, t, .]; words [b * g, t, lanes]; t
     padded to the tiles."""
-    return _forward_call(_bitmap_mask(q, k, block_size), q, k, v, sm_scale, words)[0]
+    return _sparse_fwd(q, k, v, words, sm_scale, block_size)[0]
 
 
 def _sparse_flash_fwd(q, k, v, words, sm_scale, block_size):
-    o, lse = _forward_call(_bitmap_mask(q, k, block_size), q, k, v, sm_scale, words)
+    o, lse = _sparse_fwd(q, k, v, words, sm_scale, block_size)
     # Named for the remat policy, as ``_flash``'s are.
     o, lse = checkpoint_name(o, "sparse_o"), checkpoint_name(lse, "sparse_lse")
     return o, (q, k, v, words, o, lse)
@@ -920,8 +994,7 @@ def _sparse_flash_fwd(q, k, v, words, sm_scale, block_size):
 
 def _sparse_flash_bwd(sm_scale, block_size, res, do):
     q, k, v, words, o, lse = res
-    dq, dk, dv = _backward_call(
-        _bitmap_mask(q, k, block_size), q, k, v, o, lse, do, sm_scale, words)
+    dq, dk, dv = _sparse_bwd(q, k, v, words, o, lse, do, sm_scale, block_size)
     return dq, dk, dv, np.zeros(words.shape, jax.dtypes.float0)
 
 
@@ -990,6 +1063,15 @@ def _mask_of(q, k, v, causal, block_q, block_k, window):
     return _causal_mask(q, k, v, causal, block_q, block_k)
 
 
+def _tiling():
+    """What a causal or banded trace reads beside its arguments."""
+    return (_tile_class,)
+
+
+_BLOCK_STATICS = ("causal", "scale", "block_q", "block_k", "window")
+
+
+@kernel_entry(*_BLOCK_STATICS, reads=_tiling)
 def _block_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     """One block of attention on [bh, t, d] operands -> (o, lse [bh, tq])."""
     if _kernels_fit(q.shape[1], k.shape[1], q.shape[2], v.shape[2]):
@@ -1007,6 +1089,7 @@ def _block_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     return o, (m + jnp.log(l_safe))[..., 0]
 
 
+@kernel_entry(*_BLOCK_STATICS, reads=_tiling)
 def _block_bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                window=None):
     """(dq, dk, dv) of one block given the (o, lse) of the whole row, which

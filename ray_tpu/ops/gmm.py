@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import _interpret
+from .attention import _interpret, kernel_entry
 
 
 # What a call's blocks may take of VMEM, double buffers and the float32
@@ -164,6 +164,14 @@ def _gmm_grid(m, k, n, block_m, block_n, transpose_rhs=False, bounded=False):
     )
 
 
+# The three calls below stand behind ``kernel_entry``: a model's expert layers
+# are one shape, and a layer calls the grouped matmul three times forward and
+# six backward. A trace follows the budget its blocks are cut to.
+def _budget():
+    return (_BLOCK_BUDGET,)
+
+
+@kernel_entry("block_m", "transpose_rhs", reads=_budget)
 def _gmm_pallas(lhs, rhs, tile_group, block_m, transpose_rhs=False,
                 tiles_used=None):
     """out[tile t] = lhs[tile t] @ rhs[tile_group[t]]; with
@@ -286,6 +294,7 @@ def _tgmm_grid(m, k, n, block_m, block_k, block_n, bounded=False):
     )
 
 
+@kernel_entry("num_groups", "block_m", reads=_budget)
 def _tgmm_pallas(lhs, dout, tile_group, num_groups, block_m, tiles_used=None):
     """drhs[e] = sum over m-tiles t with tile_group[t]==e of
     lhs[t]^T @ dout[t], over the first ``tiles_used`` tiles where that is
@@ -364,6 +373,7 @@ def _unwritten_kernel(after_ref, out_ref):
     pass
 
 
+@kernel_entry("shape", "dtype")
 def unwritten(shape, dtype, after):
     """An uninitialised buffer that exists once ``after`` does, for a loop
     that fills the part of a bounded layout that holds rows (a bounded

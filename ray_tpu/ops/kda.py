@@ -915,6 +915,16 @@ def _params():
     )
 
 
+# Every scan kernel's call below stands behind ``kernel_entry``: a model's
+# layers of one mixer are one shape, and each calls its forward kernel in the
+# forward pass and in the replay and its backward kernel, whose body
+# differentiates a chunk where it stands, once. A delta-rule trace follows
+# how its heads pair and how the backward kernel takes the inverse.
+def _pairing():
+    return (_PAIR, _block_diagonal)
+
+
+@_attention.kernel_entry("heads", "norm", "states", reads=_pairing)
 def _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm, states):
     batch, t, _ = q.shape
     dk, dv, n = q.shape[2] // heads, v.shape[2] // heads, t // CHUNK
@@ -938,6 +948,7 @@ def _forward_pallas(q, k, v, g, beta, gate, weight, heads, norm, states):
     )(m, g, q, k, v, beta, gate, weight)
 
 
+@_attention.kernel_entry("heads", "norm", reads=_pairing)
 def _backward_pallas(q, k, v, g, beta, gate, weight, states, inverses, do,
                      heads, norm):
     batch, t, _ = q.shape
@@ -1304,6 +1315,7 @@ def _gdn_specs(heads, dk, dv, chunk_of, tokens_first=False):
     }
 
 
+@_attention.kernel_entry("norm", "states", reads=_pairing)
 def _gdn_forward_pallas(qk, v, g, beta, gate, weight, norm, states):
     batch, _, heads, t, dk = qk.shape
     dv, n = weight.shape[1], t // CHUNK
@@ -1325,6 +1337,7 @@ def _gdn_forward_pallas(qk, v, g, beta, gate, weight, norm, states):
     )(g, qk, v, beta, gate, weight)
 
 
+@_attention.kernel_entry("norm", reads=_pairing)
 def _gdn_backward_pallas(qk, v, g, beta, gate, weight, states, inverses, do,
                          norm):
     batch, _, heads, t, dk = qk.shape
@@ -1552,6 +1565,7 @@ def _lightning_specs(dk, dv, chunk_of):
     }
 
 
+@_attention.kernel_entry("norm", "states")
 def _lightning_forward_pallas(q, k, v, gate, weight, slopes, norm, states):
     batch, heads, t, dk = q.shape
     dv, n = v.shape[3], t // LIGHTNING_CHUNK
@@ -1571,6 +1585,7 @@ def _lightning_forward_pallas(q, k, v, gate, weight, slopes, norm, states):
     )(slopes, q, k, v, gate, weight)
 
 
+@_attention.kernel_entry("norm")
 def _lightning_backward_pallas(q, k, v, gate, weight, slopes, states, do, norm):
     batch, heads, t, dk = q.shape
     dv, n = v.shape[3], t // LIGHTNING_CHUNK
@@ -1904,6 +1919,7 @@ def _ssd_specs(groups, p, n_state, chunk_of):
     }
 
 
+@_attention.kernel_entry("states")
 def _ssd_forward_pallas(u, dt, a, D, Bm, Cm, states):
     batch, t, _ = u.shape
     groups, p = dt.shape[1], D.shape[1] * D.shape[2] // _SSD_GROUP
@@ -1924,6 +1940,7 @@ def _ssd_forward_pallas(u, dt, a, D, Bm, Cm, states):
     )(u, dt, a, D, Bm, Cm)
 
 
+@_attention.kernel_entry()
 def _ssd_backward_pallas(u, dt, a, D, Bm, Cm, states, dy):
     batch, t, _ = u.shape
     groups, p = dt.shape[1], D.shape[1] * D.shape[2] // _SSD_GROUP

@@ -19,8 +19,9 @@ too, and so does set-up: every trace, lowering, backend compile and
 persistent-cache read of the process as JAX reports it
 (``tracing.watch_compiles``, ``tracing.SETUP_SPANS``), one event each from
 a millisecond up and one ``ray_tpu.compile.short`` event at the record's end
-for the count and the sum of the shorter ones. The events ride the worker's
-``task_done`` flushes;
+for the count and the sum of the shorter ones, and one
+``ray_tpu.compile.entries`` there for the calls and the traces of each
+kernel's jitted entry. The events ride the worker's ``task_done`` flushes;
 ``RAY_TPU_events_enabled=0`` turns all of it off with the recorder.
 """
 from __future__ import annotations
@@ -79,6 +80,8 @@ class TrainSession:
         # stage -> [count, seconds] of the compile durations under
         # tracing.COMPILE_FLOOR_S, which leave no event of their own.
         self._short_compiles: Dict[str, list] = {}
+        # The kernel entries' calls and traces before this session's own.
+        self._entries_before = tracing.entry_counts()
         self._closed = threading.Event()
         gc.callbacks.append(self._on_gc)
         # Never imported for the record's sake: ray_tpu.train loads jax today
@@ -164,12 +167,17 @@ class TrainSession:
         self._closed.set()
         gc.callbacks.remove(self._on_gc)
         tracing.record_spans_into(None)
-        if self._short_compiles:
-            m = time.monotonic()
-            self._recorder.record_at(
-                time.time(), m, _events.TRAIN, str(threading.get_ident()),
-                tracing.COMPILE_SHORT, {"m_start": m, **self._short_compiles},
-            )
+        tallies = (
+            (tracing.COMPILE_SHORT, self._short_compiles),
+            (tracing.COMPILE_ENTRIES, tracing.entry_counts(self._entries_before)),
+        )
+        for name, tally in tallies:
+            if tally:
+                m = time.monotonic()
+                self._recorder.record_at(
+                    time.time(), m, _events.TRAIN, str(threading.get_ident()),
+                    name, {"m_start": m, **tally},
+                )
 
     def finish(self, error: Optional[BaseException] = None):
         self.error = error

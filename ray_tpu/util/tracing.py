@@ -37,7 +37,11 @@ lowering, backend compile and persistent-cache read of the process
 (``jax.monitoring``), as events of the same layout in the same category
 while a session is held, so that set-up is on the record the turns are on.
 Their attrs are a dict, ``{"m_start": ..., "fun_name": ...}``: it is built
-when something compiles, never in a turn of the loop.
+when something compiles, never in a turn of the loop. Beside them
+``count_entry`` tallies what JAX does not report: how often each kernel's
+inlined jitted entry (``ops/attention.py`` ``kernel_entry``) was called and
+how often a call traced its body; ``entry_counts`` reads the tally, and a
+session says its share as its record ends (``COMPILE_ENTRIES``).
 """
 from __future__ import annotations
 
@@ -70,10 +74,11 @@ COMPILE_CACHE_READ = "ray_tpu.compile.cache_read"  # inside backend, on a hit: t
 COMPILE_CACHE_HIT = "ray_tpu.compile.cache_hit"  # inside backend, of no length: the persistent cache held the executable
 COMPILE_CACHE_MISS = "ray_tpu.compile.cache_miss"  # inside backend, of no length: it did not, and the compiled one was written there
 COMPILE_SHORT = "ray_tpu.compile.short"  # of no length, once, as the session's record ends: {stage: [count, seconds]} of the durations under COMPILE_FLOOR_S, which leave no event of their own
+COMPILE_ENTRIES = "ray_tpu.compile.entries"  # of no length, once, as the session's record ends: {entry: [calls, traces]} of the kernels' inlined jitted entries (ops/attention.py kernel_entry) that the session called: a trace is the entry's body run in Python, a call that made none took the jaxpr the entry held
 SHARD_PARAMS = "ray_tpu.parallel.shard_params"  # parallel/mesh.py shard_params: the host placing the leaves (device_put returns before a copy ends)
 SETUP_SPANS = (COMPILE_TRACE, COMPILE_LOWER, COMPILE_BACKEND,
                COMPILE_CACHE_READ, COMPILE_CACHE_HIT, COMPILE_CACHE_MISS,
-               COMPILE_SHORT, SHARD_PARAMS)
+               COMPILE_SHORT, COMPILE_ENTRIES, SHARD_PARAMS)
 
 # In-graph scopes. Forward and backward are already told apart by JAX's
 # ``jvp(`` / ``transpose(`` and a remat replay by ``rematted_computation``.
@@ -234,6 +239,30 @@ def _record_compile(name, duration, fun_name=None):
     recorder.record_at(
         time.time(), m, _events.TRAIN, str(threading.get_ident()), name, attrs
     )
+
+
+# entry -> [calls, traces] of the process, session or none: a list update a
+# call and one a trace, while something is traced only (a compiled step calls
+# no entry).
+_entries = {}
+
+
+def count_entry(name: str, traced: bool) -> None:
+    """``ops/attention.py`` ``kernel_entry``: an entry was called (``traced``
+    False), or its body ran in Python (True), which is a trace."""
+    _entries.setdefault(name, [0, 0])[traced] += 1
+
+
+def entry_counts(since=None) -> dict:
+    """{entry: [calls, traces]} of the process so far, or of what came after
+    an earlier reading ``since``, the entries that were called alone."""
+    since = since or {}
+    counts = {}
+    for name, (calls, traces) in list(_entries.items()):
+        before = since.get(name, (0, 0))
+        if calls > before[0]:
+            counts[name] = [calls - before[0], traces - before[1]]
+    return counts
 
 
 def _on_duration(event, duration, fun_name=None, **_):

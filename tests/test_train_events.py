@@ -305,7 +305,8 @@ def use_cache(directory):
 def compiling_loop(config):
     """Jits one function at one shape before set-up's report and at another
     in the turn after it. The sleeps run while it is traced, so its trace
-    spans are well over the floor; ``inner`` is a jit called from a jit."""
+    spans are well over the floor; ``inner`` is a jit called from a jit and
+    ``scaled`` a kernel's kind of entry, inlined as ``outer`` is traced."""
     at_start = {"jax_loaded": "jax" in sys.modules, "watching": tracing._watching}
     import jax
     import jax.numpy as jnp
@@ -313,6 +314,7 @@ def compiling_loop(config):
     if config["root"] not in sys.path:
         sys.path.insert(0, config["root"])
     from benchmarks.lib.checks import CompileCounter
+    from ray_tpu.ops.attention import kernel_entry
 
     use_cache(config["cache"])
     own, theirs = {}, CompileCounter().install()
@@ -323,11 +325,15 @@ def compiling_loop(config):
         time.sleep(0.005)
         return jnp.sin(x)
 
+    @kernel_entry("by")
+    def scaled(x, by):
+        return x * by
+
     @jax.jit
     def outer(x):
         time.sleep(0.01)
         for _ in range(64):
-            x = inner(x) * 1.0001
+            x = scaled(inner(x), 1.0001)
         return x.sum()
 
     outer(jnp.ones((4,)))
@@ -446,6 +452,18 @@ def test_the_spans_counts_agree_with_a_listener_of_the_tests_own(compiled):
         assert theirs["requests"] == own["backend"]
 
 
+def test_the_record_ends_with_the_entries_calls_and_traces(compiled):
+    for run in (compiled["cold"], compiled["warm"]):
+        said = named(run, tracing.COMPILE_ENTRIES)
+        assert len(said) == 1  # once, as the record ends
+        attrs = dict(said[0]["attrs"])
+        assert attrs.pop("m_start") == said[0]["monotonic"] \
+            >= named(run, "REPORT")[-1]["monotonic"]
+        # 64 calls a shape of ``outer``, one trace a shape, cache or none.
+        (entry, counts), = attrs.items()
+        assert entry.endswith(".scaled") and counts == [128, 2]
+
+
 def test_an_inner_jits_interval_lies_inside_its_callers(compiled):
     for run in (compiled["cold"], compiled["warm"]):
         outers = stage_spans(run, "trace", "outer")
@@ -552,7 +570,7 @@ def test_every_new_name_is_in_the_registry():
     # set-up's spans.
     assert registered - set(events.TRAIN_FIELDS) \
         == (set(tracing.HOST_SPANS) - {tracing.WORKER_EXEC}) | set(tracing.SETUP_SPANS)
-    assert len(tracing.SETUP_SPANS) == 8
+    assert len(tracing.SETUP_SPANS) == 9
     assert all(n.startswith(("ray_tpu.compile.", "ray_tpu.parallel."))
                for n in tracing.SETUP_SPANS)
     assert all(event_names.is_registered(n) for n in registered)
@@ -779,6 +797,34 @@ def test_two_sessions_in_one_process_register_one_listener(ring):
     # Each session's tally of short ones is its own, said as it ends.
     assert [e["event"] for e in said].count(tracing.COMPILE_SHORT) == 2
     assert first._short_compiles is not second._short_compiles
+
+
+def test_a_session_says_its_kernel_entries_calls_and_traces_as_its_record_ends(ring):
+    """``ops/attention.py`` ``kernel_entry`` counts for the whole process; a
+    session's event holds what was called while it was held."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.attention import kernel_entry
+
+    @kernel_entry("scale")
+    def scaled(x, scale):
+        return x * scale
+
+    x = jnp.ones((3,))
+    jax.make_jaxpr(lambda x: scaled(x, 2.0))(x)  # before the session: not its own
+    session = TrainSession(context())
+    try:
+        jax.make_jaxpr(lambda x: scaled(scaled(scaled(x, 2.0), 2.0), 3.0))(x)
+    finally:
+        session.close_record()
+        session.close_record()  # said once
+    said = [e for e in compile_events(ring) if e["event"] == tracing.COMPILE_ENTRIES]
+    assert len(said) == 1 and said[0]["entity"] == str(threading.get_ident())
+    attrs = dict(said[0]["attrs"])
+    assert attrs.pop("m_start") == said[0]["monotonic"]
+    # Three calls, one trace: 2.0 was traced before, 3.0 is a static of its own.
+    assert attrs == {"test_train_events.scaled": [3, 1]}
+    assert tracing.entry_counts()["test_train_events.scaled"] == [4, 2]
 
 
 def test_no_session_or_a_disabled_recorder_records_nothing_that_compiles(ring):
