@@ -215,7 +215,8 @@ REPLAY_KEEPS = ("flash_o", "flash_lse", "kda_o", "kda_states", "kda_t",
                 "hc_read", "hc_maps", "hc_write", "gdn_o", "gdn_states",
                 "gdn_t", "mlp_gate", "mlp_up", "mixer_out", "ffn_out",
                 "lightning_o", "lightning_states", "sparse_o", "sparse_lse",
-                "sparse_blocks", "ssd_y", "ssd_states")
+                "sparse_blocks", "ssd_y", "ssd_states", "select_o",
+                "select_lse", "select_words")
 # One object for every caller: JAX caches a jitted function's partial
 # evaluation by the policy's identity, and a second ``_through`` (xing4.py's
 # module) with a policy of its own would lower every jitted kernel entry's

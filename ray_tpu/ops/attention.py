@@ -10,10 +10,10 @@ score matrix never materializes in HBM.
 Forward and backward are pallas kernels on a TPU backend (MXU matmuls
 in f32 accumulation; the backward recomputes probabilities from the saved
 log-sum-exp). Which keys a row sees is a mask (``_Mask``: causal, a band, a
-bitmap of chosen blocks), and a mask is data: each pass's tile update is
-written once, a grid step runs it as its tile's position under the mask
-asks (``_by_position``), and one forward and one backward call take any
-mask. On any other backend
+bitmap of chosen blocks, a bit a chosen key), and a mask is data: each
+pass's tile update is written once, a grid step runs it as its tile's
+position under the mask asks (``_by_position``), and one forward and one
+backward call take any mask. On any other backend
 `flash_attention` is `attention_reference`; which one ran is visible in the
 lowered program (`tpu_custom_call`), and chip_smoke.py asserts it. Where the
 ambient mesh splits the sequence it is the ring of `ring_attention.py` over
@@ -221,6 +221,12 @@ class _Mask(NamedTuple):
     flops: Optional[tuple] = None  # of the three calls; None: no cost is stated
     bodies: tuple = (False, True)  # some tile of the grid is (interior, edge)
     vmem_limit_bytes: Optional[int] = None
+    # A mask whose words are not one lane a key tile (``_select_mask``): the
+    # lanes a step's block of them has, (walked) -> its index map, and
+    # (the block [block_q, lanes], ik) -> the tile's words. None: the bitmap's.
+    words_lanes: Optional[int] = None
+    words_index: Optional[Callable] = None
+    tile_words: Optional[Callable] = None
 
 
 def _blocks(q, k, block_q: int, block_k: int):
@@ -561,6 +567,99 @@ def _bitmap_mask(q, k, block_size: int) -> _Mask:
     )
 
 
+# A bit a (row, key) (``_select_mask``), token-level top-k attention
+# (DeepSeek-V3.2's sparse attention): row i sees the keys ``index_keys`` chose
+# for it, one set for all heads, which differs from row to row and is made
+# from the rows' own scores. The words [B, rows, lanes] int32 hold key s of a
+# row at lane group s // 4096, lane s % 128, bit (s % 4096) // 128 (8 MiB at
+# 8k tokens): a key tile of ``block_k`` keys is ``block_k // 128`` adjacent
+# bits of one group's 128 lanes, which a step's block of words brings (the
+# group is the index map's, so a tile's words arrive as its K and V do), and
+# column c of the tile reads bit c // 128 of lane c % 128: shifts and no
+# movement across lanes. The causal cut is in the bits (a row chooses among
+# the keys up to its own). What a tile costs is a dense tile's matmuls, as
+# under the bitmap: a tile's rows choose differently and together nearly
+# every key below them. K and V come at q's heads.
+SELECT_BLOCK = 1024
+_GROUP_KEYS = 32 * 128  # the keys a group of 128 lanes holds a row
+
+
+def _select_tiles(t: int):
+    """(the tile's side, the padded length): tiles are square, a power of two
+    of 128 keys so that whole tiles fill a lane group."""
+    block = max(b for b in (128, 256, 512, SELECT_BLOCK) if b <= max(t, 128))
+    return block, -(-t // block) * block
+
+
+def _pack_keys(seen, t_p: int):
+    """[B, T, T] bool -> the words [B, t_p, lanes] int32."""
+    b, t, _ = seen.shape
+    groups = -(-t_p // _GROUP_KEYS)
+    x = jnp.pad(seen, ((0, 0), (0, t_p - t), (0, groups * _GROUP_KEYS - t)))
+    x = x.reshape(b, t_p, groups, 32, 128).astype(jnp.uint32)
+    words = (x << np.arange(32, dtype=np.uint32)[:, None]).sum(3, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32).reshape(b, t_p, -1)
+
+
+def _unpack_keys(words, t: int):
+    """The words -> [B, T, T] bool."""
+    b, t_p, lanes = words.shape
+    x = jax.lax.bitcast_convert_type(words, jnp.uint32).reshape(b, t_p, -1, 1, 128)
+    bits = (x >> np.arange(32, dtype=np.uint32)[:, None]) & 1
+    return bits.reshape(b, t_p, -1)[:, :t, :t] == 1
+
+
+def _select_mask(q, heads: int) -> _Mask:
+    """q [b * heads, t, d], t padded to the tiles (``_select_tiles``); the
+    words [b, t, lanes] are the calls' operand."""
+    t_p = q.shape[1]
+    block = _select_tiles(t_p)[0]
+    n = t_p // block
+    bits, tiles = block // 128, _GROUP_KEYS // block  # a tile's, a group's
+    field = -1 if bits == 32 else (1 << bits) - 1
+
+    def live(iq, ik):  # the tile holds a key at or before one of its rows
+        return ik <= iq
+
+    def walk_rows(ik, j):
+        return j, live(j, ik), None
+
+    def words_index(walked: bool):
+        def index(b, i, j):
+            batch = jax.lax.div(b, jnp.int32(heads))
+            if walked:  # key tile i's rows
+                return batch, jax.lax.max(j, i), jax.lax.div(i, jnp.int32(tiles))
+            return batch, i, jax.lax.div(jax.lax.min(j, i), jnp.int32(tiles))
+
+        return index
+
+    def tile_words(words, ik):
+        """[block, 128]: the tile's bits of each row's lanes, from bit 0."""
+        shift = jax.lax.rem(ik, jnp.int32(tiles)) * bits
+        return jax.lax.bitwise_and(
+            jax.lax.shift_right_logical(words, jnp.broadcast_to(shift, words.shape)),
+            jnp.int32(field))
+
+    def sees(iq, ik, mine):
+        shape = (block, block)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        bit = jax.lax.shift_right_logical(
+            jnp.concatenate([mine] * bits, axis=1) if bits > 1 else mine,
+            jax.lax.div(col, jnp.int32(128)))
+        return jax.lax.bitwise_and(bit, jnp.int32(1)) == 1
+
+    return _Mask(
+        block, block, t_p, t_p, group=1, key_steps=n, row_steps=n,
+        keys=lambda iq, j: (j, live(iq, j), None), rows=walk_rows,
+        key_index=lambda i, j: jax.lax.min(j, i),
+        row_index=lambda i, j: jax.lax.max(j, i), sees=sees,
+        kernels=(_fwd_select_kernel, _bwd_dkv_select_kernel,
+                 _bwd_dq_select_kernel),
+        vmem_limit_bytes=64 * 2**20,
+        words_lanes=128, words_index=words_index, tile_words=tile_words,
+    )
+
+
 # ------------------------------------------------------- one tile update a pass
 # Each pass (the forward, dK/dV, dQ) writes its tile update, its start and
 # its finish once; a kernel is a walk that runs them as the mask says.
@@ -587,7 +686,7 @@ def _by_position(mask: _Mask, body, iq, ik, live, interior, words_ref) -> None:
             return body(lambda: mask.sees(iq, ik))
         # A mask with words of its own: a tile in which no row chose a block
         # runs nothing either.
-        mine = _tile_words(words_ref[0], ik)
+        mine = (mask.tile_words or _tile_words)(words_ref[0], ik)
         pl.when(jnp.max(mine) != 0)(
             lambda: body(lambda: mask.sees(iq, ik, mine)))
 
@@ -760,11 +859,12 @@ def _dq(mask: _Mask, sm_scale, q_ref, k_ref, v_ref, words_ref, do_ref, lse_ref,
     _write(j == pl.num_programs(2) - 1, (dq_ref, dq_scr))
 
 
-# The nine functions handed to ``pallas_call``, a name a mask and pass: a
+# The twelve functions handed to ``pallas_call``, a name a mask and pass: a
 # trace names a call by the first ``*_kernel`` identifier in its Mosaic module
 # (benchmarks/lib/trace.py) and the benchmark's FLOP tables price it by that
 # name (benchmarks/lib/flops*.py), so no other function here ends in
-# ``_kernel``. Only the bitmap's take the rows' words, after v.
+# ``_kernel`` (but ``_index_kernel``, which makes the fourth mask's words).
+# Only the bitmap's and the selection's take the rows' words, after v.
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, mask, sm_scale):
@@ -800,6 +900,18 @@ def _bwd_dkv_sparse_kernel(*refs, mask, sm_scale):
 
 
 def _bwd_dq_sparse_kernel(*refs, mask, sm_scale):
+    _dq(mask, sm_scale, *refs)
+
+
+def _fwd_select_kernel(*refs, mask, sm_scale):
+    _forward(mask, sm_scale, *refs)
+
+
+def _bwd_dkv_select_kernel(*refs, mask, sm_scale):
+    _dkv(mask, sm_scale, *refs)
+
+
+def _bwd_dq_select_kernel(*refs, mask, sm_scale):
     _dq(mask, sm_scale, *refs)
 
 
@@ -854,6 +966,9 @@ def _words_spec(mask: _Mask, words, walked: bool) -> list:
     rows are ``walked``, the walk's."""
     if words is None:
         return []
+    if mask.words_index is not None:
+        return [pl.BlockSpec((1, mask.block_q, mask.words_lanes),
+                             mask.words_index(walked))]
 
     def index(b, i, j):
         if walked:
@@ -1029,6 +1144,215 @@ def _sparse_attention(q, k, v, blocks, block_size: int, scale: float):
     return o[:, :t].reshape(b, h, t, d_v)
 
 
+# ------------------------------------------- the chosen keys, and attention under them
+
+
+@kernel_entry("sm_scale", "heads")
+def _select_fwd(q, k, v, words, sm_scale, heads):
+    return _forward_call(_select_mask(q, heads), q, k, v, sm_scale, words)
+
+
+@kernel_entry("sm_scale", "heads")
+def _select_bwd(q, k, v, words, o, lse, do, sm_scale, heads):
+    return _backward_call(
+        _select_mask(q, heads), q, k, v, o, lse, do, sm_scale, words)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _select_flash(q, k, v, words, sm_scale, heads):
+    """q, k [b * heads, t, d]; v [b * heads, t, d_v]; words [b, t, lanes]; t
+    padded to the tiles."""
+    return _select_fwd(q, k, v, words, sm_scale, heads)[0]
+
+
+def _select_flash_fwd(q, k, v, words, sm_scale, heads):
+    o, lse = _select_fwd(q, k, v, words, sm_scale, heads)
+    # Named for the remat policy, as ``_flash``'s are.
+    o, lse = checkpoint_name(o, "select_o"), checkpoint_name(lse, "select_lse")
+    return o, (q, k, v, words, o, lse)
+
+
+def _select_flash_bwd(sm_scale, heads, res, do):
+    q, k, v, words, o, lse = res
+    dq, dk, dv = _select_bwd(q, k, v, words, o, lse, do, sm_scale, heads)
+    return dq, dk, dv, np.zeros(words.shape, jax.dtypes.float0)
+
+
+_select_flash.defvjp(_select_flash_fwd, _select_flash_bwd)
+
+
+def _select_attention(q, k, v, words, scale: float):
+    """``flash_attention``'s road under ``keys``: the kernels where they fit,
+    else a masked soft-max from the unpacked words (the CPU's road, at test
+    sizes)."""
+    b, h, t, d = q.shape
+    d_v = v.shape[-1]
+    if not _kernels_fit(t, t, d, d_v):
+        s = jnp.einsum("bhtd,bhsd->bhts", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        seen = _unpack_keys(words, t)[:, None]
+        p = jax.nn.softmax(jnp.where(seen, s, NEG_INF), axis=-1)
+        return jnp.einsum("bhts,bhsd->bhtd", p.astype(v.dtype), v)
+    t_p = _select_tiles(t)[1]
+    o = _select_flash(
+        _pad_rows(q.reshape(b * h, t, d), t_p),
+        _pad_rows(k.reshape(b * h, t, d), t_p),
+        _pad_rows(v.reshape(b * h, t, d_v), t_p), words, scale, h,
+    )
+    return o[:, :t].reshape(b, h, t, d_v)
+
+
+_INT_MIN = -(2**31)
+# The indexer kernel's blocks: rows a grid step, keys a trip of its loop.
+INDEX_BLOCK_Q = 256
+INDEX_BLOCK_K = 512
+
+
+def _ordered(x):
+    """float32 -> int32 whose signed order is the floats' (-0.0 as 0.0)."""
+    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.int32)
+    return jnp.where(bits < 0, jax.lax.bitwise_xor(bits, jnp.int32(2**31 - 1)), bits)
+
+
+def _index_kernel(q_ref, k_ref, w_ref, o_ref, keys_scr, *, topk, block_k):
+    """One block of rows against every key up to their own: the scores, each
+    row's ``topk``-th largest, the words. q_ref [1, heads, rows, d]; k_ref
+    [1, t, d]; w_ref [1, rows, heads] float32; o_ref [1, rows, lanes];
+    keys_scr [rows, t] int32, the scores in ``_ordered``'s form, _INT_MIN
+    where the row does not see the key."""
+    iq = pl.program_id(1)
+    heads, rows = q_ref.shape[1], q_ref.shape[2]
+    t_p = k_ref.shape[1]
+    live = jax.lax.div((iq + 1) * rows + (block_k - 1), jnp.int32(block_k))
+    keys_scr[...] = jnp.full(keys_scr.shape, _INT_MIN, jnp.int32)
+    w = w_ref[0]
+
+    def score_tile(j, carry):
+        at = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, pl.ds(at, block_k), :]
+        acc = jnp.zeros((rows, block_k), jnp.float32)
+        for h in range(heads):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc = acc + w[:, h:h + 1] * jnp.maximum(s, 0.0)
+        shape = (rows, block_k)
+        row = iq * rows + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        col = at + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        keys_scr[:, pl.ds(at, block_k)] = jnp.where(
+            col <= row, _ordered(acc), _INT_MIN)
+        return carry
+
+    jax.lax.fori_loop(0, live, score_tile, 0)
+
+    def count_at_least(trial):
+        """[rows, 1]: how many of a row's keys stand at or above ``trial``."""
+        def some(j, lanes):
+            at = pl.multiple_of(j * block_k, block_k)
+            hit = (keys_scr[:, pl.ds(at, block_k)] >= trial).astype(jnp.int32)
+            for c in range(block_k // 128):
+                lanes = lanes + hit[:, c * 128:(c + 1) * 128]
+            return lanes
+
+        lanes = jax.lax.fori_loop(
+            0, live, some, jnp.zeros((rows, 128), jnp.int32))
+        return jnp.sum(lanes, axis=1, keepdims=True)
+
+    def bisect(i, found):
+        # The threshold's bits from the top, in the order's unsigned form:
+        # the largest value that ``topk`` keys or more stand at or above.
+        trial = jax.lax.bitwise_or(
+            found, jax.lax.shift_left(jnp.int32(1), 31 - i))
+        enough = count_at_least(jax.lax.bitwise_xor(trial, jnp.int32(_INT_MIN))) >= topk
+        return jnp.where(enough, trial, found)
+
+    found = jax.lax.fori_loop(0, 32, bisect, jnp.zeros((rows, 1), jnp.int32))
+    # A row with fewer keys than ``topk`` finds 0, below every score: it keeps
+    # what it sees, which _INT_MIN is not.
+    least = jnp.maximum(
+        jax.lax.bitwise_xor(found, jnp.int32(_INT_MIN)), jnp.int32(_INT_MIN + 1))
+    for group in range(o_ref.shape[2] // 128):
+        word = jnp.zeros((rows, 128), jnp.int32)
+        for bit in range(32):
+            at = group * _GROUP_KEYS + bit * 128
+            if at >= t_p:
+                break
+            chosen = (keys_scr[:, at:at + 128] >= least).astype(jnp.int32)
+            word = jax.lax.bitwise_or(word, jax.lax.shift_left(chosen, jnp.int32(bit)))
+        o_ref[0, :, group * 128:(group + 1) * 128] = word
+
+
+def _index_blocks():
+    """What an indexer's trace reads beside its arguments."""
+    return (INDEX_BLOCK_Q, INDEX_BLOCK_K, SELECT_BLOCK)
+
+
+@kernel_entry("topk", reads=_index_blocks)
+def _index_call(q, k, w, topk):
+    """q [b, heads, t, d]; k [b, t, d]; w [b, t, heads] float32; t padded to
+    the selection's tiles -> the words [b, t, lanes]."""
+    b, heads, t_p, d = q.shape
+    rows, block_k = min(INDEX_BLOCK_Q, t_p), min(INDEX_BLOCK_K, t_p)
+    lanes = -(-t_p // _GROUP_KEYS) * 128
+    return pl.pallas_call(
+        functools.partial(_index_kernel, topk=topk, block_k=block_k),
+        grid=(b, t_p // rows),
+        in_specs=[
+            pl.BlockSpec((1, heads, rows, d), lambda n, i: (n, 0, i, 0)),
+            pl.BlockSpec((1, t_p, d), lambda n, i: (n, 0, 0)),
+            pl.BlockSpec((1, rows, heads), lambda n, i: (n, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, rows, lanes), lambda n, i: (n, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, t_p, lanes), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((rows, t_p), jnp.int32)],
+        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=96 * 2**20,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=b * heads * t_p * t_p * d, transcendentals=0,
+            bytes_accessed=(q.size + k.size) * 2 + w.size * 4 + b * t_p * lanes * 4,
+        ),
+    )(q, k, w)
+
+
+def index_scores(q, k, w):
+    """[B, T, T] float32: I[t, s] = sum_h w[t, h] ReLU(q[t, h] . k[s]), the
+    products in float32 from the operands as they come. The XLA road, whole:
+    for test sizes."""
+    s = jnp.einsum("bhtd,bsd->bhts", q, k, preferred_element_type=jnp.float32)
+    return jnp.einsum("bhts,bth->bts", jnp.maximum(s, 0.0), w.astype(jnp.float32))
+
+
+def index_keys(q, k, w, *, topk: int):
+    """The keys each row attends, one set for all heads, as the words
+    ``flash_attention(keys=)`` takes: row t keeps, of the keys s <= t, those
+    whose score I[t, s] (``index_scores``) is at or above the ``topk``-th
+    largest of them (every key while t < topk; ties kept, so that no order
+    among equals is asked). q [B, Hi, T, D] and k [B, T, D] the index
+    queries and the one index key a token, w [B, T, Hi] the heads' weights.
+    One Pallas kernel (``_index_kernel``) where the kernels fit, XLA's lines
+    elsewhere. An integer set: nothing here is differentiated."""
+    q, k, w = (jax.lax.stop_gradient(x) for x in (q, k, w))
+    b, heads, t, d = q.shape
+    t_p = _select_tiles(t)[1]
+    w = w.astype(jnp.float32)
+    if _kernels_fit(t, t, d, d):
+        words = _index_call(
+            jnp.pad(q, ((0, 0), (0, 0), (0, t_p - t), (0, 0))),
+            _pad_rows(k, t_p), _pad_rows(w, t_p), topk)
+    else:
+        seen = _visible(t, t, None)
+        scores = jnp.where(seen, index_scores(q, k, w), -jnp.inf)
+        # -inf while a row sees fewer than topk: it keeps what it sees
+        least = jax.lax.top_k(scores, min(topk, t))[0][..., -1:]
+        words = _pack_keys(seen & (scores >= least), t_p)
+    # Named for the remat policy (models/llama.py REPLAY_KEEPS): a layer's
+    # replay is handed the set and runs no indexer.
+    return checkpoint_name(words, "select_words")
+
+
 # ------------------------------------------------- one block, kernels or XLA
 
 
@@ -1159,6 +1483,7 @@ def flash_attention(
     block_k: int = 1024,
     blocks: Optional[jax.Array] = None,
     block_size: Optional[int] = None,
+    keys: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Blockwise (flash) attention, the one entry point of the mixers.
 
@@ -1168,13 +1493,18 @@ def flash_attention(
     it can observe: a ring over the ambient mesh (``jax.set_mesh``) where
     that splits the sequence, else the Pallas kernels where they fit
     (``_kernels_fit``), else the XLA reference. The kernels' road takes
-    one of three masks (``_Mask``). ``window=w`` (causal only) keeps keys
+    one of four masks (``_Mask``). ``window=w`` (causal only) keeps keys
     0 <= i - j < w of row i: a band, at blocks of its own; the reference
     masks it; the ring refuses it. ``blocks`` [B, Hkv, T, ceil(T /
     block_size)] bool (causal self-attention only; ``select_blocks`` makes
     it) keeps, of the keys row i of a K/V group sees, those in the blocks of
     ``block_size`` keys it marks: a bitmap where the kernels fit, a masked
-    soft-max elsewhere; the ring refuses it. Else the causal mask.
+    soft-max elsewhere; the ring refuses it. ``keys`` (causal
+    self-attention at K and V of q's heads only; ``index_keys`` makes it), the
+    words [B, T padded, lanes] int32 of a bit a (row, key), keeps of the keys
+    row i sees those it marks, one set for all heads: the selection's kernels
+    where they fit, a masked soft-max elsewhere; the ring refuses it. Else
+    the causal mask.
     """
     b, h, tq, d = q.shape
     hkv, tk, d_v = k.shape[1], k.shape[2], v.shape[-1]
@@ -1204,6 +1534,19 @@ def flash_attention(
                 "the sequence: the ring has no selection"
             )
         return _sparse_attention(q, k, v, blocks, block_size, scale)
+    if keys is not None:
+        if not causal or window is not None or tq != tk or h != hkv:
+            raise ValueError(
+                "keys= is causal self-attention's, with no window and K and V "
+                f"at q's heads (causal={causal}, window={window}, Tq={tq}, "
+                f"Tk={tk}, heads={h}, K/V heads={hkv})"
+            )
+        if logical_axis_shards("seq") > 1:
+            raise ValueError(
+                f"keys= under mesh axis {ambient_axes('seq')}, which splits "
+                "the sequence: the ring has no selection"
+            )
+        return _select_attention(q, k, v, keys, scale)
     if logical_axis_shards("seq") > 1:
         from .ring_attention import ring_attention  # it imports this module
 

@@ -92,11 +92,13 @@ QK_NORM = "qk_norm"  # RMSNorm of q and k: the whole projections in attn (cfg.qk
 MOE_SHARED = "shared"  # inside moe: the shared expert every token passes
 # The mixers' flax names, which reach op_name as the attention's "attn" does.
 KDA = "kda"  # the gated delta-rule mixer (models/kimi_linear.py KDAMixer; Solar-Open2's KDA layers too)
-MLA = "mla"  # the latent-attention mixer (models/mla.py MLAMixer)
+MLA = "mla"  # the latent-attention mixer (models/mla.py MLAMixer); in a model whose layers differ, the full layers' (models/dots3.py's beside swa_mla): there also indexer, select and out_gate
+SWA_MLA = "swa_mla"  # the same module as a sliding layer's mixer (models/dots3.py): latents and heads of the sliding kind's own widths, its own theta, flash_attention under a window, out_gate
 GDN = "gdn"  # the scalar-decay gated delta-rule mixer (models/olmo_hybrid.py GDNMixer); conv, gate and scan inside it as inside kda, scan holding ops/kda.py chunk_gdn and the decay's and beta's layout for its kernels (q and k come heads first from conv; v, the gate and o go through as they lie)
 LIGHTNING = "lightning"  # the decay-only linear-attention mixer (models/minicpm_sala.py LightningMixer): projections, qk_norm and rotary scopes, ops/kda.py chunk_lightning (its kernels hold o's norm and the output gate) and the transpositions around it
 SPARSE = "sparse"  # the block-sparse top-k softmax mixer (models/minicpm_sala.py SparseAttention): projections, qk_norm, select, ops/attention.py sparse_attention's kernels, out_gate
-SPARSE_SELECT = "select"  # inside sparse, where T > dense_len: ops/attention.py select_blocks (compressed keys, the scores of every head against them, their soft-max, the sum over a group's heads, the max-pool to blocks, the forced blocks, top-k, the packed bitmap); nothing of it is differentiated
+SPARSE_SELECT = "select"  # inside sparse, where T > dense_len: ops/attention.py select_blocks (compressed keys, the scores of every head against them, their soft-max, the sum over a group's heads, the max-pool to blocks, the forced blocks, top-k, the packed bitmap); inside mla (a kind with an indexer): ops/attention.py index_keys (_index_kernel: every index head's scores of a row block against the keys up to it, their weighted sum, each row's topk-th largest by bisection, the packed words; the pads around it); a reader tells the two apart by the mixer above. Nothing of either is differentiated
+INDEXER = "indexer"  # inside mla (a kind with an indexer): the index queries' projection from the q latent, the index key's projection and LayerNorm, the heads' weights, the rotation of both's leading channels; forward alone
 MAMBA = "mamba"  # the Mamba-2 state-space mixer (models/granite_hybrid.py Mamba2Mixer): the three input projections, conv, step, ops/kda.py chunk_ssd's kernels (they hold the step's product with u and the skip) and the slices around them, norm, the output projection
 SHORTCONV = "shortconv"  # the gated short-convolution mixer (models/lfm2.py ShortConvMixer): the whole mixer of an LFM2 conv layer, its three scopes below and nothing else
 SHORTCONV_IN = "conv_in"  # inside shortconv: the one input projection to the gates B and C and the convolved x~, [hidden, 3 hidden]
@@ -115,7 +117,7 @@ MLA_Q_LATENT = "q_latent"  # inside mla (cfg.q_lora_rank): q's down-projection, 
 ATTN = "attn"  # the softmax-attention mixer (models/llama.py Attention); in a model whose layers differ, the full-attention layers' (Laguna's beside swa, Solar-Open2's beside kda)
 SWA = "swa"  # the same module as a sliding-window layer's mixer (models/laguna.py): its own head count and rotation, flash_attention under a window
 ATTN_ROPE = "rotary"  # inside attn, swa and lightning: the angles, cos and sin, the rotation of q and of k (a part of each head where the layer's kind says so) by ops/rotary.py rotate: its tables and _rotary_kernel where a head is whole vregs of lanes, _rope elsewhere; not opened by a kind that turns nothing
-ATTN_GATE = "out_gate"  # inside attn and swa (a kind with a gate) and inside sparse: the gate's projection (one value a head, or of q's width), its sigmoid, the product with each head's output
+ATTN_GATE = "out_gate"  # inside attn and swa (a kind with a gate), inside sparse, and inside mla and swa_mla (a kind with a gate): the gate's projection (one value a head, or of q's width), its sigmoid, the product with each head's output
 HC = "hc"  # a hyper-connection (models/hyper_connections.py), twice a layer: the three maps of the streams, the read before the sublayer, the write after it
 HC_PRE = "pre"  # inside hc: the streams' rms, x~ Phi, the three logits, H_pre and H_post, the read u = sum H_pre[i] X[i]
 HC_SINKHORN = "sinkhorn"  # inside hc: exp, the iterations of rows and columns, and their backward
@@ -128,9 +130,11 @@ SCOPES = (OPTIMIZER, MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
           MOE_LAYOUT, QK_NORM, MOE_SHARED, KDA_CONV, KDA_GATE, KDA_SCAN,
           MLA_LATENT, MLA_ROPE, MLA_Q_LATENT, ATTN_ROPE, ATTN_GATE, HC, HC_PRE,
           HC_SINKHORN, HC_POST, HC_STREAMS, MTP, LOSS, LOSS_HEAD, SPARSE_SELECT,
-          MAMBA_STEP, MAMBA_NORM, SHORTCONV_IN, SHORTCONV_GATED, SHORTCONV_OUT)
+          MAMBA_STEP, MAMBA_NORM, SHORTCONV_IN, SHORTCONV_GATED, SHORTCONV_OUT,
+          INDEXER)
 # Flax module names, bound in the model classes' ``blocks``.
-MIXERS = (KDA, MLA, ATTN, SWA, GDN, LIGHTNING, SPARSE, MAMBA, SHORTCONV)
+MIXERS = (KDA, MLA, ATTN, SWA, GDN, LIGHTNING, SPARSE, MAMBA, SHORTCONV,
+          SWA_MLA)
 # The decoder body's flax names (models/llama.py, xing4.py), a layer's and
 # above: parameter trees and checkpoints hold them, so none is ever renamed.
 EMBED = "embed_tokens"  # the embedding table's flax name; models/llama.py _lookup opens it as a scope around what it does outside the module (the one-hot product where a mesh splits the table, the constraint on the result)

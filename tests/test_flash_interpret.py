@@ -261,7 +261,7 @@ def test_float32_gradients_are_bit_for_bit_the_masked_everywhere_result(
 # prints a ``pallas_call`` whole (kernel jaxpr, grid, block shapes, cost
 # estimate, compiler parameters) and no source location, and held to digests.
 
-# Every distinct attention call of the benchmark's thirteen cells, bfloat16:
+# Every distinct attention call of the benchmark's fifteen cells, bfloat16:
 # (batch, heads, K/V heads, tokens, q/k head dim, v head dim, the mask's
 # keywords), from benchmarks/configs/*.json and benchmarks/traffic/*.json.
 # The Mixtral cell's calls are the ring's blocks (seq=2: 2,048 rows a device
@@ -280,6 +280,8 @@ ATTENTION_CALLS = {
     "olmo-hybrid-8k": (1, 30, 30, 8192, 128, 128, {}),
     "minicpm-sparse-16k": (1, 32, 2, 16384, 128, 128, {"block_size": 64}),
     "granite-gqa-8k": (1, 32, 8, 8192, 64, 64, {"sm_scale": 0.015625}),
+    "dots3-select-8k": (1, 32, 32, 8192, 192, 128, {"keys": True}),
+    "dots3-swa-8k": (1, 16, 16, 8192, 256, 128, {"window": 513, "sm_scale": 0.0625}),
     "ring-diagonal": (1, 32, 32, 2048, 128, 128, {"ring": True}),
     "ring-rotated": (1, 32, 32, 2048, 128, 128, {"ring": False}),
 }
@@ -329,8 +331,13 @@ def call_texts(name: str) -> dict:
             operands += (jax.ShapeDtypeStruct(
                 (b, hkv, t, t // kind["block_size"]), jnp.bool_),)
 
+        if "keys" in kind:  # the words of a bit a (row, key), 4,096 keys a lane group
+            operands += (jax.ShapeDtypeStruct((b, t, -(-t // 4096) * 128), jnp.int32),)
+
         def program(q, k, v, *blocks):
             kw = {**kind, "blocks": blocks[0]} if blocks else kind
+            if "keys" in kind:
+                kw = {"keys": blocks[0]}
             o, pull = jax.vjp(
                 lambda q, k, v: attention.flash_attention(q, k, v, **kw), q, k, v)
             return o, pull(o)
@@ -396,6 +403,10 @@ HELD = {
     # at the config's own scale of 1/64, read as the tree of that PR reads it
     "granite-gqa-8k": ("4a31791c23ec669b", "99237a0c02ae0147", "78c0c034c0c14aff",
                        "7864cd6c7190f85e"),
+    "dots3-select-8k": ("cf79b0ef7a88f639", "aceea787df6a5f78", "f80ec23b4a6573e0",
+                        "0171b2e9e61c7612"),
+    "dots3-swa-8k": ("03c9b8787627fbc9", "a8b906f7db52b89d", "508a5388aecc9093",
+                     "7b5168c3fe37ce27"),
     "ring-diagonal": ("4f37ad660cf9ba13", "deddd842bc479ee4", "07e7512d9d037b65",
                       "39c4053850660d16"),
     "ring-rotated": ("5bdfd8d4696da1c0", "444f8125842bae66", "b7924c31d3e2cda8",
@@ -419,7 +430,7 @@ def test_each_pass_under_each_mask_traces_to_the_text_it_had(kernels_on, name, p
     assert call_digests(name)[part] == HELD[name][part]
 
 
-def test_the_nine_flash_kernels_are_the_only_functions_named_so():
+def test_the_twelve_flash_kernels_and_the_indexer_are_the_only_functions_named_so():
     """A trace names a call by the first ``*_kernel`` identifier in its Mosaic
     module (benchmarks/lib/trace.py ``kernel_name``) and the FLOP tables price
     it by that name: a helper of ``ops/attention.py`` named so would rename a
@@ -427,12 +438,14 @@ def test_the_nine_flash_kernels_are_the_only_functions_named_so():
     import inspect
 
     from benchmarks.lib.flops import FLASH_MATMULS
+    from benchmarks.lib.flops_dots3 import INDEX_KERNEL, SELECT_KERNELS
     from benchmarks.lib.flops_laguna import WINDOW_KERNELS
     from benchmarks.lib.flops_minicpm_sala import SPARSE_KERNELS
     from ray_tpu.ops import attention
 
-    priced = {*FLASH_MATMULS, *WINDOW_KERNELS, *SPARSE_KERNELS}
-    assert len(priced) == 9
+    priced = {*FLASH_MATMULS, *WINDOW_KERNELS, *SPARSE_KERNELS, *SELECT_KERNELS,
+              INDEX_KERNEL}
+    assert len(priced) == 13
     source = inspect.getsource(attention)
     defined = set(re.findall(r"def (\w+_kernel)\b", source))  # nested ones too
     assert defined == priced

@@ -10,7 +10,7 @@ from jax.sharding import SingleDeviceSharding
 from ray_tpu.ops import kda, rotary
 from ray_tpu.ops.attention import (
     _backward_call, _bitmap_mask, _causal_mask, _forward_call, _window_mask,
-    flash_attention,
+    flash_attention, index_keys,
 )
 from ray_tpu.ops.gmm import _tgmm_pallas, gmm
 
@@ -1304,3 +1304,62 @@ def test_lfm2s_step_holds_its_kernels_and_reads_the_projections_thirds_in_place(
     joined = [line for line in text.splitlines()
               if "stablehlo.concatenate" in line and "tensor<2x4096x6144xbf16>" in line]
     assert not thirds and not joined
+
+
+# ------------------------------------------------ dots3's selection and band
+
+
+def _steered(monkeypatch):
+    """``flash_attention`` and ``index_keys`` take their kernels where the
+    backend is the TPU; here it is the CPU, so the probe is stood in for."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+
+
+def test_the_indexer_kernel_compiles_for_v5e_at_the_cells_shapes(v5e, monkeypatch):
+    """64 index heads of 128 over 8,192 tokens, top-2048: 256 rows a grid
+    step against every key up to them, their scores resident in VMEM (8 MiB)
+    while the threshold is bisected; the words are [8192, 256] int32."""
+    _steered(monkeypatch)
+    text = _compile_for(
+        v5e, lambda q, k, w: index_keys(q, k, w, topk=2048),
+        ((1, 64, 8192, 128), jnp.bfloat16), ((1, 8192, 128), jnp.bfloat16),
+        ((1, 8192, 64), jnp.float32))
+    assert "f32[1,8192,8192]" not in text  # no score leaves the kernel
+
+
+@pytest.mark.parametrize("t", [8192, 2304])
+def test_the_selection_kernels_compile_for_v5e_at_32_heads_of_192_and_128(
+        v5e, monkeypatch, t):
+    """A full dots3 layer's forward and both backward kernels under the words
+    of a bit a (row, key): 1,024 x 1,024 tiles, a tile's bits 8 of a lane
+    group's 32; 2,304 tokens pad to three tiles of one group."""
+    _steered(monkeypatch)
+    lanes = -(-(-(-t // 1024) * 1024) // 4096) * 128
+    t_p = -(-t // 1024) * 1024
+
+    def step(q, k, v, words):
+        return jax.value_and_grad(lambda *qkv: flash_attention(
+            *qkv, keys=words, sm_scale=192 ** -0.5).astype(jnp.float32).sum(),
+            (0, 1, 2))(q, k, v)
+
+    qk, v = ((1, 32, t, 192), jnp.bfloat16), ((1, 32, t, 128), jnp.bfloat16)
+    text = _compile_for(v5e, step, qk, qk, v, ((1, t_p, lanes), jnp.int32))
+    assert text.count("tpu_custom_call") >= 3  # the forward, dK/dV, dQ
+    assert f"[32,{t_p},{t_p}]" not in text  # and no [T, T] array beside them
+
+
+def test_the_windowed_kernels_compile_for_v5e_at_16_heads_of_256_and_128(v5e, monkeypatch):
+    """A sliding dots3 layer: q and k heads of 192 | 64, v heads of 128, a
+    band of 513 keys at 512 x 512 blocks (Laguna's run at 128 and 128)."""
+    _steered(monkeypatch)
+
+    def step(q, k, v):
+        return jax.value_and_grad(lambda *qkv: flash_attention(
+            *qkv, window=513, sm_scale=256 ** -0.5).astype(jnp.float32).sum(),
+            (0, 1, 2))(q, k, v)
+
+    qk, v = ((1, 16, 8192, 256), jnp.bfloat16), ((1, 16, 8192, 128), jnp.bfloat16)
+    text = _compile_for(v5e, step, qk, qk, v)
+    assert text.count("tpu_custom_call") >= 3

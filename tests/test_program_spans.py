@@ -420,6 +420,78 @@ def lfm2_paths():
 # ----------------------------------------------------------- in-graph scopes
 
 
+@pytest.fixture(scope="module")
+def dots3_paths():
+    """Paths of a tiny dots3 model's compiled train step: a full latent layer
+    under the indexer's selection over a dense FFN and a sliding latent layer
+    of other widths over the expert layer, a gate a head in both."""
+    from ray_tpu.models.dots3 import Dots3ForCausalLM, dots3_config
+    from ray_tpu.models.llama import chunked_causal_lm_loss
+
+    os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"  # read when gmm is traced
+    try:
+        cfg = dots3_config(
+            num_layers=2, layer_types=["full_attention", "sliding_attention"],
+            first_k_dense_replace=1, num_heads=2, num_heads_published=4,
+            swa_num_heads=2, swa_num_heads_published=4, q_lora_rank=16,
+            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+            v_head_dim=8, rope_theta=1e4, swa_q_lora_rank=16,
+            swa_kv_lora_rank=24, swa_qk_nope_head_dim=16,
+            swa_qk_rope_head_dim=8, swa_v_head_dim=8, swa_rope_theta=5e4,
+            sliding_window_size=17, index_n_heads=2, index_head_dim=16,
+            index_topk=24, attention_gate_type="headwise",
+            swa_attention_gate_type="headwise",
+            apply_mla_qkv_lora_rescale=True, num_experts_held=2,
+            vocab_size=128, hidden_size=32, intermediate_size=64,
+            moe_intermediate_size=16, num_experts=8, num_experts_per_tok=2,
+            num_shared_experts=1,
+        )
+        model = Dots3ForCausalLM(cfg)
+        ids = jnp.zeros((1, 64), jnp.int32)
+        return paths_of(compiled_step(
+            model,
+            lambda p, i, t: chunked_causal_lm_loss(model, p, i, t, chunk_size=32),
+            ids,
+        ))
+    finally:
+        del os.environ["RAY_TPU_PALLAS_INTERPRET"]
+
+
+def test_full_and_sliding_latent_mixers_carry_their_names_indexer_and_gate(
+        dots3_paths, sarvam_paths, sala_paths):
+    """What the benchmark's model.dsa_share, model.dsa_index_share and
+    model.swa_mla_share select by: a sliding layer's latent mixer is /swa_mla/
+    and a full layer's stays /mla/; inside the full one ``indexer`` (its three
+    projections and the key's LayerNorm) and ``select``, forward alone, which
+    a reader tells from the sparse mixer's ``select`` by the mixer above it;
+    inside each the latents, the rotation and the output gate, forward and
+    backward."""
+    full = [p for p in dots3_paths if f"/layers_0/{tracing.MLA}/" in p]
+    sliding = [p for p in dots3_paths if f"/layers_1/{tracing.SWA_MLA}/" in p]
+    assert full and sliding
+    assert not [p for p in dots3_paths
+                if f"/layers_0/{tracing.SWA_MLA}/" in p or f"/layers_1/{tracing.MLA}/" in p]
+    for mixer, mine in ((tracing.MLA, full), (tracing.SWA_MLA, sliding)):
+        for name in (tracing.MLA_LATENT, tracing.MLA_Q_LATENT, tracing.MLA_ROPE,
+                     tracing.ATTN_GATE):
+            assert {pass_of(p) for p in mine if f"/{mixer}/{name}/" in p} >= {
+                "forward", "backward"}, (mixer, name)
+        assert any(f"/{mixer}/{tracing.ATTN_GATE}/g_proj/" in p for p in mine)
+        assert any(f"/{mixer}/o_proj/" in p for p in mine)
+    for name in (tracing.INDEXER, tracing.SPARSE_SELECT):
+        scoped = [p for p in full if f"/{tracing.MLA}/{name}/" in p]
+        assert scoped and {pass_of(p) for p in scoped} <= {"forward", "replay"}, name
+        assert not [p for p in sliding if f"/{name}/" in p], name
+    for module in ("index_q_proj", "index_k_proj", "index_k_norm", "index_w_proj"):
+        assert any(f"/{tracing.MLA}/{tracing.INDEXER}/{module}/" in p for p in full), module
+    # the other models' /mla/ has neither, and the sparse mixer's select is its own
+    assert not [p for p in sarvam_paths
+                if f"/{tracing.INDEXER}/" in p or f"/{tracing.SPARSE_SELECT}/" in p]
+    assert not [p for p in sala_paths if f"/{tracing.MLA}/" in p]
+    assert any("/layers_0/mlp/" in p for p in dots3_paths)
+    assert any(f"/layers_1/moe/{tracing.MOE_SHARED}/shared/" in p for p in dots3_paths)
+
+
 def test_a_sparse_and_lightning_hybrid_carries_its_scopes(sala_paths):
     """What model.sparse_share, model.sparse_select_share and
     model.lightning_share select by: /sparse/ with ``select`` (forward alone:
@@ -753,7 +825,7 @@ def test_expert_matmuls_are_under_experts_forward_and_backward(moe_paths, branch
 FAMILIES = ("llama_paths", "qk_norm_paths", "tied_paths", "moe_paths:capacity", "moe_paths:gmm",
             "moe_paths:ragged", "kimi_paths", "sarvam_paths", "xing4_paths",
             "laguna_paths", "solar_paths", "olmo_paths", "sala_paths", "granite_paths",
-            "lfm2_paths")
+            "lfm2_paths", "dots3_paths")
 # Paths that may hold no name of the program, and why.
 EXEMPT = (
     # _positions' arange, inside the model's __call__ and outside every part:
@@ -796,7 +868,7 @@ LOSS_KINDS = {
     "moe_paths:gmm": "full", "moe_paths:ragged": "full", "kimi_paths": "chunked",
     "sarvam_paths": "chunked", "laguna_paths": "chunked", "solar_paths": "chunked",
     "olmo_paths": "chunked", "sala_paths": "chunked", "granite_paths": "chunked",
-    "lfm2_paths": "chunked",
+    "lfm2_paths": "chunked", "dots3_paths": "chunked",
     "xing4_paths": "mtp",
 }
 
@@ -1000,13 +1072,13 @@ def test_actor_call_leaves_exec_reply_and_recv(actor_lines):
 def test_names_emitted_are_exactly_the_list(
     llama_paths, qk_norm_paths, moe_paths, kimi_paths, sarvam_paths,
     xing4_paths, laguna_paths, solar_paths, olmo_paths, sala_paths, granite_paths,
-    lfm2_paths, session_lines, actor_lines
+    lfm2_paths, dots3_paths, session_lines, actor_lines
 ):
     spans = {name for lines in (session_lines, actor_lines)
              for line in lines for name, _, _ in line}
     assert spans == set(tracing.HOST_SPANS)
     assert all(name.startswith("ray_tpu.") for name in tracing.HOST_SPANS)
-    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + olmo_paths + sala_paths + granite_paths + lfm2_paths + [
+    paths = llama_paths + qk_norm_paths + kimi_paths + sarvam_paths + xing4_paths + laguna_paths + solar_paths + olmo_paths + sala_paths + granite_paths + lfm2_paths + dots3_paths + [
         p for ps in moe_paths.values() for p in ps]
     for name in tracing.SCOPES:  # a scope directly under a transform is in its brackets
         assert any(f"/{name}/" in p or f"({name})/" in p for p in paths), name

@@ -462,11 +462,17 @@ def tree_digest(tree) -> tuple:
 # text where each layer's trace wrote its own ("c16ef491925e5adb" before: the
 # same text but for three ``stablehlo.constant`` lines and the numbering after
 # them). The three others hold no constant of a kernel's wrapper.
+# Xing4's line was read the same way at the parent of the PR that made
+# ``MLAMixer`` learn its widths, head count, window, gate, rescale and indexer
+# from the layer's kind (``MLAConfig.latent``, PR 69), where Kimi-Linear's and
+# sarvam's read what they read: the three models whose mixer it is lower to
+# the text they lowered to.
 BEFORE = {
     "kimi-linear-48b-a3b-l5": ("4ed2711bc2778250", 117, "59821762e752310d"),
     "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "ec64c261a184e22c"),
     "mistral-7b-l4": ("06a35641bbb39a58", 21, "6ac84cd0523ca00f"),
     "sarvam-105b-l5": ("c710f6841e29dd3a", 83, "2a9ffca6aec4a4c6"),
+    "xing4-29b-a4b-l5": ("f8dffe54740580c8", 164, "8f712a609e700b41"),
 }
 
 
