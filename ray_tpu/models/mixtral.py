@@ -103,11 +103,11 @@ class MixtralConfig(LlamaConfig):
     # same slots, each row added into its token's (scatter-adds: 3.7 us a
     # marginal row all told, PERF.md §6, PR 37), so the step follows the
     # routing and nothing is paid for a pair held elsewhere. "gather": by
-    # one gather over every (token, k) pair, forward and backward, a pair
-    # held elsewhere reading zeros: the same cost whatever the routing,
-    # each a copy's, and nothing that costs a scatter-add follows the
-    # pairs that came. A held quarter gathers four times the rows it
-    # needs there, a fortieth forty times, and that is all it overpays.
+    # a kernel over tokens, forward and backward (ops.gmm.pairs_summed): a
+    # pair that is here brings its row in by a DMA of its own and a pair
+    # held elsewhere costs a scalar compare, so nothing that costs a
+    # scatter-add follows the pairs that came and a held fortieth no
+    # longer gathers forty times its rows (PERF.md §6, PR 70).
     held_rows: str = "walk"
 
     @property
@@ -594,15 +594,13 @@ def _pairs_summed(rows, slot_of_pair, gates=None):
     """Each token's sum over its K pairs' rows of the layout ``rows``
     [m_pad, D], weighted by ``gates`` [S, K] where they are given, added up
     in float32 and rounded once (``_slots_to_rows``' arithmetic, and
-    ``_rows_to_slots``' gradient's): one gather over every pair.
-    ``slot_of_pair`` [S, K] names a slot past the layout for a pair that is
-    not here, which reads zeros by its index: no row past the used tiles
-    is read."""
-    pairs = rows.at[slot_of_pair].get(mode="fill", fill_value=0)
-    pairs = pairs.astype(jnp.float32)
-    if gates is not None:
-        pairs = pairs * gates[..., None]
-    return pairs.sum(1).astype(rows.dtype)
+    ``_rows_to_slots``' gradient's): a kernel over tokens
+    (``ops.gmm.pairs_summed``). ``slot_of_pair`` [S, K] names a slot past
+    the layout for a pair that is not here, which costs a compare and reads
+    nothing: no row past the used tiles is read."""
+    from ..ops import gmm as G
+
+    return G.pairs_summed(rows, slot_of_pair, gates)
 
 
 def _held_ffn_fwd(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
@@ -669,12 +667,13 @@ def _held_ffn(x2, gates, w_gate, w_up, w_down, pair_of_slot, tile_group,
     (``MixtralConfig.held_rows``). Without ``slot_of_pair``, by walking the
     same slots and adding each into its token's float32 row: scatter-adds,
     of the rows that are here alone. With it ([S, K] int32, a slot at or
-    past ``m_pad`` for a pair that is not here), by one gather over every
-    pair, summed over K in float32 (``_pairs_summed``), forward for the
-    result and backward for x's gradient: the two passes of the layer that
-    do not stop at the used tiles, and no scatter-add. The backward is
-    written out because JAX's would pass over the whole bound: the sum of
-    the two up-projections' row gradients, the SwiGLU's, the gates'.
+    past ``m_pad`` for a pair that is not here), by a kernel over tokens that
+    reads a row only for a pair that is here and sums over K in float32
+    (``_pairs_summed``), forward for the result and backward for x's
+    gradient: no scatter-add, and no pass over the pairs held elsewhere.
+    The backward is written out because JAX's would pass over the whole
+    bound: the sum of the two up-projections' row gradients, the SwiGLU's,
+    the gates'.
 
     ``pair_of_slot`` [m_pad] int32: a slot's (token, k) pair counted
     token-major, S * K in a padding slot (``_held_index``);
@@ -839,8 +838,9 @@ class MoELayer(nn.Module):
     the tiles that hold rows (_held_ffn: the gathers to slots, the SwiGLU,
     the grouped matmuls, every gradient), and rows go back to tokens by
     walking those tiles' slots and adding each into its token's row, or,
-    where cfg.held_rows says "gather", by a gather over every pair and a
-    sum over K, forward and backward: the two passes that stay whole.
+    where cfg.held_rows says "gather", by a kernel over tokens that reads
+    the rows of the pairs that are here and sums over K, forward and
+    backward (ops.gmm.pairs_summed).
 
     "ragged": (token, k) pairs argsorted by expert feed
     `lax.ragged_dot` with exact group sizes — zero capacity padding and
@@ -991,8 +991,8 @@ class MoELayer(nn.Module):
                     slot_of_pair = None
                     if cfg.held_rows == "gather":
                         # The same map the other way: rows come back to
-                        # tokens by a gather over every pair, and a pair
-                        # held elsewhere names a slot past the layout.
+                        # tokens by a kernel over tokens, and a pair held
+                        # elsewhere names a slot past the layout.
                         slot_of_pair = _held_index(
                             order, dst, n_here, N, tiles * 128
                         ).reshape(B * T, K)

@@ -51,6 +51,20 @@ and its float32 accumulator resident in VMEM across the consecutive
 m-tiles of each expert and takes the whole [K, N] as that block where
 it fits (`_tgmm_blocks`), so each input is read once: 2.11 ms a call
 where (512, 512) blocks took 3.77 (the same runs).
+
+Back to tokens from such a bounded layout: `pairs_summed`, a pass over
+tokens whose cost follows the pairs that are here. The scalar core goes
+through a tile's slots at 4.4 ns a pair, a pair that is here brings the
+8-row tile around its row in by a DMA of its own (Mosaic slices a tiled
+extent by whole tiles) and its row is added into its token's float32 sum
+by single-sublane reads and writes: 61 ns a present row at 2,048 columns
+(25 its read's set-up on the scalar core, 30 its sum, the bytes hidden
+under the two) and 0.14 us at 5,120 (the bytes), where XLA's gather over
+every pair took 42 to 96 ns a pair whether it was here or not (dots3's
+65,536 pairs of which 2,052 are here: 0.63 ms against 6.30; Laguna's
+131,072 and 16,390: 1.58 against 6.18; every pair here at OLMoE's shapes:
+4.32 against 2.74, so the whole-layer road keeps XLA's gather; PERF.md
+§6, PR 70).
 """
 from __future__ import annotations
 
@@ -389,6 +403,193 @@ def unwritten(shape, dtype, after):
         out_shape=jax.ShapeDtypeStruct(shape, dtype),
         interpret=_interpret(),
     )(after)
+
+
+# A row of a layout comes into VMEM with the tile it lies in: a [8, 128]
+# tile of HBM is the least a DMA may slice along rows (Mosaic refuses a
+# slice of a tiled extent that is not whole tiles), and a row's tiles lie
+# side by side, so the 8 rows around it are one contiguous read.
+_ROWS_A_READ = 8
+# The rows one trip of a loop of ``_pairs_summed_kernel`` takes, written out
+# in the trip's body (Mosaic unrolls a loop whole or not at all). At 2,048
+# columns a present row costs 94 ns a row a trip, 71 at two, 62 at four and
+# 57 at eight, and a call site's lowering 0.09, 0.09, 0.18 and 0.27 s (a
+# step lowers three sites an expert layer, each anew, and eight a trip put
+# dots3's set-up 12% over its parent's): four (PERF.md §6, PR 70).
+_A_TRIP = 4
+
+
+def _pairs_summed_blocks(k: int, d: int, itemsize: int) -> tuple:
+    """(tokens a grid step, reads each half of its buffer holds) of a
+    ``pairs_summed`` call: the step's float32 sums and its output block
+    twice take at most a quarter of the budget, the two halves at most the
+    rest, and together no more than every pair of the step's; whole trips
+    of reads (``_A_TRIP``), and 64 at most: a grid step that has no more
+    rows than a half holds (dots3's 64, 8 of 256 experts held) adds nothing
+    while they fly, and past 64 a half's length buys nothing (timed at 16
+    to 256 at the four cells' shapes: PERF.md §6, PR 70)."""
+    tokens = next(
+        (t for t in (256, 128, 64, 32, 16) if t * d * (4 + 2 * itemsize) * 4
+         <= _BLOCK_BUDGET), 8
+    )
+    sums, out = (tokens + _ROWS_A_READ) * d * 4, 2 * tokens * d * itemsize
+    left = _BLOCK_BUDGET - sums - out
+    reads = left // (2 * _ROWS_A_READ * d * itemsize)
+    reads = min(tokens * k // 2, 64, reads) // _A_TRIP * _A_TRIP
+    return tokens, max(_A_TRIP, reads)
+
+
+def _pairs_summed_kernel(slot_ref, *refs, k, m_pad, gated, trip):
+    gate_ref, rows_ref, out_ref, read_scr, pair_scr, sum_scr, sems = (
+        refs if gated else (None, *refs)
+    )
+    pairs, reads = slot_ref.shape[0], read_scr.shape[1]
+    tokens = pairs // k
+    # Two bfloat16 rows lie in one 32-bit sublane, the even one low: a row
+    # is read as its sublane's words, its half moved to a float32's top.
+    packed = read_scr.dtype.itemsize == 2
+    words = read_scr.bitcast(jnp.uint32) if packed else read_scr
+
+    # The step's pairs that are here, in the order they lie (a token's in
+    # ascending k), by a loop that branches on nothing: every pair is noted
+    # where the next one here belongs, and only one that is here moves on.
+    def note(token, n):
+        for pair in range(k):
+            pair += token * k
+            pair_scr[n] = pair
+            n += (slot_ref[pair] < m_pad).astype(jnp.int32)
+        return n
+
+    n = jax.lax.fori_loop(0, tokens, note, jnp.int32(0))
+    # The last trip over them is made whole by the last of them again: such
+    # a one's read is started and waited for like any, and its row is added
+    # to a row of the sums past the tokens', which nothing reads.
+    last = pair_scr[jnp.maximum(n - 1, 0)]
+    for i in range(trip - 1):
+        pair_scr[n + i] = last
+
+    def read(at, half, i):
+        return pltpu.make_async_copy(
+            rows_ref.at[pl.ds(at, _ROWS_A_READ)], read_scr.at[half, i],
+            sems.at[half],
+        )
+
+    # Of the c-th ``reads`` of the pairs that are here, which the buffer's
+    # half c % 2 holds, the i-th: its read's start, its read's end, its sum.
+    def start(c, i):
+        slot = slot_ref[pair_scr[c * reads + i]]
+        at = pl.multiple_of(slot // _ROWS_A_READ * _ROWS_A_READ, _ROWS_A_READ)
+        read(at, c % 2, i).start()
+
+    def wait(c, i):
+        read(0, c % 2, 0).wait()
+
+    def add(c, i):
+        j = c * reads + i
+        pair = pair_scr[j]
+        row = slot_ref[pair] % _ROWS_A_READ
+        if packed:
+            word = words[c % 2, i, pl.ds(row // 2, 1), :]
+            word = jnp.where(
+                row % 2 == 1, word & jnp.uint32(0xFFFF0000), word << 16
+            )
+            value = pltpu.bitcast(word, jnp.float32)
+        else:
+            value = words[c % 2, i, pl.ds(row, 1), :].astype(jnp.float32)
+        if gated:
+            value = value * gate_ref[pair]
+        token = jnp.where(j < n, pair // k, tokens)
+        sum_scr[pl.ds(token, 1), :] += value
+
+    def rows(c, each):
+        # each(c, i) of every row i of the c-th chunk, ``trip`` of them a
+        # trip; a chunk past the last has none.
+        def some(t, carry):
+            for i in range(trip):
+                each(c, t * trip + i)
+            return carry
+
+        some_of = jnp.clip(n - c * reads, 0, reads)
+        jax.lax.fori_loop(0, -(-some_of // trip), some, 0)
+
+    # A chunk's reads are started together and waited for together, and the
+    # next chunk's are in flight, in the buffer's other half, while this
+    # one's rows are added.
+    rows(0, start)
+    sum_scr[...] = jnp.zeros_like(sum_scr)
+
+    def turn(c, carry):
+        rows(c + 1, start)
+        rows(c, wait)
+        rows(c, add)
+        return carry
+
+    jax.lax.fori_loop(0, -(-n // reads), turn, 0)
+    out_ref[...] = sum_scr[pl.ds(0, tokens), :].astype(out_ref.dtype)
+
+
+@kernel_entry(reads=lambda: (_BLOCK_BUDGET, _A_TRIP, _pairs_summed_blocks))
+def pairs_summed(rows, slot_of_pair, gates=None):
+    """out[t] = sum over k of gates[t, k] * rows[slot_of_pair[t, k]] over the
+    pairs whose slot lies under ``rows``' [m_pad, D] extent, the products
+    and the sum in float32, added in ascending k and rounded once to
+    ``rows.dtype``; without ``gates`` [S, K] the rows alone. A token with no
+    such pair reads zeros.
+
+    A pass over tokens, a tile of them a grid step: ``slot_of_pair`` [S, K]
+    int32 reaches the scalar core a tile at a time, which notes the pairs
+    that are here (a pair that is not costs that compare and nothing else);
+    the layout stays in HBM, and a pair that is here brings its row in by a
+    DMA of its own (with the 8-row tile it lies in: ``_ROWS_A_READ``),
+    started with the others of its chunk and waited for with them while the
+    next chunk's are in flight, four rows a trip of every loop over them
+    (``_A_TRIP``). What is read of the layout is the 8-row tiles that hold a
+    named row, and of those only the named rows enter a sum: a layout filled
+    to its used tiles (``unwritten``) may hold anything past them, and in a
+    slot no pair names."""
+    (m_pad, d), (s, k) = rows.shape, slot_of_pair.shape
+    if rows.dtype.itemsize not in (2, 4) or m_pad % _ROWS_A_READ:
+        raise ValueError(f"pairs_summed: rows {rows.dtype}{list(rows.shape)}")
+    tokens, reads = _pairs_summed_blocks(k, d, rows.dtype.itemsize)
+    tokens = min(tokens, -(-s // 8) * 8)
+    trip = min(_A_TRIP, reads)
+    if reads % trip:
+        raise ValueError(f"pairs_summed: {reads} reads a half, {trip} a trip")
+    tiles = -(-s // tokens)
+
+    def scalars(of, fill):
+        # Whole tiles of pairs, a pair past the tokens one that is not here.
+        flat = of.reshape(s * k)
+        return jnp.pad(flat, (0, tiles * tokens * k - s * k), constant_values=fill)
+
+    pairs_spec = pl.BlockSpec(
+        (tokens * k,), lambda i: (i,), memory_space=pltpu.SMEM
+    )
+    operands = [scalars(slot_of_pair, m_pad)]
+    if gates is not None:
+        operands.append(scalars(gates.astype(jnp.float32), 0))
+    return pl.pallas_call(
+        functools.partial(
+            _pairs_summed_kernel, k=k, m_pad=m_pad, gated=gates is not None,
+            trip=trip,
+        ),
+        grid=(tiles,),
+        in_specs=[pairs_spec] * len(operands)
+        + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((tokens, d), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((s, d), rows.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((2, reads, _ROWS_A_READ, d), rows.dtype),
+            pltpu.SMEM((tokens * k + trip,), jnp.int32),
+            pltpu.VMEM((tokens + _ROWS_A_READ, d), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=_interpret(),
+    )(*operands, rows)
 
 
 def aligned_group_layout(e_flat, num_groups: int, block_m: int = 128):

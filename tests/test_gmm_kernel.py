@@ -275,3 +275,100 @@ def test_gmm_in_bfloat16_accumulates_in_float32(interpret):
             a.astype(f32), b, rtol=2.0 ** -8, atol=2.0 ** -8 * np.abs(b).max(),
             err_msg=name,
         )
+
+
+# ------------------------------------------- a held share's rows, back to tokens
+
+
+def _pairs_summed_by_a_gather(rows, slot_of_pair, gates=None):
+    """What ``pairs_summed`` replaced in ``models/mixtral.py`` (PR 70), kept
+    as its reference: one XLA gather over every pair, a pair that is not here
+    reading zeros by its index."""
+    pairs = rows.at[slot_of_pair].get(mode="fill", fill_value=0)
+    pairs = pairs.astype(jnp.float32)
+    if gates is not None:
+        pairs = pairs * gates[..., None]
+    return pairs.sum(1).astype(rows.dtype)
+
+
+def _held_pairs(s, k, share, m_pad, used, seed):
+    """``slot_of_pair`` [s, k] of a routing that holds ``share`` of the pairs
+    in distinct slots under ``used``, ``m_pad`` for a pair that is not here;
+    token 0 has no pair here, token 1 its first alone, token 2 all k."""
+    rng = np.random.default_rng(seed)
+    here = rng.random((s, k)) < share
+    here[0], here[1], here[2] = False, [True] + [False] * (k - 1), True
+    slots = np.full((s, k), m_pad, np.int32)
+    slots[here] = rng.permutation(used)[: here.sum()]
+    return slots
+
+
+# (tokens, top-k, width, share of the pairs here); the blocks a grid step is
+# cut to: the rule's, or tiles of 16 tokens that do not divide the tokens over
+# a buffer of two halves of 4 reads, which a tile of 64 or 128 pairs fills
+# several times.
+PAIRS_CASES = [
+    (40, 4, 32, 0.25), (40, 8, 128, 1 / 32), (100, 8, 256, 0.125),
+    (72, 4, 128, 1.0), (64, 8, 128, 0.0),
+]
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gates", "no_gates"])
+@pytest.mark.parametrize("blocks", ["the_rule's", "small"])
+@pytest.mark.parametrize("s,k,d,share", PAIRS_CASES)
+def test_pairs_summed_is_the_gather_it_replaced(
+    s, k, d, share, blocks, gated, interpret, monkeypatch
+):
+    if blocks == "small":
+        monkeypatch.setattr(G, "_pairs_summed_blocks", lambda k, d, size: (16, 4))
+    m_pad, used = 1024, 768
+    slots = _held_pairs(s, k, share, m_pad, used, seed=s + k)
+    if share == 0.0:
+        slots[:] = m_pad
+    rng = np.random.default_rng(5)
+    rows = jnp.asarray(rng.standard_normal((m_pad, d)), jnp.bfloat16)
+    gates = jnp.asarray(rng.random((s, k)), jnp.bfloat16) if gated else None
+    got = G.pairs_summed(rows, jnp.asarray(slots), gates)
+    want = _pairs_summed_by_a_gather(rows, jnp.asarray(slots), gates)
+    assert got.shape == (s, d) and got.dtype == jnp.bfloat16
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert (got[0] == 0).all()
+    alone = (slots < m_pad).sum(1) <= 1
+    np.testing.assert_array_equal(got[alone], want[alone])
+    # A sum of several rounds once either way; the orders of addition may
+    # differ by the float32 sum's last place, one of bfloat16's at most.
+    assert (np.abs(got - want) <= np.abs(want) * 2.0 ** -7).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_pairs_summed_takes_nothing_from_a_row_no_pair_names(dtype, interpret, monkeypatch):
+    """Every row that no present pair names, and every row past the used
+    tiles, is NaN (past the used tiles a layout is uninitialised memory,
+    ``unwritten``): the result is finite and is the gather's."""
+    monkeypatch.setattr(G, "_pairs_summed_blocks", lambda k, d, size: (16, 4))
+    s, k, d, m_pad, used = 48, 4, 128, 512, 256
+    slots = _held_pairs(s, k, 0.25, m_pad, used, seed=11)
+    rng = np.random.default_rng(6)
+    clean = rng.standard_normal((m_pad, d)).astype(np.float32)
+    named = np.zeros(m_pad, bool)
+    named[slots[slots < m_pad]] = True
+    rows = jnp.asarray(np.where(named[:, None], clean, np.nan), dtype)
+    gates = jnp.asarray(rng.random((s, k)), dtype)
+    got = np.asarray(G.pairs_summed(rows, jnp.asarray(slots), gates), np.float32)
+    assert np.isfinite(got).all()
+    want = _pairs_summed_by_a_gather(
+        jnp.asarray(clean, dtype), jnp.asarray(slots), gates
+    )
+    np.testing.assert_allclose(
+        got, np.asarray(want, np.float32), rtol=2.0 ** -7, atol=1e-6
+    )
+
+
+@pytest.mark.parametrize("k,d", [(8, 5120), (8, 2048), (8, 4096), (4, 3584)],
+                         ids=["dots3", "laguna", "solar", "xing4"])
+def test_pairs_summed_blocks_keep_to_the_budget(k, d):
+    tokens, reads = G._pairs_summed_blocks(k, d, BF16)
+    held = (tokens + G._ROWS_A_READ) * d * 4 + tokens * d * 2 * BF16
+    held += 2 * reads * G._ROWS_A_READ * d * BF16
+    assert held <= G._BLOCK_BUDGET and tokens % G._A_TRIP == 0
+    assert G._A_TRIP <= reads <= tokens * k // 2 and reads % G._A_TRIP == 0

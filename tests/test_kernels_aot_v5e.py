@@ -12,7 +12,7 @@ from ray_tpu.ops.attention import (
     _backward_call, _bitmap_mask, _causal_mask, _forward_call, _window_mask,
     flash_attention, index_keys,
 )
-from ray_tpu.ops.gmm import _tgmm_pallas, gmm
+from ray_tpu.ops.gmm import _tgmm_pallas, gmm, pairs_summed
 
 
 @pytest.fixture(scope="module")
@@ -280,6 +280,22 @@ def test_bounded_gmm_and_its_gradient_compile_for_v5e(v5e, tokens, experts, k, n
         ((m // 128,), jnp.int32), ((1,), jnp.int32),
     )
     assert text.count("tpu_custom_call") >= 2  # dlhs and drhs
+
+
+# A held share's rows back to tokens (PR 70), at the four cells that gather:
+# (tokens, top-k, hidden, experts held). The layout is bounded at every pair,
+# in whole windows of 16 tiles; a present pair's DMA slices the 8-row tile its
+# row lies in, which Mosaic takes where it refuses a slice of one row.
+@pytest.mark.parametrize("tokens,k,d,held", [
+    (8192, 8, 5120, 8), (16384, 8, 2048, 32), (4096, 8, 4096, 8), (4096, 4, 3584, 16),
+], ids=["dots3", "laguna", "solar", "xing4"])
+def test_pairs_summed_compiles_for_v5e(v5e, tokens, k, d, held):
+    m_pad = -(-(tokens * k + (held + 1) * 128) // 2048) * 2048
+    rows, pairs = ((m_pad, d), jnp.bfloat16), ((tokens, k), jnp.int32)
+    _compile_for(v5e, pairs_summed, rows, pairs, ((tokens, k), jnp.bfloat16))
+    text = _compile_for(v5e, pairs_summed, rows, pairs)
+    # Nothing of [tokens, k, d] is made beside the kernel.
+    assert f"bf16[{tokens},{k},{d}]" not in text and f"f32[{tokens},{k},{d}]" not in text
 
 
 # The Mixtral cell's capacity FFN (ep2seq2-4k): a chip's four experts of

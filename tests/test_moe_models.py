@@ -456,8 +456,9 @@ def _equations(jaxpr):
 def test_no_row_is_scatter_added_on_the_gather_road(held_rows, row_adds, monkeypatch):
     """A scatter-add into [., 32] arrays (rows of tokens or of slots): the
     walk's two, forward into the result and backward into x's gradient, and
-    none where rows are gathered; there the traced step holds two gathers
-    of every pair's row, [256, 4, 32], forward and backward."""
+    none where rows are gathered; there the traced step holds two calls of
+    the kernel over tokens (``ops.gmm.pairs_summed``), forward and backward,
+    and no gather of every pair's row, [256, 4, 32], on either road."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     step, shapes = _layer_step(experts_held=(4, 8), held_rows=held_rows)
     eqns = list(_equations(jax.make_jaxpr(step)(*shapes).jaxpr))
@@ -466,7 +467,12 @@ def test_no_row_is_scatter_added_on_the_gather_road(held_rows, row_adds, monkeyp
     assert len(adds) == row_adds
     whole = [e for e in eqns if e.primitive.name == "gather"
              and e.outvars[0].aval.shape == (256, 4, 32)]
-    assert len(whole) == (2 if held_rows == "gather" else 0)
+    assert not whole
+    over_tokens = [e for e in eqns if e.primitive.name == "pallas_call"
+                   and e.params["jaxpr"].debug_info.func_name == "_pairs_summed_kernel"]
+    assert len(over_tokens) == (2 if held_rows == "gather" else 0)
+    for call in over_tokens:
+        assert call.outvars[0].aval.shape == (256, 32)
 
 
 # ------------------------------------------------------ one decoder body

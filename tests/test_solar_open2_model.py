@@ -466,13 +466,17 @@ def tree_digest(tree) -> tuple:
 # ``MLAMixer`` learn its widths, head count, window, gate, rescale and indexer
 # from the layer's kind (``MLAConfig.latent``, PR 69), where Kimi-Linear's and
 # sarvam's read what they read: the three models whose mixer it is lower to
-# the text they lowered to.
+# the text they lowered to. Laguna's and Xing4's again since a held share's
+# rows come back to tokens by a kernel over tokens (``ops/gmm.py``
+# ``pairs_summed``, PR 70; "ec64c261a184e22c" and "8f712a609e700b41" before,
+# with a gather over every pair): the two of the five that gather. Kimi-Linear's
+# and sarvam's, which walk, and Mistral's read what they read.
 BEFORE = {
     "kimi-linear-48b-a3b-l5": ("4ed2711bc2778250", 117, "59821762e752310d"),
-    "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "ec64c261a184e22c"),
+    "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "a67063925f4a1684"),
     "mistral-7b-l4": ("06a35641bbb39a58", 21, "6ac84cd0523ca00f"),
     "sarvam-105b-l5": ("c710f6841e29dd3a", 83, "2a9ffca6aec4a4c6"),
-    "xing4-29b-a4b-l5": ("f8dffe54740580c8", 164, "8f712a609e700b41"),
+    "xing4-29b-a4b-l5": ("f8dffe54740580c8", 164, "858b93108f6bfbdd"),
 }
 
 
