@@ -2,7 +2,7 @@
 # .buildkite/ + ci/ — here one deterministic make surface: native
 # build, bytecode lint, stress binaries, full suite).
 
-.PHONY: ci native lint raylint raylint-baseline race-smoke test \
+.PHONY: ci native lint raylint raylint-baseline race-smoke test tier1-times \
 	obs-smoke envelope-smoke chaos-smoke failover-smoke \
 	pressure-smoke shm-smoke partition-smoke straggler-smoke \
 	stress clean
@@ -58,6 +58,12 @@ race-smoke:
 
 test:
 	python -m pytest tests/ -q
+
+# Seconds by test file of the last tier-1 run (the XML its --junitxml wrote):
+# the sum, the sum over six workers, the longest cases; non-zero where a file
+# is over 150 s (README "Tests").
+tier1-times:
+	python -m tools.tier1_times /tmp/_t1.xml
 
 # Observability surface: flight-recorder event pipeline + tracing +
 # dashboard tests, including the recorder overhead-budget perf check

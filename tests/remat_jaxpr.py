@@ -1,4 +1,4 @@
-"""What tests/test_remat_residuals.py and tests/test_minicpm_sala_model.py
+"""What tests/remat_cases.py and tests/test_minicpm_sala_model.py
 read from a gradient's jaxpr: which forward kernels and which forward matmuls
 it holds, a replay's among them."""
 import collections

@@ -1,5 +1,5 @@
 """Dashboard HTTP API (the profiler spans of util/tracing.py are in
-test_program_spans.py)."""
+test_program_spans.py and test_program_paths_*.py)."""
 import json
 import urllib.error
 import urllib.request

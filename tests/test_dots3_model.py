@@ -15,13 +15,17 @@ rank's share of a full layer and of an expert layer against the uncut
 reference; the wrong programs and references of
 ``benchmarks/tools/wrong_dots3.py``; the configuration file against the
 catalog's row.
+
+This file holds the model against its reference (logits, wrong references,
+loss and gradients) and the configuration file's cases. The wrong programs
+(``tests/test_dots3_wrong_programs.py``), the hand-written lines and the
+selection (``test_dots3_layers.py``) and the ranks' shares
+(``test_dots3_shares.py``) are beside it, over ``tests/dots3_cases.py``.
 """
-import dataclasses
 import json
 import math
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -29,58 +33,18 @@ from benchmarks.lib import cells
 from benchmarks.lib.checks import logits_agreement
 from benchmarks.reference import dots3_note_decoder as reference
 from benchmarks.tools import wrong_dots3
-from ray_tpu.models.dots3 import Dots3Config, Dots3ForCausalLM
+from ray_tpu.models.dots3 import Dots3ForCausalLM
 from ray_tpu.models.llama import chunked_causal_lm_loss
-from ray_tpu.models.mixtral import MoELayer
-from ray_tpu.models.mla import Indexer, LatentKind, MLAMixer
-from ray_tpu.ops import attention
+from ray_tpu.models.mla import Indexer, LatentKind
 from ray_tpu.util import tracing
 
-SEQ = 128
-CONFIG = f"{cells.BENCH_DIR}/configs/dots3-note-prev-l5.json"
+from dots3_cases import (  # noqa: F401 - fixtures
+    CONFIG, FAR, SEQ, dots3, expected, interpret, tiny,
+)
+
+
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-# Past these a float32 program is another function than the reference.
-FAR = {"per_position_rel_err": 1e-3, "min_share_within": 0.5}
 INDEX = ("index_q_proj", "index_k_proj", "index_k_norm", "index_w_proj")
-
-
-@pytest.fixture(scope="module", autouse=True)
-def interpret():
-    # "gmm" has no XLA stand-in: on the CPU its kernels are interpreted, and
-    # at 128 rows so are the indexer, the selection's and the band's kernels.
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-        yield
-
-
-def tiny(**changes) -> dict:
-    """The file at its rehearsal widths in float32: 128 positions choose 48
-    keys of up to 128 in the full layers and see 40 in the sliding ones."""
-    config = cells.load_json(CONFIG)
-    config = {**config, **config["rehearsal"], "index_topk": 48,
-              "sliding_window_size": 40, "num_experts_per_tok": 2, **changes}
-    config["program"] = {
-        **config["program"],
-        "set": {**config["program"]["set"], "dtype": "float32",
-                "param_dtype": "float32"},
-    }
-    return config
-
-
-@pytest.fixture(scope="module")
-def dots3():
-    config = tiny()
-    model = Dots3ForCausalLM(cells.program_config(config))
-    ids = np.random.default_rng(0).integers(0, config["vocab_size"], SEQ)
-    ids = ids.astype(np.int32)
-    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids[None, :8])
-    return config, model, params, ids
-
-
-@pytest.fixture(scope="module")
-def expected(dots3):
-    config, _, params, ids = dots3
-    return reference.forward(params, ids, config, SEQ)
 
 
 def test_the_configuration_builds_dots3s_program(dots3):
@@ -151,32 +115,17 @@ def test_a_gate_of_another_type_is_refused():
         cells.program_config(tiny(attention_gate_type="elementwise"))
 
 
-def test_logits_agree_with_the_reference_in_float32(dots3, expected):
+@pytest.fixture(scope="module")
+def logits(dots3):
+    """The unchanged program's float32 logits: one jitted program, one value."""
     _, model, params, ids = dots3
-    logits = model.apply(params, ids[None])[0]
+    return jax.jit(model.apply)(params, ids[None])[0]
+
+
+def test_logits_agree_with_the_reference_in_float32(logits, expected):
     found = logits_agreement(logits, expected, {
         "per_position_rel_err": 2e-5, "min_share_within": 1.0})
     assert found["ok"], found
-
-
-def without(params, names):
-    return {"params": {
-        layer: {mixer: {k: v for k, v in sub.items() if k not in names}
-                if mixer in tracing.MIXERS else sub for mixer, sub in held.items()}
-        if layer.startswith("layers_") else held
-        for layer, held in params["params"].items()}}
-
-
-@pytest.mark.parametrize("name", [
-    "system_no_rescale", "system_no_gate", "system_window_512",
-    "system_window_514", "system_top_2047", "system_no_selection"])
-def test_a_wrong_program_is_far_from_the_reference(dots3, expected, name):
-    _, model, params, ids = dots3
-    cfg, *drop = wrong_dots3.programs(model.cfg)[name]
-    logits = Dots3ForCausalLM(cfg).apply(
-        without(params, drop[0]) if drop else params, ids[None])[0]
-    found = logits_agreement(logits, expected, FAR)
-    assert not found["ok"], found
 
 
 @pytest.mark.parametrize("name,far", [
@@ -185,13 +134,14 @@ def test_a_wrong_program_is_far_from_the_reference(dots3, expected, name):
     # within 2e-5 at every one
     ("reference_index_bf16", {**FAR, "min_share_within": 1.0}),
     ("reference_router_bf16", FAR)])
-def test_a_wrong_reference_is_far_from_the_program(dots3, name, far, monkeypatch):
-    config, model, params, ids = dots3
+def test_a_wrong_reference_is_far_from_the_program(
+        dots3, logits, name, far, monkeypatch):
+    config, _, params, ids = dots3
     function, replacement = wrong_dots3.references(
         lambda a: jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7))[name]
     monkeypatch.setattr(reference, function, replacement(getattr(reference, function)))
     wrong = reference.forward(params, ids, config, SEQ)
-    found = logits_agreement(model.apply(params, ids[None])[0], wrong, far)
+    found = logits_agreement(logits, wrong, far)
     assert not found["ok"], found
 
 
@@ -235,257 +185,6 @@ def test_every_gradient_agrees_with_the_references(both_gradients):
     # (the bias apart); embedding, final norm, head
     assert checked == 5 * 10 + 3 + 4 * 7 + 3
     assert frozen == 4 + 2 * 5  # a bias an expert layer; 5 leaves an indexer
-
-
-# ------------------------------------------------ a hand-written line each
-
-
-def one_mixer(kind: LatentKind, seq=SEQ, hidden=64, seed=0):
-    cfg = Dots3Config(
-        hidden_size=hidden, latents=((tracing.MLA, kind),),
-        initializer_range=0.3, dtype=jnp.float32, param_dtype=jnp.float32)
-    x = jnp.asarray(
-        np.random.default_rng(seed).normal(size=(1, seq, hidden)), jnp.float32)
-    positions = jnp.arange(seq)[None]
-    mixer = MLAMixer(cfg, name=tracing.MLA)
-    params = mixer.init(jax.random.PRNGKey(seed), x, positions)
-    return mixer, params, x, positions
-
-
-KIND = LatentKind(4, 32, 16, 16, 16, 1e4, mla_rope=True, q_lora_rank=24)
-
-
-def test_the_rescale_is_each_latent_times_the_root_of_hidden_over_its_rank():
-    """c_q x (64 / 24)^1/2 and c x (64 / 32)^1/2: the same as the plain layer
-    with the factors in the up-projections that read the latents."""
-    plain, params, x, positions = one_mixer(KIND)
-    rescaled = one_mixer(dataclasses.replace(KIND, rescale=True))[0]
-    p = params["params"]
-    folded = {"params": {
-        **p,
-        "q_b_proj": {"kernel": p["q_b_proj"]["kernel"] * (64 / 24) ** 0.5},
-        "kv_b_proj": {"kernel": p["kv_b_proj"]["kernel"] * (64 / 32) ** 0.5},
-    }}
-    np.testing.assert_allclose(
-        rescaled.apply(params, x, positions), plain.apply(folded, x, positions),
-        rtol=2e-5, atol=3e-5)
-
-
-def test_the_gate_is_one_sigmoid_a_head_and_token_before_o_proj():
-    """out = sum_n (o_n sigmoid(x W_g)_n) W_o[n]: with W_g = 0 half the plain
-    layer's; with head 0's column far below zero, the plain layer's without
-    head 0."""
-    plain, params, x, positions = one_mixer(KIND)
-    gated = one_mixer(dataclasses.replace(KIND, gate=True))[0]
-    out = plain.apply(params, x, positions)
-    p = params["params"]
-    zero = {"params": {**p, "g_proj": {"kernel": jnp.zeros((64, 4))}}}
-    np.testing.assert_allclose(
-        gated.apply(zero, x, positions), 0.5 * out, rtol=2e-5, atol=1e-6)
-    # x has a constant channel: its column of W_g is a bias a head
-    x1 = x.at[..., 0].set(1.0)
-    shut = jnp.zeros((64, 4)).at[0].set(jnp.asarray([-40.0, 40.0, 40.0, 40.0]))
-    headless = {"params": {
-        **p, "o_proj": {"kernel": p["o_proj"]["kernel"].at[0].set(0.0)}}}
-    np.testing.assert_allclose(
-        gated.apply({"params": {**p, "g_proj": {"kernel": shut}}}, x1, positions),
-        plain.apply(headless, x1, positions), rtol=2e-5, atol=1e-6)
-
-
-def test_a_window_of_513_is_the_row_and_the_512_before_it():
-    """Row t sees keys t - 512 .. t: the reference's band, by hand, and the
-    program's ``flash_attention(window=)`` under it at 1,100 rows."""
-    t, window = 1100, 513
-    ahead = np.arange(t)[:, None] - np.arange(t)[None, :]
-    band = (ahead >= 0) & (ahead <= 512)
-    assert band.sum(1).tolist() == [min(i + 1, 513) for i in range(t)]
-    assert band[1000].nonzero()[0][[0, -1]].tolist() == [488, 1000]
-    np.testing.assert_array_equal(attention._visible(t, t, window), band)
-    rng = np.random.default_rng(3)
-    q, k, v = (jnp.asarray(rng.normal(size=(1, 1, t, 8)), jnp.float32)
-               for _ in range(3))
-    s = np.einsum("td,sd->ts", q[0, 0], k[0, 0]) * 8 ** -0.5
-    p = np.where(band, np.exp(s - s.max(1, keepdims=True)), 0.0)
-    want = (p / p.sum(1, keepdims=True)) @ np.asarray(v[0, 0])
-    got = attention.flash_attention(q, k, v, window=window)[0, 0]
-    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
-
-
-def chosen_by_hand(scores: np.ndarray, topk: int) -> np.ndarray:
-    """Row t keeps {s <= t : I[t, s] >= the topk-th largest of I[t, :t+1]}."""
-    t = scores.shape[0]
-    seen = np.zeros((t, t), bool)
-    for row in range(t):
-        mine = scores[row, :row + 1]
-        least = np.sort(mine)[::-1][min(topk, row + 1) - 1]
-        seen[row, :row + 1] = mine >= least
-    return seen
-
-
-@pytest.mark.parametrize("t", [96, 256], ids=["xla", "kernel"])
-def test_the_threshold_keeps_ties(t):
-    """One index head of one channel, w = 1: I[t, s] = ReLU(q_t k_s), and with
-    q and k from a few small integers whole heaps of keys score alike (and
-    half score 0): a row keeps every key at its threshold, so more than
-    ``topk`` where the threshold's heap is cut."""
-    rng = np.random.default_rng(5)
-    q = rng.integers(1, 3, size=(1, 1, t, 8)).astype(np.float32) * (np.arange(8) == 0)
-    k = rng.integers(-2, 4, size=(1, t, 8)).astype(np.float32) * (np.arange(8) == 0)
-    w = np.ones((1, t, 1), np.float32)
-    topk = 24
-    words = attention.index_keys(jnp.asarray(q), jnp.asarray(k), jnp.asarray(w), topk=topk)
-    scores = np.maximum(q[0, 0, :, :1] * k[0, :, 0][None, :], 0.0)
-    want = chosen_by_hand(scores, topk)
-    np.testing.assert_array_equal(attention._unpack_keys(words, t)[0], want)
-    kept = want.sum(1)
-    assert (kept[:topk] == np.arange(1, topk + 1)).all()  # every key while few
-    assert (kept >= np.minimum(np.arange(t) + 1, topk)).all() and kept.max() > topk
-
-
-@pytest.mark.parametrize("t", [64, 160], ids=["xla", "kernel"])
-def test_the_selection_of_a_short_sequence_is_the_causal_mask(t):
-    rng = np.random.default_rng(6)
-    q, k = rng.normal(size=(1, 2, t, 16)), rng.normal(size=(1, t, 16))
-    w = rng.normal(size=(1, t, 2))
-    words = attention.index_keys(
-        *(jnp.asarray(a, jnp.float32) for a in (q, k, w)), topk=t)
-    np.testing.assert_array_equal(
-        attention._unpack_keys(words, t)[0], np.tril(np.ones((t, t), bool)))
-
-
-# ------------------------------------- the fourth mask's kernels, interpreted
-
-
-def explicit(q, k, v, seen, scale):
-    s = jnp.einsum("bhtd,bhsd->bhts", q, k) * scale
-    p = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), axis=-1)
-    return jnp.einsum("bhts,bhsd->bhtd", p, v)
-
-
-@pytest.mark.parametrize("t,topk", [(256, 100), (300, 64), (1280, 200)])
-def test_the_selection_kernels_agree_with_an_explicit_mask(t, topk):
-    """The indexer kernel's words against the scores' own threshold, and the
-    three flash kernels under them (forward, dK/dV, dQ) against a masked
-    soft-max and its autodiff: 1,280 rows are two tiles of 1,024 with a dead
-    one above the diagonal, 300 a padded tile of 256."""
-    rng = np.random.default_rng(t)
-    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
-    q, k, v = f32(1, 2, t, 24), f32(1, 2, t, 24), f32(1, 2, t, 16)
-    q_i, k_i, w = f32(1, 3, t, 16), f32(1, t, 16), f32(1, t, 3)
-    words = attention.index_keys(q_i, k_i, w, topk=topk)
-    scores = np.asarray(attention.index_scores(q_i, k_i, w))[0]
-    seen = chosen_by_hand(scores, topk)
-    np.testing.assert_array_equal(attention._unpack_keys(words, t)[0], seen)
-    seen, scale = jnp.asarray(seen)[None], 24 ** -0.5
-    weight = jnp.cos(jnp.arange(16.0))
-    got, back = jax.value_and_grad(
-        lambda *qkv: (attention.flash_attention(
-            *qkv, keys=words, sm_scale=scale) * weight).sum(), (0, 1, 2))(q, k, v)
-    want, wanted = jax.value_and_grad(
-        lambda *qkv: (explicit(*qkv, seen, scale) * weight).sum(), (0, 1, 2))(q, k, v)
-    assert float(got) == pytest.approx(float(want), rel=1e-5)
-    for a, b in zip(back, wanted):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5)
-
-
-def test_keys_are_refused_where_they_have_no_meaning():
-    q = jnp.zeros((1, 2, 64, 8))
-    words = attention._pack_keys(jnp.ones((1, 64, 64), bool), 128)
-    with pytest.raises(ValueError, match="keys= is causal"):
-        attention.flash_attention(q, q[:, :1], q[:, :1], keys=words)
-    with pytest.raises(ValueError, match="keys= is causal"):
-        attention.flash_attention(q, q, q, keys=words, window=8)
-
-
-# ------------------------------------------------------- the shares add up
-
-
-def test_the_head_ranks_shares_add_up_to_the_uncut_full_layer():
-    """Four ranks of two heads each of a full layer: what each gives of
-    o_proj's sum, from the same latents and the same selection (every rank
-    computes those alike), adds up to the reference's layer at all 8 heads."""
-    kind = LatentKind(
-        8, 32, 16, 16, 16, 1e4, mla_rope=True, q_lora_rank=24, rescale=True,
-        gate=True, indexer=Indexer(2, 16, 40))
-    _, params, x, positions = one_mixer(kind, seq=SEQ)
-    p = params["params"]
-    config = {
-        "hidden_size": 64, "rms_norm_eps": 1e-5, "q_lora_rank": 24,
-        "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 16,
-        "v_head_dim": 16, "rope_theta": 1e4, "attention_gate_type": "headwise",
-        "apply_mla_qkv_lora_rescale": True, "index_n_heads": 2,
-        "index_head_dim": 16, "index_topk": 40, "index_norm_eps": 1e-6}
-    with jax.default_matmul_precision("highest"):
-        uncut = reference.latent_attention(p, x[0], "full_attention", config)
-    total = 0.0
-    for rank in range(4):
-        held = slice(2 * rank, 2 * rank + 2)
-        mine = {"params": {
-            **p,
-            "q_b_proj": {"kernel": p["q_b_proj"]["kernel"][:, held]},
-            "kv_b_proj": {"kernel": p["kv_b_proj"]["kernel"][:, held]},
-            "g_proj": {"kernel": p["g_proj"]["kernel"][:, held]},
-            "o_proj": {"kernel": p["o_proj"]["kernel"][held]},
-        }}
-        share = one_mixer(dataclasses.replace(
-            kind, heads_held=(2 * rank, 2 * rank + 2)))[0]
-        out = share.apply(mine, x, positions)[0]
-        with jax.default_matmul_precision("highest"):
-            want = reference.latent_attention(
-                mine["params"], x[0], "full_attention", config)
-        np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
-        total = total + out
-    np.testing.assert_allclose(total, uncut, rtol=1e-4, atol=2e-5)
-
-
-def expert_layer(held):
-    """One expert layer at dots3's routing: 32 experts scored, top-2,
-    sigmoid, renormalised, x 1, one shared expert; ``held`` of them here."""
-    cfg = Dots3Config(
-        hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
-        num_experts=32, num_experts_per_tok=2, num_shared_experts=1,
-        experts_held=held, initializer_range=0.5,
-        dtype=jnp.float32, param_dtype=jnp.float32,
-    )
-    return MoELayer(cfg)
-
-
-def layer_config(held) -> dict:
-    """The reference's keys for that layer."""
-    lo, hi = held or (0, 32)
-    return {"n_routed_experts_published": 32, "n_routed_experts": hi - lo,
-            "expert_rank": lo // (hi - lo), "num_experts_per_tok": 2,
-            "routed_scaling_factor": 1, "norm_topk_prob": True,
-            "n_shared_experts": 1}
-
-
-def test_the_32_expert_ranks_shares_add_up_to_the_uncut_layer():
-    """32 ranks of one expert each: the routed parts they give, with the
-    shared expert (which every rank computes alike) counted once, are the
-    uncut reference's expert layer. With the head ranks' sum above, the parts
-    of all 4 x 32 ranks are the uncut layer's two sublayers."""
-    x = jnp.asarray(np.random.default_rng(1).normal(size=(1, 64, 32)), jnp.float32)
-    params = expert_layer(None).init(jax.random.PRNGKey(1), x)["params"]
-    tokens = x.reshape(-1, 32)
-    with jax.default_matmul_precision("highest"):
-        uncut = reference.moe(params, tokens, layer_config(None))
-        shared = reference.swiglu(params["shared"], tokens)
-        gates = np.asarray(reference.router_gates(params, tokens, layer_config(None)))
-    total, pairs = 0.0, 0
-    for rank in range(32):
-        held = (rank, rank + 1)
-        mine = {**params, **{k: params[k][held[0]:held[1]]
-                             for k in ("w_gate", "w_up", "w_down")}}
-        out = expert_layer(held).apply({"params": mine}, x).reshape(-1, 32)
-        if rank % 8 == 0:
-            with jax.default_matmul_precision("highest"):
-                want = reference.moe(mine, tokens, layer_config(held))
-            np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
-        pairs += int((gates[:, rank] > 0).sum())
-        total = total + (out - shared)
-    assert pairs == 64 * 2  # every pair is held by exactly one rank
-    np.testing.assert_allclose(total + shared, uncut, rtol=1e-4, atol=2e-5)
-    np.testing.assert_allclose(gates.sum(-1), 1.0, rtol=1e-5)  # renormalised, x 1
 
 
 # --------------------------------------------------- the configuration file

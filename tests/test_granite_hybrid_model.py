@@ -9,9 +9,15 @@ loss and every gradient leaf against the plain reference
 token), the kernels interpreted; each wrong program and wrong reference of
 ``benchmarks/tools/wrong_granite_hybrid.py`` far from it; the share tied to
 the model (the cut's layers and its quarter of the tied table against the
-whole); and what the benchmark states of the cell."""
+whole); and what the benchmark states of the cell.
+
+This file holds the model's logits against its reference, the wrong programs
+and references, and what the benchmark states of the cell. The loss and
+gradients, the quarter's logits, the odd size and the parameter counts are in
+``tests/test_granite_hybrid_gradients.py`` beside it, over
+``tests/granite_hybrid_cases.py``.
+"""
 import json
-import math
 import os
 
 import jax
@@ -26,65 +32,16 @@ from benchmarks.tools import wrong_granite_hybrid
 from ray_tpu.models.granite_hybrid import (
     GraniteHybridConfig, GraniteHybridForCausalLM,
 )
-from ray_tpu.models.llama import chunked_causal_lm_loss
 from ray_tpu.util import tracing
 
+from granite_hybrid_cases import (  # noqa: F401 - fixtures
+    CONFIG, NEAR, SEQ, granite, in_float32, interpret, leaves,
+)
 
-SEQ = 512  # two chunks of 256, and two blocks of the reference's query rows
+
 CELL = "granite-4-h-micro-l10.pretrain-8k"
-CONFIG = f"{cells.BENCH_DIR}/configs/granite-4-h-micro-l10.json"
-# 3 state-space heads of 24 over a state of 40, 6 query heads of 24 over 3 K/V
-# heads: nothing a power of two, no head count a multiple of a group of 8.
-ODD = {"hidden_size": 72, "intermediate_size": 160, "shared_intermediate_size": 160,
-       "num_attention_heads": 6, "num_key_value_heads": 3, "head_dim": 24,
-       "vocab_size": 384, "mamba_n_heads": 3, "mamba_d_head": 24,
-       "mamba_d_state": 40, "mamba_expand": 1, "attention_multiplier": 0.03}
-ODD_SEQ = 200
 # Past these a float32 program is another function than the reference.
 FAR = {"per_position_rel_err": 1e-3, "min_share_within": 0.5}
-NEAR = {"per_position_rel_err": 5e-5, "min_share_within": 1.0}
-
-
-@pytest.fixture(scope="module", autouse=True)
-def interpret():
-    # The scan's and the convolution's kernels and, from 128 rows, the flash ones.
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-        yield
-
-
-def in_float32(config):
-    return {**config, "program": {
-        **config["program"],
-        "set": {**config["program"]["set"], "dtype": "float32",
-                "param_dtype": "float32"}}}
-
-
-def build(sizes, seq, seed):
-    config = in_float32({**cells.load_json(CONFIG), **sizes})
-    model = GraniteHybridForCausalLM(cells.program_config(config))
-    ids = np.random.default_rng(seed).integers(0, config["vocab_size"], seq)
-    ids = ids.astype(np.int32)
-    params = jax.jit(model.init)(jax.random.PRNGKey(seed), ids[None, :8])
-    # The draws of 0.02 leave every projection of 128 channels near zero: the
-    # steps are then all bias, B and C all filter bias and q.k all weight.
-    # Projections of unit size give the recurrence and the soft-max data.
-    p = jax.tree_util.tree_map_with_path(
-        lambda path, w: w * 8.0 if path[-1].key == "kernel" and path[-2].key in (
-            "q_proj", "k_proj", "v_proj", "xbc_proj", "dt_proj", "z_proj") else w,
-        params["params"])
-    return config, model, {"params": p}, ids
-
-
-@pytest.fixture(scope="module")
-def granite():
-    """(configuration dict at the rehearsal size, model, params, ids), float32."""
-    return build(cells.load_json(CONFIG)["rehearsal"], SEQ, 0)
-
-
-@pytest.fixture(scope="module")
-def odd():
-    return build(ODD, ODD_SEQ, 1)
 
 
 @pytest.fixture(scope="module")
@@ -153,10 +110,6 @@ def test_what_the_builder_cannot_build_is_refused(change, message):
         cells.program_config({**cells.load_json(CONFIG), **change})
 
 
-def leaves(tree):
-    return sum(math.prod(x.shape) for x in jax.tree_util.tree_leaves(tree))
-
-
 def test_num_params_is_the_tree_at_the_published_widths_and_whole():
     config = cells.load_json(CONFIG)
     cfg = cells.program_config(config)
@@ -186,12 +139,6 @@ def test_num_params_is_the_tree_at_the_published_widths_and_whole():
     assert whole.num_params() == config["parameters_whole_model"] == 3_191_396_096
 
 
-@pytest.mark.parametrize("which", ["granite", "odd"])
-def test_num_params_is_the_tree_at_the_small_sizes(request, which):
-    _, model, params, _ = request.getfixturevalue(which)
-    assert leaves(params) == model.cfg.num_params()
-
-
 # -------------------------------------------- the model against the reference
 
 
@@ -200,14 +147,6 @@ def test_logits_agree_with_the_reference_in_float32(granite, expected):
     system = jax.jit(model.apply)(params, ids[None])[0]
     assert system.dtype == jnp.float32
     result = logits_agreement(system, expected, NEAR)
-    assert result["ok"], result
-
-
-def test_logits_agree_at_an_odd_size(odd):
-    config, model, params, ids = odd
-    system = jax.jit(model.apply)(params, ids[None])[0]
-    result = logits_agreement(
-        system, reference.forward(params, ids, config, ODD_SEQ), NEAR)
     assert result["ok"], result
 
 
@@ -251,39 +190,6 @@ def test_a_wrong_program_or_reference_is_refused(granite, expected, monkeypatch,
     assert not result["ok"], result
 
 
-@pytest.fixture(scope="module")
-def both_gradients(granite):
-    config, model, params, ids = granite
-    targets = np.roll(ids, -1)
-    system = jax.jit(jax.value_and_grad(
-        lambda p: chunked_causal_lm_loss(
-            model, p, ids[None], targets[None], chunk_size=64)
-    ))(params)
-    wanted = jax.jit(jax.value_and_grad(
-        lambda p: reference.loss(p, ids, targets, config)
-    ))(params)
-    return system, wanted
-
-
-def test_the_chunked_loss_agrees_with_the_reference(both_gradients):
-    (loss, _), (wanted, _) = both_gradients
-    assert float(loss) == pytest.approx(float(wanted), rel=1e-5)
-
-
-def test_every_gradient_agrees_with_the_references(both_gradients):
-    (_, grads), (_, wanted) = both_gradients
-    flat = dict(jax.tree_util.tree_leaves_with_path(grads["params"]))
-    errors = {}
-    for path, want in jax.tree_util.tree_leaves_with_path(wanted["params"]):
-        got, want = np.asarray(flat[path]), np.asarray(want)
-        assert got.shape == want.shape and np.abs(want).max() > 0, path
-        errors[jax.tree_util.keystr(path)] = np.abs(got - want).max() / np.abs(want).max()
-    assert max(errors.values()) < 1e-4, max(errors.items(), key=lambda e: e[1])
-    # a layer: 2 norms and the MLP's 3 weights; a mamba mixer's 10 leaves, the
-    # attention mixer's 4; the tied embedding and the final norm
-    assert len(errors) == 10 * 5 + 9 * 10 + 4 + 2
-
-
 # --------------------------------------------- the share tied to the model
 
 
@@ -294,30 +200,6 @@ def test_the_cuts_layers_are_layers_0_to_9_of_the_whole_pattern():
     assert cut.layers == whole.layers[:10]
     # one whole period: every later stage of ten layers has the same pattern
     assert all(whole.layers[i:i + 10] == cut.layers for i in (10, 20, 30))
-
-
-def test_the_quarters_logits_are_the_first_columns_of_the_whole_tables(granite):
-    """The held slice against the whole: a model with four times the table
-    whose first quarter is this one's gives, on ids of the slice, logits whose
-    first columns are the slice's (a tied table is read by rows going in and
-    by rows coming out, and no row looks at another)."""
-    config, model, params, ids = granite
-    held = config["vocab_size"]
-    rest = jax.random.normal(jax.random.PRNGKey(7), (3 * held, config["hidden_size"])) * 0.02
-    table = params["params"]["embed_tokens"]["embedding"]
-    whole_params = {"params": {**params["params"], "embed_tokens": {
-        "embedding": jnp.concatenate([table, rest])}}}
-    whole = GraniteHybridForCausalLM(cells.program_config(in_float32(
-        {**config, "vocab_size": 4 * held})))
-    logits = jax.jit(whole.apply)(whole_params, ids[None])[0]
-    assert logits.shape == (SEQ, 4 * held)
-    mine = jax.jit(model.apply)(params, ids[None])[0]
-    np.testing.assert_allclose(np.asarray(logits[:, :held]), np.asarray(mine),
-                               rtol=1e-5, atol=1e-6)
-    whole_reference = reference.forward(
-        whole_params, ids, {**config, "vocab_size": 4 * held}, SEQ)
-    result = logits_agreement(logits, whole_reference, NEAR)
-    assert result["ok"], result
 
 
 # ------------------------------------------------- what the benchmark states

@@ -138,7 +138,7 @@ def tree_digest(tree) -> tuple:
 
 # Read by this same code at the parent of the PR that let a layer choose
 # its mixer and FFN (commit 57913f4), at each file's rehearsal size. The
-# lowered steps' kernel counts are in tests/test_kernels_aot_v5e.py.
+# lowered steps' kernel counts are in tests/test_aot_v5e_steps_before.py.
 TREES_BEFORE = {
     "mistral-7b-l4": ("06a35641bbb39a58", 21),
     "mixtral-8x7b-l2": ("ec5224b367a49c8f", 22),

@@ -320,8 +320,8 @@ def test_a_differing_static_is_another_trace(static, fresh_traces):
 
 def test_a_patched_constant_a_body_reads_is_another_trace(monkeypatch, fresh_traces):
     """What the kernels' tests patch between two calls of one shape
-    (``tests/test_kda_op.py``, ``tests/test_gmm_kernel.py``,
-    ``tests/test_flash_interpret.py``) is in the entries' keys."""
+    (``tests/test_kda_op.py`` and its five siblings, ``tests/test_gmm_kernel.py``,
+    ``tests/test_flash_interpret.py`` and the ``tests/test_flash_tile*.py``) is in the entries' keys."""
     call = scan_statics()["states"][0]
     lhs, rhs = draw(1, 256, 128, dtype=F32), draw(2, 2, 128, 256, dtype=F32)
     rows = lambda: G._gmm_pallas(lhs, rhs, jnp.asarray([0, 1], jnp.int32), 128)  # noqa: E731

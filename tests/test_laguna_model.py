@@ -12,8 +12,12 @@ closed form at the published numbers, what a full layer's rotation turns,
 the four ranks' shares of one expert layer against the uncut reference, the
 programs and references of ``benchmarks/tools/wrong_laguna.py`` and three
 more made wrong, and the configuration file against the catalog's row.
+
+This file holds the model's logits against its reference, the wrong programs
+and references, and the configuration file's cases. The loss and gradients and
+the ranks' shares are in ``tests/test_laguna_gradients.py`` beside it, over
+``tests/laguna_cases.py``.
 """
-import dataclasses
 import importlib
 import math
 
@@ -26,44 +30,17 @@ from benchmarks.lib import cells
 from benchmarks.lib.checks import logits_agreement
 from benchmarks.reference import laguna_decoder as reference
 from benchmarks.tools import wrong_laguna
-from ray_tpu.models.laguna import LagunaConfig, LagunaForCausalLM, LayerAttention
-from ray_tpu.models.llama import _rope, chunked_causal_lm_loss
-from ray_tpu.models.mixtral import MoELayer
+from ray_tpu.models.laguna import LagunaForCausalLM, LayerAttention
+from ray_tpu.models.llama import _rope
 from ray_tpu.util import tracing
 
-SEQ = 128
-CONFIG = f"{cells.BENCH_DIR}/configs/laguna-xs2-33b-a3b-l8.json"
+from laguna_cases import (  # noqa: F401 - fixtures
+    CONFIG, SEQ, interpret, laguna,
+)
+
+
 # Past these a float32 program is another function than the reference.
 FAR = {"per_position_rel_err": 1e-3, "min_share_within": 0.5}
-
-
-@pytest.fixture(scope="module", autouse=True)
-def interpret():
-    # "gmm" has no XLA stand-in: on the CPU its kernels are interpreted, and
-    # at 128 rows so are the flash kernels, windowed and causal.
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-        yield
-
-
-@pytest.fixture(scope="module")
-def laguna():
-    """(configuration dict at a tiny size, model, params, ids): the file's
-    rehearsal widths, a window of 40 under 128 positions, 16 experts top-2 of
-    which 4 are held, float32."""
-    config = cells.load_json(CONFIG)
-    config = {**config, **config["rehearsal"], "sliding_window": 40,
-              "num_experts_per_tok": 2}
-    config["program"] = {
-        **config["program"],
-        "set": {**config["program"]["set"], "dtype": "float32",
-                "param_dtype": "float32"},
-    }
-    model = LagunaForCausalLM(cells.program_config(config))
-    ids = np.random.default_rng(0).integers(0, config["vocab_size"], SEQ)
-    ids = ids.astype(np.int32)
-    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids[None, :8])
-    return config, model, params, ids
 
 
 @pytest.fixture(scope="module")
@@ -237,96 +214,6 @@ def test_a_wrong_reference_is_far_from_the_program(
     other = reference.forward(params, ids, config, SEQ)
     result = logits_agreement(other, expected, FAR)
     assert not result["ok"], result
-
-
-@pytest.fixture(scope="module")
-def both_gradients(laguna):
-    config, model, params, ids = laguna
-    targets = np.roll(ids, -1)
-    system = jax.jit(jax.value_and_grad(
-        lambda p: chunked_causal_lm_loss(
-            model, p, ids[None], targets[None], chunk_size=64)
-    ))(params)
-    wanted = jax.jit(jax.value_and_grad(
-        lambda p: reference.loss(p, ids, targets, config)
-    ))(params)
-    return system, wanted
-
-
-def test_the_chunked_loss_agrees_with_the_reference(both_gradients):
-    (loss, _), (wanted, _) = both_gradients
-    assert float(loss) == pytest.approx(float(wanted), rel=1e-5)
-
-
-def test_every_gradient_agrees_with_the_references(both_gradients):
-    (_, grads), (_, wanted) = both_gradients
-    flat = dict(jax.tree_util.tree_leaves_with_path(grads["params"]))
-    checked = 0
-    for path, want in jax.tree_util.tree_leaves_with_path(wanted["params"]):
-        got, want = np.asarray(flat[path]), np.asarray(want)
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['router_bias']"):
-            assert not got.any() and not want.any()  # no gradient reaches it
-            continue
-        assert got.shape == want.shape and np.abs(want).max() > 0, name
-        np.testing.assert_allclose(
-            got, want, rtol=5e-3, atol=5e-5 * np.abs(want).max(), err_msg=name)
-        checked += 1
-    # 2 norms a layer, 5 mixer weights, 3 dense or 7 expert-layer weights;
-    # embedding, final norm, head
-    assert checked == 8 * 7 + 3 + 7 * 7 + 3
-
-
-# ------------------------------------------------- the expert layer alone
-
-
-def expert_layer(held):
-    """One expert layer at Laguna's routing: 16 experts scored, top-2,
-    sigmoid, renormalised, x 2.5, one shared expert; ``held`` of them here."""
-    cfg = LagunaConfig(
-        hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
-        num_experts=16, num_experts_per_tok=2, num_shared_experts=1,
-        routed_scaling_factor=2.5, experts_held=held, initializer_range=0.5,
-        dtype=jnp.float32, param_dtype=jnp.float32,
-    )
-    return MoELayer(cfg)
-
-
-def layer_config(held) -> dict:
-    """The reference's keys for that layer."""
-    lo, hi = held or (0, 16)
-    return {"num_experts_published": 16, "num_experts": hi - lo,
-            "expert_rank": lo // (hi - lo), "num_experts_per_tok": 2,
-            "moe_routed_scaling_factor": 2.5}
-
-
-def test_the_four_ranks_shares_add_up_to_the_uncut_layer():
-    """Four ranks of four experts each: the routed parts they give, with the
-    shared expert (which every rank computes alike) counted once, are the
-    uncut reference's expert layer."""
-    x = jnp.asarray(np.random.default_rng(1).normal(size=(2, 48, 32)), jnp.float32)
-    params = expert_layer(None).init(jax.random.PRNGKey(1), x)["params"]
-    tokens = x.reshape(-1, 32)
-    with jax.default_matmul_precision("highest"):
-        uncut = reference.moe(params, tokens, layer_config(None))
-        shared = reference.swiglu(params["shared"], tokens)
-    total, pairs = 0.0, 0
-    for rank in range(4):
-        held = (4 * rank, 4 * rank + 4)
-        mine = {**params, **{k: params[k][held[0]:held[1]]
-                             for k in ("w_gate", "w_up", "w_down")}}
-        out = expert_layer(held).apply({"params": mine}, x).reshape(-1, 32)
-        with jax.default_matmul_precision("highest"):
-            want = reference.moe(mine, tokens, layer_config(held))
-            gates = reference.router_gates(params, tokens, layer_config(held))
-        np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
-        pairs += int((np.asarray(gates)[:, held[0]:held[1]] > 0).sum())
-        total = total + (out - shared)
-    assert pairs == 96 * 2  # every pair is held by exactly one rank
-    np.testing.assert_allclose(total + shared, uncut, rtol=1e-4, atol=2e-5)
-    # gates: two a token, renormalised, times 2.5
-    np.testing.assert_allclose(np.asarray(gates).sum(-1), 2.5, rtol=1e-5)
-    assert ((np.asarray(gates) > 0).sum(-1) == 2).all()
 
 
 # --------------------------------------------------- the configuration file

@@ -98,7 +98,7 @@ def _on_a_thread_of_its_own(fn):
     result = []
     thread = threading.Thread(target=lambda: result.append(fn()), daemon=True)
     thread.start()
-    thread.join(5)
+    thread.join(60)  # a deadlock outlasts any bound; a busy machine's pass not this one
     assert not thread.is_alive(), "decr waits for the lock its thread holds"
     assert result, "raised: see the thread's exception in the warnings"
     return result[0]
@@ -113,14 +113,23 @@ def test_a_ref_the_collector_frees_inside_the_lock_does_not_deadlock(tracker):
     the driver's next submit then waited for ever in ``incr``)."""
     c = _FakeClient()
     t = tracker(c)
+    # No flusher thread: one inside ``flush`` as the region below ends would
+    # apply the queued decrement on its own way out, a moment after the
+    # asserts (seen beside six busy processes).
+    t.stop()
     t.incr(b"incycle0", c.worker_id.binary())
 
-
     def a_pass_inside_the_lock():
-        _garbage_that_drops(t, b"incycle0")
-        with t._lock:
-            gc.collect()
-            return dict(t._counts)
+        # The pass that frees the cycle is the one below and no automatic
+        # one before the lock is held, however much other threads allocate.
+        gc.disable()
+        try:
+            _garbage_that_drops(t, b"incycle0")
+            with t._lock:
+                gc.collect()
+                return dict(t._counts)
+        finally:
+            gc.enable()
 
     # Queued, not applied under the region's feet; applied on the way
     # out of it, with no further call.

@@ -13,12 +13,16 @@ each another function; the five ranks' shares of one expert layer against the
 uncut reference; the configuration file against the catalog's row; and the
 models that share ``Attention`` and ``KDAMixer``, whose parameter trees and
 lowered forward passes are what they were.
+
+This file holds the model's logits against its reference, the wrong programs
+and references, and the configuration file's cases. The loss and gradients
+(``tests/test_solar_open2_gradients.py``) and the ranks' shares and sibling
+models (``test_solar_open2_layers.py``) are beside it, over
+``tests/solar_open2_cases.py``.
 """
 import dataclasses
-import hashlib
 import importlib
 import math
-import re
 
 import jax
 import jax.numpy as jnp
@@ -29,51 +33,16 @@ from benchmarks.lib import cells
 from benchmarks.lib.checks import logits_agreement
 from benchmarks.reference import solar_open2_decoder as reference
 from benchmarks.tools import wrong_solar
-from ray_tpu.models.llama import chunked_causal_lm_loss
-from ray_tpu.models.mixtral import MoELayer
-from ray_tpu.models.solar_open2 import SolarOpen2Config, SolarOpen2ForCausalLM
+from ray_tpu.models.solar_open2 import SolarOpen2ForCausalLM
 from ray_tpu.util import tracing
 
-SEQ = 128
-CONFIG = f"{cells.BENCH_DIR}/configs/solar-open2-250b-l4.json"
+from solar_open2_cases import (  # noqa: F401 - fixtures
+    CONFIG, SEQ, interpret, solar,
+)
+
+
 # Past these a float32 program is another function than the reference.
 FAR = {"per_position_rel_err": 1e-3, "min_share_within": 0.5}
-
-
-@pytest.fixture(scope="module", autouse=True)
-def interpret():
-    # "gmm" has no XLA stand-in: on the CPU its kernels are interpreted, and
-    # with them the scan kernels of ops/kda.py and, at 128 rows, the flash
-    # kernels.
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-        yield
-
-
-@pytest.fixture(scope="module")
-def solar():
-    """(configuration dict at its rehearsal size, model, params, ids): 4 q
-    heads over 2 K/V heads of 32, 4 KDA heads of 32, 20 experts top-4 of
-    which 4 are held, float32."""
-    config = cells.load_json(CONFIG)
-    config = {**config, **config["rehearsal"]}
-    config["program"] = {
-        **config["program"],
-        "set": {**config["program"]["set"], "dtype": "float32",
-                "param_dtype": "float32"},
-    }
-    model = SolarOpen2ForCausalLM(cells.program_config(config))
-    ids = np.random.default_rng(0).integers(0, config["vocab_size"], SEQ)
-    ids = ids.astype(np.int32)
-    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids[None, :8])
-    # b_proj's draw of 0.02 leaves beta within 0.25 of 1: widen it, so that
-    # beta runs over (0, 2) and a doubling left out is far from the model.
-    p = dict(params["params"])
-    for i in (1, 2, 3):
-        kda = dict(p[f"layers_{i}"]["kda"])
-        kda["b_proj"] = {"kernel": kda["b_proj"]["kernel"] * 12.0}
-        p[f"layers_{i}"] = {**p[f"layers_{i}"], "kda": kda}
-    return config, model, {"params": p}, ids
 
 
 @pytest.fixture(scope="module")
@@ -221,116 +190,6 @@ def test_a_wrong_reference_is_far_from_the_program(
     assert not result["ok"], result
 
 
-@pytest.fixture(scope="module")
-def both_gradients(solar):
-    config, model, params, ids = solar
-    targets = np.roll(ids, -1)
-    system = jax.jit(jax.value_and_grad(
-        lambda p: chunked_causal_lm_loss(
-            model, p, ids[None], targets[None], chunk_size=64)
-    ))(params)
-    wanted = jax.jit(jax.value_and_grad(
-        lambda p: reference.loss(p, ids, targets, config)
-    ))(params)
-    return system, wanted
-
-
-def test_the_chunked_loss_agrees_with_the_reference(both_gradients):
-    (loss, _), (wanted, _) = both_gradients
-    assert float(loss) == pytest.approx(float(wanted), rel=1e-5)
-
-
-def test_every_gradient_agrees_with_the_references(both_gradients):
-    (_, grads), (_, wanted) = both_gradients
-    flat = dict(jax.tree_util.tree_leaves_with_path(grads["params"]))
-    checked = 0
-    for path, want in jax.tree_util.tree_leaves_with_path(wanted["params"]):
-        got, want = np.asarray(flat[path]), np.asarray(want)
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['router_bias']"):
-            assert not got.any() and not want.any()  # no gradient reaches it
-            continue
-        assert got.shape == want.shape and np.abs(want).max() > 0, name
-        np.testing.assert_allclose(
-            got, want, rtol=5e-3, atol=5e-5 * np.abs(want).max(), err_msg=name)
-        checked += 1
-    # a layer: 2 norms and 7 expert-layer weights; the GQA mixer's 5 weights,
-    # a KDA mixer's 16 (g_b_proj has a bias); embedding, final norm, head
-    assert checked == 4 * 9 + 5 + 3 * 16 + 3
-
-
-# ------------------------------------------------- the expert layer alone
-
-
-def expert_layer(held):
-    """One expert layer at Solar-Open2's routing: 20 experts scored, top-4,
-    sigmoid, renormalised, x 1, one shared expert; ``held`` of them here."""
-    cfg = SolarOpen2Config(
-        hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
-        num_experts=20, num_experts_per_tok=4, num_shared_experts=1,
-        experts_held=held, initializer_range=0.5, held_rows="gather",
-        dtype=jnp.float32, param_dtype=jnp.float32,
-    )
-    return MoELayer(cfg)
-
-
-def layer_config(held) -> dict:
-    """The reference's keys for that layer."""
-    lo, hi = held or (0, 20)
-    return {"n_routed_experts_published": 20, "n_routed_experts": hi - lo,
-            "expert_rank": lo // (hi - lo), "num_experts_per_tok": 4,
-            "norm_topk_prob": True, "routed_scaling_factor": 1,
-            "n_shared_experts": 1}
-
-
-@pytest.mark.parametrize("taker", [None, 2], ids=["as-scored", "one-rank-takes-all"])
-def test_the_five_ranks_shares_add_up_to_the_uncut_layer(taker):
-    """Five ranks of four experts each, a rank count that is no power of two
-    under a router whose width is no multiple of 128: the routed parts they
-    give, with the shared expert (which every rank computes alike) counted
-    once, are the uncut reference's expert layer; as the router scores at its
-    initial values, and with a router that sends every pair to one rank's four
-    experts and none to the sixteen others (the layout's bound, and a rank
-    whose tiles hold padding alone)."""
-    x = jnp.asarray(np.random.default_rng(1).normal(size=(2, 48, 32)), jnp.float32)
-    params = expert_layer(None).init(jax.random.PRNGKey(1), x)["params"]
-    if taker is not None:
-        # The reference reads no selection bias: one feature that every token
-        # holds, and a router that scores it for one rank's experts alone.
-        x = x.at[..., 0].set(4.0)
-        scores = np.full(20, -5.0, np.float32)
-        scores[4 * taker:4 * taker + 4] = 5.0
-        kernel = params["router"]["kernel"].at[0].set(scores)
-        params = {**params, "router": {"kernel": kernel}}
-    tokens = x.reshape(-1, 32)
-    with jax.default_matmul_precision("highest"):
-        uncut = reference.moe(params, tokens, layer_config(None))
-        shared = reference.shared_expert(params, tokens)
-        gates = np.asarray(reference.router_gates(params, tokens, layer_config(None)))
-    total, pairs = 0.0, 0
-    for rank in range(5):
-        held = (4 * rank, 4 * rank + 4)
-        mine = {**params, **{k: params[k][held[0]:held[1]]
-                             for k in ("w_gate", "w_up", "w_down")}}
-        out = expert_layer(held).apply({"params": mine}, x).reshape(-1, 32)
-        with jax.default_matmul_precision("highest"):
-            want = reference.moe(mine, tokens, layer_config(held))
-        np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
-        mine_pairs = int((gates[:, held[0]:held[1]] > 0).sum())
-        if taker is not None:
-            assert mine_pairs == (96 * 4 if rank == taker else 0)
-        pairs += mine_pairs
-        total = total + (out - shared)
-    assert pairs == 96 * 4  # every pair is held by exactly one rank
-    np.testing.assert_allclose(total + shared, uncut, rtol=1e-4, atol=2e-5)
-    # gates: four a token, renormalised, times 1
-    np.testing.assert_allclose(gates.sum(-1), 1.0, rtol=1e-5)
-    assert ((gates > 0).sum(-1) == 4).all()
-    # and the uncut layer through the program is the reference's too
-    whole = expert_layer(None).apply({"params": params}, x).reshape(-1, 32)
-    np.testing.assert_allclose(whole, uncut, rtol=1e-4, atol=1e-5)
-
-
 # --------------------------------------------------- the configuration file
 
 
@@ -418,77 +277,3 @@ def test_the_cells_flops_and_kernels_follow_the_layers():
     for name in ("model.gqa_share", "model.attn_gate_share"):
         reader = cells.load_reader(f"{cells.BENCH_DIR}/layer_metrics", name)
         assert reader.read({"trace_data": None}) is None
-
-
-# ------------------- the models that share Attention, KDAMixer and MoELayer
-
-
-def tree_digest(tree) -> tuple:
-    """Names, shapes and dtypes of a parameter tree in one digest, and the
-    number of leaves (tests/test_hybrid_layers.py's)."""
-    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    text = ";".join(f"{jax.tree_util.keystr(p)}:{x.shape}:{x.dtype}" for p, x in flat)
-    return hashlib.sha1(text.encode()).hexdigest()[:16], len(flat)
-
-
-# Read by this same code at the parent of the PR that gave ``AttentionKind``
-# "no rotation" and a gate of q's width and took ``KDAMixer`` off the
-# latent-attention config (commit 7b31701), at each file's rehearsal size:
-# the parameter tree's digest and leaves, and the sha1 of the lowered forward
-# pass's text over [1, 128] ids, kernels interpreted. One text, one function:
-# the same outputs bit for bit. A later change to one of these models on
-# purpose reads its new values the same way: Kimi-Linear's text since
-# ``KDAMixer`` convolves through ``ops/kda.py`` ``conv_silu``, whose two
-# Pallas passes are interpreted here with the others ("6740a415a90863fc"
-# before, with ``silu(short_conv)`` as XLA has it; tests/test_kda_op.py holds
-# the two to each other). The text is read without the counters JAX gives its
-# private functions (``@silu_158``): a ``checkpoint_name`` lowers to nothing
-# and moves them (models/llama.py REPLAY_KEEPS; these four digests read the
-# same at that change's parent and after it). Laguna's text since
-# ``ops/attention.py`` walks every mask by one forward (PR 56): its windowed
-# forward kernel, interpreted here, finds its band's first and last block
-# after the start and not before it, adds the step to the first once and not
-# twice, and takes q's K/V head first in K's and V's index maps; the tile's
-# operations are where they were ("cc97cf680f1bedbe" before; the logits and
-# every gradient at this size are the parent's bit for bit, PERF.md §6,
-# PR 56). The three others, which run the causal kernels alone, read the same.
-# Laguna's again since its held eighth's rows, ``held_rows`` "gather", reach
-# their slots over the used tiles alone (``_held_ffn`` with a gather back to
-# tokens; "0e16e4b782b6a5a6" before, with every pair laid out): Kimi-Linear's
-# and sarvam's, which walk, read what they read. Kimi-Linear's again since the
-# scan's kernels are called through ``ops/attention.py`` ``kernel_entry``
-# (PR 68): the four KDA layers share one trace of ``_forward_pallas``, so the
-# matrix of running sums it builds (``_sum_matrix``) is one constant of the
-# text where each layer's trace wrote its own ("c16ef491925e5adb" before: the
-# same text but for three ``stablehlo.constant`` lines and the numbering after
-# them). The three others hold no constant of a kernel's wrapper.
-# Xing4's line was read the same way at the parent of the PR that made
-# ``MLAMixer`` learn its widths, head count, window, gate, rescale and indexer
-# from the layer's kind (``MLAConfig.latent``, PR 69), where Kimi-Linear's and
-# sarvam's read what they read: the three models whose mixer it is lower to
-# the text they lowered to. Laguna's and Xing4's again since a held share's
-# rows come back to tokens by a kernel over tokens (``ops/gmm.py``
-# ``pairs_summed``, PR 70; "ec64c261a184e22c" and "8f712a609e700b41" before,
-# with a gather over every pair): the two of the five that gather. Kimi-Linear's
-# and sarvam's, which walk, and Mistral's read what they read.
-BEFORE = {
-    "kimi-linear-48b-a3b-l5": ("4ed2711bc2778250", 117, "59821762e752310d"),
-    "laguna-xs2-33b-a3b-l8": ("304ffe861753dc5a", 118, "a67063925f4a1684"),
-    "mistral-7b-l4": ("06a35641bbb39a58", 21, "6ac84cd0523ca00f"),
-    "sarvam-105b-l5": ("c710f6841e29dd3a", 83, "2a9ffca6aec4a4c6"),
-    "xing4-29b-a4b-l5": ("f8dffe54740580c8", 164, "858b93108f6bfbdd"),
-}
-
-
-@pytest.mark.parametrize("name", sorted(BEFORE))
-def test_the_models_that_share_the_mixers_are_bit_for_bit_what_they_were(name):
-    config = cells.load_json(f"{cells.BENCH_DIR}/configs/{name}.json")
-    config = {**config, **config["rehearsal"]}
-    model = cells.resolve(config["program"]["model"])(cells.program_config(config))
-    shapes = jax.eval_shape(
-        model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))
-    text = jax.jit(model.apply).lower(
-        shapes, jax.ShapeDtypeStruct((1, 128), np.int32)).as_text()
-    text = re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
-    digest = hashlib.sha1(text.encode()).hexdigest()[:16]
-    assert (*tree_digest(shapes), digest) == BEFORE[name]
